@@ -71,6 +71,13 @@ class HwProfile:
 
 
 HW_PROFILES: dict[str, HwProfile] = {
+    # TPU v5e chip (Google Cloud "TPU v5e"): 197 TFLOP/s bf16, 16 GB HBM
+    # @ 819 GB/s, 1,600 Gbit/s chip-to-chip over 4 ICI links
+    "v5e": HwProfile(
+        name="v5e",
+        description="TPU v5e chip (bf16 MXU peak, HBM2e, 2D-torus ICI)",
+        peak_flops=197e12, hbm_gbps=819.0, ici_gbps=50.0,
+        hbm_gb=16.0, vmem_mb=128.0),
     # TPU v5p chip: 459 TFLOP/s bf16, 95 GB HBM2e @ 2765 GB/s, 6 ICI
     # links at ~100 GB/s per direction
     "v5p": HwProfile(
@@ -92,19 +99,29 @@ HW_PROFILES: dict[str, HwProfile] = {
 
 
 def hw_profile(name: str) -> HwProfile:
-    """Profile lookup.  ``auto`` resolves from the attached devices
-    (TPU v5 → ``v5p``, anything else → ``cpu-testbed``); an unknown
-    name is a clean error listing the table, never a KeyError."""
+    """Profile lookup.  ``auto`` resolves from the first attached device
+    ("TPU v5 lite" → ``v5e``, "TPU v5" → ``v5p``, a non-TPU platform →
+    ``cpu-testbed``); a TPU kind without a profile and an unknown name
+    are clean errors listing the table, never a KeyError or a default."""
     if name == "auto":
-        kind = ""
         try:
             import jax
 
-            kind = jax.devices()[0].device_kind.lower()
+            dev = jax.devices()[0]
         except (ImportError, IndexError, RuntimeError):
-            pass  # no backend attached: the CPU-testbed default stands
-        return HW_PROFILES["v5p" if "v5" in kind and "lite" not in kind
-                           else "cpu-testbed"]
+            # no backend attached: the CPU-testbed default stands
+            return HW_PROFILES["cpu-testbed"]
+        if dev.platform != "tpu":
+            return HW_PROFILES["cpu-testbed"]
+        kind = dev.device_kind.lower()
+        for prefix, prof in (("tpu v5 lite", "v5e"), ("tpu v5", "v5p")):
+            if kind.startswith(prefix):
+                return HW_PROFILES[prof]
+        raise ValueError(
+            f"no hardware profile for TPU device_kind "
+            f"{dev.device_kind!r}: known profiles are "
+            f"{', '.join(sorted(HW_PROFILES))} — pass --hw_profile or add "
+            "one to analysis/cost.HW_PROFILES")
     try:
         return HW_PROFILES[name]
     except KeyError:

@@ -263,11 +263,16 @@ def pallas_vmem_estimates(fn_or_jaxpr, *args) -> list[tuple[str, int]]:
         if eqn.primitive.name != "pallas_call":
             continue
         gm = eqn.params.get("grid_mapping")
-        label = str(eqn.params.get("name_and_src_info", "pallas_call"))
-        label = label.split(" ")[0].split("(")[0] or "pallas_call"
+        # the kernel function's name: pallas_call(name=...) when given,
+        # else the kernel jaxpr's debug info (jax 0.9)
+        debug = getattr(eqn.params.get("jaxpr"), "debug_info", None)
+        label = (eqn.params.get("name")
+                 or getattr(debug, "func_name", None) or "pallas_call")
         total = 0
         for bm in getattr(gm, "block_mappings", ()) or ():
-            shape = [d if isinstance(d, int) else 1
+            # jax 0.9 block dims are Blocked/Element objects carrying
+            # block_size; squeezed dims carry none and count as 1
+            shape = [d if isinstance(d, int) else getattr(d, "block_size", 1)
                      for d in getattr(bm, "block_shape", ())]
             sd = getattr(bm, "array_shape_dtype", None)
             total += _shape_dtype_bytes(shape, getattr(sd, "dtype", None))

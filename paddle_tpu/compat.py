@@ -1,4 +1,5 @@
-"""Drop-in ``paddle`` module aliasing + cross-version jax shims.
+"""Drop-in ``paddle`` module aliasing + the package's names for three jax
+entry points.
 
 Reference config files and demos start with ``from
 paddle.trainer_config_helpers import *`` or ``import paddle.v2 as paddle``.
@@ -9,11 +10,10 @@ in ``sys.modules`` so those files run unmodified against the TPU runtime
 The alias is only installed when no real ``paddle`` is importable, and is
 idempotent.
 
-``shard_map`` papers over the jax spelling change: new jax exports
-``jax.shard_map`` (replication checking via ``check_vma``); 0.4.x has
-``jax.experimental.shard_map.shard_map`` (``check_rep``).  Every
-shard_map user in this package goes through this one symbol so the
-parallel layers import (and run) on both.
+``shard_map``, ``tpu_compiler_params`` and ``axis_size`` are this
+package's one spelling of three jax entry points (the installed jax is
+0.9.0, the same on the chip machine): every shard_map user, every
+pallas kernel and every ring collective goes through these names.
 """
 
 from __future__ import annotations
@@ -21,47 +21,11 @@ from __future__ import annotations
 import importlib
 import sys
 
-try:  # new-jax spelling
-    from jax import shard_map as _jax_shard_map
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # jax 0.4.x spelling
-    from jax.experimental.shard_map import shard_map as _jax_shard_map
-
-    _CHECK_KW = "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-    """Version-portable ``jax.shard_map``; ``check_vma`` maps onto the
-    installed jax's replication-check kwarg (``check_rep`` on 0.4.x)."""
-    if check_vma is not None:
-        kwargs[_CHECK_KW] = check_vma
-    return _jax_shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kwargs)
-
-
-def tpu_compiler_params(**kwargs):
-    """Version-portable pallas-TPU compiler params: new jax spells the
-    class ``pltpu.CompilerParams``, older jax ``TPUCompilerParams`` (same
-    fields).  Every pallas kernel in this package goes through this one
-    constructor so the ops import (and run) on both."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a mapped mesh axis (``lax.axis_size`` where it
-    exists; 0.4.x exposes it as ``core.axis_frame(name)`` — an int, so
-    Python-level loop bounds like ppermute rings keep working)."""
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    from jax import core
-
-    return core.axis_frame(axis_name)
+from jax import shard_map  # noqa: F401  (re-exported)
+from jax.experimental.pallas.tpu import (  # noqa: F401
+    CompilerParams as tpu_compiler_params,
+)
+from jax.lax import axis_size  # noqa: F401
 
 _ALIASES = {
     "paddle": "paddle_tpu",

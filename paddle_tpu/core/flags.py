@@ -270,7 +270,7 @@ define("vmem_mb", 128.0, "per-kernel VMEM budget for the GL-P-MEM "
                          "cores carry 128 MB)")
 define("hw_profile", "auto", "hardware profile for the GL-P-COST static "
                              "roofline (peak FLOP/s, HBM and per-link "
-                             "ICI bandwidth): v5p | cpu-testbed | auto "
+                             "ICI bandwidth): v5e | v5p | cpu-testbed | auto "
                              "(resolve from the attached devices)")
 define("mfu_floor", 0.0, "minimum predicted MFU%% for the GL-P-COST "
                          "preflight gate: a config whose static roofline "
@@ -350,8 +350,13 @@ declare_env("PADDLE_TPU_MEMBERSHIP",
             "elastic fleet: membership file the launcher rewrites on "
             "host loss/scale events")
 declare_env("JAX_PLATFORMS",
-            "externally owned jax backend selector; capi_bridge "
-            "forwards it before first device use")
+            "externally owned jax backend selector, read by jax at "
+            "import (paddle_init --use_cpu sets it before the "
+            "interpreter starts)")
+declare_env("JAX_COMPILATION_CACHE_DIR",
+            "externally owned jax compile-cache directory; when set, "
+            "core/compile_cache.configure() sets no path in code, "
+            "otherwise the cache lives in <checkout>/.jax_cache")
 declare_env("PADDLE_REFERENCE_ROOT",
             "demo runners: checkout of the reference framework for "
             "side-by-side parity runs")

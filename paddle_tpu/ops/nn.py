@@ -37,9 +37,9 @@ def _tpp_kernels_on() -> bool:
     to their jnp references — the identical op sequence to this module —
     so CPU trajectories stay bit-equal either way (the bench ablation's
     ``trajectory_identical`` contract)."""
-    import jax as _jax
+    from paddle_tpu.ops.pallas import on_tpu
 
-    return _tpp().fused_enabled() and _jax.default_backend() == "tpu"
+    return _tpp().fused_enabled() and on_tpu()
 
 
 def conv2d(

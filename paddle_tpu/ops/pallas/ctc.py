@@ -42,16 +42,25 @@ from jax.experimental.pallas import tpu as pltpu
 from paddle_tpu.compat import tpu_compiler_params
 from paddle_tpu.ops.ctc import (NEG_INF, compact_decoded, ctc_greedy_decode,
                                 ctc_loss, ctc_tables)
-from paddle_tpu.ops.pallas import default_interpret
+from paddle_tpu.ops.pallas import (pad_axis, resolve_impl,
+                                   resolve_interpret, round_up)
 
 
-def _batch_block(b: int, want: int = 8) -> int:
-    """Largest divisor of b that is <= want (the per-grid-step batch
-    block; S and V ride the lane axis, so bb stays on sublanes)."""
-    for k in range(min(want, b), 0, -1):
-        if b % k == 0:
-            return k
-    return 1
+_SUBLANES = 8
+
+
+def _batch_block(b: int, want: int = _SUBLANES) -> tuple[int, int]:
+    """(block_rows, padded_batch): the batch rides the sublane axis of
+    every block (S and V ride lanes), so Mosaic needs block_rows to be a
+    multiple of 8 — the batch is zero-padded up to one, and the block is
+    the largest multiple of 8 <= ``want`` that divides the padded size.
+    Padded rows have input length 0: they freeze at t=0 and the callers
+    slice their outputs away."""
+    bpad = round_up(b, _SUBLANES)
+    bb = max(min(want, bpad) // _SUBLANES * _SUBLANES, _SUBLANES)
+    while bpad % bb:
+        bb -= _SUBLANES
+    return bb, bpad
 
 
 def _logaddexp(a, b):
@@ -73,7 +82,7 @@ def _ctc_kernel(logp_ref, ext_ref, skip_ref, valid_ref, ilen_ref, llen_ref,
     llen = llen_ref[...]                     # [bb, 1] i32
     bb = ext.shape[0]
 
-    z = logp_ref[:, 0, :].astype(jnp.float32)          # [bb, V]
+    z = logp_ref[0].astype(jnp.float32)                # [bb, V]
     if normalize:
         zm = jnp.max(z, axis=-1, keepdims=True)
         z = z - (zm + jnp.log(jnp.sum(jnp.exp(z - zm), axis=-1,
@@ -173,25 +182,32 @@ def _ctc_kernel(logp_ref, ext_ref, skip_ref, valid_ref, ilen_ref, llen_ref,
         else:
             grad = -contrib
         grad = jnp.where(tr < ilen, grad, 0.0)
-        grad_ref[...] = grad[:, None, :].astype(grad_ref.dtype)
+        grad_ref[0] = grad.astype(grad_ref.dtype)
 
 
 def _ctc_call(log_probs, ext, can_skip, ext_valid, ilen, llen, *,
               normalize, interpret):
     b, tt, v = log_probs.shape
     s = ext.shape[1]
-    bb = _batch_block(b)
-    nb = b // bb
+    bb, bpad = _batch_block(b)
+    nb = bpad // bb
+    # time-major slab: each grid step's block is one (bb, V) frame tile —
+    # batch on sublanes, classes on lanes (a [B, T, V] layout would need
+    # a size-1 time block on the sublane axis, which Mosaic refuses)
+    logp_t = pad_axis(jnp.swapaxes(log_probs, 0, 1), 1, bpad)
+    ext, can_skip, ext_valid, ilen, llen = (
+        pad_axis(a, 0, bpad) for a in (ext, can_skip, ext_valid, ilen,
+                                        llen))
     kernel = functools.partial(_ctc_kernel, tt=tt, s=s, v=v,
                                normalize=normalize)
     # phase 0 walks t ascending, phase 1 descending — one index map
-    row = lambda i, p, t: (i, t * (1 - p) + (tt - 1 - t) * p, 0)  # noqa: E731
+    row = lambda i, p, t: (t * (1 - p) + (tt - 1 - t) * p, i, 0)  # noqa: E731
     per_b = lambda i, p, t: (i, 0)                                # noqa: E731
     loss, grad = pl.pallas_call(
         kernel,
         grid=(nb, 2, tt),
         in_specs=[
-            pl.BlockSpec((bb, 1, v), row),               # log-probs/logits
+            pl.BlockSpec((1, bb, v), row),               # log-probs/logits
             pl.BlockSpec((bb, s), per_b),                # extended labels
             pl.BlockSpec((bb, s), per_b),                # skip rule
             pl.BlockSpec((bb, s), per_b),                # position validity
@@ -200,11 +216,11 @@ def _ctc_call(log_probs, ext, can_skip, ext_valid, ilen, llen, *,
         ],
         out_specs=[
             pl.BlockSpec((bb, 1), per_b),                # loss
-            pl.BlockSpec((bb, 1, v), row),               # d loss / d input
+            pl.BlockSpec((1, bb, v), row),               # d loss / d input
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, tt, v), jnp.float32),
+            jax.ShapeDtypeStruct((bpad, 1), jnp.float32),
+            jax.ShapeDtypeStruct((tt, bpad, v), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((tt, bb, s), jnp.float32),   # alpha slab (resident)
@@ -217,8 +233,8 @@ def _ctc_call(log_probs, ext, can_skip, ext_valid, ilen, llen, *,
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
-    )(log_probs, ext, can_skip, ext_valid, ilen, llen)
-    return loss[:, 0], grad
+    )(logp_t, ext, can_skip, ext_valid, ilen, llen)
+    return loss[:b, 0], jnp.swapaxes(grad, 0, 1)[:b]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
@@ -257,13 +273,10 @@ def ctc_loss_fused(log_probs: jax.Array, input_lengths: jax.Array,
     into the kernel (the warp-ctc entry's form).  ``impl="auto"`` runs
     the Pallas forward-backward kernel on TPU and the scan references on
     other backends (bit-identical to the unfused path there)."""
-    if impl == "auto":
-        impl = "kernel" if jax.default_backend() == "tpu" else "reference"
-    if impl == "reference":
+    if resolve_impl(impl, "ctc_loss_fused") == "reference":
         return ctc_loss_fused_reference(log_probs, input_lengths, labels,
                                         label_lengths, blank, normalize)
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret)
     ext, ext_valid, can_skip = ctc_tables(labels, label_lengths, blank)
     return _ctc_fused(
         log_probs.astype(jnp.float32), ext,
@@ -291,20 +304,23 @@ def ctc_loss_fused_reference(log_probs, input_lengths, labels,
 
 def _decode_kernel(logp_ref, ilen_ref, ids_ref, keep_ref, prev_scr,
                    *, blank):
-    t = pl.program_id(0)
+    t = pl.program_id(1)
 
     @pl.when(t == 0)
     def _init():
         prev_scr[...] = jnp.full_like(prev_scr, -1)
 
-    z = logp_ref[:, 0, :]
-    best = jnp.argmax(z, axis=-1).astype(jnp.int32)[:, None]   # [B, 1]
+    z = logp_ref[0]                                            # [bb, V]
+    best = jnp.argmax(z, axis=-1).astype(jnp.int32)[:, None]   # [bb, 1]
     prev = prev_scr[...]
     valid = t < ilen_ref[...]
     keep = (best != blank) & (best != prev) & valid
-    ids_ref[...] = best
-    keep_ref[...] = keep.astype(jnp.int32)
+    ids_ref[0] = best
+    keep_ref[0] = keep.astype(jnp.int32)
     prev_scr[...] = best
+
+
+_DECODE_BATCH_BLOCK = 512
 
 
 def ctc_greedy_decode_fused(log_probs: jax.Array,
@@ -317,38 +333,39 @@ def ctc_greedy_decode_fused(log_probs: jax.Array,
     the kept frames are front-compacted.  Same contract as
     ``ops.ctc.ctc_greedy_decode``: (ids [B, T] padded with -1,
     lengths [B])."""
-    if impl == "auto":
-        impl = "kernel" if jax.default_backend() == "tpu" else "reference"
-    if impl == "reference":
+    if resolve_impl(impl, "ctc_greedy_decode_fused") == "reference":
         return ctc_greedy_decode_fused_reference(log_probs, input_lengths,
                                                  blank)
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret)
     b, tt, v = log_probs.shape
+    bb, bpad = _batch_block(b, _DECODE_BATCH_BLOCK)
     kernel = functools.partial(_decode_kernel, blank=blank)
-    step = lambda t: (0, t, 0)      # noqa: E731
-    out = lambda t: (0, t)          # noqa: E731
+    # time-major like the loss kernel: one (bb, V) frame tile per step;
+    # the per-frame outputs are (bb, 1) columns of a [T, B, 1] slab
+    step = lambda i, t: (t, i, 0)   # noqa: E731
     ids, keep = pl.pallas_call(
         kernel,
-        grid=(tt,),
+        grid=(bpad // bb, tt),
         in_specs=[
-            pl.BlockSpec((b, 1, v), step),
-            pl.BlockSpec((b, 1), lambda t: (0, 0)),
+            pl.BlockSpec((1, bb, v), step),
+            pl.BlockSpec((bb, 1), lambda i, t: (i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((b, 1), out),
-            pl.BlockSpec((b, 1), out),
+            pl.BlockSpec((1, bb, 1), step),
+            pl.BlockSpec((1, bb, 1), step),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, tt), jnp.int32),
-            jax.ShapeDtypeStruct((b, tt), jnp.int32),
+            jax.ShapeDtypeStruct((tt, bpad, 1), jnp.int32),
+            jax.ShapeDtypeStruct((tt, bpad, 1), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((b, 1), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((bb, 1), jnp.int32)],
         compiler_params=tpu_compiler_params(
-            dimension_semantics=("arbitrary",),
+            dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
-    )(log_probs, input_lengths.astype(jnp.int32)[:, None])
+    )(pad_axis(jnp.swapaxes(log_probs, 0, 1), 1, bpad),
+      pad_axis(input_lengths.astype(jnp.int32)[:, None], 0, bpad))
+    ids, keep = (jnp.swapaxes(a[:, :b, 0], 0, 1) for a in (ids, keep))
     return compact_decoded(ids, keep.astype(bool))
 
 

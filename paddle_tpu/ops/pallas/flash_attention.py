@@ -234,10 +234,9 @@ def _from_bh(x, b, h, t, d):
 
 
 def _fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
-    from paddle_tpu.ops.pallas import default_interpret
+    from paddle_tpu.ops.pallas import resolve_interpret
 
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret)
     b, t_q, h, d = q.shape
     t_k = k.shape[1]
     scale = scale if scale is not None else d ** -0.5
@@ -329,10 +328,9 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
 
 
 def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
-    from paddle_tpu.ops.pallas import default_interpret
+    from paddle_tpu.ops.pallas import resolve_interpret
 
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret)
     qp, kp, vp, o, lse, (b, t_q, t_k, h, d) = res
     scale = scale if scale is not None else d ** -0.5
     block_q = min(_round_up(block_q, 8), _round_up(t_q, 8))  # match fwd

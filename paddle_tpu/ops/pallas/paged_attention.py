@@ -282,17 +282,10 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, seq_lens,
     (kernel on TPU, reference elsewhere)."""
     d = q.shape[-1]
     scale = scale if scale is not None else d ** -0.5
-    if impl == "auto":
-        impl = "kernel" if jax.default_backend() == "tpu" else "reference"
-    if impl == "reference":
+    from paddle_tpu.ops.pallas import resolve_impl, resolve_interpret
+
+    if resolve_impl(impl, "ragged_paged_attention") == "reference":
         return ragged_paged_attention_reference(
             q, k_pages, v_pages, page_table, seq_lens, scale=scale)
-    if impl != "kernel":
-        raise ValueError(f"impl must be 'auto', 'kernel' or 'reference', "
-                         f"got {impl!r}")
-    from paddle_tpu.ops.pallas import default_interpret
-
-    if interpret is None:
-        interpret = default_interpret()
     return _kernel_impl(q, k_pages, v_pages, page_table, seq_lens, scale,
-                        interpret)
+                        resolve_interpret(interpret))

@@ -98,10 +98,9 @@ def softmax_xent(logits, targets, block_rows=256, block_v=2048,
 
 
 def _fwd(logits, targets, block_rows, block_v, interpret):
-    from paddle_tpu.ops.pallas import default_interpret
+    from paddle_tpu.ops.pallas import resolve_interpret
 
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret)
     lse, np_, vp = _lse(logits, block_rows, block_v, interpret)
     tgt = jnp.take_along_axis(logits, targets[:, None].astype(jnp.int32),
                               axis=-1)[:, 0].astype(jnp.float32)
@@ -109,10 +108,9 @@ def _fwd(logits, targets, block_rows, block_v, interpret):
 
 
 def _bwd(block_rows, block_v, interpret, res, g):
-    from paddle_tpu.ops.pallas import default_interpret
+    from paddle_tpu.ops.pallas import resolve_interpret
 
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = resolve_interpret(interpret)
     logits, lse, targets, ((n, v), np_, vp) = res
     # per-row side inputs are tiny; pallas zero-pads their edge blocks too.
     # padded rows produce garbage p but write into dl rows >= n, sliced off

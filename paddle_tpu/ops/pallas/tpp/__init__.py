@@ -37,9 +37,8 @@ path.
 
 from __future__ import annotations
 
-import jax
-
 from paddle_tpu.core import flags
+from paddle_tpu.ops.pallas import on_tpu
 
 
 def fused_enabled() -> bool:
@@ -51,7 +50,7 @@ def fused_enabled() -> bool:
         return True
     if v in ("off", "0", "false", "no"):
         return False
-    return jax.default_backend() == "tpu"
+    return on_tpu()
 
 
 from paddle_tpu.ops.pallas.tpp.brgemm import (  # noqa: E402

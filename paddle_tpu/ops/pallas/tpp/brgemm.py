@@ -32,27 +32,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.compat import tpu_compiler_params
-from paddle_tpu.ops.pallas import mxu_precision, round_up
-
-
-def resolve_impl(impl: str) -> str:
-    """The shared tpp dispatch rule: ``auto`` = kernel on TPU, reference
-    elsewhere (the paged_attention convention); validates the name."""
-    if impl == "auto":
-        return "kernel" if jax.default_backend() == "tpu" else "reference"
-    if impl not in ("kernel", "reference"):
-        raise ValueError(f"impl must be 'auto', 'kernel' or 'reference', "
-                         f"got {impl!r}")
-    return impl
-
-
-def resolve_interpret(interpret):
-    """None -> the package default (interpret off-TPU)."""
-    if interpret is None:
-        from paddle_tpu.ops.pallas import default_interpret
-
-        return default_interpret()
-    return interpret
+from paddle_tpu.ops.pallas import (mxu_precision, resolve_impl,
+                                   resolve_interpret, round_up)
 
 
 def _epilogue(y, scale, shift, act):
@@ -191,7 +172,7 @@ def brgemm(a, b, scale=None, shift=None, act=None, stats=False,
     if (scale is None) != (shift is None):
         raise ValueError("brgemm affine epilogue needs both scale and shift")
     out_dtype = out_dtype or a.dtype
-    if resolve_impl(impl) == "reference":
+    if resolve_impl(impl, "brgemm") == "reference":
         return brgemm_reference(a, b, scale=scale, shift=shift, act=act,
                                 stats=stats, out_dtype=out_dtype)
     return _kernel_impl(a, b, scale, shift, act, stats, out_dtype,

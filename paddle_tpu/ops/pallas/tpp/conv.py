@@ -42,12 +42,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.ops.pallas.tpp.brgemm import (
     _kernel_impl as _brgemm_kernel_impl,
-    resolve_impl as _auto,
-    resolve_interpret as _interpret,
 )
 from paddle_tpu.compat import tpu_compiler_params
 from paddle_tpu.core import dtype as dt
-from paddle_tpu.ops.pallas import mxu_precision, round_up
+from paddle_tpu.ops.pallas import (
+    mxu_precision,
+    resolve_impl as _auto,
+    resolve_interpret as _interpret,
+    round_up,
+)
 
 
 def _pair(v):
@@ -101,7 +104,7 @@ def _stats_kernel_impl(x, interpret, block_rows=512):
 def channel_stats(x, impl="auto", interpret=None):
     """Fused per-channel (sum, sum-of-squares) over all leading axes —
     ONE read of ``x`` for both batch-norm moments."""
-    if _auto(impl) == "reference":
+    if _auto(impl, "channel_stats") == "reference":
         return channel_stats_reference(x)
     return _stats_kernel_impl(x, _interpret(interpret))
 
@@ -302,7 +305,7 @@ def conv2d_direct(x, w, stride=1, padding=0, impl="auto", interpret=None):
     """Direct (im2col-free) 2-D convolution, NHWC / HWIO, groups=1,
     dilation=1.  Differentiable: backward transposes the XLA conv."""
     strides, pads = _pair(stride), _pair(padding)
-    if _auto(impl) == "reference":
+    if _auto(impl, "conv2d_direct") == "reference":
         return conv2d_direct_reference(x, w, stride=strides, padding=pads)
     return _direct(x, w, strides, pads, _interpret(interpret))
 
@@ -434,7 +437,7 @@ def conv2d_bn_act(x, w, scale, bias, running_mean, running_var, is_train,
         raise ValueError(f"conv2d_bn_act fuses act None or 'relu', "
                          f"got {act!r}")
     act = act or None
-    if _auto(impl) == "reference":
+    if _auto(impl, "conv2d_bn_act") == "reference":
         return conv2d_bn_act_reference(
             x, w, scale, bias, running_mean, running_var, is_train,
             momentum=momentum, eps=eps, stride=strides, padding=pads,

@@ -45,11 +45,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.compat import tpu_compiler_params
-from paddle_tpu.ops.pallas import round_up
-from paddle_tpu.ops.pallas.tpp.brgemm import (
-    resolve_impl,
-    resolve_interpret,
-)
+from paddle_tpu.ops.pallas import (pad_axis, resolve_impl,
+                                   resolve_interpret, round_up)
 
 _LANES = 128
 _SCATTER_ROW_BLOCK = 256
@@ -59,15 +56,6 @@ _UPDATE_ROW_BLOCK = 256
 
 def _scalar(x):
     return jnp.asarray(x, jnp.float32).reshape(1, 1)
-
-
-def _pad_axis(x, axis, to):
-    pad = to - x.shape[axis]
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths)
 
 
 # ---------------------------------------------------------------------------
@@ -112,17 +100,29 @@ def embedding_gather_reference(table, ids):
     return jnp.take(table, safe, axis=0)
 
 
-def _gather_kernel(ids_ref, tbl_ref, out_ref):
+_GATHER_ROWS = 8  # ids per grid step: that many row DMAs in flight
+
+
+def _gather_kernel(ids_ref, *refs):
     del ids_ref  # consumed by the index maps
-    out_ref[...] = tbl_ref[...]
+    *row_refs, out_ref = refs
+    for r, row_ref in enumerate(row_refs):
+        out_ref[r] = row_ref[0]
 
 
 def embedding_gather(table, ids, *, impl: str = "auto", interpret=None):
     """One row-DMA per id: ``out[i] = table[ids[i]]`` with the id list
     scalar-prefetched into SMEM so each grid step's table BlockSpec
-    index map reads ``ids[i]`` directly (no HBM-resident one-hot, no
-    dense gather).  Ids are clamped to ``[0, V)`` like ``jnp.take``."""
-    if resolve_impl(impl) == "reference":
+    index maps read ``ids[i]`` directly (no HBM-resident one-hot, no
+    dense gather).  Ids are clamped to ``[0, V)`` like ``jnp.take``.
+
+    The table is viewed as ``[V, 1, D]`` so a one-row block is the full
+    extent of the two tiled (minor) dims — Mosaic refuses a ``(1, D)``
+    block of a ``[V, D]`` array (a size-1 sublane block), for any D and
+    dtype.  Each grid step fetches ``_GATHER_ROWS`` rows: the table is
+    passed once per row slot, each with its own id-driven index map, so
+    the pipeline keeps that many row DMAs in flight per step."""
+    if resolve_impl(impl, "embedding_gather") == "reference":
         return embedding_gather_reference(table, ids)
     interpret = resolve_interpret(interpret)
     v, d = table.shape
@@ -131,27 +131,31 @@ def embedding_gather(table, ids, *, impl: str = "auto", interpret=None):
     n = 1
     for s in lead:
         n *= int(s)
-    dpad = round_up(d, _LANES)
-    tbl = _pad_axis(table, 1, dpad)
-    safe = jnp.clip(ids.reshape(n).astype(jnp.int32), 0, v - 1)
+    rows = _GATHER_ROWS
+    npad = round_up(n, rows)
+    safe = pad_axis(jnp.clip(ids.reshape(n).astype(jnp.int32), 0, v - 1),
+                     0, npad)
+    tbl = table.reshape(v, 1, d)
+
+    def row_spec(r):
+        return pl.BlockSpec((1, 1, d),
+                            lambda i, ids_s: (ids_s[i * rows + r], 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,  # the id list rides SMEM
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, dpad), lambda i, ids_s: (ids_s[i], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, dpad), lambda i, ids_s: (i, 0)),
+        grid=(npad // rows,),
+        in_specs=[row_spec(r) for r in range(rows)],
+        out_specs=pl.BlockSpec((rows, 1, d), lambda i, ids_s: (i, 0, 0)),
     )
     out = pl.pallas_call(
         _gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, dpad), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((npad, 1, d), table.dtype),
         compiler_params=tpu_compiler_params(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(safe, tbl)
-    return out[:, :d].reshape(*lead, d)
+    )(safe, *([tbl] * rows))
+    return out[:n, 0].reshape(*lead, d)
 
 
 # ---------------------------------------------------------------------------
@@ -196,7 +200,7 @@ def embedding_scatter_add(table, ids, rows, *, impl: str = "auto",
     accumulator across the id dimension, so every output row is written
     exactly once and duplicate ids sum exactly.  Negative ids (the dedup
     pad convention) contribute nothing."""
-    if resolve_impl(impl) == "reference":
+    if resolve_impl(impl, "embedding_scatter_add") == "reference":
         return embedding_scatter_add_reference(table, ids, rows)
     interpret = resolve_interpret(interpret)
     v, d = table.shape
@@ -207,9 +211,9 @@ def embedding_scatter_add(table, ids, rows, *, impl: str = "auto",
     nk = min(_SCATTER_ID_BLOCK, round_up(n, _LANES))
     npad = round_up(n, nk)
 
-    tbl = _pad_axis(_pad_axis(table, 0, vpad), 1, dpad)
-    rws = _pad_axis(_pad_axis(rows, 0, npad), 1, dpad)
-    idv = _pad_axis(jnp.asarray(ids).astype(jnp.int32)[None, :], 1,
+    tbl = pad_axis(pad_axis(table, 0, vpad), 1, dpad)
+    rws = pad_axis(pad_axis(rows, 0, npad), 1, dpad)
+    idv = pad_axis(jnp.asarray(ids).astype(jnp.int32)[None, :], 1,
                     npad)  # pad ids are 0-filled ...
     idv = jnp.where(jax.lax.broadcasted_iota(jnp.int32, idv.shape, 1) < n,
                     idv, -1)  # ... force the tail to the no-op id
@@ -296,7 +300,7 @@ def sparse_row_update(p, g, v=None, *, lr=0.01, mu=0.0, nesterov=False,
     rows are written back unchanged — the out-block VMEM buffer is
     uninitialised, so the passthrough write is mandatory, and it is what
     keeps untouched rows bit-identical."""
-    if resolve_impl(impl) == "reference":
+    if resolve_impl(impl, "sparse_row_update") == "reference":
         return sparse_row_update_reference(
             p, g, v, lr=lr, mu=mu, nesterov=nesterov,
             weight_decay=weight_decay)
@@ -306,8 +310,8 @@ def sparse_row_update(p, g, v=None, *, lr=0.01, mu=0.0, nesterov=False,
     bm = min(_UPDATE_ROW_BLOCK, round_up(rows, 8))
     rpad = round_up(rows, bm)
 
-    pp = _pad_axis(_pad_axis(p, 0, rpad), 1, dpad)
-    gp = _pad_axis(_pad_axis(g, 0, rpad), 1, dpad)
+    pp = pad_axis(pad_axis(p, 0, rpad), 1, dpad)
+    gp = pad_axis(pad_axis(g, 0, rpad), 1, dpad)
     blk = pl.BlockSpec((bm, dpad), lambda i: (i, 0))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     params = tpu_compiler_params(dimension_semantics=("parallel",))
@@ -326,7 +330,7 @@ def sparse_row_update(p, g, v=None, *, lr=0.01, mu=0.0, nesterov=False,
         )(_scalar(lr), pp, gp)
         return po[:rows, :d], None
 
-    vp = _pad_axis(_pad_axis(v, 0, rpad), 1, dpad)
+    vp = pad_axis(pad_axis(v, 0, rpad), 1, dpad)
     po, vo = pl.pallas_call(
         functools.partial(_sparse_mom_kernel, nesterov=bool(nesterov),
                           weight_decay=float(weight_decay)),

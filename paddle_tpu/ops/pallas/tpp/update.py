@@ -29,11 +29,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.compat import tpu_compiler_params
-from paddle_tpu.ops.pallas import round_up
-from paddle_tpu.ops.pallas.tpp.brgemm import (
-    resolve_impl,
-    resolve_interpret,
-)
+from paddle_tpu.ops.pallas import resolve_impl, resolve_interpret, round_up
 
 _LANES = 128
 
@@ -113,7 +109,7 @@ def fused_momentum_update(p, g, v, lr, mu, nesterov=False, weight_decay=0.0,
                           impl="auto", interpret=None):
     """One-pass momentum update; returns (p', v') with p/v donated in
     place on the kernel path."""
-    if resolve_impl(impl) == "reference":
+    if resolve_impl(impl, "fused_momentum_update") == "reference":
         return fused_momentum_update_reference(
             p, g, v, lr, mu, nesterov=nesterov, weight_decay=weight_decay)
     interpret = resolve_interpret(interpret)
@@ -145,7 +141,7 @@ def fused_sgd_update(p, g, lr, weight_decay=0.0, impl="auto",
                      interpret=None):
     """One-pass plain-SGD update; returns p' with p donated in place on
     the kernel path."""
-    if resolve_impl(impl) == "reference":
+    if resolve_impl(impl, "fused_sgd_update") == "reference":
         return fused_sgd_update_reference(p, g, lr,
                                           weight_decay=weight_decay)
     interpret = resolve_interpret(interpret)

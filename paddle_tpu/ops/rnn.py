@@ -164,11 +164,10 @@ def fused_input_on() -> bool:
     (external x @ W_x matmul + the pre-projected kernels), so the bench
     ablation's flag-off/flag-on trajectories stay bit-identical there —
     the same convention as ops/nn's TPP conv routing."""
-    import jax as _jax
-
+    from paddle_tpu.ops.pallas import on_tpu
     from paddle_tpu.ops.pallas.tpp import fused_enabled
 
-    return fused_enabled() and _jax.default_backend() == "tpu"
+    return fused_enabled() and on_tpu()
 
 
 def lstm_fused(xw: SequenceBatch, w_h: jax.Array,
@@ -186,7 +185,7 @@ def lstm_fused(xw: SequenceBatch, w_h: jax.Array,
     Returns (SequenceBatch of h, last LSTMState).
     """
     from paddle_tpu.core import dtype as dt
-    from paddle_tpu.ops.pallas import default_interpret
+    from paddle_tpu.ops.pallas import default_interpret, note_route
     from paddle_tpu.ops.pallas.lstm import lstm_seq
 
     d = w_h.shape[0]
@@ -195,7 +194,9 @@ def lstm_fused(xw: SequenceBatch, w_h: jax.Array,
     # (or a mixed policy pair) resolves both kernel operands to bf16,
     # the pure-f32 compat surface keeps true-f32 kernel matmuls
     data, w_h_c = dt.cast_for_matmul(xw.data, w_h)
-    if not _fused_fits(xw.batch_size, d, 4, w_h_c):
+    fits = _fused_fits(xw.batch_size, d, 4, w_h_c)
+    note_route("lstm_seq", "kernel" if fits else "scan")
+    if not fits:
         def step(state, xt):
             return lstm_cell(xt, state, w_h, peephole=peephole)
         last, ys = _masked_scan(
@@ -225,9 +226,10 @@ def lstm_fi(x: SequenceBatch, w_x: jax.Array, b: jax.Array | None,
     :func:`fused_input_on` + :func:`_fused_fits`; dtype policy matches
     :func:`lstm_fused`.  Returns (SequenceBatch of h, last LSTMState)."""
     from paddle_tpu.core import dtype as dt
-    from paddle_tpu.ops.pallas import default_interpret
+    from paddle_tpu.ops.pallas import default_interpret, note_route
     from paddle_tpu.ops.pallas.lstm import lstm_seq_fi
 
+    note_route("lstm_seq_fi", "kernel")
     d = w_h.shape[0]
     mask = x.mask().astype(jnp.float32)
     data, w_x_c, w_h_c = dt.cast_for_matmul(x.data, w_x, w_h)
@@ -254,7 +256,7 @@ def bilstm_fused(x: SequenceBatch, fw: tuple, bw: tuple):
     concatenated SequenceBatch [B, T, 2D] (forward features first)."""
     from paddle_tpu.core import dtype as dt
     from paddle_tpu.ops.math import matmul
-    from paddle_tpu.ops.pallas import default_interpret
+    from paddle_tpu.ops.pallas import default_interpret, note_route
     from paddle_tpu.ops.pallas.lstm import bilstm_seq
 
     w_x_f, b_f, w_h_f, peep_f = fw
@@ -266,6 +268,7 @@ def bilstm_fused(x: SequenceBatch, fw: tuple, bw: tuple):
     use_kernel = (fused_input_on()
                   and _fused_fits(b_, d, 4, *dt.cast_for_matmul(
                       x.data, w_x_f, w_h_f, w_x_b, w_h_b)[1:]))
+    note_route("bilstm_seq", "kernel" if use_kernel else "composed")
     if not use_kernel:
         def one(w_x, bias, w_h, peephole, reverse):
             xw = matmul(x.data.reshape(b_ * t, -1), w_x)
@@ -316,13 +319,15 @@ def gru_fused(xw: SequenceBatch, w_h: jax.Array, w_hc: jax.Array,
     Returns (SequenceBatch, last h).
     """
     from paddle_tpu.core import dtype as dt
-    from paddle_tpu.ops.pallas import default_interpret
+    from paddle_tpu.ops.pallas import default_interpret, note_route
     from paddle_tpu.ops.pallas.gru import gru_seq
 
     mask = xw.mask().astype(jnp.float32)
     # same dtype-policy rule as matmul() (see lstm_fused)
     data, w_h_c, w_hc_c = dt.cast_for_matmul(xw.data, w_h, w_hc)
-    if not _fused_fits(xw.batch_size, w_hc.shape[0], 3, w_h_c, w_hc_c):
+    fits = _fused_fits(xw.batch_size, w_hc.shape[0], 3, w_h_c, w_hc_c)
+    note_route("gru_seq", "kernel" if fits else "scan")
+    if not fits:
         def step(h, xt):
             return gru_cell(xt, h, w_h, w_hc)
         last, ys = _masked_scan(
@@ -346,9 +351,10 @@ def gru_fi(x: SequenceBatch, w_x: jax.Array, b: jax.Array | None,
     :func:`fused_input_on` + :func:`_fused_fits`.  Returns
     (SequenceBatch of h, last h)."""
     from paddle_tpu.core import dtype as dt
-    from paddle_tpu.ops.pallas import default_interpret
+    from paddle_tpu.ops.pallas import default_interpret, note_route
     from paddle_tpu.ops.pallas.gru import gru_seq_fi
 
+    note_route("gru_seq_fi", "kernel")
     d = w_hc.shape[0]
     mask = x.mask().astype(jnp.float32)
     data, w_x_c, w_h_c, w_hc_c = dt.cast_for_matmul(x.data, w_x, w_h, w_hc)
@@ -372,7 +378,7 @@ def bigru_fused(x: SequenceBatch, fw: tuple, bw: tuple):
     SequenceBatch [B, T, 2D] (forward features first)."""
     from paddle_tpu.core import dtype as dt
     from paddle_tpu.ops.math import matmul
-    from paddle_tpu.ops.pallas import default_interpret
+    from paddle_tpu.ops.pallas import default_interpret, note_route
     from paddle_tpu.ops.pallas.gru import bigru_seq
 
     w_x_f, b_f, w_h_f, w_hc_f = fw
@@ -384,6 +390,7 @@ def bigru_fused(x: SequenceBatch, fw: tuple, bw: tuple):
                   and _fused_fits(b_, d, 3, *dt.cast_for_matmul(
                       x.data, w_x_f, w_h_f, w_hc_f,
                       w_x_b, w_h_b, w_hc_b)[1:]))
+    note_route("bigru_seq", "kernel" if use_kernel else "composed")
     if not use_kernel:
         def one(w_x, bias, w_h, w_hc, reverse):
             xw = matmul(x.data.reshape(b_ * t, -1), w_x)

@@ -154,4 +154,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from paddle_tpu.core import compile_cache
+
+    compile_cache.configure()  # process entry: before the first compile
     sys.exit(main())

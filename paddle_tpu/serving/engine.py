@@ -105,10 +105,13 @@ def drain_results(completed: "queue.Queue", loop_error_now, what: str,
 
 class ServingEngine:
     def __init__(self, cfg, params, serving: ServingConfig | None = None,
-                 registry=None):
+                 registry=None, device=None):
         """``cfg``: TransformerConfig; ``params``: the matching pytree
         (e.g. from ``serving.export.load_servable``); ``serving``:
-        engine knobs."""
+        engine knobs; ``device``: the ``jax.Device`` this engine lives on
+        — weights, KV page pools and every batch are committed there, so
+        its jitted functions run there (a fleet gives each replica its
+        own).  None keeps jax's default placement."""
         import jax
 
         from paddle_tpu import metrics as metrics_mod
@@ -144,19 +147,26 @@ class ServingEngine:
                 serving_memory_report(cfg, s, params), hbm_gb=hbm_gb)
             enforce(not found,
                     found[0].message if found else "")
-        self.params = params
+        self.device = device
+        self.params = self.place(params)
         self.registry = registry or metrics_mod.get_registry()
-        self.cache = PagedKVCache(
-            cfg.num_layers, cfg.num_heads, cfg.head_dim, s.num_pages,
-            s.page_size, s.max_slots, s.max_pages_per_seq, dtype=cfg.dtype,
-            prefix_cache=s.prefix_cache)
+        # allocate the pools ON the engine's device (not on the default
+        # device and then moved: a fleet's pools would all pass through
+        # device 0), then commit them
+        with jax.default_device(device):  # None = jax's default
+            self.cache = PagedKVCache(
+                cfg.num_layers, cfg.num_heads, cfg.head_dim, s.num_pages,
+                s.page_size, s.max_slots, s.max_pages_per_seq,
+                dtype=cfg.dtype, prefix_cache=s.prefix_cache)
+        self.cache.k, self.cache.v = self.place((self.cache.k,
+                                                 self.cache.v))
         self.scheduler = Scheduler(s, self.cache)
         # 2·params is the standard per-token forward-FLOPs estimate —
         # what a prefix-cache hit's skipped recompute is booked at
         self._param_count = sum(
             int(x.size) for x in jax.tree.leaves(params))
         self._chunk_passes = 0  # incremental prefill passes this engine ran
-        self._base_key = jax.random.key(s.seed)
+        self._base_key = self.place(jax.random.key(s.seed))
         self._lock = threading.Lock()
         self._incoming: collections.deque[Request] = collections.deque()
         self._completed: queue.Queue[RequestResult] = queue.Queue()
@@ -167,11 +177,28 @@ class ServingEngine:
         self._stopped = False  # a stop()ed loop marks the engine dead
         self._build_fns()
 
+    def place(self, tree):
+        """Commit a pytree to this engine's device (identity when the
+        engine has none).  Weight swaps go through here too, so a
+        replica's params never drift to another replica's device."""
+        if self.device is None:
+            return tree
+        import jax
+
+        return jax.device_put(tree, self.device)
+
+    def _dev(self, batch: dict, *names):
+        """Host batch fields -> arrays on this engine's device."""
+        import jax
+
+        return [jax.device_put(np.asarray(batch[n]), self.device)
+                for n in names]
+
     # -- jitted compute -------------------------------------------------------
     def _build_fns(self) -> None:
         import dataclasses
 
-        import jax
+        from paddle_tpu.ops.pallas import on_tpu
 
         cfg, attn_impl = self.cfg, self.serving.attn_impl
         # prefill runs cfg.attn_impl — but a TRAINING config may name a
@@ -180,12 +207,14 @@ class ServingEngine:
         # mode is a Python loop); degrade those to exact attention,
         # which is numerically equivalent at serving shapes
         if cfg.attn_impl in ("ring", "ulysses") or (
-                cfg.attn_impl == "flash"
-                and jax.default_backend() != "tpu"):
+                cfg.attn_impl == "flash" and not on_tpu()):
             cfg = dataclasses.replace(cfg, attn_impl="exact")
+        # what prefill really runs, for callers that must not be fooled
+        # by the degrade above (chip_smoke.py asserts "flash" on the chip)
+        self.prefill_attn_impl = cfg.attn_impl
         # donating the cache lets XLA update pages in place; CPU has no
         # donation and would warn every call
-        donate = (2, 3) if jax.default_backend() == "tpu" else ()
+        donate = (2, 3) if on_tpu() else ()
         (self._prefill, self._prefill_chunk,
          self._decode) = _serving_fns(cfg, attn_impl, donate)
 
@@ -358,8 +387,8 @@ class ServingEngine:
             batch = sched.prefill_batch(admitted)
             toks, self.cache.k, self.cache.v = self._prefill(
                 self.params, self._base_key, self.cache.k, self.cache.v,
-                *_dev(batch, "ids", "seq_lens", "page_table", "rids",
-                      "temps"))
+                *self._dev(batch, "ids", "seq_lens", "page_table", "rids",
+                           "temps"))
             toks = np.asarray(toks)
             tracer.end(tk)
             t1 = time.perf_counter()
@@ -393,8 +422,8 @@ class ServingEngine:
                               batch=len(live))
             toks, self.cache.k, self.cache.v = self._decode(
                 self.params, self._base_key, self.cache.k, self.cache.v,
-                *_dev(batch, "ids", "positions", "seq_lens", "page_table",
-                      "rids", "gens", "temps"))
+                *self._dev(batch, "ids", "positions", "seq_lens",
+                           "page_table", "rids", "gens", "temps"))
             toks = np.asarray(toks)
             tracer.end(tk)
             reg.histogram(
@@ -456,8 +485,8 @@ class ServingEngine:
                           batch=len(rows), chunked=True)
         toks, self.cache.k, self.cache.v = self._prefill_chunk(
             self.params, self._base_key, self.cache.k, self.cache.v,
-            *_dev(batch, "ids", "starts", "seq_lens", "page_table",
-                  "rids", "temps"))
+            *self._dev(batch, "ids", "starts", "seq_lens", "page_table",
+                       "rids", "temps"))
         toks = np.asarray(toks)
         tracer.end(tk)
         t1 = time.perf_counter()
@@ -599,12 +628,6 @@ class ServingEngine:
             with self._lock:
                 rec["prefill_chunks"] = self._chunk_passes
         self.registry.emit(rec, kind="serve_summary")
-
-
-def _dev(batch: dict, *names):
-    import jax.numpy as jnp
-
-    return [jnp.asarray(batch[n]) for n in names]
 
 
 # (cfg, attn_impl, donate) -> (prefill, prefill_chunk, decode).  The

@@ -74,7 +74,8 @@ class LocalReplica:
         self.cfg = cfg
         self.serving = serving
         self.engine = ServingEngine(cfg, params, serving,
-                                    registry=registry)
+                                    registry=registry,
+                                    device=replica_device(index))
         self._clock = clock
         self._dead: str | None = None
         self._hung = False
@@ -160,7 +161,7 @@ class LocalReplica:
                 "the running engine's — a weight swap cannot change "
                 "the model shape")
         old = self.engine.params
-        self.engine.params = params
+        self.engine.params = self.engine.place(params)
         return old
 
     def smoke_decode(self, prompt: list[int], n: int) -> list[int]:
@@ -181,6 +182,17 @@ class LocalReplica:
             raise RuntimeError(
                 f"replica {self.index}: smoke decode produced no result")
         return list(out.tokens)
+
+
+def replica_device(index: int):
+    """Replica ``index``'s device: local devices round-robin.  On a
+    four-chip host four replicas are four one-chip servers; on one
+    device every replica shares it, as before.  Placement never changes
+    tokens (same weights, same seed, fleet-global request ids)."""
+    import jax
+
+    devices = jax.local_devices()
+    return devices[index % len(devices)]
 
 
 def smoke_check(cfg, params, prompt: list[int],

@@ -11,7 +11,7 @@ deterministic: given the same probe stream, the same replicas die at
 the same rounds, which is what lets ``tests/test_fleet.py`` assert
 token-identical recovery.
 
-Three ways a replica dies (the ``HeartbeatWatchdog`` taxonomy at fleet
+Three ways a replica dies (the ``HeartbeatWatchdog`` classification at fleet
 granularity):
 
 - **crash** — the probe reports ``alive=False`` (engine loop died, or a

@@ -47,27 +47,20 @@ class StepTelemetry:
         self._peak_flops: float | None = None
         self.global_step = 0
         # schema/5: stamp which kernel path produced this run's records
-        # (resolved once — routing is a build-time decision per step fn)
-        try:
-            from paddle_tpu.ops.pallas import tpp
+        # (resolved once — routing is a build-time decision per step fn).
+        # A failure here is an error, never a silent False: the stamp is
+        # how a chip run shows it did not fall back to the references.
+        from paddle_tpu.ops.pallas import tpp
 
-            self.fused_kernels = bool(tpp.fused_enabled())
-        except Exception as e:
-            log.debug("fused-kernel routing unknown (%s); stamping "
-                      "fused_kernels=False", e)
-            self.fused_kernels = False
+        self.fused_kernels = bool(tpp.fused_enabled())
 
     # -- hardware / program constants -----------------------------------------
     def peak_flops(self) -> float:
         if self._peak_flops is None:
-            try:
-                from paddle_tpu import profiler
+            from paddle_tpu import profiler
 
-                self._peak_flops = profiler.device_peak_flops()
-            except Exception as e:
-                log.debug("device peak FLOPs unavailable (%s); MFU will "
-                          "read 0", e)
-                self._peak_flops = 0.0
+            # raises for a TPU kind without a published peak on file
+            self._peak_flops = profiler.device_peak_flops()
         return self._peak_flops
 
     def cost_for(self, sig, lower_fn) -> tuple[float, float, dict]:
@@ -80,9 +73,9 @@ class StepTelemetry:
         The lowering runs under ``capture_comm``, so the collective
         wrappers traced in THIS program report its per-execution payload
         (and the global comm counters are left to the program's own jit
-        trace).  Cost analysis is read from the ``Lowered`` when the
-        installed jax supports it (unoptimized HLO analysis — no second
-        compilation); only as a fallback is ``.compile()`` forced."""
+        trace).  Cost analysis is read from the ``Lowered`` (unoptimized
+        HLO analysis — no second compilation); only when that comes back
+        empty is ``.compile()`` forced."""
         if sig in self._cost_cache:
             return self._cost_cache[sig]
         from paddle_tpu.telemetry import registry as reg_mod
@@ -91,16 +84,9 @@ class StepTelemetry:
         try:
             with reg_mod.capture_comm() as comm:
                 lowered = lower_fn()
-            cost = None
-            try:
-                cost = lowered.cost_analysis()
-            except Exception as e:  # capability probe: older jax only
-                log.debug("Lowered.cost_analysis unsupported (%s); "
-                          "forcing compile()", e)
+            cost = lowered.cost_analysis()
             if not cost:
                 cost = lowered.compile().cost_analysis()
-            if isinstance(cost, list):  # older jax returns [dict]
-                cost = cost[0]
             if cost:
                 flops = float(cost.get("flops", 0.0) or 0.0)
                 nbytes = float(cost.get("bytes accessed", 0.0) or 0.0)

@@ -121,7 +121,15 @@ def build_train_step(topology: Topology, optimizer,
     compiled program contains literal reduce-scatter ops on every
     backend.  On meshes with live TP/MoE axes the GSPMD lowering
     (sharding constraints, Xu et al.) is used instead — same math,
-    partitioner-chosen collectives.  Dropout note: the explicit lowering
+    partitioner-chosen collectives.
+
+    On a TPU the per-shard forward/backward is used on a pure-data mesh
+    at EVERY zero stage (zero 0/1 then all-reduce the gradients inside
+    the region): the forward holds Mosaic kernels, and GSPMD refuses to
+    partition those ("Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map").  Off-TPU the
+    kernels run interpreted (plain jax ops), so zero 0/1 keep the GSPMD
+    lowering there.  Dropout note: the explicit lowering
     folds the data-axis index into the step key (independent per-replica
     draws, like the reference's per-thread streams), so a stochastic
     model's trajectory differs from the replicated run's by the draw —
@@ -154,23 +162,31 @@ def build_train_step(topology: Topology, optimizer,
 
     dp = mesh.mesh.shape.get("data", 1) if mesh is not None else 1
     zero_on = zero >= 1 and mesh is not None and dp > 1
-    # ``lowering`` pins the ZeRO>=2 gradient-flow lowering: "auto" (the
-    # production rule — explicit shard_map on pure-data meshes, GSPMD
-    # constraints when TP/MoE axes are live), "explicit", or "gspmd".
-    # The preflight collective-sequence check (paddle_tpu/analysis)
-    # builds BOTH and compares them — the multi-host deadlock class is
-    # exactly a fleet whose hosts resolve "auto" differently.
+    # ``lowering`` pins how the data-parallel step is lowered: "auto"
+    # (the production rule below), "explicit" (per-shard forward/backward
+    # inside shard_map; with zero>=2 also literal reduce-scatter +
+    # all-gather), or "gspmd" (sharding constraints, partitioner-chosen
+    # collectives).  The preflight collective-sequence check
+    # (paddle_tpu/analysis) builds BOTH and compares them — the
+    # multi-host deadlock class is exactly a fleet whose hosts resolve
+    # "auto" differently.
     if lowering not in ("auto", "explicit", "gspmd"):
         raise ValueError(f"lowering must be auto|explicit|gspmd, "
                          f"got {lowering!r}")
-    explicit = (zero_on and zero >= 2
-                and zero_mod.explicit_lowering_ok(mesh.mesh)
-                if lowering == "auto"
-                else (zero_on and zero >= 2 and lowering == "explicit"))
-    if lowering == "explicit" and zero_on and zero >= 2 \
-            and not zero_mod.explicit_lowering_ok(mesh.mesh):
-        raise ValueError("explicit ZeRO lowering requested but the mesh "
+    pure_data = (mesh is not None
+                 and zero_mod.explicit_lowering_ok(mesh.mesh))
+    if lowering == "explicit" and dp > 1 and not pure_data:
+        raise ValueError("explicit lowering requested but the mesh "
                          "has live non-data axes")
+    from paddle_tpu.ops.pallas import on_tpu
+
+    # auto: ZeRO-2 always takes the explicit flow on a pure-data mesh; on
+    # a TPU so does every other stage, because GSPMD cannot partition the
+    # Mosaic kernels in the forward (see the docstring)
+    explicit_fwd = pure_data and (
+        lowering == "explicit"
+        or (lowering == "auto" and (zero >= 2 or on_tpu())))
+    explicit = explicit_fwd and zero >= 2  # + the explicit ZeRO-2 flow
     # TPP fused shard update (ops/pallas/tpp/update): under the explicit
     # ZeRO-2 lowering with the fused_kernels flag on, the SGD/momentum
     # update runs as one read-modify-write pass inside a shard_map region
@@ -249,7 +265,9 @@ def build_train_step(topology: Topology, optimizer,
             param_specs={n: base_specs[n] for n in train_p})
             if zero_on else None)
 
-        if explicit:
+        if explicit_fwd:
+            from paddle_tpu.parallel import collective
+
             def local_step(tp, static_c, states, feed_c, key):
                 # independent per-replica RNG stream (the reference's
                 # per-thread dropout draws, MultiGradientMachine)
@@ -271,7 +289,11 @@ def build_train_step(topology: Topology, optimizer,
                 new_states = jax.tree.map(lambda x: lax.pmean(x, "data"),
                                           new_states)
                 grads = jax.tree.map(lambda g: g / dp, grads)
-                grads = zero_mod.sync_grads(grads, gspecs)
+                if explicit:
+                    grads = zero_mod.sync_grads(grads, gspecs)
+                else:  # zero 0/1: every rank keeps the whole gradient
+                    grads = jax.tree.map(
+                        lambda g: collective.all_reduce(g, "data"), grads)
                 return cost, new_states, parts, fetch, grads
 
             # output STRUCTURE (metric keys, fetch leaves, state shapes)
@@ -283,7 +305,8 @@ def build_train_step(topology: Topology, optimizer,
                 jax.tree.map(lambda _: P(), out_sh[1]),     # new_states
                 jax.tree.map(lambda _: P(), out_sh[2]),     # metric parts
                 jax.tree.map(_batch_spec, out_sh[3]),       # fetch values
-                gspecs,                                     # synced grads
+                (gspecs if explicit                         # synced grads
+                 else jax.tree.map(lambda _: P(), out_sh[4])),
             )
             region = compat.shard_map(
                 local_step, mesh=mesh.mesh,
