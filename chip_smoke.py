@@ -327,7 +327,7 @@ def phase_train(sz: Sizes, cache: CacheCounter) -> dict:
     if pallas.on_tpu():
         check(stamp == {True}, "train: step records do not stamp "
                                "fused_kernels=True on a TPU")
-        check(census.get("_conv_kernel", 0) + census.get("_kernel", 0) > 0,
+        check(census.get("tpp_conv", 0) + census.get("tpp_brgemm", 0) > 0,
               "train: no TPP conv+BN+ReLU kernel (tpu_custom_call) in the "
               "step the trainer compiled")
         check(not any(k.endswith(":reference") for k in routes),

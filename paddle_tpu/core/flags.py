@@ -293,10 +293,13 @@ define("status_port", 0, "serve /metrics /healthz /snapshot /trace on "
                          "distributed.launch --status_port_base stamps "
                          "base+rank per process)")
 define("trace_spans", False, "record phase spans (trainer step "
-                             "feed/compute/fence, prefetch producer, "
-                             "serving request lifecycle, fleet "
-                             "router, elastic rebuilds) into the "
-                             "trace ring served at /trace")
+                             "feed/compute/fence, the feed pipeline's "
+                             "read/convert/place/stage, the serving "
+                             "engine step and request lifecycle, "
+                             "fleet router, elastic rebuilds) into "
+                             "the trace ring served at /trace, and "
+                             "mirror them into any jax.profiler trace "
+                             "taken meanwhile")
 define("trace_ring_size", 8192, "completed spans kept in the trace "
                                 "ring (oldest dropped first)")
 define("trace_dir", "", "dump this host's span ring as a Chrome trace "
@@ -305,10 +308,11 @@ define("trace_dir", "", "dump this host's span ring as a Chrome trace "
                         "with tools/trace_merge.py; empty = no dump)")
 define("profile_steps", "", "capture a jax.profiler device trace over "
                             "dispatch steps A:B of the train loop "
-                            "(half-open, e.g. '2:4'), bracketed by "
-                            "step annotations so host spans line up "
-                            "with the device timeline; emits one "
-                            "'profile' telemetry record")
+                            "(half-open, e.g. '2:4'); arms span "
+                            "tracing, whose spans the capture holds "
+                            "as host events beside the device "
+                            "timeline; emits one 'profile' telemetry "
+                            "record")
 define("profile_dir", "", "output directory for the --profile_steps "
                           "capture (empty = <tmpdir>/paddle_tpu_"
                           "profile_host<k>)")

@@ -3,9 +3,10 @@
 
 The reference wraps hot scopes in RAII timers compiled out unless WITH_TIMER;
 here the equivalent is a context-manager/decorator pair gated by the
-``with_timer`` flag, plus hooks into ``jax.profiler`` trace annotations so the
-same scopes show up in TPU profiles.  ``print_all_status`` mirrors the per-pass
-dump (``globalStat.printAllStatus()``)."""
+``with_timer`` flag.  ``print_all_status`` mirrors the per-pass dump
+(``globalStat.printAllStatus()``).  The scopes' place in a TPU profile is the
+span tracer's (``telemetry/tracing.py`` mirrors its spans into the profiler's
+trace); these timers keep the reference's aggregates only."""
 
 from __future__ import annotations
 
@@ -13,8 +14,6 @@ import contextlib
 import dataclasses
 import functools
 import time
-
-import jax
 
 from paddle_tpu.core import flags
 from paddle_tpu.core import logger
@@ -73,12 +72,11 @@ def timer(name: str, stat_set: StatSet = global_stat):
     if not flags.get("with_timer"):
         yield
         return
-    with jax.profiler.TraceAnnotation(name):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            stat_set.add(name, time.perf_counter() - t0)
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        stat_set.add(name, time.perf_counter() - t0)
 
 
 def timed(name: str | None = None):

@@ -254,6 +254,7 @@ def _fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
         o, lse = pl.pallas_call(
             functools.partial(_fwd_single_kernel, scale=scale, t_k=t_k,
                               causal=causal),
+            name="flash_attention_fwd",
             grid=(bh,),
             in_specs=[bspec(block_q), bspec(block_k), bspec(block_k)],
             out_specs=[bspec(block_q),
@@ -275,6 +276,7 @@ def _fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
     )
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, dpad), lambda b, i, j: (b, i, 0)),

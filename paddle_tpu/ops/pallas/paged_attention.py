@@ -260,6 +260,7 @@ def _kernel_impl(q, k_pages, v_pages, page_table, seq_lens, scale,
     )
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, page_size=page_size),
+        name="paged_attention_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, _Q_SUBLANES, d), q.dtype),
         compiler_params=tpu_compiler_params(

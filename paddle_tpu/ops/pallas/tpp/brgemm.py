@@ -136,6 +136,7 @@ def _kernel_impl(a, b, scale, shift, act, stats, out_dtype,
     outs = pl.pallas_call(
         functools.partial(_kernel, g_total=g_total, act=act, affine=affine,
                           stats=stats, out_dtype=out_dtype),
+        name="tpp_brgemm",
         # ni outermost so the resident stats block sees every (mi, g) of
         # its column before moving on; g innermost keeps the accumulator
         # tile live across the reduction

@@ -244,6 +244,7 @@ def _direct_fwd_raw(x, w, strides, pads, scale, shift, act, stats,
         functools.partial(_conv_kernel, kh_total=kh, kw=kw, sw=sw, ow=ow,
                           act=act, affine=affine, stats=stats,
                           out_dtype=out_dtype),
+        name="tpp_conv",
         grid=(n, oh, kh),
         in_specs=in_specs,
         out_specs=out_specs,

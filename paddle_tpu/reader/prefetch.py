@@ -16,7 +16,10 @@ overlapped and the synchronous path:
 
 - ``DevicePrefetcher`` — reader + feeder + shard on a worker thread;
   ``input_wait_ms`` is the time the consumer spent blocked on the queue
-  (0 when the pipeline keeps up).
+  (0 when the pipeline keeps up).  With span tracing on, the worker's
+  lane shows where a batch's production time goes: ``prefetch`` ⊃
+  ``feed_read`` / ``feed_convert`` / ``feed_place`` / ``feed_stage``
+  (``telemetry/tracing.py``).
 - ``SynchronousFeeds`` — the seed behavior (everything inline on the
   consumer thread); ``input_wait_ms`` is the full conversion+placement
   time, all of it on the critical path.
@@ -109,28 +112,73 @@ def skip_feed_batches(reader, skip: int, replicas: int = 1,
     return skipped_reader
 
 
-def _convert(batch, feeder, mesh, remainder: str):
+def _examples(batch) -> int:
+    return len(batch) if hasattr(batch, "__len__") else 0
+
+
+def _feed_bytes(feed) -> int:
+    import jax
+
+    return sum(int(getattr(x, "nbytes", 0)) for x in jax.tree.leaves(feed))
+
+
+def read_batch(it):
+    """``next(it)`` under a ``feed_read`` span (``examples``): the pull
+    from the reader iterator, which a ``for`` would hide.
+    ``StopIteration`` passes through and records nothing."""
+    from paddle_tpu.telemetry.tracing import get_tracer
+
+    tracer = get_tracer()
+    tk = tracer.begin("feed_read", cat="reader")
+    try:
+        batch = next(it)
+    except BaseException:
+        tracer.cancel(tk)
+        raise
+    if tk is not None:
+        tracer.end(tk, examples=_examples(batch))
+    return batch
+
+
+def convert_batch(batch, feeder, mesh, remainder: str):
     """batch -> (examples, sharded feed, mesh used, padded_timesteps,
     total_timesteps) | None (batch fully dropped).  The mesh rides along
     so a consumer whose mesh changed between staging and use (elastic
     resharding — ``rebind_mesh``) can detect and re-place a stale feed
     instead of handing the step arrays committed to dead devices.  The
     padding stats are taken host-side pre-shard (producer thread under
-    prefetch — off the step loop's critical path)."""
-    from paddle_tpu.reader.feeder import padding_stats
+    prefetch — off the step loop's critical path).
 
-    examples = len(batch) if hasattr(batch, "__len__") else 0
+    The one place a batch is converted, so the one place its two phases
+    are spans: ``feed_convert`` (feeder, padding stats, remainder policy;
+    ``bytes`` of the host arrays) and ``feed_place`` (``shard_batch``:
+    this thread's time, the transfers are async and not fenced;
+    ``bytes``, ``shards``)."""
+    from paddle_tpu.reader.feeder import padding_stats
+    from paddle_tpu.telemetry.tracing import get_tracer
+
+    tracer = get_tracer()
+    examples = _examples(batch)
+    tk = tracer.begin("feed_convert", cat="reader")
     feed = feeder(batch) if feeder is not None else batch
     padded, total = padding_stats(feed) if isinstance(feed, dict) else (0, 0)
-    if mesh is not None:
-        if remainder != "error":
-            from paddle_tpu.parallel.mesh import apply_remainder
+    if mesh is not None and remainder != "error":
+        from paddle_tpu.parallel.mesh import apply_remainder
 
-            feed = apply_remainder(
-                feed, mesh.mesh.shape.get("data", 1), remainder)
-            if feed is None:  # "drop" left nothing: skip the batch
-                return None
+        feed = apply_remainder(
+            feed, mesh.mesh.shape.get("data", 1), remainder)
+        if feed is None:  # "drop" left nothing: skip the batch
+            tracer.end(tk)
+            return None
+    nbytes = 0
+    if tk is not None:
+        nbytes = _feed_bytes(feed)
+        tracer.end(tk, bytes=nbytes)
+    if mesh is not None:
+        tk = tracer.begin("feed_place", cat="reader")
         feed = mesh.shard_batch(feed)
+        if tk is not None:
+            tracer.end(tk, bytes=nbytes, shards=mesh.num_replicas)
     return examples, feed, mesh, padded, total
 
 
@@ -177,8 +225,9 @@ class SynchronousFeeds:
     def __next__(self) -> FeedBatch:
         t0 = time.perf_counter()
         while True:
-            batch = next(self._it)  # StopIteration ends the pass
-            item = _convert(batch, self._feeder, self._mesh, self._remainder)
+            batch = read_batch(self._it)  # StopIteration ends the pass
+            item = convert_batch(batch, self._feeder, self._mesh,
+                                 self._remainder)
             if item is not None:
                 examples, feed, _, padded, total = item
                 return FeedBatch(
@@ -241,19 +290,35 @@ class DevicePrefetcher:
 
         tracer = get_tracer()  # spans land in this worker's own lane
         try:
-            for batch in self._reader():
-                if self._stop.is_set():
-                    return
-                with self._mesh_lock:
-                    mesh = self._mesh
-                with tracer.span("prefetch", cat="reader",
-                                 staged=self._q.qsize()):
-                    item = _convert(batch, self._feeder, mesh,
-                                    self._remainder)
-                if item is None:
-                    continue
-                if not _guarded_put(self._q, item, self._stop):
-                    return
+            it = iter(self._reader())
+            while True:
+                # one batch from pull to staged; its children are
+                # feed_read / feed_convert / feed_place / feed_stage
+                tk = tracer.begin("prefetch", cat="reader",
+                                  staged=self._q.qsize())
+                try:
+                    try:
+                        batch = read_batch(it)
+                    except StopIteration:
+                        tracer.cancel(tk)
+                        return
+                    if self._stop.is_set():
+                        tracer.cancel(tk)
+                        return
+                    with self._mesh_lock:
+                        mesh = self._mesh
+                    item = convert_batch(batch, self._feeder, mesh,
+                                         self._remainder)
+                    if item is None:
+                        continue
+                    # blocks while the queue is full: the worker is
+                    # ahead of the device
+                    with tracer.span("feed_stage", cat="reader"):
+                        staged = _guarded_put(self._q, item, self._stop)
+                    if not staged:
+                        return
+                finally:
+                    tracer.end(tk)
         except BaseException as e:  # propagate to the consumer, not stderr
             _guarded_put(self._q, _ProducerError(e), self._stop)
         finally:

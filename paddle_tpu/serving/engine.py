@@ -362,11 +362,46 @@ class ServingEngine:
 
     def step(self) -> bool:
         """One scheduler iteration: drain submissions, retire, admit +
-        prefill, decode.  Returns False when fully idle."""
+        prefill, decode.  Returns False when fully idle.
+
+        With span tracing on, an iteration that did work is one
+        ``serve_step`` span (an idle one records nothing): its
+        ``serve_schedule`` children are the calls that build or change
+        scheduler / KV-cache state, ``serve_prefill`` / ``serve_decode``
+        are the two device passes (dispatch + the wait for the tokens),
+        and what is left over, its self time, is this loop's own Python:
+        the small host-to-device transfers, the ``append_token`` loops,
+        histograms and gauges."""
+        from paddle_tpu.telemetry.tracing import get_tracer
+
+        tracer = get_tracer()
+        tk = None
+        if tracer.enabled:
+            tk = tracer.begin("serve_step", cat="serving",
+                              waiting=self.queued()
+                              + len(self.scheduler.queue),
+                              active=len(self.scheduler.active))
+        worked = False
+        try:
+            worked = self._step(tracer)
+        finally:
+            (tracer.end if worked else tracer.cancel)(tk)
+        return worked
+
+    def _scheduled(self, tracer, build, *args):
+        """``build(*args)`` (a scheduler call that assembles a batch)
+        under a ``serve_schedule`` span, kept only if there was one."""
+        tk = tracer.begin("serve_schedule", cat="serving")
+        batch = build(*args)
+        (tracer.end if batch is not None else tracer.cancel)(tk)
+        return batch
+
+    def _step(self, tracer) -> bool:
         sched, reg = self.scheduler, self.registry
         now = time.perf_counter()
         worked = False
 
+        tk = tracer.begin("serve_schedule", cat="serving")
         with self._lock:
             while self._incoming:
                 sched.enqueue(self._incoming.popleft())
@@ -376,19 +411,19 @@ class ServingEngine:
             self._finish(a)
             worked = True
 
-        from paddle_tpu.telemetry.tracing import get_tracer
-
-        tracer = get_tracer()
         admitted = sched.admit(now=now)
+        # a pass over empty queues and full slots is not scheduling work
+        (tracer.end if worked or admitted else tracer.cancel)(tk)
         if admitted and not self.serving.incremental_prefill:
             t0 = time.perf_counter()
+            batch = self._scheduled(tracer, sched.prefill_batch, admitted)
+            args = self._dev(batch, "ids", "seq_lens", "page_table", "rids",
+                             "temps")
             tk = tracer.begin("serve_prefill", cat="serving",
                               batch=len(admitted))
-            batch = sched.prefill_batch(admitted)
             toks, self.cache.k, self.cache.v = self._prefill(
                 self.params, self._base_key, self.cache.k, self.cache.v,
-                *self._dev(batch, "ids", "seq_lens", "page_table", "rids",
-                           "temps"))
+                *args)
             toks = np.asarray(toks)
             tracer.end(tk)
             t1 = time.perf_counter()
@@ -414,18 +449,27 @@ class ServingEngine:
             if self._prefill_incremental(admitted, tracer, reg):
                 worked = True
 
-        batch = sched.decode_batch()
+        batch = self._scheduled(tracer, sched.decode_batch)
         if batch is not None:
             live = batch.pop("live")
             t0 = time.perf_counter()
+            args = self._dev(batch, "ids", "positions", "seq_lens",
+                             "page_table", "rids", "gens", "temps")
             tk = tracer.begin("serve_decode", cat="serving",
                               batch=len(live))
             toks, self.cache.k, self.cache.v = self._decode(
                 self.params, self._base_key, self.cache.k, self.cache.v,
-                *self._dev(batch, "ids", "positions", "seq_lens",
-                           "page_table", "rids", "gens", "temps"))
+                *args)
+            if tk is not None:
+                t_dispatched = tracer.clock()
             toks = np.asarray(toks)
-            tracer.end(tk)
+            if tk is not None:
+                # what the step's kernel read (every live sequence's
+                # resident context), and how long the dispatch took
+                # before the wait for the device began
+                tracer.end(
+                    tk, context_tokens=int(batch["seq_lens"].sum()),
+                    dispatch_ms=round((t_dispatched - tk.t_start) * 1e3, 3))
             reg.histogram(
                 "serve_decode_step_ms",
                 "one continuous-batching decode step, wall ms").observe(
@@ -476,17 +520,18 @@ class ServingEngine:
                     "prefill FLOPs not recomputed on prefix-cache hits "
                     "(2·params per token estimate)").inc(
                         2.0 * self._param_count * a.cached_tokens)
-        batch = sched.prefill_chunk_batch()
+        batch = self._scheduled(tracer, sched.prefill_chunk_batch)
         if batch is None:
             return bool(admitted)
         rows, takes = batch.pop("rows"), batch.pop("takes")
         t0 = time.perf_counter()
+        args = self._dev(batch, "ids", "starts", "seq_lens", "page_table",
+                         "rids", "temps")
         tk = tracer.begin("serve_prefill", cat="serving",
                           batch=len(rows), chunked=True)
         toks, self.cache.k, self.cache.v = self._prefill_chunk(
             self.params, self._base_key, self.cache.k, self.cache.v,
-            *self._dev(batch, "ids", "starts", "seq_lens", "page_table",
-                       "rids", "temps"))
+            *args)
         toks = np.asarray(toks)
         tracer.end(tk)
         t1 = time.perf_counter()

@@ -10,7 +10,9 @@ badput buckets:
 
 ``input_wait``
     the trainer blocked on the feed (``feed`` spans — the consumer-side
-    wait, NOT the prefetch producer thread, which overlaps compute);
+    wait, NOT the prefetch producer thread's ``prefetch`` /
+    ``feed_read`` / ``feed_convert`` / ``feed_place`` / ``feed_stage``,
+    which overlap compute);
 ``fence``
     device sync at flush boundaries (``fence`` spans);
 ``recompile``
@@ -64,10 +66,15 @@ import json
 import os
 import threading
 
-# leaf span name -> badput bucket.  Parent spans ("step", "elastic",
-# "request") and overlapping producer-thread spans ("prefetch") are
-# deliberately absent: the ledger counts each wall-clock second once,
-# from the consumer-side leaf that blocked the train loop.
+# span name -> badput bucket.  The ledger counts each wall-clock second
+# of the TRAIN LOOP's thread once, from the span that blocked it, so
+# deliberately absent are: parent spans ("step", "elastic", "request");
+# the producer thread's spans, which overlap compute ("prefetch" and its
+# children "feed_read" / "feed_convert" / "feed_place" / "feed_stage" —
+# with prefetch off the first three are children of "feed", which
+# already books their time as input_wait); and the serving engine's
+# ("serve_step", "serve_schedule", "serve_prefill", "serve_decode":
+# another loop's wall-clock, accounted per request by serving_costs).
 _LEAF_BUCKET = {
     "feed": "input_wait",
     "fence": "fence",

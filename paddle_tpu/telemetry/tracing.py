@@ -15,18 +15,41 @@ stream are bit-identical to an untraced one — asserted in
 Instrumented phase boundaries (all behind the same flag):
 
 - trainer step loop — ``step`` spans with nested ``feed`` / ``compute``
-  / ``fence`` / ``checkpoint`` / ``guard_rescue`` children;
-- ``DevicePrefetcher`` producer — ``prefetch`` spans on the worker
-  thread (they land in their own lane: spans carry the thread name);
-- ``ServingEngine`` — live ``serve_prefill`` / ``serve_decode`` batch
-  spans plus a per-request retrospective ``request`` span with
-  ``queue`` / ``prefill`` / ``decode`` children reconstructed from the
-  request's own timestamps at retire time;
+  / ``fence`` / ``checkpoint`` / ``guard_rescue`` children; ``step``'s
+  self time is the loop's own Python (events, cost look-ups, heartbeats);
+- the feed pipeline (``reader/prefetch.py``) — one batch's production
+  is ``feed_read`` (the pull from the reader iterator; ``examples``),
+  ``feed_convert`` (``DataFeeder`` + padding stats + remainder policy;
+  ``bytes``) and ``feed_place`` (``mesh.shard_batch``, the thread's
+  time, no fence; ``bytes``, ``shards``).  Under ``DevicePrefetcher``
+  they sit on the worker thread (their own lane: spans carry the thread
+  name) inside a ``prefetch`` parent (``staged``) together with
+  ``feed_stage``, the blocking ``put`` on the bounded queue, while the
+  main thread's ``feed`` stays a leaf (its wait on the queue).  With
+  ``prefetch=0`` the same three are children of ``feed`` itself;
+- ``ServingEngine`` — one ``serve_step`` (``waiting``, ``active``) per
+  engine iteration that did work, with ``serve_schedule`` children
+  around the calls that build or change scheduler / KV-cache state and
+  the two leaf batch spans ``serve_prefill`` / ``serve_decode``
+  (``batch``; decode also ``context_tokens`` = KV tokens the step's
+  kernel reads, and ``dispatch_ms`` = until the jitted call returned);
+  ``serve_step``'s self time is the loop's own Python.  Plus a
+  per-request retrospective ``request`` span with ``queue`` /
+  ``prefill`` / ``decode`` children reconstructed from the request's
+  own timestamps at retire time;
 - ``FleetRouter`` — ``failover`` (with nested ``requeue``), ``route``
   and per-replica ``swap`` spans;
 - ``ElasticCoordinator`` — an ``elastic`` span with ``drain`` /
   ``gather`` / ``reshard`` / ``rebuild`` children around a live mesh
   rebuild.
+
+One clock with the device trace: while enabled, every LIVE span
+(``begin``/``end``/``span``) also opens and closes a
+``jax.profiler.TraceAnnotation`` of its name, so any device trace taken
+meanwhile (``--profile_steps``, ``jax.profiler.trace``) carries the
+program's spans as host events on the profiler's own clock, in the
+thread's lane — no marker, no alignment step.  Retrospective
+``add_span``\\ s have no live interval to mirror and are not.
 
 Span identity is DETERMINISTIC: ``span_id = rank * 2**32 + seq`` where
 ``seq`` is the per-tracer allocation counter — two runs of the same
@@ -37,15 +60,17 @@ fake clock and assert exact durations.
 
 Export is Chrome-trace-event JSON (``chrome_trace()`` / ``dump()``),
 loadable in Perfetto / ``chrome://tracing``: one complete ("ph": "X")
-event per span, ``pid`` = rank (the lane), ``tid`` = thread.  The
-introspection server's ``/trace`` endpoint drains the ring through the
-same exporter, and ``tools/trace_merge.py`` merges per-rank dumps into
-one fleet timeline.
+event per span, ``pid`` = rank (the lane), ``tid`` = thread.
+``otherData.clock`` states the export's clock: one reading of the
+tracer's clock beside ``time.time_ns()``, taken together, so a dump can
+be laid beside an xplane or another host's dump
+(``tools/trace_merge.py`` aligns lanes by it).  The introspection
+server's ``/trace`` endpoint drains the ring through the same exporter.
 
 :class:`ProfileWindow` brackets a ``--profile_steps A:B`` window of the
-train loop with ``jax.profiler`` device tracing, wrapping each step's
-dispatch in a ``jax.profiler.TraceAnnotation`` so the host-side step
-spans line up with the device timeline in xprof, and emits one
+train loop with ``jax.profiler`` device tracing and arms the tracer, so
+the capture holds the window's ``compute`` / ``feed`` / ``step`` spans
+as host events beside the device timeline, and emits one
 ``kind="profile"`` telemetry record (schema /11) carrying the window,
 the trace directory and the tracer's per-phase duration summary.
 """
@@ -107,10 +132,10 @@ class _OpenSpan:
     ``cancel`` (or used as a context manager via :meth:`Tracer.span`)."""
 
     __slots__ = ("tracer", "name", "cat", "span_id", "parent_id",
-                 "t_start", "args", "_done")
+                 "t_start", "args", "mirror", "_done")
 
     def __init__(self, tracer, name, cat, span_id, parent_id, t_start,
-                 args):
+                 args, mirror):
         self.tracer = tracer
         self.name = name
         self.cat = cat
@@ -118,6 +143,7 @@ class _OpenSpan:
         self.parent_id = parent_id
         self.t_start = t_start
         self.args = args
+        self.mirror = mirror    # the open TraceAnnotation of this span
         self._done = False
 
     def __enter__(self):
@@ -204,15 +230,38 @@ class Tracer:
 
     def begin(self, name: str, cat: str = "phase", **args) -> _OpenSpan | None:
         """Open a span (returns None when disabled).  The span nests
-        under this THREAD's innermost open span."""
+        under this THREAD's innermost open span, and is mirrored as a
+        ``jax.profiler.TraceAnnotation`` of the same name: a host event
+        in whatever device trace is being taken, on that trace's clock."""
         if not self._enabled:
             return None
+        from jax.profiler import TraceAnnotation
+
         stack = self._tstack()
         parent = stack[-1].span_id if stack else None
+        mirror = TraceAnnotation(name)
+        mirror.__enter__()
         tok = _OpenSpan(self, name, cat, self._next_id(), parent,
-                        self.clock(), args)
+                        self.clock(), args, mirror)
         stack.append(tok)
         return tok
+
+    def _unwind(self, tok: _OpenSpan) -> None:
+        """Take ``tok`` off this thread's stack and close its mirror.
+        Closing a non-top token truncates the stack above it: anything
+        still open there was abandoned by an exception path, and leaving
+        it would mis-parent the rest of the run (their mirrors close
+        first: annotations nest strictly per thread)."""
+        stack = self._tstack()
+        gone = [tok]
+        if tok in stack:
+            i = stack.index(tok)
+            gone = stack[i:]
+            del stack[i:]
+        for t in reversed(gone):
+            mirror, t.mirror = t.mirror, None
+            if mirror is not None:
+                mirror.__exit__(None, None, None)
 
     def end(self, tok: _OpenSpan | None, **args) -> Span | None:
         """Close a span opened by :meth:`begin` (None token = no-op, so
@@ -221,12 +270,7 @@ class Tracer:
             return None
         tok._done = True
         t_end = self.clock()
-        stack = self._tstack()
-        if tok in stack:
-            # closing a non-top token truncates the stack above it:
-            # anything still open there was abandoned by an exception
-            # path, and leaving it would mis-parent the rest of the run
-            del stack[stack.index(tok):]
+        self._unwind(tok)
         if args:
             tok.args.update(args)
         span = Span(tok.name, tok.cat, tok.span_id, tok.parent_id,
@@ -244,9 +288,7 @@ class Tracer:
         if tok is None or tok._done:
             return
         tok._done = True
-        stack = self._tstack()
-        if tok in stack:
-            del stack[stack.index(tok):]
+        self._unwind(tok)
 
     def span(self, name: str, cat: str = "phase", **args):
         """Context-manager form.  Disabled tracers return one shared
@@ -314,7 +356,7 @@ class Tracer:
                      drain: bool = False) -> dict:
         """Chrome-trace-event JSON dict (Perfetto / chrome://tracing
         loadable): the spans as complete events plus process/thread
-        metadata naming this rank's lane."""
+        metadata naming this rank's lane, and ``otherData.clock``."""
         if spans is None:
             spans = self.drain() if drain else self.spans
         events = [{
@@ -329,9 +371,12 @@ class Tracer:
                     "name": "thread_name", "ph": "M", "pid": s.rank,
                     "tid": s.thread, "args": {"name": s.thread}})
             events.append(s.to_event())
+        # the events' clock beside the wall clock, read together: what
+        # lays this dump beside an xplane or another host's dump
+        clock = {"tracer_s": self.clock(), "unix_ns": time.time_ns()}
         return {"traceEvents": events, "displayTimeUnit": "ms",
                 "otherData": {"rank": self.rank, "spans": len(spans),
-                              "dropped": self.dropped}}
+                              "dropped": self.dropped, "clock": clock}}
 
     def dump(self, path: str, drain: bool = False) -> str:
         """Write :meth:`chrome_trace` to ``path`` (parent dirs created)
@@ -433,13 +478,16 @@ class ProfileWindow:
     operator asked for instead of a whole run's worth of profile data.
 
     The trainer calls :meth:`maybe_start` before dispatching step ``n``
-    and :meth:`maybe_stop` after; :meth:`annotation` wraps the dispatch
-    in a ``jax.profiler.TraceAnnotation`` while the window is open, so
-    the device timeline carries host step markers that line up with the
-    tracer's ``step`` spans.  :meth:`close` stops a window left open by
-    a run shorter than B.  One ``kind="profile"`` record (schema /11)
-    is emitted when the window closes: the step range, the trace
-    directory and the tracer's per-phase duration summary.
+    and :meth:`maybe_stop` after.  A window arms its tracer, whose live
+    spans are mirrored into the capture (``Tracer.begin``): the device
+    timeline carries every span that opened and closed while the trace
+    ran — the ``compute`` span of every step of the window, the ``feed``
+    spans of all but the first, whole ``step`` spans strictly inside it
+    (the trace starts and stops around a dispatch, inside a step).
+    :meth:`close` stops a window left open by a run shorter than B.
+    One ``kind="profile"`` record (schema /11) is emitted when the
+    window closes: the step range, the trace directory and the tracer's
+    per-phase duration summary.
 
     Profiling must never kill training: start/stop failures are logged
     and the window deactivates itself.
@@ -451,6 +499,8 @@ class ProfileWindow:
         self.trace_dir = trace_dir
         self.registry = registry
         self.tracer = tracer
+        if self.window is not None and tracer is not None:
+            tracer.configure(enabled=True)
         self.active = False
         self.emitted: dict | None = None
         self._t0 = 0.0
@@ -490,15 +540,6 @@ class ProfileWindow:
             # drain or ring wrap shifts positions but not span ids
             self._span_floor = self.tracer.seq_watermark()
         return True
-
-    def annotation(self, step: int):
-        """A device-trace step marker while the window is open (a no-op
-        context manager outside it)."""
-        if not self.active:
-            return _NULL_SPAN
-        import jax
-
-        return jax.profiler.TraceAnnotation(f"train_step_{step}")
 
     def maybe_stop(self, step: int, fence=None) -> dict | None:
         """Close the window once ``step`` (the NEXT step to dispatch)

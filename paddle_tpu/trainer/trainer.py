@@ -25,7 +25,6 @@ from paddle_tpu.core.lod import SequenceBatch
 from paddle_tpu.core.parameters import Parameters
 from paddle_tpu.layers.base import LayerOutput
 from paddle_tpu.parallel.mesh import MeshContext, get_mesh
-from paddle_tpu.reader import feeder as feeder_mod
 from paddle_tpu.reader.feeder import DataFeeder, parse_seq_buckets
 from paddle_tpu.trainer import event as v2_event
 from paddle_tpu.trainer.step import build_eval_step, build_train_step
@@ -620,6 +619,8 @@ class SGD:
         from paddle_tpu.reader.prefetch import (
             DevicePrefetcher,
             SynchronousFeeds,
+            convert_batch,
+            read_batch,
             skip_feed_batches,
         )
         from paddle_tpu.telemetry import tokens_in_feed
@@ -926,7 +927,7 @@ class SGD:
                         # change meaning with the knobs
                         t_feed0 = _time.perf_counter()
                         try:
-                            data_batch = next(raw_it)
+                            data_batch = read_batch(raw_it)
                         except StopIteration:
                             tracer.cancel(tk_feed)
                             tracer.cancel(tk_step)
@@ -935,12 +936,10 @@ class SGD:
                         event_handler(v2_event.BeginIteration(pass_id,
                                                               batch_id))
                         with stat.timer("feed"):
-                            feed = feeder(data_batch)
-                            padded_ts, total_ts = feeder_mod.padding_stats(
-                                feed)
-                            feed = self.mesh.shard_batch(feed)
+                            examples, feed, _, padded_ts, total_ts = \
+                                convert_batch(data_batch, feeder, self.mesh,
+                                              remainder)
                         wait_ms = (_time.perf_counter() - t_feed0) * 1e3
-                        examples = len(data_batch)
                     else:
                         with stat.timer("feed"):
                             try:
@@ -1013,10 +1012,9 @@ class SGD:
                         # whole span as "recompile", not "compute"
                         tk_compute = tracer.begin("compute", cat="trainer",
                                                   compile=new_sig)
-                        with profile.annotation(n_disp):
-                            params, opt_state, states, cost, metrics = \
-                                self._train_step(params, opt_state,
-                                                 states, feed, step_key)
+                        params, opt_state, states, cost, metrics = \
+                            self._train_step(params, opt_state,
+                                             states, feed, step_key)
                         tracer.end(tk_compute)
                     dispatched["n"] = n_disp + 1
                     profile.maybe_stop(n_disp + 1, fence=cost)

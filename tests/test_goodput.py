@@ -123,6 +123,45 @@ def test_chaos_windows_land_in_their_buckets_and_sum_to_wall():
     assert set(BADPUT_BUCKETS) == set(BUCKETS) - {"compute"}
 
 
+def test_wall_clock_identity_holds_with_the_layer_spans_in_the_ring():
+    """The feed pipeline's and the engine step's spans are in the ring
+    beside the trainer's: producer-thread spans (``prefetch`` and its
+    four children), the inline path's children of ``feed``, and the
+    serving engine's own loop are booked nowhere, so each second of the
+    train loop is still counted once and the buckets sum to the wall."""
+    led, tracer, clk, reg = _ledger()
+    # two steps with the prefetch worker running ahead
+    for i, t0 in enumerate((0.0, 5.0)):
+        tracer.add_span("feed", t0, t0 + 1.0, cat="trainer")
+        tracer.add_span("compute", t0 + 1.0, t0 + 4.0, cat="trainer")
+        tracer.add_span("fence", t0 + 4.0, t0 + 5.0, cat="trainer")
+        tracer.add_span("step", t0, t0 + 5.0, cat="trainer")
+        p = tracer.add_span("prefetch", t0, t0 + 5.0, cat="reader")
+        tracer.add_span("feed_read", t0, t0 + 1.0, "reader", p)
+        tracer.add_span("feed_convert", t0 + 1.0, t0 + 3.0, "reader", p)
+        tracer.add_span("feed_place", t0 + 3.0, t0 + 3.5, "reader", p)
+        tracer.add_span("feed_stage", t0 + 3.5, t0 + 5.0, "reader", p)
+    # a third step on the inline path: the three are children of feed
+    f = tracer.add_span("feed", 10.0, 13.0, cat="trainer")
+    tracer.add_span("feed_read", 10.0, 11.0, "reader", f)
+    tracer.add_span("feed_convert", 11.0, 12.5, "reader", f)
+    tracer.add_span("feed_place", 12.5, 13.0, "reader", f)
+    tracer.add_span("compute", 13.0, 15.0, cat="trainer")
+    # an engine serving in the same process
+    s = tracer.add_span("serve_step", 0.0, 15.0, cat="serving")
+    tracer.add_span("serve_schedule", 0.0, 1.0, "serving", s)
+    tracer.add_span("serve_prefill", 1.0, 5.0, "serving", s)
+    tracer.add_span("serve_decode", 5.0, 15.0, "serving", s)
+    clk.t = 16.0
+    rec = led.finish()
+    b = rec["buckets_s"]
+    assert b["input_wait"] == pytest.approx(1.0 + 1.0 + 3.0)
+    assert b["compute"] == pytest.approx(3.0 + 3.0 + 2.0)
+    assert b["fence"] == pytest.approx(2.0)
+    assert b["idle"] == pytest.approx(1.0)
+    assert sum(b.values()) == pytest.approx(rec["wall_s"]) == 16.0
+
+
 def test_fold_is_incremental_over_ring_snapshots():
     led, tracer, clk, _ = _ledger()
     tracer.add_span("feed", 0.0, 1.0, cat="trainer")

@@ -284,7 +284,7 @@ def _batches(n_samples=32, batch=8):
 
 
 def _run_train(trace_spans: bool, status_port=0, scrape_at=None,
-               profile_steps="", n_samples=32, registry=None):
+               profile_steps="", n_samples=32, registry=None, prefetch=0):
     from paddle_tpu.core import rng
     from paddle_tpu.telemetry.tracing import get_tracer
 
@@ -294,6 +294,7 @@ def _run_train(trace_spans: bool, status_port=0, scrape_at=None,
     flags.set("trace_spans", trace_spans)
     flags.set("status_port", status_port)
     flags.set("profile_steps", profile_steps)
+    flags.set("prefetch_depth", prefetch)
     trainer = _tiny_trainer()
     reg = registry or MetricsRegistry("test_introspect")
     sink = MemorySink()
@@ -370,15 +371,20 @@ def test_four_step_train_serves_all_endpoints_midrun():
         _get(port, "/healthz")
 
 
-def test_disabled_tracing_is_bitwise_noop():
+@pytest.mark.parametrize("prefetch", [0, 2])
+def test_disabled_tracing_is_bitwise_noop(prefetch):
     """The no-op guard: tracing off vs on must not change the
-    trajectory AT ALL, and tracing off must record nothing."""
+    trajectory AT ALL, and tracing off must record nothing — through
+    the inline feed path (read / convert / place under ``feed``) and
+    through the prefetch worker's (under ``prefetch``)."""
     from paddle_tpu.telemetry.tracing import get_tracer
 
-    tr_off, steps_off, _, _ = _run_train(False)
+    tr_off, steps_off, _, _ = _run_train(False, prefetch=prefetch)
     assert get_tracer().spans == []  # nothing recorded, nothing leaked
-    tr_on, steps_on, _, _ = _run_train(True)
-    assert len(get_tracer().spans) > 0
+    tr_on, steps_on, _, _ = _run_train(True, prefetch=prefetch)
+    names = {s.name for s in get_tracer().spans}
+    assert {"feed_read", "feed_convert", "feed_place"} <= names
+    assert ("feed_stage" in names) == bool(prefetch)
     np.testing.assert_array_equal(
         np.asarray([r["loss"] for r in steps_off]),
         np.asarray([r["loss"] for r in steps_on]),

@@ -1,0 +1,590 @@
+"""Layer boundaries as spans: inside the feed pipeline
+(``reader/prefetch.py``), inside the serving engine's step
+(``serving/engine.py``), and their mirrors on the profiler's clock
+(``telemetry/tracing.py``).
+
+What holds: a batch's production is ``prefetch`` ⊃ ``feed_read`` /
+``feed_convert`` / ``feed_place`` / ``feed_stage`` on the worker thread
+(the same three names under ``feed`` with prefetch off), with
+``examples`` / ``bytes`` / ``shards`` stated and the children summing to
+the parent on a fake clock; an engine iteration that worked is
+``serve_step`` ⊃ ``serve_schedule`` / ``serve_prefill`` /
+``serve_decode`` with the two batch spans still leaves and
+``context_tokens`` the live sequences' lengths, an idle one records
+nothing; tracing off computes no argument and changes no result; every
+live span shows up as a host event of its name in a ``jax.profiler``
+trace taken meanwhile; a dump states its clock and ``trace_merge``
+aligns lanes by it.
+"""
+
+import glob
+import json
+import os
+import sys
+import threading
+import time
+
+import numpy as np
+import pytest
+
+import jax
+
+import paddle_tpu as paddle
+from paddle_tpu.core import flags
+from paddle_tpu.models import transformer as T
+from paddle_tpu.reader import prefetch as prefetch_mod
+from paddle_tpu.reader.prefetch import DevicePrefetcher, SynchronousFeeds
+from paddle_tpu.serving import ServingConfig, ServingEngine
+from paddle_tpu.telemetry import MetricsRegistry
+from paddle_tpu.telemetry.tracing import Tracer, get_tracer
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+WORKER = "paddle-tpu-prefetch"
+
+
+class _Clock:
+    """A clock that only the instrumented work advances."""
+
+    def __init__(self):
+        self.t = 100.0
+
+    def __call__(self):
+        return self.t
+
+
+@pytest.fixture
+def tracer():
+    """The process tracer, enabled and empty; restored afterwards."""
+    snap = flags.snapshot_raw()
+    t = get_tracer()
+    was_enabled, was_clock = t.enabled, t.clock
+    t.configure(enabled=True)
+    t.clear()
+    yield t
+    t.configure(enabled=was_enabled, clock=was_clock)
+    t.clear()
+    flags.restore_raw(snap)
+
+
+def _by_name(spans):
+    out: dict = {}
+    for s in spans:
+        out.setdefault(s.name, []).append(s)
+    return out
+
+
+class _Mesh:
+    """Stands in for a MeshContext: placing takes 5 s of the fake clock."""
+
+    num_replicas = 4
+
+    def __init__(self, clk):
+        self.clk = clk
+
+    def shard_batch(self, feed):
+        self.clk.t += 5.0
+        return feed
+
+
+def _timed_pipeline(clk, batches=3, rows=6):
+    """(reader, feeder): a pull takes 2 s of the fake clock, a
+    conversion 3 s; a batch is ``rows`` samples of 4 float32."""
+    def reader():
+        for b in range(batches):
+            clk.t += 2.0
+            yield [(np.full((4,), b, np.float32), b) for _ in range(rows)]
+
+    def feeder(batch):
+        clk.t += 3.0
+        return {"x": np.stack([r[0] for r in batch]),
+                "y": np.asarray([r[1] for r in batch], np.int32)}
+
+    return reader, feeder
+
+
+# -- the feed pipeline ---------------------------------------------------------
+
+
+def test_worker_spans_nest_under_prefetch_and_sum_to_it(tracer, monkeypatch):
+    clk = _Clock()
+    tracer.configure(clock=clk)
+    real_put = prefetch_mod._guarded_put
+
+    def slow_put(q, item, stop):
+        if isinstance(item, tuple):     # a staged feed, not a sentinel
+            clk.t += 7.0
+        return real_put(q, item, stop)
+
+    monkeypatch.setattr(prefetch_mod, "_guarded_put", slow_put)
+    reader, feeder = _timed_pipeline(clk)
+    with DevicePrefetcher(reader, feeder, _Mesh(clk), depth=2) as feeds:
+        got = list(feeds)
+    assert [fb.examples for fb in got] == [6, 6, 6]
+    spans = tracer.spans
+    assert {s.thread for s in spans} == {WORKER}
+    by = _by_name(spans)
+    assert len(by["prefetch"]) == 3     # the end-of-stream pull is cancelled
+    for parent in by["prefetch"]:
+        kids = sorted((s for s in spans if s.parent_id == parent.span_id),
+                      key=lambda s: s.t_start)
+        assert [k.name for k in kids] == ["feed_read", "feed_convert",
+                                          "feed_place", "feed_stage"]
+        assert [k.dur_ms for k in kids] == [2e3, 3e3, 5e3, 7e3]
+        assert sum(k.dur_ms for k in kids) == parent.dur_ms == 17e3
+        assert kids[0].t_start == parent.t_start
+        assert kids[-1].t_end == parent.t_end
+        read, convert, place, stage = kids
+        nbytes = 6 * 4 * 4 + 6 * 4      # x float32 [6,4] + y int32 [6]
+        assert read.args == {"examples": 6}
+        assert convert.args == {"bytes": nbytes}
+        assert place.args == {"bytes": nbytes, "shards": 4}
+        assert stage.args == {}
+        assert "staged" in parent.args
+    assert all(s.cat == "reader" for s in spans)
+
+
+def test_synchronous_feeds_record_the_same_three_names(tracer):
+    clk = _Clock()
+    tracer.configure(clock=clk)
+    reader, feeder = _timed_pipeline(clk, batches=2)
+    got = list(SynchronousFeeds(reader, feeder, _Mesh(clk)))
+    assert len(got) == 2
+    by = _by_name(tracer.spans)
+    assert set(by) == {"feed_read", "feed_convert", "feed_place"}
+    assert [s.dur_ms for s in by["feed_read"]] == [2e3, 2e3]
+    assert [s.dur_ms for s in by["feed_convert"]] == [3e3, 3e3]
+    assert [s.dur_ms for s in by["feed_place"]] == [5e3, 5e3]
+    assert by["feed_place"][0].args["shards"] == 4
+
+
+def test_a_dropped_batch_has_no_place_span(tracer):
+    from paddle_tpu.parallel.mesh import MeshContext, make_mesh
+
+    mesh = MeshContext(make_mesh({"data": 4}))
+
+    def reader():
+        yield [(np.zeros((4,), np.float32), 0)] * 2     # < 4: dropped whole
+        yield [(np.zeros((4,), np.float32), 0)] * 8
+
+    def feeder(batch):
+        return {"x": np.stack([r[0] for r in batch])}
+
+    got = list(SynchronousFeeds(reader, feeder, mesh, remainder="drop"))
+    assert [fb.examples for fb in got] == [8]
+    by = _by_name(tracer.spans)
+    assert len(by["feed_read"]) == 2 and len(by["feed_convert"]) == 2
+    assert len(by["feed_place"]) == 1
+    assert by["feed_place"][0].args == {"bytes": 8 * 4 * 4, "shards": 4}
+
+
+def _tiny_trainer():
+    from paddle_tpu.core import rng
+    from paddle_tpu.layers import activation as act
+    from paddle_tpu.layers import api as layer
+    from paddle_tpu.layers import base, data_type
+
+    base.reset_name_counters()
+    rng.seed(7)
+    x = layer.data(name="px", type=data_type.dense_vector(6))
+    h = layer.fc(input=x, size=4, act=act.SoftmaxActivation())
+    lbl = layer.data(name="py", type=data_type.integer_value(4))
+    cost = layer.classification_cost(input=h, label=lbl)
+    parameters = paddle.parameters.create(paddle.topology.Topology(cost))
+    return paddle.trainer.SGD(
+        cost=cost, parameters=parameters,
+        update_equation=paddle.optimizer.SGD(learning_rate=0.1))
+
+
+def _train(prefetch: int, n_samples=32):
+    rng = np.random.default_rng(0)
+    data = [(rng.normal(size=(6,)).astype(np.float32), int(i % 4))
+            for i in range(n_samples)]
+    trainer = _tiny_trainer()
+    losses = []
+
+    def handler(e):
+        if isinstance(e, paddle.event.EndIteration):
+            losses.append(float(e.cost))
+
+    trainer.train(reader=paddle.reader.batch(lambda: iter(data), 8),
+                  num_passes=1, event_handler=handler,
+                  metrics_registry=MetricsRegistry("layer_spans"),
+                  prefetch=prefetch)
+    return trainer, losses
+
+
+def test_trainer_inline_path_nests_the_three_under_feed(tracer):
+    _train(prefetch=0)
+    spans = tracer.spans
+    by = _by_name(spans)
+    feeds = {s.span_id for s in by["feed"]}
+    assert len(feeds) == 4 and len(by["step"]) == 4
+    for name in ("feed_read", "feed_convert", "feed_place"):
+        assert len(by[name]) == 4
+        assert all(s.parent_id in feeds for s in by[name])
+        assert all(s.thread == "MainThread" for s in by[name])
+    assert all(s.args["examples"] == 8 for s in by["feed_read"])
+    # px float32 [8,6] + py int32 [8]
+    assert all(s.args["bytes"] == 8 * 6 * 4 + 8 * 4
+               for s in by["feed_convert"] + by["feed_place"])
+    assert "prefetch" not in by and "feed_stage" not in by
+
+
+def test_trainer_prefetch_path_keeps_feed_a_leaf(tracer):
+    _train(prefetch=2)
+    spans = tracer.spans
+    by = _by_name(spans)
+    parents = {s.parent_id for s in spans}
+    assert len(by["feed"]) == 4
+    # the ledger's idle gaps are named after this leaf on the main thread
+    assert all(s.span_id not in parents and s.thread == "MainThread"
+               for s in by["feed"])
+    batches = {s.span_id for s in by["prefetch"]}
+    assert len(batches) == 4
+    for name in ("feed_read", "feed_convert", "feed_place", "feed_stage"):
+        assert len(by[name]) == 4
+        assert all(s.parent_id in batches and s.thread == WORKER
+                   for s in by[name])
+    for p in by["prefetch"]:
+        kids = [s for s in spans if s.parent_id == p.span_id]
+        assert all(p.t_start <= k.t_start and k.t_end <= p.t_end
+                   for k in kids)
+        assert sum(k.dur_ms for k in kids) <= p.dur_ms + 1e-6
+
+
+# -- the engine step -----------------------------------------------------------
+
+
+def _engine(**kw):
+    cfg = T.TransformerConfig(
+        vocab_size=64, num_layers=1, num_heads=2, embed_dim=32,
+        mlp_dim=64, max_seq_len=64, remat=False)
+    params = T.init_params(cfg, jax.random.key(1))
+    serving = dict(max_slots=2, page_size=4, num_pages=32, max_prompt_len=8,
+                   max_new_tokens=4, seed=0)
+    serving.update(kw)
+    return ServingEngine(cfg, params, ServingConfig(**serving),
+                         registry=MetricsRegistry("engine_spans"))
+
+
+def _inside(child, parent) -> bool:
+    return (parent.t_start <= child.t_start
+            and child.t_end <= parent.t_end)
+
+
+@pytest.mark.serving
+@pytest.mark.parametrize("incremental", [False, True])
+def test_serve_step_holds_schedule_prefill_and_decode(tracer, incremental):
+    eng = _engine(prefill_chunk_tokens=4 if incremental else 0)
+    real = eng.scheduler.decode_batch
+    expected = []
+
+    def counted():
+        batch = real()
+        if batch is not None:
+            expected.append(sum(a.prompt_len + len(a.generated)
+                                for a in batch["live"]))
+        return batch
+
+    eng.scheduler.decode_batch = counted
+    res = eng.generate([[5, 17, 3], [9, 2, 4, 4, 1, 7]], max_new_tokens=3)
+    assert [len(r.tokens) for r in res] == [3, 3]
+    spans = tracer.spans
+    by = _by_name(spans)
+    steps = {s.span_id: s for s in by["serve_step"]}
+    parents = {s.parent_id for s in spans}
+    assert steps and all({"waiting", "active"} <= set(s.args)
+                         for s in steps.values())
+    assert by["serve_step"][0].args["waiting"] == 2
+    for name in ("serve_schedule", "serve_prefill", "serve_decode"):
+        assert by[name], name
+        for s in by[name]:
+            assert s.parent_id in steps and _inside(s, steps[s.parent_id])
+            assert s.cat == "serving"
+    # the ledger's serve breakdown is handed LEAF spans of these names
+    for s in by["serve_prefill"] + by["serve_decode"]:
+        assert s.span_id not in parents
+    assert all(s.span_id not in parents for s in by["serve_schedule"])
+    assert [s.args["context_tokens"] for s in by["serve_decode"]] == expected
+    if not incremental:     # both prompts resident after one pass
+        assert expected[0] == (3 + 1) + (6 + 1)
+    for s in by["serve_decode"]:
+        assert 0.0 <= s.args["dispatch_ms"] <= s.dur_ms + 1e-3
+        assert s.args["batch"] >= 1
+    # a step's children never overlap: its self time is what is left
+    for sid, step in steps.items():
+        kids = sorted((s for s in spans if s.parent_id == sid),
+                      key=lambda s: s.t_start)
+        assert all(a.t_end <= b.t_start for a, b in zip(kids, kids[1:]))
+
+
+@pytest.mark.serving
+def test_an_idle_step_records_nothing(tracer):
+    eng = _engine()
+    eng.generate([[5, 17, 3]], max_new_tokens=2)
+    tracer.clear()
+    assert eng.step() is False and eng.step() is False
+    assert tracer.spans == []
+    # and leaves nothing open on this thread's stack
+    with tracer.span("after"):
+        pass
+    assert tracer.spans[0].parent_id is None
+
+
+@pytest.mark.serving
+def test_a_failing_step_leaves_no_open_span(tracer):
+    eng = _engine()
+    eng.submit([5, 17, 3], max_new_tokens=2)
+
+    def boom(*a, **kw):
+        raise RuntimeError("device lost")
+
+    eng._prefill = boom
+    with pytest.raises(RuntimeError, match="device lost"):
+        eng.step()
+    with tracer.span("after"):
+        pass
+    assert [s for s in tracer.spans if s.name == "after"][0].parent_id is None
+    assert not [s for s in tracer.spans if s.name == "serve_step"]
+
+
+# -- tracing off ---------------------------------------------------------------
+
+
+@pytest.mark.serving
+def test_disabled_tracing_computes_no_argument(monkeypatch):
+    """Off, the new call sites read no clock and size no feed."""
+    t = get_tracer()
+    was_enabled, was_clock = t.enabled, t.clock
+    t.configure(enabled=False)
+    t.clear()
+
+    def boom(*a, **kw):
+        raise AssertionError("computed with tracing off")
+
+    t.clock = boom
+    monkeypatch.setattr(prefetch_mod, "_feed_bytes", boom)
+    try:
+        _, losses = _train(prefetch=2)
+        _, losses0 = _train(prefetch=0)
+        eng = _engine()
+        eng.queued = boom
+        res = eng.generate([[5, 17, 3], [9, 2]], max_new_tokens=3)
+    finally:
+        t.configure(enabled=was_enabled, clock=was_clock)
+    assert len(losses) == 4 and losses == losses0
+    assert [len(r.tokens) for r in res] == [3, 3]
+    assert t.spans == []
+
+
+@pytest.mark.serving
+def test_traced_engine_serves_the_same_tokens(tracer):
+    prompts = [[5, 17, 3], [9, 2, 4], [1, 1, 2, 3, 5]]
+    traced = [r.tokens for r in _engine().generate(prompts, 4, 0.7)]
+    assert tracer.spans
+    tracer.configure(enabled=False)
+    plain = [r.tokens for r in _engine().generate(prompts, 4, 0.7)]
+    assert traced == plain
+
+
+# -- one clock with the device trace -------------------------------------------
+
+
+def _host_events(logdir) -> dict:
+    """{event name: [(start_ns, duration_ns)]} over the host planes of
+    the newest xplane under ``logdir``."""
+    files = sorted(glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                             recursive=True), key=os.path.getmtime)
+    data = jax.profiler.ProfileData.from_file(files[-1])
+    out: dict = {}
+    for plane in data.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    out.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.duration_ns))
+    return out
+
+
+def _start_trace(logdir):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(logdir), profiler_options=opts)
+
+
+def test_live_spans_are_host_events_of_a_profiler_trace(tmp_path):
+    t = Tracer(enabled=True, rank=0)
+    before = t.begin("opened_before_the_trace")
+    _start_trace(tmp_path)
+    try:
+        def work():
+            with t.span("prefetch", cat="reader"):
+                with t.span("feed_convert", cat="reader"):
+                    time.sleep(0.01)
+
+        th = threading.Thread(target=work, name=WORKER)
+        th.start()
+        th.join(timeout=30)
+        assert not th.is_alive()
+        with t.span("step", cat="trainer"):
+            with t.span("compute", cat="trainer"):
+                time.sleep(0.005)
+            cancelled = t.begin("feed")
+            t.cancel(cancelled)
+        t.add_span("request", 0.0, 1.0)      # retrospective: no mirror
+        t.end(before)
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(str(tmp_path))
+    for name in ("prefetch", "feed_convert", "step", "compute", "feed"):
+        assert len(events.get(name, ())) == 1, name
+    assert "request" not in events
+    # mirrors are on the profiler's clock with the span's own extent
+    spans = {s.name: s for s in t.spans}
+    for name in ("feed_convert", "compute"):
+        assert events[name][0][1] / 1e6 == pytest.approx(
+            spans[name].dur_ms, abs=2.0)
+    (s0, d0), (s1, d1) = events["step"][0], events["compute"][0]
+    assert s0 <= s1 and s1 + d1 <= s0 + d0
+    # a disabled tracer mirrors nothing
+    off = Tracer(enabled=False)
+    assert off.begin("x") is None
+
+
+def test_abandoned_children_close_their_mirrors_with_the_parent(tmp_path):
+    """Closing a non-top token truncates the stack above it; the
+    mirrors above close too, innermost first, so later spans nest
+    right in the profile as in the ring."""
+    t = Tracer(enabled=True, rank=0)
+    _start_trace(tmp_path)
+    try:
+        outer = t.begin("outer_span")
+        t.begin("abandoned_child")
+        t.end(outer)
+        with t.span("next_span"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    assert [s.name for s in t.spans] == ["outer_span", "next_span"]
+    assert t.spans[1].parent_id is None
+    events = _host_events(str(tmp_path))
+    (so, do), (sc, dc), (sn, dn) = (events["outer_span"][0],
+                                    events["abandoned_child"][0],
+                                    events["next_span"][0])
+    assert so <= sc and sc + dc <= so + do      # closed inside its parent
+    assert sn >= so + do                        # and the next one after it
+
+
+def test_profile_window_arms_the_tracer_and_needs_no_step_marker(tmp_path):
+    """``--profile_steps`` alone (no ``--trace_spans``): the window arms
+    span tracing, the capture holds the window's spans by name and no
+    ``train_step_<n>`` marker, and the record is what it was."""
+    from paddle_tpu.telemetry import MemorySink
+
+    snap = flags.snapshot_raw()
+    t = get_tracer()
+    was = t.enabled
+    t.configure(enabled=False)
+    t.clear()
+    flags.set("trace_spans", False)
+    flags.set("profile_steps", "1:4")
+    flags.set("profile_dir", str(tmp_path / "prof"))
+    reg = MetricsRegistry("profile_window")
+    sink = MemorySink()
+    reg.add_sink(sink)
+    try:
+        trainer = _tiny_trainer()
+        rng = np.random.default_rng(0)
+        data = [(rng.normal(size=(6,)).astype(np.float32), int(i % 4))
+                for i in range(32)]
+        trainer.train(reader=paddle.reader.batch(lambda: iter(data), 8),
+                      num_passes=1, event_handler=lambda e: None,
+                      metrics_registry=reg)
+        assert t.enabled
+    finally:
+        t.configure(enabled=was)
+        t.clear()
+        flags.restore_raw(snap)
+    (rec,) = [r for r in sink.records if r.get("kind") == "profile"]
+    assert set(rec) >= {"start_step", "end_step", "steps", "trace_dir",
+                        "wall_ms", "spans", "schema"}
+    assert (rec["start_step"], rec["end_step"], rec["steps"]) == (1, 4, 3)
+    assert rec["spans"]["compute"]["count"] == 3
+    events = _host_events(rec["trace_dir"])
+    assert len(events["compute"]) == 3      # the window's three dispatches
+    # the first step's feed ran before the trace started; a step span
+    # is whole only strictly inside the window (the trace stops right
+    # after the last dispatch)
+    assert len(events["feed"]) == len(events["feed_convert"]) == 2
+    assert len(events["step"]) == 1
+    assert not [n for n in events if n.startswith("train_step_")]
+    assert not hasattr(paddle.telemetry.tracing.ProfileWindow, "annotation")
+
+
+def test_stat_timer_keeps_its_aggregates(tmp_path):
+    """``core/stat.timer`` lost its own TraceAnnotation (the tracer's
+    mirrors carry the scopes); the reference's aggregates stay."""
+    from paddle_tpu.core import stat
+
+    snap = flags.snapshot_raw()
+    flags.set("with_timer", True)
+    ss = stat.StatSet("t")
+    _start_trace(tmp_path)
+    try:
+        for _ in range(3):
+            with stat.timer("forwardBackward", ss):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+        flags.restore_raw(snap)
+    assert ss.stats["forwardBackward"].count == 3
+    assert "forwardBackward" not in _host_events(str(tmp_path))
+
+
+# -- a dump states its clock ---------------------------------------------------
+
+
+def test_chrome_trace_states_its_clock_and_merge_aligns_by_it(tmp_path):
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    try:
+        import trace_merge
+    finally:
+        sys.path.pop(0)
+    clk = _Clock()
+    t = Tracer(enabled=True, rank=0, clock=clk)
+    with t.span("step"):
+        clk.t += 1.0
+    before = time.time_ns()
+    clock = t.chrome_trace()["otherData"]["clock"]
+    assert clock["tracer_s"] == clk.t
+    assert before <= clock["unix_ns"] <= time.time_ns()
+
+    # two ranks whose monotonic clocks started 40 s apart, dumped 2 s of
+    # wall clock apart: the same instant reads 101 s on rank 0 and 61 s
+    # on rank 1
+    def dump(rank, tracer_s, unix_ns, ts_us):
+        path = tmp_path / f"trace-host{rank}.json"
+        path.write_text(json.dumps({
+            "traceEvents": [{"name": "step", "ph": "X", "ts": ts_us,
+                             "dur": 5.0, "pid": rank, "tid": "MainThread",
+                             "args": {"id": rank}}],
+            "otherData": {"rank": rank, "clock": {
+                "tracer_s": tracer_s, "unix_ns": unix_ns}}}))
+        return str(path)
+
+    f0 = dump(0, 110.0, 1_000_000_000_000, 101e6)
+    f1 = dump(1, 72.0, 1_002_000_000_000, 61e6)
+    merged = trace_merge.merge([f0, f1])
+    ts = {e["pid"]: e["ts"] for e in merged["traceEvents"]
+          if e.get("ph") == "X"}
+    assert ts[0] == 101e6 and ts[1] == pytest.approx(101e6, abs=1e-3)
+    assert merged["otherData"]["unaligned"] == []
+    # a dump without the pair keeps its times and is named
+    old = tmp_path / "trace-host2.json"
+    old.write_text(json.dumps({"traceEvents": [
+        {"name": "step", "ph": "X", "ts": 7.0, "dur": 1.0, "pid": 2,
+         "tid": "MainThread", "args": {"id": 9}}]}))
+    merged = trace_merge.merge([f0, str(old)])
+    assert merged["otherData"]["unaligned"] == [str(old)]
+    assert [e["ts"] for e in merged["traceEvents"]
+            if e.get("ph") == "X" and e["pid"] == 2] == [7.0]

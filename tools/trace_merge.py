@@ -8,7 +8,13 @@ trace-event file where each rank is its own process lane (``pid`` =
 rank, process_name ``rank <k>`` — replicas from ``launch --serving``
 render as ``replica <k>``), so one Perfetto view shows the whole
 fleet's feed/compute/fence (or queue/prefill/decode) phases side by
-side, wall-clock aligned.
+side.  Each process stamps its spans on its own monotonic clock; a
+dump states that clock beside the wall clock (``otherData.clock``: one
+``tracer_s`` / ``unix_ns`` pair read together at export), and the merge
+moves every lane that has the pair onto the first such lane's clock, so
+the lanes are aligned as well as the hosts' wall clocks agree.  A file
+without the pair (an older dump, a hand-made trace) keeps its own
+times, and a warning names it.
 
 Usage::
 
@@ -70,22 +76,47 @@ def rank_of(path: str, trace: dict) -> int | None:
     return int(m.group(1)) if m else None
 
 
+def clock_pair(trace: dict) -> tuple[float, int] | None:
+    """A dump's ``otherData.clock``: (its tracer's clock in seconds, the
+    wall clock in ns), read together at export; None without one."""
+    clock = (trace.get("otherData") or {}).get("clock") or {}
+    try:
+        return float(clock["tracer_s"]), int(clock["unix_ns"])
+    except (KeyError, TypeError, ValueError):
+        return None
+
+
 def merge(files: list[str], label: str = "rank") -> dict:
     """Fold trace files into one trace-event dict with one pid lane per
-    rank.  Returns the merged trace; ``otherData.lanes`` maps pid ->
-    source file and ``otherData.empty`` lists inputs that parsed but
-    held zero span events (their lanes still exist — a rank with an
-    armed-late tracer shows as an empty lane, not a hole)."""
+    rank, lanes aligned by their clock pairs.  Returns the merged trace;
+    ``otherData.lanes`` maps pid -> source file, ``otherData.empty``
+    lists inputs that parsed but held zero span events (their lanes
+    still exist — a rank with an armed-late tracer shows as an empty
+    lane, not a hole) and ``otherData.unaligned`` those without a clock
+    pair."""
     events: list[dict] = []
     lanes: dict[int, str] = {}
     empty: list[str] = []
+    unaligned: list[str] = []
+    base = None  # the first lane with a clock pair keeps its own times
     next_free = 0
     for path in files:
         with open(path) as f:
             trace = json.load(f)
         src = (trace.get("traceEvents")
                if isinstance(trace, dict) else trace) or []
-        rank = rank_of(path, trace if isinstance(trace, dict) else {})
+        meta = trace if isinstance(trace, dict) else {}
+        rank = rank_of(path, meta)
+        pair = clock_pair(meta)
+        shift_us = 0.0
+        if pair is None:
+            unaligned.append(path)
+        else:
+            base = base or pair
+            # this lane's clock read pair[0] when the base lane's read
+            # base[0] + the wall time between the two exports
+            shift_us = ((pair[1] - base[1]) / 1e3
+                        - (pair[0] - base[0]) * 1e6)
         if rank is None or rank in lanes:
             while next_free in lanes:
                 next_free += 1
@@ -102,6 +133,7 @@ def merge(files: list[str], label: str = "rank") -> dict:
             e["pid"] = rank
             if e.get("ph") == "X":
                 n_spans += 1
+                e["ts"] = round(e["ts"] + shift_us, 3)
             if e.get("ph") == "M" and e.get("name") == "process_name":
                 e["args"] = {"name": f"{label} {rank}"}
                 have_name = True
@@ -112,10 +144,14 @@ def merge(files: list[str], label: str = "rank") -> dict:
             events.append({"name": "process_name", "ph": "M",
                            "pid": rank, "tid": 0,
                            "args": {"name": f"{label} {rank}"}})
+    if unaligned and len(files) > 1:
+        print("trace_merge: no otherData.clock in "
+              f"{', '.join(unaligned)}; those lanes keep their own "
+              "clocks", file=sys.stderr)
     return {"traceEvents": events, "displayTimeUnit": "ms",
             "otherData": {"lanes": {str(k): v
                                     for k, v in sorted(lanes.items())},
-                          "empty": empty}}
+                          "empty": empty, "unaligned": unaligned}}
 
 
 def census(merged: dict) -> dict[int, int]:
