@@ -118,7 +118,9 @@ def _topology_step(cost_fn, feed_fn, optimizer=None, compute_dtype=None,
     step = build_train_step(
         topo, opt,
         compute_dtype=jnp.bfloat16 if compute_dtype is None else compute_dtype)
-    feed = feed_fn()
+    # resident on the device, as a placed feed is: DataFeeder hands out
+    # host arrays, and a host feed would cross again on every call
+    feed = jax.device_put(feed_fn())
     key = jax.random.key(0)
     state = {"p": params, "o": opt_state, "s": states}
 

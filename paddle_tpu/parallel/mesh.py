@@ -92,7 +92,13 @@ class MeshContext:
         return NamedSharding(self.mesh, P(*axes))
 
     def shard_batch(self, tree, remainder: str = "error"):
-        """Place a feed pytree with batch-dim sharding (device_put is async).
+        """Place a feed pytree with batch-dim sharding.  A host leaf (what
+        ``DataFeeder`` makes) goes shard by shard straight to the devices
+        that hold it: one transfer each, all started by this one
+        ``device_put``, which returns before they are done and leaves them
+        to run side by side (four chips took 616 MB in 27 ms, one chip
+        154 MB in 17 ms; PERF.md, PR 25).  Nothing passes through the
+        default device.  A device leaf is resharded from where it is.
 
         ``remainder`` is the partial-batch policy: "error" (default)
         keeps the strict divisibility check below; "drop"/"pad" first run

@@ -10,7 +10,7 @@ badput buckets:
 
 ``input_wait``
     the trainer blocked on the feed (``feed`` spans — the consumer-side
-    wait, NOT the prefetch producer thread's ``prefetch`` /
+    wait, NOT the prefetch threads' ``prefetch`` /
     ``feed_read`` / ``feed_convert`` / ``feed_place`` / ``feed_stage``,
     which overlap compute);
 ``fence``
@@ -69,9 +69,9 @@ import threading
 # span name -> badput bucket.  The ledger counts each wall-clock second
 # of the TRAIN LOOP's thread once, from the span that blocked it, so
 # deliberately absent are: parent spans ("step", "elastic", "request");
-# the producer thread's spans, which overlap compute ("prefetch" and its
-# children "feed_read" / "feed_convert" / "feed_place" / "feed_stage" —
-# with prefetch off the first three are children of "feed", which
+# the prefetch threads' spans, which overlap compute ("prefetch",
+# "feed_read" / "feed_convert" / "feed_place" / "feed_stage" — with
+# prefetch off the three in the middle are children of "feed", which
 # already books their time as input_wait); and the serving engine's
 # ("serve_step", "serve_schedule", "serve_prefill", "serve_decode":
 # another loop's wall-clock, accounted per request by serving_costs).

@@ -19,14 +19,18 @@ Instrumented phase boundaries (all behind the same flag):
   self time is the loop's own Python (events, cost look-ups, heartbeats);
 - the feed pipeline (``reader/prefetch.py``) — one batch's production
   is ``feed_read`` (the pull from the reader iterator; ``examples``),
-  ``feed_convert`` (``DataFeeder`` + padding stats + remainder policy;
-  ``bytes``) and ``feed_place`` (``mesh.shard_batch``, the thread's
-  time, no fence; ``bytes``, ``shards``).  Under ``DevicePrefetcher``
-  they sit on the worker thread (their own lane: spans carry the thread
-  name) inside a ``prefetch`` parent (``staged``) together with
-  ``feed_stage``, the blocking ``put`` on the bounded queue, while the
-  main thread's ``feed`` stays a leaf (its wait on the queue).  With
-  ``prefetch=0`` the same three are children of ``feed`` itself;
+  ``feed_convert`` (``DataFeeder`` + padding stats + remainder policy,
+  host only; ``bytes``) and ``feed_place`` (``mesh.shard_batch``, the
+  one transfer; ``bytes``, ``shards``, ``from_host`` = the bytes that
+  were host arrays).  Under ``DevicePrefetcher`` ``feed_read`` and
+  ``feed_stage`` (the wait for a free slot) sit in the reader thread's
+  lane, and each pool worker's lane (spans carry the thread name) holds
+  one ``prefetch`` (``staged``, ``in_flight`` = units other workers had
+  in hand when it started) per batch around ``feed_convert`` and
+  ``feed_place``, which there is fenced and so runs to the end of the
+  transfer; the main thread's ``feed`` stays a leaf (its wait for the
+  next unit).  With ``prefetch=0`` the first three are children of
+  ``feed`` itself and nothing is fenced;
 - ``ServingEngine`` — one ``serve_step`` (``waiting``, ``active``) per
   engine iteration that did work, with ``serve_schedule`` children
   around the calls that build or change scheduler / KV-cache state and

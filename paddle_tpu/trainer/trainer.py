@@ -250,17 +250,22 @@ class SGD:
 
         Input overlap (``reader/prefetch.py``): with ``prefetch`` > 0
         (default: the ``prefetch_depth`` flag, 0 — synchronous, matching
-        v2; the CLI defaults to ``--prefetch=2``) a worker thread runs
-        ``DataFeeder.feed`` + ``mesh.shard_batch`` ahead of the step loop,
-        keeping up to ``prefetch`` device-resident feeds staged; 0 keeps
-        everything on the consumer thread with no read-ahead (feed
-        conversion then happens when the batch is pulled, just before
-        that batch's ``BeginIteration``).  The training trajectory is
+        v2; the CLI defaults to ``--prefetch=2``) one thread pulls the
+        reader, in order, and a pool of ``prefetch`` workers runs
+        ``DataFeeder.feed`` (one stack of the batch into host staging
+        arrays a worker reuses) + ``mesh.shard_batch`` (each shard
+        straight to its own device, fenced on the worker) ahead of the
+        step loop, keeping up to ``prefetch`` batches converting or
+        staged; they are handed over strictly in reader order.  0 keeps
+        everything on the consumer thread with no read-ahead (the same
+        feeder and placement, inline; feed conversion then happens when
+        the batch is pulled, just before that batch's
+        ``BeginIteration``).  The training trajectory is
         bit-identical either way (same batches, same RNG key order) — but
-        with ``prefetch`` > 0 the READER is consumed up to ``prefetch``
-        batches ahead on a worker thread, so a reader that must run in
+        with ``prefetch`` > 0 the READER is consumed up to ``prefetch`` + 1
+        batches ahead on its own thread, so a reader that must run in
         lockstep with the event stream (e.g. curriculum state mutated by
-        the event handler) or is not thread-safe should stay at 0.
+        the event handler) should stay at 0.
         Host-fed workloads should opt in (``prefetch=2`` or
         ``PADDLE_TPU_PREFETCH_DEPTH=2``) — it is the structural fix for
         the device idling through every Python-side feed conversion.

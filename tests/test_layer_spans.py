@@ -3,10 +3,12 @@
 (``serving/engine.py``), and their mirrors on the profiler's clock
 (``telemetry/tracing.py``).
 
-What holds: a batch's production is ``prefetch`` ⊃ ``feed_read`` /
-``feed_convert`` / ``feed_place`` / ``feed_stage`` on the worker thread
-(the same three names under ``feed`` with prefetch off), with
-``examples`` / ``bytes`` / ``shards`` stated and the children summing to
+What holds: a batch's production is ``feed_read`` and ``feed_stage`` on
+the prefetcher's reader thread and one ``prefetch`` (``staged``,
+``in_flight``) ⊃ ``feed_convert`` / ``feed_place`` on a pool worker, each
+worker in a lane of its own (``feed_read`` / ``feed_convert`` /
+``feed_place`` under ``feed`` with prefetch off), with ``examples`` /
+``bytes`` / ``shards`` / ``from_host`` stated and the children summing to
 the parent on a fake clock; an engine iteration that worked is
 ``serve_step`` ⊃ ``serve_schedule`` / ``serve_prefill`` /
 ``serve_decode`` with the two batch spans still leaves and
@@ -43,10 +45,20 @@ WORKER = "paddle-tpu-prefetch"
 
 
 class _Clock:
-    """A clock that only the instrumented work advances."""
+    """A clock that only the instrumented work advances — each thread's
+    own: a span opens and closes on one thread, so what another thread
+    does meanwhile cannot stretch it."""
 
     def __init__(self):
-        self.t = 100.0
+        self._local = threading.local()
+
+    @property
+    def t(self):
+        return getattr(self._local, "t", 100.0)
+
+    @t.setter
+    def t(self, value):
+        self._local.t = value
 
     def __call__(self):
         return self.t
@@ -105,42 +117,103 @@ def _timed_pipeline(clk, batches=3, rows=6):
 # -- the feed pipeline ---------------------------------------------------------
 
 
-def test_worker_spans_nest_under_prefetch_and_sum_to_it(tracer, monkeypatch):
+def test_worker_spans_nest_under_prefetch_and_sum_to_it(tracer):
     clk = _Clock()
     tracer.configure(clock=clk)
-    real_put = prefetch_mod._guarded_put
-
-    def slow_put(q, item, stop):
-        if isinstance(item, tuple):     # a staged feed, not a sentinel
-            clk.t += 7.0
-        return real_put(q, item, stop)
-
-    monkeypatch.setattr(prefetch_mod, "_guarded_put", slow_put)
     reader, feeder = _timed_pipeline(clk)
     with DevicePrefetcher(reader, feeder, _Mesh(clk), depth=2) as feeds:
         got = list(feeds)
     assert [fb.examples for fb in got] == [6, 6, 6]
     spans = tracer.spans
-    assert {s.thread for s in spans} == {WORKER}
     by = _by_name(spans)
-    assert len(by["prefetch"]) == 3     # the end-of-stream pull is cancelled
+    # the reader thread's lane: the pulls and the waits for a free slot
+    # (the end-of-stream pull is cancelled)
+    assert len(by["feed_read"]) == len(by["feed_stage"]) == 3
+    for s in by["feed_read"] + by["feed_stage"]:
+        assert s.thread == WORKER and s.parent_id is None
+    assert [s.dur_ms for s in by["feed_read"]] == [2e3] * 3
+    assert all(s.args == {"examples": 6} for s in by["feed_read"])
+    assert all(s.args == {} for s in by["feed_stage"])
+    # a worker's lane: one prefetch a unit, its two phases inside
+    assert len(by["prefetch"]) == 3
+    nbytes = 6 * 4 * 4 + 6 * 4      # x float32 [6,4] + y int32 [6]
     for parent in by["prefetch"]:
+        assert parent.thread.startswith(WORKER + "_")
         kids = sorted((s for s in spans if s.parent_id == parent.span_id),
                       key=lambda s: s.t_start)
-        assert [k.name for k in kids] == ["feed_read", "feed_convert",
-                                          "feed_place", "feed_stage"]
-        assert [k.dur_ms for k in kids] == [2e3, 3e3, 5e3, 7e3]
-        assert sum(k.dur_ms for k in kids) == parent.dur_ms == 17e3
+        assert [k.name for k in kids] == ["feed_convert", "feed_place"]
+        assert {k.thread for k in kids} == {parent.thread}
+        assert [k.dur_ms for k in kids] == [3e3, 5e3]
+        assert sum(k.dur_ms for k in kids) == parent.dur_ms == 8e3
         assert kids[0].t_start == parent.t_start
         assert kids[-1].t_end == parent.t_end
-        read, convert, place, stage = kids
-        nbytes = 6 * 4 * 4 + 6 * 4      # x float32 [6,4] + y int32 [6]
-        assert read.args == {"examples": 6}
+        convert, place = kids
         assert convert.args == {"bytes": nbytes}
-        assert place.args == {"bytes": nbytes, "shards": 4}
-        assert stage.args == {}
-        assert "staged" in parent.args
+        assert place.args == {"bytes": nbytes, "shards": 4,
+                              "from_host": nbytes}
+        assert set(parent.args) == {"staged", "in_flight"}
     assert all(s.cat == "reader" for s in spans)
+
+
+def test_concurrent_units_land_in_their_own_lanes(tracer):
+    """Two units in hand at once: each worker's spans nest in its own
+    lane, and ``in_flight`` says how many others were in hand when a
+    unit started (0 on every span would mean nothing ever overlapped)."""
+    both = threading.Barrier(2, timeout=30)
+
+    def reader():
+        for b in range(4):
+            yield [(np.full((4,), b, np.float32), b)] * 2
+
+    def feeder(batch):
+        if batch[0][1] < 2:
+            both.wait()         # units 0 and 1 are in hand together
+        return {"x": np.stack([r[0] for r in batch])}
+
+    with DevicePrefetcher(reader, feeder, depth=2) as feeds:
+        got = list(feeds)
+    assert [int(fb.feed["x"][0, 0]) for fb in got] == [0, 1, 2, 3]
+    spans = tracer.spans
+    units = _by_name(spans)["prefetch"]
+    assert len(units) == 4
+    first_two = sorted(units, key=lambda s: s.t_start)[:2]
+    assert sorted(s.args["in_flight"] for s in first_two) == [0, 1]
+    assert len({s.thread for s in first_two}) == 2
+    assert all(0 <= s.args["in_flight"] <= 1 and 0 <= s.args["staged"] <= 1
+               for s in units)
+    for unit in units:
+        kids = [s for s in spans if s.parent_id == unit.span_id]
+        assert [k.name for k in kids] == ["feed_convert"]   # no mesh
+        assert kids[0].thread == unit.thread
+        assert kids[0].args == {"bytes": 2 * 4 * 4}
+
+
+def test_from_host_tells_a_host_feed_from_one_placed_again(tracer):
+    """``feed_place.from_host`` is the bytes that were host arrays when
+    they were placed; a feeder that hands over device arrays (the hop
+    through the default device) reads 0."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.layers import data_type
+    from paddle_tpu.parallel.mesh import MeshContext, make_mesh
+    from paddle_tpu.reader.feeder import DataFeeder
+
+    mesh = MeshContext(make_mesh({"data": 4}))
+
+    def reader():
+        yield [(np.ones((6,), np.float32), 1)] * 8
+
+    feeder = DataFeeder({"x": data_type.dense_vector(6),
+                         "y": data_type.integer_value(4)})
+    for feed_fn, want in ((feeder, 8 * 6 * 4 + 8 * 4),
+                          (lambda b: {k: jnp.asarray(v)
+                                      for k, v in feeder(b).items()}, 0)):
+        tracer.clear()
+        with DevicePrefetcher(reader, feed_fn, mesh, depth=2) as feeds:
+            assert len(list(feeds)) == 1
+        (place,) = _by_name(tracer.spans)["feed_place"]
+        assert place.args == {"bytes": 8 * 6 * 4 + 8 * 4, "shards": 4,
+                              "from_host": want}
 
 
 def test_synchronous_feeds_record_the_same_three_names(tracer):
@@ -174,7 +247,8 @@ def test_a_dropped_batch_has_no_place_span(tracer):
     by = _by_name(tracer.spans)
     assert len(by["feed_read"]) == 2 and len(by["feed_convert"]) == 2
     assert len(by["feed_place"]) == 1
-    assert by["feed_place"][0].args == {"bytes": 8 * 4 * 4, "shards": 4}
+    assert by["feed_place"][0].args == {"bytes": 8 * 4 * 4, "shards": 4,
+                                        "from_host": 8 * 4 * 4}
 
 
 def _tiny_trainer():
@@ -239,12 +313,22 @@ def test_trainer_prefetch_path_keeps_feed_a_leaf(tracer):
     # the ledger's idle gaps are named after this leaf on the main thread
     assert all(s.span_id not in parents and s.thread == "MainThread"
                for s in by["feed"])
-    batches = {s.span_id for s in by["prefetch"]}
-    assert len(batches) == 4
-    for name in ("feed_read", "feed_convert", "feed_place", "feed_stage"):
+    units = {s.span_id: s for s in by["prefetch"]}
+    assert len(units) == 4
+    for name in ("feed_read", "feed_stage"):    # the reader thread's lane
         assert len(by[name]) == 4
-        assert all(s.parent_id in batches and s.thread == WORKER
+        assert all(s.parent_id is None and s.thread == WORKER
                    for s in by[name])
+    for name in ("feed_convert", "feed_place"):     # a worker's lane
+        assert len(by[name]) == 4
+        assert all(s.parent_id in units
+                   and s.thread == units[s.parent_id].thread
+                   and s.thread.startswith(WORKER + "_") for s in by[name])
+    # px float32 [8,6] + py int32 [8]: still the whole batch's host bytes
+    nbytes = 8 * 6 * 4 + 8 * 4
+    assert all(s.args == {"bytes": nbytes} for s in by["feed_convert"])
+    assert all(s.args["from_host"] == s.args["bytes"] == nbytes
+               for s in by["feed_place"])
     for p in by["prefetch"]:
         kids = [s for s in spans if s.parent_id == p.span_id]
         assert all(p.t_start <= k.t_start and k.t_end <= p.t_end

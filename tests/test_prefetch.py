@@ -112,6 +112,233 @@ def test_prefetcher_as_context_manager_drains_on_early_exit():
     assert not pf._thread.is_alive()
 
 
+# -- the pooled prefetcher: order, placement, errors, shutdown, bounds ----------
+
+def _image_feeder():
+    from paddle_tpu.layers import data_type
+
+    return DataFeeder({"img": data_type.dense_vector(3 * 8 * 8),
+                       "lbl": data_type.integer_value(1000)})
+
+
+def _image_reader(batches=14, batch=8, fail_at=None):
+    """``batches`` batches of ``batch`` samples (a [3, 8, 8] float32 row
+    and a label), every value distinct across the whole stream; raises
+    in place of batch ``fail_at``."""
+    def reader():
+        for b in range(batches):
+            if b == fail_at:
+                raise ValueError(f"reader failed at batch {b}")
+            base = float(b * batch)
+            yield [(np.arange(192, dtype=np.float32).reshape(3, 8, 8)
+                    + 1000.0 * (base + i), int(base + i))
+                   for i in range(batch)]
+    return reader
+
+
+def _mesh_on(devices):
+    return MeshContext(make_mesh({"data": len(devices)}, devices=devices))
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+@pytest.mark.parametrize("depth", [1, 3])
+def test_pooled_feeds_bit_identical_in_order_on_their_devices(n_dev, depth):
+    """Staging arrays are refilled while earlier feeds are still alive:
+    fourteen distinct batches come out of the pool exactly as the inline
+    path makes them, in reader order, every shard on its mesh device
+    (none on device 0, which this mesh leaves out)."""
+    import jax
+
+    devices = jax.devices()[1:1 + n_dev]
+    ctx = _mesh_on(devices)
+    want = list(SynchronousFeeds(_image_reader(), _image_feeder(), ctx))
+    with DevicePrefetcher(_image_reader(), _image_feeder(), ctx,
+                          depth=depth) as pf:
+        got = list(pf)      # all alive at once: no staging array recycled
+    assert len(got) == len(want) == 14
+    for g, w in zip(got, want):
+        assert g.examples == w.examples == 8
+        for name in ("img", "lbl"):
+            assert g.feed[name].dtype == w.feed[name].dtype
+            np.testing.assert_array_equal(np.asarray(g.feed[name]),
+                                          np.asarray(w.feed[name]))
+            shards = g.feed[name].addressable_shards
+            assert [s.device for s in shards] == list(devices)
+            rows = 8 // n_dev
+            for k, sh in enumerate(shards):
+                np.testing.assert_array_equal(
+                    np.asarray(sh.data),
+                    np.asarray(w.feed[name])[k * rows:(k + 1) * rows])
+    assert [int(g.feed["lbl"][0]) for g in got] == list(range(0, 112, 8))
+
+
+@pytest.mark.parametrize("who", ["reader", "feeder"])
+@pytest.mark.parametrize("k", [0, 1, 7])
+def test_an_error_at_batch_k_surfaces_after_exactly_k_good_batches(who, k):
+    ctx = _mesh_on(__import__("jax").devices()[:2])
+    inner = _image_feeder()
+
+    def feeder(batch):
+        if who == "feeder" and batch[0][1] == 8 * k:
+            raise ValueError(f"feeder failed at batch {k}")
+        return inner(batch)
+
+    pf = DevicePrefetcher(
+        _image_reader(fail_at=k if who == "reader" else None), feeder, ctx,
+        depth=3)
+    good = []
+    with pytest.raises(ValueError, match=f"{who} failed at batch {k}"):
+        for fb in pf:
+            good.append(int(fb.feed["lbl"][0]))
+    assert good == [8 * i for i in range(k)]
+    with pytest.raises(StopIteration):      # terminal, and nothing hangs
+        next(pf)
+    assert not pf._thread.is_alive()
+
+
+def _wait_until(cond, timeout=10.0):
+    deadline = time.monotonic() + timeout
+    while not cond() and time.monotonic() < deadline:
+        time.sleep(0.005)
+    return cond()
+
+
+def test_close_with_units_in_flight_returns_within_its_deadline(monkeypatch):
+    from paddle_tpu.reader import prefetch as prefetch_mod
+
+    monkeypatch.setattr(prefetch_mod, "CLOSE_DEADLINE_S", 0.5)
+    before = threading.active_count()
+    entered, release = threading.Semaphore(0), threading.Event()
+    inner = _image_feeder()
+
+    def feeder(batch):
+        entered.release()
+        release.wait(30)
+        return inner(batch)
+
+    pf = DevicePrefetcher(_image_reader(batches=1000), feeder,
+                          _mesh_on(__import__("jax").devices()[:2]), depth=2)
+    assert entered.acquire(timeout=10) and entered.acquire(timeout=10)
+    t0 = time.monotonic()
+    pf.close()      # both workers are still inside the feeder
+    assert time.monotonic() - t0 < 2.0
+    with pytest.raises(StopIteration):
+        next(pf)
+    release.set()
+    assert _wait_until(lambda: threading.active_count() <= before), \
+        "the pool outlived close()"
+    assert not pf._thread.is_alive()
+
+
+def test_rebind_mesh_with_units_in_flight_replaces_every_feed():
+    """Feeds staged or mid-conversion under the old mesh come out on the
+    new one: in order, nothing lost, nothing left on the old devices."""
+    import jax
+
+    old, new = jax.devices()[:4], jax.devices()[4:6]
+    rebound = threading.Event()
+    inner = _image_feeder()
+
+    def feeder(batch):
+        if batch[0][1] == 8:    # batch 1 waits for the rebind: placed on
+            rebound.wait(30)    # the old mesh all the same
+        return inner(batch)
+
+    with DevicePrefetcher(_image_reader(batches=6), feeder, _mesh_on(old),
+                          depth=2) as pf:
+        first = next(pf)
+        assert [s.device for s in first.feed["img"].addressable_shards] \
+            == list(old)
+        pf.rebind_mesh(_mesh_on(new))
+        rebound.set()
+        rest = list(pf)
+    assert [int(fb.feed["lbl"][0]) for fb in rest] == [8, 16, 24, 32, 40]
+    want = list(SynchronousFeeds(_image_reader(batches=6), inner,
+                                 _mesh_on(new)))[1:]
+    for g, w in zip(rest, want):
+        assert [s.device for s in g.feed["img"].addressable_shards] \
+            == list(new)
+        np.testing.assert_array_equal(np.asarray(g.feed["img"]),
+                                      np.asarray(w.feed["img"]))
+
+
+@pytest.mark.parametrize("depth", [1, 2, 4])
+def test_in_flight_plus_staged_never_exceeds_depth(depth):
+    """The stated bound: units converting + units staged <= depth (with
+    the one the consumer holds: depth + 1 feeds a device), whether the
+    consumer is the slow side or the workers are."""
+    inner = _image_feeder()
+    lock = threading.Lock()
+    seen = {"now": 0, "max_converting": 0, "max_total": 0, "read": 0}
+    pf = None
+
+    def reader():
+        for batch in _image_reader(batches=24)():
+            with lock:
+                seen["read"] += 1
+            yield batch
+
+    def feeder(batch):
+        with lock:
+            seen["now"] += 1
+            seen["max_converting"] = max(seen["max_converting"], seen["now"])
+        time.sleep(0.002)
+        try:
+            return inner(batch)
+        finally:
+            with lock:
+                seen["now"] -= 1
+
+    taken = 0
+    with DevicePrefetcher(reader, feeder,
+                          _mesh_on(__import__("jax").devices()[:2]),
+                          depth=depth) as pf:
+        for fb in pf:
+            taken += 1
+            if taken % 6 == 0:
+                time.sleep(0.05)    # the pipeline fills behind us
+            with pf._count_lock:
+                total = pf._in_flight + pf._staged
+            with lock:
+                seen["max_total"] = max(seen["max_total"], total)
+                # read-ahead: what is converting or staged, and the one
+                # batch the reader thread holds while it waits for a slot
+                assert seen["read"] <= taken + depth + 1
+    assert taken == 24
+    assert seen["max_converting"] <= depth
+    assert 1 <= seen["max_total"] <= depth
+    if depth > 1:
+        assert seen["max_converting"] > 1   # the overlap engages
+
+
+def test_settle_keeps_only_staging_the_feed_does_not_live_in():
+    """After ``_settle`` whatever is left in the staging dict can be
+    overwritten without touching the placed feed — also where the CPU
+    backend took an aligned host buffer as the device buffer."""
+    import jax
+
+    from paddle_tpu.reader.prefetch import _settle
+
+    ctx = _mesh_on(jax.devices()[:2])
+    raw = np.zeros(4 * 1024 + 64, np.float32)
+    off = (-raw.ctypes.data % 64) // 4
+    aligned = raw[off:off + 4096].reshape(8, 512)       # may be adopted
+    skewed = raw[off + 1:off + 1 + 2048].reshape(8, 256)  # must be copied
+    aligned[:] = np.arange(4096, dtype=np.float32).reshape(8, 512)
+    skewed[:] = -np.arange(2048, dtype=np.float32).reshape(8, 256)
+    host_only = np.ones((8, 2), np.float32)
+    staging = {"a": aligned, "s": skewed, "h": host_only}
+    feed = ctx.shard_batch({"a": aligned, "s": skewed})
+    feed["h"] = host_only           # a leaf that never left the host
+    want = {k: np.array(v) for k, v in feed.items()}
+    _settle(staging, feed)
+    assert "h" not in staging and "s" in staging
+    for buf in staging.values():
+        buf.fill(7.0)
+    for k in feed:
+        np.testing.assert_array_equal(np.asarray(feed[k]), want[k])
+
+
 # -- deferred fence + overlap through SGD.train -------------------------------
 
 def _run_train(sync_period, prefetch, n_samples=64, batch=8, passes=2):
@@ -198,8 +425,9 @@ def test_default_config_keeps_seed_feed_conversion_order(monkeypatch):
         trace = []
         monkeypatch.setattr(
             DataFeeder, "feed",
-            lambda self, batch: (trace.append("convert"),
-                                 orig_feed(self, batch))[1])
+            lambda self, batch, *staging: (
+                trace.append("convert"),
+                orig_feed(self, batch, *staging))[1])
 
         def handler(e):
             if type(e).__name__ == "BeginIteration":
@@ -523,6 +751,132 @@ def test_feeder_uniform_sequence_fast_path_matches_ragged():
                                   [[1, 2, 3], [4, 5, 6], [7, 8, 9]])
     np.testing.assert_array_equal(np.asarray(fast.length), [3, 3, 3])
     assert fast.data.dtype == slow.data.dtype
+
+
+# -- the feeder's contract: host arrays out, placed once ------------------------
+
+def _feeder_cases():
+    from paddle_tpu.layers import data_type as dt
+
+    rows = [np.arange(6, dtype=np.float64) + i for i in range(4)]
+    return {
+        "dense": (dt.dense_vector(6), [(r,) for r in rows],
+                  np.stack(rows).astype(np.float32)),
+        "dense_nested_lists": (
+            dt.dense_vector(6), [([[1, 2, 3], [4, 5, 6]],)] * 4,
+            np.tile(np.arange(1, 7, dtype=np.float32), (4, 1))),
+        "integer": (dt.integer_value(10), [(3,), (np.int64(1),), (4,), (1,)],
+                    np.asarray([3, 1, 4, 1], np.int32)),
+        "sparse_binary": (dt.sparse_binary_vector(5),
+                          [([0, 2],), ([],), ([4],), ([1, 1],)],
+                          np.asarray([[1, 0, 1, 0, 0], [0, 0, 0, 0, 0],
+                                      [0, 0, 0, 0, 1], [0, 1, 0, 0, 0]],
+                                     np.float32)),
+        "sparse_float": (dt.sparse_float_vector(4),
+                         [([(0, .5)],), ([(3, 2.)],), ([],), ([(1, -1.)],)],
+                         np.asarray([[.5, 0, 0, 0], [0, 0, 0, 2.],
+                                     [0, 0, 0, 0], [0, -1., 0, 0]],
+                                    np.float32)),
+    }
+
+
+@pytest.mark.parametrize("kind", sorted(_feeder_cases()))
+def test_feeder_non_sequence_kinds_return_host_arrays(kind):
+    itype, batch, want = _feeder_cases()[kind]
+    got = DataFeeder({"s": itype}).feed(batch)["s"]
+    assert type(got) is np.ndarray      # not a device array
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("kind", ["integer", "dense"])
+def test_feeder_uniform_sequence_path_returns_host_arrays(kind):
+    from paddle_tpu.layers import data_type as dt
+
+    if kind == "integer":
+        itype, seqs, dtype = dt.integer_value_sequence(100), \
+            [[1, 2, 3], [4, 5, 6]], np.int32
+    else:
+        itype, seqs, dtype = dt.dense_vector_sequence(2), \
+            [[[1., 2.]] * 3, [[3., 4.]] * 3], np.float32
+    got = DataFeeder({"w": itype}).feed([(q,) for q in seqs])["w"]
+    assert type(got.data) is np.ndarray and type(got.length) is np.ndarray
+    assert got.data.dtype == dtype and got.length.dtype == np.int32
+    assert got.data.shape[:2] == (2, 16)    # bucket-padded as before
+    np.testing.assert_array_equal(got.data[:, :3], np.asarray(seqs, dtype))
+    assert not got.data[:, 3:].any()
+    np.testing.assert_array_equal(got.length, [3, 3])
+
+
+def test_feeder_staging_is_refilled_only_where_the_shape_still_fits():
+    from paddle_tpu.layers import data_type as dt
+
+    feeder = DataFeeder({"img": dt.dense_vector(12),
+                         "lbl": dt.integer_value(10)})
+
+    def batch(n, base):
+        return [(np.full((3, 4), base + i, np.float64), i) for i in range(n)]
+
+    staging: dict = {}
+    first = feeder.feed(batch(4, 10.0), staging)
+    assert set(staging) == {"img"}      # python ints are not staged
+    buf = staging["img"]
+    assert buf.shape == (4, 3, 4) and np.shares_memory(first["img"], buf)
+    second = feeder.feed(batch(4, 50.0), staging)
+    assert staging["img"] is buf and np.shares_memory(second["img"], buf)
+    assert second["img"].dtype == np.float32
+    np.testing.assert_array_equal(
+        second["img"], feeder.feed(batch(4, 50.0))["img"])
+    third = feeder.feed(batch(3, 70.0), staging)    # a partial last batch
+    assert staging["img"] is not buf and third["img"].shape == (3, 12)
+    np.testing.assert_array_equal(
+        third["img"], feeder.feed(batch(3, 70.0))["img"])
+    # without staging every call owns its arrays
+    assert not np.shares_memory(feeder.feed(batch(4, 1.0))["img"],
+                                feeder.feed(batch(4, 1.0))["img"])
+
+
+def test_host_feed_on_a_mesh_without_device_0_leaves_nothing_there():
+    import jax
+
+    dev0 = jax.devices()[0]
+    ctx = _mesh_on(jax.devices()[2:4])
+    batch = next(iter(_image_reader(batches=1)()))
+    before = {id(a) for a in jax.live_arrays()}
+    out = ctx.shard_batch(_image_feeder()(batch))
+    jax.block_until_ready(out)
+    new = [a for a in jax.live_arrays() if id(a) not in before]
+    assert len(new) >= 2
+    assert all(dev0 not in a.devices() for a in new)
+    assert [s.device for s in out["img"].addressable_shards] \
+        == jax.devices()[2:4]
+
+
+def test_inference_and_sgd_test_run_from_host_feeds():
+    """Callers without a prefetcher: ``paddle.infer`` hands the feeder's
+    host arrays to ``jit``, ``SGD.test`` places them inline."""
+    from paddle_tpu.layers import activation as act
+    from paddle_tpu.layers import api as layer
+    from paddle_tpu.layers import base, data_type
+
+    base.reset_name_counters()
+    x = layer.data(name="px", type=data_type.dense_vector(6))
+    h = layer.fc(input=x, size=4, act=act.SoftmaxActivation())
+    lbl = layer.data(name="py", type=data_type.integer_value(4))
+    cost = layer.classification_cost(input=h, label=lbl)
+    parameters = paddle.parameters.create(paddle.topology.Topology(cost))
+    trainer = paddle.trainer.SGD(
+        cost=cost, parameters=parameters,
+        update_equation=paddle.optimizer.SGD(learning_rate=0.05))
+    trainer.train(reader=_batches(16, 8), num_passes=1)
+    rng = np.random.default_rng(2)
+    xs = [(rng.normal(size=(6,)).astype(np.float32),) for _ in range(8)]
+    probs = paddle.infer(output_layer=h, parameters=trainer.parameters,
+                         input=xs)
+    assert probs.shape == (8, 4) and np.all(np.isfinite(probs))
+    np.testing.assert_allclose(probs.sum(axis=1), 1.0, rtol=1e-5)
+    res = trainer.test(reader=_batches(16, 8))
+    assert np.isfinite(res.cost)
 
 
 @pytest.mark.slow
