@@ -351,9 +351,10 @@ def memory_report(params, opt_state, states, feed, mesh=None, *,
 
 def serving_memory_report(cfg, serving, params=None, cache=None) -> dict:
     """Static per-device byte accounting of the SERVING path: the paged
-    KV pool (k AND v, each ``layers × heads × pages × page_size ×
-    head_dim`` at the model dtype) next to the servable params — the
-    same artifact :func:`memory_report` computes for training, so an
+    KV pool (k AND v, each ``cache layers × heads × pages × page_size ×
+    head_dim`` at the model dtype; cache layers = ``num_layers ×
+    loop_steps``: a looped stack keeps one cache per pass) next to the
+    servable params — the same artifact :func:`memory_report` computes for training, so an
     oversized pool is a preflight failure, not an OOM at the first
     admission.  ``cfg`` is a TransformerConfig, ``serving`` a
     ``ServingConfig``; ``params`` (optional pytree) adds the weights.
@@ -367,7 +368,7 @@ def serving_memory_report(cfg, serving, params=None, cache=None) -> dict:
     import numpy as np
 
     itemsize = int(np.dtype(cfg.dtype).itemsize)
-    per_pool = (int(cfg.num_layers) * int(cfg.num_heads)
+    per_pool = (int(cfg.cache_layers) * int(cfg.num_heads)
                 * int(serving.num_pages) * int(serving.page_size)
                 * int(cfg.head_dim) * itemsize)
     kv = 2 * per_pool  # k and v pools

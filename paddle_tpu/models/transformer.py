@@ -65,10 +65,53 @@ class TransformerConfig:
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 1e-2
     moe_dispatch: str = "sort"  # "einsum" = dense one-hot GShard tensors
+    # -- the block's parts; the defaults are the GPT-2 block -------------
+    # width of one attention head; None = embed_dim // num_heads
+    head_dim: int | None = None
+    # "layer" (scale + bias) | "rms" (scale only); ``norm_sandwich`` adds a
+    # second norm on each branch's OUTPUT, before the residual add
+    norm: str = "layer"
+    norm_eps: float = 1e-5
+    norm_sandwich: bool = False
+    # "learned" (a [max_seq_len, E] table added to the embedding) |
+    # "rotary" (rotate-half RoPE on q and k at ``rope_theta``; no table,
+    # so max_seq_len costs nothing)
+    positions: str = "learned"
+    rope_theta: float = 1e4
+    # "gelu" (w_in/w_out with biases) | "swiglu" (gate/up/down, no bias)
+    mlp: str = "gelu"
+    tie_embeddings: bool = True  # False: a separate [E, V] "head"
+    # looped stack: the SAME num_layers weights run ``loop_steps`` times,
+    # the final norm closing every pass; pass t's K/V of layer l live in
+    # cache layer t * num_layers + l (``cache_layers`` pools).  The exit
+    # gate's parameters (exit_w, exit_b) live in the tree; at threshold 1
+    # no token leaves early and the served path never evaluates them.
+    loop_steps: int = 1
+    early_exit_threshold: float = 1.0
+
+    def __post_init__(self):
+        if self.head_dim is None:
+            object.__setattr__(self, "head_dim",
+                               self.embed_dim // self.num_heads)
+        for field, allowed in (("norm", ("layer", "rms")),
+                               ("positions", ("learned", "rotary")),
+                               ("mlp", ("gelu", "swiglu"))):
+            if getattr(self, field) not in allowed:
+                raise ValueError(f"{field} must be one of {allowed}, got "
+                                 f"{getattr(self, field)!r}")
+        if self.loop_steps < 1:
+            raise ValueError(f"loop_steps must be >= 1, got {self.loop_steps}")
+        if self.early_exit_threshold < 1.0:
+            raise NotImplementedError(
+                f"early_exit_threshold {self.early_exit_threshold} < 1 lets "
+                "a token leave the loop before its last pass: a step count "
+                "that varies by token, which neither the scans here nor the "
+                "scheduler's equal-work-per-token batches have")
 
     @property
-    def head_dim(self) -> int:
-        return self.embed_dim // self.num_heads
+    def cache_layers(self) -> int:
+        """K/V cache layers a served token occupies: one per (pass, layer)."""
+        return self.num_layers * self.loop_steps
 
     @property
     def moe(self):
@@ -84,44 +127,67 @@ class TransformerConfig:
 
 
 def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
-    """Stacked-layer params: block weights have leading dim num_layers."""
+    """Stacked-layer params: block weights have leading dim num_layers.
+    Which leaves exist follows the config's parts (norm biases only under
+    "layer", ``pos_embed`` only under "learned" positions, ``head`` only
+    when untied, the exit gate only for a looped stack)."""
     e, h, m, v_sz = cfg.embed_dim, cfg.num_heads * cfg.head_dim, cfg.mlp_dim, cfg.vocab_size
     s = cfg.num_layers
     k = iter(jax.random.split(key, 14))
     norm = lambda *shape: jax.random.normal(next(k), shape, cfg.dtype)
+    zeros = lambda *shape: jnp.zeros(shape, cfg.dtype)
     if cfg.moe_experts:
         ex = cfg.moe_experts
         ffn = {
             "wg": norm(s, e, ex) * (e ** -0.5),
             "w1": norm(s, ex, e, m) * (2.0 / e) ** 0.5,
-            "b1": jnp.zeros((s, ex, m), cfg.dtype),
+            "b1": zeros(s, ex, m),
             "w2": norm(s, ex, m, e) * (m ** -0.5) / (2 * s) ** 0.5,
-            "b2": jnp.zeros((s, ex, e), cfg.dtype),
+            "b2": zeros(s, ex, e),
+        }
+    elif cfg.mlp == "swiglu":
+        ffn = {
+            "w_gate": norm(s, e, m) * (e ** -0.5),
+            "w_up": norm(s, e, m) * (e ** -0.5),
+            "w_out": norm(s, m, e) * (m ** -0.5) / (2 * s) ** 0.5,
         }
     else:
         ffn = {
             "w_in": norm(s, e, m) * (e ** -0.5),
-            "b_in": jnp.zeros((s, m), cfg.dtype),
+            "b_in": zeros(s, m),
             "w_out": norm(s, m, e) * (m ** -0.5) / (2 * s) ** 0.5,
-            "b_out": jnp.zeros((s, e), cfg.dtype),
+            "b_out": zeros(s, e),
         }
-    return {
-        "embed": norm(v_sz, e) * (e ** -0.5),
-        "pos_embed": norm(cfg.max_seq_len, e) * 0.02,
-        "blocks": {
-            "ln1_g": jnp.ones((s, e), cfg.dtype),
-            "ln1_b": jnp.zeros((s, e), cfg.dtype),
-            "wq": norm(s, e, h) * (e ** -0.5),
-            "wk": norm(s, e, h) * (e ** -0.5),
-            "wv": norm(s, e, h) * (e ** -0.5),
-            "wo": norm(s, h, e) * (h ** -0.5) / (2 * s) ** 0.5,
-            "ln2_g": jnp.ones((s, e), cfg.dtype),
-            "ln2_b": jnp.zeros((s, e), cfg.dtype),
-            **ffn,
-        },
-        "ln_f_g": jnp.ones((e,), cfg.dtype),
-        "ln_f_b": jnp.zeros((e,), cfg.dtype),
+
+    def norm_p(name, *lead):
+        p = {f"{name}_g": jnp.ones((*lead, e), cfg.dtype)}
+        if cfg.norm == "layer":
+            p[f"{name}_b"] = zeros(*lead, e)
+        return p
+
+    params = {"embed": norm(v_sz, e) * (e ** -0.5)}
+    pos = norm(cfg.max_seq_len, e) * 0.02 if cfg.positions == "learned" \
+        else None
+    blocks = {
+        **norm_p("ln1", s),
+        "wq": norm(s, e, h) * (e ** -0.5),
+        "wk": norm(s, e, h) * (e ** -0.5),
+        "wv": norm(s, e, h) * (e ** -0.5),
+        "wo": norm(s, h, e) * (h ** -0.5) / (2 * s) ** 0.5,
+        **norm_p("ln2", s),
+        **ffn,
     }
+    if cfg.norm_sandwich:
+        blocks.update(**norm_p("ln1_post", s), **norm_p("ln2_post", s))
+    if pos is not None:
+        params["pos_embed"] = pos
+    params["blocks"] = blocks
+    params.update(norm_p("ln_f"))
+    if not cfg.tie_embeddings:
+        params["head"] = norm(e, v_sz) * (e ** -0.5)
+    if cfg.loop_steps > 1:
+        params["exit_w"], params["exit_b"] = zeros(e), zeros()
+    return params
 
 
 def param_shardings(cfg: TransformerConfig) -> dict:
@@ -139,21 +205,36 @@ def param_shardings(cfg: TransformerConfig) -> dict:
             "w2": P(None, "expert", None, None),
             "b2": P(None, "expert", None),
         }
+    elif cfg.mlp == "swiglu":
+        ffn = {"w_gate": col, "w_up": col, "w_out": row}
     else:
         ffn = {"w_in": col, "b_in": P(None, "model"),
                "w_out": row, "b_out": P()}
-    return {
+
+    def norm_p(name):
+        return {f"{name}_{x}": P()
+                for x in ("gb" if cfg.norm == "layer" else "g")}
+
+    specs = {
         "embed": P("model", None),  # vocab-sharded table (in-mesh pserver)
-        "pos_embed": P(),
         "blocks": {
-            "ln1_g": P(), "ln1_b": P(),
+            **norm_p("ln1"),
             "wq": col, "wk": col, "wv": col,
             "wo": row,
-            "ln2_g": P(), "ln2_b": P(),
+            **norm_p("ln2"),
             **ffn,
         },
-        "ln_f_g": P(), "ln_f_b": P(),
+        **norm_p("ln_f"),
     }
+    if cfg.norm_sandwich:
+        specs["blocks"].update(**norm_p("ln1_post"), **norm_p("ln2_post"))
+    if cfg.positions == "learned":
+        specs["pos_embed"] = P()
+    if not cfg.tie_embeddings:
+        specs["head"] = P(None, "model")
+    if cfg.loop_steps > 1:
+        specs["exit_w"], specs["exit_b"] = P(), P()
+    return specs
 
 
 def place_params(params: dict, mesh, cfg: TransformerConfig | None = None) -> dict:
@@ -173,6 +254,54 @@ def place_params(params: dict, mesh, cfg: TransformerConfig | None = None) -> di
 
 
 from paddle_tpu.ops.nn import layer_norm as _ln  # shared with the v2 path
+
+
+def _norm(cfg: TransformerConfig, x, p, name):
+    """The config's norm over the last axis with the parameters
+    ``p[name + "_g"]`` (and ``"_b"`` under "layer")."""
+    if cfg.norm == "rms":
+        xf = x.astype(jnp.float32)
+        xf = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                            + cfg.norm_eps)
+        return xf.astype(x.dtype) * p[name + "_g"]
+    return _ln(x, p[name + "_g"], p[name + "_b"], cfg.norm_eps)
+
+
+def _rope_table(cfg: TransformerConfig, positions):
+    """(cos, sin) [..., 1, head_dim] float32 for integer ``positions``
+    [...] — broadcast over the head axis of q/k [..., H, head_dim]."""
+    half = cfg.head_dim // 2
+    inv_freq = cfg.rope_theta ** (
+        -jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq
+    ang = jnp.concatenate([ang, ang], axis=-1)[..., None, :]
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def _rope(x, table):
+    """Rotate-half RoPE: dims (i, i + head_dim/2) are one pair."""
+    cos, sin = table
+    xf = x.astype(jnp.float32)
+    x1, x2 = jnp.split(xf, 2, axis=-1)
+    return (xf * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
+            ).astype(x.dtype)
+
+
+def _embed(cfg: TransformerConfig, params, ids, positions=None):
+    """Token states entering the stack, and the RoPE table of their
+    positions (None under learned positions, which are added here).
+    ``positions`` None = ids [B, T] sit at 0..T-1."""
+    x = params["embed"][ids]
+    if cfg.positions == "learned":
+        x = x + (params["pos_embed"][:ids.shape[1]][None]
+                 if positions is None else params["pos_embed"][positions])
+        return x, None
+    return x, _rope_table(cfg, jnp.arange(ids.shape[1])[None]
+                          if positions is None else positions)
+
+
+def _head(cfg: TransformerConfig, params, x):
+    return x @ (params["embed"].T if cfg.tie_embeddings else params["head"])
 
 
 def _attention(cfg: TransformerConfig, q, k, v, mesh):
@@ -225,8 +354,15 @@ def _attention(cfg: TransformerConfig, q, k, v, mesh):
     )
 
 
-def _block(cfg: TransformerConfig, mesh, x, layer, remat_dots=False):
-    """One pre-LN decoder block; x [B, T, E].
+def _block(cfg: TransformerConfig, x, layer, attend, rope=None, mesh=None,
+           remat_dots=False):
+    """THE decoder block; x [..., E] (training and prefill [B, T, E],
+    decode [B, E]).  ``attend(q, k, v) -> (a, kept)`` is the caller's
+    cache write and attention over q/k/v [..., H, Dh] (RoPE already
+    applied from ``rope``); ``kept`` is handed back untouched — the K/V a
+    prefill captures, the pools a chunk or decode pass updated, None in
+    training.  Returns (x, aux, kept); aux is the MoE load-balancing
+    loss, None for a dense FFN.
 
     ``remat_dots`` checkpoints the two dense segments with the
     dots-saveable policy while leaving the attention call OUTSIDE any
@@ -234,19 +370,33 @@ def _block(cfg: TransformerConfig, mesh, x, layer, remat_dots=False):
     (the flash kernel's log-sum-exp), so a whole-block checkpoint re-runs
     the flash forward in the backward scan — measured 9 ms/step at the
     124M bench shape."""
-    b, t, e = x.shape
+    lead = x.shape[:-1]
     nh, hd = cfg.num_heads, cfg.head_dim
 
     def qkv_fn(x, layer):
-        h = _ln(x, layer["ln1_g"], layer["ln1_b"])
-        q = (h @ layer["wq"]).reshape(b, t, nh, hd)
-        k = (h @ layer["wk"]).reshape(b, t, nh, hd)
-        v = (h @ layer["wv"]).reshape(b, t, nh, hd)
+        h = _norm(cfg, x, layer, "ln1")
+        q = (h @ layer["wq"]).reshape(*lead, nh, hd)
+        k = (h @ layer["wk"]).reshape(*lead, nh, hd)
+        v = (h @ layer["wv"]).reshape(*lead, nh, hd)
+        if rope is not None:
+            q, k = _rope(q, rope), _rope(k, rope)
         return q, k, v
 
+    def branch_out(x, y, bias, post):
+        """The residual add of one branch: under a sandwich the branch's
+        output (bias included) is normed first; otherwise the bias goes on
+        after the add, GPT-2's order of rounding."""
+        if cfg.norm_sandwich:
+            return x + _norm(cfg, y if bias is None else y + bias, layer,
+                             post)
+        x = x + y
+        return x if bias is None else x + bias
+
     def tail_fn(x, a, layer):
-        x = x + a.reshape(b, t, nh * hd) @ layer["wo"]
-        h = _ln(x, layer["ln2_g"], layer["ln2_b"])
+        x = branch_out(x, a.reshape(*lead, nh * hd) @ layer["wo"], None,
+                       "ln1_post")
+        h = _norm(cfg, x, layer, "ln2")
+        aux, bias = None, None
         if cfg.moe_experts:
             from paddle_tpu.parallel.moe import moe_ffn, moe_ffn_sharded
 
@@ -255,23 +405,62 @@ def _block(cfg: TransformerConfig, mesh, x, layer, remat_dots=False):
                 y, aux = moe_ffn_sharded(moe_p, h, cfg.moe, mesh)
             else:
                 y, aux = moe_ffn(moe_p, h, cfg.moe)
-            return x + y, aux
-        h = jax.nn.gelu(h @ layer["w_in"] + layer["b_in"])
-        return x + h @ layer["w_out"] + layer["b_out"], jnp.zeros(
-            (), jnp.float32)
+        elif cfg.mlp == "swiglu":
+            y = (jax.nn.silu(h @ layer["w_gate"]) * (h @ layer["w_up"])
+                 ) @ layer["w_out"]
+        else:
+            h = jax.nn.gelu(h @ layer["w_in"] + layer["b_in"])
+            y, bias = h @ layer["w_out"], layer["b_out"]
+        return branch_out(x, y, bias, "ln2_post"), aux
 
-    attn = functools.partial(_attention, cfg, mesh=mesh)
     if remat_dots:
         policy = jax.checkpoint_policies.dots_saveable
         qkv_fn = jax.checkpoint(qkv_fn, policy=policy)
         tail_fn = jax.checkpoint(tail_fn, policy=policy)
-        if cfg.attn_impl != "flash":
-            # non-custom-vjp impls would otherwise save O(T^2) softmax
-            # residuals per layer; recompute them in the backward instead
-            attn = jax.checkpoint(attn)
     q, k, v = qkv_fn(x, layer)
-    a = attn(q, k, v)
-    return tail_fn(x, a, layer)
+    a, kept = attend(q, k, v)
+    x, aux = tail_fn(x, a, layer)
+    return x, aux, kept
+
+
+def _run_stack(cfg: TransformerConfig, params, x, layer_fn, pools=(),
+               unroll=1):
+    """``loop_steps`` passes of the layer scan over the ONE stacked
+    ``blocks`` tree, the final norm closing each pass and feeding the
+    next.  ``layer_fn(x, layer, *layer_pools) -> (x, y)`` is the layer
+    scan's body.  ``pools`` are the K/V pools [cache_layers, ...]: pass t
+    reads and rewrites their rows t*L .. (t+1)*L, and ``y`` is the
+    layer's updated pools.  Returns (x, ys): the updated pools, or,
+    without pools, every (pass, layer)'s ``y`` stacked [cache_layers,
+    ...]."""
+    steps, layers = cfg.loop_steps, cfg.num_layers
+
+    def one_pass(x, pass_pools):
+        x, ys = lax.scan(lambda x, a: layer_fn(x, *a), x,
+                         (params["blocks"], *pass_pools), unroll=unroll)
+        return _norm(cfg, x, params, "ln_f"), ys
+
+    if steps == 1:  # no outer loop: today's program, op for op
+        return one_pass(x, pools)
+    if not pools:
+        x, ys = lax.scan(lambda x, _: one_pass(x, ()), x, None, length=steps)
+        return x, jax.tree.map(
+            lambda y: y.reshape(steps * layers, *y.shape[2:]), ys)
+
+    # the pools ride the outer loop's carry and each pass's rows are put
+    # back where they came from, so the loop updates them in place (as
+    # scanned operands they would be copied whole into a stacked result)
+    def carried_pass(carry, t):
+        x, whole = carry
+        x, rows = one_pass(x, tuple(
+            lax.dynamic_slice_in_dim(a, t * layers, layers) for a in whole))
+        return (x, tuple(
+            lax.dynamic_update_slice_in_dim(a, r, t * layers, 0)
+            for a, r in zip(whole, rows))), None
+
+    (x, pools), _ = lax.scan(carried_pass, (x, tuple(pools)),
+                             jnp.arange(steps))
+    return x, pools
 
 
 def forward(cfg: TransformerConfig, params: dict, ids: jax.Array,
@@ -284,18 +473,25 @@ def forward_with_aux(cfg: TransformerConfig, params: dict, ids: jax.Array,
                      mesh=None):
     """(logits [B, T, V], aux): aux is the mean MoE load-balancing loss
     across layers (0.0 for dense FFNs)."""
-    b, t = ids.shape
-    x = params["embed"][ids] + params["pos_embed"][:t][None]
+    x, rope = _embed(cfg, params, ids)
 
-    if cfg.remat == "dots":
-        block = functools.partial(_block, cfg, mesh, remat_dots=True)
-    else:
-        if not isinstance(cfg.remat, bool):
-            raise ValueError(f"remat must be True, False or 'dots', got "
-                             f"{cfg.remat!r}")
-        block = functools.partial(_block, cfg, mesh)
-        if cfg.remat:
-            block = jax.checkpoint(block)
+    if cfg.remat != "dots" and not isinstance(cfg.remat, bool):
+        raise ValueError(f"remat must be True, False or 'dots', got "
+                         f"{cfg.remat!r}")
+    attn = functools.partial(_attention, cfg, mesh=mesh)
+    if cfg.remat == "dots" and cfg.attn_impl != "flash":
+        # non-custom-vjp impls would otherwise save O(T^2) softmax
+        # residuals per layer; recompute them in the backward instead
+        attn = jax.checkpoint(attn)
+
+    def block(x, layer):
+        x, aux, _ = _block(
+            cfg, x, layer, lambda q, k, v: (attn(q, k, v), None), rope,
+            mesh, remat_dots=cfg.remat == "dots")
+        return x, jnp.zeros((), jnp.float32) if aux is None else aux
+
+    if cfg.remat is True:
+        block = jax.checkpoint(block)
 
     unroll = cfg.scan_unroll
     if unroll == "auto":
@@ -303,64 +499,59 @@ def forward_with_aux(cfg: TransformerConfig, params: dict, ids: jax.Array,
     elif not isinstance(unroll, (bool, int)):
         raise ValueError(f"scan_unroll must be 'auto', a bool, or an int; "
                          f"got {unroll!r}")
-    # block's (x, aux) return is already scan's (carry, y) contract
-    x, auxes = lax.scan(block, x, params["blocks"], unroll=unroll)
-    x = _ln(x, params["ln_f_g"], params["ln_f_b"])
-    return x @ params["embed"].T, jnp.mean(auxes)
+    x, auxes = _run_stack(cfg, params, x, block, unroll=unroll)
+    return _head(cfg, params, x), jnp.mean(auxes)
 
 
 # -- incremental inference (the serving path) ---------------------------------
 #
 # Training runs the whole context through `forward` every step; serving
 # can't — decode is one token per sequence per step over a ragged,
-# continuously re-batched population.  The two entry points below split
+# continuously re-batched population.  The entry points below split
 # the forward into the standard prefill/decode pair over the paged
 # KV-cache of ops/pallas/paged_attention.py (layout and page-table
 # semantics documented there; paddle_tpu/serving/ owns allocation and
-# scheduling).  Both reuse this module's block math verbatim, so
-# incremental decode is token-for-token equal to repeated full-context
-# `forward` argmax (asserted in tests/test_serving.py).
+# scheduling).  All run `_block` — each hands it its own cache write and
+# attention — so incremental decode is token-for-token equal to repeated
+# full-context `forward` argmax (asserted in tests/test_serving.py).  A
+# looped stack (`loop_steps` > 1) keeps one cache layer per (pass,
+# layer): the pools' leading axis is `cfg.cache_layers`.
 
 
-def _block_kv(cfg: TransformerConfig, mesh, x, layer):
-    """One decoder block that also returns its K/V — the prefill body.
-    Identical math to ``_block`` (dense FFN path; no remat — inference
-    holds no backward), with the attention inputs captured for the cache."""
-    b, t, e = x.shape
-    nh, hd = cfg.num_heads, cfg.head_dim
-    h = _ln(x, layer["ln1_g"], layer["ln1_b"])
-    q = (h @ layer["wq"]).reshape(b, t, nh, hd)
-    k = (h @ layer["wk"]).reshape(b, t, nh, hd)
-    v = (h @ layer["wv"]).reshape(b, t, nh, hd)
-    a = _attention(cfg, q, k, v, mesh)
-    x = x + a.reshape(b, t, nh * hd) @ layer["wo"]
-    h = _ln(x, layer["ln2_g"], layer["ln2_b"])
-    h = jax.nn.gelu(h @ layer["w_in"] + layer["b_in"])
-    return x + h @ layer["w_out"] + layer["b_out"], (k, v)
+def _dense_only(cfg: TransformerConfig) -> None:
+    if cfg.moe_experts:
+        raise NotImplementedError(
+            "serving prefill/decode cover the dense-FFN transformer; "
+            "quantized/MoE decode is future work")
+
+
+def _last_valid(x, seq_lens):
+    return jnp.take_along_axis(
+        x, jnp.maximum(seq_lens - 1, 0)[:, None, None], axis=1)[:, 0]
 
 
 def forward_prefill(cfg: TransformerConfig, params: dict, ids: jax.Array,
                     seq_lens: jax.Array, mesh=None):
     """Prompt pass: ids [B, T] right-padded, seq_lens [B] valid lengths.
 
-    Returns (last-token logits [B, V], k [L, B, T, H, Dh], v likewise) —
-    the K/V stacks are scattered into the paged cache by the caller
-    (``paged_attention.write_prefill_kv``).  Causal masking means padded
-    positions are never attended by valid queries, so plain right-padding
-    is exact; rows with ``seq_lens == 0`` (slack in a fixed-size prefill
-    batch) produce garbage logits the caller discards."""
-    if cfg.moe_experts:
-        raise NotImplementedError(
-            "serving prefill/decode cover the dense-FFN transformer; "
-            "quantized/MoE decode is future work")
-    b, t = ids.shape
-    x = params["embed"][ids] + params["pos_embed"][:t][None]
-    x, (ks, vs) = lax.scan(
-        functools.partial(_block_kv, cfg, mesh), x, params["blocks"])
-    x = _ln(x, params["ln_f_g"], params["ln_f_b"])
-    last = jnp.take_along_axis(
-        x, jnp.maximum(seq_lens - 1, 0)[:, None, None], axis=1)[:, 0]
-    return last @ params["embed"].T, ks, vs
+    Returns (last-token logits [B, V], k [cache_layers, B, T, H, Dh], v
+    likewise) — the K/V stacks are scattered into the paged cache by the
+    caller (``paged_attention.write_prefill_kv``).  Causal masking means
+    padded positions are never attended by valid queries, so plain
+    right-padding is exact; rows with ``seq_lens == 0`` (slack in a
+    fixed-size prefill batch) produce garbage logits the caller
+    discards."""
+    _dense_only(cfg)
+    x, rope = _embed(cfg, params, ids)
+
+    def layer_fn(x, layer):
+        x, _, kv = _block(
+            cfg, x, layer,
+            lambda q, k, v: (_attention(cfg, q, k, v, mesh), (k, v)), rope)
+        return x, kv
+
+    x, (ks, vs) = _run_stack(cfg, params, x, layer_fn)
+    return _head(cfg, params, _last_valid(x, seq_lens)), ks, vs
 
 
 def forward_prefill_chunk(cfg: TransformerConfig, params: dict,
@@ -373,51 +564,39 @@ def forward_prefill_chunk(cfg: TransformerConfig, params: dict,
     ids [B, C] right-padded chunk tokens, starts [B] the absolute
     position of each row's first token, seq_lens [B] valid NEW tokens
     this pass (0 = idle row), page_table [B, max_pages], k_cache/v_cache
-    [L, H, P, page_size, Dh].  Each block writes the chunk's K/V into
-    the mapped pages, then attends the chunk queries causally over the
-    WHOLE resident context — earlier chunks and any shared cached
-    prefix included — so a prompt split across passes (or riding a
+    [cache_layers, H, P, page_size, Dh].  Each block writes the chunk's
+    K/V into the mapped pages, then attends the chunk queries causally
+    over the WHOLE resident context — earlier chunks and any shared
+    cached prefix included — so a prompt split across passes (or riding a
     prefix-cache hit) computes the same math as one full prefill.
     Returns (last-valid logits [B, V], k_cache', v_cache'): the row
     whose chunk completes its prompt samples its first token from these
     logits; mid-prompt rows' logits are discarded by the caller."""
-    if cfg.moe_experts:
-        raise NotImplementedError(
-            "serving prefill/decode cover the dense-FFN transformer; "
-            "quantized/MoE decode is future work")
+    _dense_only(cfg)
     from paddle_tpu.ops.pallas import paged_attention as pa
 
     b, c = ids.shape
-    nh, hd = cfg.num_heads, cfg.head_dim
     # padding of offset rows can index past max_seq_len — clip (valid
     # positions satisfy starts + t < max_prompt_len <= max_seq_len)
     pos = jnp.clip(starts[:, None] + jnp.arange(c)[None, :], 0,
                    cfg.max_seq_len - 1)
-    x = params["embed"][ids] + params["pos_embed"][pos]
+    x, rope = _embed(cfg, params, ids, pos)
 
-    def block(x, layer_kv):
-        layer, kc, vc = layer_kv
-        h = _ln(x, layer["ln1_g"], layer["ln1_b"])
-        q = (h @ layer["wq"]).reshape(b, c, nh, hd)
-        k = (h @ layer["wk"]).reshape(b, c, nh, hd)
-        v = (h @ layer["wv"]).reshape(b, c, nh, hd)
-        kcs, vcs = pa.write_prefill_kv(kc[None], vc[None], k[None],
-                                       v[None], page_table, seq_lens,
-                                       starts=starts)
-        kc, vc = kcs[0], vcs[0]
-        a = pa.paged_prefill_attention(q, kc, vc, page_table, starts,
-                                       seq_lens)
-        x = x + a.reshape(b, c, nh * hd) @ layer["wo"]
-        h = _ln(x, layer["ln2_g"], layer["ln2_b"])
-        h = jax.nn.gelu(h @ layer["w_in"] + layer["b_in"])
-        return x + h @ layer["w_out"] + layer["b_out"], (kc, vc)
+    def layer_fn(x, layer, kc, vc):
+        def attend(q, k, v):
+            kcs, vcs = pa.write_prefill_kv(kc[None], vc[None], k[None],
+                                           v[None], page_table, seq_lens,
+                                           starts=starts)
+            kc2, vc2 = kcs[0], vcs[0]
+            return pa.paged_prefill_attention(
+                q, kc2, vc2, page_table, starts, seq_lens), (kc2, vc2)
 
-    x, (k_cache, v_cache) = lax.scan(
-        block, x, (params["blocks"], k_cache, v_cache))
-    x = _ln(x, params["ln_f_g"], params["ln_f_b"])
-    last = jnp.take_along_axis(
-        x, jnp.maximum(seq_lens - 1, 0)[:, None, None], axis=1)[:, 0]
-    return last @ params["embed"].T, k_cache, v_cache
+        x, _, kv = _block(cfg, x, layer, attend, rope)
+        return x, kv
+
+    x, (k_cache, v_cache) = _run_stack(cfg, params, x, layer_fn,
+                                       (k_cache, v_cache))
+    return _head(cfg, params, _last_valid(x, seq_lens)), k_cache, v_cache
 
 
 def forward_decode(cfg: TransformerConfig, params: dict, ids: jax.Array,
@@ -428,44 +607,35 @@ def forward_decode(cfg: TransformerConfig, params: dict, ids: jax.Array,
 
     ids [B] current tokens, positions [B] their absolute indices,
     seq_lens [B] = positions + 1 on live rows and 0 on idle rows,
-    page_table [B, max_pages], k_cache/v_cache [L, H, P, page_size, Dh]
-    (``paged_attention.init_kv_pages``).  Each block writes the new
-    token's K/V into its pages, then runs ragged paged attention over
-    the whole resident context.  Returns (logits [B, V], k_cache',
-    v_cache'); idle rows write the null page and read zeros.
+    page_table [B, max_pages], k_cache/v_cache [cache_layers, H, P,
+    page_size, Dh] (``paged_attention.init_kv_pages``).  Each block
+    writes the new token's K/V into its pages, then runs ragged paged
+    attention over the whole resident context.  Returns (logits [B, V],
+    k_cache', v_cache'); idle rows write the null page and read zeros.
 
     ``attn_impl`` is the paged-attention implementation ("auto" =
     Pallas kernel on TPU, jnp reference elsewhere) — deliberately
     separate from ``cfg.attn_impl``, which describes TRAINING attention
     over contiguous sequences."""
-    if cfg.moe_experts:
-        raise NotImplementedError(
-            "serving prefill/decode cover the dense-FFN transformer; "
-            "quantized/MoE decode is future work")
+    _dense_only(cfg)
     from paddle_tpu.ops.pallas import paged_attention as pa
 
-    b = ids.shape[0]
-    nh, hd = cfg.num_heads, cfg.head_dim
-    x = params["embed"][ids] + params["pos_embed"][positions]
+    x, rope = _embed(cfg, params, ids, positions)
 
-    def block(x, layer_kv):
-        layer, kc, vc = layer_kv
-        h = _ln(x, layer["ln1_g"], layer["ln1_b"])
-        q = (h @ layer["wq"]).reshape(b, nh, hd)
-        k = (h @ layer["wk"]).reshape(b, nh, hd)
-        v = (h @ layer["wv"]).reshape(b, nh, hd)
-        kc, vc = pa.write_decode_kv(kc, vc, k, v, page_table, positions)
-        a = pa.ragged_paged_attention(q, kc, vc, page_table, seq_lens,
-                                      impl=attn_impl)
-        x = x + a.reshape(b, nh * hd) @ layer["wo"]
-        h = _ln(x, layer["ln2_g"], layer["ln2_b"])
-        h = jax.nn.gelu(h @ layer["w_in"] + layer["b_in"])
-        return x + h @ layer["w_out"] + layer["b_out"], (kc, vc)
+    def layer_fn(x, layer, kc, vc):
+        def attend(q, k, v):
+            kc2, vc2 = pa.write_decode_kv(kc, vc, k, v, page_table,
+                                          positions)
+            return pa.ragged_paged_attention(
+                q, kc2, vc2, page_table, seq_lens,
+                impl=attn_impl), (kc2, vc2)
 
-    x, (k_cache, v_cache) = lax.scan(
-        block, x, (params["blocks"], k_cache, v_cache))
-    x = _ln(x, params["ln_f_g"], params["ln_f_b"])
-    return x @ params["embed"].T, k_cache, v_cache
+        x, _, kv = _block(cfg, x, layer, attend, rope)
+        return x, kv
+
+    x, (k_cache, v_cache) = _run_stack(cfg, params, x, layer_fn,
+                                       (k_cache, v_cache))
+    return _head(cfg, params, x), k_cache, v_cache
 
 
 def loss_fn(cfg: TransformerConfig, params: dict, ids: jax.Array,

@@ -20,6 +20,7 @@ process announces its ``PADDLE_TPU_REPLICA_ID`` on stderr.
 from __future__ import annotations
 
 import argparse
+import json
 import os
 import sys
 
@@ -36,6 +37,11 @@ def build_argparser() -> argparse.ArgumentParser:
     p.add_argument("--layers", type=int, default=2)
     p.add_argument("--heads", type=int, default=2)
     p.add_argument("--embed", type=int, default=64)
+    p.add_argument("--model_json", default="{}",
+                   help="with --random: further TransformerConfig fields "
+                        "as JSON, e.g. '{\"norm\": \"rms\", \"positions\": "
+                        "\"rotary\", \"mlp\": \"swiglu\", \"head_dim\": 48, "
+                        "\"loop_steps\": 3}' (a servable carries its own)")
     p.add_argument("--max_new_tokens", type=int, default=16)
     p.add_argument("--temperature", type=float, default=0.0)
     p.add_argument("--seed", type=int, default=0)
@@ -88,7 +94,8 @@ def main(argv=None) -> int:
         cfg = T.TransformerConfig(
             vocab_size=args.vocab, num_layers=args.layers,
             num_heads=args.heads, embed_dim=args.embed,
-            mlp_dim=args.embed * 4, max_seq_len=256, remat=False)
+            mlp_dim=args.embed * 4, max_seq_len=256, remat=False,
+            **json.loads(args.model_json))
         params = T.init_params(cfg, jax.random.key(args.seed))
 
     scfg = ServingConfig(

@@ -16,10 +16,13 @@ Synchronous callers (CLIs, tests, benches) skip the thread:
 Telemetry rides the shared :class:`MetricsRegistry`: histograms
 ``serve_queue_wait_ms`` / ``serve_prefill_ms`` / ``serve_decode_step_ms``
 / ``serve_ttft_ms`` / ``serve_tpot_ms``, counters ``serve_requests`` /
-``serve_tokens`` / ``serve_loop_crashes`` (background loops that died —
+``serve_tokens`` / ``serve_layer_passes_total`` (decoder blocks run by
+decode steps: batch × num_layers × loop_steps a step) /
+``serve_loop_crashes`` (background loops that died —
 pending ``results()`` callers get the loop's exception re-raised
 instead of blocking forever), gauges ``serve_active_slots`` /
-``serve_free_pages``; with ``--prefix_cache`` / ``--prefill_chunk_tokens``
+``serve_free_pages`` / ``serve_kv_bytes_per_token`` (set once, at
+construction); with ``--prefix_cache`` / ``--prefill_chunk_tokens``
 also counters ``serve_prefix_hit_tokens`` / ``serve_prefill_flops_saved``
 / ``serve_prefill_chunks`` and gauge ``serve_cached_pages``,
 one ``kind="serve"`` record per completed request and a
@@ -155,16 +158,29 @@ class ServingEngine:
         # device 0), then commit them
         with jax.default_device(device):  # None = jax's default
             self.cache = PagedKVCache(
-                cfg.num_layers, cfg.num_heads, cfg.head_dim, s.num_pages,
+                cfg.cache_layers, cfg.num_heads, cfg.head_dim, s.num_pages,
                 s.page_size, s.max_slots, s.max_pages_per_seq,
                 dtype=cfg.dtype, prefix_cache=s.prefix_cache)
         self.cache.k, self.cache.v = self.place((self.cache.k,
                                                  self.cache.v))
         self.scheduler = Scheduler(s, self.cache)
         # 2·params is the standard per-token forward-FLOPs estimate —
-        # what a prefix-cache hit's skipped recompute is booked at
-        self._param_count = sum(
-            int(x.size) for x in jax.tree.leaves(params))
+        # what a prefix-cache hit's skipped recompute is booked at; a
+        # looped stack passes its layer weights loop_steps times
+        count = lambda tree: sum(int(x.size) for x in jax.tree.leaves(tree))
+        self._flops_per_token = 2.0 * (
+            count(params) + (cfg.loop_steps - 1) * count(params["blocks"]))
+        # one resident token's K and V over every cache layer
+        self.kv_bytes_per_token = (
+            2 * cfg.cache_layers * cfg.num_heads * cfg.head_dim
+            * self.cache.k.dtype.itemsize)
+        self.registry.gauge(
+            "serve_kv_bytes_per_token",
+            "K and V bytes one resident token holds over every cache "
+            "layer (num_layers x loop_steps)").set(self.kv_bytes_per_token)
+        # what every device pass's span says of the stack it ran
+        self._loop_args = {"loop_steps": cfg.loop_steps,
+                           "cache_layers": cfg.cache_layers}
         self._chunk_passes = 0  # incremental prefill passes this engine ran
         self._base_key = self.place(jax.random.key(s.seed))
         self._lock = threading.Lock()
@@ -368,7 +384,8 @@ class ServingEngine:
         ``serve_step`` span (an idle one records nothing): its
         ``serve_schedule`` children are the calls that build or change
         scheduler / KV-cache state, ``serve_prefill`` / ``serve_decode``
-        are the two device passes (dispatch + the wait for the tokens),
+        are the two device passes (dispatch + the wait for the tokens;
+        both carry the stack they ran: ``loop_steps``, ``cache_layers``),
         and what is left over, its self time, is this loop's own Python:
         the small host-to-device transfers, the ``append_token`` loops,
         histograms and gauges."""
@@ -420,7 +437,7 @@ class ServingEngine:
             args = self._dev(batch, "ids", "seq_lens", "page_table", "rids",
                              "temps")
             tk = tracer.begin("serve_prefill", cat="serving",
-                              batch=len(admitted))
+                              batch=len(admitted), **self._loop_args)
             toks, self.cache.k, self.cache.v = self._prefill(
                 self.params, self._base_key, self.cache.k, self.cache.v,
                 *args)
@@ -456,7 +473,7 @@ class ServingEngine:
             args = self._dev(batch, "ids", "positions", "seq_lens",
                              "page_table", "rids", "gens", "temps")
             tk = tracer.begin("serve_decode", cat="serving",
-                              batch=len(live))
+                              batch=len(live), **self._loop_args)
             toks, self.cache.k, self.cache.v = self._decode(
                 self.params, self._base_key, self.cache.k, self.cache.v,
                 *args)
@@ -475,6 +492,10 @@ class ServingEngine:
                 "one continuous-batching decode step, wall ms").observe(
                     (time.perf_counter() - t0) * 1e3)
             reg.counter("serve_tokens", "tokens generated").inc(len(live))
+            reg.counter(
+                "serve_layer_passes_total",
+                "decoder blocks run by decode steps (batch x num_layers x "
+                "loop_steps a step)").inc(len(live) * self.cfg.cache_layers)
             for a in live:
                 sched.append_token(a, int(toks[a.slot]))
             worked = True
@@ -519,7 +540,7 @@ class ServingEngine:
                     "serve_prefill_flops_saved",
                     "prefill FLOPs not recomputed on prefix-cache hits "
                     "(2·params per token estimate)").inc(
-                        2.0 * self._param_count * a.cached_tokens)
+                        self._flops_per_token * a.cached_tokens)
         batch = self._scheduled(tracer, sched.prefill_chunk_batch)
         if batch is None:
             return bool(admitted)
@@ -528,7 +549,7 @@ class ServingEngine:
         args = self._dev(batch, "ids", "starts", "seq_lens", "page_table",
                          "rids", "temps")
         tk = tracer.begin("serve_prefill", cat="serving",
-                          batch=len(rows), chunked=True)
+                          batch=len(rows), chunked=True, **self._loop_args)
         toks, self.cache.k, self.cache.v = self._prefill_chunk(
             self.params, self._base_key, self.cache.k, self.cache.v,
             *args)
@@ -667,7 +688,7 @@ class ServingEngine:
                 "request_hit_rate": round(p.hits / denom, 4),
                 "evictions": p.evictions, "inserts": p.inserts,
                 "cached_pages": p.cached_pages,
-                "flops_saved": 2.0 * self._param_count * p.hit_tokens,
+                "flops_saved": self._flops_per_token * p.hit_tokens,
             }
         if self.serving.incremental_prefill:
             with self._lock:
