@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import gc
 import json
 import re
@@ -111,6 +112,9 @@ class Sizes:
         ctc_greedy_decode_fused=(512, 24, 27),
         flash_attention=(8, 1024, 12, 64),      # B, T, H, D
         ragged_paged_attention=(8, 12, 64, 2048, 16, 66),  # B,H,D,P,ps,maxp
+        # the benchmark's serve cells (bf16 pools): gpt2-large, ouro-2.6b
+        ragged_paged_attention_gpt2l=(24, 20, 64, 1537, 16, 64),
+        ragged_paged_attention_ouro=(8, 16, 128, 145, 16, 18),
         softmax_xent=(4096, 50257),             # N, V
         conv2d_bn_act=(128, 56, 64, 64, 3, 1, 1),  # N, HW, Cin, Cout, k, s, p
         conv2d_direct=(128, 224, 3, 64, 7, 2, 3),  # the ResNet stem
@@ -615,15 +619,15 @@ def _kernel_cases() -> list[KernelCase]:
     def make_flash(shape, key):
         return tuple(normal(key, i, shape, bf16) for i in range(3))
 
-    def make_paged(shape, key):
+    def make_paged(shape, key, dtype=f32):
         b, h, d, pages, ps, maxp = shape
         lens = jax.random.randint(jax.random.fold_in(key, 3), (b,), 1,
                                   maxp * ps + 1)
         # each row owns its own run of pages (page 0 is the null page)
         table = 1 + jnp.arange(b * maxp, dtype=jnp.int32).reshape(b, maxp)
-        return (normal(key, 0, (b, h, d)),
-                normal(key, 1, (h, pages, ps, d)),
-                normal(key, 2, (h, pages, ps, d)), table % pages, lens)
+        return (normal(key, 0, (b, h, d), dtype),
+                normal(key, 1, (h, pages, ps, d), dtype),
+                normal(key, 2, (h, pages, ps, d), dtype), table % pages, lens)
 
     def make_xent(shape, key):
         n, v = shape
@@ -685,6 +689,10 @@ def _kernel_cases() -> list[KernelCase]:
         return KernelCase(name, make, kernel, reference, diff, tol,
                           shape_key or name)
 
+    def paged_kernel(interp, s):
+        return lambda *a: pa.ragged_paged_attention(
+            *a, impl="kernel", interpret=interp)
+
     return [
         case("lstm_seq", make_lstm,
              lambda interp, s: lambda *a: lstm.lstm_seq(*a, False, interp,
@@ -738,10 +746,13 @@ def _kernel_cases() -> list[KernelCase]:
              lambda s: lambda q, k, v: flash_attention_reference(
                  q, k, v, True),
              diff=(0, 1, 2), tol=BF16_TOL),
-        case("ragged_paged_attention", make_paged,
-             lambda interp, s: lambda *a: pa.ragged_paged_attention(
-                 *a, impl="kernel", interpret=interp),
+        case("ragged_paged_attention", make_paged, paged_kernel,
              lambda s: pa.ragged_paged_attention_reference, tol=MXU_TOL),
+        *(case(f"ragged_paged_attention[{cell}]",
+               functools.partial(make_paged, dtype=bf16), paged_kernel,
+               lambda s: pa.ragged_paged_attention_reference, tol=BF16_TOL,
+               shape_key=f"ragged_paged_attention_{cell}")
+          for cell in ("gpt2l", "ouro")),
         case("softmax_xent", make_xent,
              lambda interp, s: lambda lg, tg: sx.softmax_xent(
                  lg, tg, 256, 2048, interp),

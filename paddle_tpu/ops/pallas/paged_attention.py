@@ -8,27 +8,45 @@ vLLM/"Ragged Paged Attention" design, PAPERS arxiv 2604.15464).  This
 module owns that cache layout end to end:
 
 - pools: ``k_pages``/``v_pages`` of shape **[H, P, page_size, D]** per
-  layer (head-major so a kernel block is one (head, page) pair — a
-  [page_size, D] tile, sublane/lane aligned without any transpose of the
-  resident cache);
+  layer (head-major: one page of all heads is ``H`` strided
+  [page_size, D] tiles, sublane/lane aligned without any transpose of
+  the resident cache);
 - per-sequence **page tables**: ``page_table[b, i]`` = pool page holding
   positions ``[i*page_size, (i+1)*page_size)`` of sequence ``b``.  Page 0
   is the NULL/scratch page: never allocated to a sequence, it absorbs the
   writes of idle batch rows (so the decode step needs no host-side
-  gather/compact of active slots) and backs unused table entries (so
-  block fetches of skipped pages stay in-bounds);
+  gather/compact of active slots) and backs unused table entries, which
+  the kernel never reads;
 - ``seq_lens[b]`` = tokens resident INCLUDING the one being decoded; the
   decode query is the last token, so the length mask alone is the causal
   mask.
 
 Two interchangeable implementations of the attention itself:
 
-- a Pallas TPU kernel (grid (B, H, pages); the page table and lengths ride
-  scalar prefetch so each block fetch DMAs exactly the page the table
-  names — ragged batches never touch pages past ``seq_len``); the single
-  decode query is broadcast over 8 sublanes to satisfy the f32 tile
-  constraint (the 8x redundant VPU/MXU work is free: decode attention is
-  bound by the K/V page reads, not compute);
+- a Pallas TPU kernel, ``paged_attention_decode``.  A grid step takes ALL
+  heads of one sequence and a block of ``N`` consecutive page slots:
+  ``N`` pieces of ``[H, page_size, D]`` per pool, each fetched by the page
+  id a scalar-prefetched list names (the pipeline double-buffers them, so
+  the next block's fetch runs under this block's arithmetic), put side by
+  side as ``[H, N * page_size, D]`` and folded into float32 running
+  max / sum / accumulator by two batched MXU passes (the one decode query
+  rides 8 sublanes).  The grid is ONE dimension over a work list built
+  from ``seq_lens`` — every row's live blocks in order, an idle row one
+  step that writes its zeros — and its length is a run-time value: a
+  block wholly past ``seq_len`` is not a step at all, so nothing is
+  fetched or multiplied for it, and no length costs a compile.  Slots of
+  a row's last block past its last live page name that page again (never
+  the null page: what they hold is multiplied by ``p == 0`` and must be
+  finite).  ``N`` comes from ``decode_block_pages`` — from ``page_size``,
+  ``head_dim``, ``num_heads``, the pool dtype and the table's width — for
+  every caller alike: one MXU pass of score columns (128 tokens), less
+  where VMEM or the table is smaller.  Measured (PERF.md §6, PR 27), the
+  kernel is bound by the pipeline's bookkeeping per block spec and grid
+  step (≈ 0.2 µs each), then by the pages' bytes: 35–39% and 66–68% of
+  the HBM roofline in the benchmark's two serve cells.  It is exactly one Mosaic call per cache layer; the
+  benchmark's reducers count ``tpu_custom_call``s inside ``jit_decode``
+  (``loop_passes_per_token``) and charge this name's time to the
+  roofline, so a split or a fusion over layers would misread both;
 - a pure-jnp reference (gather pages by table, mask, softmax) that is the
   CPU/interpret fallback AND the oracle the kernel is tested against.
 
@@ -47,7 +65,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.compat import tpu_compiler_params
-from paddle_tpu.ops.pallas import NEG_INF
+from paddle_tpu.ops.pallas import NEG_INF, round_up
 
 _Q_SUBLANES = 8  # single decode query padded to a full f32 sublane tile
 
@@ -189,12 +207,60 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
 
 # -- the Pallas kernel ---------------------------------------------------------
 
+_LANES = 128  # a vreg's lanes = an MXU pass's columns: the block's token quantum
+_VMEM_BUDGET = 6 << 20  # bytes of a step's K/V buffers, of 16 MB scoped VMEM
 
-def _decode_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, l_ref, *, scale, page_size):
-    b = pl.program_id(0)
-    i = pl.program_id(2)
-    npages = pl.num_programs(2)
+
+def decode_block_pages(num_heads: int, page_size: int, head_dim: int,
+                       itemsize: int, max_pages: int,
+                       vmem_budget: int = _VMEM_BUDGET) -> int:
+    """``N``: how many consecutive page slots one grid step of the decode
+    kernel covers, from the shapes alone (the same for every caller).
+
+    A block of ``N * page_size`` tokens is one MXU pass wide (128 score
+    columns), so ``N = 128 // page_size``: a narrower block pays the
+    pass for fewer tokens, a wider one reads (and multiplies) more dead
+    tokens past a short context's end.  Capped by what fits the VMEM
+    budget — per slot, K and V, double-buffered by the pipeline plus the
+    block assembled for the matmuls, each ``[H, page, D]`` padded to the
+    dtype's (sublane, 128) tile — and by the table's width; at least 1."""
+    sublanes = 8 * max(4 // itemsize, 1)
+    tile = (num_heads * round_up(page_size, sublanes)
+            * round_up(head_dim, _LANES) * itemsize)
+    fits = vmem_budget // (2 * 3 * tile)
+    return int(max(1, min(_LANES // page_size, fits, max_pages)))
+
+
+def _decode_work(page_table, seq_lens, n, page_size):
+    """The kernel's work list, from the lengths: ``(rows, blocks, pages,
+    steps)`` — for grid step ``g`` the batch row, the row's block and the
+    ``n`` pool pages to fetch (``pages[g * n + j]``); ``steps`` of the
+    ``B * ceil(maxp / n)`` entries are live.  A row takes one step per
+    block that holds a live token, an idle row one (its zeros)."""
+    b, maxp = page_table.shape
+    lens = seq_lens.astype(jnp.int32)
+    per_row = jnp.maximum(-(-lens // (n * page_size)), 1)
+    ends = jnp.cumsum(per_row)
+    g = jnp.arange(b * pl.cdiv(maxp, n), dtype=jnp.int32)
+    # entries past ``steps`` are never run; they only have to stay in bounds
+    rows = jnp.minimum(jnp.sum(g[:, None] >= ends[None, :], axis=1), b - 1)
+    blocks = jnp.maximum(g - (ends - per_row)[rows], 0)
+    # slots of a tail block past the row's last live page repeat that page
+    last = jnp.maximum(-(-lens // page_size) - 1, 0)[rows]
+    slots = jnp.minimum(blocks[:, None] * n + jnp.arange(n), last[:, None])
+    pages = page_table.astype(jnp.int32)[rows[:, None], slots]
+    return (rows.astype(jnp.int32), blocks.astype(jnp.int32),
+            pages.reshape(-1), ends[-1])
+
+
+def _decode_kernel(rows_ref, blocks_ref, pages_ref, lens_ref, q_ref, *refs,
+                   scale, page_size, n):
+    k_refs, v_refs = refs[:n], refs[n:2 * n]
+    o_ref, acc_ref, m_ref, l_ref = refs[2 * n:]
+    g = pl.program_id(0)
+    i = blocks_ref[g]
+    seq_len = lens_ref[rows_ref[g]]
+    block = n * page_size
 
     @pl.when(i == 0)
     def _init():
@@ -202,73 +268,74 @@ def _decode_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    seq_len = lens_ref[b]
-
-    # pages entirely past the sequence contribute nothing: skip their
-    # compute (their block fetch targets the null page — in-bounds, unread)
-    @pl.when(i * page_size < seq_len)
-    def _page():
-        q = q_ref[0, 0]  # [8, D] — the query broadcast over sublanes
-        k = k_ref[0, 0]  # [page_size, D]
-        v = v_ref[0, 0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        pos = i * page_size + lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
+    @pl.when(i * block < seq_len)  # false only for an idle row's one step
+    def _block():
+        q = q_ref[0]  # [H, 8, D] — each head's query broadcast over sublanes
+        k = jnp.concatenate([r[...] for r in k_refs], axis=1)  # [H, block, D]
+        v = jnp.concatenate([r[...] for r in v_refs], axis=1)
+        s = jnp.einsum("hqd,hkd->hqk", q, k,
+                       preferred_element_type=jnp.float32) * scale
+        pos = i * block + lax.broadcasted_iota(jnp.int32, s.shape, 2)
         s = jnp.where(pos < seq_len, s, NEG_INF)
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
+        m_prev = m_ref[:, :, :1]
+        l_prev = l_ref[:, :, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = jnp.broadcast_to(
             l_prev * corr + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
-        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * corr + jnp.einsum(
+            "hqk,hkd->hqd", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
-    @pl.when(i == npages - 1)
+    @pl.when((i + 1) * block >= seq_len)  # the row's last step
     def _finalize():
         # idle rows (seq_len 0) never accumulated: l == 0 -> output 0
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[:, :1], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] /
+                    jnp.maximum(l_ref[:, :, :1], 1e-30)).astype(o_ref.dtype)
 
 
 def _kernel_impl(q, k_pages, v_pages, page_table, seq_lens, scale,
                  interpret):
     b, h, d = q.shape
     _, _, page_size, _ = k_pages.shape
-    maxp = page_table.shape[1]
+    n = decode_block_pages(h, page_size, d, k_pages.dtype.itemsize,
+                           page_table.shape[1])
+    rows, blocks, pages, steps = _decode_work(page_table, seq_lens, n,
+                                              page_size)
     qb = jnp.broadcast_to(q[:, :, None, :], (b, h, _Q_SUBLANES, d))
+    row = pl.BlockSpec(
+        (1, h, _Q_SUBLANES, d),
+        lambda g, rows, blocks, pages, lens: (rows[g], 0, 0, 0))
+    slots = [pl.BlockSpec(
+        (h, None, page_size, d),
+        lambda g, rows, blocks, pages, lens, j=j: (0, pages[g * n + j], 0, 0))
+        for j in range(n)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # page_table, seq_lens ride SMEM
-        grid=(b, h, maxp),
-        in_specs=[
-            pl.BlockSpec((1, 1, _Q_SUBLANES, d),
-                         lambda bi, hi, pi, pt, lens: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, d),
-                         lambda bi, hi, pi, pt, lens: (hi, pt[bi, pi], 0, 0)),
-            pl.BlockSpec((1, 1, page_size, d),
-                         lambda bi, hi, pi, pt, lens: (hi, pt[bi, pi], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, _Q_SUBLANES, d),
-                               lambda bi, hi, pi, pt, lens: (bi, hi, 0, 0)),
+        num_scalar_prefetch=4,  # the work list and seq_lens ride SMEM
+        grid=(steps,),
+        in_specs=[row, *slots, *slots],
+        out_specs=row,
         scratch_shapes=[
-            pltpu.VMEM((_Q_SUBLANES, d), jnp.float32),
-            pltpu.VMEM((_Q_SUBLANES, 128), jnp.float32),
-            pltpu.VMEM((_Q_SUBLANES, 128), jnp.float32),
+            pltpu.VMEM((h, _Q_SUBLANES, d), jnp.float32),
+            pltpu.VMEM((h, _Q_SUBLANES, _LANES), jnp.float32),
+            pltpu.VMEM((h, _Q_SUBLANES, _LANES), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale, page_size=page_size),
+        functools.partial(_decode_kernel, scale=scale, page_size=page_size,
+                          n=n),
         name="paged_attention_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, _Q_SUBLANES, d), q.dtype),
         compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # in order: a row's steps share its accumulators and output
+            dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      qb, k_pages, v_pages)
+    )(rows, blocks, pages, seq_lens.astype(jnp.int32),
+      qb, *[k_pages] * n, *[v_pages] * n)
     return out[:, :, 0, :]
 
 

@@ -181,6 +181,11 @@ class ServingEngine:
         # what every device pass's span says of the stack it ran
         self._loop_args = {"loop_steps": cfg.loop_steps,
                            "cache_layers": cfg.cache_layers}
+        # tokens one fetched block of the decode kernel covers
+        from paddle_tpu.ops.pallas.paged_attention import decode_block_pages
+        self._kv_block = s.page_size * decode_block_pages(
+            cfg.num_heads, s.page_size, cfg.head_dim,
+            self.cache.k.dtype.itemsize, s.max_pages_per_seq)
         self._chunk_passes = 0  # incremental prefill passes this engine ran
         self._base_key = self.place(jax.random.key(s.seed))
         self._lock = threading.Lock()
@@ -481,11 +486,15 @@ class ServingEngine:
                 t_dispatched = tracer.clock()
             toks = np.asarray(toks)
             if tk is not None:
-                # what the step's kernel read (every live sequence's
-                # resident context), and how long the dispatch took
+                # what the step's kernel had to read (every live
+                # sequence's resident context) and what it fetched (the
+                # same in whole blocks), and how long the dispatch took
                 # before the wait for the device began
                 tracer.end(
                     tk, context_tokens=int(batch["seq_lens"].sum()),
+                    kv_block_tokens=int(
+                        (-(-batch["seq_lens"] // self._kv_block)).sum()
+                        * self._kv_block),
                     dispatch_ms=round((t_dispatched - tk.t_start) * 1e3, 3))
             reg.histogram(
                 "serve_decode_step_ms",

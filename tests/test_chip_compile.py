@@ -32,7 +32,9 @@ import chip_smoke  # noqa: E402
 # re-blocked among them
 CASES = (
     "conv2d_bn_act", "lstm_seq_fi", "gru_seq_fi", "bilstm_seq",
-    "flash_attention", "ragged_paged_attention", "softmax_xent",
+    "flash_attention", "ragged_paged_attention",
+    "ragged_paged_attention[gpt2l]", "ragged_paged_attention[ouro]",
+    "softmax_xent",
     "fused_momentum_update", "ctc_loss_fused", "ctc_loss_fused[logits]",
     "ctc_greedy_decode_fused", "embedding_gather", "embedding_scatter_add",
     "sparse_row_update",

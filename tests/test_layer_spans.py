@@ -403,6 +403,26 @@ def test_serve_step_holds_schedule_prefill_and_decode(tracer, incremental):
 
 
 @pytest.mark.serving
+def test_serve_decode_says_what_the_kernel_fetches(tracer):
+    """``kv_block_tokens``: every live context rounded up to whole blocks
+    of the decode kernel (``decode_block_pages`` page slots each), beside
+    ``context_tokens``, the part of it that is live."""
+    eng = _engine(max_prompt_len=24, max_new_tokens=6)
+    block = eng._kv_block
+    assert block == 32  # 8 slots of 4 tokens: the whole table here
+    eng.generate([[5, 17, 3], list(range(1, 21))], max_new_tokens=6)
+    decodes = _by_name(tracer.spans)["serve_decode"]
+    assert decodes
+    for d in decodes:
+        ctx, got = d.args["context_tokens"], d.args["kv_block_tokens"]
+        assert got >= ctx and got % block == 0
+        assert got < ctx + d.args["batch"] * block  # under a block a row
+    # two rows of 4 and 21 tokens fetch a block each
+    assert decodes[0].args["context_tokens"] == 25
+    assert decodes[0].args["kv_block_tokens"] == 64
+
+
+@pytest.mark.serving
 def test_an_idle_step_records_nothing(tracer):
     eng = _engine()
     eng.generate([[5, 17, 3]], max_new_tokens=2)

@@ -5,6 +5,8 @@ TTFT/TPOT percentiles, strict inference, servable export, and the
 ``python -m paddle_tpu.serving`` CLI loop (subprocess, ``serving``
 marker)."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -45,6 +47,47 @@ def make_paged(rng, lens, H=2, D=16, ps=8, maxp=4, pool=16):
     return kp, vp, pt, full_k, full_v
 
 
+# H, D, page_size, dtype of the blocked-kernel cases: the interpret-mode
+# toy and the two serve cells' heads in the pools' bf16
+_BLOCK_SHAPES = {"h2_d16_p8_f32": (2, 16, 8, "float32"),
+                 "h20_d64_p16_bf16": (20, 64, 16, "bfloat16"),
+                 "h16_d128_p16_bf16": (16, 128, 16, "bfloat16")}
+_BLOCK_LENGTHS = ("idle", "one", "one_block", "block_plus_1", "whole_table",
+                  "ragged")
+
+
+@functools.lru_cache(maxsize=None)
+def _blocked_case(shape, maxp):
+    """(N, block tokens, lens, kernel rows, reference rows) of one batch
+    holding every length of ``_BLOCK_LENGTHS``; run once per (shape, maxp)."""
+    h, d, ps, dtype = _BLOCK_SHAPES[shape]
+    dtype = jnp.dtype(dtype)
+    n = PA.decode_block_pages(h, ps, d, dtype.itemsize, maxp)
+    block = n * ps
+    rng = np.random.default_rng(maxp)
+    lens = np.array([0, 1, block, block + 1, maxp * ps,
+                     int(rng.integers(block + 2, maxp * ps))], np.int32)
+    used = -(-lens // ps)
+    pool = 1 + int(used.sum()) + 5
+    ids = rng.permutation(np.arange(1, pool))  # scattered, out of order
+    pt = np.zeros((len(lens), maxp), np.int32)  # unused entries: null page
+    at = 0
+    for b, u in enumerate(used):
+        pt[b, :u] = ids[at:at + u]
+        at += u
+    kp = rng.normal(size=(h, pool, ps, d)).astype(np.float32)
+    vp = rng.normal(size=(h, pool, ps, d)).astype(np.float32)
+    kp[:, 0] = vp[:, 0] = 0.0
+    q = jnp.asarray(rng.normal(size=(len(lens), h, d)), dtype)
+    kp, vp = jnp.asarray(kp, dtype), jnp.asarray(vp, dtype)
+    ref = PA.ragged_paged_attention(q, kp, vp, pt, lens, impl="reference")
+    poison = lambda a: a.at[:, 0].set(jnp.nan)
+    ker = PA.ragged_paged_attention(q, poison(kp), poison(vp), pt, lens,
+                                    impl="kernel", interpret=True)
+    as_f32 = lambda a: np.asarray(a.astype(jnp.float32))
+    return n, block, lens, as_f32(ker), as_f32(ref)
+
+
 class TestRaggedPagedAttention:
     def test_reference_matches_dense_on_ragged_batch(self, rng_np):
         from paddle_tpu.ops.attention import dot_product_attention
@@ -73,6 +116,40 @@ class TestRaggedPagedAttention:
                                         impl="kernel", interpret=True)
         np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("case", _BLOCK_LENGTHS)
+    @pytest.mark.parametrize("maxp", [18, 66])  # neither a multiple of N
+    @pytest.mark.parametrize("shape", sorted(_BLOCK_SHAPES))
+    def test_blocked_kernel_matches_reference(self, shape, maxp, case):
+        """One row per length of interest against the jnp oracle: live
+        pages scattered out of order over the pool, unused table entries
+        on a NaN-poisoned null page that must never reach the result."""
+        _, _, ps, dtype = _BLOCK_SHAPES[shape]
+        n, block, lens, ker, ref = _blocked_case(shape, maxp)
+        assert maxp % n and block == n * ps
+        row = _BLOCK_LENGTHS.index(case)
+        assert lens[row] == {"idle": 0, "one": 1, "one_block": block,
+                             "block_plus_1": block + 1,
+                             "whole_table": maxp * ps,
+                             "ragged": lens[row]}[case]
+        tol = 2e-5 if dtype == "float32" else 2e-2
+        assert np.isfinite(ker[row]).all()
+        np.testing.assert_allclose(ker[row], ref[row], rtol=tol, atol=tol)
+        if case == "idle":
+            assert not ker[row].any()
+
+    def test_decode_block_pages_follows_the_shapes(self):
+        pages = PA.decode_block_pages
+        # the benchmark's two serve cells and chip_smoke's case (PERF.md §6)
+        assert pages(20, 16, 64, 2, 64) == 8      # gpt2-large, bf16
+        assert pages(16, 16, 128, 2, 18) == 8     # ouro-2.6b, bf16
+        assert pages(12, 16, 64, 4, 66) == 8      # chip_smoke, float32
+        assert pages(2, 8, 16, 4, 4) == 4         # never wider than the table
+        assert pages(2, 256, 16, 4, 4) == 1       # a page wider than a block
+        got = [pages(20, 16, 64, 2, 64, vmem_budget=kb << 10)
+               for kb in (1, 256, 1024, 2048, 4096, 1 << 20)]
+        assert got == sorted(got) and got[0] == 1 and got[-1] == 8
+        assert 1 < got[2] < 8                     # the budget binds in between
 
     def test_write_then_read_round_trip(self, rng_np):
         kc, vc = PA.init_kv_pages(1, 2, 8, 4, 16)
