@@ -4,7 +4,7 @@
     python chip_smoke.py                # one chip: device, train, serve, kernels
     python chip_smoke.py --chips 4      # four chips: DP / ZeRO-2 training + a
                                         #   four-replica serving fleet, nothing else
-    python chip_smoke.py --only models  # one step of each other bench model
+    python chip_smoke.py --only models  # two steps of each other model family
 
 Phases (each fails the run; nothing carries on past a failed phase):
 
@@ -24,8 +24,8 @@ Phases (each fails the run; nothing carries on past a failed phase):
 - ``kernels``  every Pallas kernel a production route can reach, called with
                ``interpret=False`` at its bench shape, forward and backward,
                against its ``*_reference`` twin on the same chip.
-- ``models``   (not in the default run) one train step each of the LSTM, NMT,
-               CTR, CRNN and 124M-transformer bench models.
+- ``models``   (not in the default run) two train steps each of the LSTM, NMT,
+               CTR, CRNN and 124M-transformer models.
 - ``multichip`` (``--chips 4`` only) see above.
 
 The LAST stdout line is one JSON object,
@@ -75,13 +75,13 @@ def _resnet50_cost():
 @dataclasses.dataclass(frozen=True)
 class Sizes:
     seed: int = 0
-    # train: ResNet-50 at the bench.py shape (bs128, 224x224x3, 1000 classes)
+    # train: ResNet-50 at bs128, 224x224x3, 1000 classes
     train_cost: Callable = _resnet50_cost
     train_image_dim: int = 224 * 224 * 3
     train_classes: int = 1000
     train_batch: int = 128
     train_steps: int = 6            # the first one pays the compile
-    # serve: the 124M widths of bench.py's transformer row
+    # serve (and the models phase's transformer): the 124M widths
     vocab: int = 50257
     layers: int = 12
     heads: int = 12
@@ -125,6 +125,15 @@ class Sizes:
         embedding_gather=(1000, 64, 16384),     # V, D, n   (CTR bs16384)
         embedding_scatter_add=(1000, 64, 16384),
         sparse_row_update=(1000, 64),
+    ))
+    # models: name -> shape tuple (the transformer's widths are serve's)
+    model_shapes: dict = dataclasses.field(default_factory=lambda: dict(
+        lstm=(256, 100, 512, 30000),            # B, T, hidden, vocab
+        nmt=(64, 32, 512, 30000),               # B, T, width, vocab
+        ctr=(16384, 10000, 1000, 8, 64, (256, 128)),  # B, wide, vocab, fields,
+                                                      #   embed, hidden
+        crnn=(512, 32, 96, 5, 26),              # B, H, W, label len, classes
+        transformer=(16, 1024),                 # B, T
     ))
     # multichip: 3 steps each of one-device / DP / ZeRO-2 at one global batch
     multi_steps: int = 3
@@ -935,22 +944,55 @@ def phase_kernels(sz: Sizes, names=None, interpret=False) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def phase_models(sz: Sizes) -> dict:
-    """One train step each of the other bench models, through their
-    normal builders (``bench._topology_step`` = Topology +
-    ``build_train_step``; ``transformer.build_train_step``), at the
-    bench shapes.  Every model runs; the phase fails if any did not."""
+def _topology_step(cost_fn, feed_fn, optimizer):
+    """A v2-layer-API model's bf16 train step as a closure that chains its
+    own state, the feed resident on the device as a placed feed is (a host
+    feed would cross again on every call)."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from paddle_tpu.config.topology import Topology
+    from paddle_tpu.layers import base
+    from paddle_tpu.trainer.step import build_train_step
+
+    base.reset_name_counters()
+    topo = Topology(cost_fn())
+    specs = {s.name: s for s in topo.param_specs()}
+    params = paddle.parameters.create(topo).as_dict()
+    state = {"p": params, "o": optimizer.init(params, specs),
+             "s": topo.init_states()}
+    step = build_train_step(topo, optimizer, compute_dtype=jnp.bfloat16)
+    feed = jax.device_put(feed_fn())
+    key = jax.random.key(0)
+
+    def one():
+        state["p"], state["o"], state["s"], c, _ = step(
+            state["p"], state["o"], state["s"], feed, key)
+        return c
+
+    return one
+
+
+MODELS = ("lstm", "nmt", "ctr", "crnn", "transformer")
+
+
+def phase_models(sz: Sizes, names=None) -> dict:
+    """Two train steps each of the other model families, through their
+    normal builders (``_topology_step`` = Topology + ``build_train_step``;
+    ``transformer.build_train_step``), at ``sz.model_shapes``.  Every
+    model runs; the phase fails if any did not."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    import bench
     from paddle_tpu.core.lod import SequenceBatch
     from paddle_tpu.layers.data_type import (integer_value,
                                              sparse_binary_vector)
     from paddle_tpu.models import seqtoseq, transformer as T
     from paddle_tpu.models.ctr import wide_and_deep_ctr
     from paddle_tpu.models.ocr_crnn import crnn_ctc_cost
+    from paddle_tpu.models.rnn import lstm_classify_cost
     from paddle_tpu.ops import pallas
     from paddle_tpu.optimizer import AdaGrad, Adam
     from paddle_tpu.reader.feeder import DataFeeder
@@ -964,58 +1006,58 @@ def phase_models(sz: Sizes) -> dict:
     def adam(lr):
         return Adam(learning_rate=lr, moment_dtype=jnp.bfloat16)
 
-    def lstm():
-        return bench._topology_step(
-            lambda: bench._lstm_classify_cost(512),
-            lambda: {"data": seq(256, 100, 30000),
-                     "label": rng.integers(0, 2, size=(256,))},
-            optimizer=adam(2e-3))
+    def lstm(bs, t, hidden, vocab):
+        return _topology_step(
+            lambda: lstm_classify_cost(hidden, vocab),
+            lambda: {"data": seq(bs, t, vocab),
+                     "label": rng.integers(0, 2, size=(bs,))},
+            adam(2e-3))
 
-    def nmt():
-        return bench._topology_step(
+    def nmt(bs, t, dim, vocab):
+        return _topology_step(
             lambda: seqtoseq.seqtoseq_net(
-                30000, 30000, word_vector_dim=512, encoder_size=512,
-                decoder_size=512),
-            lambda: {k: seq(64, 32, 30000) for k in (
+                vocab, vocab, word_vector_dim=dim, encoder_size=dim,
+                decoder_size=dim),
+            lambda: {k: seq(bs, t, vocab) for k in (
                 "source_language_word", "target_language_word",
                 "target_language_next_word")},
-            optimizer=adam(5e-4))
+            adam(5e-4))
 
-    def ctr():
-        bs, wide_dim, vocabs = 16384, 10000, [1000] * 8
+    def ctr(bs, wide_dim, vocab, fields, embed, hidden):
         types = {"wide_input": sparse_binary_vector(wide_dim)}
-        types.update({f"cat_{i}": integer_value(v)
-                      for i, v in enumerate(vocabs)})
+        types.update({f"cat_{i}": integer_value(vocab)
+                      for i in range(fields)})
         types["label"] = integer_value(2)  # feed order = row order below
         wide = rng.integers(0, wide_dim, size=(bs, 3)).tolist()
-        cats = rng.integers(0, 1000, size=(bs, len(vocabs))).tolist()
+        cats = rng.integers(0, vocab, size=(bs, fields)).tolist()
         labels = rng.integers(0, 2, size=(bs,)).tolist()
         batch = [(w, *c, y) for w, c, y in zip(wide, cats, labels)]
-        return bench._topology_step(
+        return _topology_step(
             lambda: wide_and_deep_ctr(
-                wide_dim=wide_dim, categorical_vocab_sizes=vocabs,
-                embedding_size=64, hidden_sizes=(256, 128))[0],
+                wide_dim=wide_dim, categorical_vocab_sizes=[vocab] * fields,
+                embedding_size=embed, hidden_sizes=hidden)[0],
             lambda: DataFeeder(types).feed(batch),
-            optimizer=AdaGrad(learning_rate=1e-2))
+            AdaGrad(learning_rate=1e-2))
 
-    def crnn():
-        return bench._topology_step(
-            lambda: crnn_ctc_cost(image_height=32, image_width=96,
-                                  num_classes=26)[0],
-            lambda: {"image": rng.normal(size=(512, 32 * 96)).astype(
+    def crnn(bs, height, width, label_len, classes):
+        return _topology_step(
+            lambda: crnn_ctc_cost(image_height=height, image_width=width,
+                                  num_classes=classes)[0],
+            lambda: {"image": rng.normal(size=(bs, height * width)).astype(
                          np.float32),
-                     "label": seq(512, 5, 26)},
-            optimizer=adam(1e-3))
+                     "label": seq(bs, label_len, classes)},
+            adam(1e-3))
 
-    def transformer():
+    def transformer(bs, t):
         cfg = T.TransformerConfig(
-            vocab_size=50257, num_layers=12, num_heads=12, embed_dim=768,
-            mlp_dim=3072, max_seq_len=2048, dtype=jnp.float32, remat=False,
-            attn_impl="flash", attn_block_size=1024)
+            vocab_size=sz.vocab, num_layers=sz.layers, num_heads=sz.heads,
+            embed_dim=sz.embed, mlp_dim=sz.mlp, max_seq_len=sz.max_seq_len,
+            dtype=jnp.float32, remat=False, attn_impl="flash",
+            attn_block_size=sz.attn_block)
         params = T.init_params(cfg, jax.random.key(sz.seed))
         opt = adam(1e-4)
         state = {"p": params, "o": opt.init_tree(params)}
-        ids = jnp.asarray(rng.integers(0, cfg.vocab_size, size=(16, 1025)))
+        ids = jnp.asarray(rng.integers(0, cfg.vocab_size, size=(bs, t + 1)))
         step = T.build_train_step(cfg, opt, compute_dtype=jnp.bfloat16)
 
         def one():
@@ -1023,15 +1065,16 @@ def phase_models(sz: Sizes) -> dict:
             return loss
         return one
 
+    builders = dict(lstm=lstm, nmt=nmt, ctr=ctr, crnn=crnn,
+                    transformer=transformer)
     rows = []
-    for name, build in (("lstm h512 bs256", lstm), ("nmt bs64", nmt),
-                        ("ctr bs16384", ctr), ("crnn bs512", crnn),
-                        ("transformer 124M bs16x1024", transformer)):
+    for name in names or MODELS:
+        shape = sz.model_shapes[name]
         t0 = time.perf_counter()
-        row = {"model": name}
+        row = {"model": name, "shape": shape}
         try:
             with pallas.capture_routes() as routes:
-                one = build()
+                one = builders[name](*shape)
                 losses = [float(np.asarray(one())) for _ in range(2)]
             row.update(losses=losses, ok=bool(np.all(np.isfinite(losses))),
                        routes={f"{op}:{path}": n

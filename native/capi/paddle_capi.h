@@ -12,9 +12,8 @@
  * paddle_gradient_machine_create_shared_param below — shared machines
  * alias ONE loaded artifact (weights are baked into the compiled
  * executable; the machine is a pure function), so there is no per-thread
- * weight copy.  Measured on a single-core host, 1->8 threads are
- * throughput-flat with <2% overhead (native/capi/examples/serve_bench.c,
- * BENCHMARKS.md); per-thread compute overlap on multi-core hosts is not
+ * weight copy (native/capi/examples/serve_bench.c drives 1->8 threads).
+ * Per-thread compute overlap on multi-core hosts is not
  * yet measured — the standard deployment there is one process per
  * worker (the artifact file shared via the OS page cache).
  */

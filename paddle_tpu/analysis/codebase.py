@@ -43,7 +43,7 @@ from paddle_tpu.analysis.core import Finding, finalize, repo_root
 
 # -- corpus ---------------------------------------------------------------------
 
-DEFAULT_ROOTS = ("paddle_tpu", "tools", "bench.py")
+DEFAULT_ROOTS = ("paddle_tpu", "tools")
 
 # the threaded subsystems under the GL-THREAD / GL-LOCKORDER audit
 THREADED_MODULES = (
@@ -314,7 +314,7 @@ def pass_schema_kinds(corpus, root, known: frozenset | None = None,
     findings = []
     produced: set[str] = set()
     for rel, (_src, tree) in corpus.items():
-        if not (rel.startswith("paddle_tpu") or rel == "bench.py"):
+        if not rel.startswith("paddle_tpu"):
             continue  # offline renderers (tools/) only consume kinds
         qn = _qualname_index(tree)
         for kind, line, node in _emitted_kinds(tree):

@@ -3,7 +3,7 @@
 ResNet-50 through the TPP conv path is about fifty Mosaic kernel
 compiles per cold process, so every entry point that compiles for the
 chip (``chip_smoke.py``, ``python -m paddle_tpu.trainer``, ``python -m
-paddle_tpu.serving``, ``bench.py``) calls :func:`configure` before its
+paddle_tpu.serving``, ``benchmarks/run.py``) calls :func:`configure` before its
 first compile.  The directory is part of the cache key, so it is either
 what ``JAX_COMPILATION_CACHE_DIR`` says — jax reads that variable itself
 and nothing is set in code — or one fixed path inside the checkout,

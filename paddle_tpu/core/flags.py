@@ -173,7 +173,7 @@ define("debug_nans", False, "enable jax nan-checking (was: feenableexcept)")
 # (paddle/math/Matrix.h:79 `real`), so unmodified configs must reproduce
 # its numerics.  Opt in via --bf16 / PADDLE_TPU_BF16=1 / flags.set, or —
 # preferred — an explicit mixed-precision policy (build_train_step's
-# compute_dtype / SGD(compute_dtype=bfloat16)), which bench.py uses.
+# compute_dtype / SGD(compute_dtype=bfloat16)).
 define("bf16", False, "force bfloat16 MXU compute for float32 operands")
 # telemetry (see paddle_tpu/metrics.py): the structured per-step stream
 # and the multihost flight recorder's crash-dump location

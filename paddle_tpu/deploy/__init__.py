@@ -27,13 +27,11 @@ serving, PAPER.md §pserver); these three controllers close it here:
   (``ElasticCoordinator`` drain→reshard down); sustained serving idle
   gives it back (reshard up) — the diurnal curve.
 
-``tools/bench_deploy_chaos.py`` proves the loop end to end: a seeded
-trace ramps offered QPS 10×, the fleet scales up and back down, a
-mid-ramp checkpoint rolls out under traffic, one ``servable_corrupt``
-chaos fault forces a clean rollback — ``requests_lost == 0`` and greedy
-tokens byte-identical to a no-chaos baseline, with scale/rollout/
-rollback timings in the ``deploy`` / ``autoscale`` telemetry records
-(``tools/metrics_to_md.py`` renders both tables).
+``tests/test_deploy.py`` drives the loop: checkpoints roll out under
+traffic, a ``servable_corrupt`` chaos fault forces a clean rollback with
+no request lost, and scale/rollout/rollback timings land in the
+``deploy`` / ``autoscale`` telemetry records (``tools/metrics_to_md.py``
+renders both tables).
 
 Every background loop here follows the serving crash contract: a loop
 death is stored, counted (``serve_loop_crashes``) and re-raised at the

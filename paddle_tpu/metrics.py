@@ -2,13 +2,12 @@
 
 One metrics layer unifies the scattered primitives (``core/stat.py``
 scope timers, ``profiler.py`` MFU accounting, ``trainer/event.py``
-callbacks, the bench JSONL): a :class:`MetricsRegistry` of counters /
+callbacks): a :class:`MetricsRegistry` of counters /
 gauges / histograms with labeled series and pluggable sinks, plus a
 structured record stream — one record per train step from ``SGD.train``
 and ``trainer/cli.py`` with {step, loss, step_ms, examples_per_sec,
-tokens_per_sec, mfu_pct, hbm_gbps, comm_bytes} — that ``bench.py``
-shares, so trainer and bench records have one schema and one toolchain
-(``tools/metrics_to_md.py``, ``tools/bench_to_md.py``).
+tokens_per_sec, mfu_pct, hbm_gbps, comm_bytes} — read by one toolchain
+(``tools/metrics_to_md.py``).
 
 Typical operator setup::
 

@@ -207,8 +207,8 @@ def batch_norm(
         # single-pass stats (E[x], E[x²]) accumulated in f32 from the native
         # dtype — the elementwise normalize then runs in the activation dtype
         # (bf16 under the mixed-precision policy), halving the HBM traffic of
-        # the f32-upcast formulation.  ResNet-class training on TPU is
-        # bandwidth-bound in BN, not FLOP-bound (see BENCHMARKS.md roofline).
+        # the f32-upcast formulation (the ledger's device_ops put the BN
+        # statistics fusions at the head of the ResNet-50 step, PERF.md §5).
         if use_fused_stats is None:
             use_fused_stats = _tpp_kernels_on()
         if use_fused_stats:
@@ -274,8 +274,7 @@ def conv2d_bn_relu(
 def layer_norm(x: jax.Array, scale: jax.Array, bias: jax.Array, eps: float = 1e-5):
     """Single-pass LN: one f32 upcast, var = E[x^2] - E[x]^2 (one fused
     reduction pair instead of jnp.var's mean-then-moment second pass).
-    Measured -1.65 ms/step on the 124M LM at bs16 (BENCHMARKS.md round-5
-    LM notes).  The E[x^2] form's cancellation error is benign here:
+    The E[x^2] form's cancellation error is benign here:
     LN inputs are O(1)-O(10) activations and the subtraction happens in
     f32 regardless of x's dtype."""
     xf = x.astype(jnp.float32)

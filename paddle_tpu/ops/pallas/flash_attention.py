@@ -307,9 +307,9 @@ def _fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal=False, scale=None,
                     block_q=1024, block_k=1024, interpret=None):
-    # default tiles: 1024x1024 measured fastest on v5e at every T in
-    # {1k, 8k, 32k}, fwd and f+b (tools/bench_attn.py, device-side timing);
-    # the bwd kernels' f32 [bq, bk] intermediates stay within VMEM
+    # default tiles 1024x1024 (chosen on a v5e, device-timed, before the
+    # ledger; no cell sweeps them): the bwd kernels' f32 [bq, bk]
+    # intermediates stay within VMEM
     """Flash attention on [B, T, H, D] tensors.
 
     Numerically equal (to fp tolerance) to

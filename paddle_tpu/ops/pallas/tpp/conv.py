@@ -13,8 +13,7 @@ is finished by the fused epilogue before its single HBM write:
 - affine + ReLU (inference-mode conv+BN+ReLU: one pass, one write);
 - per-channel sum/sum-of-squares of the raw conv output (training-mode
   BN statistics) accumulated in the same pass, so the separate
-  reduction read of the conv output disappears — the measured ResNet
-  bottleneck is exactly that HBM round-trip (BENCHMARKS.md roofline).
+  reduction read of the conv output disappears.
 
 1x1 stride-1 convolutions (over half of ResNet-50's FLOPs) lower to the
 :func:`~paddle_tpu.ops.pallas.tpp.brgemm.brgemm` microkernel directly.

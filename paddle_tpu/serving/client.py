@@ -12,9 +12,8 @@ every back-off so shed pressure is visible client-side too
 (``client_backoffs``).
 
 Jitter is a pure function of ``seed`` — the same seed replays the same
-wait sequence, which is what lets the deploy chaos bench
-(``tools/bench_deploy_chaos.py``) assert byte-identical tokens across
-runs that both hit shedding.
+wait sequence, which is what lets a chaos test assert byte-identical
+tokens across runs that both hit shedding.
 """
 
 from __future__ import annotations

@@ -10,7 +10,7 @@ deployment shapes share the code:
 - **in-process** (:func:`build_local_fleet`) — N
   :class:`LocalReplica`\\ s, each its own ServingEngine over its own
   paged KV-cache, pumped by the router.  This is the deterministic
-  shape the chaos tests and ``tools/bench_serving_fleet.py`` drive, and
+  shape the chaos tests drive, and
   a fine production shape for one host with per-replica page pools.
 - **subprocess** (``distributed.launch --serving``;
   :func:`fleet_launch_argv` builds the command) — one

@@ -6,7 +6,7 @@ list), admit queued requests into free batch slots (prefill), then run
 one decode step for every live sequence.  Sequences join and leave the
 decode batch **per step** — no waiting for a whole batch to finish, which
 is where continuous batching's throughput over static batching comes
-from (``tools/bench_serving.py`` measures it).
+from.
 
 Admission control is FIFO with head-of-line blocking: a request is
 admitted only when (a) a batch slot is free, (b) the page pool can cover
@@ -49,10 +49,6 @@ class ServingConfig:
     eos_id: int | None = None
     seed: int = 0
     attn_impl: str = "auto"      # paged-attention impl (see paged_attention)
-    # naive baseline mode for benchmarking: admit only into an idle
-    # engine and never join mid-flight — every batch decodes until its
-    # LAST member finishes (what a batch `Inference` loop would do)
-    static_batching: bool = False
     # -- per-token serving cost (both off = the prior engine bit-for-
     #    bit; greedy-sampled tokens are identical either way) --
     # share full KV pages between requests with a common prompt prefix
@@ -193,8 +189,6 @@ class Scheduler:
         (FIFO, head-of-line blocking — see module docstring).  Allocates
         pages and table rows; the engine prefills the returned batch."""
         s = self.serving
-        if s.static_batching and self.active:
-            return []
         admitted: list[_Active] = []
         budget = s.max_concurrent_tokens or None
         while self.queue and len(admitted) < s.prefill_batch:
@@ -235,13 +229,7 @@ class Scheduler:
             a.finished = "length"
 
     def retire_finished(self) -> list[_Active]:
-        """Free the pages + slots of finished sequences; returns them.
-
-        Under ``static_batching`` retirement is deferred until the whole
-        batch is done — finished sequences keep their slot and pages (the
-        padded-decode waste the continuous engine avoids)."""
-        if self.serving.static_batching and self.live:
-            return []
+        """Free the pages + slots of finished sequences; returns them."""
         done = [a for a in self.slots if a is not None and a.finished]
         for a in done:
             self.cache.release(a.slot)

@@ -56,8 +56,7 @@ cost-per-token — see ``serving/engine.py``), sets the
 fleet-wide by ``FleetRouter.scrape_replicas``), and appends the record
 to ``<ledger_dir>/ledger.jsonl`` when a path is armed.  Render with
 ``tools/goodput_report.py`` or the "Goodput" table of
-``tools/metrics_to_md.py``; guard regressions with
-``tools/bench_sentinel.py``.
+``tools/metrics_to_md.py``.
 """
 
 from __future__ import annotations
@@ -299,8 +298,7 @@ def serving_costs(registry) -> dict:
 
 def append_jsonl(rec: dict, path: str) -> str:
     """Append one record to a ledger.jsonl (parent dirs created) — the
-    per-run file ``tools/goodput_report.py`` and
-    ``tools/bench_sentinel.py`` consume."""
+    per-run file ``tools/goodput_report.py`` consumes."""
     d = os.path.dirname(path)
     if d:
         os.makedirs(d, exist_ok=True)

@@ -6,10 +6,9 @@ scope timers, ``profiler.py`` MFU accounting, bench JSONL): a
 labeled series (pull side, cheap in-process aggregates) and a list of
 pluggable sinks (push side: one dict per emitted record — JSONL file,
 in-memory for tests, logging).  The per-step train records of
-``SGD.train`` / ``trainer/cli.py`` and the rows of ``bench.py`` flow
+``SGD.train`` / ``trainer/cli.py`` and the ``--job=time`` result flow
 through the same :meth:`MetricsRegistry.emit`, so operators and offline
-tooling (``tools/metrics_to_md.py``, ``tools/bench_to_md.py``) read one
-schema.
+tooling (``tools/metrics_to_md.py``) read one schema.
 
 Comm accounting: the collective wrappers in ``parallel/collective.py``
 call :func:`record_comm` while XLA traces the program, so the counters
@@ -104,8 +103,7 @@ from typing import Any
 # occupancy-seconds, cost_per_token).  The "serve" record gained
 # queue_s/prefill_s/decode_s/kv_page_s/cost_per_token fields and the
 # fleet rollup gained cost-per-token components; rendered by
-# tools/goodput_report.py and metrics_to_md.py's "Goodput" table,
-# regression-guarded by tools/bench_sentinel.py.
+# tools/goodput_report.py and metrics_to_md.py's "Goodput" table.
 # /13 extended the "preflight" record with the GL-P-COST static
 # roofline (graftlint v3): a ``cost`` dict carrying the predicted
 # step_ms / mfu_pct / compute_ms / comm_ms / overlap_headroom_ms, the

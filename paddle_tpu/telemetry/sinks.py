@@ -4,8 +4,7 @@ Every sink takes dict records from :meth:`MetricsRegistry.emit` and is
 safe to fan out to several at once:
 
 - :class:`JsonlSink`   — one JSON object per line, to a path or an open
-  file object (``bench.py`` hands it stdout so bench rows and trainer
-  step records share one schema);
+  file object;
 - :class:`MemorySink`  — list of records, for tests and notebooks;
 - :class:`LoggingSink` — compact per-record lines through
   ``paddle_tpu.core.logger`` (the operator's tail -f view).

@@ -692,7 +692,7 @@ def cmd_time(args, parsed) -> int:
         # the donating step consumes its inputs, so if it raised MID-call
         # during the device-timing attempt, carry["s"] references deleted
         # buffers and the retry would die on an unrelated deleted-buffer
-        # error (ADVICE round 5) — the state is synthetic, so rebuild it
+        # error — the state is synthetic, so rebuild it
         if any(_deleted(leaf) for leaf in jax.tree.leaves(carry["s"])):
             p2 = paddle.parameters.create(topo).as_dict()
             carry["s"] = (p2, opt.init(p2, specs), topo.init_states())
@@ -706,8 +706,8 @@ def cmd_time(args, parsed) -> int:
 
         log.warning("--job=time device timing unavailable (%s); "
                     "wall-clock two-point used", why)
-    # the benchmark result joins the structured metrics stream (same
-    # schema as bench.py rows; JSONL sink via --metrics_jsonl)
+    # the benchmark result joins the structured metrics stream (kind
+    # "bench"; JSONL sink via --metrics_jsonl)
     from paddle_tpu import metrics as metrics_mod
 
     reg = metrics_mod.get_registry()

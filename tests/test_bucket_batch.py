@@ -1,5 +1,5 @@
 """calc_batch_size / bucketed dynamic batching (PyDataProvider2.py:367-374
-semantics on static XLA shapes) — VERDICT r2 task 8."""
+semantics on static XLA shapes)."""
 
 import textwrap
 

@@ -109,8 +109,8 @@ def test_shared_param_machines(tmp_path):
 
 
 def test_forward_releases_gil_for_overlap(tmp_path):
-    """Decides the serving thread-overlap question BY CONSTRUCTION
-    (VERDICT r4 #9): during ``MergedModel.forward`` — the exact call the
+    """Decides the serving thread-overlap question BY CONSTRUCTION:
+    during ``MergedModel.forward`` — the exact call the
     C ABI's ``paddle_gradient_machine_forward`` lands in — the GIL is
     released by jaxlib's PJRT execute, so a concurrent thread makes
     Python progress while the device computes.  A 1 kHz ticker thread

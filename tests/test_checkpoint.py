@@ -273,7 +273,7 @@ def test_trainer_async_checkpoint_and_resume(tmp_path):
 
 
 def test_bf16_params_dtype_roundtrip(tmp_path):
-    """ADVICE round 5 (checkpoint.py:182): params saved bf16/fp8 must come
+    """Params saved bf16/fp8 must come
     back bf16/fp8 — the npz layer stores them f32, and without the
     manifest dtype record a resume would silently recompile the train
     step under an f32 signature."""

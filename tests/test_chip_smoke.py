@@ -36,6 +36,10 @@ TOY = chip_smoke.Sizes(
         ctc_loss_fused=(4, 9, 7, 3), ctc_greedy_decode_fused=(5, 11, 6),
         fused_momentum_update=(3, 3, 4, 8), embedding_gather=(20, 8, 13),
         lstm_seq=(4, 6, 8), gru_seq=(4, 6, 8)),
+    model_shapes=dict(
+        lstm=(4, 6, 8, 50), nmt=(3, 5, 8, 40),
+        ctr=(8, 30, 12, 3, 4, (8, 4)), crnn=(4, 32, 32, 3, 10),
+        transformer=(2, 16)),
     multi_steps=2, replicas=4)
 
 
@@ -185,6 +189,14 @@ def test_kernels_phase_toy(monkeypatch):
     with pytest.raises(chip_smoke.SmokeFailure,
                        match="fused_momentum_update"):
         chip_smoke.phase_kernels(TOY, interpret=True)
+
+
+@pytest.mark.parametrize("name", chip_smoke.MODELS)
+def test_models_phase_toy(name):
+    """``--only models`` off the chip: each family's builder (the lifted
+    ``lstm_classify_cost``, ``_topology_step``) takes two steps."""
+    (row,) = chip_smoke.phase_models(TOY, names=(name,))["rows"]
+    assert row["ok"] and len(row["losses"]) == 2  # ok = both finite
 
 
 def test_multichip_phase_toy():

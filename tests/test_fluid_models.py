@@ -1,4 +1,4 @@
-"""Fluid model-level e2e parity (VERDICT r4 #6) — ports of the four
+"""Fluid model-level e2e parity — ports of the four
 reference composition tests that exercise fluid layers + Executor as
 whole models, on the hermetic datasets:
 

@@ -149,8 +149,7 @@ def test_generic_grad_covers_new_ops():
 
 
 def test_round3_straggler_ops(rng_np):
-    """positive_negative_pair + compare/reduce/pool3d/conv3d stragglers
-    (VERDICT r2 task 7)."""
+    """positive_negative_pair + compare/reduce/pool3d/conv3d stragglers."""
     # pnpair: q0 ordered pair agrees, q1 tie
     score = np.asarray([[.1, .9], [.2, .8], [.3, .5], [.4, .5]], np.float32)
     label = np.asarray([[1.], [0.], [1.], [0.]], np.float32)

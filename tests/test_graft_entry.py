@@ -16,7 +16,7 @@ def _load():
 
 
 @pytest.mark.slow  # ~51s compile grid; the 2-device variant below keeps
-# every dry-run phase (dp/sp/tp, MoE ep, pipeline, v2, scaling) in tier-1
+# every dry-run phase (dp/sp/tp, MoE ep, pipeline, v2) in tier-1
 def test_dryrun_multichip_8():
     _load().dryrun_multichip(8)
 

@@ -1,6 +1,6 @@
 """Model-zoo tests — shape/cost sanity for the benchmark nets
-(reference: `benchmark/paddle/image/*.py`, run by `run.sh`).  Full-size
-forwards for the big nets are exercised by bench.py; here we keep CI fast:
+(reference: `benchmark/paddle/image/*.py`, run by `run.sh`).  ResNet-50 runs
+at full size in the benchmark's train cells; here we keep CI fast:
 smallnet trains a step, the big nets just build + serialize."""
 
 import jax

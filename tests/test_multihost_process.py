@@ -4,8 +4,8 @@ version of the reference's in-process cluster tests
 pservers inside the test binary and compare sparse vs dense training).
 
 Two local processes with 4 virtual CPU devices each — spawned through
-``paddle_tpu.distributed.launch`` (the trainer-fleet launcher, VERDICT
-item 4) — rendezvous through ``multihost.initialize`` (real
+``paddle_tpu.distributed.launch`` (the trainer-fleet launcher) —
+rendezvous through ``multihost.initialize`` (real
 coordinator, real ``jax.distributed`` handshake), build the 8-device dp
 mesh, feed per-process slices of a deterministic global batch through
 ``multihost.global_batch``, run 4 dp train steps, and must end

@@ -1,4 +1,4 @@
-"""Live-outer-memory nested generation (VERDICT r3 missing #4): an inner
+"""Live-outer-memory nested generation: an inner
 beam step whose recurrent memory boots from an OUTER ``memory()`` carries
 state ACROSS subsequences — each subsequence's generation starts from the
 state the previous one ended in (best beam), the reference's outer-frame

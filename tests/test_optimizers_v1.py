@@ -1,4 +1,4 @@
-"""v1 optimizer-parity stragglers (VERDICT r4 #1): sparse_momentum wiring
+"""v1 optimizer-parity stragglers: sparse_momentum wiring
 and equivalence, loud unknown-learning_method errors, per-parameter
 momentum application, and model-average apply at eval.
 

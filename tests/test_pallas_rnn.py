@@ -404,7 +404,15 @@ def test_gru_seq_fi_matches_reference_fwd_and_vjp(rng_np):
 
 
 def test_gru_seq_remat_bit_identical_to_stored_gates(rng_np):
-    from paddle_tpu.ops.pallas.gru import gru_seq
+    """Given the SAME forward ``hs`` the remat backward (u/r/c recomputed
+    per step) equals the stored-gates one bit for bit: ``array_equal``.
+    Through ``jax.grad`` bits cannot be promised off the chip: the two
+    modes run two forward programs (``emit_gates`` on / off), XLA:CPU
+    fuses the interpreted body another way when the gates are an output
+    too, and ``hs`` itself differs in its last bit (3e-8 on 20-24 of 80
+    elements).  Measured gap: 1.5 eps x the gradient's largest element
+    at most; asserted at 4."""
+    from paddle_tpu.ops.pallas import gru
 
     B, T, D = 2, 5, 8
     xw = jnp.asarray(rng_np.normal(size=(B, T, 3 * D)).astype(np.float32) * .4)
@@ -413,17 +421,32 @@ def test_gru_seq_remat_bit_identical_to_stored_gates(rng_np):
     mask = jnp.asarray((np.arange(T)[None] <
                         np.asarray([5, 3])[:, None]).astype(np.float32))
     h0 = jnp.zeros((B, D))
+    xw_t = jnp.swapaxes(xw, 0, 1)
+    d_hs = jnp.swapaxes(jnp.broadcast_to(mask[:, :, None], (B, T, D)), 0, 1)
+    d_hT = jnp.ones((B, D), jnp.float32)
+    eps = float(np.finfo(np.float32).eps)
 
     for reverse in (False, True):
+        hs, urc, _ = gru._fwd_call(xw_t, gru._mask3(mask), wh, whc, h0,
+                                   reverse=reverse, interpret=True,
+                                   emit_gates=True)
+        stored, remat = (
+            gru._gru_dxw_bwd(xw_t, mask, wh, whc, h0, hs, gates, d_hs,
+                             d_hT, reverse, True, gates is None)
+            for gates in (urc, None))
+        for a, bb in zip(stored, remat):
+            assert np.array_equal(np.asarray(a), np.asarray(bb))
+
         def grads(remat):
             def loss(xw, wh, whc):
-                hs, hT = gru_seq(xw, mask, wh, whc, h0, reverse, True,
-                                 remat)
+                hs, hT = gru.gru_seq(xw, mask, wh, whc, h0, reverse, True,
+                                     remat)
                 return jnp.sum(hs * mask[:, :, None]) + jnp.sum(hT)
             return jax.grad(loss, argnums=(0, 1, 2))(xw, wh, whc)
 
         for a, bb in zip(grads(False), grads(True)):
-            assert np.array_equal(np.asarray(a), np.asarray(bb))
+            a, bb = np.asarray(a), np.asarray(bb)
+            assert np.abs(a - bb).max() <= 4 * eps * np.abs(a).max()
 
 
 def test_bilstm_layer_node_matches_composed_pair(rng_np):

@@ -881,8 +881,8 @@ def test_inference_and_sgd_test_run_from_host_feeds():
 
 @pytest.mark.slow
 def test_overlap_speeds_up_slow_reader():
-    """The acceptance property (lenient CI threshold; bench.py publishes
-    the calibrated ≥1.5x row): reader sleep ≈ step time must overlap."""
+    """The acceptance property (lenient CI threshold): reader sleep ≈
+    step time must overlap."""
 
     def timed(sync_period, prefetch):
         from paddle_tpu.core import rng

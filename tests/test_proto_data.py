@@ -275,8 +275,8 @@ def test_preprocess_img_dataset_roundtrip(tmp_path):
 
 def test_sparse_value_slot_reader_feeder_roundtrip(tmp_path):
     """VECTOR_SPARSE_VALUE slots yield (index, value) PAIRS — the v2
-    sparse_float convention the feeder densifies (ADVICE r4: the old
-    (ids_list, values_list) tuple unpacked wrong for 2-id timesteps)."""
+    sparse_float convention the feeder densifies (an
+    (ids_list, values_list) tuple would unpack wrong for 2-id timesteps)."""
     from paddle_tpu.layers import data_type as dt
     from paddle_tpu.reader.feeder import DataFeeder
 

@@ -179,7 +179,7 @@ def test_sample_trainer_config_trains():
 @pytest.mark.skipif(not os.path.exists(LIGHT_MNIST),
                     reason="reference checkout not available")
 def test_light_mnist_parses_and_trains():
-    """v1_api_demo/mnist/light_mnist.py — the VERDICT's named compatibility
+    """v1_api_demo/mnist/light_mnist.py — the named compatibility
     config — parses unmodified and its 4x[conv-BN-relu-pool] CNN learns."""
     import jax
 

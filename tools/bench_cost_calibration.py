@@ -16,7 +16,7 @@ per step on the 1-core CI box — then:
 - fails (rc 1) when any family's prediction/measurement ratio leaves
   the documented band ``[1/BAND, BAND]`` with ``BAND = 2.0``.
 
-The band is the contract BENCHMARKS.md documents: the ``cpu-testbed``
+The band is the contract: the ``cpu-testbed``
 ``HwProfile`` constants in ``paddle_tpu/analysis/cost.py`` are
 *calibrated against this harness*, not datasheet numbers.  A run
 outside the band means either those constants or the charging rules
@@ -63,7 +63,7 @@ def _measure(step, args_fn, steps: int) -> float:
     return tracer.phase_summary()["compute"]["p50_ms"]
 
 
-# -- CPU-calibration shapes (documented; same architectures as bench.py) --------
+# -- CPU-calibration shapes (the architectures plan_search.py ranks) -----------
 
 
 def _calibrate_transformer(steps: int) -> dict:
@@ -147,6 +147,7 @@ def _calibrate_lstm(steps: int) -> dict:
     import jax.numpy as jnp
 
     from paddle_tpu.core.lod import SequenceBatch
+    from paddle_tpu.models.rnn import lstm_classify_cost
     from paddle_tpu.optimizer import Adam
 
     rng = np.random.default_rng(0)
@@ -155,7 +156,7 @@ def _calibrate_lstm(steps: int) -> dict:
                 length=np.full((16,), 50, np.int32)),
             "label": rng.integers(0, 2, size=(16,))}
     return _calibrate_topology(
-        lambda: __import__("bench")._lstm_classify_cost(256), feed,
+        lambda: lstm_classify_cost(256), feed,
         Adam(learning_rate=2e-3, moment_dtype=jnp.bfloat16), steps)
 
 

@@ -4,10 +4,7 @@ aggregate row, the comm-bytes breakdown, and any bench-kind rows.
 
 The stream is whatever a JSONL sink captured — ``SGD.train`` /
 ``trainer/cli.py`` step records (``--metrics_jsonl=PATH`` or
-``metrics.configure(jsonl=...)``) and/or ``python bench.py`` output
-(bench rows flow through the same sink API).  For the BENCHMARKS.md
-reference tables specifically, use ``tools/bench_to_md.py`` on the same
-capture.
+``metrics.configure(jsonl=...)``) and ``--job=time``'s bench-kind row.
 
 Usage: python tools/metrics_to_md.py /path/to/metrics.jsonl [--last N]
 """

@@ -45,9 +45,9 @@ sys.path.insert(0, REPO)
 # sized for (a v5e-class part); pass 0 to use the profile's capacity
 DEFAULT_HBM_GB = 16.0
 
-# the checked-in hand-picked bench configs (bench.py / BENCHMARKS.md) —
-# the plan search's correctness anchor: on the bench budget its top
-# choice should rediscover at least one of these
+# the hand-picked plans recorded in PLAN.json — the plan search's
+# correctness anchor: on that budget its top choice should rediscover
+# at least one of these
 HAND_PICKED = {
     "transformer": {"batch": 16, "remat": False, "dp": 1, "zero": 0},
     "resnet50": {"batch": 128, "dp": 1, "zero": 0},
@@ -133,6 +133,7 @@ def _trace_lstm(batch: int, remat: bool = False) -> dict:
     import jax.numpy as jnp
 
     from paddle_tpu.core.lod import SequenceBatch
+    from paddle_tpu.models.rnn import lstm_classify_cost
     from paddle_tpu.optimizer import Adam
 
     rng = np.random.default_rng(0)
@@ -141,7 +142,7 @@ def _trace_lstm(batch: int, remat: bool = False) -> dict:
                 length=np.full((batch,), 100, np.int32)),
             "label": rng.integers(0, 2, size=(batch,))}
     return _trace_topology(
-        lambda: __import__("bench")._lstm_classify_cost(512), feed,
+        lambda: lstm_classify_cost(512), feed,
         batch, optimizer=Adam(learning_rate=2e-3,
                               moment_dtype=jnp.bfloat16))
 

@@ -115,8 +115,8 @@ def measure_utilization(run_once, steps: int = 8,
                         stream_gbps: float = 670.0):
     """Quiet per-step utilization: device ms, achieved TF/s and GB/s from
     the trace's per-op ``model_flops``/``raw_bytes_accessed`` sums, and the
-    two ceiling ratios (MFU vs bf16 peak, HBM vs the STREAM-triad
-    calibration of THIS chip, 661-673 GB/s measured round 3).
+    two ceiling ratios (MFU vs bf16 peak, HBM vs ``stream_gbps``, a
+    STREAM-triad calibration of the chip, below the 819 GB/s datasheet).
 
     Returns a dict: {ms, tflops, gbps, mfu_pct, hbm_pct}.  The larger of
     mfu_pct/hbm_pct says which roof the workload is near; when both are
