@@ -634,9 +634,12 @@ def _kernel_cases() -> list[KernelCase]:
                                   maxp * ps + 1)
         # each row owns its own run of pages (page 0 is the null page)
         table = 1 + jnp.arange(b * maxp, dtype=jnp.int32).reshape(b, maxp)
+        # the pools as the engine builds them, two cache layers deep; the
+        # call addresses the second
+        pool = pa.kv_pool_shape(2, h, pages, ps, d)
         return (normal(key, 0, (b, h, d), dtype),
-                normal(key, 1, (h, pages, ps, d), dtype),
-                normal(key, 2, (h, pages, ps, d), dtype), table % pages, lens)
+                normal(key, 1, pool, dtype), normal(key, 2, pool, dtype),
+                jnp.int32(1), table % pages, lens)
 
     def make_xent(shape, key):
         n, v = shape

@@ -351,8 +351,9 @@ def memory_report(params, opt_state, states, feed, mesh=None, *,
 
 def serving_memory_report(cfg, serving, params=None, cache=None) -> dict:
     """Static per-device byte accounting of the SERVING path: the paged
-    KV pool (k AND v, each ``cache layers × heads × pages × page_size ×
-    head_dim`` at the model dtype; cache layers = ``num_layers ×
+    KV pool (k AND v, each ``paged_attention.kv_pool_shape`` at the model
+    dtype: ``cache layers × heads × pages × page_size × head_dim``, the
+    heads rounded up to whole lane groups; cache layers = ``num_layers ×
     loop_steps``: a looped stack keeps one cache per pass) next to the
     servable params — the same artifact :func:`memory_report` computes for training, so an
     oversized pool is a preflight failure, not an OOM at the first
@@ -367,10 +368,11 @@ def serving_memory_report(cfg, serving, params=None, cache=None) -> dict:
     many sequences or cache entries reference it."""
     import numpy as np
 
-    itemsize = int(np.dtype(cfg.dtype).itemsize)
-    per_pool = (int(cfg.cache_layers) * int(cfg.num_heads)
-                * int(serving.num_pages) * int(serving.page_size)
-                * int(cfg.head_dim) * itemsize)
+    from paddle_tpu.ops.pallas.paged_attention import kv_pool_shape
+
+    per_pool = int(np.prod(kv_pool_shape(
+        cfg.cache_layers, cfg.num_heads, serving.num_pages,
+        serving.page_size, cfg.head_dim))) * int(np.dtype(cfg.dtype).itemsize)
     kv = 2 * per_pool  # k and v pools
     p_bytes = tree_bytes(params) if params is not None else 0
     report = {

@@ -423,44 +423,46 @@ def _block(cfg: TransformerConfig, x, layer, attend, rope=None, mesh=None,
     return x, aux, kept
 
 
-def _run_stack(cfg: TransformerConfig, params, x, layer_fn, pools=(),
+def _run_stack(cfg: TransformerConfig, params, x, layer_fn, pools=None,
                unroll=1):
     """``loop_steps`` passes of the layer scan over the ONE stacked
     ``blocks`` tree, the final norm closing each pass and feeding the
-    next.  ``layer_fn(x, layer, *layer_pools) -> (x, y)`` is the layer
-    scan's body.  ``pools`` are the K/V pools [cache_layers, ...]: pass t
-    reads and rewrites their rows t*L .. (t+1)*L, and ``y`` is the
-    layer's updated pools.  Returns (x, ys): the updated pools, or,
-    without pools, every (pass, layer)'s ``y`` stacked [cache_layers,
-    ...]."""
+    next.
+
+    Without ``pools`` ``layer_fn(x, layer) -> (x, y)`` is the layer scan's
+    body and the result is (x, ys): every (pass, layer)'s ``y`` stacked
+    [cache_layers, ...].  With ``pools`` — the K/V pools of
+    ``paged_attention.kv_pool_shape`` — they ride the carry of the layer
+    loop and of the pass loop, whole, for every ``loop_steps`` alike:
+    ``layer_fn(x, layer, cache_layer, *pools) -> (x, pools)`` is told
+    which cache layer (``pass * num_layers + layer``) it is and addresses
+    the pools there, so no loop slices a layer's pool out or stacks one
+    back in and the buffers that entered the program are updated where
+    they are.  Returns (x, pools)."""
     steps, layers = cfg.loop_steps, cfg.num_layers
 
-    def one_pass(x, pass_pools):
-        x, ys = lax.scan(lambda x, a: layer_fn(x, *a), x,
-                         (params["blocks"], *pass_pools), unroll=unroll)
+    def one_pass(x, pools, t):
+        if pools is None:
+            x, ys = lax.scan(layer_fn, x, params["blocks"], unroll=unroll)
+        else:
+            def layer(carry, a):
+                x, pools = carry
+                return layer_fn(x, a[0], t * layers + a[1], *pools), None
+
+            (x, ys), _ = lax.scan(
+                layer, (x, pools), (params["blocks"], jnp.arange(layers)),
+                unroll=unroll)
         return _norm(cfg, x, params, "ln_f"), ys
 
-    if steps == 1:  # no outer loop: today's program, op for op
-        return one_pass(x, pools)
-    if not pools:
-        x, ys = lax.scan(lambda x, _: one_pass(x, ()), x, None, length=steps)
+    if steps == 1:  # no outer loop
+        return one_pass(x, pools, 0)
+    if pools is None:
+        x, ys = lax.scan(lambda x, _: one_pass(x, None, 0), x, None,
+                         length=steps)
         return x, jax.tree.map(
             lambda y: y.reshape(steps * layers, *y.shape[2:]), ys)
-
-    # the pools ride the outer loop's carry and each pass's rows are put
-    # back where they came from, so the loop updates them in place (as
-    # scanned operands they would be copied whole into a stacked result)
-    def carried_pass(carry, t):
-        x, whole = carry
-        x, rows = one_pass(x, tuple(
-            lax.dynamic_slice_in_dim(a, t * layers, layers) for a in whole))
-        return (x, tuple(
-            lax.dynamic_update_slice_in_dim(a, r, t * layers, 0)
-            for a, r in zip(whole, rows))), None
-
-    (x, pools), _ = lax.scan(carried_pass, (x, tuple(pools)),
-                             jnp.arange(steps))
-    return x, pools
+    return lax.scan(lambda carry, t: (one_pass(*carry, t), None),
+                    (x, pools), jnp.arange(steps))[0]
 
 
 def forward(cfg: TransformerConfig, params: dict, ids: jax.Array,
@@ -513,9 +515,14 @@ def forward_with_aux(cfg: TransformerConfig, params: dict, ids: jax.Array,
 # semantics documented there; paddle_tpu/serving/ owns allocation and
 # scheduling).  All run `_block` — each hands it its own cache write and
 # attention — so incremental decode is token-for-token equal to repeated
-# full-context `forward` argmax (asserted in tests/test_serving.py).  A
-# looped stack (`loop_steps` > 1) keeps one cache layer per (pass,
-# layer): the pools' leading axis is `cfg.cache_layers`.
+# full-context `forward` argmax (asserted in tests/test_serving.py and
+# tests/test_looped_lm.py).  The pools are two arrays for the whole model
+# (`paged_attention.kv_pool_shape`: [cache_layers, H/g, P, page_size,
+# g*Dh], one cache layer per (pass, layer) of a looped stack).  The chunk
+# and decode programs carry them WHOLE through `_run_stack`'s loops: a
+# block writes and reads at `(cache_layer, page)`, never a layer's pool as
+# a value of its own, so the donated buffers are updated where they are
+# (tests/test_looped_lm.py holds the compiled programs to it).
 
 
 def _dense_only(cfg: TransformerConfig) -> None:
@@ -564,7 +571,7 @@ def forward_prefill_chunk(cfg: TransformerConfig, params: dict,
     ids [B, C] right-padded chunk tokens, starts [B] the absolute
     position of each row's first token, seq_lens [B] valid NEW tokens
     this pass (0 = idle row), page_table [B, max_pages], k_cache/v_cache
-    [cache_layers, H, P, page_size, Dh].  Each block writes the chunk's
+    the pools of ``paged_attention.kv_pool_shape``.  Each block writes the chunk's
     K/V into the mapped pages, then attends the chunk queries causally
     over the WHOLE resident context — earlier chunks and any shared
     cached prefix included — so a prompt split across passes (or riding a
@@ -582,17 +589,15 @@ def forward_prefill_chunk(cfg: TransformerConfig, params: dict,
                    cfg.max_seq_len - 1)
     x, rope = _embed(cfg, params, ids, pos)
 
-    def layer_fn(x, layer, kc, vc):
+    def layer_fn(x, layer, cache_layer, kc, vc):
         def attend(q, k, v):
-            kcs, vcs = pa.write_prefill_kv(kc[None], vc[None], k[None],
-                                           v[None], page_table, seq_lens,
-                                           starts=starts)
-            kc2, vc2 = kcs[0], vcs[0]
+            pools = pa.write_chunk_kv(kc, vc, k, v, cache_layer, page_table,
+                                      starts, seq_lens)
             return pa.paged_prefill_attention(
-                q, kc2, vc2, page_table, starts, seq_lens), (kc2, vc2)
+                q, *pools, cache_layer, page_table, starts, seq_lens), pools
 
-        x, _, kv = _block(cfg, x, layer, attend, rope)
-        return x, kv
+        x, _, pools = _block(cfg, x, layer, attend, rope)
+        return x, pools
 
     x, (k_cache, v_cache) = _run_stack(cfg, params, x, layer_fn,
                                        (k_cache, v_cache))
@@ -607,8 +612,8 @@ def forward_decode(cfg: TransformerConfig, params: dict, ids: jax.Array,
 
     ids [B] current tokens, positions [B] their absolute indices,
     seq_lens [B] = positions + 1 on live rows and 0 on idle rows,
-    page_table [B, max_pages], k_cache/v_cache [cache_layers, H, P,
-    page_size, Dh] (``paged_attention.init_kv_pages``).  Each block
+    page_table [B, max_pages], k_cache/v_cache the pools of
+    ``paged_attention.kv_pool_shape`` (``init_kv_pages``).  Each block
     writes the new token's K/V into its pages, then runs ragged paged
     attention over the whole resident context.  Returns (logits [B, V],
     k_cache', v_cache'); idle rows write the null page and read zeros.
@@ -622,16 +627,16 @@ def forward_decode(cfg: TransformerConfig, params: dict, ids: jax.Array,
 
     x, rope = _embed(cfg, params, ids, positions)
 
-    def layer_fn(x, layer, kc, vc):
+    def layer_fn(x, layer, cache_layer, kc, vc):
         def attend(q, k, v):
-            kc2, vc2 = pa.write_decode_kv(kc, vc, k, v, page_table,
-                                          positions)
+            pools = pa.write_decode_kv(kc, vc, k, v, cache_layer, page_table,
+                                       positions)
             return pa.ragged_paged_attention(
-                q, kc2, vc2, page_table, seq_lens,
-                impl=attn_impl), (kc2, vc2)
+                q, *pools, cache_layer, page_table, seq_lens,
+                impl=attn_impl), pools
 
-        x, _, kv = _block(cfg, x, layer, attend, rope)
-        return x, kv
+        x, _, pools = _block(cfg, x, layer, attend, rope)
+        return x, pools
 
     x, (k_cache, v_cache) = _run_stack(cfg, params, x, layer_fn,
                                        (k_cache, v_cache))

@@ -7,10 +7,23 @@ in fixed-size **pages** so memory is reused without compaction (the
 vLLM/"Ragged Paged Attention" design, PAPERS arxiv 2604.15464).  This
 module owns that cache layout end to end:
 
-- pools: ``k_pages``/``v_pages`` of shape **[H, P, page_size, D]** per
-  layer (head-major: one page of all heads is ``H`` strided
-  [page_size, D] tiles, sublane/lane aligned without any transpose of
-  the resident cache);
+- pools: ONE ``k_pool`` and ONE ``v_pool`` for the whole model, of shape
+  **[cache_layers, H/g, P, page_size, g·D]** (``kv_pool_shape``, the only
+  place the shape is spelt).  ``g = 128 // D`` heads (at least one, at
+  most the model's ``H``) sit side by side in the lanes: head ``h`` is
+  lane group ``h // g``, lanes ``[(h % g)·D, (h % g + 1)·D)``, and ``H``
+  is padded up to whole groups with heads that stay zero.  So the two
+  minor dimensions ``[page_size, g·D]`` are whole (sublane, 128-lane)
+  tiles of the pool dtype wherever ``head_dim`` divides 128 or is a
+  multiple of it: the layout the pools rest in between programs, the
+  row-major layout a Mosaic call takes and the layout the writes produce
+  are the same one, and XLA has nothing to re-tile.  At ``head_dim`` 128
+  ``g`` is 1 and this is plain head-major ``[cache_layers, H, P,
+  page_size, D]``.  No program value is ever "one layer's pool": every
+  write and read below addresses the stacked pool at ``(cache_layer,
+  page)``, so the pools ride the carry of the serving programs' loops
+  and are updated where they are (PERF.md §6, PR 29: the copies this
+  removed were 80% of a decode step);
 - per-sequence **page tables**: ``page_table[b, i]`` = pool page holding
   positions ``[i*page_size, (i+1)*page_size)`` of sequence ``b``.  Page 0
   is the NULL/scratch page: never allocated to a sequence, it absorbs the
@@ -25,28 +38,30 @@ Two interchangeable implementations of the attention itself:
 
 - a Pallas TPU kernel, ``paged_attention_decode``.  A grid step takes ALL
   heads of one sequence and a block of ``N`` consecutive page slots:
-  ``N`` pieces of ``[H, page_size, D]`` per pool, each fetched by the page
-  id a scalar-prefetched list names (the pipeline double-buffers them, so
-  the next block's fetch runs under this block's arithmetic), put side by
-  side as ``[H, N * page_size, D]`` and folded into float32 running
-  max / sum / accumulator by two batched MXU passes (the one decode query
-  rides 8 sublanes).  The grid is ONE dimension over a work list built
-  from ``seq_lens`` — every row's live blocks in order, an idle row one
-  step that writes its zeros — and its length is a run-time value: a
-  block wholly past ``seq_len`` is not a step at all, so nothing is
-  fetched or multiplied for it, and no length costs a compile.  Slots of
-  a row's last block past its last live page name that page again (never
-  the null page: what they hold is multiplied by ``p == 0`` and must be
-  finite).  ``N`` comes from ``decode_block_pages`` — from ``page_size``,
-  ``head_dim``, ``num_heads``, the pool dtype and the table's width — for
-  every caller alike: one MXU pass of score columns (128 tokens), less
-  where VMEM or the table is smaller.  Measured (PERF.md §6, PR 27), the
-  kernel is bound by the pipeline's bookkeeping per block spec and grid
-  step (≈ 0.2 µs each), then by the pages' bytes: 35–39% and 66–68% of
-  the HBM roofline in the benchmark's two serve cells.  It is exactly one Mosaic call per cache layer; the
-  benchmark's reducers count ``tpu_custom_call``s inside ``jit_decode``
-  (``loop_passes_per_token``) and charge this name's time to the
-  roofline, so a split or a fusion over layers would misread both;
+  ``N`` pieces of ``[H/g, page_size, g·D]`` per pool, each fetched at
+  ``(cache_layer, page)`` — the layer and the page ids are scalar-
+  prefetched, the block specs' leading dimension is squeezed (the pipeline
+  double-buffers the pieces, so the next block's fetch runs under this
+  block's arithmetic) — put side by side as ``[H/g, N * page_size, g·D]``
+  and folded into float32 running max / sum / accumulator by two batched
+  MXU passes.  The one decode query of a head rides 8 sublanes with zeros
+  in the lanes of the other heads of its group, so a group's ``g`` heads
+  are ``g · 8`` query rows whose extra products are exact zeros; each
+  head's own lanes of the output are kept by the caller.  The grid is ONE
+  dimension over a work list built from ``seq_lens`` — every row's live
+  blocks in order, an idle row one step that writes its zeros — and its
+  length is a run-time value: a block wholly past ``seq_len`` is not a
+  step at all, so nothing is fetched or multiplied for it, and no length
+  costs a compile.  Slots of a row's last block past its last live page
+  name that page again (never the null page: what they hold is
+  multiplied by ``p == 0`` and must be finite).  ``N`` comes from
+  ``decode_block_pages`` — from ``page_size``, ``head_dim``,
+  ``num_heads``, the pool dtype and the table's width — for every caller
+  alike: one MXU pass of score columns (128 tokens), less where VMEM or
+  the table is smaller.  It is exactly one Mosaic call per cache layer;
+  the benchmark's reducers count ``tpu_custom_call``s inside
+  ``jit_decode`` (``loop_passes_per_token``) and charge this name's time
+  to the roofline, so a split or a fusion over layers would misread both;
 - a pure-jnp reference (gather pages by table, mask, softmax) that is the
   CPU/interpret fallback AND the oracle the kernel is tested against.
 
@@ -68,75 +83,158 @@ from paddle_tpu.compat import tpu_compiler_params
 from paddle_tpu.ops.pallas import NEG_INF, round_up
 
 _Q_SUBLANES = 8  # single decode query padded to a full f32 sublane tile
+_LANES = 128  # a vreg's lanes = an MXU pass's columns: the block's token quantum
 
 
 # -- cache layout helpers ------------------------------------------------------
 
 
+def head_group(num_heads: int, head_dim: int) -> int:
+    """``g``: heads side by side in a pool row's lanes — as many as fill
+    128 of them, never more than the model has."""
+    return max(1, min(_LANES // head_dim, num_heads))
+
+
+def kv_pool_shape(cache_layers: int, num_heads: int, num_pages: int,
+                  page_size: int, head_dim: int) -> tuple:
+    """THE shape of one K (or V) pool: [cache_layers, H/g, P, page_size,
+    g·D], ``H`` rounded up to whole lane groups (module docstring)."""
+    g = head_group(num_heads, head_dim)
+    return (cache_layers, -(-num_heads // g), num_pages, page_size,
+            g * head_dim)
+
+
 def init_kv_pages(num_layers: int, num_heads: int, num_pages: int,
                   page_size: int, head_dim: int, dtype=jnp.float32):
-    """(k_pages, v_pages) pools of shape [L, H, P, page_size, D], zeroed.
+    """(k_pool, v_pool) of ``kv_pool_shape``, zeroed.
 
     Page 0 of every pool is the null/scratch page (see module docstring);
     allocators must hand out ids from 1."""
-    shape = (num_layers, num_heads, num_pages, page_size, head_dim)
+    shape = kv_pool_shape(num_layers, num_heads, num_pages, page_size,
+                          head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
-def write_decode_kv(k_pages, v_pages, k, v, page_table, positions):
-    """Write one new token's K/V per batch row into a single layer's pools.
-
-    k/v: [B, H, D]; k_pages/v_pages: [H, P, page_size, D];
-    page_table: [B, max_pages]; positions: [B] absolute token index.
-    Idle rows (all-zero table rows) land in the null page."""
-    ps = k_pages.shape[2]
-    pages = jnp.take_along_axis(
-        page_table, (positions // ps)[:, None], axis=1)[:, 0]
-    offs = positions % ps
-    k_pages = k_pages.at[:, pages, offs].set(k.swapaxes(0, 1))
-    v_pages = v_pages.at[:, pages, offs].set(v.swapaxes(0, 1))
-    return k_pages, v_pages
+def copy_page(pool, src, dst):
+    """``pool`` with page ``dst`` of every cache layer a copy of ``src``."""
+    return pool.at[:, :, dst].set(pool[:, :, src])
 
 
-def write_prefill_kv(k_pages, v_pages, ks, vs, page_table, seq_lens,
-                     starts=None):
-    """Scatter a prefilled prompt batch into the stacked pools.
+def _pack_heads(x):
+    """[..., H, D] -> [..., H/g, g·D]: a pool row's lanes."""
+    *lead, h, d = x.shape
+    g = head_group(h, d)
+    if h % g:
+        x = jnp.pad(x, [(0, 0)] * len(lead) + [(0, -h % g), (0, 0)])
+    return x.reshape(*lead, -1, g * d)
 
-    ks/vs: [L, B, T, H, D] (padded prompts); k_pages/v_pages:
-    [L, H, P, page_size, D]; page_table: [B, max_pages]; seq_lens: [B].
-    Positions at or past ``seq_lens`` are redirected to the null page.
 
-    ``starts`` [B] (chunked prefill / cached-prefix tails) offsets row
-    ``b``'s writes to absolute positions ``starts[b] + [0, seq_lens[b])``
-    — the same scatter, shifted; None keeps the from-zero behaviour
-    bit-identically."""
-    _, b, t, _, _ = ks.shape
-    ps = k_pages.shape[3]
-    t_idx = jnp.arange(t)
+def _write_tokens(pool, x, cache_layer, pages, offs):
+    """x [B, T, H, D] into ``pool[cache_layer]`` at page ``pages[b, t]``,
+    row ``offs[b, t]``.  Every index dimension of the scatter is a leading
+    one and its window is the pool's minor dimension alone, so it takes
+    the carried pool in the layout it rests in and updates it in place
+    (a window over the head axis has XLA re-lay the whole pool out with
+    ``[H/g, g·D]`` minor first)."""
+    hg = jnp.arange(pool.shape[1])
+    return pool.at[cache_layer, hg, pages[..., None], offs[..., None]].set(
+        _pack_heads(x))
+
+
+def _write_pages(pool, x, pages):
+    """x [cache_layers, B, T, H, D], row ``b``'s tokens from position 0,
+    into pool pages ``pages[b, i]`` (positions ``[i * page_size, (i + 1) *
+    page_size)``), a whole page of every cache layer and head at a time:
+    one in-place ``dynamic_update_slice`` of the carried pool per page.
+    (A scatter whose window spans the layer and head axes has XLA re-lay
+    the whole pool out, there and back, around it.)"""
+    ps = pool.shape[3]
+    x = _pack_heads(x)
+    layers, b, t, groups, lanes = x.shape
+    if t % ps:
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, -t % ps), (0, 0), (0, 0)))
+    # [B * T/ps, L, H/g, 1, page_size, g·D]: one update per page
+    x = x.reshape(layers, -1, ps, groups, lanes).transpose(1, 0, 3, 2, 4)
+    x = x[:, :, :, None].astype(pool.dtype)
+    pages = pages.reshape(-1)
+    return lax.fori_loop(0, pages.shape[0], lambda i, pool: (
+        lax.dynamic_update_slice(pool, x[i], (0, 0, pages[i], 0, 0))), pool)
+
+
+def write_prefill_kv(k_pool, v_pool, ks, vs, page_table, seq_lens):
+    """Write a whole prompt pass (``forward_prefill``) into the pools.
+
+    ks/vs: [cache_layers, B, T, H, D] (padded prompts, rows from position
+    0); k_pool/v_pool: ``kv_pool_shape``; page_table: [B, max_pages];
+    seq_lens: [B].  Written a page at a time (``_write_pages``): page
+    slots wholly past ``seq_lens`` go to the null page, and the padding
+    past ``seq_lens`` inside a row's last page lands in that page, past
+    the frontier every reader masks and the next decode steps overwrite."""
+    ps = k_pool.shape[3]
+    slot = jnp.arange(-(-ks.shape[2] // ps))
+    pages = jnp.where(
+        slot[None, :] * ps < seq_lens[:, None],
+        page_table[:, jnp.minimum(slot, page_table.shape[1] - 1)], 0)
+    return _write_pages(k_pool, ks, pages), _write_pages(v_pool, vs, pages)
+
+
+def write_chunk_kv(k_pool, v_pool, k, v, cache_layer, page_table, starts,
+                   seq_lens):
+    """Write one layer's chunk (``forward_prefill_chunk``: chunked prefill
+    / cached-prefix tails) into cache layer ``cache_layer`` of the pools.
+
+    k/v: [B, T, H, D], row ``b`` at absolute positions ``starts[b] + [0,
+    seq_lens[b])``; positions at or past ``seq_lens`` are redirected to
+    the null page.  A token at a time (``_write_tokens``): a chunk may
+    start inside a page whose first rows another pass wrote."""
+    ps = k_pool.shape[3]
+    t_idx = jnp.arange(k.shape[1])
     valid = t_idx[None, :] < seq_lens[:, None]  # [B, T]
-    pos = (jnp.broadcast_to(t_idx[None, :], (b, t)) if starts is None
-           else starts[:, None] + t_idx[None, :])
+    pos = starts[:, None] + t_idx[None, :]
     # mask the page slot BEFORE the gather: an offset row's padding can
     # point past the table row (starts + t >= max_pages * page_size)
     page_slot = jnp.where(valid, pos // ps, 0)
     pages = jnp.where(valid,
                       jnp.take_along_axis(page_table, page_slot, axis=1), 0)
-    offs = pos % ps
-    k_pages = k_pages.at[:, :, pages, offs].set(ks.transpose(0, 3, 1, 2, 4))
-    v_pages = v_pages.at[:, :, pages, offs].set(vs.transpose(0, 3, 1, 2, 4))
-    return k_pages, v_pages
+    return (_write_tokens(k_pool, k, cache_layer, pages, pos % ps),
+            _write_tokens(v_pool, v, cache_layer, pages, pos % ps))
 
 
-def paged_prefill_attention(q, k_pages, v_pages, page_table, starts,
-                            seq_lens, scale=None):
+def write_decode_kv(k_pool, v_pool, k, v, cache_layer, page_table, positions):
+    """Write one new token's K/V per batch row into cache layer
+    ``cache_layer`` of the pools: a chunk of one token at ``positions``.
+
+    k/v: [B, H, D]; k_pool/v_pool: ``kv_pool_shape``; page_table:
+    [B, max_pages]; positions: [B] absolute token index.
+    Idle rows (all-zero table rows) land in the null page."""
+    return write_chunk_kv(k_pool, v_pool, k[:, None], v[:, None], cache_layer,
+                          page_table, positions, jnp.ones_like(positions))
+
+
+def _gather_context(pool, cache_layer, page_table, num_heads, head_dim):
+    """Every table entry's page of one cache layer, as contiguous
+    [B, H, max_pages * page_size, D] K (or V): ``_pack_heads`` undone,
+    the padding heads dropped."""
+    b, maxp = page_table.shape
+    _, groups, _, ps, lanes = pool.shape
+    g = lanes // head_dim
+    # the advanced indices are split by the head slice, so [B, maxp] leads
+    x = pool[cache_layer, :, page_table].reshape(
+        b, maxp, groups, ps, g, head_dim).transpose(0, 2, 4, 1, 3, 5)
+    return x.reshape(b, groups * g, maxp * ps, head_dim)[:, :num_heads]
+
+
+def paged_prefill_attention(q, k_pool, v_pool, cache_layer, page_table,
+                            starts, seq_lens, scale=None):
     """Chunk-prefill attention: queries over the whole resident paged
-    context (prefix caching + chunked prefill's compute path).
+    context of cache layer ``cache_layer`` (prefix caching + chunked
+    prefill's compute path).
 
     q: [B, C, H, D] — row ``b``'s queries sit at absolute positions
     ``starts[b] + t`` and attend causally over positions ``[0,
     starts[b] + t]`` of the paged cache: earlier chunks AND any shared
     cached prefix included.  The chunk's own K/V must already be written
-    (``write_prefill_kv`` with ``starts``).  ``seq_lens`` [B] is the
+    (``write_chunk_kv``).  ``seq_lens`` [B] is the
     valid NEW tokens per row; rows with 0 produce zeros, query positions
     past it produce garbage the caller discards.  Returns [B, C, H, D].
 
@@ -144,19 +242,14 @@ def paged_prefill_attention(q, k_pages, v_pages, page_table, starts,
     and, under jit, lowers to an XLA gather + batched matmul on TPU —
     chunked prefill is bound by the chunk's dense matmuls, while the
     per-step decode hot loop keeps the Pallas kernel above."""
-    h, _, ps, d = k_pages.shape
-    b, c, _, _ = q.shape
-    maxp = page_table.shape[1]
+    b, c, h, d = q.shape
     scale = scale if scale is not None else d ** -0.5
-    # [H, B, maxp, ps, D] -> [B, H, maxp*ps, D]
-    k = k_pages[:, page_table].transpose(1, 0, 2, 3, 4).reshape(
-        b, h, maxp * ps, d)
-    v = v_pages[:, page_table].transpose(1, 0, 2, 3, 4).reshape(
-        b, h, maxp * ps, d)
+    k = _gather_context(k_pool, cache_layer, page_table, h, d)
+    v = _gather_context(v_pool, cache_layer, page_table, h, d)
     s = jnp.einsum("bchd,bhkd->bhck", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     qpos = starts[:, None] + jnp.arange(c)[None, :]   # [B, C] absolute
-    kpos = jnp.arange(maxp * ps)
+    kpos = jnp.arange(k.shape[2])
     # causal over ABSOLUTE positions: every key at or before the query
     # was written by the prefix/chunks already resident — stale pages
     # past the write frontier sit strictly above qpos and are masked
@@ -174,24 +267,21 @@ def paged_prefill_attention(q, k_pages, v_pages, page_table, starts,
 # -- reference implementation --------------------------------------------------
 
 
-def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
-                                     seq_lens, scale=None):
-    """Pure-jnp oracle: gather each sequence's pages, mask, softmax.
+def ragged_paged_attention_reference(q, k_pool, v_pool, cache_layer,
+                                     page_table, seq_lens, scale=None):
+    """Pure-jnp oracle: gather each sequence's pages of cache layer
+    ``cache_layer``, mask, softmax.
 
-    q: [B, H, D] (one decode token per row); k_pages/v_pages:
-    [H, P, page_size, D]; returns [B, H, D].  Rows with ``seq_lens == 0``
+    q: [B, H, D] (one decode token per row); k_pool/v_pool:
+    ``kv_pool_shape``; returns [B, H, D].  Rows with ``seq_lens == 0``
     produce zeros (idle slots), not NaNs."""
-    h, _, ps, d = k_pages.shape
-    b, maxp = page_table.shape
+    _, h, d = q.shape
     scale = scale if scale is not None else d ** -0.5
-    # [H, B, maxp, ps, D] -> [B, H, maxp*ps, D]
-    k = k_pages[:, page_table].transpose(1, 0, 2, 3, 4).reshape(
-        b, h, maxp * ps, d)
-    v = v_pages[:, page_table].transpose(1, 0, 2, 3, 4).reshape(
-        b, h, maxp * ps, d)
+    k = _gather_context(k_pool, cache_layer, page_table, h, d)
+    v = _gather_context(v_pool, cache_layer, page_table, h, d)
     s = jnp.einsum("bhd,bhkd->bhk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
-    pos = jnp.arange(maxp * ps)
+    pos = jnp.arange(k.shape[2])
     s = jnp.where(pos[None, None, :] < seq_lens[:, None, None], s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
@@ -207,7 +297,6 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, page_table,
 
 # -- the Pallas kernel ---------------------------------------------------------
 
-_LANES = 128  # a vreg's lanes = an MXU pass's columns: the block's token quantum
 _VMEM_BUDGET = 6 << 20  # bytes of a step's K/V buffers, of 16 MB scoped VMEM
 
 
@@ -222,11 +311,14 @@ def decode_block_pages(num_heads: int, page_size: int, head_dim: int,
     pass for fewer tokens, a wider one reads (and multiplies) more dead
     tokens past a short context's end.  Capped by what fits the VMEM
     budget — per slot, K and V, double-buffered by the pipeline plus the
-    block assembled for the matmuls, each ``[H, page, D]`` padded to the
-    dtype's (sublane, 128) tile — and by the table's width; at least 1."""
+    block assembled for the matmuls, each ``[H/g, page, g·D]`` padded to
+    the dtype's (sublane, 128) tile — and by the table's width; at least
+    1."""
+    _, groups, _, _, lanes = kv_pool_shape(1, num_heads, 1, page_size,
+                                           head_dim)
     sublanes = 8 * max(4 // itemsize, 1)
-    tile = (num_heads * round_up(page_size, sublanes)
-            * round_up(head_dim, _LANES) * itemsize)
+    tile = (groups * round_up(page_size, sublanes)
+            * round_up(lanes, _LANES) * itemsize)
     fits = vmem_budget // (2 * 3 * tile)
     return int(max(1, min(_LANES // page_size, fits, max_pages)))
 
@@ -253,8 +345,8 @@ def _decode_work(page_table, seq_lens, n, page_size):
             pages.reshape(-1), ends[-1])
 
 
-def _decode_kernel(rows_ref, blocks_ref, pages_ref, lens_ref, q_ref, *refs,
-                   scale, page_size, n):
+def _decode_kernel(layer_ref, rows_ref, blocks_ref, pages_ref, lens_ref,
+                   q_ref, *refs, scale, page_size, n):
     k_refs, v_refs = refs[:n], refs[n:2 * n]
     o_ref, acc_ref, m_ref, l_ref = refs[2 * n:]
     g = pl.program_id(0)
@@ -270,8 +362,10 @@ def _decode_kernel(rows_ref, blocks_ref, pages_ref, lens_ref, q_ref, *refs,
 
     @pl.when(i * block < seq_len)  # false only for an idle row's one step
     def _block():
-        q = q_ref[0]  # [H, 8, D] — each head's query broadcast over sublanes
-        k = jnp.concatenate([r[...] for r in k_refs], axis=1)  # [H, block, D]
+        # [H/g, g * 8, g·D]: each head's query on 8 sublanes, zero in the
+        # lanes of its group's other heads
+        q = q_ref[0]
+        k = jnp.concatenate([r[...] for r in k_refs], axis=1)  # [H/g, block, g·D]
         v = jnp.concatenate([r[...] for r in v_refs], axis=1)
         s = jnp.einsum("hqd,hkd->hqk", q, k,
                        preferred_element_type=jnp.float32) * scale
@@ -296,31 +390,41 @@ def _decode_kernel(rows_ref, blocks_ref, pages_ref, lens_ref, q_ref, *refs,
                     jnp.maximum(l_ref[:, :, :1], 1e-30)).astype(o_ref.dtype)
 
 
-def _kernel_impl(q, k_pages, v_pages, page_table, seq_lens, scale,
+def _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale,
                  interpret):
     b, h, d = q.shape
-    _, _, page_size, _ = k_pages.shape
-    n = decode_block_pages(h, page_size, d, k_pages.dtype.itemsize,
+    _, groups, _, page_size, lanes = k_pool.shape
+    g = lanes // d
+    n = decode_block_pages(h, page_size, d, k_pool.dtype.itemsize,
                            page_table.shape[1])
     rows, blocks, pages, steps = _decode_work(page_table, seq_lens, n,
                                               page_size)
-    qb = jnp.broadcast_to(q[:, :, None, :], (b, h, _Q_SUBLANES, d))
+    # head (group, j)'s query in lanes [j·D, (j+1)·D) of rows [8j, 8j + 8)
+    # of its group, zeros elsewhere: the products with the other heads'
+    # lanes of a pool row are exact zeros
+    own = jnp.eye(g, dtype=bool)[:, None, :, None]
+    qg = _pack_heads(q).reshape(b, groups, 1, 1, g, d)
+    qb = jnp.broadcast_to(jnp.where(own, qg, 0),
+                          (b, groups, g, _Q_SUBLANES, g, d))
+    qb = qb.reshape(b, groups, g * _Q_SUBLANES, lanes)
     row = pl.BlockSpec(
-        (1, h, _Q_SUBLANES, d),
-        lambda g, rows, blocks, pages, lens: (rows[g], 0, 0, 0))
+        (1, groups, g * _Q_SUBLANES, lanes),
+        lambda s, layer, rows, blocks, pages, lens: (rows[s], 0, 0, 0))
     slots = [pl.BlockSpec(
-        (h, None, page_size, d),
-        lambda g, rows, blocks, pages, lens, j=j: (0, pages[g * n + j], 0, 0))
+        (None, groups, None, page_size, lanes),
+        lambda s, layer, rows, blocks, pages, lens, j=j: (
+            layer[0], 0, pages[s * n + j], 0, 0))
         for j in range(n)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,  # the work list and seq_lens ride SMEM
+        # the cache layer, the work list and seq_lens ride SMEM
+        num_scalar_prefetch=5,
         grid=(steps,),
         in_specs=[row, *slots, *slots],
         out_specs=row,
         scratch_shapes=[
-            pltpu.VMEM((h, _Q_SUBLANES, d), jnp.float32),
-            pltpu.VMEM((h, _Q_SUBLANES, _LANES), jnp.float32),
-            pltpu.VMEM((h, _Q_SUBLANES, _LANES), jnp.float32),
+            pltpu.VMEM((groups, g * _Q_SUBLANES, lanes), jnp.float32),
+            pltpu.VMEM((groups, g * _Q_SUBLANES, _LANES), jnp.float32),
+            pltpu.VMEM((groups, g * _Q_SUBLANES, _LANES), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -328,20 +432,26 @@ def _kernel_impl(q, k_pages, v_pages, page_table, seq_lens, scale,
                           n=n),
         name="paged_attention_decode",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, _Q_SUBLANES, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qb.shape, q.dtype),
         compiler_params=tpu_compiler_params(
             # in order: a row's steps share its accumulators and output
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(rows, blocks, pages, seq_lens.astype(jnp.int32),
-      qb, *[k_pages] * n, *[v_pages] * n)
-    return out[:, :, 0, :]
+    )(jnp.asarray(cache_layer, jnp.int32).reshape(1), rows, blocks, pages,
+      seq_lens.astype(jnp.int32), qb, *[k_pool] * n, *[v_pool] * n)
+    # rows [8j, 8j + 8) hold head (group, j) in ITS lanes; the rest of
+    # each row is the other heads' values under this head's weights
+    j = jnp.arange(g)
+    out = out.reshape(b, groups, g, _Q_SUBLANES, g, d)[:, :, j, 0, j]
+    return out.reshape(b, groups * g, d)[:, :h]
 
 
-def ragged_paged_attention(q, k_pages, v_pages, page_table, seq_lens,
-                           scale=None, impl="auto", interpret=None):
-    """Decode-step attention of q [B, H, D] over a paged KV-cache.
+def ragged_paged_attention(q, k_pool, v_pool, cache_layer, page_table,
+                           seq_lens, scale=None, impl="auto", interpret=None):
+    """Decode-step attention of q [B, H, D] over cache layer
+    ``cache_layer`` of a paged KV-cache (k_pool/v_pool:
+    ``kv_pool_shape``).
 
     ``impl``: "kernel" (Pallas; ``interpret=None`` auto-selects
     interpreter mode off-TPU, the flash_attention convention), "reference"
@@ -354,6 +464,6 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, seq_lens,
 
     if resolve_impl(impl, "ragged_paged_attention") == "reference":
         return ragged_paged_attention_reference(
-            q, k_pages, v_pages, page_table, seq_lens, scale=scale)
-    return _kernel_impl(q, k_pages, v_pages, page_table, seq_lens, scale,
-                        resolve_interpret(interpret))
+            q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale=scale)
+    return _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens,
+                        scale, resolve_interpret(interpret))

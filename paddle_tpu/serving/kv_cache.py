@@ -267,8 +267,10 @@ class PrefixCache:
 class PagedKVCache:
     """Device page pools for every layer + the host-side page table.
 
-    ``k``/``v``: [L, H, P, page_size, D] jax arrays (functional — the
-    jitted decode step returns replacements); ``page_table``: host
+    ``k``/``v``: the two pools of ``paged_attention.kv_pool_shape`` —
+    [cache_layers, H/g, P, page_size, g·D], ``g`` heads side by side in
+    the lanes — as jax arrays (functional: the jitted programs take them
+    donated and return them, updated in place); ``page_table``: host
     int32 [max_slots, max_pages_per_seq], row ``s`` owned by batch slot
     ``s``.  The allocator spans the whole pool; slot bookkeeping
     (assign/release) keeps table rows, refcounts and the free list
@@ -374,9 +376,11 @@ class PagedKVCache:
         old = pages[page_index]
         if self.allocator.refcount(old) <= 1:
             return old
+        from paddle_tpu.ops.pallas.paged_attention import copy_page
+
         new = self._alloc(1)[0]
-        self.k = self.k.at[:, :, new].set(self.k[:, :, old])
-        self.v = self.v.at[:, :, new].set(self.v[:, :, old])
+        self.k = copy_page(self.k, old, new)
+        self.v = copy_page(self.v, old, new)
         pages[page_index] = new
         self.page_table[slot, page_index] = new
         self.allocator.free([old])

@@ -372,7 +372,8 @@ def test_engine_serves_the_reference_greedy_tokens(mode, ref, weights,
         eng = ServingEngine(cfg, params, ServingConfig(
             max_slots=3, page_size=PS, num_pages=40, max_prompt_len=16,
             max_new_tokens=6, prefill_batch=2, **mode), registry=reg)
-        assert eng.cache.k.shape == (STEPS * LAYERS, 4, 40, PS, 12)
+        assert eng.cache.k.shape == PA.kv_pool_shape(STEPS * LAYERS, 4, 40, PS, 12)
+        assert eng.cache.k.shape == (STEPS * LAYERS, 1, 40, PS, 48)
         prompts = [seq[:13].tolist(), seq[3:12].tolist(), seq[:13].tolist()]
         first = eng.generate(prompts[:2])
         again = eng.generate(prompts[2:])     # a prefix hit when the cache is on
@@ -398,6 +399,86 @@ def test_engine_serves_the_reference_greedy_tokens(mode, ref, weights,
         s.args["batch"] for s in spans) * STEPS * LAYERS
     assert reg.get("serve_kv_bytes_per_token").value() == (
         2 * STEPS * LAYERS * 4 * 12 * 4) == eng.kv_bytes_per_token
+
+
+def _lane_group_cfg(steps):
+    """Two layers of three 64-wide heads: two heads a lane group, the
+    second group half padding; gelu / LayerNorm / learned positions (the
+    GPT-2 block) at ``steps`` 1 and the looped stack above it."""
+    return T.TransformerConfig(
+        vocab_size=64, num_layers=2, num_heads=3, head_dim=64, embed_dim=32,
+        mlp_dim=64, max_seq_len=64, remat=False, loop_steps=steps)
+
+
+@pytest.mark.parametrize("mode", [
+    dict(), dict(prefill_chunk_tokens=5), dict(prefix_cache=True),
+    dict(prefix_cache=True, prefill_chunk_tokens=5)],
+    ids=["plain", "chunked", "prefix", "prefix+chunked"])
+@pytest.mark.parametrize("steps", [1, 4])
+def test_engine_tokens_equal_full_forward_argmax(steps, mode):
+    """Incremental decode over the stacked, lane-grouped pools is
+    token-for-token the argmax of repeated full ``forward``, whichever
+    prompt pass filled the cache; a copy-on-write of a shared page in the
+    middle of a generation changes nothing."""
+    cfg = _lane_group_cfg(steps)
+    params = T.init_params(cfg, jax.random.key(3))
+    rng = np.random.default_rng(steps)
+    head = rng.integers(1, 64, 9).tolist()        # two full pages + 1
+    prompts = [head + rng.integers(1, 64, 4).tolist(), head[:7],
+               head + rng.integers(1, 64, 2).tolist()]
+    eng = ServingEngine(cfg, params, ServingConfig(
+        max_slots=3, page_size=PS, num_pages=40, max_prompt_len=16,
+        max_new_tokens=6, prefill_batch=2, **mode))
+    assert eng.cache.k.shape == (2 * steps, 2, 40, PS, 128)
+    got = [r.tokens for r in eng.generate(prompts[:2], max_new_tokens=5)]
+    eng.submit(prompts[2], max_new_tokens=5)
+    eng.step()                                     # admitted and prefilled
+    if mode.get("prefix_cache"):
+        assert eng.cache.prefix.hit_tokens == 8    # the two full pages
+        slot = next(iter(eng.cache._slot_pages))
+        shared = eng.cache.slot_pages(slot)[0]
+        assert eng.cache.allocator.refcount(shared) > 1
+        private = eng.cache.cow_page(slot, 0)
+        assert private != shared
+        np.testing.assert_array_equal(np.asarray(eng.cache.k[:, :, private]),
+                                      np.asarray(eng.cache.k[:, :, shared]))
+    eng.run_until_idle()
+    got.append(eng.results()[0].tokens)
+    for prompt, tokens in zip(prompts, got):
+        full = prompt + tokens
+        logits = T.forward(cfg, params, jnp.asarray([full]))
+        assert tokens == [int(t) for t in jnp.argmax(
+            logits[0, len(prompt) - 1:-1], axis=-1)]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+@pytest.mark.parametrize("steps", [1, 4])
+def test_compiled_serving_programs_update_the_pools_in_place(steps, program):
+    """The structure of the compiled program, pools donated: both pools
+    are aliased input to output, and the temporaries stay below ONE cache
+    layer's bytes (the CPU backend's gather of the live pages and its
+    expanded scatter need no more) — so no loop slices a layer's pool
+    out, stacks one back in, or re-lays a pool out.  (The tree before the
+    pools rode the carry held 2.3 / 1.3 whole pools of temp here at
+    ``steps`` 1 / 4.)  The TPU facts are PERF.md §4's AOT lines."""
+    cfg = _lane_group_cfg(steps)
+    params = T.init_params(cfg, jax.random.key(0))
+    kc, vc = PA.init_kv_pages(cfg.cache_layers, 3, 256, 8, 64)
+    pool = kc.size * kc.dtype.itemsize
+    b, maxp = 2, 4
+    i32 = lambda *shape: jnp.ones(shape, jnp.int32)
+    if program == "decode":
+        fn = lambda p, kc, vc, ids, pos, lens, pt: T.forward_decode(
+            cfg, p, ids, pos, lens, pt, kc, vc)
+        args = (i32(b), i32(b), i32(b), i32(b, maxp))
+    else:
+        fn = lambda p, kc, vc, ids, starts, lens, pt: T.forward_prefill_chunk(
+            cfg, p, ids, starts, lens, pt, kc, vc)
+        args = (i32(b, 8), i32(b), i32(b), i32(b, maxp))
+    mem = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        params, kc, vc, *args).compile().memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * pool
+    assert mem.temp_size_in_bytes < pool // cfg.cache_layers
 
 
 def test_memory_report_and_servable_take_cache_layers(tmp_path, params):

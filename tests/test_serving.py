@@ -26,7 +26,26 @@ def small_cfg(**kw):
     return T.TransformerConfig(**base)
 
 
-def make_paged(rng, lens, H=2, D=16, ps=8, maxp=4, pool=16):
+def as_pool(pages, layers=1, layer=0, fill=0.0):
+    """Logical [H, P, page_size, D] pages as a pool of ``kv_pool_shape``,
+    placed at cache layer ``layer`` of ``layers`` (the others hold ``fill``;
+    padding heads are zero, as in a pool only the program wrote).  Spells the layout on its own: head
+    ``h`` is lane group ``h // g``, lanes ``(h % g) * D ...``."""
+    pages = np.asarray(pages, np.float32)
+    h, p, ps, d = pages.shape
+    shape = PA.kv_pool_shape(layers, h, p, ps, d)
+    g = max(1, min(128 // d, h))
+    assert shape == (layers, -(-h // g), p, ps, g * d)
+    pool = np.full(shape, fill, np.float32)
+    pool[layer] = 0.0
+    for head in range(h):
+        lanes = slice((head % g) * d, (head % g + 1) * d)
+        pool[layer, head // g, :, :, lanes] = pages[head]
+    return pool
+
+
+def make_paged(rng, lens, H=2, D=16, ps=8, maxp=4, pool=16, layers=1,
+               layer=0):
     """Random contiguous K/V + their paged twin for ragged ``lens``."""
     B = len(lens)
     pt = np.zeros((B, maxp), np.int32)
@@ -44,16 +63,21 @@ def make_paged(rng, lens, H=2, D=16, ps=8, maxp=4, pool=16):
         for t in range(int(lens[b])):
             kp[:, pt[b, t // ps], t % ps] = full_k[b, t]
             vp[:, pt[b, t // ps], t % ps] = full_v[b, t]
-    return kp, vp, pt, full_k, full_v
+    return (as_pool(kp, layers, layer), as_pool(vp, layers, layer), pt,
+            full_k, full_v)
 
 
 # H, D, page_size, dtype of the blocked-kernel cases: the interpret-mode
-# toy and the two serve cells' heads in the pools' bf16
+# toy (both heads in one lane group), the two serve cells' heads in the
+# pools' bf16 (two heads a lane group at head_dim 64, one at 128), and an
+# odd head count (the last lane group half padding)
 _BLOCK_SHAPES = {"h2_d16_p8_f32": (2, 16, 8, "float32"),
                  "h20_d64_p16_bf16": (20, 64, 16, "bfloat16"),
-                 "h16_d128_p16_bf16": (16, 128, 16, "bfloat16")}
+                 "h16_d128_p16_bf16": (16, 128, 16, "bfloat16"),
+                 "h5_d64_p16_bf16": (5, 64, 16, "bfloat16")}
 _BLOCK_LENGTHS = ("idle", "one", "one_block", "block_plus_1", "whole_table",
                   "ragged")
+_LAYERS, _LAYER = 3, 1  # the blocked cases' pools, and the layer addressed
 
 
 @functools.lru_cache(maxsize=None)
@@ -79,10 +103,17 @@ def _blocked_case(shape, maxp):
     vp = rng.normal(size=(h, pool, ps, d)).astype(np.float32)
     kp[:, 0] = vp[:, 0] = 0.0
     q = jnp.asarray(rng.normal(size=(len(lens), h, d)), dtype)
-    kp, vp = jnp.asarray(kp, dtype), jnp.asarray(vp, dtype)
-    ref = PA.ragged_paged_attention(q, kp, vp, pt, lens, impl="reference")
-    poison = lambda a: a.at[:, 0].set(jnp.nan)
-    ker = PA.ragged_paged_attention(q, poison(kp), poison(vp), pt, lens,
+    ref = PA.ragged_paged_attention(
+        q, jnp.asarray(as_pool(kp, _LAYERS, _LAYER), dtype),
+        jnp.asarray(as_pool(vp, _LAYERS, _LAYER), dtype), _LAYER, pt, lens,
+        impl="reference")
+    # the kernel's pools: NaN on the null page of the layer it reads and
+    # everywhere in the layers it must not touch
+    poison = lambda a: jnp.asarray(
+        as_pool(a, _LAYERS, _LAYER, fill=np.nan), dtype
+    ).at[_LAYER, :, 0].set(jnp.nan)
+    ker = PA.ragged_paged_attention(q, poison(kp), poison(vp),
+                                    jnp.int32(_LAYER), pt, lens,
                                     impl="kernel", interpret=True)
     as_f32 = lambda a: np.asarray(a.astype(jnp.float32))
     return n, block, lens, as_f32(ker), as_f32(ref)
@@ -93,9 +124,10 @@ class TestRaggedPagedAttention:
         from paddle_tpu.ops.attention import dot_product_attention
 
         lens = np.array([1, 7, 20, 0], np.int32)
-        kp, vp, pt, full_k, full_v = make_paged(rng_np, lens)
+        kp, vp, pt, full_k, full_v = make_paged(rng_np, lens, layers=2,
+                                                layer=1)
         q = rng_np.normal(size=(4, 2, 16)).astype(np.float32)
-        out = PA.ragged_paged_attention_reference(q, kp, vp, pt, lens)
+        out = PA.ragged_paged_attention_reference(q, kp, vp, 1, pt, lens)
         out = np.asarray(out)
         for b, n in enumerate(lens):
             if n == 0:
@@ -110,9 +142,9 @@ class TestRaggedPagedAttention:
         lens = np.array([3, 8, 17, 25], np.int32)
         kp, vp, pt, _, _ = make_paged(rng_np, lens)
         q = rng_np.normal(size=(4, 2, 16)).astype(np.float32)
-        ref = PA.ragged_paged_attention(q, kp, vp, pt, lens,
+        ref = PA.ragged_paged_attention(q, kp, vp, 0, pt, lens,
                                         impl="reference")
-        ker = PA.ragged_paged_attention(q, kp, vp, pt, lens,
+        ker = PA.ragged_paged_attention(q, kp, vp, 0, pt, lens,
                                         impl="kernel", interpret=True)
         np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
@@ -121,9 +153,10 @@ class TestRaggedPagedAttention:
     @pytest.mark.parametrize("maxp", [18, 66])  # neither a multiple of N
     @pytest.mark.parametrize("shape", sorted(_BLOCK_SHAPES))
     def test_blocked_kernel_matches_reference(self, shape, maxp, case):
-        """One row per length of interest against the jnp oracle: live
-        pages scattered out of order over the pool, unused table entries
-        on a NaN-poisoned null page that must never reach the result."""
+        """One row per length of interest against the jnp oracle, at cache
+        layer 1 of 3: live pages scattered out of order over the pool,
+        unused table entries on a NaN-poisoned null page and NaN in every
+        other cache layer, none of which may reach the result."""
         _, _, ps, dtype = _BLOCK_SHAPES[shape]
         n, block, lens, ker, ref = _blocked_case(shape, maxp)
         assert maxp % n and block == n * ps
@@ -147,21 +180,101 @@ class TestRaggedPagedAttention:
         assert pages(2, 8, 16, 4, 4) == 4         # never wider than the table
         assert pages(2, 256, 16, 4, 4) == 1       # a page wider than a block
         got = [pages(20, 16, 64, 2, 64, vmem_budget=kb << 10)
-               for kb in (1, 256, 1024, 2048, 4096, 1 << 20)]
+               for kb in (1, 256, 512, 1024, 2048, 1 << 20)]
         assert got == sorted(got) and got[0] == 1 and got[-1] == 8
         assert 1 < got[2] < 8                     # the budget binds in between
 
+    @pytest.mark.parametrize("heads,head_dim,shape", [
+        (20, 64, (36, 10, 1537, 16, 128)),   # gpt2-large: two heads a group
+        (16, 128, (192, 16, 145, 16, 128)),  # ouro-2.6b: plain head-major
+        (5, 64, (36, 3, 1537, 16, 128)),     # odd: the last group half padding
+        (2, 16, (36, 1, 1537, 16, 32)),      # fewer heads than the lanes hold
+        (4, 256, (36, 4, 1537, 16, 256)),    # wider than the lanes
+    ])
+    def test_pool_shape_is_lane_whole(self, heads, head_dim, shape):
+        layers, _, pages, ps, _ = shape
+        assert PA.kv_pool_shape(layers, heads, pages, ps, head_dim) == shape
+        kc, vc = PA.init_kv_pages(2, heads, 3, ps, head_dim, jnp.bfloat16)
+        assert kc.shape == vc.shape == (2, *shape[1:2], 3, *shape[3:])
+
     def test_write_then_read_round_trip(self, rng_np):
-        kc, vc = PA.init_kv_pages(1, 2, 8, 4, 16)
+        kc, vc = PA.init_kv_pages(3, 2, 8, 4, 16)
         pt = jnp.asarray(np.array([[1, 2], [3, 0]], np.int32))
         k = rng_np.normal(size=(2, 2, 16)).astype(np.float32)
         v = rng_np.normal(size=(2, 2, 16)).astype(np.float32)
         # row 0 writes position 5 (page 2, off 1); row 1 position 2
-        kc1, vc1 = PA.write_decode_kv(kc[0], vc[0], jnp.asarray(k),
-                                      jnp.asarray(v), pt,
+        kc1, vc1 = PA.write_decode_kv(kc, vc, jnp.asarray(k),
+                                      jnp.asarray(v), 1, pt,
                                       jnp.asarray([5, 2]))
-        np.testing.assert_allclose(np.asarray(kc1)[:, 2, 1], k[0])
-        np.testing.assert_allclose(np.asarray(vc1)[:, 3, 2], v[1])
+        # both heads share lane group 0: head h in lanes [16 h, 16 h + 16)
+        np.testing.assert_allclose(
+            np.asarray(kc1)[1, 0, 2, 1, :32].reshape(2, 16), k[0])
+        np.testing.assert_allclose(
+            np.asarray(vc1)[1, 0, 3, 2, :32].reshape(2, 16), v[1])
+        want = as_pool(np.zeros((2, 8, 4, 16)), 3)
+        want[1, 0, 2, 1, :32], want[1, 0, 3, 2, :32] = k[0].ravel(), k[1].ravel()
+        np.testing.assert_array_equal(np.asarray(kc1), want)
+
+    @pytest.mark.parametrize("heads,head_dim", [(4, 64), (3, 64), (2, 128)])
+    @pytest.mark.parametrize("write", ["decode", "chunk", "prefill"])
+    def test_write_touches_only_its_cache_layer(self, rng_np, write, heads,
+                                                head_dim):
+        """A write at cache layer 1 leaves every other layer's pages, and
+        every page of layer 1 it does not name, bit-identical; what it
+        wrote reads back through the oracle's gather; a whole-stack
+        prefill writes every layer."""
+        layers, pages, ps, b, t = 3, 12, 4, 2, 8
+        shape = PA.kv_pool_shape(layers, heads, pages, ps, head_dim)
+        kc = jnp.asarray(rng_np.normal(size=shape).astype(np.float32))
+        vc = jnp.asarray(rng_np.normal(size=shape).astype(np.float32))
+        pt = jnp.asarray(np.array([[1, 2, 3], [4, 5, 0]], np.int32))
+        lens = jnp.asarray([7, 5])
+        new = lambda *lead: jnp.asarray(rng_np.normal(
+            size=(*lead, heads, head_dim)).astype(np.float32))
+        if write == "decode":
+            k, v = new(b), new(b)
+            kc1, vc1 = PA.write_decode_kv(kc, vc, k, v, 1, pt, lens - 1)
+            named = [(1, 2, int(lens[0] - 1) % ps), (1, 5, int(lens[1] - 1) % ps)]
+        elif write == "chunk":
+            k, v = new(b, t), new(b, t)
+            starts = jnp.asarray([2, 0])
+            kc1, vc1 = PA.write_chunk_kv(kc, vc, k, v, 1, pt, starts,
+                                         lens - starts)
+        else:
+            k, v = new(layers, b, t), new(layers, b, t)
+            kc1, vc1 = PA.write_prefill_kv(kc, vc, k, v, pt, lens)
+        before, after = np.asarray(kc), np.asarray(kc1)
+        changed = np.argwhere((before != after).any(axis=(1, 4)))
+        where = {tuple(int(i) for i in c) for c in changed}  # (layer, page, row)
+        rows = lambda b_, lo, hi: {(int(pt[b_, p // ps]), p % ps)
+                                   for p in range(lo, hi)}
+        if write == "decode":
+            assert where == set(named)
+        where -= {(l, 0, r) for l in range(layers) for r in range(ps)}
+        if write == "chunk":  # the null page takes the padding
+            assert where == {(1, *r) for r in rows(0, 2, 7) | rows(1, 0, 5)}
+        elif write == "prefill":  # whole pages of every layer
+            assert where == {(l, int(pg), r) for l in range(layers)
+                             for pg in (1, 2, 4, 5) for r in range(ps)}
+        assert not (np.asarray(vc) != np.asarray(vc1)).any(
+            axis=(1, 4))[[l for l in range(layers)
+                          if write != "prefill" and l != 1]].any()
+        # read back through the oracle's gather: [B, H, maxp * ps, D]
+        got = np.asarray(PA._gather_context(kc1, 1, pt, heads, head_dim))
+        if write == "decode":
+            for b_ in range(b):
+                np.testing.assert_array_equal(got[b_, :, int(lens[b_]) - 1],
+                                              np.asarray(k)[b_])
+        elif write == "chunk":
+            np.testing.assert_array_equal(
+                got[0, :, 2:7], np.asarray(k)[0, :5].swapaxes(0, 1))
+            np.testing.assert_array_equal(
+                got[1, :, 0:5], np.asarray(k)[1, :5].swapaxes(0, 1))
+        else:
+            for b_ in range(b):
+                n = int(lens[b_])
+                np.testing.assert_array_equal(
+                    got[b_, :, :n], np.asarray(k)[1, b_, :n].swapaxes(0, 1))
 
 
 class TestBitExactDecode:
