@@ -352,9 +352,11 @@ def memory_report(params, opt_state, states, feed, mesh=None, *,
 def serving_memory_report(cfg, serving, params=None, cache=None) -> dict:
     """Static per-device byte accounting of the SERVING path: the paged
     KV pool (k AND v, each ``paged_attention.kv_pool_shape`` at the model
-    dtype: ``cache layers × heads × pages × page_size × head_dim``, the
+    dtype: ``cache layers × kv_heads × pages × page_size × head_dim``, the
     heads rounded up to whole lane groups; cache layers = ``num_layers ×
-    loop_steps``: a looped stack keeps one cache per pass) next to the
+    loop_steps``: a looped stack keeps one cache per pass; under a layer
+    pattern its attention layers) and the float32 recurrent-state pool
+    (``state_layers × max_slots`` rows of ``cfg.state_shapes``) next to the
     servable params — the same artifact :func:`memory_report` computes for training, so an
     oversized pool is a preflight failure, not an OOM at the first
     admission.  ``cfg`` is a TransformerConfig, ``serving`` a
@@ -371,17 +373,21 @@ def serving_memory_report(cfg, serving, params=None, cache=None) -> dict:
     from paddle_tpu.ops.pallas.paged_attention import kv_pool_shape
 
     per_pool = int(np.prod(kv_pool_shape(
-        cfg.cache_layers, cfg.num_heads, serving.num_pages,
+        cfg.cache_layers, cfg.kv_heads, serving.num_pages,
         serving.page_size, cfg.head_dim))) * int(np.dtype(cfg.dtype).itemsize)
     kv = 2 * per_pool  # k and v pools
+    state = 4 * cfg.state_layers * int(serving.max_slots) * sum(
+        int(np.prod(shape)) for shape in cfg.state_shapes.values()
+    ) if cfg.state_layers else 0
     p_bytes = tree_bytes(params) if params is not None else 0
     report = {
         "kv_pool_bytes": kv,
+        "state_pool_bytes": state,
         "params_bytes": p_bytes,
         "num_pages": int(serving.num_pages),
         "page_size": int(serving.page_size),
         "dtype": np.dtype(cfg.dtype).name,
-        "total_bytes": kv + p_bytes,
+        "total_bytes": kv + state + p_bytes,
     }
     if cache is not None:
         page_bytes = kv // max(int(serving.num_pages), 1)

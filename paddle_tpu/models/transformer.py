@@ -31,6 +31,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from paddle_tpu.ops import attention as attn_ops
 
 
+# a layer pattern's characters -> the kind's name in ``params["blocks"]``
+_KINDS = {"*": "attn", "-": "mlp", "E": "moe", "M": "mamba"}
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
@@ -65,9 +69,24 @@ class TransformerConfig:
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 1e-2
     moe_dispatch: str = "sort"  # "einsum" = dense one-hot GShard tensors
+    # "softmax" = the capacity paths above (top-1/2, overflow dropped);
+    # "sigmoid" = dropless routing (``parallel.moe.moe_routed``): sigmoid
+    # scores over all ``moe_experts`` in float32, top-``moe_top_k`` of
+    # score + a selection-only bias, normalised and scaled by
+    # ``moe_scale``; experts are ``mlp_dim`` wide with no gate and no
+    # bias; a shared expert of ``moe_shared_dim`` (0 = none) takes every
+    # token.  ``moe_held`` = [lo, hi): the experts THIS device holds and
+    # computes (None = all) — expert parallelism's share of the layer
+    moe_router: str = "softmax"
+    moe_scale: float = 1.0
+    moe_shared_dim: int = 0
+    moe_held: tuple | None = None
     # -- the block's parts; the defaults are the GPT-2 block -------------
     # width of one attention head; None = embed_dim // num_heads
     head_dim: int | None = None
+    # K/V heads; None = num_heads.  Fewer: query head h reads K/V head
+    # h // (num_heads // kv_heads), and the cache holds kv_heads
+    kv_heads: int | None = None
     # "layer" (scale + bias) | "rms" (scale only); ``norm_sandwich`` adds a
     # second norm on each branch's OUTPUT, before the residual add
     norm: str = "layer"
@@ -75,10 +94,12 @@ class TransformerConfig:
     norm_sandwich: bool = False
     # "learned" (a [max_seq_len, E] table added to the embedding) |
     # "rotary" (rotate-half RoPE on q and k at ``rope_theta``; no table,
-    # so max_seq_len costs nothing)
+    # so max_seq_len costs nothing) | "none" (order comes from layers
+    # that read the sequence in order: a recurrent layer, causal masking)
     positions: str = "learned"
     rope_theta: float = 1e4
     # "gelu" (w_in/w_out with biases) | "swiglu" (gate/up/down, no bias)
+    # | "relu2" (w_in/w_out, squared ReLU, no bias)
     mlp: str = "gelu"
     tie_embeddings: bool = True  # False: a separate [E, V] "head"
     # looped stack: the SAME num_layers weights run ``loop_steps`` times,
@@ -88,17 +109,67 @@ class TransformerConfig:
     # no token leaves early and the served path never evaluates them.
     loop_steps: int = 1
     early_exit_threshold: float = 1.0
+    # layers of several kinds: one character per layer, each layer ONE
+    # mixer behind a pre-norm and a residual add — "*" attention, "-" the
+    # dense MLP, "E" routed experts (``moe_router="sigmoid"``), "M" a
+    # Mamba-2 mixer.  None = ``num_layers`` blocks of (attention, MLP).
+    # ``params["blocks"]`` is then a list of per-layer trees in pattern
+    # order, walked by the pattern.
+    pattern: str | None = None
+    # the Mamba-2 mixer: ``mamba_heads`` heads of ``mamba_head_dim``,
+    # ``mamba_state`` state columns, B/C in ``mamba_groups`` groups, a
+    # depthwise causal conv of ``mamba_conv`` taps, prefill in chunks of
+    # ``mamba_chunk``
+    mamba_heads: int = 0
+    mamba_head_dim: int = 64
+    mamba_state: int = 128
+    mamba_groups: int = 1
+    mamba_conv: int = 4
+    mamba_chunk: int = 128
 
     def __post_init__(self):
         if self.head_dim is None:
             object.__setattr__(self, "head_dim",
                                self.embed_dim // self.num_heads)
+        if self.kv_heads is None:
+            object.__setattr__(self, "kv_heads", self.num_heads)
+        if self.moe_held is not None:
+            object.__setattr__(self, "moe_held", tuple(self.moe_held))
         for field, allowed in (("norm", ("layer", "rms")),
-                               ("positions", ("learned", "rotary")),
-                               ("mlp", ("gelu", "swiglu"))):
+                               ("positions", ("learned", "rotary", "none")),
+                               ("mlp", ("gelu", "swiglu", "relu2")),
+                               ("moe_router", ("softmax", "sigmoid"))):
             if getattr(self, field) not in allowed:
                 raise ValueError(f"{field} must be one of {allowed}, got "
                                  f"{getattr(self, field)!r}")
+        if self.num_heads % self.kv_heads:
+            raise ValueError(f"num_heads {self.num_heads} is not a multiple "
+                             f"of kv_heads {self.kv_heads}")
+        if self.moe_experts and self.moe_router == "sigmoid":
+            if self.mlp == "swiglu":
+                raise ValueError("routed experts have no gate: mlp must be "
+                                 "'gelu' or 'relu2' under moe_router="
+                                 "'sigmoid'")
+            self.routed  # validates top_k and the held share
+        if self.pattern is not None:
+            bad = sorted(set(self.pattern) - set(_KINDS))
+            if bad or len(self.pattern) != self.num_layers:
+                raise ValueError(
+                    f"pattern {self.pattern!r} must be num_layers "
+                    f"({self.num_layers}) characters of {sorted(_KINDS)}")
+            if "E" in self.pattern and not (
+                    self.moe_experts and self.moe_router == "sigmoid"):
+                raise ValueError("an 'E' layer needs moe_experts > 0 and "
+                                 "moe_router='sigmoid'")
+            if "M" in self.pattern and not (
+                    self.mamba_heads and self.mamba_heads
+                    % self.mamba_groups == 0):
+                raise ValueError("an 'M' layer needs mamba_heads > 0, a "
+                                 "multiple of mamba_groups")
+            if self.loop_steps > 1 or self.norm_sandwich:
+                raise NotImplementedError(
+                    "a layer pattern with loop_steps > 1 or norm_sandwich: "
+                    "the pattern walk runs one pass of pre-norm layers")
         if self.loop_steps < 1:
             raise ValueError(f"loop_steps must be >= 1, got {self.loop_steps}")
         if self.early_exit_threshold < 1.0:
@@ -110,8 +181,33 @@ class TransformerConfig:
 
     @property
     def cache_layers(self) -> int:
-        """K/V cache layers a served token occupies: one per (pass, layer)."""
+        """K/V cache layers a served token occupies: one per (pass,
+        attention layer)."""
+        if self.pattern is not None:
+            return self.pattern.count("*")
         return self.num_layers * self.loop_steps
+
+    @property
+    def state_layers(self) -> int:
+        """Layers that keep a fixed recurrent state per sequence."""
+        return self.pattern.count("M") if self.pattern is not None else 0
+
+    @property
+    def state_shapes(self) -> dict:
+        """One state layer's state of one sequence: name -> shape."""
+        from paddle_tpu.ops import mamba2
+
+        return mamba2.state_shapes(self.mamba_heads, self.mamba_head_dim,
+                                   self.mamba_state, self.mamba_groups,
+                                   self.mamba_conv)
+
+    @property
+    def routed(self):
+        from paddle_tpu.parallel.moe import RoutedConfig
+
+        return RoutedConfig(num_experts=self.moe_experts,
+                            top_k=self.moe_top_k, scale=self.moe_scale,
+                            held=self.moe_held, act=self.mlp)
 
     @property
     def moe(self):
@@ -126,38 +222,105 @@ class TransformerConfig:
                          dispatch=self.moe_dispatch)
 
 
+def _ffn_params(cfg: TransformerConfig, norm, zeros, lead: tuple,
+                depth: int, experts: bool = True) -> dict:
+    """The feed-forward leaves of ``lead`` stacked layers, by the config's
+    parts (``experts`` False: the dense MLP of a config that also has
+    expert layers); ``depth`` scales the output matrices down (GPT-2's
+    residual scaling)."""
+    e, m = cfg.embed_dim, cfg.mlp_dim
+    experts = experts and cfg.moe_experts
+    if experts and cfg.moe_router == "sigmoid":
+        ex, held = cfg.moe_experts, cfg.routed.num_held
+        p = {"router": norm(*lead, e, ex) * (e ** -0.5),
+             "router_bias": zeros(*lead, ex),
+             "w_in": norm(*lead, held, e, m) * (e ** -0.5),
+             "w_out": norm(*lead, held, m, e) * (m ** -0.5)
+             / (2 * depth) ** 0.5}
+        if cfg.moe_shared_dim:
+            sh = cfg.moe_shared_dim
+            p["shared_in"] = norm(*lead, e, sh) * (e ** -0.5)
+            p["shared_out"] = norm(*lead, sh, e) * (sh ** -0.5) \
+                / (2 * depth) ** 0.5
+        return p
+    if experts:
+        ex = cfg.moe_experts
+        return {
+            "wg": norm(*lead, e, ex) * (e ** -0.5),
+            "w1": norm(*lead, ex, e, m) * (2.0 / e) ** 0.5,
+            "b1": zeros(*lead, ex, m),
+            "w2": norm(*lead, ex, m, e) * (m ** -0.5) / (2 * depth) ** 0.5,
+            "b2": zeros(*lead, ex, e),
+        }
+    if cfg.mlp == "swiglu":
+        return {
+            "w_gate": norm(*lead, e, m) * (e ** -0.5),
+            "w_up": norm(*lead, e, m) * (e ** -0.5),
+            "w_out": norm(*lead, m, e) * (m ** -0.5) / (2 * depth) ** 0.5,
+        }
+    if cfg.mlp == "relu2":
+        return {"w_in": norm(*lead, e, m) * (e ** -0.5),
+                "w_out": norm(*lead, m, e) * (m ** -0.5) / (2 * depth) ** 0.5}
+    return {
+        "w_in": norm(*lead, e, m) * (e ** -0.5),
+        "b_in": zeros(*lead, m),
+        "w_out": norm(*lead, m, e) * (m ** -0.5) / (2 * depth) ** 0.5,
+        "b_out": zeros(*lead, e),
+    }
+
+
+def _pattern_params(cfg: TransformerConfig, norm, zeros, norm_p) -> list:
+    """``params["blocks"]`` under a layer pattern: one tree per LAYER, in
+    pattern order, its leaves by the layer's kind; every layer carries its
+    pre-norm (``ln_g``, ``ln_b``).  Nothing is stacked: a layer's matrices
+    are arrays of their own, so no program can copy or slice a stack to
+    reach them (stacked per kind and indexed statically, XLA copied every
+    639 MB expert matrix of the prefill program: 6 GB of temporaries)."""
+    e, s = cfg.embed_dim, cfg.num_layers
+    h, hk = cfg.num_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+    nh = cfg.mamba_heads
+    di = nh * cfg.mamba_head_dim
+    conv_dim = di + 2 * cfg.mamba_groups * cfg.mamba_state
+
+    def layer(kind):
+        if kind == "attn":
+            return {"wq": norm(e, h) * (e ** -0.5),
+                    "wk": norm(e, hk) * (e ** -0.5),
+                    "wv": norm(e, hk) * (e ** -0.5),
+                    "wo": norm(h, e) * (h ** -0.5) / (2 * s) ** 0.5}
+        if kind in ("mlp", "moe"):
+            return _ffn_params(cfg, norm, zeros, (), s, kind == "moe")
+        # dt_bias around softplus^-1(0.01) and A = -exp(a_log) <= -1 (the
+        # usual ranges); drawn, not constant, so a swapped head shows
+        return {
+            "in_proj": norm(e, 2 * di + 2 * cfg.mamba_groups
+                            * cfg.mamba_state + nh) * (e ** -0.5),
+            "conv_w": norm(cfg.mamba_conv, conv_dim)
+            * (cfg.mamba_conv ** -0.5),
+            "conv_b": zeros(conv_dim),
+            "dt_bias": -4.6 + 0.5 * norm(nh),
+            "a_log": jnp.abs(norm(nh)),
+            "d": jnp.ones((nh,), cfg.dtype),
+            "norm_g": jnp.ones((di,), cfg.dtype),
+            "out_proj": norm(di, e) * (di ** -0.5) / (2 * s) ** 0.5}
+
+    return [{**norm_p("ln"), **layer(_KINDS[c])} for c in cfg.pattern]
+
+
 def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
-    """Stacked-layer params: block weights have leading dim num_layers.
+    """Stacked-layer params: block weights have leading dim num_layers
+    (under a layer ``pattern``: a list of per-layer trees,
+    ``_pattern_params``).
     Which leaves exist follows the config's parts (norm biases only under
     "layer", ``pos_embed`` only under "learned" positions, ``head`` only
     when untied, the exit gate only for a looped stack)."""
-    e, h, m, v_sz = cfg.embed_dim, cfg.num_heads * cfg.head_dim, cfg.mlp_dim, cfg.vocab_size
+    e, h, v_sz = cfg.embed_dim, cfg.num_heads * cfg.head_dim, cfg.vocab_size
+    hk = cfg.kv_heads * cfg.head_dim
     s = cfg.num_layers
-    k = iter(jax.random.split(key, 14))
+    k = iter(jax.random.split(
+        key, 14 if cfg.pattern is None else 8 + 8 * cfg.num_layers))
     norm = lambda *shape: jax.random.normal(next(k), shape, cfg.dtype)
     zeros = lambda *shape: jnp.zeros(shape, cfg.dtype)
-    if cfg.moe_experts:
-        ex = cfg.moe_experts
-        ffn = {
-            "wg": norm(s, e, ex) * (e ** -0.5),
-            "w1": norm(s, ex, e, m) * (2.0 / e) ** 0.5,
-            "b1": zeros(s, ex, m),
-            "w2": norm(s, ex, m, e) * (m ** -0.5) / (2 * s) ** 0.5,
-            "b2": zeros(s, ex, e),
-        }
-    elif cfg.mlp == "swiglu":
-        ffn = {
-            "w_gate": norm(s, e, m) * (e ** -0.5),
-            "w_up": norm(s, e, m) * (e ** -0.5),
-            "w_out": norm(s, m, e) * (m ** -0.5) / (2 * s) ** 0.5,
-        }
-    else:
-        ffn = {
-            "w_in": norm(s, e, m) * (e ** -0.5),
-            "b_in": zeros(s, m),
-            "w_out": norm(s, m, e) * (m ** -0.5) / (2 * s) ** 0.5,
-            "b_out": zeros(s, e),
-        }
 
     def norm_p(name, *lead):
         p = {f"{name}_g": jnp.ones((*lead, e), cfg.dtype)}
@@ -165,14 +328,25 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
             p[f"{name}_b"] = zeros(*lead, e)
         return p
 
+    if cfg.pattern is not None:
+        params = {"embed": norm(v_sz, e) * (e ** -0.5)}
+        if cfg.positions == "learned":
+            params["pos_embed"] = norm(cfg.max_seq_len, e) * 0.02
+        params["blocks"] = _pattern_params(cfg, norm, zeros, norm_p)
+        params.update(norm_p("ln_f"))
+        if not cfg.tie_embeddings:
+            params["head"] = norm(e, v_sz) * (e ** -0.5)
+        return params
+
+    ffn = _ffn_params(cfg, norm, zeros, (s,), s)
     params = {"embed": norm(v_sz, e) * (e ** -0.5)}
     pos = norm(cfg.max_seq_len, e) * 0.02 if cfg.positions == "learned" \
         else None
     blocks = {
         **norm_p("ln1", s),
         "wq": norm(s, e, h) * (e ** -0.5),
-        "wk": norm(s, e, h) * (e ** -0.5),
-        "wv": norm(s, e, h) * (e ** -0.5),
+        "wk": norm(s, e, hk) * (e ** -0.5),
+        "wv": norm(s, e, hk) * (e ** -0.5),
         "wo": norm(s, h, e) * (h ** -0.5) / (2 * s) ** 0.5,
         **norm_p("ln2", s),
         **ffn,
@@ -195,6 +369,12 @@ def param_shardings(cfg: TransformerConfig) -> dict:
     (axis names degrade to replicated if absent from the mesh via
     MeshContext.param_sharding semantics; used directly with NamedSharding
     they must exist)."""
+    if cfg.pattern is not None or (cfg.moe_experts
+                                   and cfg.moe_router == "sigmoid"):
+        raise NotImplementedError(
+            "param_shardings: no tensor- or expert-parallel layout is "
+            "written for a layer pattern or for dropless routed experts "
+            "(the expert exchange across devices is not built)")
     col, row = P(None, None, "model"), P(None, "model", None)
     if cfg.moe_experts:
         # experts over the "expert" axis (layer-stack dim first)
@@ -207,6 +387,8 @@ def param_shardings(cfg: TransformerConfig) -> dict:
         }
     elif cfg.mlp == "swiglu":
         ffn = {"w_gate": col, "w_up": col, "w_out": row}
+    elif cfg.mlp == "relu2":
+        ffn = {"w_in": col, "w_out": row}
     else:
         ffn = {"w_in": col, "b_in": P(None, "model"),
                "w_out": row, "b_out": P()}
@@ -296,6 +478,8 @@ def _embed(cfg: TransformerConfig, params, ids, positions=None):
         x = x + (params["pos_embed"][:ids.shape[1]][None]
                  if positions is None else params["pos_embed"][positions])
         return x, None
+    if cfg.positions == "none":
+        return x, None
     return x, _rope_table(cfg, jnp.arange(ids.shape[1])[None]
                           if positions is None else positions)
 
@@ -305,6 +489,11 @@ def _head(cfg: TransformerConfig, params, x):
 
 
 def _attention(cfg: TransformerConfig, q, k, v, mesh):
+    if cfg.kv_heads != cfg.num_heads:
+        # contiguous attention over [B, T, H, Dh]: every query head gets
+        # its K/V head's copy (the cache keeps the kv_heads that exist)
+        rep = cfg.num_heads // cfg.kv_heads
+        k, v = jnp.repeat(k, rep, axis=-2), jnp.repeat(v, rep, axis=-2)
     if cfg.attn_impl in ("ring", "ulysses"):
         assert mesh is not None and "seq" in mesh.axis_names, (
             f"{cfg.attn_impl} attention needs a mesh with a 'seq' axis"
@@ -354,15 +543,61 @@ def _attention(cfg: TransformerConfig, q, k, v, mesh):
     )
 
 
+def _qkv(cfg: TransformerConfig, h, layer, rope):
+    """Normed states h [..., E] -> q [..., H, Dh], k and v [..., KV, Dh],
+    RoPE applied from ``rope``."""
+    lead, hd = h.shape[:-1], cfg.head_dim
+    q = (h @ layer["wq"]).reshape(*lead, cfg.num_heads, hd)
+    k = (h @ layer["wk"]).reshape(*lead, cfg.kv_heads, hd)
+    v = (h @ layer["wv"]).reshape(*lead, cfg.kv_heads, hd)
+    if rope is not None:
+        q, k = _rope(q, rope), _rope(k, rope)
+    return q, k, v
+
+
+def _mlp(cfg: TransformerConfig, h, layer, mesh=None, live=None,
+         experts: bool = True):
+    """The feed-forward branch over normed states h [..., E], by the
+    config's parts (``experts`` False: the dense MLP of a config that
+    also has expert layers): (y, the output bias to add or None, aux).
+    aux is the capacity MoE's load-balancing loss, the routed MoE's
+    counts (``parallel.moe.moe_routed``), None for a dense FFN."""
+    experts = experts and cfg.moe_experts
+    if experts and cfg.moe_router == "sigmoid":
+        from paddle_tpu.parallel.moe import moe_routed
+
+        y, counts = moe_routed(layer, h, cfg.routed, live)
+        return y, None, counts
+    if experts:
+        from paddle_tpu.parallel.moe import moe_ffn, moe_ffn_sharded
+
+        moe_p = {n: layer[n] for n in ("wg", "w1", "b1", "w2", "b2")}
+        if mesh is not None and "expert" in mesh.axis_names:
+            y, aux = moe_ffn_sharded(moe_p, h, cfg.moe, mesh)
+        else:
+            y, aux = moe_ffn(moe_p, h, cfg.moe)
+        return y, None, aux
+    if cfg.mlp == "swiglu":
+        return (jax.nn.silu(h @ layer["w_gate"]) * (h @ layer["w_up"])
+                ) @ layer["w_out"], None, None
+    if cfg.mlp == "relu2":
+        return jnp.square(jax.nn.relu(h @ layer["w_in"])) @ layer["w_out"], \
+            None, None
+    h = jax.nn.gelu(h @ layer["w_in"] + layer["b_in"])
+    return h @ layer["w_out"], layer["b_out"], None
+
+
 def _block(cfg: TransformerConfig, x, layer, attend, rope=None, mesh=None,
            remat_dots=False):
-    """THE decoder block; x [..., E] (training and prefill [B, T, E],
-    decode [B, E]).  ``attend(q, k, v) -> (a, kept)`` is the caller's
-    cache write and attention over q/k/v [..., H, Dh] (RoPE already
-    applied from ``rope``); ``kept`` is handed back untouched — the K/V a
-    prefill captures, the pools a chunk or decode pass updated, None in
-    training.  Returns (x, aux, kept); aux is the MoE load-balancing
-    loss, None for a dense FFN.
+    """THE decoder block of a homogeneous stack — the pair (attention,
+    feed-forward) of the residual-branch units a layer ``pattern`` walks
+    one at a time (``_run_pattern``); x [..., E] (training and prefill
+    [B, T, E], decode [B, E]).  ``attend(q, k, v) -> (a, kept)`` is the
+    caller's cache write and attention over q [..., H, Dh] and k/v
+    [..., KV, Dh] (RoPE already applied from ``rope``); ``kept`` is handed
+    back untouched — the K/V a prefill captures, the pools a chunk or
+    decode pass updated, None in training.  Returns (x, aux, kept); aux
+    is the capacity MoE's load-balancing loss, None otherwise.
 
     ``remat_dots`` checkpoints the two dense segments with the
     dots-saveable policy while leaving the attention call OUTSIDE any
@@ -374,13 +609,7 @@ def _block(cfg: TransformerConfig, x, layer, attend, rope=None, mesh=None,
     nh, hd = cfg.num_heads, cfg.head_dim
 
     def qkv_fn(x, layer):
-        h = _norm(cfg, x, layer, "ln1")
-        q = (h @ layer["wq"]).reshape(*lead, nh, hd)
-        k = (h @ layer["wk"]).reshape(*lead, nh, hd)
-        v = (h @ layer["wv"]).reshape(*lead, nh, hd)
-        if rope is not None:
-            q, k = _rope(q, rope), _rope(k, rope)
-        return q, k, v
+        return _qkv(cfg, _norm(cfg, x, layer, "ln1"), layer, rope)
 
     def branch_out(x, y, bias, post):
         """The residual add of one branch: under a sandwich the branch's
@@ -395,22 +624,9 @@ def _block(cfg: TransformerConfig, x, layer, attend, rope=None, mesh=None,
     def tail_fn(x, a, layer):
         x = branch_out(x, a.reshape(*lead, nh * hd) @ layer["wo"], None,
                        "ln1_post")
-        h = _norm(cfg, x, layer, "ln2")
-        aux, bias = None, None
-        if cfg.moe_experts:
-            from paddle_tpu.parallel.moe import moe_ffn, moe_ffn_sharded
-
-            moe_p = {n: layer[n] for n in ("wg", "w1", "b1", "w2", "b2")}
-            if mesh is not None and "expert" in mesh.axis_names:
-                y, aux = moe_ffn_sharded(moe_p, h, cfg.moe, mesh)
-            else:
-                y, aux = moe_ffn(moe_p, h, cfg.moe)
-        elif cfg.mlp == "swiglu":
-            y = (jax.nn.silu(h @ layer["w_gate"]) * (h @ layer["w_up"])
-                 ) @ layer["w_out"]
-        else:
-            h = jax.nn.gelu(h @ layer["w_in"] + layer["b_in"])
-            y, bias = h @ layer["w_out"], layer["b_out"]
+        y, bias, aux = _mlp(cfg, _norm(cfg, x, layer, "ln2"), layer, mesh)
+        if cfg.moe_router == "sigmoid":
+            aux = None  # routing counts: the pattern walk's to report
         return branch_out(x, y, bias, "ln2_post"), aux
 
     if remat_dots:
@@ -421,6 +637,73 @@ def _block(cfg: TransformerConfig, x, layer, attend, rope=None, mesh=None,
     a, kept = attend(q, k, v)
     x, aux = tail_fn(x, a, layer)
     return x, aux, kept
+
+
+def _mamba_mixer(cfg: TransformerConfig, h, layer, conv, ssd):
+    """The Mamba-2 mixer over normed states h [..., E].  ``conv(xbc, w,
+    bias)`` and ``ssd(x, dt, a, b, c, d)`` are the caller's arrangement of
+    the causal convolution and of the recurrence (whole padded prompts in
+    prefill, one token against the state pool in decode:
+    ``ops/mamba2.py``), both returning float32."""
+    f32 = jnp.float32
+    lead = h.shape[:-1]
+    nh, p, g, n = (cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_groups,
+                   cfg.mamba_state)
+    di = nh * p
+    z, xbc, dt = jnp.split(h @ layer["in_proj"], [di, 2 * di + 2 * g * n],
+                           axis=-1)
+    xbc = jax.nn.silu(conv(xbc, layer["conv_w"], layer["conv_b"])
+                      ).astype(h.dtype)
+    x, b, c = jnp.split(xbc, [di, di + g * n], axis=-1)
+    dt = jax.nn.softplus(dt.astype(f32) + layer["dt_bias"].astype(f32))
+    y = ssd(x.reshape(*lead, nh, p), dt, -jnp.exp(layer["a_log"].astype(f32)),
+            b.reshape(*lead, g, n), c.reshape(*lead, g, n), layer["d"])
+    # gate, then RMSNorm over each of the g groups of d_inner / g
+    y = (y.reshape(*lead, di) * jax.nn.silu(z.astype(f32))
+         ).reshape(*lead, g, di // g)
+    y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + cfg.norm_eps)
+    y = y.reshape(*lead, di).astype(h.dtype) * layer["norm_g"]
+    return y @ layer["out_proj"]
+
+
+def _run_pattern(cfg: TransformerConfig, params, x, rope, attend, mamba,
+                 live=None, mesh=None):
+    """The stack of a layer ``pattern``: every layer is ``x + mixer(norm(
+    x))``, the mixer by the layer's character; the final norm closes it.
+    ``params["blocks"]`` is the list of the layers' own trees, so nothing
+    is sliced out of a stack, statically or dynamically.
+
+    ``attend(i, q, k, v) -> a`` is the caller's cache write and attention
+    of the ``i``-th attention layer (= cache layer ``i``); ``mamba(i) ->
+    (conv, ssd)`` the ``i``-th state layer's arrangement
+    (``_mamba_mixer``); both keep what they must hand back in the
+    caller's own variables.  ``live`` (bool, x's leading shape) marks the
+    rows that are tokens, for the routing counts.  Returns (x, counts):
+    the routed layers' ``moe_routed`` counts summed (the busiest expert's
+    tokens: the largest), None without routed layers."""
+    lead = x.shape[:-1]
+    seen = dict.fromkeys(_KINDS.values(), 0)
+    counts = None
+    for c, layer in zip(cfg.pattern, params["blocks"]):
+        kind = _KINDS[c]
+        i = seen[kind]       # the layer's index among its kind
+        seen[kind] += 1
+        h = _norm(cfg, x, layer, "ln")
+        if kind == "attn":
+            a = attend(i, *_qkv(cfg, h, layer, rope))
+            y = a.reshape(*lead, cfg.num_heads * cfg.head_dim) @ layer["wo"]
+        elif kind == "mamba":
+            y = _mamba_mixer(cfg, h, layer, *mamba(i))
+        else:
+            y, bias, aux = _mlp(cfg, h, layer, mesh, live, kind == "moe")
+            if bias is not None:
+                y = y + bias
+            if kind == "moe":
+                counts = aux if counts is None else jnp.concatenate(
+                    [counts[:3] + aux[:3],
+                     jnp.maximum(counts[3:], aux[3:])])
+        x = x + y
+    return _norm(cfg, x, params, "ln_f"), counts
 
 
 def _run_stack(cfg: TransformerConfig, params, x, layer_fn, pools=None,
@@ -480,6 +763,11 @@ def forward_with_aux(cfg: TransformerConfig, params: dict, ids: jax.Array,
     if cfg.remat != "dots" and not isinstance(cfg.remat, bool):
         raise ValueError(f"remat must be True, False or 'dots', got "
                          f"{cfg.remat!r}")
+    if cfg.pattern is not None:
+        # whole sequences, nothing kept (no remat policy is applied: the
+        # backward of the chunked scan is XLA's autodiff of it, untuned)
+        x, _, _ = _prefill_pattern(cfg, params, x, rope, None, mesh)
+        return _head(cfg, params, x), jnp.zeros((), jnp.float32)
     attn = functools.partial(_attention, cfg, mesh=mesh)
     if cfg.remat == "dots" and cfg.attn_impl != "flash":
         # non-custom-vjp impls would otherwise save O(T^2) softmax
@@ -525,13 +813,6 @@ def forward_with_aux(cfg: TransformerConfig, params: dict, ids: jax.Array,
 # (tests/test_looped_lm.py holds the compiled programs to it).
 
 
-def _dense_only(cfg: TransformerConfig) -> None:
-    if cfg.moe_experts:
-        raise NotImplementedError(
-            "serving prefill/decode cover the dense-FFN transformer; "
-            "quantized/MoE decode is future work")
-
-
 def _last_valid(x, seq_lens):
     return jnp.take_along_axis(
         x, jnp.maximum(seq_lens - 1, 0)[:, None, None], axis=1)[:, 0]
@@ -548,8 +829,11 @@ def forward_prefill(cfg: TransformerConfig, params: dict, ids: jax.Array,
     right-padding is exact; rows with ``seq_lens == 0`` (slack in a
     fixed-size prefill batch) produce garbage logits the caller
     discards."""
-    _dense_only(cfg)
     x, rope = _embed(cfg, params, ids)
+    if cfg.pattern is not None:
+        x, (ks, vs), extras = _prefill_pattern(cfg, params, x, rope,
+                                               seq_lens, mesh)
+        return _head(cfg, params, _last_valid(x, seq_lens)), ks, vs, extras
 
     def layer_fn(x, layer):
         x, _, kv = _block(
@@ -559,6 +843,42 @@ def forward_prefill(cfg: TransformerConfig, params: dict, ids: jax.Array,
 
     x, (ks, vs) = _run_stack(cfg, params, x, layer_fn)
     return _head(cfg, params, _last_valid(x, seq_lens)), ks, vs
+
+
+def _prefill_pattern(cfg: TransformerConfig, params, x, rope, seq_lens, mesh):
+    """Whole right-padded sequences x [B, T, E] through a layer pattern
+    (``seq_lens`` None = training: every position is a token).  Returns
+    (x, (ks, vs) [cache_layers, B, T, KV, Dh] or (None, None), extras)."""
+    from paddle_tpu.ops import mamba2
+
+    kept, state = [], {"ssm": [], "conv": []}
+
+    def attend(i, q, k, v):
+        kept.append((k, v))
+        return _attention(cfg, q, k, v, mesh)
+
+    def mamba(i):
+        def conv(xbc, w, bias):
+            out, last = mamba2.conv_prefill(xbc, w, bias, seq_lens)
+            state["conv"].append(last)
+            return out
+
+        def ssd(*args):
+            y, last = mamba2.ssd_prefill(*args, seq_lens=seq_lens,
+                                         chunk=cfg.mamba_chunk)
+            state["ssm"].append(last)
+            return y
+
+        return conv, ssd
+
+    live = None if seq_lens is None else (
+        jnp.arange(x.shape[1])[None, :] < seq_lens[:, None])
+    x, counts = _run_pattern(cfg, params, x, rope, attend, mamba, live, mesh)
+    ks, vs = ((jnp.stack([k for k, _ in kept]), jnp.stack([v for _, v in kept]))
+              if kept else (None, None))
+    return x, (ks, vs), {
+        "state": {n: jnp.stack(v) for n, v in state.items() if v},
+        "moe_counts": counts}
 
 
 def forward_prefill_chunk(cfg: TransformerConfig, params: dict,
@@ -579,7 +899,6 @@ def forward_prefill_chunk(cfg: TransformerConfig, params: dict,
     Returns (last-valid logits [B, V], k_cache', v_cache'): the row
     whose chunk completes its prompt samples its first token from these
     logits; mid-prompt rows' logits are discarded by the caller."""
-    _dense_only(cfg)
     from paddle_tpu.ops.pallas import paged_attention as pa
 
     b, c = ids.shape
@@ -588,13 +907,35 @@ def forward_prefill_chunk(cfg: TransformerConfig, params: dict,
     pos = jnp.clip(starts[:, None] + jnp.arange(c)[None, :], 0,
                    cfg.max_seq_len - 1)
     x, rope = _embed(cfg, params, ids, pos)
+    if cfg.pattern is not None:
+        if cfg.state_layers:
+            raise NotImplementedError(
+                "forward_prefill_chunk with state layers: a chunk would "
+                "have to start from its slot's recurrent state and leave "
+                "it behind, and a prefix-cache hit needs a snapshot of the "
+                "state at the shared prefix's last token; neither is built")
+        pools = [k_cache, v_cache]
+
+        def attend_chunk(i, q, k, v):
+            pools[:] = pa.write_chunk_kv(*pools, k, v, i, page_table, starts,
+                                         seq_lens)
+            return pa.paged_prefill_attention(
+                q, *pools, i, page_table, starts, seq_lens,
+                kv_heads=cfg.kv_heads)
+
+        live = jnp.arange(c)[None, :] < seq_lens[:, None]
+        x, counts = _run_pattern(cfg, params, x, rope, attend_chunk, None,
+                                 live)
+        return (_head(cfg, params, _last_valid(x, seq_lens)), *pools,
+                {"state": {}, "moe_counts": counts})
 
     def layer_fn(x, layer, cache_layer, kc, vc):
         def attend(q, k, v):
             pools = pa.write_chunk_kv(kc, vc, k, v, cache_layer, page_table,
                                       starts, seq_lens)
             return pa.paged_prefill_attention(
-                q, *pools, cache_layer, page_table, starts, seq_lens), pools
+                q, *pools, cache_layer, page_table, starts, seq_lens,
+                kv_heads=cfg.kv_heads), pools
 
         x, _, pools = _block(cfg, x, layer, attend, rope)
         return x, pools
@@ -607,8 +948,10 @@ def forward_prefill_chunk(cfg: TransformerConfig, params: dict,
 def forward_decode(cfg: TransformerConfig, params: dict, ids: jax.Array,
                    positions: jax.Array, seq_lens: jax.Array,
                    page_table: jax.Array, k_cache, v_cache,
-                   attn_impl: str = "auto", mesh=None):
-    """One incremental decode step over the paged KV-cache.
+                   attn_impl: str = "auto", mesh=None, state=None):
+    """One incremental decode step over the paged KV-cache (and, under a
+    pattern with state layers, over the ``state`` pools {part:
+    [state_layers, B, ...]}, row = batch row).
 
     ids [B] current tokens, positions [B] their absolute indices,
     seq_lens [B] = positions + 1 on live rows and 0 on idle rows,
@@ -622,10 +965,13 @@ def forward_decode(cfg: TransformerConfig, params: dict, ids: jax.Array,
     Pallas kernel on TPU, jnp reference elsewhere) — deliberately
     separate from ``cfg.attn_impl``, which describes TRAINING attention
     over contiguous sequences."""
-    _dense_only(cfg)
     from paddle_tpu.ops.pallas import paged_attention as pa
 
     x, rope = _embed(cfg, params, ids, positions)
+    if cfg.pattern is not None:
+        return _decode_pattern(cfg, params, x, rope, positions, seq_lens,
+                               page_table, k_cache, v_cache, attn_impl,
+                               state)
 
     def layer_fn(x, layer, cache_layer, kc, vc):
         def attend(q, k, v):
@@ -633,7 +979,7 @@ def forward_decode(cfg: TransformerConfig, params: dict, ids: jax.Array,
                                        positions)
             return pa.ragged_paged_attention(
                 q, *pools, cache_layer, page_table, seq_lens,
-                impl=attn_impl), pools
+                impl=attn_impl, kv_heads=cfg.kv_heads), pools
 
         x, _, pools = _block(cfg, x, layer, attend, rope)
         return x, pools
@@ -641,6 +987,50 @@ def forward_decode(cfg: TransformerConfig, params: dict, ids: jax.Array,
     x, (k_cache, v_cache) = _run_stack(cfg, params, x, layer_fn,
                                        (k_cache, v_cache))
     return _head(cfg, params, x), k_cache, v_cache
+
+
+def _decode_pattern(cfg: TransformerConfig, params, x, rope, positions,
+                    seq_lens, page_table, k_cache, v_cache, attn_impl, state):
+    """One token per row x [B, E] through a layer pattern.  Every pool —
+    K, V and the state parts — is rebound as it is updated, at a static
+    layer index: the buffers that entered the program are written where
+    they are."""
+    from paddle_tpu.ops import mamba2
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    pools = [k_cache, v_cache]
+    state = dict(state or {})
+    live = seq_lens > 0
+
+    def attend(i, q, k, v):
+        pools[:] = pa.write_decode_kv(*pools, k, v, i, page_table, positions)
+        return pa.ragged_paged_attention(q, *pools, i, page_table, seq_lens,
+                                         impl=attn_impl,
+                                         kv_heads=cfg.kv_heads)
+
+    def keep(name, i, new):
+        """Layer i's rows of pool ``name`` <- ``new`` on live rows."""
+        old = state[name][i]
+        mask = live.reshape(-1, *[1] * (old.ndim - 1))
+        state[name] = state[name].at[i].set(
+            jnp.where(mask, new.astype(old.dtype), old))
+
+    def mamba(i):
+        def conv(xbc, w, bias):
+            out, new = mamba2.conv_step(state["conv"][i], xbc, w, bias)
+            keep("conv", i, new)
+            return out
+
+        def ssd(*args):
+            y, new = mamba2.ssd_step(state["ssm"][i], *args)
+            keep("ssm", i, new)
+            return y
+
+        return conv, ssd
+
+    x, counts = _run_pattern(cfg, params, x, rope, attend, mamba, live)
+    return (_head(cfg, params, x), *pools,
+            {"state": state, "moe_counts": counts})
 
 
 def loss_fn(cfg: TransformerConfig, params: dict, ids: jax.Array,

@@ -47,7 +47,11 @@ Two interchangeable implementations of the attention itself:
   MXU passes.  The one decode query of a head rides 8 sublanes with zeros
   in the lanes of the other heads of its group, so a group's ``g`` heads
   are ``g · 8`` query rows whose extra products are exact zeros; each
-  head's own lanes of the output are kept by the caller.  The grid is ONE
+  head's own lanes of the output are kept by the caller.  Where the cache
+  holds fewer K/V heads than the model has query heads (``kv_heads``),
+  the ``rep = H / kv_heads`` query heads of a K/V head ride those rows
+  instead (``rep`` rounded up to 8): the pools, the blocks fetched and
+  the kernel body are the same.  The grid is ONE
   dimension over a work list built from ``seq_lens`` — every row's live
   blocks in order, an idle row one step that writes its zeros — and its
   length is a run-time value: a block wholly past ``seq_len`` is not a
@@ -55,8 +59,8 @@ Two interchangeable implementations of the attention itself:
   costs a compile.  Slots of a row's last block past its last live page
   name that page again (never the null page: what they hold is
   multiplied by ``p == 0`` and must be finite).  ``N`` comes from
-  ``decode_block_pages`` — from ``page_size``, ``head_dim``,
-  ``num_heads``, the pool dtype and the table's width — for every caller
+  ``decode_block_pages`` — from ``page_size``, ``head_dim``, the
+  cache's heads, the pool dtype and the table's width — for every caller
   alike: one MXU pass of score columns (128 tokens), less where VMEM or
   the table is smaller.  It is exactly one Mosaic call per cache layer;
   the benchmark's reducers count ``tpu_custom_call``s inside
@@ -224,8 +228,15 @@ def _gather_context(pool, cache_layer, page_table, num_heads, head_dim):
     return x.reshape(b, groups * g, maxp * ps, head_dim)[:, :num_heads]
 
 
+def _grouped(q, kv_heads):
+    """q [..., H, D] -> [..., KV, rep, D]: query head h reads K/V head
+    h // rep."""
+    *lead, h, d = q.shape
+    return q.reshape(*lead, kv_heads, h // kv_heads, d)
+
+
 def paged_prefill_attention(q, k_pool, v_pool, cache_layer, page_table,
-                            starts, seq_lens, scale=None):
+                            starts, seq_lens, scale=None, kv_heads=None):
     """Chunk-prefill attention: queries over the whole resident paged
     context of cache layer ``cache_layer`` (prefix caching + chunked
     prefill's compute path).
@@ -243,56 +254,59 @@ def paged_prefill_attention(q, k_pool, v_pool, cache_layer, page_table,
     chunked prefill is bound by the chunk's dense matmuls, while the
     per-step decode hot loop keeps the Pallas kernel above."""
     b, c, h, d = q.shape
+    kv = kv_heads or h
     scale = scale if scale is not None else d ** -0.5
-    k = _gather_context(k_pool, cache_layer, page_table, h, d)
-    v = _gather_context(v_pool, cache_layer, page_table, h, d)
-    s = jnp.einsum("bchd,bhkd->bhck", q.astype(jnp.float32),
+    k = _gather_context(k_pool, cache_layer, page_table, kv, d)
+    v = _gather_context(v_pool, cache_layer, page_table, kv, d)
+    s = jnp.einsum("bchrd,bhkd->bhrck", _grouped(q, kv).astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     qpos = starts[:, None] + jnp.arange(c)[None, :]   # [B, C] absolute
     kpos = jnp.arange(k.shape[2])
     # causal over ABSOLUTE positions: every key at or before the query
     # was written by the prefix/chunks already resident — stale pages
     # past the write frontier sit strictly above qpos and are masked
-    mask = kpos[None, None, None, :] <= qpos[:, None, :, None]
+    mask = kpos[None, None, None, None, :] <= qpos[:, None, None, :, None]
     s = jnp.where(mask, s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    out = jnp.einsum("bhck,bhkd->bhcd", p / jnp.maximum(l, 1e-30),
+    out = jnp.einsum("bhrck,bhkd->bchrd", p / jnp.maximum(l, 1e-30),
                      v.astype(jnp.float32))
-    out = jnp.where(seq_lens[:, None, None, None] > 0, out, 0.0)
-    return out.transpose(0, 2, 1, 3).astype(q.dtype)
+    out = jnp.where(seq_lens[:, None, None, None, None] > 0, out, 0.0)
+    return out.reshape(b, c, h, d).astype(q.dtype)
 
 
 # -- reference implementation --------------------------------------------------
 
 
 def ragged_paged_attention_reference(q, k_pool, v_pool, cache_layer,
-                                     page_table, seq_lens, scale=None):
+                                     page_table, seq_lens, scale=None,
+                                     kv_heads=None):
     """Pure-jnp oracle: gather each sequence's pages of cache layer
     ``cache_layer``, mask, softmax.
 
     q: [B, H, D] (one decode token per row); k_pool/v_pool:
     ``kv_pool_shape``; returns [B, H, D].  Rows with ``seq_lens == 0``
     produce zeros (idle slots), not NaNs."""
-    _, h, d = q.shape
+    b, h, d = q.shape
+    kv = kv_heads or h
     scale = scale if scale is not None else d ** -0.5
-    k = _gather_context(k_pool, cache_layer, page_table, h, d)
-    v = _gather_context(v_pool, cache_layer, page_table, h, d)
-    s = jnp.einsum("bhd,bhkd->bhk", q.astype(jnp.float32),
+    k = _gather_context(k_pool, cache_layer, page_table, kv, d)
+    v = _gather_context(v_pool, cache_layer, page_table, kv, d)
+    s = jnp.einsum("bhrd,bhkd->bhrk", _grouped(q, kv).astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     pos = jnp.arange(k.shape[2])
-    s = jnp.where(pos[None, None, :] < seq_lens[:, None, None], s, NEG_INF)
+    s = jnp.where(pos < seq_lens[:, None, None, None], s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    out = jnp.einsum("bhk,bhkd->bhd", p / jnp.maximum(l, 1e-30),
+    out = jnp.einsum("bhrk,bhkd->bhrd", p / jnp.maximum(l, 1e-30),
                      v.astype(jnp.float32))
     # fully-masked rows: NEG_INF is finite, so p == 1 everywhere and the
     # sum above is a mean of null/stale pages — zero them explicitly to
     # match the kernel's l == 0 path
-    out = jnp.where(seq_lens[:, None, None] > 0, out, 0.0)
-    return out.astype(q.dtype)
+    out = jnp.where(seq_lens[:, None, None, None] > 0, out, 0.0)
+    return out.reshape(b, h, d).astype(q.dtype)
 
 
 # -- the Pallas kernel ---------------------------------------------------------
@@ -304,7 +318,8 @@ def decode_block_pages(num_heads: int, page_size: int, head_dim: int,
                        itemsize: int, max_pages: int,
                        vmem_budget: int = _VMEM_BUDGET) -> int:
     """``N``: how many consecutive page slots one grid step of the decode
-    kernel covers, from the shapes alone (the same for every caller).
+    kernel covers, from the shapes alone (the same for every caller;
+    ``num_heads`` = the heads the CACHE holds).
 
     A block of ``N * page_size`` tokens is one MXU pass wide (128 score
     columns), so ``N = 128 // page_size``: a narrower block pays the
@@ -391,24 +406,32 @@ def _decode_kernel(layer_ref, rows_ref, blocks_ref, pages_ref, lens_ref,
 
 
 def _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale,
-                 interpret):
+                 interpret, kv_heads=None):
     b, h, d = q.shape
+    kv = kv_heads or h
+    rep = h // kv
     _, groups, _, page_size, lanes = k_pool.shape
     g = lanes // d
-    n = decode_block_pages(h, page_size, d, k_pool.dtype.itemsize,
+    n = decode_block_pages(kv, page_size, d, k_pool.dtype.itemsize,
                            page_table.shape[1])
     rows, blocks, pages, steps = _decode_work(page_table, seq_lens, n,
                                               page_size)
-    # head (group, j)'s query in lanes [j·D, (j+1)·D) of rows [8j, 8j + 8)
-    # of its group, zeros elsewhere: the products with the other heads'
-    # lanes of a pool row are exact zeros
+    # K/V head (group, j)'s ``rep`` query heads in lanes [j·D, (j+1)·D) of
+    # rows [qr·j, qr·(j + 1)) of its group, zeros elsewhere: the products
+    # with the other heads' lanes of a pool row are exact zeros.  One
+    # query head a K/V head is repeated down its 8 sublanes.
+    qr = round_up(rep, _Q_SUBLANES)
     own = jnp.eye(g, dtype=bool)[:, None, :, None]
-    qg = _pack_heads(q).reshape(b, groups, 1, 1, g, d)
-    qb = jnp.broadcast_to(jnp.where(own, qg, 0),
-                          (b, groups, g, _Q_SUBLANES, g, d))
-    qb = qb.reshape(b, groups, g * _Q_SUBLANES, lanes)
+    qg = _pack_heads(_grouped(q, kv).swapaxes(1, 2))   # [B, rep, H/g, g·D]
+    qg = qg.reshape(b, rep, groups, 1, g, d).transpose(0, 2, 3, 1, 4, 5)
+    qb = jnp.where(own, qg, 0)                         # [B, H/g, g, rep, g, D]
+    if rep == 1:
+        qb = jnp.broadcast_to(qb, (b, groups, g, qr, g, d))
+    elif qr > rep:
+        qb = jnp.pad(qb, [(0, 0)] * 3 + [(0, qr - rep)] + [(0, 0)] * 2)
+    qb = qb.reshape(b, groups, g * qr, lanes)
     row = pl.BlockSpec(
-        (1, groups, g * _Q_SUBLANES, lanes),
+        (1, groups, g * qr, lanes),
         lambda s, layer, rows, blocks, pages, lens: (rows[s], 0, 0, 0))
     slots = [pl.BlockSpec(
         (None, groups, None, page_size, lanes),
@@ -422,9 +445,9 @@ def _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale,
         in_specs=[row, *slots, *slots],
         out_specs=row,
         scratch_shapes=[
-            pltpu.VMEM((groups, g * _Q_SUBLANES, lanes), jnp.float32),
-            pltpu.VMEM((groups, g * _Q_SUBLANES, _LANES), jnp.float32),
-            pltpu.VMEM((groups, g * _Q_SUBLANES, _LANES), jnp.float32),
+            pltpu.VMEM((groups, g * qr, lanes), jnp.float32),
+            pltpu.VMEM((groups, g * qr, _LANES), jnp.float32),
+            pltpu.VMEM((groups, g * qr, _LANES), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -440,18 +463,24 @@ def _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale,
         interpret=interpret,
     )(jnp.asarray(cache_layer, jnp.int32).reshape(1), rows, blocks, pages,
       seq_lens.astype(jnp.int32), qb, *[k_pool] * n, *[v_pool] * n)
-    # rows [8j, 8j + 8) hold head (group, j) in ITS lanes; the rest of
-    # each row is the other heads' values under this head's weights
+    # rows [qr·j, qr·(j + 1)) hold K/V head (group, j)'s query heads in
+    # ITS lanes; the rest of each row is the other heads' values under
+    # this head's weights
     j = jnp.arange(g)
-    out = out.reshape(b, groups, g, _Q_SUBLANES, g, d)[:, :, j, 0, j]
-    return out.reshape(b, groups * g, d)[:, :h]
+    out = out.reshape(b, groups, g, qr, g, d)
+    if rep == 1:
+        return out[:, :, j, 0, j].reshape(b, groups * g, d)[:, :h]
+    out = out[:, :, j, :rep, j]                        # [g, B, H/g, rep, D]
+    return out.transpose(1, 2, 0, 3, 4).reshape(b, groups * g * rep, d)[:, :h]
 
 
 def ragged_paged_attention(q, k_pool, v_pool, cache_layer, page_table,
-                           seq_lens, scale=None, impl="auto", interpret=None):
+                           seq_lens, scale=None, impl="auto", interpret=None,
+                           kv_heads=None):
     """Decode-step attention of q [B, H, D] over cache layer
     ``cache_layer`` of a paged KV-cache (k_pool/v_pool:
-    ``kv_pool_shape``).
+    ``kv_pool_shape`` of ``kv_heads`` heads, None = H: query head h reads
+    K/V head ``h // (H // kv_heads)``).
 
     ``impl``: "kernel" (Pallas; ``interpret=None`` auto-selects
     interpreter mode off-TPU, the flash_attention convention), "reference"
@@ -464,6 +493,7 @@ def ragged_paged_attention(q, k_pool, v_pool, cache_layer, page_table,
 
     if resolve_impl(impl, "ragged_paged_attention") == "reference":
         return ragged_paged_attention_reference(
-            q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale=scale)
+            q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale=scale,
+            kv_heads=kv_heads)
     return _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens,
-                        scale, resolve_interpret(interpret))
+                        scale, resolve_interpret(interpret), kv_heads)

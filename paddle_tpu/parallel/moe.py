@@ -32,6 +32,16 @@ Two execution paths with identical math:
 
 ``aux_load_balancing_loss`` is the Switch loss: E * mean(load_fraction *
 mean_gate_prob) per expert, pushing the router toward uniform load.
+
+Beside the capacity paths, :func:`moe_routed` is the DROPLESS layer of
+today's large sparse models, for a device that holds a contiguous share
+``[lo, hi)`` of the experts (expert parallelism's unit; all of them on
+one device): sigmoid scores in float32 over ALL experts, top-k of score
++ a selection-only correction bias, the chosen scores normalised and
+scaled, every assignment to a held expert computed whatever the load,
+assignments to absent experts left to the devices that hold them, plus
+an optional shared expert every token passes.  No exchange, no
+capacity, nothing that stands in for the absent share.
 """
 
 from __future__ import annotations
@@ -321,6 +331,144 @@ def moe_ffn_sharded(params: dict, x: jax.Array, cfg: MoEConfig, mesh,
     )
     return fn(params["wg"], params["w1"], params["b1"], params["w2"],
               params["b2"], x)
+
+
+# -- dropless routing over a held share ----------------------------------------
+
+# Up to this many rows every held expert runs over every row (masked by
+# the combine weight): the layer streams its weights once, which is what a
+# decode batch costs whatever the arrangement, and on a v5e the MXU hides
+# the wasted rows for a long while — both matmuls over 64 experts of 2688 x
+# 1856 take 1.8 / 1.9 / 3.5 / 7.1 ms at 64 / 256 / 512 / 1024 rows, against
+# 18 ms for the pair of ``lax.ragged_dot`` calls whatever the rows (PERF.md
+# section 6, PR 30).  Above it the assignments are sorted by expert and go
+# through the grouped product, which multiplies only the assignments made.
+DENSE_MAX_TOKENS = 2048
+# a padded pass is mostly padding: its live rows are gathered to the front
+# and the masked product runs over the smallest of these row counts that
+# holds them (chosen at run time from ``live``; the last is all rows)
+DENSE_BUCKETS = (256, 512, 1024)
+
+
+@dataclass(frozen=True)
+class RoutedConfig:
+    num_experts: int            # the router's width: ALL experts
+    top_k: int
+    scale: float = 1.0          # on the normalised weights of the chosen
+    held: tuple = None          # [lo, hi) of the experts computed here
+    act: str = "relu2"          # "relu2" | "gelu" | "silu" (no gate, no bias)
+
+    def __post_init__(self):
+        held = (0, self.num_experts) if self.held is None else tuple(self.held)
+        object.__setattr__(self, "held", held)
+        lo, hi = held
+        if not 0 <= lo < hi <= self.num_experts:
+            raise ValueError(f"held experts [{lo}, {hi}) must lie inside "
+                             f"[0, {self.num_experts})")
+        if not 1 <= self.top_k <= self.num_experts:
+            raise ValueError(f"top_k {self.top_k} outside [1, "
+                             f"{self.num_experts}]")
+
+    @property
+    def num_held(self) -> int:
+        return self.held[1] - self.held[0]
+
+
+def _act(name: str, h):
+    if name == "relu2":
+        return jnp.square(jax.nn.relu(h))
+    return {"gelu": jax.nn.gelu, "silu": jax.nn.silu}[name](h)
+
+
+def route_topk(x2, router, bias, cfg: RoutedConfig):
+    """The published router, in float32: (expert ids [T, k], weights
+    [T, k]).  The correction ``bias`` moves which experts are chosen and
+    never what they weigh."""
+    f32 = jnp.float32
+    s = jax.nn.sigmoid(jnp.dot(x2.astype(f32), router.astype(f32),
+                               precision=lax.Precision.HIGHEST))
+    _, idx = lax.top_k(s + bias.astype(f32), cfg.top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    return idx, w / jnp.sum(w, axis=-1, keepdims=True) * cfg.scale
+
+
+def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None):
+    """x [..., D] -> (y like x, counts int32 [4]).
+
+    ``params``: ``router`` [D, X] and ``router_bias`` [X] over all X
+    experts; ``w_in`` [held, D, F] and ``w_out`` [held, F, D] of the held
+    ones; optionally ``shared_in`` [D, S] / ``shared_out`` [S, D].  ``y``
+    is the held experts' part of the layer's result plus the shared
+    expert.  ``live`` (bool, x's leading shape) masks rows that are no
+    token (idle decode rows, padding): they are routed nowhere.
+    ``counts`` = assignments on held experts, assignments on absent ones,
+    held experts with at least one token, the busiest held expert's
+    tokens — over live rows."""
+    f32 = jnp.float32
+    shape = x.shape
+    x2 = x.reshape(-1, shape[-1])
+    t, k = x2.shape[0], cfg.top_k
+    lo, hi = cfg.held
+    idx, w = route_topk(x2, params["router"], params["router_bias"], cfg)
+    held = (idx >= lo) & (idx < hi)
+    if live is not None:
+        alive = live.reshape(-1, 1)
+        absent = jnp.sum(~held & alive)
+        held = held & alive
+    else:
+        absent = jnp.sum(~held)
+    w = jnp.where(held, w, 0.0)
+    local = jnp.where(held, idx - lo, cfg.num_held)   # num_held = nowhere
+    w_in, w_out = params["w_in"], params["w_out"]
+    if t <= DENSE_MAX_TOKENS:
+        # [T, held] combine weights; every held expert over every row
+        comb = jnp.sum(w[..., None] * (local[..., None] == jnp.arange(
+            cfg.num_held)), axis=1)
+        loads = jnp.sum(comb > 0, axis=0)
+
+        def masked(rows, comb):
+            h = jnp.einsum("td,xdf->xtf", rows, w_in,
+                           preferred_element_type=f32)
+            h = (_act(cfg.act, h) * comb.T[..., None]).astype(x.dtype)
+            return jnp.einsum("xtf,xfd->td", h, w_out,
+                              preferred_element_type=f32)
+
+        buckets = [b for b in DENSE_BUCKETS if b < t]
+        if live is None or not buckets:
+            y = masked(x2, comb)
+        else:
+            # live rows first; the rest of a bucket weighs nothing
+            front = jnp.argsort(~live.reshape(-1), stable=True)
+
+            def over(n):
+                idx = front[:n]
+                return lambda: jnp.zeros((t, shape[-1]), f32).at[idx].set(
+                    masked(x2[idx], comb[idx]))
+
+            y = lax.switch(
+                jnp.searchsorted(jnp.asarray(buckets), jnp.sum(live)),
+                [over(b) for b in buckets] + [lambda: masked(x2, comb)])
+    else:
+        # assignments sorted by held expert (the absent ones last), each
+        # expert's rows through its own matrices
+        flat = local.reshape(-1)
+        order = jnp.argsort(flat)
+        loads = jnp.bincount(flat, length=cfg.num_held + 1)[:-1]
+        rows = x2[order // k]
+        h = lax.ragged_dot(rows, w_in, loads, preferred_element_type=f32)
+        h = _act(cfg.act, h).astype(x.dtype)
+        ys = lax.ragged_dot(h, w_out, loads, preferred_element_type=f32)
+        ws = w.reshape(-1)[order]
+        ys = jnp.where(ws[:, None] > 0, ys * ws[:, None], 0.0)
+        y = jnp.zeros((t, shape[-1]), f32).at[order // k].add(ys)
+    if "shared_in" in params:
+        hs = _act(cfg.act, jnp.dot(x2, params["shared_in"],
+                                   preferred_element_type=f32))
+        y = y + jnp.dot(hs.astype(x.dtype), params["shared_out"],
+                        preferred_element_type=f32)
+    counts = jnp.stack([jnp.sum(held), absent, jnp.sum(loads > 0),
+                        jnp.max(loads)]).astype(jnp.int32)
+    return y.astype(x.dtype).reshape(shape), counts
 
 
 def place_moe_params(params: dict, mesh, axis: str = "expert") -> dict:

@@ -21,8 +21,13 @@ decode steps: batch × num_layers × loop_steps a step) /
 ``serve_loop_crashes`` (background loops that died —
 pending ``results()`` callers get the loop's exception re-raised
 instead of blocking forever), gauges ``serve_active_slots`` /
-``serve_free_pages`` / ``serve_kv_bytes_per_token`` (set once, at
-construction); with ``--prefix_cache`` / ``--prefill_chunk_tokens``
+``serve_free_pages`` / ``serve_kv_bytes_per_token`` /
+``serve_state_bytes_per_slot`` (set once, at construction); under routed
+experts counters ``serve_moe_assignments_total{where=held|absent}`` /
+``serve_moe_experts_touched_total`` and gauge
+``serve_moe_load_max_over_mean`` (decode steps: the busiest held
+expert's tokens over the mean); with ``--prefix_cache`` /
+``--prefill_chunk_tokens``
 also counters ``serve_prefix_hit_tokens`` / ``serve_prefill_flops_saved``
 / ``serve_prefill_chunks`` and gauge ``serve_cached_pages``,
 one ``kind="serve"`` record per completed request and a
@@ -137,6 +142,13 @@ class ServingEngine:
                 "reservation — nothing could ever be admitted")
         enforce(s.prefill_chunk_tokens >= 0,
                 "prefill_chunk_tokens must be >= 0 (0 = chunking off)")
+        if cfg.state_layers and s.incremental_prefill:
+            raise NotImplementedError(
+                "prefix_cache / prefill_chunk_tokens with recurrent-state "
+                "layers: a chunk must start from its slot's state and leave "
+                "it behind (forward_prefill_chunk carries none), and a "
+                "prefix hit needs a snapshot of the state at the shared "
+                "prefix's last token (PrefixCache keeps pages only)")
         # GL-P-MEM serving path: with an --hbm_gb budget set, the static
         # KV pool + params bytes must fit BEFORE the pools are allocated
         # — an oversized pool fails here, not at the first admission
@@ -158,11 +170,13 @@ class ServingEngine:
         # device 0), then commit them
         with jax.default_device(device):  # None = jax's default
             self.cache = PagedKVCache(
-                cfg.cache_layers, cfg.num_heads, cfg.head_dim, s.num_pages,
+                cfg.cache_layers, cfg.kv_heads, cfg.head_dim, s.num_pages,
                 s.page_size, s.max_slots, s.max_pages_per_seq,
-                dtype=cfg.dtype, prefix_cache=s.prefix_cache)
-        self.cache.k, self.cache.v = self.place((self.cache.k,
-                                                 self.cache.v))
+                dtype=cfg.dtype, prefix_cache=s.prefix_cache,
+                state_layers=cfg.state_layers,
+                state_shapes=cfg.state_shapes if cfg.state_layers else None)
+        self.cache.k, self.cache.v, self.cache.state = self.place(
+            (self.cache.k, self.cache.v, self.cache.state))
         self.scheduler = Scheduler(s, self.cache)
         # 2·params is the standard per-token forward-FLOPs estimate —
         # what a prefix-cache hit's skipped recompute is booked at; a
@@ -170,21 +184,39 @@ class ServingEngine:
         count = lambda tree: sum(int(x.size) for x in jax.tree.leaves(tree))
         self._flops_per_token = 2.0 * (
             count(params) + (cfg.loop_steps - 1) * count(params["blocks"]))
+        # a pattern's routed layers: their counts ride behind a pass's
+        # tokens, and a token passes top_k of the experts, not all held
+        self._routed = bool(cfg.pattern and "E" in cfg.pattern)
+        if self._routed:
+            self._expert_slots = cfg.routed.num_held * cfg.pattern.count("E")
+            self._flops_per_token -= 2.0 * max(
+                0.0, 1.0 - cfg.moe_top_k / cfg.moe_experts) * count(
+                    [(b["w_in"], b["w_out"]) for b in params["blocks"]
+                     if "router" in b])
         # one resident token's K and V over every cache layer
         self.kv_bytes_per_token = (
-            2 * cfg.cache_layers * cfg.num_heads * cfg.head_dim
+            2 * cfg.cache_layers * cfg.kv_heads * cfg.head_dim
             * self.cache.k.dtype.itemsize)
         self.registry.gauge(
             "serve_kv_bytes_per_token",
             "K and V bytes one resident token holds over every cache "
-            "layer (num_layers x loop_steps)").set(self.kv_bytes_per_token)
+            "layer (attention layers x loop_steps) at the cache's "
+            "kv_heads").set(self.kv_bytes_per_token)
+        self.registry.gauge(
+            "serve_state_bytes_per_slot",
+            "recurrent-state bytes one resident sequence holds over every "
+            "state layer (0 without such layers)").set(
+                self.cache.state_bytes_per_slot)
         # what every device pass's span says of the stack it ran
         self._loop_args = {"loop_steps": cfg.loop_steps,
                            "cache_layers": cfg.cache_layers}
+        if cfg.pattern is not None:
+            self._loop_args.update(kv_heads=cfg.kv_heads,
+                                   state_layers=cfg.state_layers)
         # tokens one fetched block of the decode kernel covers
         from paddle_tpu.ops.pallas.paged_attention import decode_block_pages
         self._kv_block = s.page_size * decode_block_pages(
-            cfg.num_heads, s.page_size, cfg.head_dim,
+            cfg.kv_heads, s.page_size, cfg.head_dim,
             self.cache.k.dtype.itemsize, s.max_pages_per_seq)
         self._chunk_passes = 0  # incremental prefill passes this engine ran
         self._base_key = self.place(jax.random.key(s.seed))
@@ -197,6 +229,30 @@ class ServingEngine:
         self._loop_error: BaseException | None = None
         self._stopped = False  # a stop()ed loop marks the engine dead
         self._build_fns()
+
+    def _split_counts(self, out, rows: int, where: str):
+        """A pass's output -> its ``rows`` sampled tokens; the routing
+        counts that ride behind them (``moe_routed``) are booked under
+        ``where`` ("prefill" | "decode") and returned as span args."""
+        out = np.asarray(out)
+        if not self._routed:
+            return out, {}
+        held, absent, touched, busiest = (int(x) for x in out[rows:])
+        reg = self.registry
+        c = reg.counter("serve_moe_assignments_total",
+                        "(token, expert) assignments routed, by whether "
+                        "this device holds the expert")
+        c.inc(held, where="held")
+        c.inc(absent, where="absent")
+        reg.counter("serve_moe_experts_touched_total",
+                    "(layer, held expert) pairs that saw at least one "
+                    "token, summed over passes").inc(touched)
+        if where == "decode" and held:
+            reg.gauge("serve_moe_load_max_over_mean",
+                      "busiest held expert's tokens over the mean, last "
+                      "decode step").set(busiest * self._expert_slots / held)
+        return out[:rows], {"moe_assignments": held,
+                            "experts_touched": touched}
 
     def place(self, tree):
         """Commit a pytree to this engine's device (identity when the
@@ -440,14 +496,17 @@ class ServingEngine:
             t0 = time.perf_counter()
             batch = self._scheduled(tracer, sched.prefill_batch, admitted)
             args = self._dev(batch, "ids", "seq_lens", "page_table", "rids",
-                             "temps")
+                             "temps", "slots")
             tk = tracer.begin("serve_prefill", cat="serving",
                               batch=len(admitted), **self._loop_args)
-            toks, self.cache.k, self.cache.v = self._prefill(
-                self.params, self._base_key, self.cache.k, self.cache.v,
-                *args)
-            toks = np.asarray(toks)
-            tracer.end(tk)
+            cache = self.cache
+            toks, cache.k, cache.v, cache.state = self._prefill(
+                self.params, self._base_key, cache.k, cache.v, *args,
+                cache.state)
+            toks, counts = self._split_counts(
+                toks, self.serving.prefill_batch, "prefill")
+            if tk is not None:
+                tracer.end(tk, **counts)
             t1 = time.perf_counter()
             hist = reg.histogram("serve_prefill_ms",
                                  "prefill pass wall ms (per admitted batch)")
@@ -479,13 +538,18 @@ class ServingEngine:
                              "page_table", "rids", "gens", "temps")
             tk = tracer.begin("serve_decode", cat="serving",
                               batch=len(live), **self._loop_args)
-            toks, self.cache.k, self.cache.v = self._decode(
-                self.params, self._base_key, self.cache.k, self.cache.v,
-                *args)
+            cache = self.cache
+            toks, cache.k, cache.v, cache.state = self._decode(
+                self.params, self._base_key, cache.k, cache.v, *args,
+                cache.state)
             if tk is not None:
                 t_dispatched = tracer.clock()
-            toks = np.asarray(toks)
+            toks, counts = self._split_counts(
+                toks, self.serving.max_slots, "decode")
             if tk is not None:
+                if self.cfg.state_layers:
+                    # rows of the state pools this step read and rewrote
+                    counts["state_slots"] = len(live)
                 # what the step's kernel had to read (every live
                 # sequence's resident context) and what it fetched (the
                 # same in whole blocks), and how long the dispatch took
@@ -495,7 +559,8 @@ class ServingEngine:
                     kv_block_tokens=int(
                         (-(-batch["seq_lens"] // self._kv_block)).sum()
                         * self._kv_block),
-                    dispatch_ms=round((t_dispatched - tk.t_start) * 1e3, 3))
+                    dispatch_ms=round((t_dispatched - tk.t_start) * 1e3, 3),
+                    **counts)
             reg.histogram(
                 "serve_decode_step_ms",
                 "one continuous-batching decode step, wall ms").observe(
@@ -559,11 +624,13 @@ class ServingEngine:
                          "rids", "temps")
         tk = tracer.begin("serve_prefill", cat="serving",
                           batch=len(rows), chunked=True, **self._loop_args)
-        toks, self.cache.k, self.cache.v = self._prefill_chunk(
+        toks, self.cache.k, self.cache.v, _ = self._prefill_chunk(
             self.params, self._base_key, self.cache.k, self.cache.v,
             *args)
-        toks = np.asarray(toks)
-        tracer.end(tk)
+        toks, counts = self._split_counts(
+            toks, self.serving.prefill_batch, "prefill")
+        if tk is not None:
+            tracer.end(tk, **counts)
         t1 = time.perf_counter()
         reg.histogram("serve_prefill_ms",
                       "prefill pass wall ms (per admitted batch)").observe(
@@ -730,33 +797,61 @@ def _serving_fns(cfg, attn_impl, donate):
     from paddle_tpu.ops.pallas import paged_attention as pa
     from paddle_tpu.serving import sampling
 
+    def sampled(logits, keys, temps, extras):
+        """The sampled tokens; behind them the routed layers' counts of
+        this pass (one small int32 array rides out, nothing else to
+        wait for)."""
+        toks = sampling.sample_tokens(logits, keys, temps)
+        counts = extras.get("moe_counts")
+        return toks if counts is None else jnp.concatenate(
+            [toks.astype(jnp.int32), counts])
+
+    def unpack(out):
+        """A forward's result -> (logits, k, v, extras): a config without
+        a layer pattern hands back no extras."""
+        return out if len(out) == 4 else (*out, {})
+
     def prefill(params, base_key, kc, vc, ids, lens, table, rids,
-                temps):
-        logits, ks, vs = T.forward_prefill(cfg, params, ids, lens)
-        kc, vc = pa.write_prefill_kv(kc, vc, ks, vs, table, lens)
+                temps, slots=None, state=None):
+        logits, ks, vs, extras = unpack(
+            T.forward_prefill(cfg, params, ids, lens))
+        if ks is not None:
+            kc, vc = pa.write_prefill_kv(kc, vc, ks, vs, table, lens)
+        # each row's recurrent state, whole, into its slot's row of every
+        # state layer (a slack row's slot does not exist: dropped)
+        state = dict(state or {})
+        for name, pool in state.items():
+            for i in range(pool.shape[0]):
+                pool = pool.at[i, slots].set(
+                    extras["state"][name][i].astype(pool.dtype), mode="drop")
+            state[name] = pool
         keys = sampling.request_keys(
             base_key, rids, jnp.zeros_like(rids))
-        return sampling.sample_tokens(logits, keys, temps), kc, vc
+        return sampled(logits, keys, temps, extras), kc, vc, state
 
     def decode(params, base_key, kc, vc, ids, positions, lens, table,
-               rids, gens, temps):
-        logits, kc, vc = T.forward_decode(
+               rids, gens, temps, state=None):
+        logits, kc, vc, extras = unpack(T.forward_decode(
             cfg, params, ids, positions, lens, table, kc, vc,
-            attn_impl=attn_impl)
+            attn_impl=attn_impl, state=state))
         keys = sampling.request_keys(base_key, rids, gens)
-        return sampling.sample_tokens(logits, keys, temps), kc, vc
+        return (sampled(logits, keys, temps, extras), kc, vc,
+                extras.get("state", {}))
 
     def prefill_chunk(params, base_key, kc, vc, ids, starts, lens,
                       table, rids, temps):
-        logits, kc, vc = T.forward_prefill_chunk(
-            cfg, params, ids, starts, lens, table, kc, vc)
+        logits, kc, vc, extras = unpack(T.forward_prefill_chunk(
+            cfg, params, ids, starts, lens, table, kc, vc))
         keys = sampling.request_keys(
             base_key, rids, jnp.zeros_like(rids))
-        return sampling.sample_tokens(logits, keys, temps), kc, vc
+        return sampled(logits, keys, temps, extras), kc, vc, {}
 
-    fns = (jax.jit(prefill, donate_argnums=donate),
+    # the state pools ride last and are donated with the page pools
+    with_state = lambda n: tuple(donate) + (
+        (n,) if donate and cfg.state_layers else ())
+    fns = (jax.jit(prefill, donate_argnums=with_state(10)),
            jax.jit(prefill_chunk, donate_argnums=donate),
-           jax.jit(decode, donate_argnums=donate))
+           jax.jit(decode, donate_argnums=with_state(11)))
     with _FN_LOCK:
         # a racing builder may have won; keep the first so every engine
         # shares one executable cache

@@ -55,11 +55,14 @@ def _cfg_from_json(d: dict):
     return TransformerConfig(**d)
 
 
-def _flatten(params: dict, prefix="") -> dict[str, np.ndarray]:
+def _flatten(params, prefix="") -> dict[str, np.ndarray]:
+    """Nested dicts (and lists: a layer pattern's per-layer trees, keyed
+    by index) -> {"a/b/0/c": array}."""
     flat = {}
-    for k, v in params.items():
+    items = enumerate(params) if isinstance(params, list) else params.items()
+    for k, v in items:
         key = f"{prefix}{k}"
-        if isinstance(v, dict):
+        if isinstance(v, (dict, list)):
             flat.update(_flatten(v, key + "/"))
         else:
             flat[key] = np.asarray(v)
@@ -73,7 +76,17 @@ def _unflatten(flat: dict[str, np.ndarray]) -> dict:
         for p in parts[:-1]:
             node = node.setdefault(p, {})
         node[parts[-1]] = v
-    return out
+
+    def lists(node):
+        """A dict keyed 0..n-1 was a list."""
+        if not isinstance(node, dict):
+            return node
+        node = {k: lists(v) for k, v in node.items()}
+        if node and all(k.isdigit() for k in node):
+            return [node[str(i)] for i in range(len(node))]
+        return node
+
+    return lists(out)
 
 
 def export_servable(out_dir: str, cfg, params: dict,

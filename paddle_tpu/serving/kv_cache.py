@@ -20,7 +20,11 @@ page granularity: only FULL pages of prompt tokens are ever shared (a
 partially-filled page is written by its owner as generation proceeds, so
 it stays private — every sequence's diverging suffix lands in its own
 pages), and :meth:`PagedKVCache.cow_page` materialises a private copy
-should a writer ever meet a shared page.  The :class:`PrefixCache` trie
+should a writer ever meet a shared page.  Beside the pages, a model
+with recurrent layers keeps per sequence a FIXED state that no page
+holds: :class:`PagedKVCache` then owns state pools addressed by batch
+slot (``state``), written whole by a slot's prefill and rewritten by
+every decode step.  The :class:`PrefixCache` trie
 hashes page-granular prompt chunks to resident pages (longest-prefix
 match), holds one reference on every cached page, and evicts LRU
 refcount-0 entries (cached, no active user) under page pressure — so a
@@ -276,12 +280,24 @@ class PagedKVCache:
     (assign/release) keeps table rows, refcounts and the free list
     consistent.  With ``prefix_cache=True`` the :class:`PrefixCache`
     trie rides along and ``assign_with_prefix`` maps cached prefixes
-    into new rows instead of recomputing them."""
+    into new rows instead of recomputing them.
+
+    ``num_heads`` is the heads the CACHE holds (a model's K/V heads).
+    ``state``: {part: [state_layers, max_slots, *shape]} float32, one row
+    per batch slot for every layer that keeps a recurrent state
+    (``state_shapes``: part -> one layer's shape for one sequence; empty
+    without such layers).  Slot ``s`` owns row ``s``: its prefill writes
+    the row whole, so a reused slot needs no zeroing, and like the page
+    pools the arrays go donated through the jitted programs and come
+    back updated in place.  Nothing of it is shareable between requests:
+    the prefix trie has no snapshot of it (``ServingEngine`` refuses the
+    combination)."""
 
     def __init__(self, num_layers: int, num_heads: int, head_dim: int,
                  num_pages: int, page_size: int, max_slots: int,
                  max_pages_per_seq: int, dtype=None,
-                 prefix_cache: bool = False):
+                 prefix_cache: bool = False, state_layers: int = 0,
+                 state_shapes: dict | None = None):
         import jax.numpy as jnp
 
         from paddle_tpu.ops.pallas.paged_attention import init_kv_pages
@@ -291,11 +307,21 @@ class PagedKVCache:
         self.k, self.v = init_kv_pages(
             num_layers, num_heads, num_pages, page_size, head_dim,
             dtype=dtype or jnp.float32)
+        self.state = {
+            name: jnp.zeros((state_layers, max_slots, *shape), jnp.float32)
+            for name, shape in (state_shapes or {}).items()
+        } if state_layers else {}
         self.allocator = PageAllocator(num_pages)
         self.page_table = np.zeros((max_slots, max_pages_per_seq), np.int32)
         self._slot_pages: dict[int, list[int]] = {}
         self.prefix: PrefixCache | None = (
             PrefixCache(self) if prefix_cache else None)
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Bytes of recurrent state one sequence holds, over every state
+        layer and part."""
+        return sum(int(a.nbytes) // a.shape[1] for a in self.state.values())
 
     def pages_needed(self, tokens: int) -> int:
         return -(-tokens // self.page_size)

@@ -281,7 +281,11 @@ class Scheduler:
     def prefill_batch(self, admitted: list[_Active]) -> dict:
         """Fixed-shape arrays for one prefill pass over newly admitted
         sequences (padded to ``prefill_batch`` rows x ``max_prompt_len``;
-        slack rows are masked with len 0 and the null-page table row)."""
+        slack rows are masked with len 0 and the null-page table row).
+        ``slots`` names each row's batch slot — the row of the cache's
+        state pools its recurrent state goes to; a slack row names
+        ``max_slots``, a row that does not exist, and its write is
+        dropped."""
         s = self.serving
         nb, t = s.prefill_batch, s.max_prompt_len
         ids = np.zeros((nb, t), np.int32)
@@ -289,14 +293,16 @@ class Scheduler:
         table = np.zeros((nb, self.cache.max_pages_per_seq), np.int32)
         rids = np.zeros((nb,), np.int32)
         temps = np.zeros((nb,), np.float32)
+        slots = np.full((nb,), s.max_slots, np.int32)
         for j, a in enumerate(admitted):
             ids[j, :a.prompt_len] = a.request.prompt
             lens[j] = a.prompt_len
             table[j] = self.cache.page_table[a.slot]
             rids[j] = a.request.id
             temps[j] = a.request.temperature
+            slots[j] = a.slot
         return {"ids": ids, "seq_lens": lens, "page_table": table,
-                "rids": rids, "temps": temps}
+                "rids": rids, "temps": temps, "slots": slots}
 
     # -- incremental prefill (prefix cache / chunked) --------------------------
     def prefilling(self) -> list[_Active]:

@@ -322,12 +322,6 @@ def test_what_the_stack_cannot_do_raises(bad, err):
         assert "varies by token" in str(e.value)
 
 
-def test_moe_under_the_engine_still_raises():
-    cfg = looped_cfg(moe_experts=2)
-    with pytest.raises(NotImplementedError):
-        T.forward_decode(cfg, {}, None, None, None, None, None, None)
-
-
 def test_parameter_counts():
     def count(cfg):
         tree = jax.eval_shape(lambda: T.init_params(cfg, jax.random.key(0)))
