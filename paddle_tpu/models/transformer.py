@@ -666,43 +666,51 @@ def _mamba_mixer(cfg: TransformerConfig, h, layer, conv, ssd):
     return y @ layer["out_proj"]
 
 
-def _run_pattern(cfg: TransformerConfig, params, x, rope, attend, mamba,
-                 live=None, mesh=None):
-    """The stack of a layer ``pattern``: every layer is ``x + mixer(norm(
-    x))``, the mixer by the layer's character; the final norm closes it.
-    ``params["blocks"]`` is the list of the layers' own trees, so nothing
-    is sliced out of a stack, statically or dynamically.
-
-    ``attend(i, q, k, v) -> a`` is the caller's cache write and attention
-    of the ``i``-th attention layer (= cache layer ``i``); ``mamba(i) ->
-    (conv, ssd)`` the ``i``-th state layer's arrangement
+def _pattern_layer(cfg: TransformerConfig, kind, layer, x, rope, attend,
+                   mamba, live=None, mesh=None):
+    """One layer of a ``pattern``: ``x + mixer(norm(x))``, the mixer by
+    the layer's ``kind`` (a value of ``_KINDS``).  ``attend(q, k, v) ->
+    a`` is the caller's cache write and attention of this attention
+    layer, ``mamba = (conv, ssd)`` this state layer's arrangement
     (``_mamba_mixer``); both keep what they must hand back in the
     caller's own variables.  ``live`` (bool, x's leading shape) marks the
     rows that are tokens, for the routing counts.  Returns (x, counts):
-    the routed layers' ``moe_routed`` counts summed (the busiest expert's
-    tokens: the largest), None without routed layers."""
+    a routed layer's ``moe_routed`` counts, else None."""
     lead = x.shape[:-1]
+    counts = None
+    h = _norm(cfg, x, layer, "ln")
+    if kind == "attn":
+        a = attend(*_qkv(cfg, h, layer, rope))
+        y = a.reshape(*lead, cfg.num_heads * cfg.head_dim) @ layer["wo"]
+    elif kind == "mamba":
+        y = _mamba_mixer(cfg, h, layer, *mamba)
+    else:
+        y, bias, aux = _mlp(cfg, h, layer, mesh, live, kind == "moe")
+        if bias is not None:
+            y = y + bias
+        if kind == "moe":
+            counts = aux
+    return x + y, counts
+
+
+def _run_pattern(cfg: TransformerConfig, params, x, layer_fn):
+    """The stack of a layer ``pattern``, the final norm closing it.
+    ``params["blocks"]`` is the list of the layers' own trees, so nothing
+    is sliced out of a stack, statically or dynamically.
+    ``layer_fn(kind, i, layer, x) -> (x, counts)`` is the caller's
+    arrangement of ``_pattern_layer`` for the ``i``-th layer of its kind
+    (an attention layer's ``i`` is its cache layer).  Returns (x,
+    counts): the routed layers' ``moe_routed`` counts summed (the busiest
+    expert's tokens: the largest), None without routed layers."""
     seen = dict.fromkeys(_KINDS.values(), 0)
     counts = None
     for c, layer in zip(cfg.pattern, params["blocks"]):
         kind = _KINDS[c]
-        i = seen[kind]       # the layer's index among its kind
-        seen[kind] += 1
-        h = _norm(cfg, x, layer, "ln")
-        if kind == "attn":
-            a = attend(i, *_qkv(cfg, h, layer, rope))
-            y = a.reshape(*lead, cfg.num_heads * cfg.head_dim) @ layer["wo"]
-        elif kind == "mamba":
-            y = _mamba_mixer(cfg, h, layer, *mamba(i))
-        else:
-            y, bias, aux = _mlp(cfg, h, layer, mesh, live, kind == "moe")
-            if bias is not None:
-                y = y + bias
-            if kind == "moe":
-                counts = aux if counts is None else jnp.concatenate(
-                    [counts[:3] + aux[:3],
-                     jnp.maximum(counts[3:], aux[3:])])
-        x = x + y
+        x, aux = layer_fn(kind, seen[kind], layer, x)
+        seen[kind] += 1     # the layer's index among its kind
+        if aux is not None:
+            counts = aux if counts is None else jnp.concatenate(
+                [counts[:3] + aux[:3], jnp.maximum(counts[3:], aux[3:])])
     return _norm(cfg, x, params, "ln_f"), counts
 
 
@@ -845,39 +853,58 @@ def forward_prefill(cfg: TransformerConfig, params: dict, ids: jax.Array,
     return _head(cfg, params, _last_valid(x, seq_lens)), ks, vs
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _prefill_layer(cfg: TransformerConfig, kind, mesh, layer, x, rope,
+                   seq_lens):
+    """One layer of a pattern over whole right-padded sequences x [B, T,
+    E]: (x, what the layer leaves behind, counts).  A jitted function of
+    its own, so the program that calls it traces and lowers each KIND of
+    layer once and not each layer: getting a pattern's prefill program
+    ready is the host tracing and lowering it, seconds an engine (PERF.md
+    section 6, PR 31); XLA inlines the calls."""
+    from paddle_tpu.ops import mamba2
+
+    kept = {}
+
+    def attend(q, k, v):
+        kept["kv"] = (k, v)
+        return _attention(cfg, q, k, v, mesh)
+
+    def conv(xbc, w, bias):
+        out, kept["conv"] = mamba2.conv_prefill(xbc, w, bias, seq_lens)
+        return out
+
+    def ssd(*args):
+        y, kept["ssm"] = mamba2.ssd_prefill(*args, seq_lens=seq_lens,
+                                            chunk=cfg.mamba_chunk)
+        return y
+
+    live = None if seq_lens is None else (
+        jnp.arange(x.shape[1])[None, :] < seq_lens[:, None])
+    x, counts = _pattern_layer(cfg, kind, layer, x, rope, attend,
+                               (conv, ssd), live, mesh)
+    return x, kept, counts
+
+
 def _prefill_pattern(cfg: TransformerConfig, params, x, rope, seq_lens, mesh):
     """Whole right-padded sequences x [B, T, E] through a layer pattern
     (``seq_lens`` None = training: every position is a token).  Returns
     (x, (ks, vs) [cache_layers, B, T, KV, Dh] or (None, None), extras)."""
-    from paddle_tpu.ops import mamba2
+    kept = {"kv": [], "ssm": [], "conv": []}
 
-    kept, state = [], {"ssm": [], "conv": []}
+    def layer_fn(kind, i, layer, x):
+        x, left, counts = _prefill_layer(cfg, kind, mesh, layer, x, rope,
+                                         seq_lens)
+        for name, v in left.items():
+            kept[name].append(v)
+        return x, counts
 
-    def attend(i, q, k, v):
-        kept.append((k, v))
-        return _attention(cfg, q, k, v, mesh)
-
-    def mamba(i):
-        def conv(xbc, w, bias):
-            out, last = mamba2.conv_prefill(xbc, w, bias, seq_lens)
-            state["conv"].append(last)
-            return out
-
-        def ssd(*args):
-            y, last = mamba2.ssd_prefill(*args, seq_lens=seq_lens,
-                                         chunk=cfg.mamba_chunk)
-            state["ssm"].append(last)
-            return y
-
-        return conv, ssd
-
-    live = None if seq_lens is None else (
-        jnp.arange(x.shape[1])[None, :] < seq_lens[:, None])
-    x, counts = _run_pattern(cfg, params, x, rope, attend, mamba, live, mesh)
-    ks, vs = ((jnp.stack([k for k, _ in kept]), jnp.stack([v for _, v in kept]))
-              if kept else (None, None))
+    x, counts = _run_pattern(cfg, params, x, layer_fn)
+    kv = kept.pop("kv")
+    ks, vs = ((jnp.stack([k for k, _ in kv]), jnp.stack([v for _, v in kv]))
+              if kv else (None, None))
     return x, (ks, vs), {
-        "state": {n: jnp.stack(v) for n, v in state.items() if v},
+        "state": {n: jnp.stack(v) for n, v in kept.items() if v},
         "moe_counts": counts}
 
 
@@ -924,8 +951,10 @@ def forward_prefill_chunk(cfg: TransformerConfig, params: dict,
                 kv_heads=cfg.kv_heads)
 
         live = jnp.arange(c)[None, :] < seq_lens[:, None]
-        x, counts = _run_pattern(cfg, params, x, rope, attend_chunk, None,
-                                 live)
+        x, counts = _run_pattern(
+            cfg, params, x, lambda kind, i, layer, x: _pattern_layer(
+                cfg, kind, layer, x, rope,
+                functools.partial(attend_chunk, i), None, live))
         return (_head(cfg, params, _last_valid(x, seq_lens)), *pools,
                 {"state": {}, "moe_counts": counts})
 
@@ -1028,7 +1057,10 @@ def _decode_pattern(cfg: TransformerConfig, params, x, rope, positions,
 
         return conv, ssd
 
-    x, counts = _run_pattern(cfg, params, x, rope, attend, mamba, live)
+    x, counts = _run_pattern(
+        cfg, params, x, lambda kind, i, layer, x: _pattern_layer(
+            cfg, kind, layer, x, rope, functools.partial(attend, i),
+            mamba(i) if kind == "mamba" else None, live))
     return (_head(cfg, params, x), *pools,
             {"state": state, "moe_counts": counts})
 

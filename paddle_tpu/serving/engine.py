@@ -22,7 +22,13 @@ decode steps: batch × num_layers × loop_steps a step) /
 pending ``results()`` callers get the loop's exception re-raised
 instead of blocking forever), gauges ``serve_active_slots`` /
 ``serve_free_pages`` / ``serve_kv_bytes_per_token`` /
-``serve_state_bytes_per_slot`` (set once, at construction); under routed
+``serve_state_bytes_per_slot`` (set once, at construction); from the
+from-zero prefill passes counters ``serve_prefill_padded_tokens_total``
+(rows x length of each pass's shape) / ``serve_prefill_prompt_tokens_total``
+(their ratio is the passes' fill share) and gauge
+``serve_prefill_programs`` (the shapes a pass may take: the scheduler's
+``prefill_rows``, compiled with the decode program by the step that admits
+the engine's first request; set once, at construction); under routed
 experts counters ``serve_moe_assignments_total{where=held|absent}`` /
 ``serve_moe_experts_touched_total`` and gauge
 ``serve_moe_load_max_over_mean`` (decode steps: the busiest held
@@ -207,6 +213,11 @@ class ServingEngine:
             "recurrent-state bytes one resident sequence holds over every "
             "state layer (0 without such layers)").set(
                 self.cache.state_bytes_per_slot)
+        self.registry.gauge(
+            "serve_prefill_programs",
+            "shapes a from-zero prefill pass may take, all compiled "
+            "before the engine's first pass").set(
+                len(self.scheduler.prefill_rows))
         # what every device pass's span says of the stack it ran
         self._loop_args = {"loop_steps": cfg.loop_steps,
                            "cache_layers": cfg.cache_layers}
@@ -219,6 +230,9 @@ class ServingEngine:
             cfg.kv_heads, s.page_size, cfg.head_dim,
             self.cache.k.dtype.itemsize, s.max_pages_per_seq)
         self._chunk_passes = 0  # incremental prefill passes this engine ran
+        # the compiled executables the step loop dispatches, by a prefill
+        # pass's rows and "decode" (_make_ready, at the first admission)
+        self._programs: dict = {}
         self._base_key = self.place(jax.random.key(s.seed))
         self._lock = threading.Lock()
         self._incoming: collections.deque[Request] = collections.deque()
@@ -446,7 +460,9 @@ class ServingEngine:
         ``serve_schedule`` children are the calls that build or change
         scheduler / KV-cache state, ``serve_prefill`` / ``serve_decode``
         are the two device passes (dispatch + the wait for the tokens;
-        both carry the stack they ran: ``loop_steps``, ``cache_layers``),
+        both carry the stack they ran: ``loop_steps``, ``cache_layers``;
+        a from-zero prefill pass also the shape it ran and what was in it:
+        ``rows``, ``padded_tokens``, ``prompt_tokens``),
         and what is left over, its self time, is this loop's own Python:
         the small host-to-device transfers, the ``append_token`` loops,
         histograms and gauges."""
@@ -474,6 +490,46 @@ class ServingEngine:
         (tracer.end if batch is not None else tracer.cancel)(tk)
         return batch
 
+    def _make_ready(self) -> dict:
+        """Compile every program this engine will dispatch, before the
+        first of them serves (the step that admits the first request
+        calls this ahead of its prefill pass): each member of the
+        scheduler's prefill ladder and the decode program, lowered and
+        compiled for a batch of slack rows only, and held: the step loop
+        calls these executables, not the jitted functions.  ``jax.jit``
+        would compile a shape the first time traffic brings it — seconds,
+        in the middle of serving; an executable compiles nothing, and
+        refuses arguments of another shape or type (a weight swap brings
+        the same) instead.  Nothing runs here, so pools, state and page
+        table are what they were.  Replicas share the jitted functions,
+        so a fleet on one device traces, lowers and compiles once.  The
+        incremental path has one prefill shape, compiled by its first
+        pass as before."""
+        t0 = time.perf_counter()
+        cache, sched = self.cache, self.scheduler
+        rows = () if self.serving.incremental_prefill else sched.prefill_rows
+        programs = {}
+        for n in rows:
+            t1 = time.perf_counter()
+            args = self._dev(sched.prefill_arrays([], n), "ids", "seq_lens",
+                             "page_table", "rids", "temps", "slots")
+            programs[n] = self._prefill.lower(
+                self.params, self._base_key, cache.k, cache.v, *args,
+                cache.state).compile()
+            log.debug("prefill program of %d row(s) ready after %.2f s", n,
+                      time.perf_counter() - t1)
+        args = self._dev(sched.decode_arrays([]), "ids", "positions",
+                         "seq_lens", "page_table", "rids", "gens", "temps")
+        programs["decode"] = self._decode.lower(
+            self.params, self._base_key, cache.k, cache.v, *args,
+            cache.state).compile()
+        with self._lock:    # all or none: a failure is met again
+            self._programs = programs
+        log.info("serving engine ready: %d prefill program(s) of %s row(s) x "
+                 "%d + decode in %.2f s", len(rows), list(rows),
+                 self.serving.max_prompt_len, time.perf_counter() - t0)
+        return programs
+
     def _step(self, tracer) -> bool:
         sched, reg = self.scheduler, self.registry
         now = time.perf_counter()
@@ -484,6 +540,7 @@ class ServingEngine:
             while self._incoming:
                 sched.enqueue(self._incoming.popleft())
                 worked = True
+            programs = self._programs
 
         for a in sched.retire_finished():
             self._finish(a)
@@ -492,22 +549,38 @@ class ServingEngine:
         admitted = sched.admit(now=now)
         # a pass over empty queues and full slots is not scheduling work
         (tracer.end if worked or admitted else tracer.cancel)(tk)
+        if admitted and not programs:
+            # the first admission: nothing has been dispatched yet (an
+            # idle step stays as cheap as it was: a fleet's router pumps
+            # idle replicas all the time)
+            programs = self._make_ready()
         if admitted and not self.serving.incremental_prefill:
             t0 = time.perf_counter()
             batch = self._scheduled(tracer, sched.prefill_batch, admitted)
             args = self._dev(batch, "ids", "seq_lens", "page_table", "rids",
                              "temps", "slots")
+            rows, length = batch["ids"].shape
+            fill = {"rows": rows, "padded_tokens": rows * length,
+                    "prompt_tokens": int(batch["seq_lens"].sum())}
             tk = tracer.begin("serve_prefill", cat="serving",
-                              batch=len(admitted), **self._loop_args)
+                              batch=len(admitted), **fill, **self._loop_args)
             cache = self.cache
-            toks, cache.k, cache.v, cache.state = self._prefill(
+            toks, cache.k, cache.v, cache.state = programs[rows](
                 self.params, self._base_key, cache.k, cache.v, *args,
                 cache.state)
-            toks, counts = self._split_counts(
-                toks, self.serving.prefill_batch, "prefill")
+            toks, counts = self._split_counts(toks, rows, "prefill")
             if tk is not None:
                 tracer.end(tk, **counts)
             t1 = time.perf_counter()
+            # the ratio of the two counters is the passes' fill share
+            reg.counter(
+                "serve_prefill_padded_tokens_total",
+                "tokens the from-zero prefill passes computed (rows x "
+                "length of each pass's shape)").inc(fill["padded_tokens"])
+            reg.counter(
+                "serve_prefill_prompt_tokens_total",
+                "prompt tokens the from-zero prefill passes carried").inc(
+                    fill["prompt_tokens"])
             hist = reg.histogram("serve_prefill_ms",
                                  "prefill pass wall ms (per admitted batch)")
             hist.observe((t1 - t0) * 1e3)
@@ -539,7 +612,7 @@ class ServingEngine:
             tk = tracer.begin("serve_decode", cat="serving",
                               batch=len(live), **self._loop_args)
             cache = self.cache
-            toks, cache.k, cache.v, cache.state = self._decode(
+            toks, cache.k, cache.v, cache.state = programs["decode"](
                 self.params, self._base_key, cache.k, cache.v, *args,
                 cache.state)
             if tk is not None:

@@ -40,9 +40,9 @@ class ServingConfig:
     max_slots: int = 8           # decode batch size = max concurrent seqs
     page_size: int = 16
     num_pages: int = 256         # pool size incl. the null page
-    max_prompt_len: int = 64     # prefill pad length (one compile signature)
+    max_prompt_len: int = 64     # longest prompt = a prefill pass's length
     max_new_tokens: int = 64     # per-request cap (requests may ask less)
-    prefill_batch: int = 4       # admissions per step (one compile signature)
+    prefill_batch: int = 4       # admissions per step = a pass's most rows
     # 0 = no budget; else cap on the summed reservations (prompt +
     # max_new_tokens) of resident sequences — bounds worst-case context
     max_concurrent_tokens: int = 0
@@ -71,6 +71,27 @@ class ServingConfig:
         """True when prompts are prefilled through the offset chunk path
         (prefix cache and/or chunking) instead of one from-zero pass."""
         return self.prefix_cache or self.prefill_chunk_tokens > 0
+
+
+def prefill_rows(prefill_batch: int) -> tuple[int, ...]:
+    """The row counts a from-zero prefill pass may take, smallest first:
+    one, and ``prefill_batch`` — the prefill ladder; every member is
+    ``max_prompt_len`` long.
+
+    A pass's time follows its shape, and most passes carry one row (a
+    closed loop admits a request the moment one finishes: 72-87% of the
+    passes of the benchmark's serve cells), so the single row is where a
+    second program pays.  It is also all the ladder there is, because the
+    engine compiles every member before it serves
+    (``ServingEngine._make_ready``) and a member costs set-up time in
+    every process, warm cache or not: the host tracing and lowering the
+    program, about 1.2 s on a v5e machine, a twentieth of a serving
+    process's whole set-up.  A two-row member and half- and
+    quarter-length rows were measured too and bring 4-12% more tokens a
+    second on a compute-bound stack for that second each (PERF.md section
+    6, PR 31): not here until a member costs less.  ``prefill_batch`` rows
+    hold whatever ``admit`` hands over."""
+    return tuple(sorted({1, prefill_batch}))
 
 
 @dataclasses.dataclass
@@ -129,6 +150,7 @@ class Scheduler:
         self.queue: collections.deque[Request] = collections.deque()
         self.slots: list[_Active | None] = [None] * serving.max_slots
         self.rejected_admissions = 0  # out-of-pages/budget head blocks
+        self.prefill_rows = prefill_rows(serving.prefill_batch)
 
     # -- state views ----------------------------------------------------------
     @property
@@ -239,13 +261,18 @@ class Scheduler:
     # -- decode batch assembly ------------------------------------------------
     def decode_batch(self) -> dict | None:
         """Fixed-shape arrays for one decode step over all live
-        sequences, or None when there are none.  Idle/finished slots ride
-        along masked (seq_len 0, null-page table row) so the jitted step
-        has a single compile signature.  Sequences still mid-prefill
-        (incremental path: no token sampled yet) are not decoded."""
+        sequences, or None when there are none.  Sequences still
+        mid-prefill (incremental path: no token sampled yet) are not
+        decoded."""
         live = [a for a in self.live if a.generated]
-        if not live:
-            return None
+        return self.decode_arrays(live) if live else None
+
+    def decode_arrays(self, live: list[_Active]) -> dict:
+        """The decode step's arrays with ``live`` decoding.  Every other
+        slot rides along masked (seq_len 0, null-page table row) so the
+        jitted step has a single compile signature — with no sequence at
+        all it is the batch the engine compiles the decode program for
+        (``ServingEngine._make_ready``)."""
         n = self.serving.max_slots
         ids = np.zeros((n,), np.int32)
         positions = np.zeros((n,), np.int32)
@@ -279,15 +306,23 @@ class Scheduler:
         }
 
     def prefill_batch(self, admitted: list[_Active]) -> dict:
-        """Fixed-shape arrays for one prefill pass over newly admitted
-        sequences (padded to ``prefill_batch`` rows x ``max_prompt_len``;
-        slack rows are masked with len 0 and the null-page table row).
-        ``slots`` names each row's batch slot — the row of the cache's
-        state pools its recurrent state goes to; a slack row names
-        ``max_slots``, a row that does not exist, and its write is
-        dropped."""
+        """Arrays for one prefill pass over newly admitted sequences, at
+        the fewest rows of ``prefill_rows`` that hold them, not at
+        ``prefill_batch`` rows whatever they are: a pass's time follows
+        its shape."""
+        return self.prefill_arrays(admitted, next(
+            n for n in self.prefill_rows if n >= len(admitted)))
+
+    def prefill_arrays(self, admitted: list[_Active], rows: int) -> dict:
+        """A prefill pass's arrays at ``rows`` x ``max_prompt_len``.  Slack
+        rows are masked with len 0 and the null-page table row — with no
+        sequence at all it is the batch the engine compiles that member
+        of the ladder for (``ServingEngine._make_ready``).  ``slots``
+        names each row's batch slot — the row of the cache's state pools
+        its recurrent state goes to; a slack row names ``max_slots``, a
+        row that does not exist, and its write is dropped."""
         s = self.serving
-        nb, t = s.prefill_batch, s.max_prompt_len
+        nb, t = rows, s.max_prompt_len
         ids = np.zeros((nb, t), np.int32)
         lens = np.zeros((nb,), np.int32)
         table = np.zeros((nb, self.cache.max_pages_per_seq), np.int32)

@@ -403,6 +403,35 @@ def test_serve_step_holds_schedule_prefill_and_decode(tracer, incremental):
 
 
 @pytest.mark.serving
+def test_serve_prefill_says_what_its_shape_carried(tracer):
+    """A pass's span names the shape it ran (``rows``, ``padded_tokens`` =
+    rows x length) and the prompt tokens in it; two counters sum the same
+    over the passes, so their ratio is the fill share; the gauge (set
+    once, in the registry the engine was built with) is the number of
+    shapes the engine compiles."""
+    reg = MetricsRegistry("prefill_fill")
+    eng = _engine(max_slots=4, max_prompt_len=24, prefill_batch=4)
+    built_with, eng.registry = eng.registry, reg
+    script = [((10,), 1), ((20, 3), 4), ((24,), 1), ((9, 9, 9), 4)]
+    for lens, _ in script:
+        eng.generate([[7] * n for n in lens], max_new_tokens=2)
+    passes = _by_name(tracer.spans)["serve_prefill"]
+    assert len(passes) == len(script)
+    for span, (lens, rows) in zip(passes, script):
+        assert span.args["batch"] == len(lens)
+        assert span.args["rows"] == rows
+        assert span.args["padded_tokens"] == rows * 24
+        assert span.args["prompt_tokens"] == sum(lens)
+    padded = reg.get("serve_prefill_padded_tokens_total").value()
+    prompt = reg.get("serve_prefill_prompt_tokens_total").value()
+    assert padded == (1 + 4 + 1 + 4) * 24
+    assert prompt == 10 + 23 + 24 + 27
+    assert prompt / padded == pytest.approx(84 / 240)   # the fill share
+    assert built_with.get("serve_prefill_programs").value() == len(
+        eng.scheduler.prefill_rows) == 2
+
+
+@pytest.mark.serving
 def test_serve_decode_says_what_the_kernel_fetches(tracer):
     """``kv_block_tokens``: every live context rounded up to whole blocks
     of the decode kernel (``decode_block_pages`` page slots each), beside
@@ -443,6 +472,7 @@ def test_a_failing_step_leaves_no_open_span(tracer):
     def boom(*a, **kw):
         raise RuntimeError("device lost")
 
+    boom.lower = boom   # getting ready lowers the program before any pass
     eng._prefill = boom
     with pytest.raises(RuntimeError, match="device lost"):
         eng.step()

@@ -5,6 +5,7 @@ TTFT/TPOT percentiles, strict inference, servable export, and the
 ``python -m paddle_tpu.serving`` CLI loop (subprocess, ``serving``
 marker)."""
 
+import dataclasses
 import functools
 
 import numpy as np
@@ -489,6 +490,222 @@ class TestSchedulerAndEngine:
         sched = mk(num_pages=8, max_pages_per_seq=4, budget=16)
         sched.enqueue(Request(id=3, prompt=[1] * 4, max_new_tokens=4))
         assert len(sched.queue) == 1 and len(sched.admit()) == 1
+
+
+# -- the prefill ladder ---------------------------------------------------------
+#
+# One serving shape for every ladder test: one row or four, 96 long, on
+# models small enough that the CPU compiles both in a second.
+
+LADDER_SERVING = dict(max_slots=4, page_size=16, num_pages=64,
+                      max_prompt_len=96, max_new_tokens=4, prefill_batch=4,
+                      seed=0)
+LADDER = ((1, 96), (4, 96))     # rows (1, prefill_batch) x max_prompt_len
+# one admitted batch each: 1 ... prefill_batch rows, prompts at both ends of
+# the length, on a page's edge and either side of it
+LADDER_BATCHES = [(1,), (15,), (16,), (17,), (95,), (96,), (50, 7),
+                  (96, 96), (1, 1), (90, 20, 33), (16, 32, 48),
+                  (5, 96, 64, 17), (33, 44, 55, 66), (96,) * 4]
+
+
+def _ladder_cfg(kind):
+    if kind == "plain":
+        return small_cfg(max_seq_len=128)
+    if kind == "looped":
+        return small_cfg(max_seq_len=128, norm="rms", positions="rotary",
+                         mlp="swiglu", loop_steps=3)
+    return T.TransformerConfig(   # layers of three kinds, two with state
+        vocab_size=64, num_layers=4, num_heads=4, kv_heads=2, head_dim=8,
+        embed_dim=32, mlp_dim=24, max_seq_len=128, norm="rms",
+        positions="none", mlp="relu2", tie_embeddings=False, pattern="ME*M",
+        moe_experts=8, moe_router="sigmoid", moe_top_k=2, moe_shared_dim=16,
+        moe_held=(0, 4), mamba_heads=4, mamba_head_dim=8, mamba_state=16,
+        mamba_groups=2, mamba_conv=4, mamba_chunk=32, remat=False)
+
+
+@functools.lru_cache(maxsize=None)
+def _ladder_engines(kind):
+    """(an engine that shapes its passes, its twin that runs every pass at
+    the largest member); both see the same batches in the same order."""
+    cfg = _ladder_cfg(kind)
+    params = T.init_params(cfg, jax.random.key(3))
+    shaped = ServingEngine(cfg, params, ServingConfig(**LADDER_SERVING))
+    padded = ServingEngine(cfg, params, ServingConfig(**LADDER_SERVING))
+    assert shaped.scheduler.prefill_rows == (1, 4)
+    padded.scheduler.prefill_rows = (4,)
+    return shaped, padded
+
+
+class _Compiles:
+    """The two ``jax.monitoring`` events the benchmark's ``CompileWatch``
+    counts: a compile, or a fetch from the persistent cache."""
+
+    count = 0
+    listening = False
+
+    @classmethod
+    def listen(cls):
+        if not cls.listening:
+            jax.monitoring.register_event_duration_secs_listener(cls._on)
+            cls.listening = True
+        return cls
+
+    @classmethod
+    def _on(cls, event, duration, **kw):
+        if event.endswith(("backend_compile_duration",
+                           "cache_retrieval_time_sec")):
+            cls.count += 1
+
+
+class TestPrefillLadder:
+    @pytest.mark.parametrize("batch,longest,shape", [
+        (4, 768, ((1, 768), (4, 768))),
+        (4, 96, LADDER),
+        (2, 192, ((1, 192), (2, 192))),
+        (8, 16, ((1, 16), (8, 16))),
+        (3, 100, ((1, 100), (3, 100))),
+        (1, 2048, ((1, 2048),)),     # one row is all such an engine admits
+    ])
+    def test_ladder_from_two_numbers(self, batch, longest, shape):
+        """One row and ``prefill_batch`` rows at ``max_prompt_len``: one
+        length whatever it is (a program costs set-up time), and the
+        largest member holds whatever ``admit`` may hand over."""
+        from paddle_tpu.serving.kv_cache import PagedKVCache
+        from paddle_tpu.serving.scheduler import Scheduler, prefill_rows
+
+        assert prefill_rows(batch) == tuple(rows for rows, _ in shape)
+        s = ServingConfig(**{**LADDER_SERVING, "prefill_batch": batch,
+                             "max_prompt_len": longest, "max_slots": 8,
+                             "num_pages": 8 * (-(-longest // 16) + 1) + 1})
+        sched = Scheduler(s, PagedKVCache(1, 2, 16, s.num_pages, s.page_size,
+                                          s.max_slots, s.max_pages_per_seq))
+        got = tuple(sched.prefill_arrays([], rows)["ids"].shape
+                    for rows in sched.prefill_rows)
+        assert got == shape
+        assert got[-1] == (batch, longest)
+
+    @pytest.mark.parametrize("kind", ["plain", "looped", "pattern"])
+    def test_every_engine_has_the_same_ladder(self, kind):
+        """The ladder comes from ``prefill_batch`` alone, whatever the
+        model: a scanned stack, a looped one and a layer pattern with
+        state pools all get the one-row program beside the full one."""
+        cfg = _ladder_cfg(kind)
+        reg = MetricsRegistry(f"ladder_{kind}")
+        eng = ServingEngine(cfg, T.init_params(cfg, jax.random.key(6)),
+                            ServingConfig(**LADDER_SERVING), registry=reg)
+        assert eng.scheduler.prefill_rows == (1, 4)
+        assert reg.get("serve_prefill_programs").value() == 2
+
+    @pytest.mark.parametrize("lens,shape", [
+        ((1,), (1, 96)), ((96,), (1, 96)), ((5, 5), (4, 96)),
+        ((5, 96, 5), (4, 96)), ((96,) * 4, (4, 96)),
+    ])
+    def test_smallest_covering_member_is_picked(self, lens, shape):
+        from paddle_tpu.serving.kv_cache import PagedKVCache
+        from paddle_tpu.serving.scheduler import Request, Scheduler
+
+        s = ServingConfig(**LADDER_SERVING)
+        sched = Scheduler(s, PagedKVCache(1, 2, 16, s.num_pages, s.page_size,
+                                          s.max_slots, s.max_pages_per_seq))
+        assert sched.prefill_rows == (1, 4)
+        for i, n in enumerate(lens):
+            sched.enqueue(Request(id=i, prompt=[1 + i] * n, max_new_tokens=2))
+        admitted = sched.admit()
+        batch = sched.prefill_batch(admitted)
+        assert batch["ids"].shape == shape
+        rows = len(lens)
+        assert batch["seq_lens"].tolist() == list(lens) + [0] * (
+            shape[0] - rows)
+        # slack rows keep their contract at every shape
+        assert (batch["slots"][rows:] == s.max_slots).all()
+        assert not batch["page_table"][rows:].any()
+        assert batch["page_table"].shape == (shape[0], s.max_pages_per_seq)
+        for j, a in enumerate(admitted):
+            assert batch["ids"][j, :a.prompt_len].tolist() == a.request.prompt
+            assert not batch["ids"][j, a.prompt_len:].any()
+            assert batch["slots"][j] == a.slot
+
+    @pytest.mark.parametrize("lens", LADDER_BATCHES, ids=str)
+    @pytest.mark.parametrize("kind", ["plain", "looped", "pattern"])
+    def test_shaped_passes_serve_the_same_tokens(self, kind, lens, rng_np):
+        """Leaving the padding out changes no answer: greedy tokens are
+        those of the full-size pass; pages and recurrent state too, to a
+        few float32 roundings (a matmul of another shape may sum in another
+        order: 1e-5, set from the dtype before the first run)."""
+        shaped, padded = _ladder_engines(kind)
+        prompts = [list(rng_np.integers(1, 64, size=n)) for n in lens]
+        seen = []
+        real = shaped.scheduler.prefill_batch
+        shaped.scheduler.prefill_batch = lambda admitted: seen.append(
+            real(admitted)) or seen[-1]
+        try:
+            a = shaped.generate(prompts, max_new_tokens=3)
+        finally:
+            del shaped.scheduler.prefill_batch
+        b = padded.generate(prompts, max_new_tokens=3)
+        assert [x["ids"].shape for x in seen] == [
+            LADDER[0] if len(lens) == 1 else LADDER[1]]
+        assert [r.tokens for r in a] == [r.tokens for r in b]
+        # the null page takes the slack rows' writes: no reader sees it
+        for x, y in ((shaped.cache.k, padded.cache.k),
+                     (shaped.cache.v, padded.cache.v)):
+            np.testing.assert_allclose(np.asarray(x)[:, :, 1:],
+                                       np.asarray(y)[:, :, 1:],
+                                       rtol=1e-5, atol=1e-5)
+        assert shaped.cache.state.keys() == padded.cache.state.keys()
+        for name in shaped.cache.state:
+            np.testing.assert_allclose(
+                np.asarray(shaped.cache.state[name]),
+                np.asarray(padded.cache.state[name]), rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("kind", ["plain", "pattern"])
+    def test_nothing_compiles_after_the_first_admission(self, kind, rng_np):
+        """Every program is compiled by the step that admits the first
+        request, whatever that request is (here one short row): no
+        admissible batch compiles (or fetches from the persistent cache)
+        afterwards.  An idle step compiles nothing: a fleet's router pumps
+        idle replicas."""
+        watch = _Compiles.listen()
+        # a vocabulary no other test serves: its programs are not compiled yet
+        cfg = dataclasses.replace(_ladder_cfg(kind), vocab_size=71)
+        eng = ServingEngine(cfg, T.init_params(cfg, jax.random.key(4)),
+                            ServingConfig(**LADDER_SERVING))
+        before = watch.count
+        assert eng.step() is False and watch.count == before
+        eng.generate([[5, 17, 3]], max_new_tokens=2)
+        ready = watch.count
+        # the ladder and decode
+        assert ready - before >= len(eng.scheduler.prefill_rows) + 1
+        for lens in LADDER_BATCHES:
+            eng.generate([list(rng_np.integers(1, 64, size=n))
+                          for n in lens], max_new_tokens=3)
+        assert watch.count == ready
+
+    def test_making_ready_leaves_the_cache_as_it_was(self, rng_np):
+        """Getting every member of the ladder and the decode program
+        ready compiles and runs nothing: the pages, the recurrent state
+        and the page table are bit for bit what they were."""
+        cfg = _ladder_cfg("pattern")
+        eng = ServingEngine(cfg, T.init_params(cfg, jax.random.key(5)),
+                            ServingConfig(**LADDER_SERVING))
+        assert eng.scheduler.prefill_rows == (1, 4)  # beside state pools
+        cache = eng.cache
+        fill = lambda x: jnp.asarray(
+            rng_np.normal(size=x.shape).astype(np.float32))
+        cache.k, cache.v = fill(cache.k), fill(cache.v)
+        cache.state = {n: fill(x) for n, x in cache.state.items()}
+        cache.assign(1, 40)     # a resident sequence's table row
+        k, v, state, table = (np.asarray(cache.k), np.asarray(cache.v),
+                              {n: np.asarray(x) for n, x in
+                               cache.state.items()}, cache.page_table.copy())
+        assert state and table.any()
+        eng._make_ready()
+        assert np.array_equal(np.asarray(cache.k), k)
+        assert np.array_equal(np.asarray(cache.v), v)
+        for name, was in state.items():
+            assert np.array_equal(np.asarray(cache.state[name]), was)
+        assert np.array_equal(cache.page_table, table)
+        assert eng.scheduler.active == [] and not eng.scheduler.queue
 
 
 class TestServeTelemetry:
