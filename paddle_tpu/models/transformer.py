@@ -69,14 +69,18 @@ class TransformerConfig:
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 1e-2
     moe_dispatch: str = "sort"  # "einsum" = dense one-hot GShard tensors
-    # "softmax" = the capacity paths above (top-1/2, overflow dropped);
-    # "sigmoid" = dropless routing (``parallel.moe.moe_routed``): sigmoid
-    # scores over all ``moe_experts`` in float32, top-``moe_top_k`` of
-    # score + a selection-only bias, normalised and scaled by
-    # ``moe_scale``; experts are ``mlp_dim`` wide with no gate and no
-    # bias; a shared expert of ``moe_shared_dim`` (0 = none) takes every
-    # token.  ``moe_held`` = [lo, hi): the experts THIS device holds and
-    # computes (None = all) — expert parallelism's share of the layer
+    # "softmax" = the CAPACITY paths above (``moe_ffn``: top-1/2, overflow
+    # dropped, biased GELU experts) and nothing else; the two others are
+    # DROPLESS routing (``parallel.moe.moe_routed``): scores over all
+    # ``moe_experts`` in float32 — "sigmoid" (top-``moe_top_k`` of score +
+    # a selection-only bias) | "softmax_topk" (softmax over all experts,
+    # no bias) — the chosen scores normalised and scaled by ``moe_scale``;
+    # experts are ``mlp_dim`` wide with no bias, ``mlp`` their kind
+    # ("relu2" | "gelu": act(x W_in) W_out; "swiglu": gated, (silu(x
+    # W_gate) * (x W_in)) W_out); a shared expert of ``moe_shared_dim`` (0
+    # = none; ungated) takes every token.  ``moe_held`` = [lo, hi): the
+    # experts THIS device holds and computes (None = all) — expert
+    # parallelism's share of the layer
     moe_router: str = "softmax"
     moe_scale: float = 1.0
     moe_shared_dim: int = 0
@@ -98,6 +102,9 @@ class TransformerConfig:
     # that read the sequence in order: a recurrent layer, causal masking)
     positions: str = "learned"
     rope_theta: float = 1e4
+    # RMSNorm over head_dim on every head of q and of k, before RoPE, with
+    # one gain each a layer (``q_norm_g``, ``k_norm_g``) at ``norm_eps``
+    qk_norm: bool = False
     # "gelu" (w_in/w_out with biases) | "swiglu" (gate/up/down, no bias)
     # | "relu2" (w_in/w_out, squared ReLU, no bias)
     mlp: str = "gelu"
@@ -111,8 +118,9 @@ class TransformerConfig:
     early_exit_threshold: float = 1.0
     # layers of several kinds: one character per layer, each layer ONE
     # mixer behind a pre-norm and a residual add — "*" attention, "-" the
-    # dense MLP, "E" routed experts (``moe_router="sigmoid"``), "M" a
-    # Mamba-2 mixer.  None = ``num_layers`` blocks of (attention, MLP).
+    # dense MLP, "E" routed experts (dropless: ``moe_router`` "sigmoid" or
+    # "softmax_topk"), "M" a Mamba-2 mixer.  None = ``num_layers`` blocks
+    # of (attention, MLP).
     # ``params["blocks"]`` is then a list of per-layer trees in pattern
     # order, walked by the pattern.
     pattern: str | None = None
@@ -126,6 +134,14 @@ class TransformerConfig:
     mamba_groups: int = 1
     mamba_conv: int = 4
     mamba_chunk: int = 128
+    # generation by diffusion over blocks: positions come ``block_len`` at
+    # a time.  The attention mask is causal over BLOCKS (position i sees j
+    # iff j // block_len <= i // block_len) and a served sequence is
+    # generated a block a time: the block starts as ``mask_id`` tokens, a
+    # pass computes all its positions (``forward_decode_block``) and some
+    # are unmasked; 1 = one token a pass, every program of before
+    block_len: int = 1
+    mask_id: int | None = None
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -138,19 +154,37 @@ class TransformerConfig:
         for field, allowed in (("norm", ("layer", "rms")),
                                ("positions", ("learned", "rotary", "none")),
                                ("mlp", ("gelu", "swiglu", "relu2")),
-                               ("moe_router", ("softmax", "sigmoid"))):
+                               ("moe_router", ("softmax", "sigmoid",
+                                               "softmax_topk"))):
             if getattr(self, field) not in allowed:
                 raise ValueError(f"{field} must be one of {allowed}, got "
                                  f"{getattr(self, field)!r}")
         if self.num_heads % self.kv_heads:
             raise ValueError(f"num_heads {self.num_heads} is not a multiple "
                              f"of kv_heads {self.kv_heads}")
-        if self.moe_experts and self.moe_router == "sigmoid":
-            if self.mlp == "swiglu":
-                raise ValueError("routed experts have no gate: mlp must be "
-                                 "'gelu' or 'relu2' under moe_router="
-                                 "'sigmoid'")
+        if self.moe_experts and self.moe_dropless:
+            if self.mlp == "swiglu" and self.moe_shared_dim:
+                raise NotImplementedError(
+                    "a shared expert beside gated routed experts: the "
+                    "shared expert has no gate matrix")
             self.routed  # validates top_k and the held share
+        if self.block_len < 1:
+            raise ValueError(f"block_len must be >= 1, got {self.block_len}")
+        if self.block_len > 1:
+            if self.mask_id is None or not (
+                    0 <= self.mask_id < self.vocab_size):
+                raise ValueError(
+                    f"block_len {self.block_len} > 1 needs a mask_id inside "
+                    f"[0, {self.vocab_size}), got {self.mask_id!r}")
+            if self.attn_impl not in ("exact", "flash"):
+                raise NotImplementedError(
+                    f"attn_impl {self.attn_impl!r} under block_len > 1: only "
+                    "'exact' and 'flash' take the block-causal mask")
+            if self.loop_steps > 1 or (self.pattern and "M" in self.pattern):
+                raise NotImplementedError(
+                    "block_len > 1 with loop_steps > 1 or Mamba layers: a "
+                    "block pass over a looped stack or a recurrent state is "
+                    "not built")
         if self.pattern is not None:
             bad = sorted(set(self.pattern) - set(_KINDS))
             if bad or len(self.pattern) != self.num_layers:
@@ -158,9 +192,10 @@ class TransformerConfig:
                     f"pattern {self.pattern!r} must be num_layers "
                     f"({self.num_layers}) characters of {sorted(_KINDS)}")
             if "E" in self.pattern and not (
-                    self.moe_experts and self.moe_router == "sigmoid"):
-                raise ValueError("an 'E' layer needs moe_experts > 0 and "
-                                 "moe_router='sigmoid'")
+                    self.moe_experts and self.moe_dropless):
+                raise ValueError("an 'E' layer needs moe_experts > 0 and a "
+                                 "dropless moe_router ('sigmoid' or "
+                                 "'softmax_topk')")
             if "M" in self.pattern and not (
                     self.mamba_heads and self.mamba_heads
                     % self.mamba_groups == 0):
@@ -202,12 +237,20 @@ class TransformerConfig:
                                    self.mamba_conv)
 
     @property
+    def moe_dropless(self) -> bool:
+        """The experts run through ``moe_routed`` (no capacity)."""
+        return self.moe_router in ("sigmoid", "softmax_topk")
+
+    @property
     def routed(self):
         from paddle_tpu.parallel.moe import RoutedConfig
 
-        return RoutedConfig(num_experts=self.moe_experts,
-                            top_k=self.moe_top_k, scale=self.moe_scale,
-                            held=self.moe_held, act=self.mlp)
+        gated = self.mlp == "swiglu"
+        return RoutedConfig(
+            num_experts=self.moe_experts, top_k=self.moe_top_k,
+            scale=self.moe_scale, held=self.moe_held,
+            act="silu" if gated else self.mlp, gated=gated,
+            score="sigmoid" if self.moe_router == "sigmoid" else "softmax")
 
     @property
     def moe(self):
@@ -230,13 +273,16 @@ def _ffn_params(cfg: TransformerConfig, norm, zeros, lead: tuple,
     residual scaling)."""
     e, m = cfg.embed_dim, cfg.mlp_dim
     experts = experts and cfg.moe_experts
-    if experts and cfg.moe_router == "sigmoid":
+    if experts and cfg.moe_dropless:
         ex, held = cfg.moe_experts, cfg.routed.num_held
-        p = {"router": norm(*lead, e, ex) * (e ** -0.5),
-             "router_bias": zeros(*lead, ex),
-             "w_in": norm(*lead, held, e, m) * (e ** -0.5),
-             "w_out": norm(*lead, held, m, e) * (m ** -0.5)
-             / (2 * depth) ** 0.5}
+        p = {"router": norm(*lead, e, ex) * (e ** -0.5)}
+        if cfg.moe_router == "sigmoid":
+            p["router_bias"] = zeros(*lead, ex)
+        p.update(w_in=norm(*lead, held, e, m) * (e ** -0.5),
+                 w_out=norm(*lead, held, m, e) * (m ** -0.5)
+                 / (2 * depth) ** 0.5)
+        if cfg.routed.gated:
+            p["w_gate"] = norm(*lead, held, e, m) * (e ** -0.5)
         if cfg.moe_shared_dim:
             sh = cfg.moe_shared_dim
             p["shared_in"] = norm(*lead, e, sh) * (e ** -0.5)
@@ -269,6 +315,15 @@ def _ffn_params(cfg: TransformerConfig, norm, zeros, lead: tuple,
     }
 
 
+def _qk_norm_params(cfg: TransformerConfig, lead: tuple) -> dict:
+    """The per-head q/k norm gains of ``lead`` stacked layers (none
+    without ``qk_norm``)."""
+    if not cfg.qk_norm:
+        return {}
+    return {"q_norm_g": jnp.ones((*lead, cfg.head_dim), cfg.dtype),
+            "k_norm_g": jnp.ones((*lead, cfg.head_dim), cfg.dtype)}
+
+
 def _pattern_params(cfg: TransformerConfig, norm, zeros, norm_p) -> list:
     """``params["blocks"]`` under a layer pattern: one tree per LAYER, in
     pattern order, its leaves by the layer's kind; every layer carries its
@@ -287,7 +342,8 @@ def _pattern_params(cfg: TransformerConfig, norm, zeros, norm_p) -> list:
             return {"wq": norm(e, h) * (e ** -0.5),
                     "wk": norm(e, hk) * (e ** -0.5),
                     "wv": norm(e, hk) * (e ** -0.5),
-                    "wo": norm(h, e) * (h ** -0.5) / (2 * s) ** 0.5}
+                    "wo": norm(h, e) * (h ** -0.5) / (2 * s) ** 0.5,
+                    **_qk_norm_params(cfg, ())}
         if kind in ("mlp", "moe"):
             return _ffn_params(cfg, norm, zeros, (), s, kind == "moe")
         # dt_bias around softplus^-1(0.01) and A = -exp(a_log) <= -1 (the
@@ -350,6 +406,7 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
         "wo": norm(s, h, e) * (h ** -0.5) / (2 * s) ** 0.5,
         **norm_p("ln2", s),
         **ffn,
+        **_qk_norm_params(cfg, (s,)),
     }
     if cfg.norm_sandwich:
         blocks.update(**norm_p("ln1_post", s), **norm_p("ln2_post", s))
@@ -369,8 +426,7 @@ def param_shardings(cfg: TransformerConfig) -> dict:
     (axis names degrade to replicated if absent from the mesh via
     MeshContext.param_sharding semantics; used directly with NamedSharding
     they must exist)."""
-    if cfg.pattern is not None or (cfg.moe_experts
-                                   and cfg.moe_router == "sigmoid"):
+    if cfg.pattern is not None or (cfg.moe_experts and cfg.moe_dropless):
         raise NotImplementedError(
             "param_shardings: no tensor- or expert-parallel layout is "
             "written for a layer pattern or for dropless routed experts "
@@ -410,6 +466,8 @@ def param_shardings(cfg: TransformerConfig) -> dict:
     }
     if cfg.norm_sandwich:
         specs["blocks"].update(**norm_p("ln1_post"), **norm_p("ln2_post"))
+    if cfg.qk_norm:
+        specs["blocks"].update(q_norm_g=P(), k_norm_g=P())
     if cfg.positions == "learned":
         specs["pos_embed"] = P()
     if not cfg.tie_embeddings:
@@ -442,11 +500,15 @@ def _norm(cfg: TransformerConfig, x, p, name):
     """The config's norm over the last axis with the parameters
     ``p[name + "_g"]`` (and ``"_b"`` under "layer")."""
     if cfg.norm == "rms":
-        xf = x.astype(jnp.float32)
-        xf = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
-                            + cfg.norm_eps)
-        return xf.astype(x.dtype) * p[name + "_g"]
+        return _rms(cfg, x, p[name + "_g"])
     return _ln(x, p[name + "_g"], p[name + "_b"], cfg.norm_eps)
+
+
+def _rms(cfg: TransformerConfig, x, gain):
+    xf = x.astype(jnp.float32)
+    xf = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                        + cfg.norm_eps)
+    return xf.astype(x.dtype) * gain
 
 
 def _rope_table(cfg: TransformerConfig, positions):
@@ -484,8 +546,11 @@ def _embed(cfg: TransformerConfig, params, ids, positions=None):
                           if positions is None else positions)
 
 
-def _head(cfg: TransformerConfig, params, x):
-    return x @ (params["embed"].T if cfg.tie_embeddings else params["head"])
+def _head(cfg: TransformerConfig, params, x, out_dtype=None):
+    w = params["embed"].T if cfg.tie_embeddings else params["head"]
+    if out_dtype is None:
+        return x @ w
+    return jnp.matmul(x, w, preferred_element_type=out_dtype)
 
 
 def _attention(cfg: TransformerConfig, q, k, v, mesh):
@@ -510,12 +575,14 @@ def _attention(cfg: TransformerConfig, q, k, v, mesh):
             q, k, v, block_size=min(cfg.attn_block_size, q.shape[1]),
             causal=True
         )
+    # True = causal over tokens; an int > 1 = causal over blocks of it
+    causal = True if cfg.block_len == 1 else cfg.block_len
     if cfg.attn_impl == "flash":
         from paddle_tpu.ops.pallas import flash_attention
 
         bs = cfg.attn_block_size
         if mesh is None:
-            return flash_attention(q, k, v, True, None, bs, bs)
+            return flash_attention(q, k, v, causal, None, bs, bs)
         # pallas_call has no GSPMD partitioning rule — run the kernel
         # per-device under shard_map (batch over data, heads over model;
         # sequence sharding needs attn_impl="ring" or "ulysses" instead)
@@ -532,15 +599,18 @@ def _attention(cfg: TransformerConfig, q, k, v, mesh):
             None,
         )
         fn = shard_map(
-            lambda q, k, v: flash_attention(q, k, v, True, None, bs, bs),
+            lambda q, k, v: flash_attention(q, k, v, causal, None, bs, bs),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False,
         )
         return fn(q, k, v)
     t = q.shape[1]
-    return attn_ops.dot_product_attention(
-        q, k, v, mask=attn_ops.causal_mask(t, t)
-    )
+    if cfg.block_len > 1:
+        blk = jnp.arange(t) // cfg.block_len
+        mask = (blk[:, None] >= blk[None, :])[None, None]
+    else:
+        mask = attn_ops.causal_mask(t, t)
+    return attn_ops.dot_product_attention(q, k, v, mask=mask)
 
 
 def _qkv(cfg: TransformerConfig, h, layer, rope):
@@ -550,6 +620,9 @@ def _qkv(cfg: TransformerConfig, h, layer, rope):
     q = (h @ layer["wq"]).reshape(*lead, cfg.num_heads, hd)
     k = (h @ layer["wk"]).reshape(*lead, cfg.kv_heads, hd)
     v = (h @ layer["wv"]).reshape(*lead, cfg.kv_heads, hd)
+    if cfg.qk_norm:
+        q, k = (_rms(cfg, q, layer["q_norm_g"]),
+                _rms(cfg, k, layer["k_norm_g"]))
     if rope is not None:
         q, k = _rope(q, rope), _rope(k, rope)
     return q, k, v
@@ -563,7 +636,7 @@ def _mlp(cfg: TransformerConfig, h, layer, mesh=None, live=None,
     aux is the capacity MoE's load-balancing loss, the routed MoE's
     counts (``parallel.moe.moe_routed``), None for a dense FFN."""
     experts = experts and cfg.moe_experts
-    if experts and cfg.moe_router == "sigmoid":
+    if experts and cfg.moe_dropless:
         from paddle_tpu.parallel.moe import moe_routed
 
         y, counts = moe_routed(layer, h, cfg.routed, live)
@@ -625,7 +698,7 @@ def _block(cfg: TransformerConfig, x, layer, attend, rope=None, mesh=None,
         x = branch_out(x, a.reshape(*lead, nh * hd) @ layer["wo"], None,
                        "ln1_post")
         y, bias, aux = _mlp(cfg, _norm(cfg, x, layer, "ln2"), layer, mesh)
-        if cfg.moe_router == "sigmoid":
+        if cfg.moe_dropless:
             aux = None  # routing counts: the pattern walk's to report
         return branch_out(x, y, bias, "ln2_post"), aux
 
@@ -1018,6 +1091,69 @@ def forward_decode(cfg: TransformerConfig, params: dict, ids: jax.Array,
     return _head(cfg, params, x), k_cache, v_cache
 
 
+def forward_decode_block(cfg: TransformerConfig, params: dict,
+                         ids: jax.Array, masked: jax.Array,
+                         starts: jax.Array, seq_lens: jax.Array,
+                         page_table: jax.Array, k_cache, v_cache,
+                         attn_impl: str = "auto"):
+    """One pass over each row's in-progress block of ``block_len``
+    positions — ``forward_decode``'s sibling for generation by diffusion
+    over blocks.
+
+    ids [B, T] the block's tokens (``T = block_len``), masked [B, T] bool
+    the positions still to be generated (they embed ``mask_id`` whatever
+    ``ids`` holds there), starts [B] the block's first absolute position,
+    seq_lens [B] = starts + T on live rows and 0 on idle rows.  Each
+    attention layer writes the block's K/V into its pages — over what the
+    pass before left there: a block's K/V stand only once a pass found
+    nothing masked — then every position attends the row's whole context
+    ``[0, seq_lens)``, earlier blocks and its own block alike, unmasked
+    (``paged_attention.block_paged_attention``).  Returns (float32 logits
+    [B, T, V], k_cache', v_cache', extras) — each position's logits
+    predict that position's OWN token."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    t = ids.shape[1]
+    ids = jnp.where(masked, cfg.mask_id, ids)
+    pos = jnp.clip(starts[:, None] + jnp.arange(t)[None, :], 0,
+                   cfg.max_seq_len - 1)
+    x, rope = _embed(cfg, params, ids, pos)
+    live = seq_lens > 0
+    new = jnp.where(live, t, 0)
+
+    def attend(cache_layer, kc, vc, q, k, v):
+        pools = pa.write_chunk_kv(kc, vc, k, v, cache_layer, page_table,
+                                  starts, new)
+        return pa.block_paged_attention(
+            q, *pools, cache_layer, page_table, seq_lens, impl=attn_impl,
+            kv_heads=cfg.kv_heads), pools
+
+    if cfg.pattern is not None:
+        pools = [k_cache, v_cache]
+
+        def attend_layer(i, q, k, v):
+            a, pools[:] = attend(i, *pools, q, k, v)
+            return a
+
+        x, counts = _run_pattern(
+            cfg, params, x, lambda kind, i, layer, x: _pattern_layer(
+                cfg, kind, layer, x, rope,
+                functools.partial(attend_layer, i), None,
+                jnp.broadcast_to(live[:, None], ids.shape)))
+        return (_head(cfg, params, x, jnp.float32), *pools,
+                {"state": {}, "moe_counts": counts})
+
+    def layer_fn(x, layer, cache_layer, kc, vc):
+        x, _, pools = _block(cfg, x, layer,
+                             functools.partial(attend, cache_layer, kc, vc),
+                             rope)
+        return x, pools
+
+    x, (k_cache, v_cache) = _run_stack(cfg, params, x, layer_fn,
+                                       (k_cache, v_cache))
+    return _head(cfg, params, x, jnp.float32), k_cache, v_cache, {}
+
+
 def _decode_pattern(cfg: TransformerConfig, params, x, rope, positions,
                     seq_lens, page_table, k_cache, v_cache, attn_impl, state):
     """One token per row x [B, E] through a layer pattern.  Every pool —
@@ -1076,6 +1212,11 @@ def loss_fn(cfg: TransformerConfig, params: dict, ids: jax.Array,
     63.0 ms/step at the 124M bench): XLA fuses the CE chain into the
     LM-head backward matmuls, which the opaque pallas_call boundary
     prevents — kept as a library op and a documented negative result."""
+    if cfg.block_len > 1:
+        raise NotImplementedError(
+            "loss_fn under block_len > 1: the diffusion objective (masked "
+            "blocks beside their clean copy) is not built; next-token "
+            "cross-entropy is not this model's loss")
     logits, aux = forward_with_aux(cfg, params, ids[:, :-1], mesh=mesh)
     targets = ids[:, 1:]
     lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)

@@ -33,13 +33,27 @@ from paddle_tpu.ops.pallas import NEG_INF, round_up as _round_up
 
 
 def _causal_valid(bq, bk, qi0, ki0, t_k, causal):
-    """[bq, bk] bool: key in range, and (if causal) key pos <= query pos."""
+    """[bq, bk] bool: key in range, and (if causal) key pos <= query pos.
+    ``causal`` may be a block length ``B`` > 1 (an int, not a bool):
+    causal over blocks of ``B`` positions, key block <= query block, so a
+    query sees its whole block — generation by diffusion over blocks;
+    ``True`` is ``B = 1``."""
     qi = qi0 + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     ki = ki0 + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
     valid = ki < t_k
     if causal:
-        valid &= qi >= ki
+        b = int(causal)
+        valid &= qi >= ki if b == 1 else qi // b >= ki // b
     return valid
+
+
+def _tile_live(i, j, bq, bk, causal):
+    """False for a (query tile i, key tile j) wholly above the (block)
+    diagonal: its first key lies past what the tile's last query sees."""
+    last, b = i * bq + bq - 1, int(causal)
+    if b > 1:
+        last = last // b * b + b - 1
+    return j * bk <= last
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
@@ -78,7 +92,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
     if causal:
-        pl.when(j * bk <= i * bq + bq - 1)(_tile)
+        pl.when(_tile_live(i, j, bq, bk, causal))(_tile)
     else:
         _tile()
 
@@ -117,7 +131,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                                preferred_element_type=jnp.float32)
 
     if causal:
-        pl.when(j * bk <= i * bq + bq - 1)(_tile)
+        pl.when(_tile_live(i, j, bq, bk, causal))(_tile)
     else:
         _tile()
 
@@ -157,7 +171,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                preferred_element_type=jnp.float32)
 
     if causal:
-        pl.when(j * bk <= i * bq + bq - 1)(_tile)
+        pl.when(_tile_live(i, j, bq, bk, causal))(_tile)
     else:
         _tile()
 
@@ -314,6 +328,8 @@ def flash_attention(q, k, v, causal=False, scale=None,
 
     Numerically equal (to fp tolerance) to
     ``attention.dot_product_attention(q, k, v, causal mask)``; O(T) memory.
+    ``causal``: False | True | a block length > 1 (causal over blocks:
+    ``_causal_valid``).
     ``interpret=None`` auto-selects interpreter mode off-TPU.
     """
     b, t_q, h, d = q.shape
@@ -438,7 +454,8 @@ def flash_attention_reference(q, k, v, causal=False, scale=None):
                    k.astype(jnp.float32)) * scale
     if causal:
         t_q, t_k = s.shape[-2], s.shape[-1]
-        ok = jnp.arange(t_q)[:, None] >= jnp.arange(t_k)[None, :]
+        ok = (jnp.arange(t_q)[:, None] // int(causal)
+              >= jnp.arange(t_k)[None, :] // int(causal))
         s = jnp.where(ok[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p,

@@ -51,7 +51,13 @@ Two interchangeable implementations of the attention itself:
   holds fewer K/V heads than the model has query heads (``kv_heads``),
   the ``rep = H / kv_heads`` query heads of a K/V head ride those rows
   instead (``rep`` rounded up to 8): the pools, the blocks fetched and
-  the kernel body are the same.  The grid is ONE
+  the kernel body are the same.  A pass that carries a BLOCK of ``T``
+  positions a sequence (generation by diffusion over blocks:
+  ``block_paged_attention``) needs no other kernel either: every
+  position of the block sees the same context ``[0, block end)`` — the
+  block's own K/V are written before the call — so its ``T`` positions
+  ride as ``T * rep`` query heads of their K/V head and the length mask
+  is still the whole mask.  The grid is ONE
   dimension over a work list built from ``seq_lens`` — every row's live
   blocks in order, an idle row one step that writes its zeros — and its
   length is a run-time value: a block wholly past ``seq_len`` is not a
@@ -497,3 +503,24 @@ def ragged_paged_attention(q, k_pool, v_pool, cache_layer, page_table,
             kv_heads=kv_heads)
     return _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens,
                         scale, resolve_interpret(interpret), kv_heads)
+
+
+def block_paged_attention(q, k_pool, v_pool, cache_layer, page_table,
+                          seq_lens, scale=None, impl="auto", interpret=None,
+                          kv_heads=None):
+    """A block pass's attention: q [B, T, H, D], the ``T`` positions of
+    each row's in-progress block, every one of them over the row's whole
+    resident context ``[0, seq_lens[b])`` — earlier blocks and the block
+    itself, whose K/V are already in its pages (no mask inside a block).
+    Since all ``T`` positions read the same keys, they are folded into
+    the query heads: K/V head ``j``'s queries become its ``rep`` heads at
+    position 0, then at position 1, ... (``T * rep`` heads), and
+    :func:`ragged_paged_attention` runs as it is.  Returns [B, T, H, D]."""
+    b, t, h, d = q.shape
+    kv = kv_heads or h
+    folded = _grouped(q, kv).swapaxes(1, 2).reshape(b, t * h, d)
+    out = ragged_paged_attention(
+        folded, k_pool, v_pool, cache_layer, page_table, seq_lens,
+        scale=scale, impl=impl, interpret=interpret, kv_heads=kv)
+    return out.reshape(b, kv, t, h // kv, d).swapaxes(1, 2).reshape(
+        b, t, h, d)

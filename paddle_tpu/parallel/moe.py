@@ -36,12 +36,15 @@ mean_gate_prob) per expert, pushing the router toward uniform load.
 Beside the capacity paths, :func:`moe_routed` is the DROPLESS layer of
 today's large sparse models, for a device that holds a contiguous share
 ``[lo, hi)`` of the experts (expert parallelism's unit; all of them on
-one device): sigmoid scores in float32 over ALL experts, top-k of score
-+ a selection-only correction bias, the chosen scores normalised and
-scaled, every assignment to a held expert computed whatever the load,
+one device): scores in float32 over ALL experts — sigmoid, or softmax
+(``RoutedConfig.score``) — top-k of score + a selection-only correction
+bias where the tree has one, the chosen scores normalised and scaled,
+every assignment to a held expert computed whatever the load,
 assignments to absent experts left to the devices that hold them, plus
-an optional shared expert every token passes.  No exchange, no
-capacity, nothing that stands in for the absent share.
+an optional shared expert every token passes.  An expert is ``act(x
+W_in) W_out`` or, gated (``RoutedConfig.gated``), ``(silu(x W_gate) * (x
+W_in)) W_out``.  No exchange, no capacity, nothing that stands in for
+the absent share.
 """
 
 from __future__ import annotations
@@ -356,7 +359,14 @@ class RoutedConfig:
     top_k: int
     scale: float = 1.0          # on the normalised weights of the chosen
     held: tuple = None          # [lo, hi) of the experts computed here
-    act: str = "relu2"          # "relu2" | "gelu" | "silu" (no gate, no bias)
+    act: str = "relu2"          # "relu2" | "gelu" | "silu" (no bias)
+    # what the router's logits become before the top-k: "sigmoid" (each
+    # expert scored alone) | "softmax" (over all ``num_experts``); either
+    # way the chosen scores are renormalised to sum to ``scale``
+    score: str = "sigmoid"
+    # True: an expert is (act(x W_gate) * (x W_in)) W_out — SwiGLU with
+    # ``act="silu"`` — and the tree holds ``w_gate`` beside ``w_in``
+    gated: bool = False
 
     def __post_init__(self):
         held = (0, self.num_experts) if self.held is None else tuple(self.held)
@@ -368,6 +378,9 @@ class RoutedConfig:
         if not 1 <= self.top_k <= self.num_experts:
             raise ValueError(f"top_k {self.top_k} outside [1, "
                              f"{self.num_experts}]")
+        if self.score not in ("sigmoid", "softmax"):
+            raise ValueError(f"score must be 'sigmoid' or 'softmax', got "
+                             f"{self.score!r}")
 
     @property
     def num_held(self) -> int:
@@ -382,12 +395,14 @@ def _act(name: str, h):
 
 def route_topk(x2, router, bias, cfg: RoutedConfig):
     """The published router, in float32: (expert ids [T, k], weights
-    [T, k]).  The correction ``bias`` moves which experts are chosen and
-    never what they weigh."""
+    [T, k]).  The correction ``bias`` (None: the router has none) moves
+    which experts are chosen and never what they weigh."""
     f32 = jnp.float32
-    s = jax.nn.sigmoid(jnp.dot(x2.astype(f32), router.astype(f32),
-                               precision=lax.Precision.HIGHEST))
-    _, idx = lax.top_k(s + bias.astype(f32), cfg.top_k)
+    s = jnp.dot(x2.astype(f32), router.astype(f32),
+                precision=lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(s) if cfg.score == "sigmoid" else jax.nn.softmax(s, -1)
+    _, idx = lax.top_k(s if bias is None else s + bias.astype(f32),
+                       cfg.top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     return idx, w / jnp.sum(w, axis=-1, keepdims=True) * cfg.scale
 
@@ -395,9 +410,10 @@ def route_topk(x2, router, bias, cfg: RoutedConfig):
 def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None):
     """x [..., D] -> (y like x, counts int32 [4]).
 
-    ``params``: ``router`` [D, X] and ``router_bias`` [X] over all X
-    experts; ``w_in`` [held, D, F] and ``w_out`` [held, F, D] of the held
-    ones; optionally ``shared_in`` [D, S] / ``shared_out`` [S, D].  ``y``
+    ``params``: ``router`` [D, X] and, optionally, ``router_bias`` [X]
+    over all X experts; ``w_in`` [held, D, F] and ``w_out`` [held, F, D]
+    of the held ones (gated: ``w_gate`` [held, D, F] too); optionally
+    ``shared_in`` [D, S] / ``shared_out`` [S, D].  ``y``
     is the held experts' part of the layer's result plus the shared
     expert.  ``live`` (bool, x's leading shape) masks rows that are no
     token (idle decode rows, padding): they are routed nowhere.
@@ -409,7 +425,7 @@ def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None):
     x2 = x.reshape(-1, shape[-1])
     t, k = x2.shape[0], cfg.top_k
     lo, hi = cfg.held
-    idx, w = route_topk(x2, params["router"], params["router_bias"], cfg)
+    idx, w = route_topk(x2, params["router"], params.get("router_bias"), cfg)
     held = (idx >= lo) & (idx < hi)
     if live is not None:
         alive = live.reshape(-1, 1)
@@ -420,6 +436,7 @@ def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None):
     w = jnp.where(held, w, 0.0)
     local = jnp.where(held, idx - lo, cfg.num_held)   # num_held = nowhere
     w_in, w_out = params["w_in"], params["w_out"]
+    w_gate = params["w_gate"] if cfg.gated else None
     if t <= DENSE_MAX_TOKENS:
         # [T, held] combine weights; every held expert over every row
         comb = jnp.sum(w[..., None] * (local[..., None] == jnp.arange(
@@ -429,7 +446,12 @@ def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None):
         def masked(rows, comb):
             h = jnp.einsum("td,xdf->xtf", rows, w_in,
                            preferred_element_type=f32)
-            h = (_act(cfg.act, h) * comb.T[..., None]).astype(x.dtype)
+            if cfg.gated:
+                h = h * _act(cfg.act, jnp.einsum(
+                    "td,xdf->xtf", rows, w_gate, preferred_element_type=f32))
+            else:
+                h = _act(cfg.act, h)
+            h = (h * comb.T[..., None]).astype(x.dtype)
             return jnp.einsum("xtf,xfd->td", h, w_out,
                               preferred_element_type=f32)
 
@@ -456,7 +478,12 @@ def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None):
         loads = jnp.bincount(flat, length=cfg.num_held + 1)[:-1]
         rows = x2[order // k]
         h = lax.ragged_dot(rows, w_in, loads, preferred_element_type=f32)
-        h = _act(cfg.act, h).astype(x.dtype)
+        if cfg.gated:
+            h = h * _act(cfg.act, lax.ragged_dot(
+                rows, w_gate, loads, preferred_element_type=f32))
+        else:
+            h = _act(cfg.act, h)
+        h = h.astype(x.dtype)
         ys = lax.ragged_dot(h, w_out, loads, preferred_element_type=f32)
         ws = w.reshape(-1)[order]
         ys = jnp.where(ws[:, None] > 0, ys * ws[:, None], 0.0)

@@ -64,6 +64,13 @@ def build_argparser() -> argparse.ArgumentParser:
                    help="split long-prompt prefill into chunks of this "
                         "many tokens interleaved with decode steps "
                         "(0 = whole-prompt prefill, today's behavior)")
+    p.add_argument("--denoise_steps", type=int, default=2,
+                   help="a model that generates by blocks (--model_json "
+                        "block_len > 1): denoising passes a block gets "
+                        "before the pass that commits it")
+    p.add_argument("--unmask_policy", default="low_confidence_static",
+                   choices=("low_confidence_static", "sequential"),
+                   help="which masked positions a denoising pass unmasks")
     p.add_argument("--metrics_jsonl", default=None)
     p.add_argument("--replicas", type=int, default=1,
                    help="serve through a local fleet of N replica "
@@ -109,7 +116,8 @@ def main(argv=None) -> int:
         num_pages=args.num_pages, max_prompt_len=args.max_prompt_len,
         max_new_tokens=args.max_new_tokens, seed=args.seed,
         prefix_cache=args.prefix_cache,
-        prefill_chunk_tokens=args.prefill_chunk_tokens)
+        prefill_chunk_tokens=args.prefill_chunk_tokens,
+        denoise_steps=args.denoise_steps, unmask_policy=args.unmask_policy)
     if args.replicas > 1:
         from paddle_tpu.serving.fleet import build_local_fleet
 

@@ -32,7 +32,14 @@ the engine's first request; set once, at construction); under routed
 experts counters ``serve_moe_assignments_total{where=held|absent}`` /
 ``serve_moe_experts_touched_total`` and gauge
 ``serve_moe_load_max_over_mean`` (decode steps: the busiest held
-expert's tokens over the mean); with ``--prefix_cache`` /
+expert's tokens over the mean); under a model that generates by blocks
+(``block_len`` > 1) gauge ``serve_block_length`` and counters
+``serve_block_passes_total{kind=denoise|commit}`` (a row of a block
+pass, by whether it went in with masked positions),
+``serve_blocks_committed_total``, ``serve_block_positions_total``
+(rows x block_len computed) and ``serve_tokens_dropped_total`` (committed
+positions never handed out: a last block's surplus); with
+``--prefix_cache`` /
 ``--prefill_chunk_tokens``
 also counters ``serve_prefix_hit_tokens`` / ``serve_prefill_flops_saved``
 / ``serve_prefill_chunks`` and gauge ``serve_cached_pages``,
@@ -148,6 +155,14 @@ class ServingEngine:
                 "reservation — nothing could ever be admitted")
         enforce(s.prefill_chunk_tokens >= 0,
                 "prefill_chunk_tokens must be >= 0 (0 = chunking off)")
+        if cfg.block_len > 1 and s.incremental_prefill:
+            raise NotImplementedError(
+                "prefix_cache / prefill_chunk_tokens with block_len > 1: the "
+                "chunk path's attention is causal over tokens, not over "
+                "blocks (paged_prefill_attention), a chunk would have to end "
+                "on a block boundary, and a shared prefix page may hold the "
+                "prompt's tail, which is the first generated block's to "
+                "write; none of it is built")
         if cfg.state_layers and s.incremental_prefill:
             raise NotImplementedError(
                 "prefix_cache / prefill_chunk_tokens with recurrent-state "
@@ -183,7 +198,7 @@ class ServingEngine:
                 state_shapes=cfg.state_shapes if cfg.state_layers else None)
         self.cache.k, self.cache.v, self.cache.state = self.place(
             (self.cache.k, self.cache.v, self.cache.state))
-        self.scheduler = Scheduler(s, self.cache)
+        self.scheduler = Scheduler(s, self.cache, cfg.block_len)
         # 2·params is the standard per-token forward-FLOPs estimate —
         # what a prefix-cache hit's skipped recompute is booked at; a
         # looped stack passes its layer weights loop_steps times
@@ -224,6 +239,13 @@ class ServingEngine:
         if cfg.pattern is not None:
             self._loop_args.update(kv_heads=cfg.kv_heads,
                                    state_layers=cfg.state_layers)
+        self._block = cfg.block_len
+        if self._block > 1:
+            self._loop_args.update(block=self._block)
+            self.registry.gauge(
+                "serve_block_length",
+                "positions a sequence's block pass carries (generation by "
+                "diffusion over blocks)").set(self._block)
         # tokens one fetched block of the decode kernel covers
         from paddle_tpu.ops.pallas.paged_attention import decode_block_pages
         self._kv_block = s.page_size * decode_block_pages(
@@ -307,7 +329,8 @@ class ServingEngine:
         # donation and would warn every call
         donate = (2, 3) if on_tpu() else ()
         (self._prefill, self._prefill_chunk,
-         self._decode) = _serving_fns(cfg, attn_impl, donate)
+         self._decode) = _serving_fns(cfg, attn_impl, donate,
+                                      self.serving.unmask_policy)
 
     # -- public API -----------------------------------------------------------
     def check_request(self, prompt,
@@ -465,7 +488,15 @@ class ServingEngine:
         ``rows``, ``padded_tokens``, ``prompt_tokens``),
         and what is left over, its self time, is this loop's own Python:
         the small host-to-device transfers, the ``append_token`` loops,
-        histograms and gauges."""
+        histograms and gauges.
+
+        What a pass hands out: a prefill pass each admitted request's
+        first token and a decode step one token a live sequence — or,
+        under a model that generates by blocks (``block_len`` > 1), a
+        prefill pass nothing (it leaves the K/V of the prompt's whole
+        blocks) and a block pass (``_block_pass``) 0 to ``block_len``
+        tokens a sequence: all of a block's at once, when the pass that
+        found nothing masked has committed it."""
         from paddle_tpu.telemetry.tracing import get_tracer
 
         tracer = get_tracer()
@@ -489,6 +520,14 @@ class ServingEngine:
         batch = build(*args)
         (tracer.end if batch is not None else tracer.cancel)(tk)
         return batch
+
+    def _context_args(self, seq_lens) -> dict:
+        """What a decode pass's kernel had to read (every live row's
+        resident context) and what it fetched (the same in whole blocks
+        of the kernel's), as span args."""
+        return {"context_tokens": int(seq_lens.sum()),
+                "kv_block_tokens": int((-(-seq_lens // self._kv_block)).sum()
+                                       * self._kv_block)}
 
     def _make_ready(self) -> dict:
         """Compile every program this engine will dispatch, before the
@@ -562,6 +601,9 @@ class ServingEngine:
             rows, length = batch["ids"].shape
             fill = {"rows": rows, "padded_tokens": rows * length,
                     "prompt_tokens": int(batch["seq_lens"].sum())}
+            by_blocks = self._block > 1
+            if by_blocks:
+                fill["blocks_written"] = fill["prompt_tokens"] // self._block
             tk = tracer.begin("serve_prefill", cat="serving",
                               batch=len(admitted), **fill, **self._loop_args)
             cache = self.cache
@@ -585,13 +627,16 @@ class ServingEngine:
                                  "prefill pass wall ms (per admitted batch)")
             hist.observe((t1 - t0) * 1e3)
             # the first generated token of each request is sampled here
+            # (by blocks: none; the first block's commit hands out the first)
             reg.counter("serve_tokens", "tokens generated").inc(
-                len(admitted))
+                0 if by_blocks else len(admitted))
             for j, a in enumerate(admitted):
                 reg.histogram(
                     "serve_queue_wait_ms",
                     "request wait between arrival and admission").observe(
                         (a.t_admit - a.request.arrival) * 1e3)
+                if by_blocks:
+                    continue
                 a.t_first = t1
                 reg.histogram(
                     "serve_ttft_ms", "time to first token").observe(
@@ -604,7 +649,10 @@ class ServingEngine:
                 worked = True
 
         batch = self._scheduled(tracer, sched.decode_batch)
-        if batch is not None:
+        if batch is not None and self._block > 1:
+            self._block_pass(tracer, programs["decode"], batch)
+            worked = True
+        elif batch is not None:
             live = batch.pop("live")
             t0 = time.perf_counter()
             args = self._dev(batch, "ids", "positions", "seq_lens",
@@ -623,15 +671,10 @@ class ServingEngine:
                 if self.cfg.state_layers:
                     # rows of the state pools this step read and rewrote
                     counts["state_slots"] = len(live)
-                # what the step's kernel had to read (every live
-                # sequence's resident context) and what it fetched (the
-                # same in whole blocks), and how long the dispatch took
+                # what the step read, and how long the dispatch took
                 # before the wait for the device began
                 tracer.end(
-                    tk, context_tokens=int(batch["seq_lens"].sum()),
-                    kv_block_tokens=int(
-                        (-(-batch["seq_lens"] // self._kv_block)).sum()
-                        * self._kv_block),
+                    tk, **self._context_args(batch["seq_lens"]),
                     dispatch_ms=round((t_dispatched - tk.t_start) * 1e3, 3),
                     **counts)
             reg.histogram(
@@ -661,6 +704,85 @@ class ServingEngine:
                       "reclaimable once no sequence maps them)").set(
                           self.cache.prefix.cached_pages)
         return worked
+
+    def _block_pass(self, tracer, program, batch) -> None:
+        """One block pass over every live sequence's block in progress
+        (the decode step of a model that generates by blocks).  Out of
+        the device comes ONE int32 array: per row and position the token
+        the pass chose, whether the policy unmasked the position, the
+        confidence's float32 bits; behind them the routing counts.  A row
+        that went in with nothing masked is committed by this pass — its
+        K/V stand — and its tokens are handed to ``append_token`` one by
+        one, in position order, until the request has what it asked for
+        (the rest of the block is dropped)."""
+        sched, reg, bl = self.scheduler, self.registry, self._block
+        live = batch.pop("live")
+        t0 = time.perf_counter()
+        args = self._dev(batch, "ids", "positions", "seq_lens",
+                         "page_table", "rids", "gens", "temps")
+        tk = tracer.begin("serve_decode", cat="serving", batch=len(live),
+                          positions=len(live) * bl, **self._loop_args)
+        cache = self.cache
+        out, cache.k, cache.v, cache.state = program(
+            self.params, self._base_key, cache.k, cache.v, *args,
+            cache.state)
+        if tk is not None:
+            t_dispatched = tracer.clock()
+        n = self.serving.max_slots * bl
+        out, counts = self._split_counts(out, 3 * n, "decode")
+        toks, unmasked, conf = (out[:n].reshape(-1, bl),
+                                out[n:2 * n].reshape(-1, bl),
+                                out[2 * n:].view(np.float32).reshape(-1, bl))
+        masked_in = int(batch["ids"][:, bl:2 * bl].sum())
+        handed = dropped = commits = 0
+        for a in live:
+            i = a.slot
+            ready = sched.block_pass_done(a, toks[i], unmasked[i], conf[i])
+            if ready is None:
+                continue
+            commits += 1
+            if not a.generated:
+                a.t_first = time.perf_counter()
+                reg.histogram(
+                    "serve_ttft_ms", "time to first token").observe(
+                        (a.t_first - a.request.arrival) * 1e3)
+            for token in ready:
+                if a.finished:
+                    dropped += 1
+                else:
+                    sched.append_token(a, token)
+                    handed += 1
+        if tk is not None:
+            tracer.end(
+                tk, **self._context_args(batch["seq_lens"]),
+                dispatch_ms=round((t_dispatched - tk.t_start) * 1e3, 3),
+                masked_in=masked_in, unmasked=int(unmasked.sum()),
+                committed=commits, commit_rows=commits, tokens_out=handed,
+                **counts)
+        reg.histogram(
+            "serve_decode_step_ms",
+            "one continuous-batching decode step, wall ms").observe(
+                (time.perf_counter() - t0) * 1e3)
+        reg.counter("serve_tokens", "tokens generated").inc(handed)
+        reg.counter(
+            "serve_layer_passes_total",
+            "decoder blocks run by decode steps (batch x num_layers x "
+            "loop_steps a step)").inc(len(live) * bl * self.cfg.cache_layers)
+        passes = reg.counter(
+            "serve_block_passes_total",
+            "rows of block passes, by whether the row went in with masked "
+            "positions (denoise) or with none (commit)")
+        passes.inc(len(live) - commits, kind="denoise")
+        passes.inc(commits, kind="commit")
+        reg.counter("serve_blocks_committed_total",
+                    "blocks whose K/V a commit pass left in the cache").inc(
+                        commits)
+        reg.counter("serve_block_positions_total",
+                    "positions block passes computed (rows x block_len)").inc(
+                        len(live) * bl)
+        reg.counter("serve_tokens_dropped_total",
+                    "committed positions never handed out (a last block's "
+                    "surplus, what followed an eos)").inc(dropped)
 
     def _prefill_incremental(self, admitted, tracer, reg) -> bool:
         """The flag-on prefill path (prefix cache / chunked prefill):
@@ -806,7 +928,7 @@ class ServingEngine:
         self._completed.put(RequestResult(
             id=a.request.id, prompt=list(a.request.prompt),
             tokens=list(a.generated), finish_reason=a.finished,
-            metrics=rec))
+            metrics=rec, trail=a.trail))
 
     def emit_summary(self) -> None:
         """One ``serve_summary`` record with the latency histograms'
@@ -845,7 +967,7 @@ class ServingEngine:
         self.registry.emit(rec, kind="serve_summary")
 
 
-# (cfg, attn_impl, donate) -> (prefill, prefill_chunk, decode).  The
+# (cfg, attn_impl, donate, policy) -> (prefill, prefill_chunk, decode).  The
 # jitted serving closures are fully determined by this key — params,
 # caches and batches all arrive as arguments — so engines built on the
 # same config (every fleet replica, a restarted engine, a weight swap)
@@ -856,8 +978,11 @@ _FN_MEMO: dict = {}
 _FN_LOCK = threading.Lock()
 
 
-def _serving_fns(cfg, attn_impl, donate):
-    key = (cfg, attn_impl, donate)
+def _serving_fns(cfg, attn_impl, donate, policy="low_confidence_static"):
+    """``policy``: ``ServingConfig.unmask_policy``, compiled into the
+    decode program of a model that generates by blocks (no other program
+    reads it)."""
+    key = (cfg, attn_impl, donate, policy if cfg.block_len > 1 else None)
     with _FN_LOCK:
         fns = _FN_MEMO.get(key)
         if fns is not None:
@@ -870,14 +995,17 @@ def _serving_fns(cfg, attn_impl, donate):
     from paddle_tpu.ops.pallas import paged_attention as pa
     from paddle_tpu.serving import sampling
 
-    def sampled(logits, keys, temps, extras):
-        """The sampled tokens; behind them the routed layers' counts of
-        this pass (one small int32 array rides out, nothing else to
-        wait for)."""
-        toks = sampling.sample_tokens(logits, keys, temps)
+    def with_counts(toks, extras):
+        """What a pass hands the host; behind it the routed layers'
+        counts of this pass (one small int32 array rides out, nothing
+        else to wait for)."""
         counts = extras.get("moe_counts")
         return toks if counts is None else jnp.concatenate(
             [toks.astype(jnp.int32), counts])
+
+    def sampled(logits, keys, temps, extras):
+        return with_counts(sampling.sample_tokens(logits, keys, temps),
+                           extras)
 
     def unpack(out):
         """A forward's result -> (logits, k, v, extras): a config without
@@ -898,6 +1026,10 @@ def _serving_fns(cfg, attn_impl, donate):
                 pool = pool.at[i, slots].set(
                     extras["state"][name][i].astype(pool.dtype), mode="drop")
             state[name] = pool
+        if cfg.block_len > 1:
+            # nothing is sampled: the pass leaves K/V (the head is dead
+            # code here); the counts ride behind a row of zeros
+            return with_counts(jnp.zeros_like(rids), extras), kc, vc, state
         keys = sampling.request_keys(
             base_key, rids, jnp.zeros_like(rids))
         return sampled(logits, keys, temps, extras), kc, vc, state
@@ -910,6 +1042,30 @@ def _serving_fns(cfg, attn_impl, donate):
         keys = sampling.request_keys(base_key, rids, gens)
         return (sampled(logits, keys, temps, extras), kc, vc,
                 extras.get("state", {}))
+
+    def decode_block(params, base_key, kc, vc, ids, positions, lens, table,
+                     rids, gens, temps, state=None):
+        """The block pass (``Scheduler.decode_arrays`` under a block
+        length: ``ids`` = tokens | masked flags | how many to unmask).
+        Out: per position the chosen token, whether the policy unmasked
+        it, its confidence's float32 bits; then the routing counts."""
+        bl = cfg.block_len
+        masked = ids[:, bl:2 * bl] > 0
+        logits, kc, vc, extras = T.forward_decode_block(
+            cfg, params, ids[:, :bl], masked, positions, lens, table, kc,
+            vc, attn_impl=attn_impl)
+        toks, conf = sampling.sample_block(
+            logits, base_key, rids, gens, temps)
+        chosen = sampling.choose_unmask(policy, masked, conf, ids[:, 2 * bl])
+        out = jnp.concatenate([
+            toks.reshape(-1), chosen.astype(jnp.int32).reshape(-1),
+            jax.lax.bitcast_convert_type(conf, jnp.int32).reshape(-1)])
+        return with_counts(out, extras), kc, vc, {}
+
+    if cfg.block_len > 1:
+        # under the same name: the device trace's module stays jit_decode
+        decode_block.__name__ = "decode"
+        decode = decode_block
 
     def prefill_chunk(params, base_key, kc, vc, ids, starts, lens,
                       table, rids, temps):

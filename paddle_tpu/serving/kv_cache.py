@@ -282,6 +282,15 @@ class PagedKVCache:
     trie rides along and ``assign_with_prefix`` maps cached prefixes
     into new rows instead of recomputing them.
 
+    Under a model that generates by blocks (``block_len`` > 1, a
+    divisor of ``page_size``: a block never straddles a page) nothing
+    here changes: a request's reservation — prompt + max_new_tokens, in
+    whole pages — already holds its last block whole, so the pages of
+    the block in progress are assigned before its first pass.  Every
+    pass rewrites that block's rows; they stand only once a pass found
+    nothing masked (the scheduler's ``_Block`` knows; the cache does
+    not).
+
     ``num_heads`` is the heads the CACHE holds (a model's K/V heads).
     ``state``: {part: [state_layers, max_slots, *shape]} float32, one row
     per batch slot for every layer that keeps a recurrent state

@@ -32,3 +32,53 @@ def sample_tokens(logits, keys, temperatures):
         lambda k, l: jax.random.categorical(k, l)
     )(keys, logits.astype(jnp.float32) / temps).astype(jnp.int32)
     return jnp.where(temperatures > 0, drawn, greedy)
+
+
+def sample_block(logits, base_key, request_ids, first_index, temperatures):
+    """A block pass's choice at every position: logits [B, T, V] (float32),
+    request_ids / first_index / temperatures [B] -> (tokens [B, T] int32,
+    confidence [B, T] float32).  Position ``t`` of row ``b`` is greedy at
+    ``temperature <= 0``, else drawn under the key of index
+    ``first_index[b] + t`` of its request; its confidence is the softmax
+    probability of the token chosen, at temperature 1.  When every row
+    is greedy nothing is drawn."""
+    import jax
+    import jax.numpy as jnp
+
+    b, t, v = logits.shape
+    flat = logits.reshape(b * t, v)
+    temps = jnp.repeat(temperatures, t)
+
+    def drawn():
+        keys = request_keys(
+            base_key, jnp.repeat(request_ids, t),
+            (first_index[:, None] + jnp.arange(t)[None, :]).reshape(-1))
+        return sample_tokens(flat, keys, temps)
+
+    toks = jax.lax.cond(
+        jnp.any(temperatures > 0), drawn,
+        lambda: jnp.argmax(flat, axis=-1).astype(jnp.int32))
+    chosen = jnp.take_along_axis(flat, toks[:, None], axis=-1)[:, 0]
+    conf = jnp.exp(chosen - jax.nn.logsumexp(flat, axis=-1))
+    return toks.reshape(b, t), conf.reshape(b, t)
+
+
+def choose_unmask(policy, masked, confidence, count):
+    """Which masked positions a denoising pass unmasks: masked [B, T]
+    bool, confidence [B, T], count [B] how many -> [B, T] bool.
+    "low_confidence_static": the ``count`` most confident masked positions
+    (ties: the leftmost); "sequential": the ``count`` leftmost."""
+    import jax.numpy as jnp
+
+    t = masked.shape[1]
+    if policy == "sequential":
+        rank = jnp.cumsum(masked, axis=1) - 1
+    elif policy == "low_confidence_static":
+        score = jnp.where(masked, confidence, -1.0)
+        before = jnp.arange(t)[:, None] < jnp.arange(t)[None, :]   # [u, t]
+        ahead = (score[:, :, None] > score[:, None, :]) | (
+            (score[:, :, None] == score[:, None, :]) & before)
+        rank = jnp.sum(ahead, axis=1)
+    else:
+        raise ValueError(f"unknown unmask policy {policy!r}")
+    return masked & (rank < count[:, None])

@@ -60,6 +60,16 @@ class ServingConfig:
     # step, interleaved with decode steps, so a long prompt stops
     # stalling the decode batch's TTFT; 0 = whole prompt in one pass
     prefill_chunk_tokens: int = 0
+    # -- generation by diffusion over blocks (a model with ``block_len`` >
+    #    1; read by nothing else) --
+    # denoising passes a block gets at most before the pass that commits
+    # it: quality traded against passes (1 = the whole block from one pass)
+    denoise_steps: int = 2
+    # which masked positions a denoising pass unmasks:
+    # "low_confidence_static" — the ceil(masked / steps left) whose chosen
+    # token has the highest softmax probability; "sequential" — as many,
+    # left to right.  An unmasked position is never masked again
+    unmask_policy: str = "low_confidence_static"
 
     @property
     def max_pages_per_seq(self) -> int:
@@ -113,6 +123,31 @@ class RequestResult:
     tokens: list[int]            # generated tokens (incl. eos if hit)
     finish_reason: str           # "length" | "eos"
     metrics: dict = dataclasses.field(default_factory=dict)
+    # generation by blocks only: every generated POSITION of the blocks
+    # the request committed, in position order — ``tokens`` (the handed-
+    # out ones first; a last block's surplus and what followed an eos are
+    # only here), ``steps`` (the denoising pass of its block, from 0, that
+    # unmasked each) and ``confidence`` (the softmax probability its token
+    # had in that pass): the order a block came out in
+    trail: dict | None = None
+
+
+@dataclasses.dataclass
+class _Block:
+    """The block a sequence is generating: ``start`` its first absolute
+    position; per position the token (0 while masked), the denoising pass
+    that unmasked it (-1: known from the prompt, None: still masked) and
+    the confidence it had then; ``passes`` = denoising passes run."""
+
+    start: int
+    ids: list
+    steps: list
+    conf: list
+    passes: int = 0
+
+    @property
+    def masked(self) -> int:
+        return sum(s is None for s in self.steps)
 
 
 @dataclasses.dataclass
@@ -129,6 +164,12 @@ class _Active:
     cached_tokens: int = 0       # prompt tokens mapped from the prefix cache
     prefilled: int = 0           # prompt tokens whose K/V are resident
     prefill_chunks: int = 0      # incremental prefill passes run
+    # generation by blocks: the block in progress, block passes run for
+    # this request, and the trail its committed blocks leave
+    # (``RequestResult.trail``)
+    block: _Block | None = None
+    block_passes: int = 0
+    trail: dict | None = None
 
     @property
     def prompt_len(self) -> int:
@@ -142,11 +183,25 @@ class _Active:
 
 
 class Scheduler:
-    def __init__(self, serving: ServingConfig, cache: PagedKVCache):
+    def __init__(self, serving: ServingConfig, cache: PagedKVCache,
+                 block_len: int = 1):
+        """``block_len`` > 1: the model generates a block of positions a
+        pass (``TransformerConfig.block_len``); every admitted sequence
+        then carries a ``_Block`` and ``decode_arrays`` builds block
+        passes."""
         enforce(cache.page_table.shape[0] >= serving.max_slots,
                 "cache has fewer slot rows than max_slots")
+        enforce(block_len == 1 or serving.page_size % block_len == 0,
+                f"page_size {serving.page_size} is not a multiple of the "
+                f"model's block_len {block_len}: a block would straddle "
+                "two pages")
+        enforce(serving.unmask_policy in ("low_confidence_static",
+                                          "sequential"),
+                f"unknown unmask_policy {serving.unmask_policy!r}")
+        enforce(serving.denoise_steps >= 1, "denoise_steps must be >= 1")
         self.serving = serving
         self.cache = cache
+        self.block_len = block_len
         self.queue: collections.deque[Request] = collections.deque()
         self.slots: list[_Active | None] = [None] * serving.max_slots
         self.rejected_admissions = 0  # out-of-pages/budget head blocks
@@ -237,6 +292,12 @@ class Scheduler:
             a = _Active(request=req, slot=slot, reserved_tokens=reserve,
                         t_admit=now, cached_tokens=covered,
                         prefilled=covered)
+            if self.block_len > 1:
+                # the prompt's whole blocks are prefilled; its tail opens
+                # the first generated block as known positions
+                a.trail = {"tokens": [], "steps": [], "confidence": []}
+                self._open_block(a, len(req.prompt) // self.block_len
+                                 * self.block_len)
             self.slots[slot] = a
             admitted.append(a)
         return admitted
@@ -249,6 +310,49 @@ class Scheduler:
             a.finished = "eos"
         elif len(a.generated) >= a.request.max_new_tokens:
             a.finished = "length"
+
+    # -- generation by blocks --------------------------------------------------
+    def _open_block(self, a: _Active, start: int) -> None:
+        """``a`` starts the block at ``start``: prompt tokens inside it
+        are known, the rest masked."""
+        known = a.request.prompt[start:start + self.block_len]
+        rest = self.block_len - len(known)
+        a.block = _Block(start=start, ids=list(known) + [0] * rest,
+                         steps=[-1] * len(known) + [None] * rest,
+                         conf=[1.0] * len(known) + [0.0] * rest)
+
+    def unmask_count(self, blk: _Block) -> int:
+        """Positions the next pass over ``blk`` unmasks: its masked ones
+        spread evenly over the denoising passes left (0: nothing is
+        masked, the pass commits the block)."""
+        left = max(self.serving.denoise_steps - blk.passes, 1)
+        return -(-blk.masked // left)
+
+    def block_pass_done(self, a: _Active, tokens, unmasked, conf) -> list | None:
+        """Book one block pass's result for ``a``: ``tokens`` / ``conf``
+        [block_len] what the pass chose at every position and how sure it
+        was, ``unmasked`` [block_len] the positions its policy unmasked.
+        A pass that found nothing masked has left the block's K/V in the
+        cache: the block is committed, its generated positions join the
+        trail, the next block opens, and the tokens to hand out come
+        back, in position order (None: a denoising pass)."""
+        blk = a.block
+        a.block_passes += 1
+        if blk.masked:
+            for t in range(self.block_len):
+                if unmasked[t]:
+                    enforce(blk.steps[t] is None,
+                            "a block pass unmasked a known position")
+                    blk.ids[t], blk.steps[t] = int(tokens[t]), blk.passes
+                    blk.conf[t] = float(conf[t])
+            blk.passes += 1
+            return None
+        first = max(a.prompt_len - blk.start, 0)   # the prompt's tail
+        for name, vals in (("tokens", blk.ids), ("steps", blk.steps),
+                           ("confidence", blk.conf)):
+            a.trail[name].extend(vals[first:])
+        self._open_block(a, blk.start + self.block_len)
+        return blk.ids[first:]
 
     def retire_finished(self) -> list[_Active]:
         """Free the pages + slots of finished sequences; returns them."""
@@ -264,7 +368,7 @@ class Scheduler:
         sequences, or None when there are none.  Sequences still
         mid-prefill (incremental path: no token sampled yet) are not
         decoded."""
-        live = [a for a in self.live if a.generated]
+        live = [a for a in self.live if a.generated or a.block is not None]
         return self.decode_arrays(live) if live else None
 
     def decode_arrays(self, live: list[_Active]) -> dict:
@@ -272,7 +376,18 @@ class Scheduler:
         slot rides along masked (seq_len 0, null-page table row) so the
         jitted step has a single compile signature — with no sequence at
         all it is the batch the engine compiles the decode program for
-        (``ServingEngine._make_ready``)."""
+        (``ServingEngine._make_ready``).
+
+        Under a block length ``B`` > 1 a row is its sequence's block in
+        progress, under the same names: ``ids`` [slots, 2B + 1] = the
+        block's tokens | its masked flags | how many masked positions
+        this pass unmasks (0: the pass commits the block); ``positions``
+        the block's first position; ``seq_lens`` the context the block's
+        positions read, the block itself included (start + B); ``gens``
+        the index of the row's first position in its request's sampling
+        keys (block passes run so far x B)."""
+        if self.block_len > 1:
+            return self._block_arrays(live)
         n = self.serving.max_slots
         ids = np.zeros((n,), np.int32)
         positions = np.zeros((n,), np.int32)
@@ -305,6 +420,32 @@ class Scheduler:
             "rids": rids, "gens": gens, "temps": temps, "live": live,
         }
 
+    def _block_arrays(self, live: list[_Active]) -> dict:
+        n, bl = self.serving.max_slots, self.block_len
+        ids = np.zeros((n, 2 * bl + 1), np.int32)
+        positions = np.zeros((n,), np.int32)
+        seq_lens = np.zeros((n,), np.int32)
+        rids = np.zeros((n,), np.int32)
+        gens = np.zeros((n,), np.int32)
+        temps = np.zeros((n,), np.float32)
+        table = np.zeros_like(self.cache.page_table)
+        for a in live:
+            i, blk = a.slot, a.block
+            ids[i, :bl] = blk.ids
+            ids[i, bl:2 * bl] = [s is None for s in blk.steps]
+            ids[i, 2 * bl] = self.unmask_count(blk)
+            positions[i] = blk.start
+            seq_lens[i] = blk.start + bl
+            rids[i] = a.request.id
+            gens[i] = a.block_passes * bl
+            temps[i] = a.request.temperature
+            table[i] = self.cache.page_table[i]
+        return {
+            "ids": ids, "positions": positions, "seq_lens": seq_lens,
+            "page_table": table,
+            "rids": rids, "gens": gens, "temps": temps, "live": live,
+        }
+
     def prefill_batch(self, admitted: list[_Active]) -> dict:
         """Arrays for one prefill pass over newly admitted sequences, at
         the fewest rows of ``prefill_rows`` that hold them, not at
@@ -331,7 +472,9 @@ class Scheduler:
         slots = np.full((nb,), s.max_slots, np.int32)
         for j, a in enumerate(admitted):
             ids[j, :a.prompt_len] = a.request.prompt
-            lens[j] = a.prompt_len
+            # under a block length only the prompt's whole blocks are
+            # prefilled (its tail is the first generated block's)
+            lens[j] = a.prompt_len // self.block_len * self.block_len
             table[j] = self.cache.page_table[a.slot]
             rids[j] = a.request.id
             temps[j] = a.request.temperature

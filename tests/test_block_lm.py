@@ -1,0 +1,553 @@
+"""Generation by diffusion over blocks through ``ServingEngine`` — a
+decode pass that carries a block of positions a sequence — against the
+plain float32 reference ``benchmarks/references/sdar.py`` on seeded random
+weights at a toy size (2 layers of attention + 8 softmax-routed SwiGLU
+experts top-2, per-head q/k norms, rotary, block length 4): the engine's
+order of unmasking, tokens and confidences against the reference's plain
+generation loop, for both policies and 1 / 2 / 4 denoising passes; the
+``[noisy ; clean]`` layout against the block pass over the paged cache;
+prompts whose tail opens the first block; a last block's surplus dropped;
+one slot and many; a slot reused; what the counters and spans say of a
+pass; the kernels' masks; the routed layer's scores, gate and shares.
+
+Tolerances.  Program and reference both compute in float32 here (the CPU
+backend's dots are exact float32), so they differ by the order of
+summation only: logits of unit scale agree to ``TOL`` = 2e-4 and a
+confidence (a probability) to 1e-5; with margins that wide apart the
+order of unmasking and the tokens are equal.  bfloat16 in the program's
+place flips one served token in seventy and int8 weights five times as
+many, with wider gaps (``test_bf16_passes_and_int8_weights_fail``).
+"""
+
+import dataclasses
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.models import transformer as T
+from paddle_tpu.ops import attention as attn_ops
+import paddle_tpu.ops.pallas.flash_attention  # noqa: F401 (the module)
+from paddle_tpu.ops.pallas import paged_attention as PA
+from paddle_tpu.parallel import moe
+from paddle_tpu.serving import ServingConfig, ServingEngine, sampling
+from paddle_tpu.serving.kv_cache import PagedKVCache
+from paddle_tpu.serving.scheduler import Request, Scheduler
+from paddle_tpu.telemetry import MetricsRegistry
+from paddle_tpu.telemetry import tracing as tracing_mod
+
+import sys  # noqa: E402
+
+# the package re-exports the function under the module's name
+FA = sys.modules["paddle_tpu.ops.pallas.flash_attention"]
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TOL = 2e-4
+BL = 4
+M = dict(vocab_size=97, num_layers=4, num_heads=4, kv_heads=2, head_dim=8,
+         embed_dim=32, mlp_dim=16, max_seq_len=128, norm="rms",
+         norm_eps=1e-6, positions="rotary", rope_theta=1e6, qk_norm=True,
+         mlp="swiglu", tie_embeddings=False, pattern="*E*E", moe_experts=8,
+         moe_router="softmax_topk", moe_top_k=2, block_len=BL, mask_id=96)
+LENS = (5, 8, 3, 14, 1, 6, 7)       # L mod 4 = 1, 0, 3, 2, 1, 2, 3
+SERVING = dict(max_slots=3, page_size=8, num_pages=40, max_prompt_len=24,
+               max_new_tokens=16, prefill_batch=2)
+
+
+def block_cfg(**kw):
+    return T.TransformerConfig(**{**M, "remat": False, **kw})
+
+
+@pytest.fixture(scope="module")
+def ref():
+    spec = importlib.util.spec_from_file_location(
+        "sdar_reference",
+        os.path.join(REPO, "benchmarks", "references", "sdar.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def weights(ref):
+    return ref.init_weights(M, 7, jnp.float32)
+
+
+@pytest.fixture(scope="module")
+def params(ref, weights):
+    return ref.program_tree(weights)
+
+
+@pytest.fixture(scope="module")
+def prompts():
+    rng = np.random.default_rng(0)
+    return [rng.integers(0, 96, size=n).tolist() for n in LENS]
+
+
+def engine(params, cfg=None, registry=None, **serving):
+    return ServingEngine(cfg or block_cfg(), params,
+                         ServingConfig(**{**SERVING, **serving}),
+                         registry=registry or MetricsRegistry("block_lm"))
+
+
+# -- the engine against the reference's generation loop --------------------------
+
+
+@pytest.mark.parametrize("policy", ["low_confidence_static", "sequential"])
+@pytest.mark.parametrize("steps", [1, 2, 4])
+def test_engine_equals_the_reference(steps, policy, ref, weights, params,
+                                     prompts):
+    """Seven requests through three slots (they join mid-flight), 10 new
+    tokens each — not a multiple of the block length, prompts with every
+    ``L mod B``: the same order of unmasking, the same tokens at every
+    generated position (the dropped surplus included), confidences to
+    1e-5, and exactly the asked number handed out."""
+    eng = engine(params, denoise_steps=steps, unmask_policy=policy)
+    for r in eng.generate(prompts, max_new_tokens=10):
+        want = ref.generate(weights, M, r.prompt, 10, steps, policy)
+        total = -(-(len(r.prompt) + 10) // BL) * BL - len(r.prompt)
+        assert len(r.trail["tokens"]) == total == len(want["tokens"])
+        assert r.trail["steps"] == want["steps"]
+        assert r.trail["tokens"] == want["tokens"]
+        assert r.tokens == want["tokens"][:10] and r.finish_reason == "length"
+        np.testing.assert_allclose(r.trail["confidence"], want["confidence"],
+                                   atol=1e-5)
+        assert max(r.trail["steps"]) <= steps - 1
+
+
+def test_block_pass_logits_equal_the_noisy_clean_layout(ref, weights, params):
+    """A block pass over the paged cache, its earlier blocks prefilled
+    under the block-causal mask, against ONE forward over ``[noisy ;
+    clean]``: the logits of the block in progress, at every position,
+    with two of them masked."""
+    cfg = block_cfg()
+    rng = np.random.default_rng(3)
+    seq = rng.integers(0, 96, size=16).tolist()
+    start = 12
+    _, ks, vs, _ = T.forward_prefill(cfg, params, jnp.asarray([seq]),
+                                     jnp.asarray([start]))
+    kc, vc = PA.init_kv_pages(2, 2, 8, 8, 8)
+    table = jnp.asarray([[1, 2, 3, 0]], jnp.int32)
+    kc, vc = PA.write_prefill_kv(kc, vc, ks, vs, table, jnp.asarray([start]))
+    masked = jnp.asarray([[False, True, False, True]])
+    got, *_ = T.forward_decode_block(
+        cfg, params, jnp.asarray([seq[start:]]), masked, jnp.asarray([start]),
+        jnp.asarray([start + BL]), table, kc, vc, attn_impl="reference")
+    clean = jnp.asarray(seq)
+    noisy = clean.at[jnp.asarray([13, 15])].set(M["mask_id"])
+    with jax.default_matmul_precision("highest"):
+        want = ref.denoise_logits(weights, noisy, clean, M)[start:]
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want),
+                               atol=TOL, rtol=TOL)
+
+
+def test_forward_equals_the_reference_under_the_block_causal_mask(
+        ref, weights, params):
+    seq = np.random.default_rng(4).integers(0, 96, size=22).tolist()
+    got = T.forward(block_cfg(), params, jnp.asarray([seq]))[0]
+    with jax.default_matmul_precision("highest"):
+        want = ref.logits_fn(weights, jnp.asarray(seq), M)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=TOL,
+                               rtol=TOL)
+    # and it is not the token-causal forward: position 0 sees position 3
+    causal = T.forward(block_cfg(block_len=1, mask_id=None), params,
+                       jnp.asarray([seq]))[0]
+    assert float(jnp.max(jnp.abs(causal[0] - got[0]))) > 1e-2
+
+
+def test_one_slot_and_a_slot_reused(ref, weights, params, prompts):
+    """One slot: the requests follow each other through the same slot
+    and the same pages; what the one before left there changes nothing."""
+    eng = engine(params, max_slots=1, prefill_batch=1, num_pages=6)
+    res = eng.generate(prompts[:4], max_new_tokens=7)
+    for r in res:
+        want = ref.generate(weights, M, r.prompt, 7, 2)
+        assert (r.tokens, r.trail["steps"]) == (want["tokens"][:7],
+                                                want["steps"])
+    assert eng.cache.allocator.free_pages == 5
+
+
+def test_served_gaps_reads_the_step_that_unmasked(ref, weights, params,
+                                                  prompts):
+    """The comparison the benchmark makes: in float32 every served token
+    is the reference's best at the step that unmasked it (gap 0); with
+    the order of unmasking shifted by one pass the same tokens are held
+    against another state of their block and no longer are."""
+    res = engine(params).generate(prompts, max_new_tokens=10)
+    reqs = [(r.prompt, r.tokens, r.trail) for r in res]
+    gaps = ref.served_gaps(M, weights, reqs, 40)
+    assert len(gaps["served"]) == 10 * len(prompts)
+    assert ref.summarise(gaps["served"])["widest"] < TOL
+    other = [(p, t, dict(tr, steps=[1 - s for s in tr["steps"]]))
+             for p, t, tr in reqs]
+    assert ref.summarise(ref.served_gaps(M, weights, other, 40)
+                         ["served"])["widest"] > 0.05
+    with pytest.raises(ValueError, match="does not close the blocks"):
+        ref.served_gaps(M, weights, [(reqs[0][0], reqs[0][1], dict(
+            reqs[0][2], tokens=reqs[0][2]["tokens"][:-1]))], 40)
+
+
+def test_bf16_passes_and_int8_weights_fail(ref, weights, params, prompts):
+    """The same comparison with the program in the stated precision and
+    in the one below it: bfloat16 weights and arithmetic stay inside
+    limits that an engine serving int8-rounded weights misses."""
+    low = lambda tree, f: jax.tree.map(f, tree)
+    bf16 = low(params, lambda a: a.astype(jnp.bfloat16))
+    int8 = low(params, lambda a: ref._int8(a, 0).astype(jnp.bfloat16)
+               if a.ndim >= 2 else a.astype(jnp.bfloat16))
+    mean = {}
+    for name, tree in (("bf16", bf16), ("int8", int8)):
+        res = engine(tree, block_cfg(dtype=jnp.bfloat16)).generate(
+            prompts, max_new_tokens=10)
+        gaps = ref.served_gaps(M, weights, [(r.prompt, r.tokens, r.trail)
+                                            for r in res], 40)
+        mean[name] = ref.summarise(gaps["served"])["mean"]
+    # my CPU readings: 2.1e-6 and 4.1e-4 (a flipped token's gap, averaged
+    # over 70 served tokens; the reference's median margin is 0.24)
+    assert mean["bf16"] < 5e-5 < mean["int8"]
+
+
+def test_eos_inside_a_block_ends_the_request_there(params, prompts):
+    base = engine(params).generate(prompts[:1], max_new_tokens=10)[0]
+    eos = base.tokens[1]
+    r = engine(params, eos_id=eos).generate(prompts[:1], max_new_tokens=10)[0]
+    cut = base.tokens.index(eos) + 1
+    assert r.tokens == base.tokens[:cut] and r.finish_reason == "eos"
+    # the block was committed whole: its other positions are in the trail
+    assert len(r.trail["tokens"]) % BL == (-len(r.prompt)) % BL
+    assert len(r.trail["tokens"]) >= cut
+
+
+def test_temperature_draws_are_a_function_of_the_seed(params, prompts):
+    draw = lambda seed: [r.tokens for r in engine(params, seed=seed).generate(
+        prompts[:3], max_new_tokens=9, temperature=1.5)]
+    a, b, c = draw(1), draw(1), draw(2)
+    greedy = [r.tokens for r in engine(params).generate(
+        prompts[:3], max_new_tokens=9)]
+    assert a == b and a != c and a != greedy
+
+
+# -- what a pass counts ----------------------------------------------------------
+
+
+def test_counters_and_spans_count_what_a_block_pass_does(params, prompts):
+    reg = MetricsRegistry("block_counts")
+    tracing_mod.configure_tracing(enabled=True)
+    tracer = tracing_mod.get_tracer()
+    tracer.clear()
+    try:
+        eng = engine(params, registry=reg)
+        res = eng.generate(prompts, max_new_tokens=10)
+        spans = [s for s in tracer.spans if s.name in ("serve_decode",
+                                                       "serve_prefill")]
+    finally:
+        tracing_mod.configure_tracing(enabled=False)
+        tracer.clear()
+    val = lambda name, **lab: reg.get(name).value(**lab)
+    blocks = sum(-(-(n + 10) // BL) - n // BL for n in LENS)
+    positions = sum(len(r.trail["tokens"]) for r in res)
+    assert val("serve_tokens") == 10 * len(LENS)
+    assert val("serve_blocks_committed_total") == blocks
+    assert val("serve_block_passes_total", kind="commit") == blocks
+    assert val("serve_tokens_dropped_total") == positions - 10 * len(LENS)
+    # a first block with one masked position takes one denoising pass
+    denoise = sum(2 * (-(-(n + 10) // BL) - n // BL) - (n % BL == 3)
+                  for n in LENS)
+    assert val("serve_block_passes_total", kind="denoise") == denoise
+    rows = blocks + denoise
+    assert val("serve_block_positions_total") == rows * BL
+    assert val("serve_layer_passes_total") == rows * BL * 2
+    assert reg.get("serve_block_length").value() == BL
+    dec = [s.args for s in spans if s.name == "serve_decode"]
+    assert sum(a["batch"] for a in dec) == rows
+    assert sum(a["committed"] for a in dec) == blocks
+    assert sum(a["tokens_out"] for a in dec) == 10 * len(LENS)
+    assert sum(a["unmasked"] for a in dec) == positions
+    assert sum(a["masked_in"] for a in dec) >= positions
+    assert all(a["block"] == BL and a["positions"] == a["batch"] * BL
+               and a["context_tokens"] >= a["batch"] * BL
+               and a["moe_assignments"] == a["positions"] * 2 * 2
+               for a in dec)
+    pre = [s.args for s in spans if s.name == "serve_prefill"]
+    assert sum(a["blocks_written"] for a in pre) == sum(n // BL for n in LENS)
+    assert sum(a["prompt_tokens"] for a in pre) == sum(
+        n // BL * BL for n in LENS)
+
+
+@pytest.mark.parametrize("steps,want", [(1, [4]), (2, [2, 2]), (3, [2, 1, 1]),
+                                        (4, [1, 1, 1, 1]), (6, [1, 1, 1, 1])])
+def test_unmask_count_spreads_the_block_over_the_passes(steps, want):
+    s = ServingConfig(**SERVING, denoise_steps=steps)
+    cache = PagedKVCache(1, 1, 8, s.num_pages, s.page_size, s.max_slots,
+                         s.max_pages_per_seq)
+    sched = Scheduler(s, cache, block_len=BL)
+    sched.enqueue(Request(id=0, prompt=list(range(8)), max_new_tokens=4))
+    a, = sched.admit()
+    got = []
+    while a.block.masked:
+        n = sched.unmask_count(a.block)
+        got.append(n)
+        arrays = sched.decode_arrays([a])
+        assert arrays["ids"].shape == (s.max_slots, 2 * BL + 1)
+        assert arrays["ids"][0, 2 * BL] == n
+        assert arrays["ids"][0, BL:2 * BL].sum() == a.block.masked
+        assert (arrays["positions"][0], arrays["seq_lens"][0]) == (8, 12)
+        free = [t for t in range(BL) if a.block.steps[t] is None][:n]
+        assert sched.block_pass_done(
+            a, [7] * BL, [t in free for t in range(BL)], [0.5] * BL) is None
+    assert got == want
+    assert sched.unmask_count(a.block) == 0
+    assert sched.block_pass_done(a, [0] * BL, [False] * BL,
+                                 [0.0] * BL) == [7] * BL
+    assert a.block.start == 12 and a.block.masked == BL
+    assert a.block_passes == len(want) + 1
+
+
+@pytest.mark.parametrize("policy,want", [
+    ("low_confidence_static", [[False, True, False, True],
+                               [True, False, False, False]]),
+    ("sequential", [[True, True, False, False],
+                    [True, False, False, False]])])
+def test_choose_unmask(policy, want):
+    masked = jnp.asarray([[True, True, True, True],
+                          [True, False, True, True]])
+    # row 1: positions 0, 2 and 3 tie: the leftmost goes first
+    conf = jnp.asarray([[0.1, 0.9, 0.2, 0.5], [0.3, 0.99, 0.3, 0.3]])
+    got = sampling.choose_unmask(policy, masked, conf, jnp.asarray([2, 1]))
+    assert np.asarray(got).tolist() == want
+    none = sampling.choose_unmask(policy, masked, conf, jnp.asarray([0, 0]))
+    assert not np.asarray(none).any()
+
+
+# -- what is refused by name ------------------------------------------------------
+
+
+@pytest.mark.parametrize("serving,match", [
+    (dict(prefix_cache=True), "prefix_cache / prefill_chunk_tokens"),
+    (dict(prefill_chunk_tokens=8), "prefix_cache / prefill_chunk_tokens"),
+    (dict(page_size=6, num_pages=60), "not a multiple of the model's "
+                                      "block_len"),
+    (dict(unmask_policy="threshold"), "unknown unmask_policy"),
+    (dict(denoise_steps=0), "denoise_steps must be >= 1")])
+def test_the_engine_refuses_what_is_not_built(serving, match, params):
+    with pytest.raises((NotImplementedError, Exception), match=match):
+        engine(params, **serving)
+
+
+@pytest.mark.parametrize("fields,match", [
+    (dict(mask_id=None), "needs a mask_id"),
+    (dict(mask_id=97), "needs a mask_id"),
+    (dict(block_len=0), "block_len must be >= 1"),
+    (dict(attn_impl="blockwise"), "only 'exact' and 'flash'"),
+    (dict(pattern=None, num_layers=2, moe_experts=0, loop_steps=2),
+     "loop_steps > 1 or Mamba layers"),
+    (dict(moe_shared_dim=8), "shared expert beside gated"),
+    (dict(moe_router="softmax"), "dropless moe_router")])
+def test_the_config_refuses_what_is_not_built(fields, match):
+    with pytest.raises((ValueError, NotImplementedError), match=match):
+        block_cfg(**fields)
+
+
+def test_loss_is_refused_under_a_block_length(params):
+    with pytest.raises(NotImplementedError, match="diffusion objective"):
+        T.loss_fn(block_cfg(), params, jnp.zeros((1, 9), jnp.int32))
+
+
+def test_a_dense_stack_generates_by_blocks_too():
+    """No layer pattern: the scanned stack of (attention, MLP) blocks
+    runs the same block pass.  Over a block with nothing masked its
+    logits are the block-causal forward's at those positions, and the
+    engine serves it."""
+    cfg = T.TransformerConfig(
+        vocab_size=61, num_layers=2, num_heads=2, embed_dim=16, mlp_dim=32,
+        max_seq_len=64, block_len=BL, mask_id=60, remat=False)
+    params = T.init_params(cfg, jax.random.key(1))
+    seq = list(range(1, 13))
+    _, ks, vs = T.forward_prefill(cfg, params, jnp.asarray([seq]),
+                                  jnp.asarray([8]))
+    kc, vc = PA.init_kv_pages(2, 2, 8, 8, 8)
+    table = jnp.asarray([[1, 2, 0]], jnp.int32)
+    kc, vc = PA.write_prefill_kv(kc, vc, ks, vs, table, jnp.asarray([8]))
+    got, *_ = T.forward_decode_block(
+        cfg, params, jnp.asarray([seq[8:]]), jnp.zeros((1, BL), bool),
+        jnp.asarray([8]), jnp.asarray([12]), table, kc, vc,
+        attn_impl="reference")
+    want = T.forward(cfg, params, jnp.asarray([seq]))[0, 8:]
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want),
+                               atol=TOL, rtol=TOL)
+    eng = ServingEngine(cfg, params, ServingConfig(**SERVING),
+                        registry=MetricsRegistry("dense_block"))
+    r, = eng.generate([seq[:9]], max_new_tokens=6)
+    assert len(r.tokens) == 6 and len(r.trail["tokens"]) == 7
+
+
+# -- the kernels' masks -----------------------------------------------------------
+
+
+@pytest.mark.parametrize("t,bq", [(24, 8), (40, 16), (12, 1024)])
+def test_flash_forward_under_the_block_causal_mask(t, bq):
+    """Interpret mode, several tiles and one: the mask at block
+    granularity against the jnp mask, tiles above the block diagonal
+    skipped; and ``causal=1`` is ``causal=True`` bit for bit."""
+    k1, k2, k3 = jax.random.split(jax.random.key(t), 3)
+    q, k, v = (jax.random.normal(kk, (2, t, 2, 8)) for kk in (k1, k2, k3))
+    got = FA.flash_attention(q, k, v, BL, None, bq, bq, True)
+    blk = jnp.arange(t) // BL
+    want = attn_ops.dot_product_attention(
+        q, k, v, mask=(blk[:, None] >= blk[None, :])[None, None])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(FA.flash_attention_reference(q, k, v, BL)),
+        np.asarray(want), atol=2e-5)
+    one = FA.flash_attention(q, k, v, 1, None, bq, bq, True)
+    true = FA.flash_attention(q, k, v, True, None, bq, bq, True)
+    assert np.array_equal(np.asarray(one), np.asarray(true))
+    assert float(jnp.max(jnp.abs(true - got))) > 1e-3
+
+
+def test_flash_backward_under_the_block_causal_mask():
+    k1, k2, k3 = jax.random.split(jax.random.key(9), 3)
+    q, k, v = (jax.random.normal(kk, (1, 24, 2, 8)) for kk in (k1, k2, k3))
+    blk = jnp.arange(24) // BL
+    mask = (blk[:, None] >= blk[None, :])[None, None]
+    f = lambda q, k, v: jnp.sum(FA.flash_attention(
+        q, k, v, BL, None, 8, 8, True) ** 2)
+    g = lambda q, k, v: jnp.sum(attn_ops.dot_product_attention(
+        q, k, v, mask=mask) ** 2)
+    for a, b in zip(jax.grad(f, (0, 1, 2))(q, k, v),
+                    jax.grad(g, (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
+
+
+@pytest.mark.parametrize("impl", ["reference", "kernel"])
+def test_block_positions_ride_the_query_heads(impl):
+    """``rep`` 8 x block 4 = 32 query rows a K/V head (the shape the
+    benchmark's configuration runs), ragged lengths and an idle row: the
+    folded call against plain attention of every position over the row's
+    whole context; the kernel in interpret mode."""
+    b, kv, rep, d, ps, maxp = 3, 2, 8, 128, 8, 4
+    h = kv * rep
+    ks = jax.random.split(jax.random.key(2), 4)
+    q = jax.random.normal(ks[0], (b, BL, h, d))
+    kc = jax.random.normal(ks[1], PA.kv_pool_shape(2, kv, 16, ps, d))
+    vc = jax.random.normal(ks[2], PA.kv_pool_shape(2, kv, 16, ps, d))
+    table = jnp.asarray([[1, 2, 3, 4], [5, 6, 0, 0], [0, 0, 0, 0]], jnp.int32)
+    lens = jnp.asarray([28, 12, 0], jnp.int32)
+    got = PA.block_paged_attention(q, kc, vc, 1, table, lens, impl=impl,
+                                   interpret=True, kv_heads=kv)
+    assert got.shape == q.shape
+    kk = PA._gather_context(kc, 1, table, kv, d)     # [B, KV, T, D]
+    vv = PA._gather_context(vc, 1, table, kv, d)
+    s = jnp.einsum("btgrd,bgkd->btgrk", q.reshape(b, BL, kv, rep, d),
+                   kk) * d ** -0.5
+    s = jnp.where(jnp.arange(maxp * ps) < lens[:, None, None, None, None],
+                  s, -1e30)
+    want = jnp.einsum("btgrk,bgkd->btgrd", jax.nn.softmax(s, -1), vv)
+    want = jnp.where(lens[:, None, None, None, None] > 0, want, 0.0)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(want.reshape(q.shape)), atol=2e-5)
+
+
+# -- the routed layer: score, gate, shares ----------------------------------------
+
+
+def _routed_layer(key, score, gated, experts=8, d=16, f=12):
+    ks = jax.random.split(key, 5)
+    p = {"router": jax.random.normal(ks[0], (d, experts)),
+         "w_in": jax.random.normal(ks[1], (experts, d, f)) * d ** -0.5,
+         "w_out": jax.random.normal(ks[2], (experts, f, d)) * f ** -0.5}
+    if score == "sigmoid":
+        p["router_bias"] = 0.1 * jax.random.normal(ks[3], (experts,))
+    if gated:
+        p["w_gate"] = jax.random.normal(ks[4], (experts, d, f)) * d ** -0.5
+    return p
+
+
+def _expert_loop(p, x, cfg):
+    """The layer as a loop over experts, in plain form."""
+    logits = x @ p["router"]
+    s = jax.nn.sigmoid(logits) if cfg.score == "sigmoid" \
+        else jax.nn.softmax(logits, -1)
+    _, idx = jax.lax.top_k(s + p.get("router_bias", 0.0), cfg.top_k)
+    w = jnp.take_along_axis(s, idx, -1)
+    w = w / w.sum(-1, keepdims=True) * cfg.scale
+    y = jnp.zeros_like(x)
+    for e in range(cfg.num_experts):
+        c = jnp.sum(jnp.where(idx == e, w, 0.0), -1, keepdims=True)
+        h = x @ p["w_in"][e]
+        h = jax.nn.silu(x @ p["w_gate"][e]) * h if cfg.gated \
+            else moe._act(cfg.act, h)
+        y = y + c * (h @ p["w_out"][e])
+    return y
+
+
+@pytest.mark.parametrize("rows", [24, moe.DENSE_MAX_TOKENS + 8])
+@pytest.mark.parametrize("score,gated", [("sigmoid", False),
+                                         ("softmax", True)])
+def test_routed_layer_equals_a_loop_over_experts(score, gated, rows):
+    """Both arrangements of ``moe_routed`` (every expert over every row;
+    rows sorted by expert) for both kinds of layer."""
+    cfg = moe.RoutedConfig(num_experts=8, top_k=2, scale=1.5, score=score,
+                           gated=gated, act="silu" if gated else "relu2")
+    p = _routed_layer(jax.random.key(5), score, gated)
+    x = jax.random.normal(jax.random.key(6), (rows, 16))
+    got, counts = moe.moe_routed(p, x, cfg)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(_expert_loop(p, x, cfg)),
+                               atol=TOL, rtol=TOL)
+    assert int(counts[0]) == 2 * rows and int(counts[1]) == 0
+
+
+@pytest.mark.parametrize("score,gated", [("sigmoid", False),
+                                         ("softmax", True)])
+def test_the_shares_add_up_to_the_uncut_layer(score, gated):
+    """Expert parallelism's unit: every device routes over all experts
+    and computes its own share's part; the parts of all shares add up to
+    what the uncut layer gives."""
+    kw = dict(num_experts=8, top_k=3, score=score, gated=gated,
+              act="silu" if gated else "relu2")
+    p = _routed_layer(jax.random.key(7), score, gated)
+    x = jax.random.normal(jax.random.key(8), (2, 9, 16))
+    whole, counts = moe.moe_routed(p, x, moe.RoutedConfig(**kw))
+    parts, held = 0.0, 0
+    for lo, hi in ((0, 3), (3, 4), (4, 8)):
+        share = {k: (v[lo:hi] if k in ("w_in", "w_out", "w_gate") else v)
+                 for k, v in p.items()}
+        y, c = moe.moe_routed(share, x, moe.RoutedConfig(held=(lo, hi), **kw))
+        parts, held = parts + y, held + int(c[0])
+        assert int(c[0]) + int(c[1]) == 18 * 3
+    np.testing.assert_allclose(np.asarray(parts), np.asarray(whole),
+                               atol=TOL, rtol=TOL)
+    assert held == int(counts[0]) == 18 * 3
+
+
+def test_default_fields_leave_the_tree_and_the_programs_alone():
+    """``block_len`` 1, no q/k norms, sigmoid routing: the parameter tree
+    of a pattern with routed experts holds nothing of this module's
+    additions, and the same key draws the same weights as before them
+    (the programs themselves are held to the parent's text by the AOT
+    comparison PERF.md records)."""
+    cfg = T.TransformerConfig(
+        vocab_size=61, num_layers=2, num_heads=2, embed_dim=16, mlp_dim=8,
+        max_seq_len=32, norm="rms", positions="none", mlp="relu2",
+        pattern="*E", moe_experts=4, moe_router="sigmoid", moe_top_k=2,
+        remat=False)
+    params = T.init_params(cfg, jax.random.key(0))
+    assert set(params["blocks"][0]) == {"ln_g", "wq", "wk", "wv", "wo"}
+    assert set(params["blocks"][1]) == {"ln_g", "router", "router_bias",
+                                        "w_in", "w_out"}
+    assert dataclasses.replace(cfg, block_len=1, qk_norm=False) == cfg
+    assert cfg.moe_dropless and cfg.routed.score == "sigmoid" \
+        and not cfg.routed.gated
+    # the draws' order: router, (bias: zeros), w_in, w_out, as it was
+    k = iter(jax.random.split(jax.random.key(0), 8 + 8 * 2))
+    draw = lambda *shape: jax.random.normal(next(k), shape, cfg.dtype)
+    draw(61, 16)
+    for _ in range(4):
+        draw(16, 16)
+    np.testing.assert_array_equal(
+        np.asarray(params["blocks"][1]["router"]),
+        np.asarray(draw(16, 4) * 16 ** -0.5))
