@@ -1075,13 +1075,18 @@ def forward_decode(cfg: TransformerConfig, params: dict, ids: jax.Array,
                                page_table, k_cache, v_cache, attn_impl,
                                state)
 
+    # what the step's cache layers share, made once: XLA leaves it in the
+    # layer loop's body otherwise
+    plan = pa.decode_plan(k_cache, page_table, positions, seq_lens,
+                          cfg.kv_heads, cfg.head_dim)
+
     def layer_fn(x, layer, cache_layer, kc, vc):
         def attend(q, k, v):
             pools = pa.write_decode_kv(kc, vc, k, v, cache_layer, page_table,
-                                       positions)
+                                       positions, plan)
             return pa.ragged_paged_attention(
                 q, *pools, cache_layer, page_table, seq_lens,
-                impl=attn_impl, kv_heads=cfg.kv_heads), pools
+                impl=attn_impl, kv_heads=cfg.kv_heads, plan=plan), pools
 
         x, _, pools = _block(cfg, x, layer, attend, rope)
         return x, pools
@@ -1167,11 +1172,15 @@ def _decode_pattern(cfg: TransformerConfig, params, x, rope, positions,
     state = dict(state or {})
     live = seq_lens > 0
 
+    plan = pa.decode_plan(k_cache, page_table, positions, seq_lens,
+                          cfg.kv_heads, cfg.head_dim)
+
     def attend(i, q, k, v):
-        pools[:] = pa.write_decode_kv(*pools, k, v, i, page_table, positions)
+        pools[:] = pa.write_decode_kv(*pools, k, v, i, page_table, positions,
+                                      plan)
         return pa.ragged_paged_attention(q, *pools, i, page_table, seq_lens,
                                          impl=attn_impl,
-                                         kv_heads=cfg.kv_heads)
+                                         kv_heads=cfg.kv_heads, plan=plan)
 
     def keep(name, i, new):
         """Layer i's rows of pool ``name`` <- ``new`` on live rows."""

@@ -82,6 +82,7 @@ mirroring the stub-fallback stance of this package.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -210,15 +211,65 @@ def write_chunk_kv(k_pool, v_pool, k, v, cache_layer, page_table, starts,
             _write_tokens(v_pool, v, cache_layer, pages, pos % ps))
 
 
-def write_decode_kv(k_pool, v_pool, k, v, cache_layer, page_table, positions):
+class DecodePlan(NamedTuple):
+    """What every cache layer of ONE decode step shares: where the new
+    token's K/V rows go (``rows`` [B, H/g, 4]: cache layer 0, head group,
+    page, row of the page) and the kernel's work list (``work``:
+    ``_decode_work``).  Index arithmetic over ``page_table``, ``positions``
+    and ``seq_lens`` alone — a program that walks the layers in a loop
+    makes it once, before the loop, and hands it to :func:`write_decode_kv`
+    and :func:`ragged_paged_attention` (XLA moves none of it out of a
+    ``while`` body: it was a third of the operations of a ``gpt2-large``
+    decode step, PERF.md §6, PR 33)."""
+
+    rows: jax.Array
+    work: tuple
+
+
+def _decode_rows(pool, page_table, positions):
+    """[B, H/g, 4]: where row ``b``'s token at ``positions[b]`` goes in
+    cache layer 0 of ``pool`` — (0, head group, page, row of the page)."""
+    _, groups, _, ps, _ = pool.shape
+    pages = jnp.take_along_axis(page_table, (positions // ps)[:, None],
+                                axis=1)
+    return jnp.stack(jnp.broadcast_arrays(
+        0, jnp.arange(groups)[None, :], pages, (positions % ps)[:, None]),
+        axis=-1).astype(jnp.int32)
+
+
+def decode_plan(k_pool, page_table, positions, seq_lens, kv_heads: int,
+                head_dim: int) -> DecodePlan:
+    """The :class:`DecodePlan` of a decode step over pools shaped like
+    ``k_pool`` holding ``kv_heads`` heads of ``head_dim``."""
+    ps = k_pool.shape[3]
+    n = decode_block_pages(kv_heads, ps, head_dim, k_pool.dtype.itemsize,
+                           page_table.shape[1])
+    return DecodePlan(_decode_rows(k_pool, page_table, positions),
+                      _decode_work(page_table, seq_lens, n, ps))
+
+
+_ROWS = lax.ScatterDimensionNumbers(
+    update_window_dims=(2,), inserted_window_dims=(0, 1, 2, 3),
+    scatter_dims_to_operand_dims=(0, 1, 2, 3))
+
+
+def write_decode_kv(k_pool, v_pool, k, v, cache_layer, page_table, positions,
+                    plan: DecodePlan | None = None):
     """Write one new token's K/V per batch row into cache layer
-    ``cache_layer`` of the pools: a chunk of one token at ``positions``.
+    ``cache_layer`` of the pools.
 
     k/v: [B, H, D]; k_pool/v_pool: ``kv_pool_shape``; page_table:
     [B, max_pages]; positions: [B] absolute token index.
-    Idle rows (all-zero table rows) land in the null page."""
-    return write_chunk_kv(k_pool, v_pool, k[:, None], v[:, None], cache_layer,
-                          page_table, positions, jnp.ones_like(positions))
+    Idle rows (all-zero table rows) land in the null page.  ``plan``: the
+    step's :func:`decode_plan` (made here if None).  The scatter is
+    ``_write_tokens``'s — every leading dimension indexed, the window the
+    pool's minor dimension alone, in place on the carried pool — with its
+    index rows made once a step and only the layer added here."""
+    rows = plan.rows if plan is not None else _decode_rows(
+        k_pool, page_table, positions)
+    at = rows + jnp.asarray([1, 0, 0, 0], jnp.int32) * cache_layer
+    return tuple(lax.scatter(pool, at, _pack_heads(x).astype(pool.dtype),
+                             _ROWS) for pool, x in ((k_pool, k), (v_pool, v)))
 
 
 def _gather_context(pool, cache_layer, page_table, num_heads, head_dim):
@@ -412,7 +463,7 @@ def _decode_kernel(layer_ref, rows_ref, blocks_ref, pages_ref, lens_ref,
 
 
 def _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale,
-                 interpret, kv_heads=None):
+                 interpret, kv_heads=None, work=None):
     b, h, d = q.shape
     kv = kv_heads or h
     rep = h // kv
@@ -420,8 +471,8 @@ def _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale,
     g = lanes // d
     n = decode_block_pages(kv, page_size, d, k_pool.dtype.itemsize,
                            page_table.shape[1])
-    rows, blocks, pages, steps = _decode_work(page_table, seq_lens, n,
-                                              page_size)
+    rows, blocks, pages, steps = work or _decode_work(
+        page_table, seq_lens, n, page_size)
     # K/V head (group, j)'s ``rep`` query heads in lanes [j·D, (j+1)·D) of
     # rows [qr·j, qr·(j + 1)) of its group, zeros elsewhere: the products
     # with the other heads' lanes of a pool row are exact zeros.  One
@@ -482,7 +533,7 @@ def _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale,
 
 def ragged_paged_attention(q, k_pool, v_pool, cache_layer, page_table,
                            seq_lens, scale=None, impl="auto", interpret=None,
-                           kv_heads=None):
+                           kv_heads=None, plan: DecodePlan | None = None):
     """Decode-step attention of q [B, H, D] over cache layer
     ``cache_layer`` of a paged KV-cache (k_pool/v_pool:
     ``kv_pool_shape`` of ``kv_heads`` heads, None = H: query head h reads
@@ -492,7 +543,9 @@ def ragged_paged_attention(q, k_pool, v_pool, cache_layer, page_table,
     interpreter mode off-TPU, the flash_attention convention), "reference"
     (pure jnp — the production CPU path: interpret-mode Pallas is a
     per-block Python loop, far too slow to serve from), or "auto"
-    (kernel on TPU, reference elsewhere)."""
+    (kernel on TPU, reference elsewhere).  ``plan``: the step's
+    :func:`decode_plan`, whose work list the kernel then takes instead of
+    making its own."""
     d = q.shape[-1]
     scale = scale if scale is not None else d ** -0.5
     from paddle_tpu.ops.pallas import resolve_impl, resolve_interpret
@@ -502,7 +555,8 @@ def ragged_paged_attention(q, k_pool, v_pool, cache_layer, page_table,
             q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale=scale,
             kv_heads=kv_heads)
     return _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens,
-                        scale, resolve_interpret(interpret), kv_heads)
+                        scale, resolve_interpret(interpret), kv_heads,
+                        plan and plan.work)
 
 
 def block_paged_attention(q, k_pool, v_pool, cache_layer, page_table,

@@ -7,7 +7,9 @@ KV-cache for the transformer LM, plus a micro-batching dense path for the
 CTR/recommender models.
 
 - ``kv_cache``   — PageAllocator (free-list, null page 0) + PagedKVCache
-  (device page pools + host page tables);
+  (device page pools + host page tables, and the token array: the last
+  token every slot sampled, int32[max_slots], kept on the device beside
+  the pools so a decode step's input never passes through the host);
 - ``scheduler``  — continuous-batching request scheduler: admission
   control by free pages / concurrent-token budget, prefill/decode
   interleave, per-step join/retire; deterministic given seed + arrival
@@ -15,7 +17,13 @@ CTR/recommender models.
 - ``engine``     — ServingEngine: thread-safe submit()/results() over a
   background step loop (or synchronous ``run_until_idle`` for CLIs and
   tests), jitted prefill/decode closures, per-request telemetry
-  (queue wait, TTFT, TPOT) through the MetricsRegistry;
+  (queue wait, TTFT, TPOT) through the MetricsRegistry.  The loop runs
+  one pass ahead: it dispatches decode step n + 1 — built from the
+  scheduler's count of tokens in flight, its input read from the token
+  array — before it reads step n's tokens, hands those out one
+  iteration later, and drains only where it must (idle, ``stop()``, a
+  weight swap, the incremental prefill path, a model that generates by
+  blocks);
 - ``sampling``   — greedy + temperature sampling under explicit PRNG keys;
 - ``export``     — checkpoint -> servable artifact (sha256 manifest, the
   trainer checkpoint format's serving twin);

@@ -13,6 +13,46 @@ and exposes a thread-safe ``submit()/results()`` API:
 Synchronous callers (CLIs, tests, benches) skip the thread:
 ``eng.generate(prompts)`` or ``submit(...)`` + ``run_until_idle()``.
 
+**The order of an iteration** (:meth:`ServingEngine.step`).  The loop
+keeps one pass in flight: the last token every slot sampled lives on the
+device (``PagedKVCache.tokens``, carried and donated beside the pools), so
+nothing a decode step needs waits for the step before it.  An iteration
+
+1. takes in new submissions, retires what the last iteration's reads
+   finished and admits into the freed slots;
+2. DISPATCHES the admitted requests' prefill pass, behind the decode step
+   still running; it scatters each row's first token into the token
+   array on the device, so the admitted ride the very next decode step;
+3. builds that step from what the scheduler knows without reading
+   anything — a sequence's position, context and key index count its
+   tokens in flight — transfers its arrays and DISPATCHES it;
+4. only then READS what was dispatched before that step, oldest first —
+   the last iteration's decode step, this one's prefill pass — and hands
+   the tokens out (``Scheduler.append_token``, one by one, in order).  A
+   request's result is delivered once its last token has been read (by
+   the retirement that follows).  One decode step stays in flight.
+
+A finish by length is known from the counts and the sequence rides no
+further pass; an ``eos`` is data and is seen one pass late — the surplus
+pass's token is dropped and counted (``serve_tokens_dropped_total``), its
+K/V write lands inside the request's own reservation.  A device error
+surfaces at the read, one pass late, and fails the loop as before; the
+passes dispatched behind the failed one are lost with it, whoever met the
+failure (the loop thread, a caller stepping by hand, a drain).
+
+**Where the loop drains** (reads everything in flight before going on;
+counter ``serve_loop_drains_total{why}``): when there is nothing left to
+dispatch (``idle``: so ``step()`` keeps returning True while a pass is
+unread, and ``run_until_idle`` leaves none), at ``stop()`` (``stop``),
+before a weight swap (``swap``: :meth:`ServingEngine.set_params`), and in the
+two configurations that keep the synchronous order — dispatch, read,
+then go on — for every pass: the incremental prefill path
+(``incremental``: prefix cache / chunked prefill, whose pass's first
+tokens the host seeds into the token array) and a model that generates
+by blocks (``block``: the next block pass's input is which positions the
+last one unmasked; carrying that on the device is ROADMAP's follow-up).
+Which it is follows from the engine's configuration; there is no switch.
+
 Telemetry rides the shared :class:`MetricsRegistry`: histograms
 ``serve_queue_wait_ms`` / ``serve_prefill_ms`` / ``serve_decode_step_ms``
 / ``serve_ttft_ms`` / ``serve_tpot_ms``, counters ``serve_requests`` /
@@ -20,7 +60,9 @@ Telemetry rides the shared :class:`MetricsRegistry`: histograms
 decode steps: batch × num_layers × loop_steps a step) /
 ``serve_loop_crashes`` (background loops that died —
 pending ``results()`` callers get the loop's exception re-raised
-instead of blocking forever), gauges ``serve_active_slots`` /
+instead of blocking forever) / ``serve_passes_ahead_total{kind=prefill|
+decode}`` (passes dispatched while the one before was unread) /
+``serve_loop_drains_total{why}`` (above), gauges ``serve_active_slots`` /
 ``serve_free_pages`` / ``serve_kv_bytes_per_token`` /
 ``serve_state_bytes_per_slot`` (set once, at construction); from the
 from-zero prefill passes counters ``serve_prefill_padded_tokens_total``
@@ -38,7 +80,8 @@ expert's tokens over the mean); under a model that generates by blocks
 pass, by whether it went in with masked positions),
 ``serve_blocks_committed_total``, ``serve_block_positions_total``
 (rows x block_len computed) and ``serve_tokens_dropped_total`` (committed
-positions never handed out: a last block's surplus); with
+positions never handed out: a last block's surplus; without blocks, what
+the surplus pass after an ``eos`` sampled); with
 ``--prefix_cache`` /
 ``--prefill_chunk_tokens``
 also counters ``serve_prefix_hit_tokens`` / ``serve_prefill_flops_saved``
@@ -52,6 +95,7 @@ one ``kind="serve"`` record per completed request and a
 from __future__ import annotations
 
 import collections
+import dataclasses
 import queue
 import threading
 import time
@@ -124,6 +168,21 @@ def drain_results(completed: "queue.Queue", loop_error_now, what: str,
     return out
 
 
+@dataclasses.dataclass
+class _Pass:
+    """A dispatched pass whose tokens the host has not read."""
+
+    kind: str           # "prefill" | "decode": its span and counters
+    rows: list          # the sequences it samples a token for
+    n_out: int          # tokens in ``out`` (routing counts ride behind)
+    args: dict          # what its span says of it (empty: tracing off)
+    t_from: float       # start of the interval it adds to the loop: when
+                        # the loop turned to it, or the read-back before
+                        # its own where that ended later
+    out: object = None  # the int32 device array its tokens come out in
+    span: object = None  # its open span
+
+
 class ServingEngine:
     def __init__(self, cfg, params, serving: ServingConfig | None = None,
                  registry=None, device=None):
@@ -184,8 +243,14 @@ class ServingEngine:
             enforce(not found,
                     found[0].message if found else "")
         self.device = device
-        self.params = self.place(params)
+        # one iteration at a time (the loop thread, a synchronous caller,
+        # a swap's smoke decode): what is in flight has one owner
+        self._pump = threading.RLock()
+        # what the loop has on the device: the passes dispatched and not
+        # read yet, oldest first (touched only under _pump)
+        self._in_flight: collections.deque[_Pass] = collections.deque()
         self.registry = registry or metrics_mod.get_registry()
+        self.params = self.place(params)
         # allocate the pools ON the engine's device (not on the default
         # device and then moved: a fleet's pools would all pass through
         # device 0), then commit them
@@ -196,8 +261,12 @@ class ServingEngine:
                 dtype=cfg.dtype, prefix_cache=s.prefix_cache,
                 state_layers=cfg.state_layers,
                 state_shapes=cfg.state_shapes if cfg.state_layers else None)
-        self.cache.k, self.cache.v, self.cache.state = self.place(
-            (self.cache.k, self.cache.v, self.cache.state))
+        if cfg.block_len > 1:
+            self.cache.tokens = None    # the host chooses a block's input
+        (self.cache.k, self.cache.v, self.cache.state,
+         self.cache.tokens) = self.place(
+            (self.cache.k, self.cache.v, self.cache.state,
+             self.cache.tokens))
         self.scheduler = Scheduler(s, self.cache, cfg.block_len)
         # 2·params is the standard per-token forward-FLOPs estimate —
         # what a prefix-cache hit's skipped recompute is booked at; a
@@ -240,6 +309,11 @@ class ServingEngine:
             self._loop_args.update(kv_heads=cfg.kv_heads,
                                    state_layers=cfg.state_layers)
         self._block = cfg.block_len
+        # the two configurations whose every pass is read before the next
+        # is dispatched (module docstring)
+        self._sync = self._block > 1 or s.incremental_prefill
+        if self._sync:      # no pass goes out behind an unread one
+            self._loop_args["ahead"] = 0
         if self._block > 1:
             self._loop_args.update(block=self._block)
             self.registry.gauge(
@@ -292,13 +366,34 @@ class ServingEngine:
 
     def place(self, tree):
         """Commit a pytree to this engine's device (identity when the
-        engine has none).  Weight swaps go through here too, so a
-        replica's params never drift to another replica's device."""
+        engine has none)."""
         if self.device is None:
             return tree
         import jax
 
         return jax.device_put(tree, self.device)
+
+    def set_params(self, params):
+        """Serve ``params`` (same tree, shapes and types: the held
+        executables take no other) from the next pass on; returns what
+        was served before.  A weight swap goes through here, so a
+        replica's params never drift to another replica's device, and
+        whatever is in flight is read first, in the same hold of the loop
+        as the assignment: no pass dispatched under the old weights is
+        left for the new ones to follow, and a background loop dispatches
+        none in between."""
+        with self._pump:
+            self._drain("swap")
+            old, self.params = self.params, self.place(params)
+        return old
+
+    def _params(self):
+        """The weights a pass is dispatched under.  Every caller is inside
+        an iteration and holds ``_pump`` already; taken again here, where
+        the attribute is read, because that hold is what keeps a swap from
+        landing between a drain and a dispatch."""
+        with self._pump:
+            return self.params
 
     def _dev(self, batch: dict, *names):
         """Host batch fields -> arrays on this engine's device."""
@@ -437,6 +532,9 @@ class ServingEngine:
         self._thread.start()
 
     def stop(self) -> None:
+        """Stop the background loop, read what it left in flight (tokens
+        are handed out, requests whose last token that was are delivered)
+        and emit the summary."""
         self._stop.set()
         t, self._thread = self._thread, None
         if t is not None:
@@ -447,10 +545,14 @@ class ServingEngine:
             # keep accepting — generate()/run_until_idle still serve.
             with self._lock:
                 self._stopped = True
+        if self._drain("stop"):
+            with self._pump:
+                self._retire()
         self.emit_summary()
 
     def run_until_idle(self) -> None:
-        """Drive the loop on the calling thread until no work remains."""
+        """Drive the loop on the calling thread until no work remains,
+        in flight included."""
         while self.step():
             pass
 
@@ -475,20 +577,41 @@ class ServingEngine:
                       "requests", type(e).__name__, e)
 
     def step(self) -> bool:
-        """One scheduler iteration: drain submissions, retire, admit +
-        prefill, decode.  Returns False when fully idle.
+        """One iteration of the loop.  Returns False when fully idle: no
+        submission, no resident sequence, no pass in flight.
+
+        In order (module docstring): take in submissions, retire what the
+        last iteration's reads finished, admit; DISPATCH the admitted
+        requests' prefill pass; build and DISPATCH the next decode step,
+        behind whatever is still running; only then READ what was
+        dispatched before that step and hand the tokens out
+        (``Scheduler.append_token``, in order; a request is delivered once
+        its last token has been read).  So a decode step's tokens are
+        handed out by the iteration after the one that dispatched it, a
+        prefill pass's first tokens by its own, and the device always has
+        the next step queued.  The incremental prefill path and a model
+        that generates by blocks read every pass before the next is
+        dispatched instead (``_sync``); ``stop()`` and a weight swap
+        (``set_params``) read what is in flight; an engine with an unread pass
+        is not idle.
 
         With span tracing on, an iteration that did work is one
         ``serve_step`` span (an idle one records nothing): its
         ``serve_schedule`` children are the calls that build or change
-        scheduler / KV-cache state, ``serve_prefill`` / ``serve_decode``
-        are the two device passes (dispatch + the wait for the tokens;
-        both carry the stack they ran: ``loop_steps``, ``cache_layers``;
-        a from-zero prefill pass also the shape it ran and what was in it:
-        ``rows``, ``padded_tokens``, ``prompt_tokens``),
-        and what is left over, its self time, is this loop's own Python:
-        the small host-to-device transfers, the ``append_token`` loops,
-        histograms and gauges.
+        scheduler / KV-cache state; ``serve_prefill`` / ``serve_decode``
+        are leaves, one a pass, carrying the stack it ran (``loop_steps``,
+        ``cache_layers``), ``ahead`` (1: dispatched while the pass before
+        it was unread) and ``dispatch_ms`` (the dispatch call's own time);
+        a from-zero prefill pass also the shape it ran and what was in it
+        (``rows``, ``padded_tokens``, ``prompt_tokens``).  A pass's span
+        closes at its read-back.  It opens after the read-back before it
+        has ended, in the iteration that reads it: around the dispatch of
+        the pass that follows it and the wait for its own tokens — or,
+        where the loop reads every pass at once (``_sync``), around its
+        own dispatch and the wait, as it always did.  Spans of one thread
+        never overlap.  What is left over of ``serve_step``, its self
+        time, is this loop's own Python: the small host-to-device
+        transfers, the ``append_token`` loops, histograms and gauges.
 
         What a pass hands out: a prefill pass each admitted request's
         first token and a decode step one token a live sequence — or,
@@ -500,17 +623,21 @@ class ServingEngine:
         from paddle_tpu.telemetry.tracing import get_tracer
 
         tracer = get_tracer()
-        tk = None
-        if tracer.enabled:
-            tk = tracer.begin("serve_step", cat="serving",
-                              waiting=self.queued()
-                              + len(self.scheduler.queue),
-                              active=len(self.scheduler.active))
-        worked = False
-        try:
-            worked = self._step(tracer)
-        finally:
-            (tracer.end if worked else tracer.cancel)(tk)
+        with self._pump:
+            tk = None
+            if tracer.enabled:
+                tk = tracer.begin("serve_step", cat="serving",
+                                  waiting=self.queued()
+                                  + len(self.scheduler.queue),
+                                  active=len(self.scheduler.active))
+            worked = False
+            try:
+                worked = self._step(tracer)
+            except BaseException:
+                self._lose(tracer)
+                raise
+            finally:
+                (tracer.end if worked else tracer.cancel)(tk)
         return worked
 
     def _scheduled(self, tracer, build, *args):
@@ -534,16 +661,17 @@ class ServingEngine:
         first of them serves (the step that admits the first request
         calls this ahead of its prefill pass): each member of the
         scheduler's prefill ladder and the decode program, lowered and
-        compiled for a batch of slack rows only, and held: the step loop
-        calls these executables, not the jitted functions.  ``jax.jit``
-        would compile a shape the first time traffic brings it — seconds,
-        in the middle of serving; an executable compiles nothing, and
-        refuses arguments of another shape or type (a weight swap brings
-        the same) instead.  Nothing runs here, so pools, state and page
-        table are what they were.  Replicas share the jitted functions,
-        so a fleet on one device traces, lowers and compiles once.  The
-        incremental path has one prefill shape, compiled by its first
-        pass as before."""
+        compiled for a batch of slack rows only — the token array
+        (``PagedKVCache.tokens``) among their arguments — and held: the
+        step loop calls these executables, not the jitted functions.
+        ``jax.jit`` would compile a shape the first time traffic brings
+        it — seconds, in the middle of serving; an executable compiles
+        nothing, and refuses arguments of another shape or type (a
+        weight swap brings the same) instead.  Nothing runs here, so
+        pools, state, token array and page table are what they were.
+        Replicas share the jitted functions, so a fleet on one device
+        traces, lowers and compiles once.  The incremental path has one
+        prefill shape, compiled by its first pass as before."""
         t0 = time.perf_counter()
         cache, sched = self.cache, self.scheduler
         rows = () if self.serving.incremental_prefill else sched.prefill_rows
@@ -553,24 +681,35 @@ class ServingEngine:
             args = self._dev(sched.prefill_arrays([], n), "ids", "seq_lens",
                              "page_table", "rids", "temps", "slots")
             programs[n] = self._prefill.lower(
-                self.params, self._base_key, cache.k, cache.v, *args,
-                cache.state).compile()
+                self._params(), self._base_key, cache.k, cache.v, *args,
+                cache.state, cache.tokens).compile()
             log.debug("prefill program of %d row(s) ready after %.2f s", n,
                       time.perf_counter() - t1)
-        args = self._dev(sched.decode_arrays([]), "ids", "positions",
-                         "seq_lens", "page_table", "rids", "gens", "temps")
+        batch = sched.decode_arrays([])
+        if self._block > 1:
+            args = self._dev(batch, "ids", "positions", "seq_lens",
+                             "page_table", "rids", "gens", "temps")
+        else:   # a step's ids are the token array, which the device keeps
+            args = [cache.tokens] + self._dev(
+                batch, "positions", "seq_lens", "page_table", "rids", "gens",
+                "temps")
         programs["decode"] = self._decode.lower(
-            self.params, self._base_key, cache.k, cache.v, *args,
+            self._params(), self._base_key, cache.k, cache.v, *args,
             cache.state).compile()
         with self._lock:    # all or none: a failure is met again
             self._programs = programs
         log.info("serving engine ready: %d prefill program(s) of %s row(s) x "
-                 "%d + decode in %.2f s", len(rows), list(rows),
-                 self.serving.max_prompt_len, time.perf_counter() - t0)
+                 "%d + decode in %.2f s; %s, %s", len(rows), list(rows),
+                 self.serving.max_prompt_len, time.perf_counter() - t0,
+                 "a block's input is the host's" if self._block > 1 else
+                 f"the last token of {self.serving.max_slots} slot(s) stays "
+                 "on the device (the token array)",
+                 "every pass is read before the next" if self._sync
+                 else "the loop runs one pass ahead")
         return programs
 
     def _step(self, tracer) -> bool:
-        sched, reg = self.scheduler, self.registry
+        sched, reg, flight = self.scheduler, self.registry, self._in_flight
         now = time.perf_counter()
         worked = False
 
@@ -580,11 +719,8 @@ class ServingEngine:
                 sched.enqueue(self._incoming.popleft())
                 worked = True
             programs = self._programs
-
-        for a in sched.retire_finished():
-            self._finish(a)
+        if self._retire():      # what the last iteration's reads finished
             worked = True
-
         admitted = sched.admit(now=now)
         # a pass over empty queues and full slots is not scheduling work
         (tracer.end if worked or admitted else tracer.cancel)(tk)
@@ -594,54 +730,11 @@ class ServingEngine:
             # idle replicas all the time)
             programs = self._make_ready()
         if admitted and not self.serving.incremental_prefill:
-            t0 = time.perf_counter()
-            batch = self._scheduled(tracer, sched.prefill_batch, admitted)
-            args = self._dev(batch, "ids", "seq_lens", "page_table", "rids",
-                             "temps", "slots")
-            rows, length = batch["ids"].shape
-            fill = {"rows": rows, "padded_tokens": rows * length,
-                    "prompt_tokens": int(batch["seq_lens"].sum())}
-            by_blocks = self._block > 1
-            if by_blocks:
-                fill["blocks_written"] = fill["prompt_tokens"] // self._block
-            tk = tracer.begin("serve_prefill", cat="serving",
-                              batch=len(admitted), **fill, **self._loop_args)
-            cache = self.cache
-            toks, cache.k, cache.v, cache.state = programs[rows](
-                self.params, self._base_key, cache.k, cache.v, *args,
-                cache.state)
-            toks, counts = self._split_counts(toks, rows, "prefill")
-            if tk is not None:
-                tracer.end(tk, **counts)
-            t1 = time.perf_counter()
-            # the ratio of the two counters is the passes' fill share
-            reg.counter(
-                "serve_prefill_padded_tokens_total",
-                "tokens the from-zero prefill passes computed (rows x "
-                "length of each pass's shape)").inc(fill["padded_tokens"])
-            reg.counter(
-                "serve_prefill_prompt_tokens_total",
-                "prompt tokens the from-zero prefill passes carried").inc(
-                    fill["prompt_tokens"])
-            hist = reg.histogram("serve_prefill_ms",
-                                 "prefill pass wall ms (per admitted batch)")
-            hist.observe((t1 - t0) * 1e3)
-            # the first generated token of each request is sampled here
-            # (by blocks: none; the first block's commit hands out the first)
-            reg.counter("serve_tokens", "tokens generated").inc(
-                0 if by_blocks else len(admitted))
-            for j, a in enumerate(admitted):
-                reg.histogram(
-                    "serve_queue_wait_ms",
-                    "request wait between arrival and admission").observe(
-                        (a.t_admit - a.request.arrival) * 1e3)
-                if by_blocks:
-                    continue
-                a.t_first = t1
-                reg.histogram(
-                    "serve_ttft_ms", "time to first token").observe(
-                        (t1 - a.request.arrival) * 1e3)
-                sched.append_token(a, int(toks[j]))
+            # behind the decode step still in flight; its first tokens go
+            # into the token array, so the admitted ride the step below
+            self._send_prefill(tracer, programs, admitted)
+            if self._block > 1:
+                self._settle(tracer, "block")
             worked = True
 
         if self.serving.incremental_prefill:
@@ -649,46 +742,29 @@ class ServingEngine:
                 worked = True
 
         batch = self._scheduled(tracer, sched.decode_batch)
-        if batch is not None and self._block > 1:
-            self._block_pass(tracer, programs["decode"], batch)
-            worked = True
-        elif batch is not None:
-            live = batch.pop("live")
-            t0 = time.perf_counter()
-            args = self._dev(batch, "ids", "positions", "seq_lens",
-                             "page_table", "rids", "gens", "temps")
-            tk = tracer.begin("serve_decode", cat="serving",
-                              batch=len(live), **self._loop_args)
-            cache = self.cache
-            toks, cache.k, cache.v, cache.state = programs["decode"](
-                self.params, self._base_key, cache.k, cache.v, *args,
-                cache.state)
-            if tk is not None:
-                t_dispatched = tracer.clock()
-            toks, counts = self._split_counts(
-                toks, self.serving.max_slots, "decode")
-            if tk is not None:
-                if self.cfg.state_layers:
-                    # rows of the state pools this step read and rewrote
-                    counts["state_slots"] = len(live)
-                # what the step read, and how long the dispatch took
-                # before the wait for the device began
-                tracer.end(
-                    tk, **self._context_args(batch["seq_lens"]),
-                    dispatch_ms=round((t_dispatched - tk.t_start) * 1e3, 3),
-                    **counts)
-            reg.histogram(
-                "serve_decode_step_ms",
-                "one continuous-batching decode step, wall ms").observe(
-                    (time.perf_counter() - t0) * 1e3)
-            reg.counter("serve_tokens", "tokens generated").inc(len(live))
-            reg.counter(
-                "serve_layer_passes_total",
-                "decoder blocks run by decode steps (batch x num_layers x "
-                "loop_steps a step)").inc(len(live) * self.cfg.cache_layers)
-            for a in live:
-                sched.append_token(a, int(toks[a.slot]))
-            worked = True
+        if self._block > 1:
+            if batch is not None:
+                self._block_pass(tracer, programs["decode"], batch)
+                worked = True
+        else:
+            # one pass ahead: the next decode step goes out first, and
+            # only then is what was dispatched before it read
+            waiting = len(flight)
+            if waiting:
+                # the span of the pass about to be read: the dispatch of
+                # the step that follows it and the wait for its tokens
+                self._open(tracer, flight[0])
+            if batch is not None:
+                self._send_decode(tracer, programs, batch)
+            if self._sync:
+                self._settle(tracer, "incremental")
+            elif batch is None:     # nothing left to dispatch behind it
+                self._settle(tracer, "idle")
+            else:
+                for _ in range(waiting):
+                    self._read(tracer)
+            if waiting or batch is not None:
+                worked = True
 
         reg.gauge("serve_active_slots",
                   "sequences resident in the decode batch").set(
@@ -704,6 +780,181 @@ class ServingEngine:
                       "reclaimable once no sequence maps them)").set(
                           self.cache.prefix.cached_pages)
         return worked
+
+    # -- passes in flight -------------------------------------------------------
+    def _retire(self) -> bool:
+        done = self.scheduler.retire_finished()
+        for a in done:
+            self._finish(a)
+        return bool(done)
+
+    def _send_prefill(self, tracer, programs, admitted) -> None:
+        """Dispatch the from-zero prefill pass of ``admitted``.  It leaves
+        each row's first token in the token array on the device, so the
+        next decode step goes out before this pass is read."""
+        reg = self.registry
+        t0 = time.perf_counter()
+        batch = self._scheduled(tracer, self.scheduler.prefill_batch,
+                                admitted)
+        args = self._dev(batch, "ids", "seq_lens", "page_table", "rids",
+                         "temps", "slots")
+        rows, length = batch["ids"].shape
+        fill = {"rows": rows, "padded_tokens": rows * length,
+                "prompt_tokens": int(batch["seq_lens"].sum())}
+        if self._block > 1:
+            fill["blocks_written"] = fill["prompt_tokens"] // self._block
+        # the ratio of the two counters is the passes' fill share
+        reg.counter(
+            "serve_prefill_padded_tokens_total",
+            "tokens the from-zero prefill passes computed (rows x "
+            "length of each pass's shape)").inc(fill["padded_tokens"])
+        reg.counter(
+            "serve_prefill_prompt_tokens_total",
+            "prompt tokens the from-zero prefill passes carried").inc(
+                fill["prompt_tokens"])
+        self._send(tracer, _Pass(
+            "prefill", admitted, rows,
+            dict(batch=len(admitted), **fill, **self._loop_args)
+            if tracer.enabled else {}, t0), programs[rows], *args)
+        if self._block == 1:    # by blocks a prefill pass samples nothing
+            self.scheduler.sent(admitted)
+
+    def _send_decode(self, tracer, programs, batch) -> None:
+        """Dispatch one decode step over ``batch`` (``decode_batch``): a
+        token for every live row, read from and written to the token
+        array on the device."""
+        live = batch.pop("live")
+        t0 = time.perf_counter()
+        args = self._dev(batch, "positions", "seq_lens", "page_table",
+                         "rids", "gens", "temps")
+        said = {}
+        if tracer.enabled:
+            # what the step reads, and of a pattern with recurrent state
+            # the rows of the state pools it reads and rewrites
+            said = dict(batch=len(live), **self._loop_args,
+                        **self._context_args(batch["seq_lens"]))
+            if self.cfg.state_layers:
+                said["state_slots"] = len(live)
+        self._send(tracer, _Pass("decode", live, self.serving.max_slots,
+                                 said, t0), programs["decode"], *args)
+        self.scheduler.sent(live)
+        self.registry.counter(
+            "serve_layer_passes_total",
+            "decoder blocks run by decode steps (batch x num_layers x "
+            "loop_steps a step)").inc(len(live) * self.cfg.cache_layers)
+
+    def _send(self, tracer, p: _Pass, program, *args) -> None:
+        """Dispatch ``program`` (a held executable) for ``p`` over the
+        carried arrays — pools, state, token array: donated, rebound to
+        what comes back, touched by nothing in between — and put ``p`` in
+        flight.  Nothing here waits for the device."""
+        flight, cache = self._in_flight, self.cache
+        ahead = int(bool(flight))
+        if self._sync:
+            # read before anything else is dispatched: its span is its
+            # own dispatch and the wait
+            self._open(tracer, p)
+        t0 = time.perf_counter()
+        carried = (cache.tokens, *args, cache.state) if p.kind == "decode" \
+            else (*args, cache.state, cache.tokens)
+        p.out, cache.k, cache.v, cache.state, cache.tokens = program(
+            self._params(), self._base_key, cache.k, cache.v, *carried)
+        if p.args:
+            p.args.update(ahead=ahead, dispatch_ms=round(
+                (time.perf_counter() - t0) * 1e3, 3))
+        flight.append(p)
+        self.registry.counter(
+            "serve_passes_ahead_total",
+            "passes dispatched while the pass before them was unread").inc(
+                ahead, kind=p.kind)
+
+    def _open(self, tracer, p: _Pass) -> None:
+        if p.span is None:
+            p.span = tracer.begin("serve_" + p.kind, cat="serving")
+
+    def _read(self, tracer) -> None:
+        """Wait for the tokens of the oldest pass in flight and hand them
+        out (it stays in flight until they have come).  The interval the
+        pass added to the loop — from the later of its dispatch and the
+        read-back before it to its own read-back — is its observation in
+        ``serve_prefill_ms`` / ``serve_decode_step_ms``."""
+        sched, reg, flight = self.scheduler, self.registry, self._in_flight
+        p = flight[0]
+        self._open(tracer, p)
+        toks, counts = self._split_counts(p.out, p.n_out, p.kind)
+        flight.popleft()
+        tracer.end(p.span, **p.args, **counts)
+        now = time.perf_counter()
+        took_ms = (now - p.t_from) * 1e3
+        for behind in flight:   # dispatched before this read-back ended
+            behind.t_from = now
+        handed = 0
+        if p.kind == "decode":
+            reg.histogram(
+                "serve_decode_step_ms",
+                "one continuous-batching decode step, wall ms").observe(
+                    took_ms)
+            for a in p.rows:
+                handed += sched.landed(a, int(toks[a.slot]))
+            reg.counter("serve_tokens_dropped_total",
+                        "committed positions never handed out (a last "
+                        "block's surplus, what followed an eos)").inc(
+                            len(p.rows) - handed)
+        else:
+            reg.histogram("serve_prefill_ms",
+                          "prefill pass wall ms (per admitted batch)"
+                          ).observe(took_ms)
+            for j, a in enumerate(p.rows):
+                reg.histogram(
+                    "serve_queue_wait_ms",
+                    "request wait between arrival and admission").observe(
+                        (a.t_admit - a.request.arrival) * 1e3)
+                if self._block > 1:
+                    # nothing sampled: the first block's commit hands out
+                    # the first token
+                    continue
+                a.t_first = now
+                reg.histogram(
+                    "serve_ttft_ms", "time to first token").observe(
+                        (now - a.request.arrival) * 1e3)
+                handed += sched.landed(a, int(toks[j]))
+        reg.counter("serve_tokens", "tokens generated").inc(handed)
+
+    def _settle(self, tracer, why: str) -> bool:
+        """Read every pass in flight, oldest first (a drain, counted under
+        ``why``); False when there was none."""
+        flight = self._in_flight
+        if not flight:
+            return False
+        while flight:
+            self._read(tracer)
+        self.registry.counter(
+            "serve_loop_drains_total",
+            "times the loop read everything in flight before going on, by "
+            "why: idle | stop | swap | incremental | block").inc(1.0, why=why)
+        return True
+
+    def _drain(self, why: str) -> bool:
+        """:meth:`_settle` from outside an iteration (``stop``,
+        ``set_params``)."""
+        from paddle_tpu.telemetry.tracing import get_tracer
+
+        tracer = get_tracer()
+        with self._pump:
+            try:
+                return self._settle(tracer, why)
+            except BaseException:
+                self._lose(tracer)
+                raise
+
+    def _lose(self, tracer) -> None:
+        """A pass failed (at its dispatch or, a device error, at its
+        read): the passes behind it ran on the pools it was to hand on.
+        None is read again — whoever steps, drains or stops next starts
+        with nothing in flight, whichever caller met the failure."""
+        flight = self._in_flight
+        while flight:
+            tracer.cancel(flight.pop().span)
 
     def _block_pass(self, tracer, program, batch) -> None:
         """One block pass over every live sequence's block in progress
@@ -724,7 +975,7 @@ class ServingEngine:
                           positions=len(live) * bl, **self._loop_args)
         cache = self.cache
         out, cache.k, cache.v, cache.state = program(
-            self.params, self._base_key, cache.k, cache.v, *args,
+            self._params(), self._base_key, cache.k, cache.v, *args,
             cache.state)
         if tk is not None:
             t_dispatched = tracer.clock()
@@ -790,7 +1041,8 @@ class ServingEngine:
         offset prefill pass over up to ``prefill_batch`` mid-prefill
         sequences — each advances by at most ``prefill_chunk_tokens``
         (its whole uncached tail when chunking is off) — interleaved
-        with the decode pass that follows in the same engine iteration.
+        with the decode pass that follows in the same engine iteration,
+        and read at once: this path keeps the synchronous order.
         A row whose prompt completes samples its first token from the
         pass's logits, and its full prompt pages are registered in the
         prefix cache for later requests to share."""
@@ -820,7 +1072,7 @@ class ServingEngine:
         tk = tracer.begin("serve_prefill", cat="serving",
                           batch=len(rows), chunked=True, **self._loop_args)
         toks, self.cache.k, self.cache.v, _ = self._prefill_chunk(
-            self.params, self._base_key, self.cache.k, self.cache.v,
+            self._params(), self._base_key, self.cache.k, self.cache.v,
             *args)
         toks, counts = self._split_counts(
             toks, self.serving.prefill_batch, "prefill")
@@ -837,6 +1089,7 @@ class ServingEngine:
             # emit_summary reads this from the caller's thread while the
             # background loop writes it (the GL-THREAD audited contract)
             self._chunk_passes += 1
+        seeded = False
         for j, a in enumerate(rows):
             a.prefilled += takes[j]
             a.prefill_chunks += 1
@@ -849,9 +1102,15 @@ class ServingEngine:
                         (t1 - a.request.arrival) * 1e3)
                 reg.counter("serve_tokens", "tokens generated").inc(1)
                 sched.append_token(a, int(toks[j]))
+                seeded = True
                 if self.cache.prefix is not None:
                     self.cache.prefix.insert(
                         a.request.prompt, self.cache.slot_pages(a.slot))
+        if seeded:
+            # this path's program does not write the token array: seed it
+            # from the host, which here has read every token there is
+            self.cache.tokens, = self._dev(
+                {"tokens": sched.last_tokens()}, "tokens")
         return True
 
     def _finish(self, a) -> None:
@@ -1003,17 +1262,17 @@ def _serving_fns(cfg, attn_impl, donate, policy="low_confidence_static"):
         return toks if counts is None else jnp.concatenate(
             [toks.astype(jnp.int32), counts])
 
-    def sampled(logits, keys, temps, extras):
-        return with_counts(sampling.sample_tokens(logits, keys, temps),
-                           extras)
-
     def unpack(out):
         """A forward's result -> (logits, k, v, extras): a config without
         a layer pattern hands back no extras."""
         return out if len(out) == 4 else (*out, {})
 
     def prefill(params, base_key, kc, vc, ids, lens, table, rids,
-                temps, slots=None, state=None):
+                temps, slots=None, state=None, last=None):
+        """``last``: the token array (``PagedKVCache.tokens``); each row's
+        first token goes into it at the row's slot.  None under a block
+        length, where nothing is sampled (and for a caller that only
+        lowers the pass): the program is then the one it always was."""
         logits, ks, vs, extras = unpack(
             T.forward_prefill(cfg, params, ids, lens))
         if ks is not None:
@@ -1029,19 +1288,28 @@ def _serving_fns(cfg, attn_impl, donate, policy="low_confidence_static"):
         if cfg.block_len > 1:
             # nothing is sampled: the pass leaves K/V (the head is dead
             # code here); the counts ride behind a row of zeros
-            return with_counts(jnp.zeros_like(rids), extras), kc, vc, state
+            return (with_counts(jnp.zeros_like(rids), extras), kc, vc, state,
+                    last)
         keys = sampling.request_keys(
             base_key, rids, jnp.zeros_like(rids))
-        return sampled(logits, keys, temps, extras), kc, vc, state
+        toks = sampling.sample_tokens(logits, keys, temps)
+        if last is not None:
+            # a slack row's slot does not exist: dropped, as its state is
+            last = last.at[slots].set(toks, mode="drop")
+        return with_counts(toks, extras), kc, vc, state, last
 
-    def decode(params, base_key, kc, vc, ids, positions, lens, table,
+    def decode(params, base_key, kc, vc, last, positions, lens, table,
                rids, gens, temps, state=None):
+        """``last`` (``PagedKVCache.tokens``) is the step's ``ids``, and
+        gets the tokens the step samples; rows that are not decoding keep
+        what they had."""
         logits, kc, vc, extras = unpack(T.forward_decode(
-            cfg, params, ids, positions, lens, table, kc, vc,
+            cfg, params, last, positions, lens, table, kc, vc,
             attn_impl=attn_impl, state=state))
         keys = sampling.request_keys(base_key, rids, gens)
-        return (sampled(logits, keys, temps, extras), kc, vc,
-                extras.get("state", {}))
+        toks = sampling.sample_tokens(logits, keys, temps)
+        return (with_counts(toks, extras), kc, vc, extras.get("state", {}),
+                jnp.where(lens > 0, toks, last))
 
     def decode_block(params, base_key, kc, vc, ids, positions, lens, table,
                      rids, gens, temps, state=None):
@@ -1073,14 +1341,19 @@ def _serving_fns(cfg, attn_impl, donate, policy="low_confidence_static"):
             cfg, params, ids, starts, lens, table, kc, vc))
         keys = sampling.request_keys(
             base_key, rids, jnp.zeros_like(rids))
-        return sampled(logits, keys, temps, extras), kc, vc, {}
+        return (with_counts(sampling.sample_tokens(logits, keys, temps),
+                            extras), kc, vc, {})
 
-    # the state pools ride last and are donated with the page pools
-    with_state = lambda n: tuple(donate) + (
-        (n,) if donate and cfg.state_layers else ())
-    fns = (jax.jit(prefill, donate_argnums=with_state(10)),
+    # the state pools ride behind the batch and are donated with the page
+    # pools, and so is the token array of the one-token programs
+    def donated(state_at, tokens_at):
+        return tuple(donate) + (
+            (state_at,) if donate and cfg.state_layers else ()) + (
+            (tokens_at,) if donate and cfg.block_len == 1 else ())
+
+    fns = (jax.jit(prefill, donate_argnums=donated(10, 11)),
            jax.jit(prefill_chunk, donate_argnums=donate),
-           jax.jit(decode, donate_argnums=with_state(11)))
+           jax.jit(decode, donate_argnums=donated(11, 4)))
     with _FN_LOCK:
         # a racing builder may have won; keep the first so every engine
         # shares one executable cache

@@ -160,9 +160,7 @@ class LocalReplica:
                 f"replica {self.index}: servable config does not match "
                 "the running engine's — a weight swap cannot change "
                 "the model shape")
-        old = self.engine.params
-        self.engine.params = self.engine.place(params)
-        return old
+        return self.engine.set_params(params)
 
     def smoke_decode(self, prompt: list[int], n: int) -> list[int]:
         """Greedy-decode ``n`` tokens through the full serving path

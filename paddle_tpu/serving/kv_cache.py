@@ -300,7 +300,16 @@ class PagedKVCache:
     pools the arrays go donated through the jitted programs and come
     back updated in place.  Nothing of it is shareable between requests:
     the prefix trie has no snapshot of it (``ServingEngine`` refuses the
-    combination)."""
+    combination).
+
+    ``tokens``: int32 [max_slots], the last token each slot's sequence
+    sampled — the next decode step's input, kept where it was made: the
+    from-zero prefill programs scatter a row's first token into it, the
+    decode program reads its ``ids`` from it and writes what it samples
+    back (rows that are not decoding keep what they had), so the host
+    never sends a token it only just fetched.  Carried and donated like
+    the pools.  An engine whose model generates by blocks has none
+    (None): its next input is the host's choice of what to unmask."""
 
     def __init__(self, num_layers: int, num_heads: int, head_dim: int,
                  num_pages: int, page_size: int, max_slots: int,
@@ -320,6 +329,7 @@ class PagedKVCache:
             name: jnp.zeros((state_layers, max_slots, *shape), jnp.float32)
             for name, shape in (state_shapes or {}).items()
         } if state_layers else {}
+        self.tokens = jnp.zeros((max_slots,), jnp.int32)
         self.allocator = PageAllocator(num_pages)
         self.page_table = np.zeros((max_slots, max_pages_per_seq), np.int32)
         self._slot_pages: dict[int, list[int]] = {}

@@ -20,6 +20,15 @@ decides WHAT to run; the jitted compute lives in ``engine.py``.  Given a
 seed and an arrival order, the whole trace (admissions, batch
 compositions, sampled tokens) is deterministic; wall-clock enters only
 the telemetry.
+
+The engine keeps one pass in flight (``engine.py``), so a sequence's
+tokens come in two kinds: ``generated``, handed out, and ``in_flight``,
+sampled by a dispatched pass the host has not read yet (:meth:`sent` /
+:meth:`landed`).  Everything a decode step needs but its input token —
+position, context length, sampling-key index — counts both, and the
+reservation made at admission covers both, so the next step's arrays are
+built before the last step's tokens are known.  The input token itself
+never leaves the device (``PagedKVCache.tokens``).
 """
 
 from __future__ import annotations
@@ -159,6 +168,9 @@ class _Active:
     reserved_tokens: int
     generated: list[int] = dataclasses.field(default_factory=list)
     finished: str | None = None  # finish reason once known
+    # tokens a dispatched pass has sampled for this sequence that the host
+    # has not read yet (the engine runs one pass ahead)
+    in_flight: int = 0
     t_admit: float = 0.0
     t_first: float = 0.0
     cached_tokens: int = 0       # prompt tokens mapped from the prefix cache
@@ -176,10 +188,15 @@ class _Active:
         return len(self.request.prompt)
 
     @property
+    def sampled(self) -> int:
+        """Tokens sampled for this sequence so far, read or not."""
+        return len(self.generated) + self.in_flight
+
+    @property
     def next_position(self) -> int:
         """Absolute index of the token the next decode step feeds (the
         last sampled token, not yet in the cache)."""
-        return self.prompt_len + len(self.generated) - 1
+        return self.prompt_len + self.sampled - 1
 
 
 class Scheduler:
@@ -304,12 +321,29 @@ class Scheduler:
 
     # -- token append + retirement --------------------------------------------
     def append_token(self, a: _Active, token: int) -> None:
-        """Record a sampled token; flips ``finished`` on eos/length."""
+        """Hand out a sampled token; flips ``finished`` on eos/length."""
         a.generated.append(token)
         if self.serving.eos_id is not None and token == self.serving.eos_id:
             a.finished = "eos"
         elif len(a.generated) >= a.request.max_new_tokens:
             a.finished = "length"
+
+    def sent(self, rows: list[_Active]) -> None:
+        """A pass that samples one token for each of ``rows`` has been
+        dispatched."""
+        for a in rows:
+            a.in_flight += 1
+
+    def landed(self, a: _Active, token: int) -> bool:
+        """One in-flight token of ``a`` has been read: hand it out — unless
+        the sequence finished meanwhile (an eos is data, seen one pass
+        late: what the surplus pass sampled is dropped).  True when handed
+        out."""
+        a.in_flight -= 1
+        if a.finished:
+            return False
+        self.append_token(a, token)
+        return True
 
     # -- generation by blocks --------------------------------------------------
     def _open_block(self, a: _Active, start: int) -> None:
@@ -367,8 +401,11 @@ class Scheduler:
         """Fixed-shape arrays for one decode step over all live
         sequences, or None when there are none.  Sequences still
         mid-prefill (incremental path: no token sampled yet) are not
-        decoded."""
-        live = [a for a in self.live if a.generated or a.block is not None]
+        decoded, and neither is one whose ``max_new_tokens`` the tokens
+        in flight already reach: it is known finished without reading
+        anything and rides no further pass."""
+        live = [a for a in self.live if a.block is not None
+                or 0 < a.sampled < a.request.max_new_tokens]
         return self.decode_arrays(live) if live else None
 
     def decode_arrays(self, live: list[_Active]) -> dict:
@@ -389,33 +426,28 @@ class Scheduler:
         if self.block_len > 1:
             return self._block_arrays(live)
         n = self.serving.max_slots
-        ids = np.zeros((n,), np.int32)
         positions = np.zeros((n,), np.int32)
         seq_lens = np.zeros((n,), np.int32)
         rids = np.zeros((n,), np.int32)
         gens = np.zeros((n,), np.int32)
         temps = np.zeros((n,), np.float32)
-        decoding = set()
         for a in live:
             i = a.slot
-            decoding.add(i)
-            ids[i] = a.generated[-1]
             positions[i] = a.next_position
             seq_lens[i] = a.next_position + 1
             rids[i] = a.request.id
-            gens[i] = len(a.generated)
+            gens[i] = a.sampled
             temps[i] = a.request.temperature
-        table = self.cache.page_table.copy()
-        for i in range(n):
-            # write_decode_kv's idle-row contract is "all-zero table row
-            # → null page", which mid-prefill slots (mapped pages, no
-            # token yet) would silently break: their masked write at
-            # position 0 would corrupt the first prompt page.  Free
-            # slots are already zeroed, so flag-off this is a no-op.
-            if i not in decoding:
-                table[i, :] = 0
+        # write_decode_kv's idle-row contract is "all-zero table row →
+        # null page", which mid-prefill slots (mapped pages, no token
+        # yet) would silently break: their masked write at position 0
+        # would corrupt the first prompt page.  So only the decoding rows
+        # are copied (a fresh array every step: the transfer may alias it)
+        rows = [a.slot for a in live]
+        table = np.zeros_like(self.cache.page_table)
+        table[rows] = self.cache.page_table[rows]
         return {
-            "ids": ids, "positions": positions, "seq_lens": seq_lens,
+            "positions": positions, "seq_lens": seq_lens,
             "page_table": table,
             "rids": rids, "gens": gens, "temps": temps, "live": live,
         }
@@ -483,6 +515,16 @@ class Scheduler:
                 "rids": rids, "temps": temps, "slots": slots}
 
     # -- incremental prefill (prefix cache / chunked) --------------------------
+    def last_tokens(self) -> np.ndarray:
+        """int32 [max_slots]: the last token handed out for each resident
+        sequence (0 elsewhere) — what the device's token array holds when
+        nothing is in flight."""
+        out = np.zeros((self.serving.max_slots,), np.int32)
+        for a in self.active:
+            if a.generated:
+                out[a.slot] = a.generated[-1]
+        return out
+
     def prefilling(self) -> list[_Active]:
         """Sequences admitted but not yet fully prompt-resident — the
         incremental-prefill work list, slot order (deterministic)."""

@@ -34,9 +34,13 @@ Instrumented phase boundaries (all behind the same flag):
 - ``ServingEngine`` — one ``serve_step`` (``waiting``, ``active``) per
   engine iteration that did work, with ``serve_schedule`` children
   around the calls that build or change scheduler / KV-cache state and
-  the two leaf batch spans ``serve_prefill`` / ``serve_decode``
-  (``batch``; decode also ``context_tokens`` = KV tokens the step's
-  kernel reads, and ``dispatch_ms`` = until the jitted call returned);
+  the two leaf batch spans ``serve_prefill`` / ``serve_decode``, one a
+  pass, closed at the pass's read-back (``batch``, ``dispatch_ms`` = the
+  dispatch call's own time, ``ahead`` = 1 when the pass went out behind
+  an unread one; decode also ``context_tokens`` = KV tokens the step's
+  kernel reads).  The loop runs one pass ahead, so a pass is read by the
+  iteration after the one that dispatched it: its span opens there,
+  after the read-back before it, and spans of one thread never overlap;
   ``serve_step``'s self time is the loop's own Python.  Plus a
   per-request retrospective ``request`` span with ``queue`` /
   ``prefill`` / ``decode`` children reconstructed from the request's

@@ -71,7 +71,9 @@ def test_lock_registry_covers_threaded_subsystems():
     from paddle_tpu.analysis import lock_registry
 
     reg = lock_registry()
-    assert reg["paddle_tpu/serving/engine.py"]["ServingEngine"] == ["_lock"]
+    # _pump: one iteration (or drain) of the step loop at a time
+    assert reg["paddle_tpu/serving/engine.py"]["ServingEngine"] == [
+        "_lock", "_pump"]
     assert "_mesh_lock" in \
         reg["paddle_tpu/reader/prefetch.py"]["DevicePrefetcher"]
     assert reg["paddle_tpu/resilience/elastic.py"]["ElasticCoordinator"] \

@@ -388,6 +388,67 @@ class TestWeightSwap:
         assert [got[r] for r in rids] == [old, old]
 
 
+class TestOnePassAhead:
+    """Every replica's engine keeps one pass in flight; the router's pump
+    is ``ServingEngine.step``."""
+
+    def test_pumped_replicas_serve_the_single_engines_tokens(self, model,
+                                                             rng_np):
+        cfg, params = model
+        reqs = _mixed_requests(rng_np, n=10)
+        reg = MetricsRegistry("fleet_ahead")
+        rids, res, router = _serve(model, n_replicas=3, registry=reg,
+                                   requests=reqs)
+        assert all(not rep.engine._in_flight for rep in router.replicas)
+        assert reg.get("serve_passes_ahead_total").value(kind="decode") > 0
+        assert reg.get("serve_tokens_dropped_total").value() == 0
+        # sampling keys are (seed, request id, token index): one engine
+        # given the fleet's ids serves the fleet's tokens
+        eng = ServingEngine(cfg, params, small_scfg())
+        for rid, (p, n, t) in zip(rids, reqs):
+            eng.submit(p, n, t, request_id=rid)
+        eng.run_until_idle()
+        assert ({r.id: r.tokens for r in eng.results()}
+                == {rid: res[rid].tokens for rid in rids})
+
+    def test_a_drained_replica_has_nothing_in_flight_for_a_swap(self, model,
+                                                                tmp_path):
+        """An eos leaves a surplus pass queued behind it.  The iteration
+        that delivers the request's result also reads that pass (nothing
+        is left to dispatch behind it), so a replica the router sees
+        drained has nothing in flight under the old weights — and what
+        ``set_params`` would read if it had (``tests/test_serving.py``) is
+        nothing here.  The new weights serve."""
+        cfg, params = model
+        params2 = T.init_params(cfg, jax.random.key(2))
+        sv = export_servable(str(tmp_path / "sv"), cfg, params2)
+        prompt, hot = [5, 6, 7], 1.5
+        free = ServingEngine(cfg, params, small_scfg())
+        free.submit(prompt, 6, hot, request_id=0)
+        free.run_until_idle()
+        tokens = free.results()[0].tokens
+        at = next(i for i in (2, 3, 4) if tokens[i] not in tokens[:i])
+        reg = MetricsRegistry("swap_ahead")
+        router = build_local_fleet(cfg, params,
+                                   small_scfg(eos_id=tokens[at]), n=2,
+                                   registry=reg)
+        rid = router.submit(prompt, max_new_tokens=6, temperature=hot)
+        assert rid == 0
+        while router.stats()["delivered"] < 1:
+            router.pump()
+        assert router.results()[0].tokens == tokens[:at + 1]
+        assert reg.get("serve_tokens_dropped_total").value() == 1
+        assert all(not rep.engine._in_flight for rep in router.replicas)
+        assert router.swap_servable(sv) == {0: "swapped", 1: "swapped"}
+        assert all(not rep.engine._in_flight for rep in router.replicas)
+        assert reg.get("serve_loop_drains_total").value(why="swap") == 0
+        ref = ServingEngine(cfg, params2, small_scfg()).generate(
+            [[5, 6, 7]], max_new_tokens=3)[0].tokens
+        rid = router.submit([5, 6, 7], max_new_tokens=3)
+        router.run_until_idle()
+        assert {r.id: r.tokens for r in router.results()}[rid] == ref
+
+
 class TestRouterLifecycle:
     def test_loop_crash_fails_pending_and_refuses_submit(self, model):
         router = build_local_fleet(*model, small_scfg(), n=1,

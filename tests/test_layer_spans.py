@@ -366,7 +366,8 @@ def test_serve_step_holds_schedule_prefill_and_decode(tracer, incremental):
     def counted():
         batch = real()
         if batch is not None:
-            expected.append(sum(a.prompt_len + len(a.generated)
+            # the tokens a pass in flight has sampled are context too
+            expected.append(sum(a.prompt_len + a.sampled
                                 for a in batch["live"]))
         return batch
 
@@ -393,13 +394,73 @@ def test_serve_step_holds_schedule_prefill_and_decode(tracer, incremental):
     if not incremental:     # both prompts resident after one pass
         assert expected[0] == (3 + 1) + (6 + 1)
     for s in by["serve_decode"]:
-        assert 0.0 <= s.args["dispatch_ms"] <= s.dur_ms + 1e-3
+        # the dispatch call's own time; read at once (the incremental
+        # path), a pass's span holds its dispatch
+        assert 0.0 <= s.args["dispatch_ms"]
+        if incremental:
+            assert s.args["dispatch_ms"] <= s.dur_ms + 1e-3
         assert s.args["batch"] >= 1
     # a step's children never overlap: its self time is what is left
     for sid, step in steps.items():
         kids = sorted((s for s in spans if s.parent_id == sid),
                       key=lambda s: s.t_start)
         assert all(a.t_end <= b.t_start for a, b in zip(kids, kids[1:]))
+
+
+@pytest.mark.serving
+@pytest.mark.parametrize("incremental", [False, True])
+def test_one_span_a_pass_and_none_overlap_one_pass_ahead(tracer, incremental):
+    """Three requests through two slots.  The loop dispatches a decode
+    step before it reads the pass before it (``ahead`` = 1) — the
+    incremental prefill path reads every pass at once (0) — and either way
+    there is one ``serve_decode`` leaf a decode step with the args the
+    reducers read, closed at its read-back, and no two batch spans of the
+    loop's thread overlap; the two counters say what the loop did."""
+    reg = MetricsRegistry("ahead_spans")
+    eng = _engine(prefill_batch=2, prefill_chunk_tokens=8 if incremental
+                  else 0)
+    eng.registry = reg
+    for prompt, n in (([5, 17, 3], 3), ([9, 2, 4, 4, 1, 7], 2),
+                      ([8, 8, 1], 2)):
+        eng.submit(prompt, n)
+    eng.run_until_idle()
+    assert sorted(len(r.tokens) for r in eng.results()) == [2, 2, 3]
+    by = _by_name(tracer.spans)
+    dec, pre = by["serve_decode"], by["serve_prefill"]
+    # a row for every token a decode step handed out: A and B together,
+    # then A alone, then C — read at once, B's slot is refilled and C
+    # decodes beside A, one step sooner
+    assert [s.args["batch"] for s in dec] == ([2, 2] if incremental
+                                              else [2, 1, 1])
+    assert len(pre) == 2
+    assert reg.get("serve_decode_step_ms").summary()["count"] == len(dec)
+    assert reg.get("serve_prefill_ms").summary()["count"] == len(pre)
+    for s in dec:
+        assert {"batch", "context_tokens", "kv_block_tokens", "dispatch_ms",
+                "loop_steps", "cache_layers", "ahead"} <= set(s.args)
+    passes = sorted(dec + pre, key=lambda s: s.t_start)
+    assert len({s.thread for s in passes}) == 1
+    assert all(a.t_end <= b.t_start for a, b in zip(passes, passes[1:]))
+    parents = {s.parent_id for s in tracer.spans}
+    assert all(s.span_id not in parents for s in passes)      # leaves
+    value = lambda name, **lab: (reg.get(name).value(**lab)
+                                 if reg.get(name) else 0)
+    if incremental:
+        assert {s.args["ahead"] for s in passes} == {0}
+        assert value("serve_passes_ahead_total", kind="decode") == 0
+        assert value("serve_loop_drains_total",
+                     why="incremental") == len(dec)
+        assert value("serve_loop_drains_total", why="idle") == 0
+    else:
+        # every decode step went out behind an unread pass; the second
+        # prefill pass behind an unread decode step, the first behind none
+        assert [s.args["ahead"] for s in dec] == [1, 1, 1]
+        assert [s.args["ahead"] for s in pre] == [0, 1]
+        assert value("serve_passes_ahead_total", kind="decode") == 3
+        assert value("serve_passes_ahead_total", kind="prefill") == 1
+        assert value("serve_loop_drains_total", why="idle") == 1
+        assert value("serve_loop_drains_total", why="incremental") == 0
+    assert value("serve_tokens_dropped_total") == 0
 
 
 @pytest.mark.serving

@@ -278,6 +278,89 @@ class TestRaggedPagedAttention:
                     got[b_, :, :n], np.asarray(k)[1, b_, :n].swapaxes(0, 1))
 
 
+def _body_primitives(jaxpr, inside=False, out=None):
+    """(name, result dtype kind) of the primitives in the bodies of
+    ``jaxpr``'s loops (``scan`` / ``while``), nested calls included, a
+    Pallas kernel's own body left out."""
+    out = set() if out is None else out
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if inside:
+            out.add((name, eqn.outvars[0].aval.dtype.kind))
+        if name == "pallas_call":
+            continue
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _body_primitives(sub, inside or name in ("scan", "while"),
+                                     out)
+    return out
+
+
+class TestDecodePlan:
+    """What a decode step's cache layers share is made once a step."""
+
+    @pytest.mark.parametrize("heads,head_dim", [(4, 64), (3, 64), (2, 128)])
+    def test_a_planned_write_is_the_one_token_chunk(self, rng_np, heads,
+                                                    head_dim):
+        """With the step's plan, without one and as a chunk of one token
+        (what ``write_decode_kv`` was): the same pools, bit for bit, an
+        idle row's token in the null page."""
+        layers, pages, ps = 3, 12, 4
+        shape = PA.kv_pool_shape(layers, heads, pages, ps, head_dim)
+        kc = jnp.asarray(rng_np.normal(size=shape).astype(np.float32))
+        vc = jnp.asarray(rng_np.normal(size=shape).astype(np.float32))
+        pt = jnp.asarray(np.array([[1, 2, 3], [4, 5, 0], [0, 0, 0]], np.int32))
+        positions, lens = jnp.asarray([6, 4, 0]), jnp.asarray([7, 5, 0])
+        k, v = (jnp.asarray(rng_np.normal(size=(3, heads, head_dim))
+                            .astype(np.float32)) for _ in range(2))
+        plan = PA.decode_plan(kc, pt, positions, lens, heads, head_dim)
+        assert plan.rows.shape == (3, shape[1], 4)
+        want = PA.write_chunk_kv(kc, vc, k[:, None], v[:, None], 1, pt,
+                                 positions, jnp.ones_like(positions))
+        for got in (PA.write_decode_kv(kc, vc, k, v, 1, pt, positions, plan),
+                    PA.write_decode_kv(kc, vc, k, v, 1, pt, positions)):
+            for a, b in zip(got, want):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("kv_heads", [4, 2])
+    def test_the_kernel_takes_the_plans_work_list(self, rng_np, kv_heads):
+        """The interpret-mode kernel over a plan's work list gives what it
+        gives over its own, idle row and grouped query heads included."""
+        lens = np.array([9, 0, 17, 3], np.int32)
+        kc, vc, pt, _, _ = make_paged(rng_np, lens, H=kv_heads, layers=2,
+                                      layer=1)
+        q = jnp.asarray(rng_np.normal(size=(4, 4, 16)).astype(np.float32))
+        kc, vc, pt, lens = (jnp.asarray(x) for x in (kc, vc, pt, lens))
+        plan = PA.decode_plan(kc, pt, jnp.maximum(lens - 1, 0), lens,
+                              kv_heads, 16)
+        run = functools.partial(PA.ragged_paged_attention, q, kc, vc, 1, pt,
+                                lens, impl="kernel", interpret=True,
+                                kv_heads=kv_heads)
+        np.testing.assert_array_equal(np.asarray(run(plan=plan)),
+                                      np.asarray(run()))
+
+    @pytest.mark.parametrize("kind", ["dense", "looped"])
+    def test_the_layer_loop_holds_none_of_the_index_arithmetic(self, kind):
+        """``forward_decode``'s layer loop: no cumulative sum (the work
+        list) and no whole-number division or remainder (page and row of
+        a position, blocks of a length) is left in its body."""
+        cfg = small_cfg(**({"loop_steps": 2} if kind == "looped" else {}))
+        params = T.init_params(cfg, jax.random.key(0))
+        kc, vc = PA.init_kv_pages(cfg.cache_layers, cfg.kv_heads, 9, 4,
+                                  cfg.head_dim)
+        b = 3
+        args = (jnp.zeros(b, jnp.int32), jnp.asarray([5, 0, 2]),
+                jnp.asarray([6, 0, 3]), jnp.zeros((b, 4), jnp.int32))
+        body = _body_primitives(jax.make_jaxpr(
+            lambda *a: T.forward_decode(cfg, params, *a, kc, vc,
+                                        attn_impl="kernel"))(*args).jaxpr)
+        names = {name for name, _ in body}
+        assert {"pallas_call", "scatter"} <= names and "cumsum" not in names
+        assert not body & {("div", "i"), ("rem", "i"), ("floor_divide", "i")}
+
+
 class TestBitExactDecode:
     def test_paged_incremental_equals_full_context_argmax(self, rng_np):
         """The acceptance bit-exactness property: engine tokens (paged
@@ -1203,3 +1286,359 @@ class TestKvPoolPreflightGate:
                 max_prompt_len=16, max_new_tokens=8))
         finally:
             flags.set("hbm_gb", old)
+
+
+# -- one pass ahead ---------------------------------------------------------------
+# The loop dispatches pass n + 1 before it reads pass n: a step's input
+# token stays on the device, the scheduler counts tokens in flight.  Same
+# tokens, request for request, as a plain forward of the same model.
+
+_AHEAD_CFGS = {
+    "dense": dict(),
+    "looped": dict(loop_steps=2, norm="rms", positions="rotary",
+                   mlp="swiglu"),
+    # a toy M / E / * pattern: recurrent state by slot beside the pages,
+    # routing counts riding out behind the tokens
+    "pattern": dict(
+        vocab_size=97, num_layers=3, num_heads=4, kv_heads=2, head_dim=8,
+        embed_dim=32, mlp_dim=24, norm="rms", positions="none", mlp="relu2",
+        tie_embeddings=False, pattern="ME*", moe_experts=8,
+        moe_router="sigmoid", moe_top_k=2, moe_scale=2.5, moe_shared_dim=40,
+        moe_held=[0, 8], mamba_heads=4, mamba_head_dim=8, mamba_state=16,
+        mamba_groups=2, mamba_conv=4, mamba_chunk=8),
+}
+_PAD = 32   # every plain forward at one shape (causal: the tail is unseen)
+
+
+@functools.lru_cache(maxsize=None)
+def _ahead_model(kind):
+    cfg = small_cfg(**_AHEAD_CFGS[kind])
+    return cfg, T.init_params(cfg, jax.random.key(7))
+
+
+def _plain_generation(cfg, params, prompt, n, rid, temperature, seed):
+    """``n`` tokens after ``prompt``, one full forward a token: token i of
+    request ``rid`` under ``fold_in(fold_in(key(seed), rid), i)``."""
+    from paddle_tpu.serving.sampling import request_keys, sample_tokens
+
+    fwd = _plain_forward(cfg)
+    seq, out = list(prompt), []
+    for i in range(n):
+        ids = jnp.asarray([seq + [0] * (_PAD - len(seq))])
+        logits = fwd(params, ids)[0, len(seq) - 1][None]
+        keys = request_keys(jax.random.key(seed),
+                            jnp.asarray([rid], jnp.int32),
+                            jnp.asarray([i], jnp.int32))
+        tok = int(sample_tokens(logits, keys,
+                                jnp.asarray([temperature], jnp.float32))[0])
+        out.append(tok)
+        seq.append(tok)
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def _plain_forward(cfg):
+    return jax.jit(functools.partial(T.forward, cfg))
+
+
+def _ahead_engine(kind, reg=None, **kw):
+    cfg, params = _ahead_model(kind)
+    serving = dict(max_slots=3, page_size=4, num_pages=48, max_prompt_len=12,
+                   max_new_tokens=8, prefill_batch=2, seed=5)
+    serving.update(kw)
+    return ServingEngine(cfg, params, ServingConfig(**serving),
+                         registry=reg or MetricsRegistry("ahead"))
+
+
+def _in_flight(eng):
+    return len(eng._in_flight)
+
+
+class TestOnePassAhead:
+    @pytest.mark.parametrize("temperature", [0.0, 0.9],
+                             ids=["greedy", "seeded"])
+    @pytest.mark.parametrize("kind", list(_AHEAD_CFGS))
+    def test_generations_equal_a_plain_forward(self, kind, temperature,
+                                               rng_np):
+        """Seven requests through three slots, two rows a prefill pass,
+        arriving while others decode and finishing at different steps: each
+        gets the tokens a token-by-token forward of the model gives it."""
+        cfg, params = _ahead_model(kind)
+        eng = _ahead_engine(kind)
+        lens, news = (3, 9, 12, 1, 6, 5, 10), (8, 3, 5, 1, 7, 2, 6)
+        prompts = [[int(t) for t in rng_np.integers(1, cfg.vocab_size, n)]
+                   for n in lens]
+        ids = [eng.submit(p, n, temperature)
+               for p, n in zip(prompts[:2], news[:2])]
+        for _ in range(3):      # the first two are decoding
+            assert eng.step()
+        ids += [eng.submit(p, n, temperature)
+                for p, n in zip(prompts[2:5], news[2:5])]
+        for _ in range(2):
+            assert eng.step()
+        ids += [eng.submit(p, n, temperature)
+                for p, n in zip(prompts[5:], news[5:])]
+        eng.run_until_idle()
+        assert _in_flight(eng) == 0
+        got = {r.id: r for r in eng.results()}
+        for rid, prompt, n in zip(ids, prompts, news):
+            assert got[rid].finish_reason == "length"
+            assert got[rid].tokens == _plain_generation(
+                cfg, params, prompt, n, rid, temperature, seed=5), (kind, rid)
+
+    def test_an_eos_is_seen_one_pass_late(self, rng_np):
+        """The pass after the one that sampled an eos is already queued
+        when the eos is read: that row's surplus token is dropped and
+        counted, never handed out, and nobody else can tell — tokens, page
+        tables and the K/V every other sequence wrote are what a run gives
+        in which the request ends at that token by LENGTH (known without a
+        read: no surplus pass)."""
+        cfg, params = _ahead_model("dense")
+        prompts = [[int(t) for t in rng_np.integers(1, 64, n)]
+                   for n in (9, 5, 11, 7)]
+        hot = 1.5       # sampled, not greedy: a drawn toy repeats itself
+        eng = _ahead_engine("dense")
+        for p in prompts:
+            eng.submit(p, 6, hot)
+        eng.run_until_idle()
+        free = sorted(eng.results(), key=lambda r: r.id)
+        # request 1 ends at its 4th or 5th token; page_size 8: (5 + 6),
+        # (5 + 4) and (5 + 5) tokens reserve the same two pages
+        others = {t for r in free if r.id != 1 for t in r.tokens}
+        at = next(i for i in (3, 4) if free[1].tokens[i] not in others
+                  and free[1].tokens[i] not in free[1].tokens[:i])
+        eos = free[1].tokens[at]
+
+        def run(**kw):
+            reg = MetricsRegistry("eos_late")
+            eng = _ahead_engine("dense", reg, page_size=8, **kw)
+            handed, rows = [], {}
+            inner, admit = eng.scheduler.append_token, eng.scheduler.admit
+
+            def watch(a, token):
+                handed.append((a.request.id, len(a.generated), token))
+                inner(a, token)
+
+            def admitted(now=0.0):
+                out = admit(now=now)
+                for a in out:
+                    rows[a.request.id] = eng.cache.page_table[a.slot].copy()
+                return out
+
+            eng.scheduler.append_token = watch
+            eng.scheduler.admit = admitted
+            news = [6, at + 1 if "eos_id" not in kw else 6, 6, 6]
+            for p, n in zip(prompts, news):
+                eng.submit(p, n, hot)
+            eng.run_until_idle()
+            res = {r.id: r for r in eng.results()}
+            return eng, reg, res, handed, rows
+
+        late, reg, got, handed, rows = run(eos_id=eos)
+        base, reg0, want, handed0, rows0 = run()
+        assert got[1].finish_reason == "eos"
+        assert want[1].finish_reason == "length"
+        assert got[1].tokens == free[1].tokens[:at + 1] == want[1].tokens
+        # every token once, in order, with ``generated`` what it was
+        # before it; the surplus one never
+        assert handed == handed0
+        for rid in (0, 2, 3):
+            assert got[rid].tokens == free[rid].tokens == want[rid].tokens
+        assert reg.get("serve_tokens_dropped_total").value() == 1
+        assert reg0.get("serve_tokens_dropped_total").value() == 0
+        assert reg.get("serve_tokens").value() == 6 * 3 + at + 1
+        # the surplus row did ride one decode step more
+        layers = cfg.cache_layers
+        assert (reg.get("serve_layer_passes_total").value()
+                == reg0.get("serve_layer_passes_total").value() + layers)
+        # the others' page tables, and their K/V wherever they wrote it
+        k, v = np.asarray(late.cache.k), np.asarray(late.cache.v)
+        k0, v0 = np.asarray(base.cache.k), np.asarray(base.cache.v)
+        for rid in (0, 2, 3):
+            np.testing.assert_array_equal(rows[rid], rows0[rid])
+            written = len(prompts[rid]) + len(got[rid].tokens) - 1
+            for pos in range(written):
+                page, off = rows[rid][pos // 8], pos % 8
+                np.testing.assert_array_equal(k[:, :, page, off],
+                                              k0[:, :, page, off])
+                np.testing.assert_array_equal(v[:, :, page, off],
+                                              v0[:, :, page, off])
+        assert late.cache.allocator.free_pages == 47
+
+    def test_a_finish_by_length_needs_no_read(self, rng_np):
+        """A sequence whose ``max_new_tokens`` the tokens in flight reach
+        rides no further pass: decode steps run one row-layer for every
+        token they hand out, nothing is dropped."""
+        reg = MetricsRegistry("by_length")
+        eng = _ahead_engine("dense", reg)
+        cfg = eng.cfg
+        news = (1, 2, 5, 8, 3)
+        eng.generate([[int(t) for t in rng_np.integers(1, 64, 6)]
+                      for _ in news], max_new_tokens=None)
+        # generate() asks every request for the engine's cap: ask again
+        reg = eng.registry = MetricsRegistry("by_length_2")
+        decoded = []
+        inner = eng.scheduler.append_token
+
+        def watch(a, token):
+            decoded.append(bool(a.generated))
+            inner(a, token)
+
+        eng.scheduler.append_token = watch
+        for n in news:
+            eng.submit([int(t) for t in rng_np.integers(1, 64, 6)], n)
+        eng.run_until_idle()
+        assert sorted(len(r.tokens) for r in eng.results()) == sorted(news)
+        assert reg.get("serve_tokens").value() == sum(news)
+        assert sum(decoded) == sum(n - 1 for n in news)
+        assert (reg.get("serve_layer_passes_total").value()
+                == sum(decoded) * cfg.cache_layers)
+        assert reg.get("serve_tokens_dropped_total").value() == 0
+
+    def test_an_engine_with_a_pass_in_flight_is_not_idle(self, rng_np):
+        """``step()`` is True while a pass is unread; ``run_until_idle``,
+        ``stop()`` and a weight swap (``set_params``) leave none."""
+        reg = MetricsRegistry("in_flight")
+        eng = _ahead_engine("dense", reg)
+        drains = lambda why: (reg.get("serve_loop_drains_total").value(why=why)
+                              if reg.get("serve_loop_drains_total") else 0)
+        eng.submit([3, 1, 4], 2)
+        # prefill and the first decode step go out; the prefill is read
+        assert eng.step() and _in_flight(eng) == 1
+        assert len(eng.scheduler.slots[0].generated) == 1
+        assert eng.step() and _in_flight(eng) == 0      # the step is read
+        assert eng.scheduler.slots[0].finished == "length"
+        assert drains("idle") == 1 and not eng.results()
+        assert eng.step() and not eng.step()            # retired: delivered
+        assert [len(r.tokens) for r in eng.results()] == [2]
+        # a swap reads what the old weights left in flight
+        eng.submit([2, 7, 1, 8], 3)
+        assert eng.step() and _in_flight(eng) == 1
+        eng.set_params(eng.params)
+        assert _in_flight(eng) == 0 and drains("swap") == 1
+        assert len(eng.scheduler.slots[0].generated) == 2
+        eng.run_until_idle()
+        assert _in_flight(eng) == 0
+        assert [len(r.tokens) for r in eng.results()] == [3]
+        # stop() reads too, and delivers what that finishes
+        eng.submit([5, 9, 2], 2)
+        assert eng.step() and _in_flight(eng) == 1 and not eng.results()
+        eng.stop()
+        assert _in_flight(eng) == 0 and drains("stop") == 1
+        assert [len(r.tokens) for r in eng.results()] == [2]
+        assert eng.cache.allocator.free_pages == 47
+
+    def test_a_background_loop_leaves_nothing_in_flight(self, rng_np):
+        eng = _ahead_engine("dense")
+        eng.start()
+        try:
+            ids = [eng.submit([int(t) for t in rng_np.integers(1, 64, 5)], n)
+                   for n in (4, 1, 6, 2, 8)]
+            got = eng.results(n=5, timeout=120.0)
+        finally:
+            eng.stop()
+        assert sorted(r.id for r in got) == ids and _in_flight(eng) == 0
+        assert sorted(len(r.tokens) for r in got) == [1, 2, 4, 6, 8]
+
+    def test_drains_from_another_thread_race_nothing(self, rng_np):
+        """``set_params`` (a weight swap's drain) from the caller's thread while
+        the background loop runs one pass ahead: every pass is read once,
+        every request gets the tokens it gets alone."""
+        import sys
+        import threading
+
+        cfg, params = _ahead_model("dense")
+        prompts = [[int(t) for t in rng_np.integers(1, 64, 4 + i % 5)]
+                   for i in range(12)]
+        news = [2 + i % 6 for i in range(12)]
+        eng = _ahead_engine("dense")
+        swaps, done = [0], threading.Event()
+
+        def swapper():
+            while not done.is_set():
+                eng.set_params(eng.params)
+                swaps[0] += 1
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        t = threading.Thread(target=swapper)
+        eng.start()
+        try:
+            t.start()
+            ids = [eng.submit(p, n) for p, n in zip(prompts, news)]
+            got = eng.results(n=len(ids), timeout=120.0)
+        finally:
+            done.set()
+            t.join(timeout=60.0)
+            eng.stop()
+            sys.setswitchinterval(interval)
+        assert not t.is_alive() and swaps[0] > 0 and _in_flight(eng) == 0
+        assert sorted(r.id for r in got) == ids
+        by_id = {r.id: r.tokens for r in got}
+        for rid, prompt, n in zip(ids, prompts, news):
+            assert by_id[rid] == _plain_generation(
+                cfg, params, prompt, n, rid, 0.0, seed=5)
+
+    def test_a_failing_pass_fails_the_pending_requests(self, rng_np):
+        """A device error surfaces where the pass is read, one pass late:
+        it still kills the loop, which fails every pending request."""
+        reg = MetricsRegistry("late_fault")
+        eng = _ahead_engine("dense", reg)
+        boom = RuntimeError("injected device fault")
+        real, reads = eng._split_counts, []
+
+        def read(out, rows, where):
+            reads.append(where)
+            if where == "decode":
+                raise boom
+            return real(out, rows, where)
+
+        eng._split_counts = read
+        eng.submit([1, 2, 3], 4)
+        eng.submit([4, 5, 6], 4)
+        eng.start()
+        try:
+            with pytest.raises(RuntimeError,
+                               match="serving loop crashed") as ei:
+                eng.results(n=1, timeout=60.0)
+            assert ei.value.__cause__ is boom
+            with pytest.raises(RuntimeError, match="submit refused"):
+                eng.submit([1, 2, 3], 2)
+        finally:
+            eng.stop()
+        # the prefill was read and handed out before the fault was met
+        assert reads[0] == "prefill" and reads.count("decode") == 1
+        assert reg.counter("serve_loop_crashes", "").value() == 1.0
+        assert _in_flight(eng) == 0
+
+    @pytest.mark.parametrize("via", ["step", "run_until_idle", "set_params",
+                                     "stop"])
+    def test_a_failing_pass_is_lost_for_every_caller(self, via, rng_np):
+        """No loop thread: whoever meets the failure — a caller stepping by
+        hand, a swap's drain, ``stop()`` — the step dispatched behind the
+        failed one is lost with it.  What comes next (a step, a swap, a
+        second ``stop()``) reads neither again."""
+        eng = _ahead_engine("dense")
+        boom = RuntimeError("injected device fault")
+        real, reads = eng._split_counts, []
+
+        def read(out, rows, where):
+            reads.append(where)
+            if where == "decode":
+                raise boom
+            return real(out, rows, where)
+
+        eng._split_counts = read
+        eng.submit([1, 2, 3], 6)
+        assert eng.step() and _in_flight(eng) == 1     # the first decode step
+        meet = {"step": eng.step, "run_until_idle": eng.run_until_idle,
+                "set_params": lambda: eng.set_params(eng.params),
+                "stop": eng.stop}[via]
+        with pytest.raises(RuntimeError) as ei:
+            meet()
+        assert ei.value is boom and _in_flight(eng) == 0
+        # a stepping caller had the next step out behind the failed one
+        assert reads == ["prefill", "decode"]
+        eng.set_params(eng.params)
+        eng.stop()
+        assert reads == ["prefill", "decode"] and _in_flight(eng) == 0
