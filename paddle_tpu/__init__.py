@@ -25,6 +25,10 @@ A from-scratch reimplementation of the capabilities of 2017-era PaddlePaddle
 __version__ = "0.1.0"
 
 import importlib as _importlib
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter()  # with the reading at the bottom: the
+                                   # import_paddle_tpu set-up span
 
 from paddle_tpu.core import flags  # noqa: F401
 from paddle_tpu.core.place import (  # noqa: F401
@@ -105,3 +109,12 @@ def infer(output_layer, parameters, input, feeding=None, field="value"):
         feeding=feeding,
         field=field,
     )
+
+
+# XLA's build events (trace / lower / compile / cache fetch) are heard from
+# here on: one listener a process (telemetry/tracing.py).  jax is loaded
+# by now (core.place), so this costs the telemetry package's own import.
+from paddle_tpu.telemetry import tracing as _tracing  # noqa: E402
+
+_tracing.install_xla_listener()
+_IMPORT_WINDOW = (_IMPORT_T0, _time.perf_counter())

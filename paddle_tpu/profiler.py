@@ -127,7 +127,7 @@ def read_device_trace(logdir: str):
     where op_events are the per-HLO-op events of the device's "XLA Ops"
     thread (dur_us, model_flops, raw_bytes_accessed, tf_op, source) and
     module_ms sums the "XLA Modules" thread — the device-side wall time.
-    Single implementation shared by device_step_ms and tools/xprof.py."""
+    ``device_step_ms`` reads the second."""
     import glob
     import gzip
     import json

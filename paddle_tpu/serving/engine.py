@@ -111,9 +111,28 @@ from paddle_tpu.serving.scheduler import (
     Scheduler,
     ServingConfig,
 )
+from paddle_tpu.telemetry import tracing
+from paddle_tpu.telemetry.registry import geometric_buckets
 
-_LAT_HISTS = ("serve_queue_wait_ms", "serve_prefill_ms",
-              "serve_decode_step_ms", "serve_ttft_ms", "serve_tpot_ms")
+# the latency histograms whose quantiles are read (the router's and the
+# autoscaler's TTFT p99, SLO checks, the benchmark's per-layer metrics):
+# geometric edges, so a quantile is within 5% of the observations around
+# it at any magnitude (DEFAULT_BUCKETS' ... 10, 25, 50 ... put a 9.1 ms
+# step at 7.3 and a 43.5 ms one at 34.5)
+_LATENCY_BUCKETS = geometric_buckets(0.1, 60_000.0, 1.05)
+_LATENCY_HELP = {
+    "serve_queue_wait_ms": "request wait between arrival and admission",
+    "serve_prefill_ms": "prefill pass wall ms (per admitted batch)",
+    "serve_decode_step_ms": "one continuous-batching decode step, wall ms",
+    "serve_ttft_ms": "time to first token",
+    "serve_tpot_ms": "mean per-token decode latency",
+}
+_LAT_HISTS = tuple(_LATENCY_HELP)
+
+
+def _latency(registry, name: str):
+    return registry.histogram(name, _LATENCY_HELP[name],
+                              buckets=_LATENCY_BUCKETS)
 
 
 def drain_results(completed: "queue.Queue", loop_error_now, what: str,
@@ -183,7 +202,19 @@ class _Pass:
     span: object = None  # its open span
 
 
+def _resident_bytes(engine, _) -> dict:
+    """What a new engine put on its device (``engine_init``'s args)."""
+    import jax
+
+    nbytes = lambda tree: sum(int(x.nbytes) for x in jax.tree.leaves(tree))
+    cache = engine.cache
+    return {"params_bytes": nbytes(engine.params),
+            "pool_bytes": nbytes((cache.k, cache.v)),
+            "state_bytes": nbytes(cache.state)}
+
+
 class ServingEngine:
+    @tracing.setup_span("engine_init", _resident_bytes)
     def __init__(self, cfg, params, serving: ServingConfig | None = None,
                  registry=None, device=None):
         """``cfg``: TransformerConfig; ``params``: the matching pytree
@@ -620,9 +651,7 @@ class ServingEngine:
         blocks) and a block pass (``_block_pass``) 0 to ``block_len``
         tokens a sequence: all of a block's at once, when the pass that
         found nothing masked has committed it."""
-        from paddle_tpu.telemetry.tracing import get_tracer
-
-        tracer = get_tracer()
+        tracer = tracing.get_tracer()
         with self._pump:
             tk = None
             if tracer.enabled:
@@ -671,36 +700,50 @@ class ServingEngine:
         pools, state, token array and page table are what they were.
         Replicas share the jitted functions, so a fleet on one device
         traces, lowers and compiles once.  The incremental path has one
-        prefill shape, compiled by its first pass as before."""
-        t0 = time.perf_counter()
+        prefill shape, compiled by its first pass as before.
+
+        One ``engine_ready`` set-up span around one ``program_ready`` a
+        program (``program``, ``rows``, ``length``: a prefill shape, or
+        the decode step's slots x positions a pass), each with XLA's own
+        trace / lower / compile-or-fetch under it
+        (``tracing.XlaBuildListener``); the log lines read the same
+        clock readings."""
         cache, sched = self.cache, self.scheduler
         rows = () if self.serving.incremental_prefill else sched.prefill_rows
+        length = self.serving.max_prompt_len
+        tracer = tracing.get_tracer()
         programs = {}
-        for n in rows:
-            t1 = time.perf_counter()
-            args = self._dev(sched.prefill_arrays([], n), "ids", "seq_lens",
-                             "page_table", "rids", "temps", "slots")
-            programs[n] = self._prefill.lower(
-                self._params(), self._base_key, cache.k, cache.v, *args,
-                cache.state, cache.tokens).compile()
-            log.debug("prefill program of %d row(s) ready after %.2f s", n,
-                      time.perf_counter() - t1)
-        batch = sched.decode_arrays([])
-        if self._block > 1:
-            args = self._dev(batch, "ids", "positions", "seq_lens",
-                             "page_table", "rids", "gens", "temps")
-        else:   # a step's ids are the token array, which the device keeps
-            args = [cache.tokens] + self._dev(
-                batch, "positions", "seq_lens", "page_table", "rids", "gens",
-                "temps")
-        programs["decode"] = self._decode.lower(
-            self._params(), self._base_key, cache.k, cache.v, *args,
-            cache.state).compile()
+        with tracer.timed("engine_ready", programs=len(rows) + 1) as ready:
+            for n in rows:
+                with tracer.timed("program_ready", program="prefill",
+                                  rows=n, length=length) as one:
+                    args = self._dev(sched.prefill_arrays([], n), "ids",
+                                     "seq_lens", "page_table", "rids",
+                                     "temps", "slots")
+                    programs[n] = self._prefill.lower(
+                        self._params(), self._base_key, cache.k, cache.v,
+                        *args, cache.state, cache.tokens).compile()
+                log.debug("prefill program of %d row(s) ready after %.2f s",
+                          n, one.seconds)
+            with tracer.timed("program_ready", program="decode",
+                              rows=self.serving.max_slots,
+                              length=self._block):
+                batch = sched.decode_arrays([])
+                if self._block > 1:
+                    args = self._dev(batch, "ids", "positions", "seq_lens",
+                                     "page_table", "rids", "gens", "temps")
+                else:   # a step's ids are the token array, the device's
+                    args = [cache.tokens] + self._dev(
+                        batch, "positions", "seq_lens", "page_table", "rids",
+                        "gens", "temps")
+                programs["decode"] = self._decode.lower(
+                    self._params(), self._base_key, cache.k, cache.v, *args,
+                    cache.state).compile()
         with self._lock:    # all or none: a failure is met again
             self._programs = programs
         log.info("serving engine ready: %d prefill program(s) of %s row(s) x "
                  "%d + decode in %.2f s; %s, %s", len(rows), list(rows),
-                 self.serving.max_prompt_len, time.perf_counter() - t0,
+                 length, ready.seconds,
                  "a block's input is the host's" if self._block > 1 else
                  f"the last token of {self.serving.max_slots} slot(s) stays "
                  "on the device (the token array)",
@@ -890,10 +933,7 @@ class ServingEngine:
             behind.t_from = now
         handed = 0
         if p.kind == "decode":
-            reg.histogram(
-                "serve_decode_step_ms",
-                "one continuous-batching decode step, wall ms").observe(
-                    took_ms)
+            _latency(reg, "serve_decode_step_ms").observe(took_ms)
             for a in p.rows:
                 handed += sched.landed(a, int(toks[a.slot]))
             reg.counter("serve_tokens_dropped_total",
@@ -901,22 +941,17 @@ class ServingEngine:
                         "block's surplus, what followed an eos)").inc(
                             len(p.rows) - handed)
         else:
-            reg.histogram("serve_prefill_ms",
-                          "prefill pass wall ms (per admitted batch)"
-                          ).observe(took_ms)
+            _latency(reg, "serve_prefill_ms").observe(took_ms)
             for j, a in enumerate(p.rows):
-                reg.histogram(
-                    "serve_queue_wait_ms",
-                    "request wait between arrival and admission").observe(
-                        (a.t_admit - a.request.arrival) * 1e3)
+                _latency(reg, "serve_queue_wait_ms").observe(
+                    (a.t_admit - a.request.arrival) * 1e3)
                 if self._block > 1:
                     # nothing sampled: the first block's commit hands out
                     # the first token
                     continue
                 a.t_first = now
-                reg.histogram(
-                    "serve_ttft_ms", "time to first token").observe(
-                        (now - a.request.arrival) * 1e3)
+                _latency(reg, "serve_ttft_ms").observe(
+                    (now - a.request.arrival) * 1e3)
                 handed += sched.landed(a, int(toks[j]))
         reg.counter("serve_tokens", "tokens generated").inc(handed)
 
@@ -937,9 +972,7 @@ class ServingEngine:
     def _drain(self, why: str) -> bool:
         """:meth:`_settle` from outside an iteration (``stop``,
         ``set_params``)."""
-        from paddle_tpu.telemetry.tracing import get_tracer
-
-        tracer = get_tracer()
+        tracer = tracing.get_tracer()
         with self._pump:
             try:
                 return self._settle(tracer, why)
@@ -994,9 +1027,8 @@ class ServingEngine:
             commits += 1
             if not a.generated:
                 a.t_first = time.perf_counter()
-                reg.histogram(
-                    "serve_ttft_ms", "time to first token").observe(
-                        (a.t_first - a.request.arrival) * 1e3)
+                _latency(reg, "serve_ttft_ms").observe(
+                    (a.t_first - a.request.arrival) * 1e3)
             for token in ready:
                 if a.finished:
                     dropped += 1
@@ -1010,10 +1042,8 @@ class ServingEngine:
                 masked_in=masked_in, unmasked=int(unmasked.sum()),
                 committed=commits, commit_rows=commits, tokens_out=handed,
                 **counts)
-        reg.histogram(
-            "serve_decode_step_ms",
-            "one continuous-batching decode step, wall ms").observe(
-                (time.perf_counter() - t0) * 1e3)
+        _latency(reg, "serve_decode_step_ms").observe(
+            (time.perf_counter() - t0) * 1e3)
         reg.counter("serve_tokens", "tokens generated").inc(handed)
         reg.counter(
             "serve_layer_passes_total",
@@ -1048,10 +1078,8 @@ class ServingEngine:
         prefix cache for later requests to share."""
         sched = self.scheduler
         for a in admitted:
-            reg.histogram(
-                "serve_queue_wait_ms",
-                "request wait between arrival and admission").observe(
-                    (a.t_admit - a.request.arrival) * 1e3)
+            _latency(reg, "serve_queue_wait_ms").observe(
+                (a.t_admit - a.request.arrival) * 1e3)
             if a.cached_tokens:
                 reg.counter(
                     "serve_prefix_hit_tokens",
@@ -1079,9 +1107,7 @@ class ServingEngine:
         if tk is not None:
             tracer.end(tk, **counts)
         t1 = time.perf_counter()
-        reg.histogram("serve_prefill_ms",
-                      "prefill pass wall ms (per admitted batch)").observe(
-                          (t1 - t0) * 1e3)
+        _latency(reg, "serve_prefill_ms").observe((t1 - t0) * 1e3)
         reg.counter("serve_prefill_chunks",
                     "incremental prefill passes (chunk or cached "
                     "tail)").inc(len(rows))
@@ -1097,9 +1123,8 @@ class ServingEngine:
                 # the pass's last-valid logits are this row's first-
                 # token logits: its prompt is fully resident now
                 a.t_first = t1
-                reg.histogram(
-                    "serve_ttft_ms", "time to first token").observe(
-                        (t1 - a.request.arrival) * 1e3)
+                _latency(reg, "serve_ttft_ms").observe(
+                    (t1 - a.request.arrival) * 1e3)
                 reg.counter("serve_tokens", "tokens generated").inc(1)
                 sched.append_token(a, int(toks[j]))
                 seeded = True
@@ -1119,9 +1144,7 @@ class ServingEngine:
         ttft_ms = (a.t_first - a.request.arrival) * 1e3
         tpot_ms = ((now - a.t_first) / max(n - 1, 1)) * 1e3
         total_ms = (now - a.request.arrival) * 1e3
-        from paddle_tpu.telemetry.tracing import get_tracer
-
-        tracer = get_tracer()
+        tracer = tracing.get_tracer()
         if tracer.enabled:
             # the request's lifecycle, reconstructed retrospectively at
             # retire time from its own timestamps: one parent "request"
@@ -1138,9 +1161,7 @@ class ServingEngine:
                             cat="serving", parent_id=parent, request=rid)
             tracer.add_span("decode", a.t_first, now, cat="serving",
                             parent_id=parent, request=rid)
-        self.registry.histogram(
-            "serve_tpot_ms", "mean per-token decode latency").observe(
-                tpot_ms)
+        _latency(self.registry, "serve_tpot_ms").observe(tpot_ms)
         self.registry.counter(
             "serve_requests", "completed requests").inc(
                 1.0, reason=a.finished)

@@ -16,8 +16,18 @@ badput buckets:
 ``fence``
     device sync at flush boundaries (``fence`` spans);
 ``recompile``
-    ``compute`` spans stamped ``compile=True`` by the trainer when the
-    dispatch built a new executable for an unseen signature;
+    ``compute`` spans stamped ``compile=True`` by the trainer — the
+    dispatch under which XLA's backend compiled a program
+    (``xla_compile`` events heard by ``tracing.XlaBuildListener``; a
+    fetch from the persistent cache is not one) — and ``xla_compile``
+    spans outside any ``compute`` (a set-up phase that compiled);
+``startup``
+    set-up on the train loop's thread: ``cat="setup"`` spans
+    (``train_setup`` from the top of ``train()`` to the first step
+    around ``build_step`` / ``place_state`` / ``params_sync``, the copy
+    back at a pass's end) and every ``xla_cache_fetch``, each second
+    once: what a nested ``restore`` or ``xla_compile`` booked is not
+    booked again;
 ``checkpoint_save`` / ``checkpoint_restore``
     cursor/final checkpoint writes (``checkpoint`` spans) and state
     restores (the trainer's retrospective ``restore`` span, cut from
@@ -34,7 +44,7 @@ badput buckets:
     and the rebuild itself (``gather``/``reshard``/``rebuild`` spans);
 ``idle``
     whatever remains: wall-clock not covered by any classified span
-    (build/placement before step 0, pass turnaround, ring overflow).
+    (pass turnaround, ring overflow; set-up only with no span on it).
 
 The ledger is a **fold over signals that already exist** — tracewire
 spans and resilience counters.  It introduces no clocks of its own, so
@@ -47,7 +57,8 @@ account — only spans older than one whole ring per fold interval can
 drop, and the closing record carries the tracer's drop counter so a
 truncated account is visible, not silent.
 
-``finish()`` emits one ``kind="ledger"`` telemetry record (schema /12)
+``finish()`` emits one ``kind="ledger"`` telemetry record (schema /12;
+``startup`` since /16)
 with the bucket seconds, ``goodput_fraction`` (= compute / wall), the
 serving cost split when serving counters are present (prefill/decode
 compute-seconds, queue-seconds, KV-page occupancy-seconds,
@@ -86,15 +97,18 @@ _LEAF_BUCKET = {
     "rebuild": "elastic_reshard",
 }
 
-BADPUT_BUCKETS = ("input_wait", "fence", "recompile", "checkpoint_save",
-                  "checkpoint_restore", "guard_rescue", "restart",
-                  "elastic_drain", "elastic_reshard", "idle")
+BADPUT_BUCKETS = ("input_wait", "fence", "recompile", "startup",
+                  "checkpoint_save", "checkpoint_restore", "guard_rescue",
+                  "restart", "elastic_drain", "elastic_reshard", "idle")
 BUCKETS = ("compute",) + BADPUT_BUCKETS
 
 # restore intervals remembered for the nested-in-guard_rescue
 # subtraction; a run with more restores than this merely double-counts
 # the excess into guard_rescue instead of growing without bound
 _MAX_RESTORE_INTERVALS = 256
+# ... and the disjoint intervals set-up has booked (the tracer keeps at
+# most tracing.KEPT_MAX such spans; restores add theirs)
+_MAX_BOOKED_INTERVALS = 1024
 
 
 class GoodputLedger:
@@ -126,6 +140,11 @@ class GoodputLedger:
         self._buckets = {b: 0.0 for b in BUCKETS}
         self._seen_ids: set[int] = set()   # span ids of the last fold
         self._restores: list[tuple[float, float]] = []
+        # what set-up spans, restores and stand-alone compiles have
+        # booked, as sorted disjoint intervals: spans nest there, and
+        # each second goes to the innermost one that claims it
+        self._booked: list[tuple[float, float]] = []
+        self._thread: str | None = None    # the train loop's, at start()
         self._restarts_seen = 0.0
         self._spans_folded = 0
         self._t0: float | None = None
@@ -135,6 +154,7 @@ class GoodputLedger:
     def start(self) -> "GoodputLedger":
         with self._lock:
             self._t0 = self.clock()
+            self._thread = threading.current_thread().name
         return self
 
     @property
@@ -143,12 +163,50 @@ class GoodputLedger:
             return self._t0 is not None
 
     # -- the fold --------------------------------------------------------------
+    def _claim(self, t0: float, t1: float) -> float:
+        """Seconds of ``[t0, t1]`` (cut to the run) that no earlier claim
+        holds; the interval then holds them."""
+        t0 = max(t0, self._t0)
+        if t1 <= t0:
+            return 0.0
+        free, merged, lo, hi = t1 - t0, [], t0, t1
+        for b0, b1 in self._booked:
+            if b1 < t0 or b0 > t1:
+                merged.append((b0, b1))
+                continue
+            free -= max(0.0, min(b1, t1) - max(b0, t0))
+            lo, hi = min(lo, b0), max(hi, b1)
+        if len(merged) < _MAX_BOOKED_INTERVALS:
+            merged.append((lo, hi))
+            merged.sort()
+            self._booked = merged
+        return max(free, 0.0)
+
+    def _held(self, t0: float, t1: float) -> float:
+        """Seconds of ``[t0, t1]`` that set-up's claims hold."""
+        return sum(max(0.0, min(b1, t1) - max(b0, t0))
+                   for b0, b1 in self._booked)
+
     def _classify(self, span) -> None:
         dur = max(0.0, span.t_end - span.t_start)
         name = span.name
         if name == "compute":
+            if span.args.get("cache_fetches"):
+                # the fetch under it is startup's line, booked already
+                dur = max(0.0, dur - self._held(span.t_start, span.t_end))
             which = "recompile" if span.args.get("compile") else "compute"
             self._buckets[which] += dur
+            return
+        if span.cat in ("setup", "xla"):
+            if span.thread != self._thread:
+                return      # another loop's set-up (a serving replica's)
+            if name == "xla_compile":
+                if span.args.get("under") != "compute":
+                    self._buckets["recompile"] += self._claim(
+                        span.t_start, span.t_end)
+            elif span.cat == "setup" or name == "xla_cache_fetch":
+                self._buckets["startup"] += self._claim(
+                    span.t_start, span.t_end)
             return
         bucket = _LEAF_BUCKET.get(name)
         if bucket is None:
@@ -156,6 +214,9 @@ class GoodputLedger:
         if name == "restore":
             if len(self._restores) < _MAX_RESTORE_INTERVALS:
                 self._restores.append((span.t_start, span.t_end))
+            # under train_setup at a resume: claimed here, so the set-up
+            # span around it books only what is left
+            dur = self._claim(span.t_start, span.t_end)
         elif name == "guard_rescue":
             # a rollback that restored from checkpoint nests a restore
             # span inside this one; subtract it so the second lands in
@@ -205,6 +266,9 @@ class GoodputLedger:
             cur = {s.span_id for s in spans}
             new = [s for s in spans if s.span_id not in self._seen_ids]
             self._seen_ids = cur
+            # inner before outer: a child ends no later than its parent,
+            # and what it claims its parent does not book again
+            new.sort(key=lambda s: (s.t_end, -s.t_start))
             for s in new:
                 self._classify(s)
             self._fold_restarts()

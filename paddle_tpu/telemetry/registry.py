@@ -19,8 +19,10 @@ them into the per-step records.
 
 from __future__ import annotations
 
+import bisect
 import contextlib
 import dataclasses
+import math
 import threading
 import time
 from typing import Any
@@ -133,7 +135,18 @@ from typing import Any
 # deploys_export_failed / autoscale_actions{action} /
 # pool_shifts{event} / fleet_replicas_added / fleet_replicas_retired /
 # fleet_scrape_errors / client_backoffs.
-SCHEMA = "paddle_tpu.metrics/15"
+# /16 made set-up visible (telemetry/tracing.py, telemetry/goodput.py):
+# the "ledger" record's buckets_s gained ``startup`` (cat="setup" spans
+# and persistent-cache fetches, out of ``idle``) and ``recompile`` is
+# backend-compile time only (a fetch used to read the same); the
+# "profile" record's span summary may carry the set-up and ``xla_*``
+# span names.  New counters xla_programs_total{how} /
+# xla_build_seconds_total{phase} (tracing.XlaBuildListener, one a
+# process).  The serve latency histograms (serve_decode_step_ms /
+# serve_prefill_ms / serve_queue_wait_ms / serve_ttft_ms /
+# serve_tpot_ms) take geometric bucket edges (ratio <= 1.05, 0.1 ms -
+# 60 s) in place of DEFAULT_BUCKETS.  No new record kinds.
+SCHEMA = "paddle_tpu.metrics/16"
 
 # every record kind the schema knows.  The GL-SCHEMA codebase pass
 # (paddle_tpu/analysis) cross-checks this against the tree: an emitted
@@ -146,6 +159,16 @@ RECORD_KINDS = ("step", "bench", "fault", "recovery", "serve",
 # last edge land in the +Inf bucket)
 DEFAULT_BUCKETS = (1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0, 500.0,
                    1000.0, 2500.0, 5000.0)
+
+
+def geometric_buckets(lo: float, hi: float, ratio: float) -> tuple:
+    """Upper bounds from ``lo`` to at least ``hi``, each ``ratio`` times
+    the one before: a quantile read from them is off by at most
+    ``ratio - 1`` of its value, at any magnitude."""
+    if not (lo > 0 and hi > lo and ratio > 1):
+        raise ValueError(f"geometric_buckets({lo}, {hi}, {ratio})")
+    n = math.ceil(math.log(hi / lo) / math.log(ratio))
+    return tuple(lo * (hi / lo) ** (i / n) for i in range(n + 1))
 
 
 def _label_key(labels: dict) -> tuple:
@@ -227,12 +250,8 @@ class Histogram(_Metric):
             h.total += value
             h.min = min(h.min, value)
             h.max = max(h.max, value)
-            for i, edge in enumerate(self.bucket_edges):
-                if value <= edge:
-                    h.buckets[i] += 1
-                    break
-            else:
-                h.buckets[-1] += 1
+            # the first edge >= value; past the last, the overflow bucket
+            h.buckets[bisect.bisect_left(self.bucket_edges, value)] += 1
 
     def _percentile_of(self, h: _Hist, q: float) -> float:
         """Linear-interpolated q-th percentile from the bucket counts.

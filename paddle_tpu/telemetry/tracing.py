@@ -45,6 +45,21 @@ Instrumented phase boundaries (all behind the same flag):
   per-request retrospective ``request`` span with ``queue`` /
   ``prefill`` / ``decode`` children reconstructed from the request's
   own timestamps at retire time;
+- set-up, ``cat="setup"`` — ``import_paddle_tpu`` (the package's own
+  import, booked when the global tracer is armed); a serving replica's
+  ``engine_init`` (``params_bytes``, ``pool_bytes``, ``state_bytes``)
+  and ``engine_ready`` around one ``program_ready`` (``program``,
+  ``rows``, ``length``) a compiled program; the trainer's
+  ``train_setup`` from the top of ``SGD.train`` to its first ``step``,
+  around ``build_step``, ``place_state`` (``arrays``, ``bytes``), the
+  ``restore`` span and ``params_sync`` (``arrays``, ``bytes``; also the
+  copy back when a pass ends);
+- XLA's own build events, ``cat="xla"`` (:class:`XlaBuildListener`, one
+  a process) — ``xla_trace`` / ``xla_lower`` / ``xla_compile`` /
+  ``xla_cache_fetch``: retrospective children of whatever span of that
+  thread was open when jax built a program (``fun``, ``under`` = that
+  span's name), which gets ``compiles`` / ``cache_fetches`` counts.
+  They appear only when something is built: a steady loop has none;
 - ``FleetRouter`` — ``failover`` (with nested ``requeue``), ``route``
   and per-replica ``swap`` spans;
 - ``ElasticCoordinator`` — an ``elastic`` span with ``drain`` /
@@ -58,6 +73,11 @@ meanwhile (``--profile_steps``, ``jax.profiler.trace``) carries the
 program's spans as host events on the profiler's own clock, in the
 thread's lane — no marker, no alignment step.  Retrospective
 ``add_span``\\ s have no live interval to mirror and are not.
+
+Set-up outlives the window: spans of the two categories above are kept
+BESIDE the ring (at most ``KEPT_MAX``; overflow counts as dropped), so a
+``clear()`` at a measurement window's opening or a ring wrap leaves them
+where they were, first in ``spans``; ``drain()`` hands them out once.
 
 Span identity is DETERMINISTIC: ``span_id = rank * 2**32 + seq`` where
 ``seq`` is the per-tracer allocation counter — two runs of the same
@@ -86,11 +106,23 @@ the trace directory and the tracer's per-phase duration summary.
 from __future__ import annotations
 
 import collections
+import functools
 import threading
 import time
 
 # spans the ring keeps by default; at ~120 bytes/span this is ~1 MB
 DEFAULT_RING = 8192
+
+# the categories kept beside the ring (module docstring), and how many
+SETUP_CAT, XLA_CAT = "setup", "xla"
+_KEPT_CATS = (SETUP_CAT, XLA_CAT)
+KEPT_MAX = 256
+# ... of which XLA's builds may take this many: a set-up span ends after
+# the builds under it, and must not find the room gone
+_KEPT_XLA_MAX = 192
+# a trace or a lowering shorter than this is counted, not spanned: jax
+# fires one for every jitted helper it meets inside an outer trace
+XLA_SPAN_FLOOR_S = 5e-3
 
 # rank multiplier for deterministic span ids: ids never collide across
 # ranks in a merged timeline, and (rank, seq) is recoverable from the id
@@ -162,6 +194,51 @@ class _OpenSpan:
         return False
 
 
+class _Timed:
+    """Context manager of :meth:`Tracer.timed`: an interval that is
+    always measured (``seconds``, for the log line that reports it) and
+    is a span when tracing is on — one pair of readings for both.
+    ``args`` may be filled inside the block."""
+
+    __slots__ = ("tracer", "name", "cat", "args", "seconds", "_tok", "_t0")
+
+    def __init__(self, tracer, name, cat, args):
+        self.tracer, self.name, self.cat, self.args = tracer, name, cat, args
+        self.seconds = 0.0
+
+    def __enter__(self):
+        # off, the tracer's clock is not read (nor anything else of it)
+        self._tok = self.tracer.begin(self.name, self.cat)
+        self._t0 = (self._tok.t_start if self._tok is not None
+                    else time.perf_counter())
+        return self
+
+    def __exit__(self, *exc):
+        span = self.tracer.end(self._tok, **self.args)
+        self.seconds = (span.t_end if span is not None
+                        else time.perf_counter()) - self._t0
+        return False
+
+
+def setup_span(name: str, args_of=None):
+    """Decorator: the call is a ``cat="setup"`` span of the process's
+    tracer (nothing but a flag read when tracing is off).
+    ``args_of(self, result)`` gives the span its args."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapped(self, *a, **kw):
+            tracer = get_tracer()
+            if not tracer.enabled:
+                return fn(self, *a, **kw)
+            with tracer.timed(name) as t:
+                out = fn(self, *a, **kw)
+                if args_of is not None:
+                    t.args.update(args_of(self, out))
+            return out
+        return wrapped
+    return deco
+
+
 class _NullSpan:
     """The disabled-tracer fast path: one shared, allocation-free
     context manager.  ``span()`` on a disabled tracer returns this very
@@ -203,6 +280,7 @@ class Tracer:
         self._lock = threading.RLock()
         self._spans: collections.deque[Span] = collections.deque(
             maxlen=max(int(capacity), 1))
+        self._kept: list[Span] = []     # set-up and xla spans, not ringed
         self._seq = 0
         self._stack = threading.local()  # per-thread open-span stack
         self._dropped = 0
@@ -221,6 +299,8 @@ class Tracer:
                 self.clock = clock
             if rank is not None:
                 self.rank = int(rank)
+        if enabled and self is _default:
+            _book_import(self)
         return self
 
     # -- recording -------------------------------------------------------------
@@ -281,13 +361,22 @@ class Tracer:
         self._unwind(tok)
         if args:
             tok.args.update(args)
-        span = Span(tok.name, tok.cat, tok.span_id, tok.parent_id,
-                    self.rank, threading.current_thread().name,
-                    tok.t_start, t_end, tok.args)
+        return self._record(Span(
+            tok.name, tok.cat, tok.span_id, tok.parent_id, self.rank,
+            threading.current_thread().name, tok.t_start, t_end, tok.args))
+
+    def _record(self, span: Span) -> Span:
         with self._lock:
-            if len(self._spans) == self._spans.maxlen:
-                self._dropped += 1
-            self._spans.append(span)
+            if span.cat in _KEPT_CATS:
+                if len(self._kept) < (KEPT_MAX if span.cat == SETUP_CAT
+                                      else _KEPT_XLA_MAX):
+                    self._kept.append(span)
+                else:
+                    self._dropped += 1
+            else:
+                if len(self._spans) == self._spans.maxlen:
+                    self._dropped += 1
+                self._spans.append(span)
         return span
 
     def cancel(self, tok: _OpenSpan | None) -> None:
@@ -306,6 +395,12 @@ class Tracer:
             return _NULL_SPAN
         return self.begin(name, cat, **args)
 
+    def timed(self, name: str, cat: str = SETUP_CAT, **args) -> _Timed:
+        """``with tracer.timed("engine_ready") as t: ...`` then
+        ``t.seconds``: measured whether or not tracing is on, and a span
+        (``cat="setup"`` by default) when it is."""
+        return _Timed(self, name, cat, args)
+
     def add_span(self, name: str, t_start: float, t_end: float,
                  cat: str = "phase", parent_id: int | None = None,
                  **args) -> int | None:
@@ -316,21 +411,33 @@ class Tracer:
         disabled."""
         if not self._enabled:
             return None
-        sid = self._next_id()
-        span = Span(name, cat, sid, parent_id, self.rank,
-                    threading.current_thread().name,
-                    float(t_start), float(t_end), args)
-        with self._lock:
-            if len(self._spans) == self._spans.maxlen:
-                self._dropped += 1
-            self._spans.append(span)
-        return sid
+        return self._record(Span(
+            name, cat, self._next_id(), parent_id, self.rank,
+            threading.current_thread().name, float(t_start), float(t_end),
+            args)).span_id
+
+    def _retro(self, name: str, duration: float, fun=None) -> Span:
+        """One of XLA's builds, just over: ``[now - duration, now]`` under
+        this thread's innermost open span (:class:`XlaBuildListener`)."""
+        now = self.clock()
+        stack = self._tstack()
+        parent = stack[-1] if stack else None
+        args = {"fun": fun} if fun else {}
+        if parent is not None:
+            args["under"] = parent.name
+            key = _PARENT_COUNT.get(name)
+            if key:
+                parent.args[key] = parent.args.get(key, 0) + 1
+        return self._record(Span(
+            name, XLA_CAT, self._next_id(),
+            parent.span_id if parent is not None else None, self.rank,
+            threading.current_thread().name, now - duration, now, args))
 
     # -- reading ---------------------------------------------------------------
     @property
     def spans(self) -> list[Span]:
         with self._lock:
-            return list(self._spans)
+            return self._kept + list(self._spans)
 
     @property
     def dropped(self) -> int:
@@ -346,16 +453,19 @@ class Tracer:
             return self._seq
 
     def clear(self) -> None:
+        """Empty the ring (a measurement window opens).  What is kept
+        beside it -- set-up, XLA's builds -- stays: ``drain`` takes it."""
         with self._lock:
             self._spans.clear()
             self._dropped = 0
 
     def drain(self) -> list[Span]:
-        """Pop every completed span (the ``/trace`` endpoint's read —
-        each scrape gets the ring once, so a polling scraper streams
-        the timeline instead of re-downloading it)."""
+        """Pop every completed span, the kept ones first (the ``/trace``
+        endpoint's read — each scrape gets every span once, so a polling
+        scraper streams the timeline instead of re-downloading it)."""
         with self._lock:
-            out = list(self._spans)
+            out = self._kept + list(self._spans)
+            self._kept = []
             self._spans.clear()
         return out
 
@@ -448,6 +558,8 @@ def get_tracer() -> Tracer:
 
             _default = Tracer(enabled=bool(flags.get("trace_spans")),
                               capacity=int(flags.get("trace_ring_size")))
+            if _default.enabled:
+                _book_import(_default)
         return _default
 
 
@@ -457,6 +569,150 @@ def configure_tracing(enabled: bool | None = None, clock=None,
     trainer re-reads the ``trace_spans`` flag at ``train()`` entry via
     this, so a flag set after import still takes effect."""
     return get_tracer().configure(enabled=enabled, clock=clock, rank=rank)
+
+
+def _book_import(tracer: Tracer) -> None:
+    """``import paddle_tpu`` as a set-up span, from the two readings the
+    package took at the top and the bottom of its ``__init__`` — once,
+    when the process's tracer is armed (what ran before the package's
+    first line is the gap before this span; nothing here guesses it)."""
+    global _import_booked
+    if _import_booked or tracer.clock is not time.perf_counter:
+        return
+    import paddle_tpu
+
+    window = getattr(paddle_tpu, "_IMPORT_WINDOW", None)
+    if window is not None:
+        _import_booked = True
+        tracer.add_span("import_paddle_tpu", *window, cat=SETUP_CAT)
+
+
+_import_booked = False
+
+
+# -- XLA's build events ---------------------------------------------------------
+
+# jax.monitoring duration event -> (span name, phase label).  The four
+# that jax 0.9 fires when it builds a program; none fires in a loop that
+# only dispatches what is compiled.
+_XLA_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": ("xla_trace", "trace"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": ("xla_lower", "lower"),
+    "/jax/core/compile/backend_compile_duration": ("xla_compile", "compile"),
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        ("xla_cache_fetch", "cache_fetch"),
+}
+# a program is one of the last two: what the parent span counts it as,
+# and ``xla_programs_total``'s ``how``
+_PARENT_COUNT = {"xla_compile": "compiles", "xla_cache_fetch": "cache_fetches"}
+_HOW = {"compile": "compiled", "cache_fetch": "fetched"}
+
+
+class XlaBuildListener:
+    """XLA's build events, heard inside the program.
+
+    Always: ``xla_programs_total{how=compiled|fetched}`` and
+    ``xla_build_seconds_total{phase=trace|lower|compile|cache_fetch}`` in
+    the registry, and ``events``, the raw count of each event as jax
+    fired it.  With the tracer enabled each event is also a retrospective
+    span over ``[now - duration, now]`` under the innermost live span of
+    the thread that built, which gets ``compiles`` / ``cache_fetches``.
+
+    On a persistent-cache hit jax fires ``backend_compile_duration``
+    AROUND ``cache_retrieval_time_sec`` (the compile event times
+    ``compile_or_get_cached``): the pair is ONE program, fetched.  The
+    fetch is booked when it fires; the compile event that follows on the
+    same thread widens it to its own interval (key, read, load) and adds
+    the function's name, and books no compile.
+
+    ``tracer`` / ``registry``: zero-argument callables (default: the
+    process's own), so a test drives one with a fake clock.
+    """
+
+    def __init__(self, tracer=None, registry=None):
+        if registry is None:
+            from paddle_tpu.telemetry.registry import get_default_registry
+
+            registry = get_default_registry
+        self._tracer = tracer or get_tracer
+        self._registry = registry
+        self._lock = threading.Lock()
+        self.events = {phase: 0 for _, phase in _XLA_EVENTS.values()}
+        self._pending = threading.local()  # this thread's unclaimed fetch
+
+    def _count(self, how: str | None, phase: str, seconds: float) -> None:
+        reg = self._registry()
+        if how:
+            reg.counter("xla_programs_total",
+                        "programs XLA built for this process: compiled, or "
+                        "fetched from the persistent cache").inc(how=how)
+        reg.counter("xla_build_seconds_total",
+                    "seconds jax spent building programs, by phase").inc(
+                        max(seconds, 0.0), phase=phase)
+
+    def __call__(self, event: str, duration: float, **kw) -> None:
+        hit = _XLA_EVENTS.get(event)
+        if hit is None:
+            return
+        try:
+            self._heard(*hit, float(duration), kw.get("fun_name"))
+        except Exception as e:     # jax calls this inside its compile path
+            from paddle_tpu.core import logger as log
+
+            log.debug("xla build listener: %s: %s", type(e).__name__, e)
+
+    def _heard(self, name: str, phase: str, duration: float, fun) -> None:
+        with self._lock:
+            self.events[phase] += 1
+        fetch = None
+        if phase == "compile":
+            fetch = getattr(self._pending, "fetch", None)
+            self._pending.fetch = None
+        tracer = self._tracer()
+        if fetch is not None and duration >= fetch[0]:
+            # the compile event around a fetch: one program, fetched
+            self._count(None, "cache_fetch", duration - fetch[0])
+            span = fetch[1]
+            if span is not None:
+                span.t_end = tracer.clock()
+                span.t_start = span.t_end - duration
+                if fun:
+                    span.args["fun"] = fun
+            return
+        self._count(_HOW.get(phase), phase, duration)
+        span = None
+        if tracer.enabled and (duration >= XLA_SPAN_FLOOR_S
+                               or phase in _HOW):
+            span = tracer._retro(name, duration, fun)
+        if phase == "cache_fetch":
+            self._pending.fetch = (duration, span)
+
+
+_listener: XlaBuildListener | None = None
+
+
+def install_xla_listener() -> XlaBuildListener:
+    """Register the process's one :class:`XlaBuildListener` with
+    ``jax.monitoring`` (``import paddle_tpu`` does; again is a no-op)."""
+    global _listener
+    with _default_lock:
+        if _listener is None:
+            import jax.monitoring
+
+            _listener = XlaBuildListener()
+            jax.monitoring.register_event_duration_secs_listener(_listener)
+        return _listener
+
+
+def uninstall_xla_listener() -> None:
+    """Take the process's listener off ``jax.monitoring`` (tests)."""
+    global _listener
+    with _default_lock:
+        if _listener is not None:
+            import jax.monitoring
+
+            jax.monitoring.unregister_event_duration_listener(_listener)
+            _listener = None
 
 
 # -- windowed device profiling (--profile_steps A:B) ---------------------------

@@ -26,6 +26,7 @@ from paddle_tpu.core.parameters import Parameters
 from paddle_tpu.layers.base import LayerOutput
 from paddle_tpu.parallel.mesh import MeshContext, get_mesh
 from paddle_tpu.reader.feeder import DataFeeder, parse_seq_buckets
+from paddle_tpu.telemetry import tracing as tracing_mod
 from paddle_tpu.trainer import event as v2_event
 from paddle_tpu.trainer.step import build_eval_step, build_train_step
 
@@ -135,12 +136,17 @@ class SGD:
         self._train_step = None
         self._eval_step = None
         self._compiled_sigs: set = set()
+        self._setup_span = None  # train()'s open train_setup span
         self._telemetry = None  # StepTelemetry, bound by train()
         self._telemetry_costs: dict = {}  # per-signature cost analysis
         self.__gradient_machine__ = self  # v2 attr some user code touches
 
     # -- internal -------------------------------------------------------------
+    @tracing_mod.setup_span("params_sync", lambda self, out: _tree_size(out))
     def _params_dict(self):
+        """``Parameters`` -> a dict of device arrays, one ``__getitem__``
+        (a host copy) and one ``asarray`` (back to the device) an array,
+        at every ``train()``: the ``params_sync`` set-up span."""
         return {n: jax.numpy.asarray(self.parameters[n]) for n in self.parameters.names()}
 
     def _zero_active(self) -> bool:
@@ -168,7 +174,10 @@ class SGD:
                                param_specs=base)
 
     def _ensure_built(self):
-        if self._train_step is None:
+        if self._train_step is not None:
+            return
+        with tracing_mod.get_tracer().span("build_step",
+                                           cat=tracing_mod.SETUP_CAT):
             node_names = {n.name for n in self.topology.nodes}
             wanted = {
                 name
@@ -193,11 +202,13 @@ class SGD:
 
                 self._tap_grads = build_tap_grads(self.topology, taps)
 
+    @tracing_mod.setup_span("place_state", lambda self, out: _tree_size(out))
     def _placed_state(self):
         """(params, states, opt_state) on the mesh, as the jitted step
         takes them: parameters and states replicated, the optimizer
         state carried over from the last run or freshly initialised in
-        its ZeRO layout."""
+        its ZeRO layout.  The ``place_state`` set-up span (``arrays``,
+        ``bytes`` of the three), around its ``params_sync``."""
         params = self.mesh.replicate(self._params_dict())
         states = self.mesh.replicate(self.states)
         opt_state = self._opt_state
@@ -329,7 +340,6 @@ class SGD:
         from paddle_tpu.distributed import multihost as mh
         from paddle_tpu.telemetry import StepTelemetry
         from paddle_tpu.telemetry import introspect as introspect_mod
-        from paddle_tpu.telemetry import tracing as tracing_mod
 
         if sync_period is None:
             sync_period = flags.get("sync_period")
@@ -368,6 +378,11 @@ class SGD:
             tracing_mod.configure_tracing(enabled=True)
             self._goodput_ledger = goodput_mod.GoodputLedger(
                 registry=self._telemetry.registry).start()
+        # set-up, from here to the first step (the step loop ends it; the
+        # finally below does where no step ever came)
+        tracer = tracing_mod.get_tracer()
+        self._setup_span = tracer.begin("train_setup",
+                                        cat=tracing_mod.SETUP_CAT)
         prev_debug_nans = jax.config.jax_debug_nans
         if flags.get("debug_nans"):
             # the documented jax nan-checking traps at the originating op;
@@ -444,6 +459,8 @@ class SGD:
                              checkpoint_keep=checkpoint_keep,
                              elastic=elastic)
         finally:
+            tracer.end(self._setup_span)
+            self._setup_span = None
             jax.config.update("jax_debug_nans", prev_debug_nans)
             if watchdog is not None:
                 watchdog.stop()
@@ -521,8 +538,10 @@ class SGD:
                                      dtype=np.uint32))
         mh.flight_recorder().heartbeat("restored", path=path)
         if tracer.enabled:
+            # under train_setup at a resume, top-level in a guard's rescue
             tracer.add_span("restore", tk0, tracer.clock(), cat="trainer",
-                            path=path)
+                            parent_id=getattr(self._setup_span, "span_id",
+                                              None), path=path)
         if self._telemetry is not None:
             self._telemetry.registry.gauge(
                 "checkpoint_restore_ms",
@@ -649,8 +668,6 @@ class SGD:
         # the --profile_steps windowed device capture, keyed by the
         # DISPATCH step counter (fence-time counters lag under deferred
         # fencing, so the window brackets what actually runs)
-        from paddle_tpu.telemetry import tracing as tracing_mod
-
         tracer = tracing_mod.get_tracer()
         prev_window = getattr(self, "_profile_window", None)
         if prev_window is not None:
@@ -921,6 +938,9 @@ class SGD:
                     # render.  Both tokens are canceled (not recorded)
                     # when the pull turns out to be the end-of-pass
                     # sentinel.
+                    if self._setup_span is not None:
+                        tracer.end(self._setup_span)    # the first step
+                        self._setup_span = None
                     tk_step = tracer.begin("step", cat="trainer",
                                            pass_id=pass_id,
                                            batch_id=batch_id)
@@ -1012,15 +1032,18 @@ class SGD:
                     profile.maybe_start(n_disp)
                     t_step0 = _time.perf_counter()
                     with stat.timer("forwardBackward+update"):
-                        # compile=True marks the dispatch that built a
-                        # new executable — the goodput ledger books the
-                        # whole span as "recompile", not "compute"
-                        tk_compute = tracer.begin("compute", cat="trainer",
-                                                  compile=new_sig)
+                        tk_compute = tracer.begin("compute", cat="trainer")
                         params, opt_state, states, cost, metrics = \
                             self._train_step(params, opt_state,
                                              states, feed, step_key)
-                        tracer.end(tk_compute)
+                        if tk_compute is not None:
+                            # compile=True marks the dispatch under which
+                            # XLA's backend compiled (the listener's count;
+                            # a fetch from the persistent cache is not one)
+                            # — the goodput ledger books the whole span as
+                            # "recompile", not "compute"
+                            tracer.end(tk_compute, compile=bool(
+                                tk_compute.args.get("compiles")))
                     dispatched["n"] = n_disp + 1
                     profile.maybe_stop(n_disp + 1, fence=cost)
                     if guard is not None:
@@ -1105,7 +1128,11 @@ class SGD:
                 if feeds is not None:
                     feeds.close()
             # write back for checkpoint/event access
-            self.parameters.update_from(params)
+            with tracer.span("params_sync", cat=tracing_mod.SETUP_CAT,
+                             back=True) as tk_sync:
+                self.parameters.update_from(params)
+                if tk_sync is not None:
+                    tk_sync.args.update(_tree_size(params))
             self.states = dict(states)
             self._opt_state = opt_state
             if preempted["flag"] and not pass_complete:
@@ -1259,6 +1286,13 @@ class SGD:
                     initializer=I.constant(0.0), is_static=True,
                 ))
             self.parameters._values[name] = jax.numpy.asarray(arr)
+
+
+def _tree_size(tree) -> dict:
+    """``arrays`` and ``bytes`` of a pytree's leaves (set-up span args)."""
+    leaves = jax.tree.leaves(tree)
+    return {"arrays": len(leaves),
+            "bytes": sum(int(getattr(x, "nbytes", 0)) for x in leaves)}
 
 
 def _mean_dicts(dicts: list[dict]) -> dict:

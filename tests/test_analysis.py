@@ -556,7 +556,7 @@ def test_preflight_cli_clean_config_exits_zero(tmp_path):
     recs = [json.loads(line) for line in open(jsonl)]
     pf = [r for r in recs if r.get("kind") == "preflight"]
     assert pf and pf[0]["clean"] is True
-    assert pf[0]["schema"] == "paddle_tpu.metrics/15"
+    assert pf[0]["schema"] == "paddle_tpu.metrics/16"
     # the schema/9 GL-P-MEM memory report rode along
     mem = pf[0]["memory"]
     assert mem["params_bytes"] > 0 and mem["opt_state_bytes"] > 0

@@ -19,6 +19,7 @@ The acceptance properties of the ledger:
 
 import json
 import os
+import threading
 import time
 
 import numpy as np
@@ -37,7 +38,11 @@ from paddle_tpu.telemetry.goodput import (
     GoodputLedger,
     serving_costs,
 )
-from paddle_tpu.telemetry.tracing import Tracer, configure_tracing
+from paddle_tpu.telemetry.tracing import (
+    Tracer,
+    configure_tracing,
+    get_tracer,
+)
 
 
 @pytest.fixture(autouse=True)
@@ -45,6 +50,7 @@ def _restore_tracing_and_flags():
     """The trainer arms the global tracer when --goodput_ledger is on
     and never disarms it; undo that (and any flag edits) per test."""
     prev = flags.snapshot_raw()
+    get_tracer().drain()    # clear() leaves the kept set-up spans
     yield
     flags.restore_raw(prev)
     configure_tracing(enabled=bool(flags.get("trace_spans")))
@@ -162,6 +168,79 @@ def test_wall_clock_identity_holds_with_the_layer_spans_in_the_ring():
     assert sum(b.values()) == pytest.approx(rec["wall_s"]) == 16.0
 
 
+def test_set_up_is_startup_each_second_once_and_the_buckets_sum_to_wall():
+    """A resumed run's timeline: ``train_setup`` around a build, a
+    placement that compiled (``xla_compile`` -> recompile), a restore
+    (-> checkpoint_restore) and a ``Parameters`` round trip; then a first
+    dispatch that fetched its program (the fetch is startup's line, the
+    rest of the span compute), one that compiled, the copy back.  Each
+    second lands once, out of ``idle``."""
+    led, tracer, clk, reg = _ledger()
+    # kept beside the ring, so handed to the fold BEFORE the ring's
+    # restore: the fold sorts inner before outer itself
+    setup = tracer.add_span("train_setup", 0.0, 10.0, cat="setup")
+    tracer.add_span("build_step", 0.0, 1.0, cat="setup", parent_id=setup)
+    place = tracer.add_span("place_state", 1.0, 5.0, cat="setup",
+                            parent_id=setup)
+    tracer.add_span("params_sync", 1.0, 2.0, cat="setup", parent_id=place)
+    tracer.add_span("xla_trace", 2.0, 2.5, cat="xla", parent_id=place,
+                    under="place_state")
+    tracer.add_span("xla_compile", 2.5, 4.5, cat="xla", parent_id=place,
+                    under="place_state")
+    tracer.add_span("restore", 5.0, 8.0, cat="trainer", parent_id=setup)
+    first = tracer.add_span("compute", 10.0, 14.0, cat="trainer",
+                            compile=False, cache_fetches=1)
+    tracer.add_span("xla_trace", 10.0, 12.0, cat="xla", parent_id=first,
+                    under="compute")
+    tracer.add_span("xla_cache_fetch", 12.5, 13.5, cat="xla",
+                    parent_id=first, under="compute")
+    second = tracer.add_span("compute", 14.0, 20.0, cat="trainer",
+                             compile=True, compiles=1)
+    tracer.add_span("xla_compile", 15.0, 19.0, cat="xla", parent_id=second,
+                    under="compute")
+    tracer.add_span("compute", 20.0, 21.0, cat="trainer", compile=False)
+    tracer.add_span("params_sync", 21.0, 21.5, cat="setup", back=True)
+    # another loop's set-up (a serving replica in the process): not ours
+    other = Tracer(enabled=True, rank=0, clock=clk)
+    t = threading.Thread(target=lambda: other.add_span(
+        "engine_ready", 0.0, 20.0, cat="setup"), name="serving-engine")
+    t.start()
+    t.join()
+    tracer._kept.extend(other.spans)
+    clk.t = 22.0
+    rec = led.finish()
+    b = rec["buckets_s"]
+    # train_setup's 10 s less the compile's 2 and the restore's 3, the
+    # fetch's 1, the copy back's 0.5
+    assert b["startup"] == pytest.approx(5.0 + 1.0 + 0.5)
+    assert b["recompile"] == pytest.approx(2.0 + 6.0)
+    assert b["checkpoint_restore"] == pytest.approx(3.0)
+    assert b["compute"] == pytest.approx(3.0 + 1.0)
+    assert b["idle"] == pytest.approx(0.5)
+    assert sum(b.values()) == pytest.approx(rec["wall_s"]) == 22.0
+    assert set(b) == set(BUCKETS) and "startup" in BADPUT_BUCKETS
+
+
+@pytest.mark.parametrize("order", ["inner_first", "outer_first", "two_folds"])
+def test_nested_set_up_spans_book_each_second_once_in_any_order(order):
+    led, tracer, clk, reg = _ledger()
+    inner = [("build_step", 1.0, 2.0), ("place_state", 2.0, 6.0),
+             ("params_sync", 2.0, 3.0)]
+    outer = [("train_setup", 0.0, 8.0)]
+    seq = inner + outer if order != "outer_first" else outer + inner
+    for i, (name, t0, t1) in enumerate(seq):
+        tracer.add_span(name, t0, t1, cat="setup")
+        if order == "two_folds" and i == 1:
+            led.fold()
+    # a span from before the run (the import) is not the run's
+    tracer.add_span("import_paddle_tpu", -5.0, -1.0, cat="setup")
+    clk.t = 10.0
+    b = led.finish()["buckets_s"]
+    assert b["startup"] == pytest.approx(8.0)
+    assert b["idle"] == pytest.approx(2.0)
+    assert sum(b.values()) == pytest.approx(10.0)
+
+
 def test_fold_is_incremental_over_ring_snapshots():
     led, tracer, clk, _ = _ledger()
     tracer.add_span("feed", 0.0, 1.0, cat="trainer")
@@ -190,7 +269,7 @@ def test_finish_emits_ledger_record_gauge_and_jsonl(tmp_path):
     path = str(tmp_path / "ledger.jsonl")
     rec = led.finish(path=path)
     assert rec["kind"] == "ledger"
-    assert rec["schema"].endswith("/15")
+    assert rec["schema"].endswith("/16")
     assert reg.get("goodput_fraction").value() == pytest.approx(0.75)
     recs = [r for r in sink.records if r.get("kind") == "ledger"]
     assert len(recs) == 1
@@ -288,6 +367,8 @@ def test_fifty_step_chaos_run_ledger_sums_to_wall(tmp_path):
     b = rec["buckets_s"]
     assert sum(b.values()) == pytest.approx(rec["wall_s"], rel=0.01)
     assert rec["spans_dropped"] == 0
+    # build and placement before step 0 are startup's, no longer idle's
+    assert b["startup"] > 0
     assert b["compute"] > 0                      # steady-state steps
     assert b["recompile"] > 0                    # first-signature builds
     assert b["input_wait"] > 0                   # the starved reader

@@ -290,7 +290,7 @@ def _run_train(trace_spans: bool, status_port=0, scrape_at=None,
 
     rng.seed(7)
     get_tracer().configure(enabled=trace_spans)
-    get_tracer().clear()
+    get_tracer().drain()    # clear() leaves the kept set-up spans
     flags.set("trace_spans", trace_spans)
     flags.set("status_port", status_port)
     flags.set("profile_steps", profile_steps)
@@ -385,6 +385,10 @@ def test_disabled_tracing_is_bitwise_noop(prefetch):
     names = {s.name for s in get_tracer().spans}
     assert {"feed_read", "feed_convert", "feed_place"} <= names
     assert ("feed_stage" in names) == bool(prefetch)
+    # set-up's spans and the listener's are on too, and change nothing
+    assert {"train_setup", "build_step", "place_state",
+            "params_sync"} <= names
+    assert any(n.startswith("xla_") for n in names)
     np.testing.assert_array_equal(
         np.asarray([r["loss"] for r in steps_off]),
         np.asarray([r["loss"] for r in steps_on]),
@@ -402,7 +406,7 @@ def test_profile_steps_window_emits_record(tmp_path):
     assert len(prof) == 1
     rec = prof[0]
     assert rec["start_step"] == 1 and rec["end_step"] == 3
-    assert rec["schema"] == "paddle_tpu.metrics/15"
+    assert rec["schema"] == "paddle_tpu.metrics/16"
     assert rec["trace_dir"] == str(tmp_path / "prof")
     assert os.path.isdir(rec["trace_dir"])  # the device capture landed
     assert rec["spans"]["compute"]["count"] == 2  # the window's steps

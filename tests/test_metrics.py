@@ -305,3 +305,45 @@ def test_flight_recorder_dumps_when_train_step_raises(tmp_path):
     assert len(payload["records"]) >= 2
     assert all(r["kind"] == "step" for r in payload["records"])
     assert any(h["tag"] == "begin_batch" for h in payload["heartbeats"])
+
+
+def test_geometric_buckets_and_observe_by_bisection():
+    """The serve latency histograms' edges: each at most 5% above the
+    one before, 0.1 ms to 60 s; an observation lands in the first bucket
+    whose edge is not below it, one past the last edge in the overflow
+    bucket, as the linear search did."""
+    from paddle_tpu.serving.engine import _LATENCY_BUCKETS
+    from paddle_tpu.telemetry.registry import geometric_buckets
+
+    edges = geometric_buckets(0.1, 60_000.0, 1.05)
+    assert edges == _LATENCY_BUCKETS
+    assert edges[0] == pytest.approx(0.1) and edges[-1] == pytest.approx(6e4)
+    assert all(b / a <= 1.05 + 1e-12 for a, b in zip(edges, edges[1:]))
+    assert len(edges) < 300
+    with pytest.raises(ValueError):
+        geometric_buckets(1.0, 1.0, 1.05)
+    reg = metrics.MetricsRegistry("buckets")
+    h = reg.histogram("t_ms", "", buckets=(1.0, 2.0, 4.0))
+    for v in (0.5, 1.0, 1.5, 2.0, 3.9, 4.0, 4.1, 100.0):
+        h.observe(v)
+    assert list(h.summary()["buckets"].values()) == [2, 2, 2, 2]
+
+
+@pytest.mark.parametrize("step_ms", [9.1, 14.6, 43.5, 238.0])
+def test_a_steady_steps_quantile_reads_within_5pct_of_it(step_ms):
+    """What DEFAULT_BUCKETS could not: a loop whose step takes 9.1 ms
+    read 7.3 from the (5, 10] bucket's interpolation."""
+    from paddle_tpu.serving.engine import _latency
+
+    reg = metrics.MetricsRegistry("steady")
+    rng = np.random.default_rng(0)
+    # a steady step, with the odd short and long one a live loop has
+    steps = np.concatenate([step_ms * (1 + 0.01 * rng.standard_normal(480)),
+                            np.full(10, step_ms / 4), np.full(10, step_ms * 4)])
+    coarse = reg.histogram("coarse_ms", "")
+    for v in steps:
+        _latency(reg, "serve_decode_step_ms").observe(float(v))
+        coarse.observe(float(v))
+    got = reg.get("serve_decode_step_ms").percentile(50)
+    assert abs(got - step_ms) / step_ms < 0.05
+    assert abs(coarse.percentile(50) - step_ms) / step_ms > 0.1

@@ -400,7 +400,7 @@ def test_deferred_fence_bursts_and_schema2_fields():
     _, steps, events = _run_train(4, 2, n_samples=32, batch=8, passes=1)
     assert len(steps) == 4
     for r in steps:
-        assert r["schema"] == "paddle_tpu.metrics/15"
+        assert r["schema"] == "paddle_tpu.metrics/16"
         assert "input_wait_ms" in r and "host_stall_ms" in r
         assert r["input_wait_ms"] >= 0.0 and r["host_stall_ms"] >= 0.0
     # with sync_period=4 the EndIterations arrive as one burst after the

@@ -807,7 +807,7 @@ class TestServeTelemetry:
         serves = [r for r in sink.records if r.get("kind") == "serve"]
         assert len(serves) == 3
         for r in serves:
-            assert r["schema"] == "paddle_tpu.metrics/15"
+            assert r["schema"] == "paddle_tpu.metrics/16"
             for f in ("queue_wait_ms", "ttft_ms", "tpot_ms", "total_ms"):
                 assert r[f] >= 0.0
             assert r["new_tokens"] == 4

@@ -623,8 +623,9 @@ def trace_table(profiles: list[dict]) -> None:
 def goodput_table(ledgers: list[dict]) -> None:
     """Render the schema /12 goodput ledger (``kind="ledger"``,
     telemetry/goodput.py): the wall-clock account — one row per badput
-    bucket with its share of wall — plus the serving cost-per-token
-    split when the run served.  Buckets above 10% of wall are flagged:
+    bucket with its share of wall (``startup`` since /16: set-up spans
+    and persistent-cache fetches, which ``idle`` used to hide) — plus the
+    serving cost-per-token split when the run served.  Buckets above 10% of wall are flagged:
     they are the lever the ledger exists to point at."""
     if not ledgers:
         return
