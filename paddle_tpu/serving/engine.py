@@ -16,7 +16,11 @@ Synchronous callers (CLIs, tests, benches) skip the thread:
 **The order of an iteration** (:meth:`ServingEngine.step`).  The loop
 keeps one pass in flight: the last token every slot sampled lives on the
 device (``PagedKVCache.tokens``, carried and donated beside the pools), so
-nothing a decode step needs waits for the step before it.  An iteration
+nothing a decode step needs waits for the step before it.  A model that
+generates by blocks (``block_len`` > 1) runs the same loop, its block pass
+the decode step: the array holds every slot's block in progress, tokens |
+masked flags, and what the host decides — how many positions a pass
+unmasks, which pass commits — it counts at dispatch.  An iteration
 
 1. takes in new submissions, retires what the last iteration's reads
    finished and admits into the freed slots;
@@ -30,7 +34,9 @@ nothing a decode step needs waits for the step before it.  An iteration
    the last iteration's decode step, this one's prefill pass — and hands
    the tokens out (``Scheduler.append_token``, one by one, in order).  A
    request's result is delivered once its last token has been read (by
-   the retirement that follows).  One decode step stays in flight.
+   the retirement that follows).  One decode step stays in flight.  (By
+   blocks a prefill pass samples nothing and is read an iteration later;
+   a block is handed out at the read of the pass that committed it.)
 
 A finish by length is known from the counts and the sequence rides no
 further pass; an ``eos`` is data and is seen one pass late — the surplus
@@ -45,13 +51,11 @@ counter ``serve_loop_drains_total{why}``): when there is nothing left to
 dispatch (``idle``: so ``step()`` keeps returning True while a pass is
 unread, and ``run_until_idle`` leaves none), at ``stop()`` (``stop``),
 before a weight swap (``swap``: :meth:`ServingEngine.set_params`), and in the
-two configurations that keep the synchronous order — dispatch, read,
+one configuration that keeps the synchronous order — dispatch, read,
 then go on — for every pass: the incremental prefill path
 (``incremental``: prefix cache / chunked prefill, whose pass's first
-tokens the host seeds into the token array) and a model that generates
-by blocks (``block``: the next block pass's input is which positions the
-last one unmasked; carrying that on the device is ROADMAP's follow-up).
-Which it is follows from the engine's configuration; there is no switch.
+tokens the host seeds into the token array).  Which it is follows from
+the engine's configuration; there is no switch.
 
 Telemetry rides the shared :class:`MetricsRegistry`: histograms
 ``serve_queue_wait_ms`` / ``serve_prefill_ms`` / ``serve_decode_step_ms``
@@ -79,9 +83,9 @@ expert's tokens over the mean); under a model that generates by blocks
 ``serve_block_passes_total{kind=denoise|commit}`` (a row of a block
 pass, by whether it went in with masked positions),
 ``serve_blocks_committed_total``, ``serve_block_positions_total``
-(rows x block_len computed) and ``serve_tokens_dropped_total`` (committed
-positions never handed out: a last block's surplus; without blocks, what
-the surplus pass after an ``eos`` sampled); with
+(rows x block_len computed) and ``serve_tokens_dropped_total`` (positions
+chosen and never handed out: a last block's surplus, and what the
+surplus pass after an ``eos`` sampled or unmasked); with
 ``--prefix_cache`` /
 ``--prefill_chunk_tokens``
 also counters ``serve_prefix_hit_tokens`` / ``serve_prefill_flops_saved``
@@ -192,7 +196,7 @@ class _Pass:
     """A dispatched pass whose tokens the host has not read."""
 
     kind: str           # "prefill" | "decode": its span and counters
-    rows: list          # the sequences it samples a token for
+    rows: list          # the sequences it samples for
     n_out: int          # tokens in ``out`` (routing counts ride behind)
     args: dict          # what its span says of it (empty: tracing off)
     t_from: float       # start of the interval it adds to the loop: when
@@ -291,9 +295,8 @@ class ServingEngine:
                 s.page_size, s.max_slots, s.max_pages_per_seq,
                 dtype=cfg.dtype, prefix_cache=s.prefix_cache,
                 state_layers=cfg.state_layers,
-                state_shapes=cfg.state_shapes if cfg.state_layers else None)
-        if cfg.block_len > 1:
-            self.cache.tokens = None    # the host chooses a block's input
+                state_shapes=cfg.state_shapes if cfg.state_layers else None,
+                block_len=cfg.block_len)
         (self.cache.k, self.cache.v, self.cache.state,
          self.cache.tokens) = self.place(
             (self.cache.k, self.cache.v, self.cache.state,
@@ -340,12 +343,19 @@ class ServingEngine:
             self._loop_args.update(kv_heads=cfg.kv_heads,
                                    state_layers=cfg.state_layers)
         self._block = cfg.block_len
-        # the two configurations whose every pass is read before the next
+        # the one configuration whose every pass is read before the next
         # is dispatched (module docstring)
-        self._sync = self._block > 1 or s.incremental_prefill
+        self._sync = s.incremental_prefill
         if self._sync:      # no pass goes out behind an unread one
             self._loop_args["ahead"] = 0
+        # the decode step's batch fields in the program's order and the int32s
+        # out: a token a slot — by blocks token | unmasked | confidence bits
+        self._decode_fields = ("positions", "seq_lens", "page_table", "rids",
+                               "gens", "temps")
+        self._decode_out = s.max_slots
         if self._block > 1:
+            self._decode_fields = ("ids", *self._decode_fields)
+            self._decode_out *= 3 * self._block
             self._loop_args.update(block=self._block)
             self.registry.gauge(
                 "serve_block_length",
@@ -620,11 +630,10 @@ class ServingEngine:
         its last token has been read).  So a decode step's tokens are
         handed out by the iteration after the one that dispatched it, a
         prefill pass's first tokens by its own, and the device always has
-        the next step queued.  The incremental prefill path and a model
-        that generates by blocks read every pass before the next is
-        dispatched instead (``_sync``); ``stop()`` and a weight swap
-        (``set_params``) read what is in flight; an engine with an unread pass
-        is not idle.
+        the next step queued.  The incremental prefill path reads every
+        pass before the next is dispatched instead (``_sync``); ``stop()``
+        and a weight swap (``set_params``) read what is in flight; an
+        engine with an unread pass is not idle.
 
         With span tracing on, an iteration that did work is one
         ``serve_step`` span (an idle one records nothing): its
@@ -648,9 +657,9 @@ class ServingEngine:
         first token and a decode step one token a live sequence — or,
         under a model that generates by blocks (``block_len`` > 1), a
         prefill pass nothing (it leaves the K/V of the prompt's whole
-        blocks) and a block pass (``_block_pass``) 0 to ``block_len``
-        tokens a sequence: all of a block's at once, when the pass that
-        found nothing masked has committed it."""
+        blocks) and a block pass 0 to ``block_len`` tokens a sequence:
+        all of a block's at once, when the pass that went in over nothing
+        masked, and so committed it, is read (``_land_blocks``)."""
         tracer = tracing.get_tracer()
         with self._pump:
             tk = None
@@ -722,31 +731,25 @@ class ServingEngine:
                                      "temps", "slots")
                     programs[n] = self._prefill.lower(
                         self._params(), self._base_key, cache.k, cache.v,
-                        *args, cache.state, cache.tokens).compile()
+                        *self._carried("prefill", args)).compile()
                 log.debug("prefill program of %d row(s) ready after %.2f s",
                           n, one.seconds)
             with tracer.timed("program_ready", program="decode",
                               rows=self.serving.max_slots,
                               length=self._block):
-                batch = sched.decode_arrays([])
-                if self._block > 1:
-                    args = self._dev(batch, "ids", "positions", "seq_lens",
-                                     "page_table", "rids", "gens", "temps")
-                else:   # a step's ids are the token array, the device's
-                    args = [cache.tokens] + self._dev(
-                        batch, "positions", "seq_lens", "page_table", "rids",
-                        "gens", "temps")
+                args = self._dev(sched.decode_arrays([]),
+                                 *self._decode_fields)
                 programs["decode"] = self._decode.lower(
-                    self._params(), self._base_key, cache.k, cache.v, *args,
-                    cache.state).compile()
+                    self._params(), self._base_key, cache.k, cache.v,
+                    *self._carried("decode", args)).compile()
         with self._lock:    # all or none: a failure is met again
             self._programs = programs
+        what = "block in progress" if self._block > 1 else "last token"
         log.info("serving engine ready: %d prefill program(s) of %s row(s) x "
                  "%d + decode in %.2f s; %s, %s", len(rows), list(rows),
                  length, ready.seconds,
-                 "a block's input is the host's" if self._block > 1 else
-                 f"the last token of {self.serving.max_slots} slot(s) stays "
-                 "on the device (the token array)",
+                 f"the {what} of {self.serving.max_slots} slot(s) stays on "
+                 "the device (the token array)",
                  "every pass is read before the next" if self._sync
                  else "the loop runs one pass ahead")
         return programs
@@ -772,12 +775,16 @@ class ServingEngine:
             # idle step stays as cheap as it was: a fleet's router pumps
             # idle replicas all the time)
             programs = self._make_ready()
+        # one pass ahead: the next decode step goes out first, and only
+        # then is what was dispatched before it read
+        waiting = len(flight)
         if admitted and not self.serving.incremental_prefill:
             # behind the decode step still in flight; its first tokens go
             # into the token array, so the admitted ride the step below
             self._send_prefill(tracer, programs, admitted)
-            if self._block > 1:
-                self._settle(tracer, "block")
+            # and are this iteration's to hand out; by blocks the pass
+            # samples nothing and is read behind the step below, next time
+            waiting += self._block == 1
             worked = True
 
         if self.serving.incremental_prefill:
@@ -785,29 +792,21 @@ class ServingEngine:
                 worked = True
 
         batch = self._scheduled(tracer, sched.decode_batch)
-        if self._block > 1:
-            if batch is not None:
-                self._block_pass(tracer, programs["decode"], batch)
-                worked = True
+        if waiting:
+            # the span of the pass about to be read: the dispatch of the
+            # step that follows it and the wait for its tokens
+            self._open(tracer, flight[0])
+        if batch is not None:
+            self._send_decode(tracer, programs, batch)
+        if self._sync:
+            self._settle(tracer, "incremental")
+        elif batch is None:     # nothing left to dispatch behind it
+            self._settle(tracer, "idle")
         else:
-            # one pass ahead: the next decode step goes out first, and
-            # only then is what was dispatched before it read
-            waiting = len(flight)
-            if waiting:
-                # the span of the pass about to be read: the dispatch of
-                # the step that follows it and the wait for its tokens
-                self._open(tracer, flight[0])
-            if batch is not None:
-                self._send_decode(tracer, programs, batch)
-            if self._sync:
-                self._settle(tracer, "incremental")
-            elif batch is None:     # nothing left to dispatch behind it
-                self._settle(tracer, "idle")
-            else:
-                for _ in range(waiting):
-                    self._read(tracer)
-            if waiting or batch is not None:
-                worked = True
+            for _ in range(waiting):
+                self._read(tracer)
+        if waiting or batch is not None:
+            worked = True
 
         reg.gauge("serve_active_slots",
                   "sequences resident in the decode batch").set(
@@ -834,7 +833,8 @@ class ServingEngine:
     def _send_prefill(self, tracer, programs, admitted) -> None:
         """Dispatch the from-zero prefill pass of ``admitted``.  It leaves
         each row's first token in the token array on the device, so the
-        next decode step goes out before this pass is read."""
+        next decode step goes out before this pass is read (by blocks it
+        samples nothing: a row's first block pass opens from the host's)."""
         reg = self.registry
         t0 = time.perf_counter()
         batch = self._scheduled(tracer, self.scheduler.prefill_batch,
@@ -865,11 +865,11 @@ class ServingEngine:
     def _send_decode(self, tracer, programs, batch) -> None:
         """Dispatch one decode step over ``batch`` (``decode_batch``): a
         token for every live row, read from and written to the token
-        array on the device."""
+        array on the device (by blocks: a pass over every live row's block)."""
         live = batch.pop("live")
         t0 = time.perf_counter()
-        args = self._dev(batch, "positions", "seq_lens", "page_table",
-                         "rids", "gens", "temps")
+        args = self._dev(batch, *self._decode_fields)
+        bl = self._block
         said = {}
         if tracer.enabled:
             # what the step reads, and of a pattern with recurrent state
@@ -878,13 +878,16 @@ class ServingEngine:
                         **self._context_args(batch["seq_lens"]))
             if self.cfg.state_layers:
                 said["state_slots"] = len(live)
-        self._send(tracer, _Pass("decode", live, self.serving.max_slots,
-                                 said, t0), programs["decode"], *args)
+            if bl > 1:      # masked going in: the host's count
+                said.update(positions=len(live) * bl,
+                            masked_in=sum(a.block.masked for a in live))
+        self._send(tracer, _Pass("decode", live, self._decode_out, said, t0),
+                   programs["decode"], *args)
         self.scheduler.sent(live)
         self.registry.counter(
             "serve_layer_passes_total",
             "decoder blocks run by decode steps (batch x num_layers x "
-            "loop_steps a step)").inc(len(live) * self.cfg.cache_layers)
+            "loop_steps a step)").inc(len(live) * bl * self.cfg.cache_layers)
 
     def _send(self, tracer, p: _Pass, program, *args) -> None:
         """Dispatch ``program`` (a held executable) for ``p`` over the
@@ -898,10 +901,11 @@ class ServingEngine:
             # own dispatch and the wait
             self._open(tracer, p)
         t0 = time.perf_counter()
-        carried = (cache.tokens, *args, cache.state) if p.kind == "decode" \
-            else (*args, cache.state, cache.tokens)
-        p.out, cache.k, cache.v, cache.state, cache.tokens = program(
-            self._params(), self._base_key, cache.k, cache.v, *carried)
+        p.out, cache.k, cache.v, cache.state, tokens = program(
+            self._params(), self._base_key, cache.k, cache.v,
+            *self._carried(p.kind, args))
+        if tokens is not None:
+            cache.tokens = tokens
         if p.args:
             p.args.update(ahead=ahead, dispatch_ms=round(
                 (time.perf_counter() - t0) * 1e3, 3))
@@ -910,6 +914,17 @@ class ServingEngine:
             "serve_passes_ahead_total",
             "passes dispatched while the pass before them was unread").inc(
                 ahead, kind=p.kind)
+
+    def _carried(self, kind: str, args) -> tuple:
+        """A program's arguments behind the weights, the key and the pools:
+        its batch fields and what is carried.  The token array is a
+        one-token decode step's ids; a prefill pass by blocks never sees it."""
+        cache = self.cache
+        if self._block == 1 and kind == "decode":
+            return (cache.tokens, *args, cache.state)
+        if self._block > 1 and kind == "prefill":
+            return (*args, cache.state)
+        return (*args, cache.state, cache.tokens)
 
     def _open(self, tracer, p: _Pass) -> None:
         if p.span is None:
@@ -926,6 +941,9 @@ class ServingEngine:
         self._open(tracer, p)
         toks, counts = self._split_counts(p.out, p.n_out, p.kind)
         flight.popleft()
+        if self._block > 1 and p.kind == "decode":
+            # booked inside the span, which says what came of the pass
+            counts.update(self._land_blocks(p.rows, toks))
         tracer.end(p.span, **p.args, **counts)
         now = time.perf_counter()
         took_ms = (now - p.t_from) * 1e3
@@ -934,12 +952,10 @@ class ServingEngine:
         handed = 0
         if p.kind == "decode":
             _latency(reg, "serve_decode_step_ms").observe(took_ms)
-            for a in p.rows:
-                handed += sched.landed(a, int(toks[a.slot]))
-            reg.counter("serve_tokens_dropped_total",
-                        "committed positions never handed out (a last "
-                        "block's surplus, what followed an eos)").inc(
-                            len(p.rows) - handed)
+            if self._block == 1:
+                for a in p.rows:
+                    handed += sched.landed(a, int(toks[a.slot]))
+                self._dropped(len(p.rows) - handed)
         else:
             _latency(reg, "serve_prefill_ms").observe(took_ms)
             for j, a in enumerate(p.rows):
@@ -966,7 +982,7 @@ class ServingEngine:
         self.registry.counter(
             "serve_loop_drains_total",
             "times the loop read everything in flight before going on, by "
-            "why: idle | stop | swap | incremental | block").inc(1.0, why=why)
+            "why: idle | stop | swap | incremental").inc(1.0, why=why)
         return True
 
     def _drain(self, why: str) -> bool:
@@ -989,81 +1005,49 @@ class ServingEngine:
         while flight:
             tracer.cancel(flight.pop().span)
 
-    def _block_pass(self, tracer, program, batch) -> None:
-        """One block pass over every live sequence's block in progress
-        (the decode step of a model that generates by blocks).  Out of
-        the device comes ONE int32 array: per row and position the token
-        the pass chose, whether the policy unmasked the position, the
-        confidence's float32 bits; behind them the routing counts.  A row
-        that went in with nothing masked is committed by this pass — its
-        K/V stand — and its tokens are handed to ``append_token`` one by
-        one, in position order, until the request has what it asked for
-        (the rest of the block is dropped)."""
+    def _dropped(self, n: int) -> None:
+        self.registry.counter(
+            "serve_tokens_dropped_total",
+            "positions chosen and never handed out (a last block's "
+            "surplus, what followed an eos)").inc(n)
+
+    def _land_blocks(self, rows, out) -> dict:
+        """A block pass has been read.  ``out``: per slot and position
+        the token the pass chose, whether the policy unmasked the
+        position, the confidence's float32 bits.  Each of ``rows`` books
+        its own (``Scheduler.block_landed``: a row that went in with
+        nothing masked was committed by this pass and its tokens are
+        handed out now).  -> what the pass's span says of it."""
         sched, reg, bl = self.scheduler, self.registry, self._block
-        live = batch.pop("live")
-        t0 = time.perf_counter()
-        args = self._dev(batch, "ids", "positions", "seq_lens",
-                         "page_table", "rids", "gens", "temps")
-        tk = tracer.begin("serve_decode", cat="serving", batch=len(live),
-                          positions=len(live) * bl, **self._loop_args)
-        cache = self.cache
-        out, cache.k, cache.v, cache.state = program(
-            self._params(), self._base_key, cache.k, cache.v, *args,
-            cache.state)
-        if tk is not None:
-            t_dispatched = tracer.clock()
-        n = self.serving.max_slots * bl
-        out, counts = self._split_counts(out, 3 * n, "decode")
-        toks, unmasked, conf = (out[:n].reshape(-1, bl),
-                                out[n:2 * n].reshape(-1, bl),
-                                out[2 * n:].view(np.float32).reshape(-1, bl))
-        masked_in = int(batch["ids"][:, bl:2 * bl].sum())
+        toks, unmasked, conf = out.reshape(3, -1, bl)
+        conf = conf.view(np.float32)
         handed = dropped = commits = 0
-        for a in live:
-            i = a.slot
-            ready = sched.block_pass_done(a, toks[i], unmasked[i], conf[i])
-            if ready is None:
-                continue
-            commits += 1
-            if not a.generated:
+        for a in rows:
+            i, fresh = a.slot, not a.generated
+            h, d, committed = sched.block_landed(a, toks[i], unmasked[i],
+                                                 conf[i])
+            if fresh and h:
                 a.t_first = time.perf_counter()
                 _latency(reg, "serve_ttft_ms").observe(
                     (a.t_first - a.request.arrival) * 1e3)
-            for token in ready:
-                if a.finished:
-                    dropped += 1
-                else:
-                    sched.append_token(a, token)
-                    handed += 1
-        if tk is not None:
-            tracer.end(
-                tk, **self._context_args(batch["seq_lens"]),
-                dispatch_ms=round((t_dispatched - tk.t_start) * 1e3, 3),
-                masked_in=masked_in, unmasked=int(unmasked.sum()),
-                committed=commits, commit_rows=commits, tokens_out=handed,
-                **counts)
-        _latency(reg, "serve_decode_step_ms").observe(
-            (time.perf_counter() - t0) * 1e3)
+            handed, dropped, commits = (handed + h, dropped + d,
+                                        commits + committed)
         reg.counter("serve_tokens", "tokens generated").inc(handed)
-        reg.counter(
-            "serve_layer_passes_total",
-            "decoder blocks run by decode steps (batch x num_layers x "
-            "loop_steps a step)").inc(len(live) * bl * self.cfg.cache_layers)
+        self._dropped(dropped)
         passes = reg.counter(
             "serve_block_passes_total",
             "rows of block passes, by whether the row went in with masked "
             "positions (denoise) or with none (commit)")
-        passes.inc(len(live) - commits, kind="denoise")
+        passes.inc(len(rows) - commits, kind="denoise")
         passes.inc(commits, kind="commit")
         reg.counter("serve_blocks_committed_total",
                     "blocks whose K/V a commit pass left in the cache").inc(
                         commits)
         reg.counter("serve_block_positions_total",
                     "positions block passes computed (rows x block_len)").inc(
-                        len(live) * bl)
-        reg.counter("serve_tokens_dropped_total",
-                    "committed positions never handed out (a last block's "
-                    "surplus, what followed an eos)").inc(dropped)
+                        len(rows) * bl)
+        return {"unmasked": int(unmasked.sum()), "committed": commits,
+                "commit_rows": commits, "tokens_out": handed}
 
     def _prefill_incremental(self, admitted, tracer, reg) -> bool:
         """The flag-on prefill path (prefix cache / chunked prefill):
@@ -1333,15 +1317,25 @@ def _serving_fns(cfg, attn_impl, donate, policy="low_confidence_static"):
                 jnp.where(lens > 0, toks, last))
 
     def decode_block(params, base_key, kc, vc, ids, positions, lens, table,
-                     rids, gens, temps, state=None):
+                     rids, gens, temps, state=None, last=None):
         """The block pass (``Scheduler.decode_arrays`` under a block
-        length: ``ids`` = tokens | masked flags | how many to unmask).
+        length: ``ids`` = tokens | masked flags | how many to unmask |
+        whether the row opens its block).  ``last``
+        (``PagedKVCache.tokens``): every slot's block in progress, tokens
+        | masked flags.  A row that opens its block takes the host's, any
+        other the device's own; what the pass unmasks is written back,
+        and rows that ride no pass keep theirs.  None (a caller that only
+        lowers the pass): ``ids`` as given, nothing carried.
         Out: per position the chosen token, whether the policy unmasked
         it, its confidence's float32 bits; then the routing counts."""
         bl = cfg.block_len
-        masked = ids[:, bl:2 * bl] > 0
+        masked, known = ids[:, bl:2 * bl] > 0, ids[:, :bl]
+        if last is not None:
+            opens = ids[:, 2 * bl + 1:] > 0
+            known = jnp.where(opens, known, last[:, :bl])
+            masked = jnp.where(opens, masked, last[:, bl:] > 0)
         logits, kc, vc, extras = T.forward_decode_block(
-            cfg, params, ids[:, :bl], masked, positions, lens, table, kc,
+            cfg, params, known, masked, positions, lens, table, kc,
             vc, attn_impl=attn_impl)
         toks, conf = sampling.sample_block(
             logits, base_key, rids, gens, temps)
@@ -1349,7 +1343,11 @@ def _serving_fns(cfg, attn_impl, donate, policy="low_confidence_static"):
         out = jnp.concatenate([
             toks.reshape(-1), chosen.astype(jnp.int32).reshape(-1),
             jax.lax.bitcast_convert_type(conf, jnp.int32).reshape(-1)])
-        return with_counts(out, extras), kc, vc, {}
+        if last is not None:
+            last = jnp.where((lens > 0)[:, None], jnp.concatenate(
+                [jnp.where(chosen, toks, known),
+                 (masked & ~chosen).astype(jnp.int32)], axis=1), last)
+        return with_counts(out, extras), kc, vc, {}, last
 
     if cfg.block_len > 1:
         # under the same name: the device trace's module stays jit_decode
@@ -1366,15 +1364,17 @@ def _serving_fns(cfg, attn_impl, donate, policy="low_confidence_static"):
                             extras), kc, vc, {})
 
     # the state pools ride behind the batch and are donated with the page
-    # pools, and so is the token array of the one-token programs
+    # pools, and so is the token array wherever a program is handed it (by
+    # blocks the prefill programs are not, and the block pass takes it last)
     def donated(state_at, tokens_at):
         return tuple(donate) + (
             (state_at,) if donate and cfg.state_layers else ()) + (
-            (tokens_at,) if donate and cfg.block_len == 1 else ())
+            (tokens_at,) if donate else ())
 
     fns = (jax.jit(prefill, donate_argnums=donated(10, 11)),
            jax.jit(prefill_chunk, donate_argnums=donate),
-           jax.jit(decode, donate_argnums=donated(11, 4)))
+           jax.jit(decode, donate_argnums=donated(
+               11, 4 if cfg.block_len == 1 else 12)))
     with _FN_LOCK:
         # a racing builder may have won; keep the first so every engine
         # shares one executable cache

@@ -308,14 +308,17 @@ class PagedKVCache:
     decode program reads its ``ids`` from it and writes what it samples
     back (rows that are not decoding keep what they had), so the host
     never sends a token it only just fetched.  Carried and donated like
-    the pools.  An engine whose model generates by blocks has none
-    (None): its next input is the host's choice of what to unmask."""
+    the pools.  Under a model that generates by blocks (``block_len`` > 1)
+    it is int32 [max_slots, 2 x block_len]: each slot's block in progress,
+    tokens | masked flags.  The block pass reads its input from it — or,
+    where the host opens a block, from the row the host sends — and
+    writes back what it unmasked; rows that ride no pass keep theirs."""
 
     def __init__(self, num_layers: int, num_heads: int, head_dim: int,
                  num_pages: int, page_size: int, max_slots: int,
                  max_pages_per_seq: int, dtype=None,
                  prefix_cache: bool = False, state_layers: int = 0,
-                 state_shapes: dict | None = None):
+                 state_shapes: dict | None = None, block_len: int = 1):
         import jax.numpy as jnp
 
         from paddle_tpu.ops.pallas.paged_attention import init_kv_pages
@@ -329,7 +332,9 @@ class PagedKVCache:
             name: jnp.zeros((state_layers, max_slots, *shape), jnp.float32)
             for name, shape in (state_shapes or {}).items()
         } if state_layers else {}
-        self.tokens = jnp.zeros((max_slots,), jnp.int32)
+        self.tokens = jnp.zeros(
+            (max_slots,) if block_len == 1 else (max_slots, 2 * block_len),
+            jnp.int32)
         self.allocator = PageAllocator(num_pages)
         self.page_table = np.zeros((max_slots, max_pages_per_seq), np.int32)
         self._slot_pages: dict[int, list[int]] = {}

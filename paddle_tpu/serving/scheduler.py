@@ -29,6 +29,16 @@ position, context length, sampling-key index — counts both, and the
 reservation made at admission covers both, so the next step's arrays are
 built before the last step's tokens are known.  The input token itself
 never leaves the device (``PagedKVCache.tokens``).
+
+Under a model that generates by blocks the same split runs through a
+block's bookkeeping.  Both unmasking policies unmask exactly the count
+they are given, so everything the host DECIDES follows from counts
+known when a pass is dispatched (:meth:`sent`: a block's passes and
+masked count, the pass that commits it, the next block's start, a
+finish by length); what the device CHOSE — tokens, order, confidences —
+is booked when the pass is read, one pass late (:meth:`block_landed`),
+and a block's tokens and masked flags stay on the device meanwhile
+(``PagedKVCache.tokens``).
 """
 
 from __future__ import annotations
@@ -143,20 +153,21 @@ class RequestResult:
 
 @dataclasses.dataclass
 class _Block:
-    """The block a sequence is generating: ``start`` its first absolute
-    position; per position the token (0 while masked), the denoising pass
-    that unmasked it (-1: known from the prompt, None: still masked) and
-    the confidence it had then; ``passes`` = denoising passes run."""
+    """A block a sequence is generating: ``start`` its first absolute
+    position.  Counted when a pass is dispatched: ``passes`` = denoising
+    passes sent, ``masked`` = positions still masked once they have run.
+    Booked when a pass is read: per position the token (0 while masked),
+    the denoising pass that unmasked it (-1: known from the prompt, None:
+    still masked) and the confidence it had then; ``landed`` = denoising
+    passes read."""
 
     start: int
     ids: list
     steps: list
     conf: list
+    masked: int
     passes: int = 0
-
-    @property
-    def masked(self) -> int:
-        return sum(s is None for s in self.steps)
+    landed: int = 0
 
 
 @dataclasses.dataclass
@@ -176,11 +187,14 @@ class _Active:
     cached_tokens: int = 0       # prompt tokens mapped from the prefix cache
     prefilled: int = 0           # prompt tokens whose K/V are resident
     prefill_chunks: int = 0      # incremental prefill passes run
-    # generation by blocks: the block in progress, block passes run for
-    # this request, and the trail its committed blocks leave
+    # generation by blocks: the block the next pass is for, block passes
+    # dispatched for this request, the block of every pass in flight
+    # (oldest first), and the trail its committed blocks leave
     # (``RequestResult.trail``)
     block: _Block | None = None
     block_passes: int = 0
+    unread: collections.deque = dataclasses.field(
+        default_factory=collections.deque)
     trail: dict | None = None
 
     @property
@@ -329,10 +343,23 @@ class Scheduler:
             a.finished = "length"
 
     def sent(self, rows: list[_Active]) -> None:
-        """A pass that samples one token for each of ``rows`` has been
-        dispatched."""
+        """A pass that samples for each of ``rows`` has been dispatched:
+        one token a row — or, by blocks, one pass over the row's block,
+        whose outcome in counts is known now: a denoising pass unmasks
+        ``unmask_count`` positions, a pass over nothing masked commits
+        the block and the next one opens."""
         for a in rows:
             a.in_flight += 1
+            blk = a.block
+            if blk is None:
+                continue
+            a.unread.append(blk)
+            a.block_passes += 1
+            if blk.masked:
+                blk.masked -= self.unmask_count(blk)
+                blk.passes += 1
+            else:
+                self._open_block(a, blk.start + self.block_len)
 
     def landed(self, a: _Active, token: int) -> bool:
         """One in-flight token of ``a`` has been read: hand it out — unless
@@ -353,7 +380,7 @@ class Scheduler:
         rest = self.block_len - len(known)
         a.block = _Block(start=start, ids=list(known) + [0] * rest,
                          steps=[-1] * len(known) + [None] * rest,
-                         conf=[1.0] * len(known) + [0.0] * rest)
+                         conf=[1.0] * len(known) + [0.0] * rest, masked=rest)
 
     def unmask_count(self, blk: _Block) -> int:
         """Positions the next pass over ``blk`` unmasks: its masked ones
@@ -362,31 +389,42 @@ class Scheduler:
         left = max(self.serving.denoise_steps - blk.passes, 1)
         return -(-blk.masked // left)
 
-    def block_pass_done(self, a: _Active, tokens, unmasked, conf) -> list | None:
-        """Book one block pass's result for ``a``: ``tokens`` / ``conf``
-        [block_len] what the pass chose at every position and how sure it
-        was, ``unmasked`` [block_len] the positions its policy unmasked.
-        A pass that found nothing masked has left the block's K/V in the
-        cache: the block is committed, its generated positions join the
-        trail, the next block opens, and the tokens to hand out come
-        back, in position order (None: a denoising pass)."""
-        blk = a.block
-        a.block_passes += 1
-        if blk.masked:
+    def block_landed(self, a: _Active, tokens, unmasked,
+                     conf) -> tuple[int, int, bool]:
+        """The oldest block pass of ``a`` in flight has been read:
+        ``tokens`` / ``conf`` [block_len] what it chose at every position
+        and how sure it was, ``unmasked`` [block_len] the positions its
+        policy unmasked.  A pass that went in over nothing masked has left
+        the block's K/V in the cache: the block is committed, its
+        generated positions join the trail and are handed out
+        (``append_token``), in position order, until the request has what
+        it asked for.  An ``eos`` is data, seen one pass late: what the
+        surplus pass behind it unmasked is dropped, its block never
+        committed.  -> (tokens handed out, positions dropped, committed)."""
+        a.in_flight -= 1
+        blk = a.unread.popleft()
+        if a.finished:
+            return 0, int(sum(unmasked)), False
+        if None in blk.steps:
             for t in range(self.block_len):
                 if unmasked[t]:
                     enforce(blk.steps[t] is None,
                             "a block pass unmasked a known position")
-                    blk.ids[t], blk.steps[t] = int(tokens[t]), blk.passes
+                    blk.ids[t], blk.steps[t] = int(tokens[t]), blk.landed
                     blk.conf[t] = float(conf[t])
-            blk.passes += 1
-            return None
+            blk.landed += 1
+            return 0, 0, False
         first = max(a.prompt_len - blk.start, 0)   # the prompt's tail
         for name, vals in (("tokens", blk.ids), ("steps", blk.steps),
                            ("confidence", blk.conf)):
             a.trail[name].extend(vals[first:])
-        self._open_block(a, blk.start + self.block_len)
-        return blk.ids[first:]
+        handed = 0
+        for token in blk.ids[first:]:
+            if a.finished:
+                break
+            self.append_token(a, token)
+            handed += 1
+        return handed, self.block_len - first - handed, True
 
     def retire_finished(self) -> list[_Active]:
         """Free the pages + slots of finished sequences; returns them."""
@@ -402,10 +440,16 @@ class Scheduler:
         sequences, or None when there are none.  Sequences still
         mid-prefill (incremental path: no token sampled yet) are not
         decoded, and neither is one whose ``max_new_tokens`` the tokens
-        in flight already reach: it is known finished without reading
-        anything and rides no further pass."""
-        live = [a for a in self.live if a.block is not None
-                or 0 < a.sampled < a.request.max_new_tokens]
+        in flight already reach — by blocks, whose next block would open
+        past the last position it asked for: it is known finished without
+        reading anything and rides no further pass."""
+        def rides(a):
+            if a.block is not None:
+                return (a.block.start
+                        < a.prompt_len + a.request.max_new_tokens)
+            return 0 < a.sampled < a.request.max_new_tokens
+
+        live = [a for a in self.live if rides(a)]
         return self.decode_arrays(live) if live else None
 
     def decode_arrays(self, live: list[_Active]) -> dict:
@@ -416,13 +460,17 @@ class Scheduler:
         (``ServingEngine._make_ready``).
 
         Under a block length ``B`` > 1 a row is its sequence's block in
-        progress, under the same names: ``ids`` [slots, 2B + 1] = the
+        progress, under the same names: ``ids`` [slots, 2B + 2] = the
         block's tokens | its masked flags | how many masked positions
-        this pass unmasks (0: the pass commits the block); ``positions``
-        the block's first position; ``seq_lens`` the context the block's
-        positions read, the block itself included (start + B); ``gens``
-        the index of the row's first position in its request's sampling
-        keys (block passes run so far x B)."""
+        this pass unmasks (0: the pass commits the block) | whether the
+        pass OPENS the block: its first pass takes tokens and flags from
+        this row (the prompt's tail known, the rest masked), every later
+        one from the device's own copy (``PagedKVCache.tokens``) and the
+        row's are zeros; ``positions`` the block's first position;
+        ``seq_lens`` the context the block's positions read, the block
+        itself included (start + B); ``gens`` the index of the row's
+        first position in its request's sampling keys (block passes
+        dispatched so far x B)."""
         if self.block_len > 1:
             return self._block_arrays(live)
         n = self.serving.max_slots
@@ -454,7 +502,7 @@ class Scheduler:
 
     def _block_arrays(self, live: list[_Active]) -> dict:
         n, bl = self.serving.max_slots, self.block_len
-        ids = np.zeros((n, 2 * bl + 1), np.int32)
+        ids = np.zeros((n, 2 * bl + 2), np.int32)
         positions = np.zeros((n,), np.int32)
         seq_lens = np.zeros((n,), np.int32)
         rids = np.zeros((n,), np.int32)
@@ -463,8 +511,10 @@ class Scheduler:
         table = np.zeros_like(self.cache.page_table)
         for a in live:
             i, blk = a.slot, a.block
-            ids[i, :bl] = blk.ids
-            ids[i, bl:2 * bl] = [s is None for s in blk.steps]
+            if not blk.passes:      # no pass has touched it: as opened
+                ids[i, :bl] = blk.ids
+                ids[i, bl:2 * bl] = [s is None for s in blk.steps]
+                ids[i, 2 * bl + 1] = 1
             ids[i, 2 * bl] = self.unmask_count(blk)
             positions[i] = blk.start
             seq_lens[i] = blk.start + bl

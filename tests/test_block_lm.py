@@ -230,6 +230,230 @@ def test_temperature_draws_are_a_function_of_the_seed(params, prompts):
     assert a == b and a != c and a != greedy
 
 
+# -- one pass ahead ---------------------------------------------------------------
+# Block pass n + 1 is dispatched before pass n is read: a block's tokens
+# and masked flags stay on the device, the scheduler counts at dispatch
+# what the host decides and books at the read what the device chose.  The
+# oracle is the same engine with every pass read before the next is built.
+
+
+def _watched(eng):
+    """Every hand-out of ``eng``, in order: (request, index, token)."""
+    handed, inner = [], eng.scheduler.append_token
+
+    def watch(a, token):
+        handed.append((a.request.id, len(a.generated), token))
+        inner(a, token)
+
+    eng.scheduler.append_token = watch
+    return handed
+
+
+def _run_drained(eng):
+    while eng.step():
+        eng._drain("sync")
+
+
+def _same_answers(got, want):
+    assert sorted(got) == sorted(want)
+    for rid, r in got.items():
+        w = want[rid]
+        assert (r.tokens, r.finish_reason) == (w.tokens, w.finish_reason)
+        assert r.trail["tokens"] == w.trail["tokens"]
+        assert r.trail["steps"] == w.trail["steps"]
+        np.testing.assert_allclose(r.trail["confidence"],
+                                   w.trail["confidence"], atol=1e-6)
+
+
+@pytest.mark.parametrize("policy", ["low_confidence_static", "sequential"])
+@pytest.mark.parametrize("steps", [1, 2, 3])
+def test_the_background_loop_equals_the_loop_drained(steps, policy, params,
+                                                     prompts):
+    """Seven requests through three slots, queued before the loop starts:
+    the background loop, one pass ahead, gives every request the tokens,
+    the trail (tokens, steps, confidences) and the order of hand-out that
+    the engine gives it when every pass is read before the next; nothing
+    is left in flight and every pass sent was read."""
+    def run(background):
+        eng = engine(params, denoise_steps=steps, unmask_policy=policy)
+        handed = _watched(eng)
+        seen, admit = [], eng.scheduler.admit
+
+        def admitted(now=0.0):
+            out = admit(now=now)
+            seen.extend(out)
+            return out
+
+        eng.scheduler.admit = admitted
+        for p in prompts:
+            eng.submit(p, 10)
+        if background:
+            eng.start()
+            try:
+                res = eng.results(n=len(prompts), timeout=300.0)
+            finally:
+                eng.stop()
+        else:
+            _run_drained(eng)
+            res = eng.results()
+        assert not eng._in_flight and len(seen) == len(prompts)
+        assert all(a.in_flight == 0 and not a.unread for a in seen)
+        return eng, {r.id: r for r in res}, handed
+
+    ahead, got, handed = run(True)
+    sync, want, handed0 = run(False)
+    _same_answers(got, want)
+    per = lambda h, rid: [(i, t) for r, i, t in h if r == rid]
+    for rid in got:
+        assert per(handed, rid) == per(handed0, rid)
+        assert [t for _, t in per(handed, rid)] == got[rid].tokens
+    val = lambda e, name, **lab: e.registry.get(name).value(**lab)
+    # the same passes, row for row, whenever they were read
+    for name, lab in (("serve_block_passes_total", {"kind": "denoise"}),
+                      ("serve_block_passes_total", {"kind": "commit"}),
+                      ("serve_block_positions_total", {}),
+                      ("serve_tokens_dropped_total", {}),
+                      ("serve_tokens", {})):
+        assert val(ahead, name, **lab) == val(sync, name, **lab)
+    assert val(ahead, "serve_passes_ahead_total", kind="decode") > 0
+    assert ahead.cache.allocator.free_pages == SERVING["num_pages"] - 1
+
+
+def test_the_order_of_hand_out_when_nobody_waits_for_a_slot(params, prompts):
+    """Three requests in three slots: the passes are the same passes, so
+    ahead and drained hand the same tokens out in the same order,
+    request against request."""
+    def run(drained):
+        eng = engine(params)
+        handed = _watched(eng)
+        for p in prompts[:3]:
+            eng.submit(p, 9)
+        _run_drained(eng) if drained else eng.run_until_idle()
+        return handed, eng
+
+    handed, eng = run(False)
+    handed0, sync = run(True)
+    assert handed == handed0 and len(handed) == 27
+    steps = lambda e: e.registry.get("serve_decode_step_ms").summary()["count"]
+    assert steps(eng) == steps(sync)
+    assert eng.registry.get("serve_loop_drains_total").value(why="idle") == 1
+
+
+def test_an_eos_inside_a_block_is_seen_one_pass_late(params, prompts):
+    """The first pass of the next block is out when the commit that holds
+    the eos is read: that surplus pass is dropped and counted, its block
+    never committed, nothing of it handed out, the pages freed -- against
+    a run in which the request ends at that token by LENGTH, which is
+    known at dispatch and costs no surplus pass."""
+    hot = 1.5       # sampled, not greedy: a drawn toy repeats itself
+    base = engine(params).generate(prompts[:3], max_new_tokens=10,
+                                   temperature=hot)
+    # a token nobody else draws, first drawn inside a block that is not
+    # its request's last
+    who, at = next(
+        (k, i) for k, r in enumerate(base) for i, tok in enumerate(r.tokens)
+        if tok not in r.tokens[:i]
+        and all(tok not in o.tokens for o in base if o is not r)
+        and (len(r.prompt) + i) // BL * BL + BL < len(r.prompt) + 10)
+    eos, cut = base[who].tokens[at], at + 1
+
+    def run(**kw):
+        reg = MetricsRegistry("block_eos")
+        eng = engine(params, registry=reg, **kw)
+        news = [10] * 3
+        if "eos_id" not in kw:
+            news[who] = cut
+        ids = [eng.submit(p, n, hot) for p, n in zip(prompts[:3], news)]
+        eng.run_until_idle()
+        got = {r.id: r for r in eng.results()}
+        return eng, reg, [got[i] for i in ids]
+
+    late, reg, got = run(eos_id=eos)
+    by_len, reg0, want = run()
+    for k, (r, w, b) in enumerate(zip(got, want, base)):
+        assert r.tokens == w.tokens == (b.tokens[:cut] if k == who
+                                        else b.tokens)
+        assert r.trail == w.trail           # the committed blocks only
+        assert (r.finish_reason, w.finish_reason) == (
+            ("eos", "length") if k == who else ("length", "length"))
+    val = lambda r, name: r.get(name).value()
+    # one more row of one block pass, whatever it unmasked dropped
+    rows = BL * late.cfg.cache_layers
+    assert val(reg, "serve_layer_passes_total") == (
+        val(reg0, "serve_layer_passes_total") + rows)
+    assert val(reg, "serve_block_positions_total") == (
+        val(reg0, "serve_block_positions_total") + BL)
+    surplus = val(reg, "serve_tokens_dropped_total") - val(
+        reg0, "serve_tokens_dropped_total")
+    assert 1 <= surplus <= BL
+    assert val(reg, "serve_tokens") == val(reg0, "serve_tokens") == cut + 20
+    assert val(reg, "serve_blocks_committed_total") == val(
+        reg0, "serve_blocks_committed_total")
+    assert not late._in_flight
+    assert late.cache.allocator.free_pages == SERVING["num_pages"] - 1
+
+
+def test_a_reused_slot_opens_from_the_hosts_row(ref, weights, params,
+                                                prompts):
+    """One slot.  Whatever block its last sequence -- or anything else --
+    left in the device's copy, a new request's first pass takes tokens
+    and flags from the row the host sends; only the pass that OPENS a
+    block carries one."""
+    eng = engine(params, max_slots=1, prefill_batch=1, num_pages=6)
+    eng.generate(prompts[:1], max_new_tokens=7)
+    left = np.asarray(eng.cache.tokens)
+    assert left.shape == (1, 2 * BL) and left[0, :BL].any()
+    assert not left[0, BL:].any()       # a committed block: nothing masked
+    # worse than a predecessor: every position "known", all the wrong token
+    eng.cache.tokens = jnp.full_like(eng.cache.tokens, 5).at[:, BL:].set(0)
+    sent, arrays = [], eng.scheduler.decode_arrays
+
+    def watch(live):
+        out = arrays(live)
+        if live:
+            sent.append(out["ids"][0].copy())
+        return out
+
+    eng.scheduler.decode_arrays = watch
+    r, = eng.generate(prompts[1:2], max_new_tokens=7)
+    want = ref.generate(weights, M, r.prompt, 7, 2)
+    assert (r.tokens, r.trail["steps"]) == (want["tokens"][:7], want["steps"])
+    opens = [int(row[2 * BL + 1]) for row in sent]
+    # 8 prompt tokens, 7 new: two blocks of (open, denoise, commit)
+    assert opens == [1, 0, 0, 1, 0, 0]
+    assert all(not row[:2 * BL].any() for row, o in zip(sent, opens) if not o)
+    assert [int(row[2 * BL]) for row in sent] == [2, 2, 0, 2, 2, 0]
+
+
+@pytest.mark.parametrize("via", ["set_params", "stop"])
+def test_a_drain_mid_block_loses_nothing(via, params, prompts):
+    """A weight swap's drain, or ``stop()``, with a block half unmasked
+    and its next pass in flight: the pass is read and booked, and the
+    engine goes on to the same answers."""
+    want = {r.id: r for r in engine(params).generate(
+        prompts[:4], max_new_tokens=10)}
+    reg = MetricsRegistry("mid_block")
+    eng = engine(params, registry=reg)
+    for p in prompts[:4]:
+        eng.submit(p, 10)
+    for _ in range(2):
+        assert eng.step()
+    # the third request's prefill pass and the second block pass
+    assert [p.kind for p in eng._in_flight] == ["prefill", "decode"]
+    mid = [a for a in eng.scheduler.active if a.unread]
+    half = lambda blk: blk.landed == 1 and None in blk.steps
+    assert [half(a.unread[0]) for a in mid] == [True, True, False]
+    if via == "stop":
+        eng.stop()
+    else:
+        eng.set_params(eng.params)
+    assert not eng._in_flight and all(not a.unread for a in mid)
+    assert reg.get("serve_loop_drains_total").value(why={
+        "stop": "stop", "set_params": "swap"}[via]) == 1
+    eng.run_until_idle()        # never threaded: it keeps serving
+    _same_answers({r.id: r for r in eng.results()}, want)
+
+
 # -- what a pass counts ----------------------------------------------------------
 
 
@@ -286,24 +510,44 @@ def test_unmask_count_spreads_the_block_over_the_passes(steps, want):
     sched = Scheduler(s, cache, block_len=BL)
     sched.enqueue(Request(id=0, prompt=list(range(8)), max_new_tokens=4))
     a, = sched.admit()
-    got = []
-    while a.block.masked:
-        n = sched.unmask_count(a.block)
+    got, blk = [], a.block
+    while blk.masked:
+        n = sched.unmask_count(blk)
         got.append(n)
+        ids = sched.decode_arrays([a])["ids"]
+        assert ids.shape == (s.max_slots, 2 * BL + 2)
+        assert ids[0, 2 * BL] == n
+        # the pass that opens the block carries the host's row, a later
+        # one nothing: its input is the device's own copy
+        opens = len(got) == 1
+        assert ids[0, 2 * BL + 1] == opens
+        assert ids[0, BL:2 * BL].sum() == (BL if opens else 0)
         arrays = sched.decode_arrays([a])
-        assert arrays["ids"].shape == (s.max_slots, 2 * BL + 1)
-        assert arrays["ids"][0, 2 * BL] == n
-        assert arrays["ids"][0, BL:2 * BL].sum() == a.block.masked
         assert (arrays["positions"][0], arrays["seq_lens"][0]) == (8, 12)
-        free = [t for t in range(BL) if a.block.steps[t] is None][:n]
-        assert sched.block_pass_done(
-            a, [7] * BL, [t in free for t in range(BL)], [0.5] * BL) is None
+        assert arrays["gens"][0] == (len(got) - 1) * BL
+        before = blk.masked
+        sched.sent([a])     # counted at dispatch: nothing has been read
+        assert blk.masked == before - n and a.in_flight == len(got)
+        assert blk.steps == [None] * BL
     assert got == want
-    assert sched.unmask_count(a.block) == 0
-    assert sched.block_pass_done(a, [0] * BL, [False] * BL,
-                                 [0.0] * BL) == [7] * BL
-    assert a.block.start == 12 and a.block.masked == BL
-    assert a.block_passes == len(want) + 1
+    assert sched.unmask_count(blk) == 0
+    sched.sent([a])         # the pass that commits it: the next block opens
+    assert a.block is not blk and (a.block.start, a.block.masked) == (12, BL)
+    assert a.block_passes == len(want) + 1 == a.in_flight
+    # every position it asked for is in flight: it rides no further pass
+    assert sched.decode_batch() is None and not a.finished
+    # the reads, one pass late each
+    for k, n in enumerate(want):
+        free = [t for t in range(BL) if blk.steps[t] is None][:n]
+        assert sched.block_landed(
+            a, [7] * BL, [t in free for t in range(BL)],
+            [0.5] * BL) == (0, 0, False)
+        assert sorted(x for x in blk.steps if x is not None)[-1] == k
+    assert not a.generated and a.trail["tokens"] == []
+    assert sched.block_landed(a, [0] * BL, [False] * BL,
+                              [0.0] * BL) == (BL, 0, True)
+    assert a.generated == a.trail["tokens"] == [7] * BL
+    assert a.finished == "length" and a.in_flight == 0 and not a.unread
 
 
 @pytest.mark.parametrize("policy,want", [

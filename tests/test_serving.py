@@ -1291,7 +1291,10 @@ class TestKvPoolPreflightGate:
 # -- one pass ahead ---------------------------------------------------------------
 # The loop dispatches pass n + 1 before it reads pass n: a step's input
 # token stays on the device, the scheduler counts tokens in flight.  Same
-# tokens, request for request, as a plain forward of the same model.
+# tokens, request for request, as a plain forward of the same model -- or,
+# for a model that generates by blocks (its input is the block in progress,
+# tokens and masked flags; tests/test_block_lm.py holds it to its plain
+# reference), as the same engine with every pass read before the next.
 
 _AHEAD_CFGS = {
     "dense": dict(),
@@ -1306,6 +1309,10 @@ _AHEAD_CFGS = {
         moe_router="sigmoid", moe_top_k=2, moe_scale=2.5, moe_shared_dim=40,
         moe_held=[0, 8], mamba_heads=4, mamba_head_dim=8, mamba_state=16,
         mamba_groups=2, mamba_conv=4, mamba_chunk=8),
+    # generation by diffusion over blocks of 4: the decode step is a
+    # block pass, a prefill pass samples nothing
+    "block": dict(block_len=4, mask_id=63, norm="rms", positions="rotary",
+                  qk_norm=True),
 }
 _PAD = 32   # every plain forward at one shape (causal: the tail is unseen)
 
@@ -1354,6 +1361,13 @@ def _in_flight(eng):
     return len(eng._in_flight)
 
 
+def _run_drained(eng):
+    """The synchronous order: whatever an iteration left in flight is
+    read before the next one builds anything."""
+    while eng.step():
+        eng._drain("sync")
+
+
 class TestOnePassAhead:
     @pytest.mark.parametrize("temperature", [0.0, 0.9],
                              ids=["greedy", "seeded"])
@@ -1381,10 +1395,22 @@ class TestOnePassAhead:
         eng.run_until_idle()
         assert _in_flight(eng) == 0
         got = {r.id: r for r in eng.results()}
+        want = lambda rid, prompt, n: _plain_generation(
+            cfg, params, prompt, n, rid, temperature, seed=5)
+        if kind == "block":
+            sync = _ahead_engine(kind)
+            assert ids == [sync.submit(p, n, temperature)
+                           for p, n in zip(prompts, news)]
+            _run_drained(sync)
+            drained = {r.id: r for r in sync.results()}
+            want = lambda rid, prompt, n: drained[rid].tokens
+            for rid in ids:
+                assert got[rid].trail["tokens"] == drained[rid].trail["tokens"]
+                assert got[rid].trail["steps"] == drained[rid].trail["steps"]
         for rid, prompt, n in zip(ids, prompts, news):
             assert got[rid].finish_reason == "length"
-            assert got[rid].tokens == _plain_generation(
-                cfg, params, prompt, n, rid, temperature, seed=5), (kind, rid)
+            assert got[rid].tokens == want(rid, prompt, n), (kind, rid)
+            assert len(got[rid].tokens) == n
 
     def test_an_eos_is_seen_one_pass_late(self, rng_np):
         """The pass after the one that sampled an eos is already queued
@@ -1465,6 +1491,49 @@ class TestOnePassAhead:
                                               v0[:, :, page, off])
         assert late.cache.allocator.free_pages == 47
 
+    @pytest.mark.parametrize("kind", ["dense", "block"])
+    def test_a_busy_run_reads_every_pass_once_and_never_drains(self, kind,
+                                                               rng_np):
+        """Seven requests through three slots, all queued before the first
+        iteration: every pass is read exactly once, in the order it was
+        dispatched; every pass but the first went out behind an unread one
+        (``serve_passes_ahead_total``); the loop drains once, when nothing
+        is left to dispatch, after its last pass."""
+        reg = MetricsRegistry("busy")
+        eng = _ahead_engine(kind, reg)
+        value = lambda name, **lab: (reg.get(name).value(**lab)
+                                     if reg.get(name) else 0)
+        drained = lambda: sum(value("serve_loop_drains_total", why=w)
+                              for w in ("idle", "stop", "swap", "incremental"))
+        sent, reads, drains_at_send = [], [], []
+        send, split = eng._send, eng._split_counts
+
+        def watch_send(tracer, p, program, *args):
+            drains_at_send.append(drained())
+            send(tracer, p, program, *args)
+            sent.append(p)
+
+        def watch_read(out, rows, where):
+            reads.append(out)
+            return split(out, rows, where)
+
+        eng._send, eng._split_counts = watch_send, watch_read
+        news = (8, 3, 5, 1, 7, 2, 6)
+        for n in news:
+            eng.submit([int(t) for t in rng_np.integers(1, 63, 1 + n)], n)
+        eng.run_until_idle()
+        assert sorted(len(r.tokens) for r in eng.results()) == sorted(news)
+        assert len(reads) == len(sent) and _in_flight(eng) == 0
+        assert all(out is p.out for out, p in zip(reads, sent))
+        decodes = sum(p.kind == "decode" for p in sent)
+        # a decode step always follows something unread: the step before
+        # it, or the prefill pass that admitted its first rows
+        assert value("serve_passes_ahead_total", kind="decode") == decodes
+        assert value("serve_passes_ahead_total", kind="prefill") == (
+            len(sent) - decodes - 1)
+        assert set(drains_at_send) == {0} and drained() == 1
+        assert value("serve_loop_drains_total", why="idle") == 1
+
     def test_a_finish_by_length_needs_no_read(self, rng_np):
         """A sequence whose ``max_new_tokens`` the tokens in flight reach
         rides no further pass: decode steps run one row-layer for every
@@ -1540,18 +1609,19 @@ class TestOnePassAhead:
         assert sorted(r.id for r in got) == ids and _in_flight(eng) == 0
         assert sorted(len(r.tokens) for r in got) == [1, 2, 4, 6, 8]
 
-    def test_drains_from_another_thread_race_nothing(self, rng_np):
+    @pytest.mark.parametrize("kind", ["dense", "block"])
+    def test_drains_from_another_thread_race_nothing(self, kind, rng_np):
         """``set_params`` (a weight swap's drain) from the caller's thread while
         the background loop runs one pass ahead: every pass is read once,
         every request gets the tokens it gets alone."""
         import sys
         import threading
 
-        cfg, params = _ahead_model("dense")
-        prompts = [[int(t) for t in rng_np.integers(1, 64, 4 + i % 5)]
+        cfg, params = _ahead_model(kind)
+        prompts = [[int(t) for t in rng_np.integers(1, 63, 4 + i % 5)]
                    for i in range(12)]
         news = [2 + i % 6 for i in range(12)]
-        eng = _ahead_engine("dense")
+        eng = _ahead_engine(kind)
         swaps, done = [0], threading.Event()
 
         def swapper():
@@ -1575,9 +1645,16 @@ class TestOnePassAhead:
         assert not t.is_alive() and swaps[0] > 0 and _in_flight(eng) == 0
         assert sorted(r.id for r in got) == ids
         by_id = {r.id: r.tokens for r in got}
+        want = lambda rid, prompt, n: _plain_generation(
+            cfg, params, prompt, n, rid, 0.0, seed=5)
+        if kind == "block":     # mid-block drains: the engine never drained
+            sync = _ahead_engine(kind)
+            assert ids == [sync.submit(p, n) for p, n in zip(prompts, news)]
+            sync.run_until_idle()
+            alone = {r.id: r.tokens for r in sync.results()}
+            want = lambda rid, prompt, n: alone[rid]
         for rid, prompt, n in zip(ids, prompts, news):
-            assert by_id[rid] == _plain_generation(
-                cfg, params, prompt, n, rid, 0.0, seed=5)
+            assert by_id[rid] == want(rid, prompt, n)
 
     def test_a_failing_pass_fails_the_pending_requests(self, rng_np):
         """A device error surfaces where the pass is read, one pass late:
@@ -1642,3 +1719,105 @@ class TestOnePassAhead:
         eng.set_params(eng.params)
         eng.stop()
         assert reads == ["prefill", "decode"] and _in_flight(eng) == 0
+
+
+# -- the programs' text -----------------------------------------------------------
+# What generation by blocks added to the loop (PR 35: the block in
+# progress carried on the device) is chosen by ``cfg.block_len``; with a
+# block length of 1 the serving programs are the ones they were, and so
+# are a block model's prefill programs and its block pass as a caller
+# lowers it that hands it no block state.
+
+
+def _program_texts(kind):
+    """The StableHLO of the programs an engine of ``kind`` compiles at
+    its first admission (``_make_ready``): each member of the prefill
+    ladder and the decode step, token array among the arguments — by
+    blocks the prefill programs without it and the block pass in the
+    12-argument form of ``serve_block_lm.aot_programs``."""
+    eng = _ahead_engine(kind)
+    cache, sched, bl = eng.cache, eng.scheduler, eng.cfg.block_len
+    head = (eng.params, eng._base_key, cache.k, cache.v)
+    texts = {}
+    for n in sched.prefill_rows:
+        args = eng._dev(sched.prefill_arrays([], n), "ids", "seq_lens",
+                        "page_table", "rids", "temps", "slots")
+        texts[f"prefill {n}"] = eng._prefill.lower(
+            *head, *args, cache.state,
+            *([cache.tokens] if bl == 1 else [])).as_text()
+    batch = sched.decode_arrays([])
+    args = eng._dev(batch, "positions", "seq_lens", "page_table", "rids",
+                    "gens", "temps")
+    ids = cache.tokens
+    if bl > 1:
+        ids = jnp.asarray(batch["ids"][:, :2 * bl + 1])
+    texts["decode"] = eng._decode.lower(
+        *head, ids, *args, cache.state).as_text()
+    return texts
+
+
+# sha256 of each text as the tree BEFORE PR 35 lowers it (commit ceaf5eb,
+# this file's helper run against a checkout of it), under the jax the
+# hashes were taken with
+_PARENT_JAX = "0.9.0"
+_PARENT_TEXTS = {
+    "dense": {"prefill 1": "f51f01045fdddbbf", "prefill 2": "acb55758a61e8117",
+              "decode": "4aa4c8ac1aff7f45"},
+    "looped": {"prefill 1": "8381819b3f182b7d",
+               "prefill 2": "0ce97baf97b3f688",
+               "decode": "88a364c4a92ed73e"},
+    "pattern": {"prefill 1": "b438709ba0f50853",
+                "prefill 2": "a87c11c902af4a0f",
+                "decode": "01201bb80137c1dc"},
+    "block": {"prefill 1": "d51d030ccd1e4bb1", "prefill 2": "816adfd62cf8cff6",
+              "decode": "fa73163fd5696615"},
+}
+
+
+@pytest.mark.skipif(jax.__version__ != _PARENT_JAX,
+                    reason="the recorded texts are another jax's")
+@pytest.mark.parametrize("kind", list(_AHEAD_CFGS))
+def test_programs_lower_to_the_parents_text(kind):
+    import hashlib
+
+    got = {name: hashlib.sha256(text.encode()).hexdigest()[:16]
+           for name, text in _program_texts(kind).items()}
+    assert got == _PARENT_TEXTS[kind]
+
+
+def test_the_block_pass_lowers_without_the_state_array():
+    """The 12-argument call ``benchmarks/drivers/serve_block_lm.py:
+    aot_programs`` makes: ``ids`` [slots, 2B + 1] as given and no block
+    state.  Nothing is carried then, and the pass computes what the
+    engine's own 13-argument program computes for a row that opens its
+    block from the same ids."""
+    eng = _ahead_engine("block")
+    cache, sched, bl = eng.cache, eng.scheduler, eng.cfg.block_len
+    eng.submit([3, 1, 4, 1, 5, 9], 4)
+    with eng._pump:
+        sched.enqueue(eng._incoming.popleft())
+    live = sched.admit()
+    batch = sched.decode_arrays(live)
+    assert batch["ids"].shape == (3, 2 * bl + 2)
+    assert batch["ids"][0].tolist() == [5, 9, 0, 0, 0, 0, 1, 1, 1, 1]
+    head = (eng.params, eng._base_key, cache.k, cache.v)
+    rest = eng._dev(batch, "positions", "seq_lens", "page_table", "rids",
+                    "gens", "temps")
+    ids = jnp.asarray(batch["ids"])
+    lowered = eng._decode.lower(*head, ids[:, :2 * bl + 1], *rest, {})
+    out, _, _, _, none = lowered.compile()(
+        *head, ids[:, :2 * bl + 1], *rest, {})
+    want, _, _, _, block = eng._decode(*head, ids, *rest, {}, cache.tokens)
+    assert none is None and block.shape == (3, 2 * bl)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    # and the state the engine's program leaves: what the pass unmasked
+    n = 3 * bl
+    toks, chosen = (np.asarray(want)[:n].reshape(3, bl),
+                    np.asarray(want)[n:2 * n].reshape(3, bl))
+    assert chosen[0].sum() == batch["ids"][0, 2 * bl] == 1
+    assert not chosen[0, :2].any()              # the prompt's tail is known
+    assert np.asarray(block)[0, :bl].tolist() == [
+        t if c else k for t, c, k in zip(toks[0], chosen[0], [5, 9, 0, 0])]
+    assert np.asarray(block)[0, bl:].tolist() == [
+        int(m and not c) for m, c in zip([0, 0, 1, 1], chosen[0])]
+    assert not np.asarray(block)[1:].any()      # rows that ride no pass

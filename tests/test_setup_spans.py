@@ -234,9 +234,9 @@ def test_import_is_a_span_once_the_process_tracer_is_armed(tracer):
 # -- the engine -------------------------------------------------------------------
 
 
-def _engine(**kw):
+def _engine(vocab_size=64, **kw):
     cfg = T.TransformerConfig(
-        vocab_size=64, num_layers=1, num_heads=2, embed_dim=32,
+        vocab_size=vocab_size, num_layers=1, num_heads=2, embed_dim=32,
         mlp_dim=64, max_seq_len=64, remat=False)
     params = T.init_params(cfg, jax.random.key(1))
     serving = dict(max_slots=2, page_size=4, num_pages=32, max_prompt_len=8,
@@ -253,7 +253,12 @@ def _inside(child, parent) -> bool:
 
 @pytest.mark.serving
 def test_engine_ready_holds_one_program_ready_a_program(tracer):
-    eng = _engine()
+    # a model of this test's own: engines of one configuration share their
+    # jitted functions (``_serving_fns``), so a process that served the
+    # common toy before -- another test file on this xdist worker -- finds
+    # every program lowered and compiled, and jax fires no event at all
+    # ("engine ready in 0.01 s")
+    eng = _engine(vocab_size=67)
     (init,) = [s for s in tracer.spans if s.name == "engine_init"]
     assert init.cat == "setup" and init.args["params_bytes"] > 0
     assert init.args["pool_bytes"] == eng.cache.k.nbytes + eng.cache.v.nbytes
@@ -272,8 +277,11 @@ def test_engine_ready_holds_one_program_ready_a_program(tracer):
     for p in programs:
         assert p.parent_id == ready.span_id and _inside(p, ready)
         kids = [s for s in spans if s.parent_id == p.span_id]
-        # every lower().compile() shows what XLA did for it
-        assert {k.name for k in kids} >= {"xla_lower"}
+        # every lower().compile() shows what XLA did for it: the build is
+        # always a span (a trace or a lowering only from 5 ms up, which is
+        # the host's speed: under that it is counted)
+        assert len({k.name for k in kids}
+                   & {"xla_compile", "xla_cache_fetch"}) == 1
         assert p.args.get("compiles", 0) + p.args.get("cache_fetches", 0) == 1
         assert all(k.cat == "xla" and _inside(k, p) for k in kids)
     # ready in the step that admitted the first request
