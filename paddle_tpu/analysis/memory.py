@@ -355,8 +355,9 @@ def serving_memory_report(cfg, serving, params=None, cache=None) -> dict:
     dtype: ``cache layers × kv_heads × pages × page_size × head_dim``, the
     heads rounded up to whole lane groups; cache layers = ``num_layers ×
     loop_steps``: a looped stack keeps one cache per pass; under a layer
-    pattern its attention layers) and the float32 recurrent-state pool
-    (``state_layers × max_slots`` rows of ``cfg.state_shapes``) next to the
+    pattern its attention layers) and the float32 state pools
+    (``cfg.state_parts``: ``max_slots`` rows a layer that keeps the part,
+    whatever kind of layer that is) next to the
     servable params — the same artifact :func:`memory_report` computes for training, so an
     oversized pool is a preflight failure, not an OOM at the first
     admission.  ``cfg`` is a TransformerConfig, ``serving`` a
@@ -376,9 +377,9 @@ def serving_memory_report(cfg, serving, params=None, cache=None) -> dict:
         cfg.cache_layers, cfg.kv_heads, serving.num_pages,
         serving.page_size, cfg.head_dim))) * int(np.dtype(cfg.dtype).itemsize)
     kv = 2 * per_pool  # k and v pools
-    state = 4 * cfg.state_layers * int(serving.max_slots) * sum(
-        int(np.prod(shape)) for shape in cfg.state_shapes.values()
-    ) if cfg.state_layers else 0
+    state = 4 * int(serving.max_slots) * sum(
+        layers * int(np.prod(shape))
+        for layers, shape in cfg.state_parts.values())
     p_bytes = tree_bytes(params) if params is not None else 0
     report = {
         "kv_pool_bytes": kv,

@@ -142,6 +142,32 @@ class TransformerConfig:
     # are unmasked; 1 = one token a pass, every program of before
     block_len: int = 1
     mask_id: int | None = None
+    # Compressed Convolutional Attention (arXiv:2510.04476) on every "*"
+    # layer of a pattern: None = plain attention (q, k, v three products
+    # of the normed state, token by token); (k0, k1) = q | k pass two
+    # causal convolutions over the SEQUENCE — depthwise of k0 taps, then
+    # one head_dim x head_dim matrix a head a tap of k1 taps — and get the
+    # mean of the projections they came from added back, q and k are
+    # L2-normalised to sqrt(head_dim) a head (k times a learned
+    # temperature a K/V head), and the second half of the K/V heads' v is
+    # the PREVIOUS token's projection.  Such a layer keeps a state a
+    # sequence beside its pages (``ops/cca.py``)
+    cca_taps: tuple | None = None
+    # the share of a head RoPE rotates: its first ``head_dim x
+    # rope_fraction`` dims (rotate-half inside them), the rest pass
+    rope_fraction: float = 1.0
+    # the routed experts' router: 0 = one matrix over the normed state; >
+    # 0 = the state is projected down to this width, averaged over DEPTH
+    # with the previous routed layer's (r_l = z_l + decay_l * r_{l-1},
+    # carried by the pattern walk beside x, zero before the first), and an
+    # RMSNorm and a three-layer GELU MLP of this width score the experts
+    moe_router_hidden: int = 0
+    # True: the chosen experts' scores are normalised to sum to
+    # ``moe_scale``; False: an expert weighs its own score x ``moe_scale``
+    moe_renorm: bool = True
+    # a pattern layer's residual add: False = x + y; True = (g_x * x +
+    # b_x) + (g_y * y + b_y), four learned vectors a layer
+    residual_scale: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -151,6 +177,8 @@ class TransformerConfig:
             object.__setattr__(self, "kv_heads", self.num_heads)
         if self.moe_held is not None:
             object.__setattr__(self, "moe_held", tuple(self.moe_held))
+        if self.cca_taps is not None:
+            object.__setattr__(self, "cca_taps", tuple(self.cca_taps))
         for field, allowed in (("norm", ("layer", "rms")),
                                ("positions", ("learned", "rotary", "none")),
                                ("mlp", ("gelu", "swiglu", "relu2")),
@@ -205,6 +233,44 @@ class TransformerConfig:
                 raise NotImplementedError(
                     "a layer pattern with loop_steps > 1 or norm_sandwich: "
                     "the pattern walk runs one pass of pre-norm layers")
+        if not 0.0 < self.rope_fraction <= 1.0 or int(
+                self.head_dim * self.rope_fraction) % 2:
+            raise ValueError(
+                f"rope_fraction {self.rope_fraction} must lie in (0, 1] and "
+                f"rotate an even number of head_dim {self.head_dim}'s dims")
+        walked = self.pattern is not None
+        for field, on in (("cca_taps", self.cca_taps is not None),
+                          ("moe_router_hidden", self.moe_router_hidden),
+                          ("residual_scale", self.residual_scale)):
+            if on and not walked:
+                raise NotImplementedError(
+                    f"{field} without a layer pattern: the homogeneous "
+                    "stack's scan carries neither a state a layer, nor the "
+                    "router's state from layer to layer, nor a layer's "
+                    "residual scales; only the pattern walk does")
+        if self.cca_taps is not None:
+            if len(self.cca_taps) != 2 or min(self.cca_taps) < 2:
+                raise ValueError(
+                    f"cca_taps {self.cca_taps!r} must be two tap counts >= 2 "
+                    "(a stage of one tap reads no other token: no state)")
+            if self.kv_heads % 2:
+                raise ValueError(
+                    f"cca_taps needs an even kv_heads (half of them take the "
+                    f"previous token's v), got {self.kv_heads}")
+            if self.qk_norm:
+                raise ValueError(
+                    "cca_taps with qk_norm: CCA normalises q and k itself")
+            if self.block_len > 1:
+                raise NotImplementedError(
+                    "cca_taps with block_len > 1: a block pass rewrites its "
+                    "block's positions pass after pass, and the state a CCA "
+                    "layer keeps stands for ONE last token; a state per "
+                    "block boundary is not built (loop_steps > 1 is refused "
+                    "with every pattern)")
+        if self.moe_router_hidden and self.moe_router != "softmax_topk":
+            raise ValueError(
+                "moe_router_hidden > 0 scores the experts by a softmax: "
+                f"moe_router must be 'softmax_topk', got {self.moe_router!r}")
         if self.loop_steps < 1:
             raise ValueError(f"loop_steps must be >= 1, got {self.loop_steps}")
         if self.early_exit_threshold < 1.0:
@@ -223,18 +289,41 @@ class TransformerConfig:
         return self.num_layers * self.loop_steps
 
     @property
+    def state_kinds(self) -> dict:
+        """The kinds of layer that keep a fixed state per sequence: kind
+        -> (how many layers of it the pattern has, {part: one layer's
+        shape for one sequence}).  A Mamba-2 layer keeps a state and no
+        pages; a CCA attention layer keeps one BESIDE its pages."""
+        from paddle_tpu.ops import cca, mamba2
+
+        kinds = {}
+        if self.pattern is None:
+            return kinds
+        if "M" in self.pattern:
+            kinds["mamba"] = (self.pattern.count("M"), mamba2.state_shapes(
+                self.mamba_heads, self.mamba_head_dim, self.mamba_state,
+                self.mamba_groups, self.mamba_conv))
+        if self.cca_taps is not None and "*" in self.pattern:
+            kinds["attn"] = (self.pattern.count("*"), cca.state_shapes(
+                self.cca_taps, self.num_heads, self.kv_heads, self.head_dim))
+        return kinds
+
+    @property
     def state_layers(self) -> int:
-        """Layers that keep a fixed recurrent state per sequence."""
-        return self.pattern.count("M") if self.pattern is not None else 0
+        """Layers that keep a fixed state per sequence, of any kind."""
+        return sum(n for n, _ in self.state_kinds.values())
+
+    @property
+    def state_parts(self) -> dict:
+        """What the serving cache holds by batch slot: part -> (layers
+        that keep it, one layer's shape for one sequence)."""
+        return {part: (n, shape) for n, shapes in self.state_kinds.values()
+                for part, shape in shapes.items()}
 
     @property
     def state_shapes(self) -> dict:
-        """One state layer's state of one sequence: name -> shape."""
-        from paddle_tpu.ops import mamba2
-
-        return mamba2.state_shapes(self.mamba_heads, self.mamba_head_dim,
-                                   self.mamba_state, self.mamba_groups,
-                                   self.mamba_conv)
+        """Every part of every kind's state: part -> one layer's shape."""
+        return {part: shape for part, (_, shape) in self.state_parts.items()}
 
     @property
     def moe_dropless(self) -> bool:
@@ -250,7 +339,9 @@ class TransformerConfig:
             num_experts=self.moe_experts, top_k=self.moe_top_k,
             scale=self.moe_scale, held=self.moe_held,
             act="silu" if gated else self.mlp, gated=gated,
-            score="sigmoid" if self.moe_router == "sigmoid" else "softmax")
+            score="sigmoid" if self.moe_router == "sigmoid" else "softmax",
+            router_hidden=self.moe_router_hidden, renorm=self.moe_renorm,
+            eps=self.norm_eps)
 
     @property
     def moe(self):
@@ -275,9 +366,22 @@ def _ffn_params(cfg: TransformerConfig, norm, zeros, lead: tuple,
     experts = experts and cfg.moe_experts
     if experts and cfg.moe_dropless:
         ex, held = cfg.moe_experts, cfg.routed.num_held
-        p = {"router": norm(*lead, e, ex) * (e ** -0.5)}
-        if cfg.moe_router == "sigmoid":
-            p["router_bias"] = zeros(*lead, ex)
+        r = cfg.moe_router_hidden
+        if r:
+            # the MLP router (``parallel.moe.route_mlp``): ``router`` is
+            # its last matrix; the depth average starts at a half
+            p = {"router_down": norm(*lead, e, r) * (e ** -0.5),
+                 "router_down_b": zeros(*lead, r),
+                 "router_decay": jnp.full((*lead, r), 0.5, cfg.dtype),
+                 "router_norm_g": jnp.ones((*lead, r), cfg.dtype),
+                 "router_w1": norm(*lead, r, r) * (r ** -0.5),
+                 "router_w2": norm(*lead, r, r) * (r ** -0.5),
+                 "router": norm(*lead, r, ex) * (r ** -0.5),
+                 "router_bias": zeros(*lead, ex)}
+        else:
+            p = {"router": norm(*lead, e, ex) * (e ** -0.5)}
+            if cfg.moe_router == "sigmoid":
+                p["router_bias"] = zeros(*lead, ex)
         p.update(w_in=norm(*lead, held, e, m) * (e ** -0.5),
                  w_out=norm(*lead, held, m, e) * (m ** -0.5)
                  / (2 * depth) ** 0.5)
@@ -337,13 +441,27 @@ def _pattern_params(cfg: TransformerConfig, norm, zeros, norm_p) -> list:
     di = nh * cfg.mamba_head_dim
     conv_dim = di + 2 * cfg.mamba_groups * cfg.mamba_state
 
+    def cca():
+        """A CCA layer's leaves beside the four projections: the two
+        convolutions over q | k (tap K-1 is the current token) and k's
+        temperature a K/V head."""
+        if cfg.cca_taps is None:
+            return {}
+        (k0, k1), hd = cfg.cca_taps, cfg.head_dim
+        heads = cfg.num_heads + cfg.kv_heads
+        return {"cca_conv0_w": norm(k0, heads * hd) * (k0 ** -0.5),
+                "cca_conv0_b": zeros(heads * hd),
+                "cca_conv1_w": norm(k1, heads, hd, hd) * ((k1 * hd) ** -0.5),
+                "cca_conv1_b": zeros(heads * hd),
+                "cca_temp": jnp.ones((cfg.kv_heads,), cfg.dtype)}
+
     def layer(kind):
         if kind == "attn":
             return {"wq": norm(e, h) * (e ** -0.5),
                     "wk": norm(e, hk) * (e ** -0.5),
                     "wv": norm(e, hk) * (e ** -0.5),
                     "wo": norm(h, e) * (h ** -0.5) / (2 * s) ** 0.5,
-                    **_qk_norm_params(cfg, ())}
+                    **_qk_norm_params(cfg, ()), **cca()}
         if kind in ("mlp", "moe"):
             return _ffn_params(cfg, norm, zeros, (), s, kind == "moe")
         # dt_bias around softplus^-1(0.01) and A = -exp(a_log) <= -1 (the
@@ -360,7 +478,14 @@ def _pattern_params(cfg: TransformerConfig, norm, zeros, norm_p) -> list:
             "norm_g": jnp.ones((di,), cfg.dtype),
             "out_proj": norm(di, e) * (di ** -0.5) / (2 * s) ** 0.5}
 
-    return [{**norm_p("ln"), **layer(_KINDS[c])} for c in cfg.pattern]
+    def res():
+        if not cfg.residual_scale:
+            return {}
+        return {"res_x_g": jnp.ones((e,), cfg.dtype), "res_x_b": zeros(e),
+                "res_y_g": jnp.ones((e,), cfg.dtype), "res_y_b": zeros(e)}
+
+    return [{**norm_p("ln"), **layer(_KINDS[c]), **res()}
+            for c in cfg.pattern]
 
 
 def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
@@ -513,8 +638,9 @@ def _rms(cfg: TransformerConfig, x, gain):
 
 def _rope_table(cfg: TransformerConfig, positions):
     """(cos, sin) [..., 1, head_dim] float32 for integer ``positions``
-    [...] — broadcast over the head axis of q/k [..., H, head_dim]."""
-    half = cfg.head_dim // 2
+    [...] — broadcast over the head axis of q/k [..., H, head_dim]; under
+    a ``rope_fraction`` below 1 only that share of head_dim wide."""
+    half = int(cfg.head_dim * cfg.rope_fraction) // 2
     inv_freq = cfg.rope_theta ** (
         -jnp.arange(half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[..., None] * inv_freq
@@ -523,8 +649,13 @@ def _rope_table(cfg: TransformerConfig, positions):
 
 
 def _rope(x, table):
-    """Rotate-half RoPE: dims (i, i + head_dim/2) are one pair."""
+    """Rotate-half RoPE: dims (i, i + head_dim/2) are one pair.  A table
+    narrower than x rotates x's first dims and passes the rest."""
     cos, sin = table
+    rot = cos.shape[-1]
+    if rot < x.shape[-1]:
+        return jnp.concatenate(
+            [_rope(x[..., :rot], table), x[..., rot:]], axis=-1)
     xf = x.astype(jnp.float32)
     x1, x2 = jnp.split(xf, 2, axis=-1)
     return (xf * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
@@ -628,18 +759,62 @@ def _qkv(cfg: TransformerConfig, h, layer, rope):
     return q, k, v
 
 
+def _cca_qkv(cfg: TransformerConfig, h, layer, rope, window):
+    """CCA's q [..., H, Dh], k and v [..., KV, Dh] from normed states h
+    [..., E] (``TransformerConfig.cca_taps``).  ``window(part, x, n) ->
+    [..., n+1, C]`` float32 is the caller's arrangement of "x's last n
+    inputs before each token, and the token" (``ops/cca.py``: whole padded
+    prompts in prefill, one token against its slot's state in decode); it
+    keeps the state it must hand back in the caller's own variables."""
+    f32 = jnp.float32
+    lead, hd = h.shape[:-1], cfg.head_dim
+    nh, kv = cfg.num_heads, cfg.kv_heads
+    k0, k1 = cfg.cca_taps
+    u = jnp.concatenate([h @ layer["wq"], h @ layer["wk"]], axis=-1)
+    # depthwise over the sequence, then a head's own matrix a tap
+    c0 = jnp.einsum("...kc,kc->...c", window("cca_u", u, k0 - 1),
+                    layer["cca_conv0_w"].astype(f32)) \
+        + layer["cca_conv0_b"].astype(f32)
+    win = window("cca_c", c0, k1 - 1).astype(h.dtype).reshape(
+        *lead, k1, nh + kv, hd)
+    c1 = jnp.einsum("...kgd,kgde->...ge", win, layer["cca_conv1_w"],
+                    preferred_element_type=f32) \
+        + layer["cca_conv1_b"].astype(f32).reshape(nh + kv, hd)
+    # the mean of the projections q and k came from, of a K/V head and
+    # the query heads that read it, added back
+    uh = u.astype(f32).reshape(*lead, nh + kv, hd)
+    qt, kt = uh[..., :nh, :], uh[..., nh:, :]
+    q = c1[..., :nh, :] + 0.5 * (qt + jnp.repeat(kt, nh // kv, axis=-2))
+    k = c1[..., nh:, :] + 0.5 * (
+        jnp.mean(qt.reshape(*lead, kv, nh // kv, hd), axis=-2) + kt)
+    # sqrt(Dh) x / |x| is an RMS norm with no gain; k's temperature is
+    # one a K/V head
+    q = _rms(cfg, q, 1.0).astype(h.dtype)
+    k = _rms(cfg, k, layer["cca_temp"].astype(f32)[:, None]).astype(h.dtype)
+    if rope is not None:
+        q, k = _rope(q, rope), _rope(k, rope)
+    # the first half of the K/V heads' v is this token's, the second half
+    # the previous token's
+    hv = h @ layer["wv"]
+    half = kv * hd // 2
+    prev = window("cca_v", hv[..., half:], 1)[..., 0, :].astype(h.dtype)
+    v = jnp.concatenate([hv[..., :half], prev], axis=-1)
+    return q, k, v.reshape(*lead, kv, hd)
+
+
 def _mlp(cfg: TransformerConfig, h, layer, mesh=None, live=None,
-         experts: bool = True):
+         experts: bool = True, carry=None):
     """The feed-forward branch over normed states h [..., E], by the
     config's parts (``experts`` False: the dense MLP of a config that
     also has expert layers): (y, the output bias to add or None, aux).
     aux is the capacity MoE's load-balancing loss, the routed MoE's
-    counts (``parallel.moe.moe_routed``), None for a dense FFN."""
+    counts (``parallel.moe.moe_routed``), None for a dense FFN.  ``carry``:
+    an MLP router's state of this layer (``moe_router_hidden``)."""
     experts = experts and cfg.moe_experts
     if experts and cfg.moe_dropless:
         from paddle_tpu.parallel.moe import moe_routed
 
-        y, counts = moe_routed(layer, h, cfg.routed, live)
+        y, counts = moe_routed(layer, h, cfg.routed, live, carry)
         return y, None, counts
     if experts:
         from paddle_tpu.parallel.moe import moe_ffn, moe_ffn_sharded
@@ -740,46 +915,64 @@ def _mamba_mixer(cfg: TransformerConfig, h, layer, conv, ssd):
 
 
 def _pattern_layer(cfg: TransformerConfig, kind, layer, x, rope, attend,
-                   mamba, live=None, mesh=None):
-    """One layer of a ``pattern``: ``x + mixer(norm(x))``, the mixer by
-    the layer's ``kind`` (a value of ``_KINDS``).  ``attend(q, k, v) ->
-    a`` is the caller's cache write and attention of this attention
-    layer, ``mamba = (conv, ssd)`` this state layer's arrangement
-    (``_mamba_mixer``); both keep what they must hand back in the
-    caller's own variables.  ``live`` (bool, x's leading shape) marks the
-    rows that are tokens, for the routing counts.  Returns (x, counts):
-    a routed layer's ``moe_routed`` counts, else None."""
+                   mamba, live=None, mesh=None, carry=None, window=None):
+    """One layer of a ``pattern``: ``x + mixer(norm(x))`` (under
+    ``residual_scale`` both terms scaled and shifted by the layer's own
+    vectors), the mixer by the layer's ``kind`` (a value of ``_KINDS``).
+    ``attend(q, k, v) -> a`` is the caller's cache write and attention of
+    this attention layer, ``mamba = (conv, ssd)`` this state layer's
+    arrangement (``_mamba_mixer``), ``window`` a CCA layer's
+    (``_cca_qkv``); all keep what they must hand back in the caller's own
+    variables.  ``live`` (bool, x's leading shape) marks the rows that are
+    tokens, for the routing counts.  ``carry`` [..., moe_router_hidden]
+    float32 is the router's state of the routed layer before (None
+    without an MLP router): a routed layer reads and replaces it, every
+    other kind passes it on.  Returns (x, counts, carry): a routed
+    layer's ``moe_routed`` counts, else None."""
     lead = x.shape[:-1]
     counts = None
     h = _norm(cfg, x, layer, "ln")
     if kind == "attn":
-        a = attend(*_qkv(cfg, h, layer, rope))
+        qkv = (_qkv(cfg, h, layer, rope) if cfg.cca_taps is None
+               else _cca_qkv(cfg, h, layer, rope, window))
+        a = attend(*qkv)
         y = a.reshape(*lead, cfg.num_heads * cfg.head_dim) @ layer["wo"]
     elif kind == "mamba":
         y = _mamba_mixer(cfg, h, layer, *mamba)
     else:
-        y, bias, aux = _mlp(cfg, h, layer, mesh, live, kind == "moe")
+        if kind == "moe" and cfg.moe_router_hidden:
+            from paddle_tpu.parallel.moe import router_state
+
+            carry = router_state(layer, h, carry)
+        y, bias, aux = _mlp(cfg, h, layer, mesh, live, kind == "moe", carry)
         if bias is not None:
             y = y + bias
         if kind == "moe":
             counts = aux
-    return x + y, counts
+    if cfg.residual_scale:
+        return ((x * layer["res_x_g"] + layer["res_x_b"])
+                + (y * layer["res_y_g"] + layer["res_y_b"])), counts, carry
+    return x + y, counts, carry
 
 
 def _run_pattern(cfg: TransformerConfig, params, x, layer_fn):
     """The stack of a layer ``pattern``, the final norm closing it.
     ``params["blocks"]`` is the list of the layers' own trees, so nothing
     is sliced out of a stack, statically or dynamically.
-    ``layer_fn(kind, i, layer, x) -> (x, counts)`` is the caller's
-    arrangement of ``_pattern_layer`` for the ``i``-th layer of its kind
-    (an attention layer's ``i`` is its cache layer).  Returns (x,
-    counts): the routed layers' ``moe_routed`` counts summed (the busiest
-    expert's tokens: the largest), None without routed layers."""
+    ``layer_fn(kind, i, layer, x, carry) -> (x, counts, carry)`` is the
+    caller's arrangement of ``_pattern_layer`` for the ``i``-th layer of
+    its kind (an attention layer's ``i`` is its cache layer); ``carry`` is
+    the MLP router's state, walked beside x from layer to layer (None
+    without one: nothing is carried).  Returns (x, counts): the routed
+    layers' ``moe_routed`` counts summed (the busiest expert's tokens:
+    the largest), None without routed layers."""
     seen = dict.fromkeys(_KINDS.values(), 0)
     counts = None
+    carry = jnp.zeros((*x.shape[:-1], cfg.moe_router_hidden), jnp.float32) \
+        if cfg.moe_router_hidden else None
     for c, layer in zip(cfg.pattern, params["blocks"]):
         kind = _KINDS[c]
-        x, aux = layer_fn(kind, seen[kind], layer, x)
+        x, aux, carry = layer_fn(kind, seen[kind], layer, x, carry)
         seen[kind] += 1     # the layer's index among its kind
         if aux is not None:
             counts = aux if counts is None else jnp.concatenate(
@@ -928,14 +1121,15 @@ def forward_prefill(cfg: TransformerConfig, params: dict, ids: jax.Array,
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2))
 def _prefill_layer(cfg: TransformerConfig, kind, mesh, layer, x, rope,
-                   seq_lens):
+                   seq_lens, carry=None):
     """One layer of a pattern over whole right-padded sequences x [B, T,
-    E]: (x, what the layer leaves behind, counts).  A jitted function of
-    its own, so the program that calls it traces and lowers each KIND of
-    layer once and not each layer: getting a pattern's prefill program
-    ready is the host tracing and lowering it, seconds an engine (PERF.md
-    section 6, PR 31); XLA inlines the calls."""
-    from paddle_tpu.ops import mamba2
+    E]: (x, what the layer leaves behind, counts, the router's carry).  A
+    jitted function of its own, so the program that calls it traces and
+    lowers each KIND of layer once and not each layer: getting a
+    pattern's prefill program ready is the host tracing and lowering it,
+    seconds an engine (PERF.md section 6, PR 31); XLA inlines the
+    calls."""
+    from paddle_tpu.ops import cca, mamba2
 
     kept = {}
 
@@ -952,32 +1146,39 @@ def _prefill_layer(cfg: TransformerConfig, kind, mesh, layer, x, rope,
                                             chunk=cfg.mamba_chunk)
         return y
 
+    def window(part, x, n):
+        win, kept[part] = cca.window_prefill(x, n, seq_lens)
+        return win
+
     live = None if seq_lens is None else (
         jnp.arange(x.shape[1])[None, :] < seq_lens[:, None])
-    x, counts = _pattern_layer(cfg, kind, layer, x, rope, attend,
-                               (conv, ssd), live, mesh)
-    return x, kept, counts
+    x, counts, carry = _pattern_layer(
+        cfg, kind, layer, x, rope, attend, (conv, ssd), live, mesh,
+        carry=carry, window=window)
+    return x, kept, counts, carry
 
 
 def _prefill_pattern(cfg: TransformerConfig, params, x, rope, seq_lens, mesh):
     """Whole right-padded sequences x [B, T, E] through a layer pattern
     (``seq_lens`` None = training: every position is a token).  Returns
-    (x, (ks, vs) [cache_layers, B, T, KV, Dh] or (None, None), extras)."""
-    kept = {"kv": [], "ssm": [], "conv": []}
+    (x, (ks, vs) [cache_layers, B, T, KV, Dh] or (None, None), extras):
+    ``extras["state"]`` = {part: [layers that keep it, B, ...]}, each
+    row's state at its last valid token."""
+    kept = {}
 
-    def layer_fn(kind, i, layer, x):
-        x, left, counts = _prefill_layer(cfg, kind, mesh, layer, x, rope,
-                                         seq_lens)
+    def layer_fn(kind, i, layer, x, carry):
+        x, left, counts, carry = _prefill_layer(cfg, kind, mesh, layer, x,
+                                                rope, seq_lens, carry)
         for name, v in left.items():
-            kept[name].append(v)
-        return x, counts
+            kept.setdefault(name, []).append(v)
+        return x, counts, carry
 
     x, counts = _run_pattern(cfg, params, x, layer_fn)
-    kv = kept.pop("kv")
+    kv = kept.pop("kv", [])
     ks, vs = ((jnp.stack([k for k, _ in kv]), jnp.stack([v for _, v in kv]))
               if kv else (None, None))
     return x, (ks, vs), {
-        "state": {n: jnp.stack(v) for n, v in kept.items() if v},
+        "state": {n: jnp.stack(kept[n]) for n in cfg.state_parts},
         "moe_counts": counts}
 
 
@@ -1025,9 +1226,9 @@ def forward_prefill_chunk(cfg: TransformerConfig, params: dict,
 
         live = jnp.arange(c)[None, :] < seq_lens[:, None]
         x, counts = _run_pattern(
-            cfg, params, x, lambda kind, i, layer, x: _pattern_layer(
+            cfg, params, x, lambda kind, i, layer, x, carry: _pattern_layer(
                 cfg, kind, layer, x, rope,
-                functools.partial(attend_chunk, i), None, live))
+                functools.partial(attend_chunk, i), None, live, carry=carry))
         return (_head(cfg, params, _last_valid(x, seq_lens)), *pools,
                 {"state": {}, "moe_counts": counts})
 
@@ -1052,8 +1253,8 @@ def forward_decode(cfg: TransformerConfig, params: dict, ids: jax.Array,
                    page_table: jax.Array, k_cache, v_cache,
                    attn_impl: str = "auto", mesh=None, state=None):
     """One incremental decode step over the paged KV-cache (and, under a
-    pattern with state layers, over the ``state`` pools {part:
-    [state_layers, B, ...]}, row = batch row).
+    pattern with state layers, over the ``state`` pools {part: [layers
+    that keep it, B, ...]}, row = batch row).
 
     ids [B] current tokens, positions [B] their absolute indices,
     seq_lens [B] = positions + 1 on live rows and 0 on idle rows,
@@ -1141,10 +1342,10 @@ def forward_decode_block(cfg: TransformerConfig, params: dict,
             return a
 
         x, counts = _run_pattern(
-            cfg, params, x, lambda kind, i, layer, x: _pattern_layer(
+            cfg, params, x, lambda kind, i, layer, x, carry: _pattern_layer(
                 cfg, kind, layer, x, rope,
                 functools.partial(attend_layer, i), None,
-                jnp.broadcast_to(live[:, None], ids.shape)))
+                jnp.broadcast_to(live[:, None], ids.shape), carry=carry))
         return (_head(cfg, params, x, jnp.float32), *pools,
                 {"state": {}, "moe_counts": counts})
 
@@ -1165,7 +1366,7 @@ def _decode_pattern(cfg: TransformerConfig, params, x, rope, positions,
     K, V and the state parts — is rebound as it is updated, at a static
     layer index: the buffers that entered the program are written where
     they are."""
-    from paddle_tpu.ops import mamba2
+    from paddle_tpu.ops import cca, mamba2
     from paddle_tpu.ops.pallas import paged_attention as pa
 
     pools = [k_cache, v_cache]
@@ -1202,10 +1403,16 @@ def _decode_pattern(cfg: TransformerConfig, params, x, rope, positions,
 
         return conv, ssd
 
+    def window(i, part, x, n):
+        win, new = cca.window_step(state[part][i], x)
+        keep(part, i, new)
+        return win
+
     x, counts = _run_pattern(
-        cfg, params, x, lambda kind, i, layer, x: _pattern_layer(
+        cfg, params, x, lambda kind, i, layer, x, carry: _pattern_layer(
             cfg, kind, layer, x, rope, functools.partial(attend, i),
-            mamba(i) if kind == "mamba" else None, live))
+            mamba(i) if kind == "mamba" else None, live, carry=carry,
+            window=functools.partial(window, i)))
     return (_head(cfg, params, x), *pools,
             {"state": state, "moe_counts": counts})
 

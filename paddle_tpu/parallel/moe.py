@@ -361,12 +361,21 @@ class RoutedConfig:
     held: tuple = None          # [lo, hi) of the experts computed here
     act: str = "relu2"          # "relu2" | "gelu" | "silu" (no bias)
     # what the router's logits become before the top-k: "sigmoid" (each
-    # expert scored alone) | "softmax" (over all ``num_experts``); either
-    # way the chosen scores are renormalised to sum to ``scale``
+    # expert scored alone) | "softmax" (over all ``num_experts``); under
+    # ``renorm`` the chosen scores are normalised to sum to ``scale``
     score: str = "sigmoid"
     # True: an expert is (act(x W_gate) * (x W_in)) W_out — SwiGLU with
     # ``act="silu"`` — and the tree holds ``w_gate`` beside ``w_in``
     gated: bool = False
+    # 0: the logits are one product of the token's state; > 0: they come
+    # from an MLP of this width over the ROUTER's state, which the caller
+    # carries from routed layer to routed layer (``router_state``,
+    # ``route_mlp``; its RMSNorm at ``eps``)
+    router_hidden: int = 0
+    eps: float = 1e-5
+    # False: a chosen expert weighs its own score x ``scale``, whatever
+    # the others chosen with it scored
+    renorm: bool = True
 
     def __post_init__(self):
         held = (0, self.num_experts) if self.held is None else tuple(self.held)
@@ -393,22 +402,60 @@ def _act(name: str, h):
     return {"gelu": jax.nn.gelu, "silu": jax.nn.silu}[name](h)
 
 
-def route_topk(x2, router, bias, cfg: RoutedConfig):
-    """The published router, in float32: (expert ids [T, k], weights
+def _choose(s, bias, cfg: RoutedConfig):
+    """Router logits s [T, X] float32 -> (expert ids [T, k], weights
     [T, k]).  The correction ``bias`` (None: the router has none) moves
     which experts are chosen and never what they weigh."""
     f32 = jnp.float32
-    s = jnp.dot(x2.astype(f32), router.astype(f32),
-                precision=lax.Precision.HIGHEST)
     s = jax.nn.sigmoid(s) if cfg.score == "sigmoid" else jax.nn.softmax(s, -1)
     _, idx = lax.top_k(s if bias is None else s + bias.astype(f32),
                        cfg.top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
+    if not cfg.renorm:
+        return idx, w * cfg.scale
     return idx, w / jnp.sum(w, axis=-1, keepdims=True) * cfg.scale
 
 
-def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None):
-    """x [..., D] -> (y like x, counts int32 [4]).
+def route_topk(x2, router, bias, cfg: RoutedConfig):
+    """The published linear router, in float32: (expert ids [T, k],
+    weights [T, k]) of token states x2 [T, D]."""
+    f32 = jnp.float32
+    s = jnp.dot(x2.astype(f32), router.astype(f32),
+                precision=lax.Precision.HIGHEST)
+    return _choose(s, bias, cfg)
+
+
+def router_state(params: dict, x, prev):
+    """An MLP router's state of this layer, float32 [..., R]: the token's
+    normed state x [..., D] projected down, plus the state of the routed
+    layer before (``prev``, zeros before the first) decayed by this
+    layer's own vector — an exponential average over DEPTH, which is why
+    the caller carries it from layer to layer beside x."""
+    f32, hi = jnp.float32, lax.Precision.HIGHEST
+    z = jnp.dot(x.astype(f32), params["router_down"].astype(f32),
+                precision=hi) + params["router_down_b"].astype(f32)
+    return z + params["router_decay"].astype(f32) * prev
+
+
+def route_mlp(r2, params: dict, cfg: RoutedConfig):
+    """The MLP router over its state r2 [T, R] (``router_state``), in
+    float32: RMSNorm, two GELU layers of width R, ``router`` [R, X] to
+    the logits; (expert ids [T, k], weights [T, k])."""
+    f32, hi = jnp.float32, lax.Precision.HIGHEST
+    h = r2 * lax.rsqrt(jnp.mean(r2 * r2, axis=-1, keepdims=True) + cfg.eps) \
+        * params["router_norm_g"].astype(f32)
+    for name in ("router_w1", "router_w2"):
+        h = jax.nn.gelu(jnp.dot(h, params[name].astype(f32), precision=hi),
+                        approximate=False)
+    s = jnp.dot(h, params["router"].astype(f32), precision=hi)
+    return _choose(s, params.get("router_bias"), cfg)
+
+
+def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None,
+               carry=None):
+    """x [..., D] -> (y like x, counts int32 [4]).  ``carry`` [..., R]:
+    this layer's router state under ``cfg.router_hidden``
+    (``router_state``), which then chooses the experts in x's place.
 
     ``params``: ``router`` [D, X] and, optionally, ``router_bias`` [X]
     over all X experts; ``w_in`` [held, D, F] and ``w_out`` [held, F, D]
@@ -425,7 +472,11 @@ def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None):
     x2 = x.reshape(-1, shape[-1])
     t, k = x2.shape[0], cfg.top_k
     lo, hi = cfg.held
-    idx, w = route_topk(x2, params["router"], params.get("router_bias"), cfg)
+    if cfg.router_hidden:
+        idx, w = route_mlp(carry.reshape(t, cfg.router_hidden), params, cfg)
+    else:
+        idx, w = route_topk(x2, params["router"], params.get("router_bias"),
+                            cfg)
     held = (idx >= lo) & (idx < hi)
     if live is not None:
         alive = live.reshape(-1, 1)

@@ -259,11 +259,14 @@ class ServingEngine:
                 "write; none of it is built")
         if cfg.state_layers and s.incremental_prefill:
             raise NotImplementedError(
-                "prefix_cache / prefill_chunk_tokens with recurrent-state "
-                "layers: a chunk must start from its slot's state and leave "
-                "it behind (forward_prefill_chunk carries none), and a "
-                "prefix hit needs a snapshot of the state at the shared "
-                "prefix's last token (PrefixCache keeps pages only)")
+                "prefix_cache / prefill_chunk_tokens with layers that keep "
+                f"a state a sequence ({sorted(cfg.state_kinds)}: a "
+                "recurrent layer's, a CCA attention layer's convolution "
+                "tail and shifted value): a chunk must start from its "
+                "slot's state and leave it behind (forward_prefill_chunk "
+                "carries none), and a prefix hit needs a snapshot of the "
+                "state at the shared prefix's last token (PrefixCache keeps "
+                "pages only)")
         # GL-P-MEM serving path: with an --hbm_gb budget set, the static
         # KV pool + params bytes must fit BEFORE the pools are allocated
         # — an oversized pool fails here, not at the first admission
@@ -294,9 +297,7 @@ class ServingEngine:
                 cfg.cache_layers, cfg.kv_heads, cfg.head_dim, s.num_pages,
                 s.page_size, s.max_slots, s.max_pages_per_seq,
                 dtype=cfg.dtype, prefix_cache=s.prefix_cache,
-                state_layers=cfg.state_layers,
-                state_shapes=cfg.state_shapes if cfg.state_layers else None,
-                block_len=cfg.block_len)
+                state_parts=cfg.state_parts, block_len=cfg.block_len)
         (self.cache.k, self.cache.v, self.cache.state,
          self.cache.tokens) = self.place(
             (self.cache.k, self.cache.v, self.cache.state,
@@ -398,12 +399,15 @@ class ServingEngine:
         reg.counter("serve_moe_experts_touched_total",
                     "(layer, held expert) pairs that saw at least one "
                     "token, summed over passes").inc(touched)
+        said = {"moe_assignments": held, "experts_touched": touched,
+                "moe_load_max": busiest}
         if where == "decode" and held:
+            over = busiest * self._expert_slots / held
             reg.gauge("serve_moe_load_max_over_mean",
                       "busiest held expert's tokens over the mean, last "
-                      "decode step").set(busiest * self._expert_slots / held)
-        return out[:rows], {"moe_assignments": held,
-                            "experts_touched": touched}
+                      "decode step").set(over)
+            said["moe_load_max_over_mean"] = round(over, 3)
+        return out[:rows], said
 
     def place(self, tree):
         """Commit a pytree to this engine's device (identity when the

@@ -292,10 +292,13 @@ class PagedKVCache:
     not).
 
     ``num_heads`` is the heads the CACHE holds (a model's K/V heads).
-    ``state``: {part: [state_layers, max_slots, *shape]} float32, one row
-    per batch slot for every layer that keeps a recurrent state
-    (``state_shapes``: part -> one layer's shape for one sequence; empty
-    without such layers).  Slot ``s`` owns row ``s``: its prefill writes
+    ``state``: {part: [layers, max_slots, *shape]} float32, one row per
+    batch slot for every layer that keeps that part of a fixed state a
+    sequence (``state_parts``: part -> (how many layers keep it, one
+    layer's shape for one sequence) — a model's kinds of layer say, see
+    ``TransformerConfig.state_kinds``: a recurrent layer's state stands
+    in place of pages, a CCA attention layer's beside them; empty without
+    such layers).  Slot ``s`` owns row ``s``: its prefill writes
     the row whole, so a reused slot needs no zeroing, and like the page
     pools the arrays go donated through the jitted programs and come
     back updated in place.  Nothing of it is shareable between requests:
@@ -317,8 +320,8 @@ class PagedKVCache:
     def __init__(self, num_layers: int, num_heads: int, head_dim: int,
                  num_pages: int, page_size: int, max_slots: int,
                  max_pages_per_seq: int, dtype=None,
-                 prefix_cache: bool = False, state_layers: int = 0,
-                 state_shapes: dict | None = None, block_len: int = 1):
+                 prefix_cache: bool = False,
+                 state_parts: dict | None = None, block_len: int = 1):
         import jax.numpy as jnp
 
         from paddle_tpu.ops.pallas.paged_attention import init_kv_pages
@@ -329,9 +332,8 @@ class PagedKVCache:
             num_layers, num_heads, num_pages, page_size, head_dim,
             dtype=dtype or jnp.float32)
         self.state = {
-            name: jnp.zeros((state_layers, max_slots, *shape), jnp.float32)
-            for name, shape in (state_shapes or {}).items()
-        } if state_layers else {}
+            part: jnp.zeros((layers, max_slots, *shape), jnp.float32)
+            for part, (layers, shape) in (state_parts or {}).items()}
         self.tokens = jnp.zeros(
             (max_slots,) if block_len == 1 else (max_slots, 2 * block_len),
             jnp.int32)
@@ -343,8 +345,8 @@ class PagedKVCache:
 
     @property
     def state_bytes_per_slot(self) -> int:
-        """Bytes of recurrent state one sequence holds, over every state
-        layer and part."""
+        """Bytes of fixed state one sequence holds, over every part and
+        every layer that keeps it."""
         return sum(int(a.nbytes) // a.shape[1] for a in self.state.values())
 
     def pages_needed(self, tokens: int) -> int:

@@ -745,27 +745,63 @@ def test_routed_layer_equals_a_loop_over_experts(score, gated, rows):
     assert int(counts[0]) == 2 * rows and int(counts[1]) == 0
 
 
+def _zaya_reference():
+    spec = importlib.util.spec_from_file_location(
+        "zaya_reference",
+        os.path.join(REPO, "benchmarks", "references", "zaya.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 @pytest.mark.parametrize("score,gated", [("sigmoid", False),
-                                         ("softmax", True)])
+                                         ("softmax", True),
+                                         ("mlp_top1", True)])
 def test_the_shares_add_up_to_the_uncut_layer(score, gated):
     """Expert parallelism's unit: every device routes over all experts
     and computes its own share's part; the parts of all shares add up to
-    what the uncut layer gives."""
-    kw = dict(num_experts=8, top_k=3, score=score, gated=gated,
-              act="silu" if gated else "relu2")
-    p = _routed_layer(jax.random.key(7), score, gated)
+    what the uncut layer gives.  ``mlp_top1``: the experts chosen by an
+    MLP router over its carried state, one a token, weighing its own
+    probability (not renormalised), gated — the whole against the plain
+    reference's sublayer (``references/zaya.py``)."""
     x = jax.random.normal(jax.random.key(8), (2, 9, 16))
-    whole, counts = moe.moe_routed(p, x, moe.RoutedConfig(**kw))
+    if score == "mlp_top1":
+        ref = _zaya_reference()
+        m = dict(vocab_size=31, num_layers=2, num_heads=2, kv_heads=2,
+                 head_dim=4, embed_dim=16, mlp_dim=12, cca_taps=[2, 2],
+                 moe_experts=8, moe_router_hidden=6, norm_eps=1e-5,
+                 init={"router_gain": 4.0})
+        l = ref.init_weights(m, 3, jnp.float32)["layers"][0]["moe"]
+        p = {ref._MOE.get(n, ref._RES.get(n, n)): v for n, v in l.items()}
+        prev = jax.random.normal(jax.random.key(9), (2, 9, 6))
+        kw = dict(num_experts=8, top_k=1, score="softmax", gated=True,
+                  act="silu", router_hidden=6, renorm=False)
+        carry, k = moe.router_state(p, x, prev), 1
+        with jax.default_matmul_precision("highest"):
+            want, r = ref.moe_mixer(l, x.reshape(18, 16),
+                                    prev.reshape(18, 6), m)
+        np.testing.assert_allclose(np.asarray(carry).reshape(18, 6),
+                                   np.asarray(r), atol=TOL, rtol=TOL)
+    else:
+        kw = dict(num_experts=8, top_k=3, score=score, gated=gated,
+                  act="silu" if gated else "relu2")
+        p, carry, k, want = (_routed_layer(jax.random.key(7), score, gated),
+                             None, 3, None)
+    whole, counts = moe.moe_routed(p, x, moe.RoutedConfig(**kw), None, carry)
+    if want is not None:
+        np.testing.assert_allclose(np.asarray(whole).reshape(18, 16),
+                                   np.asarray(want), atol=TOL, rtol=TOL)
     parts, held = 0.0, 0
     for lo, hi in ((0, 3), (3, 4), (4, 8)):
-        share = {k: (v[lo:hi] if k in ("w_in", "w_out", "w_gate") else v)
-                 for k, v in p.items()}
-        y, c = moe.moe_routed(share, x, moe.RoutedConfig(held=(lo, hi), **kw))
+        share = {k_: (v[lo:hi] if k_ in ("w_in", "w_out", "w_gate") else v)
+                 for k_, v in p.items()}
+        y, c = moe.moe_routed(share, x, moe.RoutedConfig(held=(lo, hi), **kw),
+                              None, carry)
         parts, held = parts + y, held + int(c[0])
-        assert int(c[0]) + int(c[1]) == 18 * 3
+        assert int(c[0]) + int(c[1]) == 18 * k
     np.testing.assert_allclose(np.asarray(parts), np.asarray(whole),
                                atol=TOL, rtol=TOL)
-    assert held == int(counts[0]) == 18 * 3
+    assert held == int(counts[0]) == 18 * k
 
 
 def test_default_fields_leave_the_tree_and_the_programs_alone():
