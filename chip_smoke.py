@@ -112,9 +112,15 @@ class Sizes:
         ctc_greedy_decode_fused=(512, 24, 27),
         flash_attention=(8, 1024, 12, 64),      # B, T, H, D
         ragged_paged_attention=(8, 12, 64, 2048, 16, 66),  # B,H,D,P,ps,maxp
-        # the benchmark's serve cells (bf16 pools): gpt2-large, ouro-2.6b
+        # the benchmark's serve cells (bf16 pools): gpt2-large, ouro-2.6b,
+        # the caches of few heads at their longer blocks (32 and 64 page
+        # slots a grid step): sdar-30b's 4 heads, zaya1-8b's 2, and a
+        # float32 cache of 32 heads, whose block the VMEM budget cuts to 4
         ragged_paged_attention_gpt2l=(24, 20, 64, 1537, 16, 64),
         ragged_paged_attention_ouro=(8, 16, 128, 145, 16, 18),
+        ragged_paged_attention_sdar=(64, 4, 128, 3073, 16, 48),
+        ragged_paged_attention_zaya=(64, 2, 128, 8193, 16, 128),
+        ragged_paged_attention_vmem=(4, 32, 128, 129, 16, 32),
         softmax_xent=(4096, 50257),             # N, V
         conv2d_bn_act=(128, 56, 64, 64, 3, 1, 1),  # N, HW, Cin, Cout, k, s, p
         conv2d_direct=(128, 224, 3, 64, 7, 2, 3),  # the ResNet stem
@@ -764,7 +770,10 @@ def _kernel_cases() -> list[KernelCase]:
                functools.partial(make_paged, dtype=bf16), paged_kernel,
                lambda s: pa.ragged_paged_attention_reference, tol=BF16_TOL,
                shape_key=f"ragged_paged_attention_{cell}")
-          for cell in ("gpt2l", "ouro")),
+          for cell in ("gpt2l", "ouro", "sdar", "zaya")),
+        case("ragged_paged_attention[vmem]", make_paged, paged_kernel,
+             lambda s: pa.ragged_paged_attention_reference, tol=MXU_TOL,
+             shape_key="ragged_paged_attention_vmem"),
         case("softmax_xent", make_xent,
              lambda interp, s: lambda lg, tg: sx.softmax_xent(
                  lg, tg, 256, 2048, interp),

@@ -37,20 +37,31 @@ module owns that cache layout end to end:
 Two interchangeable implementations of the attention itself:
 
 - a Pallas TPU kernel, ``paged_attention_decode``.  A grid step takes ALL
-  heads of one sequence and a block of ``N`` consecutive page slots:
-  ``N`` pieces of ``[H/g, page_size, g·D]`` per pool, each fetched at
-  ``(cache_layer, page)`` — the layer and the page ids are scalar-
-  prefetched, the block specs' leading dimension is squeezed (the pipeline
-  double-buffers the pieces, so the next block's fetch runs under this
-  block's arithmetic) — put side by side as ``[H/g, N * page_size, g·D]``
-  and folded into float32 running max / sum / accumulator by two batched
-  MXU passes.  The one decode query of a head rides 8 sublanes with zeros
+  heads of one sequence and a block of ``N`` consecutive page slots, and
+  the kernel fetches them ITSELF: both pools enter the Mosaic call once,
+  where they rest (``pl.ANY``), and a step's LIVE pages — a run-time
+  count from ``seq_lens``; a slot past the row's last page is not
+  fetched at all — are copied by ``pltpu.make_async_copy`` from
+  ``pool.at[cache_layer, :, page]`` (``[H/g, page_size, g·D]``, the
+  pools' resting layout; the layer, the work list and the page table are
+  scalar-prefetched) into their place in one of two VMEM buffers a pool,
+  ``[H/g, N * page_size, g·D]``.  Step ``s`` starts step ``s + 1``'s
+  copies — another row's too — before it waits for its own, so the next
+  block's fetch runs under this block's arithmetic.  (Fetched through
+  ``N`` block specs a pool, as until PR 39, every page slot cost its
+  pipeline bookkeeping each step and the block could not grow.)  The
+  block is folded into float32 running max / sum / accumulator by two
+  batched MXU passes; rows of a buffer past ``seq_len`` hold the page's
+  own tail, an earlier step's pages or nothing yet — Inf and NaN bits
+  maybe — so their scores are masked AND their V rows are made zero
+  (``p == 0`` alone does not make ``0 · NaN`` zero).  The one decode
+  query of a head rides 8 sublanes with zeros
   in the lanes of the other heads of its group, so a group's ``g`` heads
   are ``g · 8`` query rows whose extra products are exact zeros; each
   head's own lanes of the output are kept by the caller.  Where the cache
   holds fewer K/V heads than the model has query heads (``kv_heads``),
   the ``rep = H / kv_heads`` query heads of a K/V head ride those rows
-  instead (``rep`` rounded up to 8): the pools, the blocks fetched and
+  instead (``rep`` rounded up to 8): the pools, the pages copied and
   the kernel body are the same.  A pass that carries a BLOCK of ``T``
   positions a sequence (generation by diffusion over blocks:
   ``block_paged_attention``) needs no other kernel either: every
@@ -62,13 +73,15 @@ Two interchangeable implementations of the attention itself:
   blocks in order, an idle row one step that writes its zeros — and its
   length is a run-time value: a block wholly past ``seq_len`` is not a
   step at all, so nothing is fetched or multiplied for it, and no length
-  costs a compile.  Slots of a row's last block past its last live page
-  name that page again (never the null page: what they hold is
-  multiplied by ``p == 0`` and must be finite).  ``N`` comes from
-  ``decode_block_pages`` — from ``page_size``, ``head_dim``, the
-  cache's heads, the pool dtype and the table's width — for every caller
-  alike: one MXU pass of score columns (128 tokens), less where VMEM or
-  the table is smaller.  It is exactly one Mosaic call per cache layer;
+  costs a compile.  ``N`` comes from ``decode_block_pages`` — from
+  ``page_size``, ``head_dim``, the cache's heads, the pool dtype and the
+  table's width — for every caller alike: a step costs about half a
+  microsecond beside its bytes, so the block is as many MXU passes of
+  score columns (128 tokens) as make a step carry a megabyte of K and V
+  at the cache's bytes a token — one pass where the cache holds sixteen
+  lane groups, two at ten, eight (1,024 tokens) where it holds two —
+  less where VMEM or the table is smaller.  It is exactly one Mosaic call per
+  cache layer;
   the benchmark's reducers count ``tpu_custom_call``s inside
   ``jit_decode`` (``loop_passes_per_token``) and charge this name's time
   to the roofline, so a split or a fusion over layers would misread both;
@@ -369,6 +382,11 @@ def ragged_paged_attention_reference(q, k_pool, v_pool, cache_layer,
 # -- the Pallas kernel ---------------------------------------------------------
 
 _VMEM_BUDGET = 6 << 20  # bytes of a step's K/V buffers, of 16 MB scoped VMEM
+# K/V bytes one grid step should carry: a step costs about 0.3 microseconds
+# beside its pages' copies, and the kernel alone stopped gaining past a
+# megabyte a step at 2, 4, 10 and 16 lane groups (PERF.md §6, PR 39: the
+# chip sweep)
+_STEP_BYTES = 1 << 20
 
 
 def decode_block_pages(num_heads: int, page_size: int, head_dim: int,
@@ -378,53 +396,91 @@ def decode_block_pages(num_heads: int, page_size: int, head_dim: int,
     kernel covers, from the shapes alone (the same for every caller;
     ``num_heads`` = the heads the CACHE holds).
 
-    A block of ``N * page_size`` tokens is one MXU pass wide (128 score
-    columns), so ``N = 128 // page_size``: a narrower block pays the
-    pass for fewer tokens, a wider one reads (and multiplies) more dead
-    tokens past a short context's end.  Capped by what fits the VMEM
-    budget — per slot, K and V, double-buffered by the pipeline plus the
-    block assembled for the matmuls, each ``[H/g, page, g·D]`` padded to
-    the dtype's (sublane, 128) tile — and by the table's width; at least
-    1."""
+    A block is whole MXU passes of score columns (128 tokens each), as
+    many as make the step carry ``_STEP_BYTES`` of K and V at the cache's
+    bytes a token (``H/g`` rows of ``g·D`` lanes, both pools): a cache
+    with many heads carries that in one pass, one with few heads takes a
+    longer block, or its steps cost more than their bytes.  Capped by
+    what fits the VMEM budget — per page slot, K and V, the two buffers
+    the copies land in plus the block as the body holds it for the
+    matmuls (K loaded, V masked), each ``[H/g, page, g·D]`` padded to
+    the dtype's (sublane, 128) tile — and by the table's width; at
+    least 1."""
     _, groups, _, _, lanes = kv_pool_shape(1, num_heads, 1, page_size,
                                            head_dim)
     sublanes = 8 * max(4 // itemsize, 1)
     tile = (groups * round_up(page_size, sublanes)
             * round_up(lanes, _LANES) * itemsize)
     fits = vmem_budget // (2 * 3 * tile)
-    return int(max(1, min(_LANES // page_size, fits, max_pages)))
+    per_pass = max(_LANES // page_size, 1)
+    token = 2 * groups * lanes * itemsize
+    passes = max(-(-_STEP_BYTES // (per_pass * page_size * token)), 1)
+    return int(max(1, min(per_pass * passes, fits, max_pages)))
+
+
+def decode_steps(seq_lens, block: int):
+    """The grid steps each row takes at ``block`` tokens a step: one per
+    block that holds a live token, an idle row one (its zeros).  Plain
+    arithmetic, so numpy lengths on the host (the engine's ``kv_steps``)
+    and traced ones (``_decode_work``) are counted by the same rule."""
+    steps = -(-seq_lens // block)
+    return steps + (steps == 0)
 
 
 def _decode_work(page_table, seq_lens, n, page_size):
-    """The kernel's work list, from the lengths: ``(rows, blocks, pages,
-    steps)`` — for grid step ``g`` the batch row, the row's block and the
-    ``n`` pool pages to fetch (``pages[g * n + j]``); ``steps`` of the
-    ``B * ceil(maxp / n)`` entries are live.  A row takes one step per
-    block that holds a live token, an idle row one (its zeros)."""
+    """The kernel's work list, from the lengths: ``(rows, blocks, table,
+    steps)`` — for grid step ``g`` the batch row and the row's block
+    (whose page slots are ``table[row * maxp + block * n + j]``: the page
+    table, flat); ``steps`` of the ``B * ceil(maxp / n)`` entries are
+    live, ``decode_steps`` a row."""
     b, maxp = page_table.shape
-    lens = seq_lens.astype(jnp.int32)
-    per_row = jnp.maximum(-(-lens // (n * page_size)), 1)
+    per_row = decode_steps(seq_lens.astype(jnp.int32), n * page_size)
     ends = jnp.cumsum(per_row)
     g = jnp.arange(b * pl.cdiv(maxp, n), dtype=jnp.int32)
     # entries past ``steps`` are never run; they only have to stay in bounds
     rows = jnp.minimum(jnp.sum(g[:, None] >= ends[None, :], axis=1), b - 1)
     blocks = jnp.maximum(g - (ends - per_row)[rows], 0)
-    # slots of a tail block past the row's last live page repeat that page
-    last = jnp.maximum(-(-lens // page_size) - 1, 0)[rows]
-    slots = jnp.minimum(blocks[:, None] * n + jnp.arange(n), last[:, None])
-    pages = page_table.astype(jnp.int32)[rows[:, None], slots]
     return (rows.astype(jnp.int32), blocks.astype(jnp.int32),
-            pages.reshape(-1), ends[-1])
+            page_table.astype(jnp.int32).reshape(-1), ends[-1:])
 
 
-def _decode_kernel(layer_ref, rows_ref, blocks_ref, pages_ref, lens_ref,
-                   q_ref, *refs, scale, page_size, n):
-    k_refs, v_refs = refs[:n], refs[n:2 * n]
-    o_ref, acc_ref, m_ref, l_ref = refs[2 * n:]
+def _decode_kernel(layer_ref, rows_ref, blocks_ref, table_ref, steps_ref,
+                   lens_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
+                   acc_ref, m_ref, l_ref, *, scale, page_size, n):
     g = pl.program_id(0)
     i = blocks_ref[g]
     seq_len = lens_ref[rows_ref[g]]
     block = n * page_size
+    slot = g % 2
+    layer = layer_ref[0]
+    maxp = table_ref.shape[0] // lens_ref.shape[0]  # the table's width
+
+    def pages(step, buf, then):
+        """``then(copy)`` for every live page of work item ``step``: the
+        copy of both pools' page into ``buf`` at its place in the block."""
+        row, blk = rows_ref[step], blocks_ref[step]
+        live = jnp.clip(pl.cdiv(lens_ref[row], page_size) - blk * n, 0, n)
+        at = row * maxp + blk * n
+
+        def page(j, _):
+            src = table_ref[at + j]
+            to = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+            for p, (hbm, vmem) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                then(pltpu.make_async_copy(hbm.at[layer, :, src],
+                                           vmem.at[buf, :, to],
+                                           sems.at[p, buf]))
+
+        lax.fori_loop(0, live, page, None)
+
+    @pl.when(g == 0)
+    def _first():
+        pages(g, slot, lambda copy: copy.start())
+
+    # the next work item's pages, another row's too, under this one's
+    # arithmetic
+    @pl.when(g + 1 < steps_ref[0])
+    def _next():
+        pages(g + 1, 1 - slot, lambda copy: copy.start())
 
     @pl.when(i == 0)
     def _init():
@@ -434,15 +490,23 @@ def _decode_kernel(layer_ref, rows_ref, blocks_ref, pages_ref, lens_ref,
 
     @pl.when(i * block < seq_len)  # false only for an idle row's one step
     def _block():
+        pages(g, slot, lambda copy: copy.wait())
         # [H/g, g * 8, g·D]: each head's query on 8 sublanes, zero in the
         # lanes of its group's other heads
         q = q_ref[0]
-        k = jnp.concatenate([r[...] for r in k_refs], axis=1)  # [H/g, block, g·D]
-        v = jnp.concatenate([r[...] for r in v_refs], axis=1)
+        k = k_buf[slot]                                 # [H/g, block, g·D]
+
+        def live(shape, axis):
+            """Which of the block's tokens, along ``axis``, the row has."""
+            return i * block + lax.broadcasted_iota(
+                jnp.int32, shape, axis) < seq_len
+
+        # rows past the row's end hold what the buffer or the page's tail
+        # held, Inf and NaN bits maybe: ``p == 0`` does not make them zero
+        v = jnp.where(live((1, block, 1), 1), v_buf[slot], 0)
         s = jnp.einsum("hqd,hkd->hqk", q, k,
                        preferred_element_type=jnp.float32) * scale
-        pos = i * block + lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where(pos < seq_len, s, NEG_INF)
+        s = jnp.where(live(s.shape, 2), s, NEG_INF)
         m_prev = m_ref[:, :, :1]
         l_prev = l_ref[:, :, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -471,7 +535,7 @@ def _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale,
     g = lanes // d
     n = decode_block_pages(kv, page_size, d, k_pool.dtype.itemsize,
                            page_table.shape[1])
-    rows, blocks, pages, steps = work or _decode_work(
+    rows, blocks, table, steps = work or _decode_work(
         page_table, seq_lens, n, page_size)
     # K/V head (group, j)'s ``rep`` query heads in lanes [j·D, (j+1)·D) of
     # rows [qr·j, qr·(j + 1)) of its group, zeros elsewhere: the products
@@ -489,19 +553,18 @@ def _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale,
     qb = qb.reshape(b, groups, g * qr, lanes)
     row = pl.BlockSpec(
         (1, groups, g * qr, lanes),
-        lambda s, layer, rows, blocks, pages, lens: (rows[s], 0, 0, 0))
-    slots = [pl.BlockSpec(
-        (None, groups, None, page_size, lanes),
-        lambda s, layer, rows, blocks, pages, lens, j=j: (
-            layer[0], 0, pages[s * n + j], 0, 0))
-        for j in range(n)]
+        lambda s, layer, rows, blocks, table, steps, lens: (rows[s], 0, 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)  # where it rests: the kernel copies
     grid_spec = pltpu.PrefetchScalarGridSpec(
         # the cache layer, the work list and seq_lens ride SMEM
-        num_scalar_prefetch=5,
-        grid=(steps,),
-        in_specs=[row, *slots, *slots],
+        num_scalar_prefetch=6,
+        grid=(steps[0],),
+        in_specs=[row, pool, pool],
         out_specs=row,
         scratch_shapes=[
+            pltpu.VMEM((2, groups, n * page_size, lanes), k_pool.dtype),
+            pltpu.VMEM((2, groups, n * page_size, lanes), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),  # [pool, buffer]
             pltpu.VMEM((groups, g * qr, lanes), jnp.float32),
             pltpu.VMEM((groups, g * qr, _LANES), jnp.float32),
             pltpu.VMEM((groups, g * qr, _LANES), jnp.float32),
@@ -518,8 +581,8 @@ def _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale,
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(jnp.asarray(cache_layer, jnp.int32).reshape(1), rows, blocks, pages,
-      seq_lens.astype(jnp.int32), qb, *[k_pool] * n, *[v_pool] * n)
+    )(jnp.asarray(cache_layer, jnp.int32).reshape(1), rows, blocks, table,
+      steps, seq_lens.astype(jnp.int32), qb, k_pool, v_pool)
     # rows [qr·j, qr·(j + 1)) hold K/V head (group, j)'s query heads in
     # ITS lanes; the rest of each row is the other heads' values under
     # this head's weights

@@ -362,11 +362,13 @@ class ServingEngine:
                 "serve_block_length",
                 "positions a sequence's block pass carries (generation by "
                 "diffusion over blocks)").set(self._block)
-        # tokens one fetched block of the decode kernel covers
-        from paddle_tpu.ops.pallas.paged_attention import decode_block_pages
-        self._kv_block = s.page_size * decode_block_pages(
+        # tokens one grid step of the decode kernel covers, and its rule
+        # for the steps a row takes (``_context_args`` counts by it)
+        from paddle_tpu.ops.pallas import paged_attention
+        self._kv_block = s.page_size * paged_attention.decode_block_pages(
             cfg.kv_heads, s.page_size, cfg.head_dim,
             self.cache.k.dtype.itemsize, s.max_pages_per_seq)
+        self._kv_steps = paged_attention.decode_steps
         self._chunk_passes = 0  # incremental prefill passes this engine ran
         # the compiled executables the step loop dispatches, by a prefill
         # pass's rows and "decode" (_make_ready, at the first admission)
@@ -692,11 +694,15 @@ class ServingEngine:
 
     def _context_args(self, seq_lens) -> dict:
         """What a decode pass's kernel had to read (every live row's
-        resident context) and what it fetched (the same in whole blocks
-        of the kernel's), as span args."""
+        resident context), what it fetched (the same in whole pages: it
+        copies a row's live pages alone) and how (the tokens a grid step
+        covers; the steps a cache layer: a step a block that holds a live
+        token, an idle slot one), as span args — from the host's lengths."""
+        page, block = self.serving.page_size, self._kv_block
         return {"context_tokens": int(seq_lens.sum()),
-                "kv_block_tokens": int((-(-seq_lens // self._kv_block)).sum()
-                                       * self._kv_block)}
+                "kv_block_tokens": int((-(-seq_lens // page)).sum() * page),
+                "kv_block_len": block,
+                "kv_steps": int(self._kv_steps(seq_lens, block).sum())}
 
     def _make_ready(self) -> dict:
         """Compile every program this engine will dispatch, before the

@@ -34,6 +34,8 @@ CASES = (
     "conv2d_bn_act", "lstm_seq_fi", "gru_seq_fi", "bilstm_seq",
     "flash_attention", "ragged_paged_attention",
     "ragged_paged_attention[gpt2l]", "ragged_paged_attention[ouro]",
+    "ragged_paged_attention[sdar]", "ragged_paged_attention[zaya]",
+    "ragged_paged_attention[vmem]",
     "softmax_xent",
     "fused_momentum_update", "ctc_loss_fused", "ctc_loss_fused[logits]",
     "ctc_greedy_decode_fused", "embedding_gather", "embedding_scatter_add",

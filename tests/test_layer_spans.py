@@ -351,10 +351,10 @@ def test_trainer_prefetch_path_keeps_feed_a_leaf(tracer):
 # -- the engine step -----------------------------------------------------------
 
 
-def _engine(**kw):
+def _engine(max_seq_len=64, **kw):
     cfg = T.TransformerConfig(
         vocab_size=64, num_layers=1, num_heads=2, embed_dim=32,
-        mlp_dim=64, max_seq_len=64, remat=False)
+        mlp_dim=64, max_seq_len=max_seq_len, remat=False)
     params = T.init_params(cfg, jax.random.key(1))
     serving = dict(max_slots=2, page_size=4, num_pages=32, max_prompt_len=8,
                    max_new_tokens=4, seed=0)
@@ -506,22 +506,62 @@ def test_serve_prefill_says_what_its_shape_carried(tracer):
 
 @pytest.mark.serving
 def test_serve_decode_says_what_the_kernel_fetches(tracer):
-    """``kv_block_tokens``: every live context rounded up to whole blocks
-    of the decode kernel (``decode_block_pages`` page slots each), beside
-    ``context_tokens``, the part of it that is live."""
+    """``kv_block_tokens``: every live context rounded up to whole PAGES
+    (the decode kernel copies a row's live pages, not whole blocks),
+    beside ``context_tokens``, the part of it that is live."""
     eng = _engine(max_prompt_len=24, max_new_tokens=6)
-    block = eng._kv_block
-    assert block == 32  # 8 slots of 4 tokens: the whole table here
+    page = eng.serving.page_size
+    assert page == 4
     eng.generate([[5, 17, 3], list(range(1, 21))], max_new_tokens=6)
     decodes = _by_name(tracer.spans)["serve_decode"]
     assert decodes
     for d in decodes:
         ctx, got = d.args["context_tokens"], d.args["kv_block_tokens"]
-        assert got >= ctx and got % block == 0
-        assert got < ctx + d.args["batch"] * block  # under a block a row
-    # two rows of 4 and 21 tokens fetch a block each
+        assert got >= ctx and got % page == 0
+        assert got < ctx + d.args["batch"] * page  # under a page a row
+    # two rows of 4 and 21 tokens: one page and six
     assert decodes[0].args["context_tokens"] == 25
-    assert decodes[0].args["kv_block_tokens"] == 64
+    assert decodes[0].args["kv_block_tokens"] == 28
+
+
+@pytest.mark.serving
+@pytest.mark.parametrize("pages", [8, 640])
+def test_serve_decode_says_how_the_kernel_steps(tracer, pages):
+    """``kv_block_len`` (the tokens a grid step of the decode kernel
+    covers: ``decode_block_pages`` of the cache's shape) and ``kv_steps``
+    (the kernel's grid a cache layer: a step for every block that holds a
+    live token, an idle slot one) — from the HOST's lengths: the loop is
+    still a pass ahead, so no device read crept in."""
+    from paddle_tpu.ops.pallas.paged_attention import decode_block_pages
+
+    reg = MetricsRegistry("kernel_steps")
+    eng = _engine(max_slots=3, max_prompt_len=24, num_pages=3 * pages + 1,
+                  max_new_tokens=4 * pages - 24, max_seq_len=4 * pages)
+    assert eng.serving.max_pages_per_seq == pages
+    eng.registry = reg
+    block = 4 * decode_block_pages(2, 4, 16, 4, pages)
+    # this toy's cache (2 heads of 16, float32, pages of 4) would carry a
+    # megabyte in 4,096 tokens: the whole of the narrow table; under the
+    # wide one the VMEM budget binds first (a page pads to the tile's 8 rows)
+    assert block == {8: 32, 640: 1024}[pages]
+    prompts = [[5, 17, 3], list(range(1, 21))]
+    eng.generate(prompts, max_new_tokens=6)
+    decodes = _by_name(tracer.spans)["serve_decode"]
+    assert [d.args["batch"] for d in decodes] == [2] * 5
+    for i, d in enumerate(decodes):
+        # what the step reads: each prompt, its first token and i more;
+        # the third slot idles and takes its one step
+        lens = [len(p) + 1 + i for p in prompts] + [0]
+        assert d.args["context_tokens"] == sum(lens)
+        assert d.args["kv_block_len"] == block
+        assert d.args["kv_steps"] == 3
+    assert [d.args["ahead"] for d in decodes] == [1] * len(decodes)
+    assert reg.get("serve_passes_ahead_total").value(
+        kind="decode") == len(decodes)
+    # lengths around a block's end, as the kernel's work list counts them
+    said = eng._context_args(np.array([0, 1, block, block + 1, 3 * block]))
+    assert said["kv_steps"] == 1 + 1 + 1 + 2 + 3
+    assert said["kv_block_tokens"] == 4 + block + (block + 4) + 3 * block
 
 
 @pytest.mark.serving

@@ -68,14 +68,24 @@ def make_paged(rng, lens, H=2, D=16, ps=8, maxp=4, pool=16, layers=1,
             full_k, full_v)
 
 
-# H, D, page_size, dtype of the blocked-kernel cases: the interpret-mode
-# toy (both heads in one lane group), the two serve cells' heads in the
-# pools' bf16 (two heads a lane group at head_dim 64, one at 128), and an
-# odd head count (the last lane group half padding)
+# H, D, page_size, dtype of the blocked-kernel cases (H = the heads the
+# cache holds): the interpret-mode toy (both heads in one lane group; its
+# block is the whole table), chip_smoke's float32 case, the five serve
+# cells' caches in the pools' bf16 (two heads a lane group at head_dim 64,
+# one at 128; the 2- and 4-head caches at their longer blocks), and an odd
+# head count (the last lane group half padding).  Beside each: the page
+# slots a step covers at a table of 66 pages (``decode_block_pages``)
 _BLOCK_SHAPES = {"h2_d16_p8_f32": (2, 16, 8, "float32"),
+                 "h12_d64_p16_f32": (12, 64, 16, "float32"),
                  "h20_d64_p16_bf16": (20, 64, 16, "bfloat16"),
                  "h16_d128_p16_bf16": (16, 128, 16, "bfloat16"),
-                 "h5_d64_p16_bf16": (5, 64, 16, "bfloat16")}
+                 "h5_d64_p16_bf16": (5, 64, 16, "bfloat16"),
+                 "h2_d128_p16_bf16": (2, 128, 16, "bfloat16"),
+                 "h4_d128_p16_bf16": (4, 128, 16, "bfloat16")}
+_BLOCK_PAGES = {"h2_d16_p8_f32": 66, "h12_d64_p16_f32": 16,
+                "h20_d64_p16_bf16": 16, "h16_d128_p16_bf16": 8,
+                "h5_d64_p16_bf16": 48, "h2_d128_p16_bf16": 64,
+                "h4_d128_p16_bf16": 32}
 _BLOCK_LENGTHS = ("idle", "one", "one_block", "block_plus_1", "whole_table",
                   "ragged")
 _LAYERS, _LAYER = 3, 1  # the blocked cases' pools, and the layer addressed
@@ -88,10 +98,12 @@ def _blocked_case(shape, maxp):
     h, d, ps, dtype = _BLOCK_SHAPES[shape]
     dtype = jnp.dtype(dtype)
     n = PA.decode_block_pages(h, ps, d, dtype.itemsize, maxp)
-    block = n * ps
+    block, cap = n * ps, maxp * ps
     rng = np.random.default_rng(maxp)
-    lens = np.array([0, 1, block, block + 1, maxp * ps,
-                     int(rng.integers(block + 2, maxp * ps))], np.int32)
+    # a block as wide as the table: "block + 1" is the whole table too
+    lens = np.array([0, 1, block, min(block + 1, cap), cap,
+                     int(rng.integers(block + 2 if block + 2 < cap else 2,
+                                      cap))], np.int32)
     used = -(-lens // ps)
     pool = 1 + int(used.sum()) + 5
     ids = rng.permutation(np.arange(1, pool))  # scattered, out of order
@@ -151,7 +163,7 @@ class TestRaggedPagedAttention:
                                    rtol=2e-5, atol=2e-5)
 
     @pytest.mark.parametrize("case", _BLOCK_LENGTHS)
-    @pytest.mark.parametrize("maxp", [18, 66])  # neither a multiple of N
+    @pytest.mark.parametrize("maxp", [18, 66])  # no multiple of a shorter N
     @pytest.mark.parametrize("shape", sorted(_BLOCK_SHAPES))
     def test_blocked_kernel_matches_reference(self, shape, maxp, case):
         """One row per length of interest against the jnp oracle, at cache
@@ -160,10 +172,11 @@ class TestRaggedPagedAttention:
         other cache layer, none of which may reach the result."""
         _, _, ps, dtype = _BLOCK_SHAPES[shape]
         n, block, lens, ker, ref = _blocked_case(shape, maxp)
-        assert maxp % n and block == n * ps
+        assert n == min(_BLOCK_PAGES[shape], maxp) and block == n * ps
+        assert n == maxp or maxp % n
         row = _BLOCK_LENGTHS.index(case)
         assert lens[row] == {"idle": 0, "one": 1, "one_block": block,
-                             "block_plus_1": block + 1,
+                             "block_plus_1": min(block + 1, maxp * ps),
                              "whole_table": maxp * ps,
                              "ragged": lens[row]}[case]
         tol = 2e-5 if dtype == "float32" else 2e-2
@@ -172,18 +185,112 @@ class TestRaggedPagedAttention:
         if case == "idle":
             assert not ker[row].any()
 
-    def test_decode_block_pages_follows_the_shapes(self):
-        pages = PA.decode_block_pages
-        # the benchmark's two serve cells and chip_smoke's case (PERF.md §6)
-        assert pages(20, 16, 64, 2, 64) == 8      # gpt2-large, bf16
-        assert pages(16, 16, 128, 2, 18) == 8     # ouro-2.6b, bf16
-        assert pages(12, 16, 64, 4, 66) == 8      # chip_smoke, float32
-        assert pages(2, 8, 16, 4, 4) == 4         # never wider than the table
-        assert pages(2, 256, 16, 4, 4) == 1       # a page wider than a block
-        got = [pages(20, 16, 64, 2, 64, vmem_budget=kb << 10)
+    @pytest.mark.parametrize("kv_heads", [2, 4])
+    def test_a_block_pass_over_a_few_head_cache(self, rng_np, kv_heads):
+        """``block_paged_attention`` (``T`` = 4 positions a row folded into
+        the query heads, 8 query heads a K/V head) over a 2- and a 4-head
+        cache of 128 lanes at their longer blocks: a row inside its first
+        block, one a token into its second, an idle one."""
+        ps, d, t, maxp = 16, 128, 4, 80
+        n = PA.decode_block_pages(kv_heads, ps, d, 2, maxp)
+        assert n == {2: 64, 4: 32}[kv_heads]
+        lens = np.array([n * ps + 1, 0, 37, maxp * ps - 5], np.int32)
+        kp, vp, pt, _, _ = make_paged(rng_np, lens, H=kv_heads, D=d, ps=ps,
+                                      maxp=maxp, pool=160, layers=2, layer=1)
+        q = jnp.asarray(rng_np.normal(size=(4, t, 8 * kv_heads, d)),
+                        jnp.bfloat16)
+        run = functools.partial(
+            PA.block_paged_attention, q, jnp.asarray(kp, jnp.bfloat16),
+            jnp.asarray(vp, jnp.bfloat16), 1, pt, lens, kv_heads=kv_heads)
+        ker = np.asarray(run(impl="kernel", interpret=True), np.float32)
+        ref = np.asarray(run(impl="reference"), np.float32)
+        np.testing.assert_allclose(ker, ref, rtol=2e-2, atol=2e-2)
+        assert not ker[1].any()
+
+    @pytest.mark.parametrize("shape", ["h2_d16_p8_f32", "h20_d64_p16_bf16",
+                                       "h2_d128_p16_bf16"])
+    def test_dead_rows_and_pages_never_reach_the_result(self, shape):
+        """The kernel copies a row's live pages into a buffer it reuses:
+        past a row's end the buffer holds the page's own tail, an earlier
+        step's pages or nothing yet.  So poison what no live token owns —
+        every page no row lists and every row of a last page past
+        ``seq_len``, NaN in K, Inf in V — and hold the result to the
+        oracle's over clean pools: weighting by ``p == 0`` is not enough."""
+        h, d, ps, dtype = _BLOCK_SHAPES[shape]
+        dtype, maxp = jnp.dtype(dtype), 80
+        block = ps * PA.decode_block_pages(h, ps, d, dtype.itemsize, maxp)
+        rng = np.random.default_rng(7)
+        # a long row, then short ones that leave most of its buffer stale
+        lens = np.array([maxp * ps - 3, 1, 0, min(block + ps + 1, maxp * ps),
+                         ps, 5], np.int32)
+        used = -(-lens // ps)
+        pool = 1 + int(used.sum()) + 6
+        ids = rng.permutation(np.arange(1, pool))
+        pt = np.zeros((len(lens), maxp), np.int32)
+        at = 0
+        for b, u in enumerate(used):
+            pt[b, :u] = ids[at:at + u]
+            at += u
+        kp = rng.normal(size=(h, pool, ps, d)).astype(np.float32)
+        vp = rng.normal(size=(h, pool, ps, d)).astype(np.float32)
+        dead = np.ones((pool, ps), bool)
+        for b, n in enumerate(lens):
+            for tok in range(int(n)):
+                dead[pt[b, tok // ps], tok % ps] = False
+        assert dead[0].all() and dead[ids[at:]].all() and dead.sum() > 7 * ps
+        q = jnp.asarray(rng.normal(size=(len(lens), h, d)), dtype)
+        pools = lambda k_fill, v_fill: [
+            jnp.asarray(as_pool(np.where(dead[None, :, :, None], fill, a),
+                                _LAYERS, _LAYER, fill=fill), dtype)
+            for a, fill in ((kp, k_fill), (vp, v_fill))]
+        ref = PA.ragged_paged_attention(q, *pools(0.0, 0.0), _LAYER, pt, lens,
+                                        impl="reference")
+        for k_fill, v_fill in ((np.nan, np.inf), (-np.inf, np.nan)):
+            ker = PA.ragged_paged_attention(
+                q, *pools(k_fill, v_fill), jnp.int32(_LAYER), pt, lens,
+                impl="kernel", interpret=True)
+            ker = np.asarray(ker.astype(jnp.float32))
+            assert np.isfinite(ker).all()
+            tol = 2e-5 if dtype == jnp.float32 else 2e-2
+            np.testing.assert_allclose(
+                ker, np.asarray(ref.astype(jnp.float32)), rtol=tol, atol=tol)
+
+    # the heads the CACHE holds, page, head_dim, itemsize, the table's
+    # width -> page slots a grid step covers (PERF.md §6, PR 39)
+    @pytest.mark.parametrize("name,args,want", [
+        ("gpt2-large", (20, 16, 64, 2, 64), 16),         # 10 lane groups
+        ("ouro-2.6b", (16, 16, 128, 2, 18), 8),          # a megabyte a pass
+        ("nemotron-3-nano", (2, 16, 128, 2, 48), 48),    # 2 K/V heads: table
+        ("sdar-30b", (4, 16, 128, 2, 48), 32),
+        ("zaya1-8b", (2, 16, 128, 2, 128), 64),
+        ("chip_smoke", (12, 16, 64, 4, 66), 16),         # float32
+        ("one head", (1, 16, 128, 2, 256), 128),
+        ("table-capped", (2, 16, 128, 2, 20), 20),
+        ("toy, table-capped", (2, 8, 16, 4, 4), 4),
+        ("a page wider than a block", (2, 256, 16, 4, 4), 4),
+        ("a page of many passes", (16, 256, 128, 2, 4), 1),
+    ])
+    def test_decode_block_pages_follows_the_shapes(self, name, args, want):
+        """Whole MXU passes of 128 tokens, as many as make a step carry
+        a megabyte of K and V at the cache's bytes a token."""
+        assert PA.decode_block_pages(*args) == want
+        h, ps, d, itemsize, maxp = args
+        groups, lanes = PA.kv_pool_shape(1, h, 1, ps, d)[1::3]
+        carried = want * ps * 2 * groups * lanes * itemsize
+        assert want == maxp or carried >= 1 << 20
+        assert want == 1 or (want * ps) % 128 == 0 or want == maxp
+
+    def test_decode_block_pages_fits_the_vmem_budget(self):
+        got = [PA.decode_block_pages(20, 16, 64, 2, 64, vmem_budget=kb << 10)
                for kb in (1, 256, 512, 1024, 2048, 1 << 20)]
-        assert got == sorted(got) and got[0] == 1 and got[-1] == 8
-        assert 1 < got[2] < 8                     # the budget binds in between
+        assert got == sorted(got) and got[0] == 1 and got[-1] == 16
+        assert 1 < got[2] < 16                    # the budget binds in between
+        few = [PA.decode_block_pages(2, 16, 128, 2, 128, vmem_budget=kb << 10)
+               for kb in (64, 512, 6 << 10)]
+        assert few == [1, 10, 64]
+        # a step's K/V in VMEM: two landing buffers a pool and the block
+        # the body holds, each slot a padded [H/g, page, g·D] tile
+        assert PA.decode_block_pages(32, 16, 128, 4, 32) == 4  # float32
 
     @pytest.mark.parametrize("heads,head_dim,shape", [
         (20, 64, (36, 10, 1537, 16, 128)),   # gpt2-large: two heads a group
