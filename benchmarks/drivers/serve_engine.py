@@ -9,7 +9,9 @@ where the engine hands it out (a wrapper around the scheduler's
 ``append_token``: the program has no streaming callback yet), so time to
 first token and time per output token are the benchmark's own clock.
 After the window the plain reference reads a seeded sample of the served
-requests.
+requests.  In a traced run the profile is stopped off this thread
+(``harness/trace.stop_off_thread``): ``stop_trace`` takes tens of
+seconds, and a load generator that waits for it sends nothing meanwhile.
 """
 
 from __future__ import annotations
@@ -187,8 +189,10 @@ def run(run) -> dict:
             stamps.profiling = True
             prof["state"], prof["t0"] = "on", time.perf_counter()
         elif prof["state"] == "on" and now >= prof["t0"] + prof_len:
+            # off this thread: the loop keeps collecting answers and
+            # sending the clients' next requests while stop_trace runs
             stamps.profiling = False
-            trace_mod.stop()
+            prof["stopper"] = trace_mod.stop_off_thread(prof)
             prof["state"] = "done"
         if closed:
             for r in collect(0.002):
@@ -222,6 +226,10 @@ def run(run) -> dict:
     collect(0.0)
     backlog = sum(1 for rid in info if rid not in results)
     engine.stop()
+    if "stopper" in prof:
+        # after the engine: while this thread waits here no client sends
+        prof["stopper"].join()
+        run.log(f"stop_trace took {prof['stop_s']:.1f} s, off this thread")
     mem = run.devices[0].memory_stats() or {}
     # live buffers and the programs' reserved scratch are separate pools
     peak_bytes = int(mem.get("peak_bytes_in_use", 0)) + int(
@@ -305,19 +313,20 @@ def run(run) -> dict:
              "sizes": {"max_slots": sv["max_slots"]},
              "decode_context_tokens": stamps.decode_context_tokens,
              "served_sample": [served[r] for r in picks]}
+    notes = {"window_s": window_s, "requests_due": len(window),
+             "requests_finished": finished_in, "tokens": tokens,
+             "ttft_median_ms": stats.median(ttft),
+             "itl_median_ms": stats.median(itl),
+             "routes": routes, "served_tokens_checked": len(gaps["served"]),
+             "distinct_tokens": distinct, "backlog_at_end": backlog}
     if run.trace and prof["state"] == "done":
         trace_mod.attach(layer, profile_dir, prof["marker_ns"],
                          only=("serve_prefill", "serve_decode"))
+        notes.update(trace_mod.margin_notes(layer, prof))
     return {"end_to_end": e2e, "attempted": len(window) + refused["n"],
             "failed": failed, "layer": layer,
             "compiles_in_window": compiles, "memory_peak_bytes": peak_bytes,
-            "notes": {"window_s": window_s, "requests_due": len(window),
-                      "requests_finished": finished_in, "tokens": tokens,
-                      "ttft_median_ms": stats.median(ttft),
-                      "itl_median_ms": stats.median(itl),
-                      "routes": routes, "served_tokens_checked":
-                      len(gaps["served"]), "distinct_tokens": distinct,
-                      "backlog_at_end": backlog}}
+            "notes": notes}
 
 
 def control(cell_run) -> dict:

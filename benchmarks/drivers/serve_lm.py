@@ -12,18 +12,15 @@ stamps, the same window rules, the same definitions of
 checks); what differs is where the program's configuration comes from,
 the pools sized by cache layers (``num_layers x loop_steps``), and what
 the looped-stack metrics need beside the spans: ``span_offset_ns``, which
-puts the program's spans on the device trace's clock.  One more
-difference, in traced runs only: the profile is stopped on a thread of
-its own (``stop_trace`` takes seconds here, and on the load generator's
-thread it kept finished clients from sending, so the traced run read the
-profiler's stall as queueing and lost occupancy).
+puts the program's spans on the device trace's clock.  In traced runs
+both stop the profile off the load generator's thread
+(``harness/trace.stop_off_thread``).
 """
 
 from __future__ import annotations
 
 import gc
 import os
-import threading
 import time
 
 import numpy as np
@@ -50,12 +47,6 @@ def _program_config(cfg: dict, T):
     return T.TransformerConfig(
         **m, dtype=getattr(jnp, cfg["dtype"]), remat=False,
         attn_impl=cfg["prefill_attn_impl"])
-
-
-def _stop_profile(prof: dict) -> None:
-    t0 = time.perf_counter()
-    trace_mod.stop()
-    prof["stop_s"] = time.perf_counter() - t0
 
 
 def run(run) -> dict:
@@ -170,14 +161,10 @@ def run(run) -> dict:
             stamps.profiling = True
             prof["state"], prof["t0"] = "on", time.perf_counter()
         elif prof["state"] == "on" and now >= prof["t0"] + prof_len:
-            # stop_trace collects and writes the trace for seconds: on a
-            # thread of its own, so that this loop keeps collecting answers
-            # and sending the clients' next requests meanwhile
+            # off this thread: the loop keeps collecting answers and
+            # sending the clients' next requests while stop_trace runs
             stamps.profiling = False
-            prof["stopper"] = threading.Thread(
-                target=_stop_profile, args=(prof,), name="bench-trace-stop",
-                daemon=True)
-            prof["stopper"].start()
+            prof["stopper"] = trace_mod.stop_off_thread(prof)
             prof["state"] = "done"
         if closed:
             for r in collect(0.002):
@@ -299,9 +286,16 @@ def run(run) -> dict:
              "sizes": {"max_slots": sv["max_slots"]},
              "decode_context_tokens": stamps.decode_context_tokens,
              "served_sample": [served[r] for r in picks]}
+    notes = {"window_s": window_s, "requests_due": len(window),
+             "requests_finished": finished_in, "tokens": tokens,
+             "ttft_median_ms": stats.median(ttft),
+             "itl_median_ms": stats.median(itl),
+             "routes": routes, "served_tokens_checked": len(gaps["served"]),
+             "distinct_tokens": distinct, "backlog_at_end": backlog}
     if run.trace and prof["state"] == "done":
         trace_mod.attach(layer, profile_dir, prof["marker_ns"],
                          only=("serve_prefill", "serve_decode"))
+        notes.update(trace_mod.margin_notes(layer, prof))
         if layer.get("profile_window"):
             # span time (s, host clock) * 1e9 + this = the profile's clock
             layer["span_offset_ns"] = (layer["profile_window"][0]
@@ -309,13 +303,7 @@ def run(run) -> dict:
     return {"end_to_end": e2e, "attempted": len(window) + refused["n"],
             "failed": failed, "layer": layer,
             "compiles_in_window": compiles, "memory_peak_bytes": peak_bytes,
-            "notes": {"window_s": window_s, "requests_due": len(window),
-                      "requests_finished": finished_in, "tokens": tokens,
-                      "ttft_median_ms": stats.median(ttft),
-                      "itl_median_ms": stats.median(itl),
-                      "routes": routes, "served_tokens_checked":
-                      len(gaps["served"]), "distinct_tokens": distinct,
-                      "backlog_at_end": backlog}}
+            "notes": notes}
 
 
 def control(cell_run) -> dict:

@@ -102,6 +102,7 @@ class Run:
     checks: list = dataclasses.field(default_factory=list)
     compiles: CompileWatch | None = None
     scratch: str = ""
+    watchdog_ends: float | None = None
 
     def log(self, msg: str) -> None:
         result_mod.log(f"[{self.workload} +{time.perf_counter() - self.proc_t0:.1f}s] {msg}")
@@ -121,6 +122,7 @@ class Run:
         """From here the run may take ``seconds`` more.  A run that hangs
         dumps every thread's stack and exits non-zero instead of holding
         the chip."""
+        self.watchdog_ends = time.perf_counter() + seconds
         faulthandler.dump_traceback_later(seconds, exit=True,
                                           file=sys.__stderr__)
 
@@ -253,11 +255,17 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             "idle_gaps": trace_mod.idle_gaps(
                 prof, out["layer"].get("host_spans", ()), win)}
     faulthandler.cancel_dump_traceback_later()
+    notes = out.get("notes", {})
+    if trace and run.watchdog_ends is not None:
+        # a traced run's margin, printed so that its erosion is seen
+        # before it kills a run: the trace's size grows with the engine's
+        # speed, and with it the time to stop, load and reduce it
+        notes = dict(notes, watchdog_left_s=run.watchdog_ends
+                     - time.perf_counter())
     line = result_mod.final_line(
         correct, out["attempted"], out["failed"], values, units, device,
         breakdown, extra={"workload": workload, "seed": int(seed),
-                          "checks": run.checks,
-                          "notes": out.get("notes", {})})
+                          "checks": run.checks, "notes": notes})
     result_mod.emit(line)
     return json.loads(line)
 

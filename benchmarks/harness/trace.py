@@ -16,6 +16,8 @@ from __future__ import annotations
 import glob
 import os
 import re
+import threading
+import time
 
 MODULES, OPS = "XLA Modules", "XLA Ops"
 HOST_PREFIX = "bench:"            # host annotations the benchmark emits
@@ -29,7 +31,6 @@ def start(logdir: str) -> int:
     reader makes millions of calls) and emit the start marker; returns
     the marker's time on the host's perf_counter clock (ns)."""
     import shutil
-    import time
 
     import jax.profiler
 
@@ -48,6 +49,35 @@ def stop() -> None:
     with jax.profiler.TraceAnnotation(HOST_PREFIX + "end"):
         pass
     jax.profiler.stop_trace()
+
+
+def stop_off_thread(prof: dict) -> threading.Thread:
+    """``stop()`` on a thread of its own, started here: ``stop_trace``
+    collects and writes the trace for ~46 us a device event -- tens of
+    seconds -- and a load generator that waits for it sends nothing
+    meanwhile.  ``prof["stop_s"]`` gets the seconds it took.  The caller
+    joins the thread once its sending loop is over, before ``attach``."""
+    def stopper():
+        t0 = time.perf_counter()
+        try:
+            stop()
+        finally:
+            prof["stop_s"] = time.perf_counter() - t0
+
+    thread = threading.Thread(target=stopper, name="bench-trace-stop",
+                              daemon=True)
+    thread.start()
+    return thread
+
+
+def margin_notes(layer: dict, prof: dict) -> dict:
+    """What a traced run's time limits erode with, for the line's
+    ``notes``: the seconds ``stop_trace`` took and the device events
+    ("XLA Ops") of the profile ``attach`` loaded."""
+    devices = (layer.get("profile") or {}).get("devices", {})
+    return {"stop_trace_s": prof.get("stop_s"),
+            "profile_events": sum(len(lines.get(OPS, ()))
+                                  for lines in devices.values())}
 
 
 def span_dicts(spans) -> list[dict]:
@@ -147,16 +177,21 @@ def span_of(profile: dict) -> tuple[int, int]:
     return min(starts), max(ends)
 
 
+def _busy_unions(profile: dict, window) -> dict:
+    """Per device: the merged intervals in which an operation ran (the
+    "XLA Ops" events, or the modules where a device has no ops line)."""
+    return {dev: _union((s, e) for _, s, e in _clip(
+        lines.get(OPS) or lines.get(MODULES) or [], window))
+        for dev, lines in profile["devices"].items()}
+
+
 def busy(profile: dict, window=None) -> dict:
     """Per device: seconds in which an operation ran (union of the
     "XLA Ops" intervals, or of the modules where a device has no ops
     line), and the mean over devices."""
     window = window or span_of(profile)
-    per = {}
-    for dev, lines in profile["devices"].items():
-        evs = lines.get(OPS) or lines.get(MODULES) or []
-        per[dev] = _length(_union(
-            (s, e) for _, s, e in _clip(evs, window))) / 1e9
+    per = {dev: _length(merged) / 1e9
+           for dev, merged in _busy_unions(profile, window).items()}
     n = max(len(per), 1)
     return {"per_device_s": per, "busy_s": sum(per.values()) / n,
             "window_s": (window[1] - window[0]) / 1e9}
@@ -168,17 +203,28 @@ def idle_pct(profile: dict, window=None) -> float:
 
 
 def module_ms(profile: dict, pattern: str | None = None, window=None) -> dict:
-    """Sum and count of the "XLA Modules" events whose name matches,
-    averaged over devices: the device-side time of whole programs."""
+    """Time and count of the "XLA Modules" events whose name matches,
+    averaged over devices: the device-side time of whole programs.  A
+    call that an end of the window cuts gives the time it spends inside
+    (the rooflines' floors count a cut step by that share too) and counts
+    by that time in units of the mean call that lies whole inside -- not
+    of its own length, which the profile's end may have cut as well -- so
+    time over count is the mean time of the whole calls."""
     rx = re.compile(pattern) if pattern else None
-    tot, cnt, ndev = 0.0, 0, 0
+    lo, hi = window or (float("-inf"), float("inf"))
+    tot, cnt, ndev = 0.0, 0.0, 0
     for lines in profile["devices"].values():
-        evs = [e for e in _clip(lines.get(MODULES, []), window)
-               if rx is None or rx.search(e[0])]
-        if evs:
+        # (ns inside the window, the call's own ns)
+        parts = [(min(s + d, hi) - max(s, lo), d)
+                 for n, s, d in lines.get(MODULES, [])
+                 if s + d > lo and s < hi and (rx is None or rx.search(n))]
+        if parts:
             ndev += 1
-            tot += sum(e - s for _, s, e in evs) / 1e6
-            cnt += len(evs)
+            tot += sum(t for t, _ in parts) / 1e6
+            whole = [d for t, d in parts if t == d]
+            cut = sum(t for t, d in parts if t < d)
+            mean = sum(whole) / len(whole) if whole else 0
+            cnt += len(whole) + cut / mean if cut and mean else len(parts)
     ndev = max(ndev, 1)
     return {"total_ms": tot / ndev, "count": cnt / ndev}
 
@@ -194,9 +240,9 @@ def op_sums(profile: dict, window=None) -> dict[str, float]:
     ndev = max(len(profile["devices"]), 1)
     for lines in profile["devices"].values():
         for n, s, e in _clip(lines.get(OPS, []), window):
-            if not CONTAINER.search(n):
-                sums[n] = sums.get(n, 0.0) + (e - s) / 1e9 / ndev
-    return sums
+            sums[n] = sums.get(n, 0.0) + (e - s) / 1e9 / ndev
+    # a profile holds millions of events of some thousands of names
+    return {n: v for n, v in sums.items() if not CONTAINER.search(n)}
 
 
 def kernel_seconds(profile: dict, pattern: str, window=None,
@@ -249,13 +295,13 @@ def exposed_collective_s(profile: dict, window=None) -> dict:
 def idle_gaps(profile: dict, host_spans=(), window=None, top: int = 10):
     """The longest gaps of the busiest-idle device, each named after the
     host span (name, start_ns, end_ns on the profile's clock) that covers
-    most of it; gaps of one name are summed.  [[name, seconds], ...]."""
+    most of it (of equal covers the first in ``host_spans``); gaps of one
+    name are summed.  [[name, seconds], ...].  One sweep over the gaps
+    and the spans, both by start: a gap looks only at the spans that
+    overlap it, not at every span (657k gaps x 1,200 spans took 3 min)."""
     window = window or span_of(profile)
-    dev = min(profile["devices"],
-              key=lambda d: busy(profile, window)["per_device_s"][d])
-    lines = profile["devices"][dev]
-    merged = _union((s, e) for _, s, e in _clip(
-        lines.get(OPS) or lines.get(MODULES) or [], window))
+    unions = _busy_unions(profile, window)
+    merged = unions[min(unions, key=lambda d: _length(unions[d]))]
     gaps, cur = [], window[0]
     for s, e in merged:
         if s > cur:
@@ -263,13 +309,20 @@ def idle_gaps(profile: dict, host_spans=(), window=None, top: int = 10):
         cur = max(cur, e)
     if window[1] > cur:
         gaps.append((cur, window[1]))
+    spans = sorted((ss, se, i, name)
+                   for i, (name, ss, se) in enumerate(host_spans) if se > ss)
+    live, nxt = [], 0       # the spans that start before this gap's end
     named: dict[str, float] = {}
-    for gs, ge in gaps:
-        best, cover = "host:untraced", 0
-        for name, ss, se in host_spans:
+    for gs, ge in gaps:     # ascending and disjoint
+        while nxt < len(spans) and spans[nxt][0] < ge:
+            live.append(spans[nxt])
+            nxt += 1
+        live = [sp for sp in live if sp[1] > gs]    # ... and end after its start
+        best, cover, first = "host:untraced", 0, 0
+        for ss, se, i, name in live:
             c = min(ge, se) - max(gs, ss)
-            if c > cover:
-                best, cover = name, c
+            if c > cover or (c == cover and i < first):
+                best, cover, first = name, c, i
         named[best] = named.get(best, 0.0) + (ge - gs) / 1e9
     return [[k, v] for k, v in sorted(named.items(),
                                       key=lambda kv: -kv[1])[:top]]

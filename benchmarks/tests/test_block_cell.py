@@ -204,8 +204,12 @@ def test_benchmark_json_names_the_cell_and_its_metrics():
     assert entry == {"name": CELL, "config": CONFIG,
                      "traffic": cell["traffic_name"], "chips": 1,
                      "why": cell["why"]}
-    assert bench["workloads"][-1] == entry and len(cell["why"]) <= 200
-    conf = bench["configs"][-1]
+    assert len(cell["why"]) <= 200
+    # found by name, in order among themselves: a later PR appends behind
+    # them, so nothing here says "last"
+    cells = [w["name"] for w in bench["workloads"]]
+    assert cells.index(CELL) == cells.index("nemo3n_serve_closed64") + 1
+    conf = next(c for c in bench["configs"] if c["name"] == CONFIG)
     cfg = runner.load_json("configs", CONFIG, [runner.ROOT])
     assert (conf["name"], conf["source"], conf["reduced"]) == (
         CONFIG, cfg["source_url"], ["num_hidden_layers"])
@@ -217,7 +221,8 @@ def test_benchmark_json_names_the_cell_and_its_metrics():
                                       if moves[n] == "serve_tok_per_s"}
     new = [m for m in bench["per_layer"] if m.get("workloads") == [CELL]]
     assert [m["name"] for m in new] == cell["per_layer"][6:]
-    assert bench["per_layer"][-len(new):] == new      # appended, in order
+    at = bench["per_layer"].index(new[0])
+    assert bench["per_layer"][at:at + len(new)] == new     # one block, in order
     for name in cell["per_layer"]:
         spec = runner.load_json("layer_metrics", name, [runner.ROOT])
         decl = next(m for m in bench["per_layer"] if m["name"] == name)
@@ -226,7 +231,8 @@ def test_benchmark_json_names_the_cell_and_its_metrics():
             k: decl[k] for k in ("unit", "better", "source", "layer",
                                  "moves")}, name
     e2e = {m["name"]: m for m in bench["end_to_end"]}
-    assert e2e["serve_tok_per_s"]["workloads"][-1] == CELL
+    rate = e2e["serve_tok_per_s"]["workloads"]
+    assert CELL in rate and rate == [n for n in cells if n in rate]
     assert set(cell["end_to_end"]) - {"setup_s"} == {
         n for n, m in e2e.items() if CELL in m.get("workloads", ())}
     assert set(cell["limits"]) == {"served_logit_gap", "served_mean_gap"}

@@ -159,3 +159,91 @@ def test_no_chip_no_result():
         cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
     assert p.returncode == runner.EXIT_NO_CHIP
     assert '"correct"' not in p.stdout and "need 1 TPU" in p.stderr
+
+
+@pytest.mark.parametrize("cell, driver", [("gpt2_toy_closed", "serve_engine"),
+                                          ("ouro_toy_closed", "serve_lm")])
+def test_a_slow_stop_trace_stalls_no_client(cell, driver, data_root, capsys,
+                                            monkeypatch):
+    """``stop_trace`` takes longer than the window and the grace together
+    (as it does on the chip past ~650k device events): the clients go on
+    sending meanwhile, nothing is failed, the driver joins the stop before
+    it loads the trace, and the line says how long the stop took."""
+    import threading
+    import time
+
+    from benchmarks.harness import trace as trace_mod
+
+    cfg = runner.load_json("configs", runner.load_json(
+        "workloads", cell, [data_root])["config"], [data_root])
+    assert cfg["driver"] == driver
+    seconds = 1.0
+    grace = runner.load_py("drivers", "serve_engine", [runner.ROOT]).GRACE_S
+    real_stop, real_attach, seen = trace_mod.stop, trace_mod.attach, {}
+
+    def slow_stop():
+        seen["stop_thread"] = threading.current_thread().name
+        time.sleep(seconds + grace + 1.0)
+        real_stop()
+        seen["stopped"] = True
+
+    def attach(*a, **kw):
+        seen["stopped_before_attach"] = seen.get("stopped", False) and not [
+            t for t in threading.enumerate() if t.name == "bench-trace-stop"]
+        return real_attach(*a, **kw)
+
+    monkeypatch.setattr(trace_mod, "stop", slow_stop)
+    monkeypatch.setattr(trace_mod, "attach", attach)
+    out = _run(cell, data_root, capsys, trace=True, seconds=seconds)
+    assert out["correct"] and out["failed"] == 0 and out["attempted"] > 8
+    assert seen["stop_thread"] == "bench-trace-stop"
+    assert seen["stopped_before_attach"] is True
+    # requests were sent while stop_trace slept: a loop that waited for it
+    # sends its clients' next requests ~11 s late
+    assert out["metrics"]["loadgen_late_mean_ms.serve"]["value"] < 50.0
+    notes = out["notes"]
+    assert notes["stop_trace_s"] >= seconds + grace + 1.0
+    assert notes["profile_events"] == 0          # no device plane on a CPU
+    assert 0 < notes["watchdog_left_s"] < 3 * seconds + 240 - notes[
+        "stop_trace_s"]
+    assert notes["backlog_at_end"] <= 4
+
+
+def test_an_untraced_line_carries_no_margin_notes(data_root, capsys):
+    out = _run("gpt2_toy_closed", data_root, capsys, seconds=1.0)
+    assert not {"stop_trace_s", "profile_events", "watchdog_left_s"} & set(
+        out["notes"])
+
+
+def _sending_loops(path):
+    """What each outermost ``while`` loop of a driver that calls
+    ``send(...)`` calls, anywhere in its body."""
+    import ast
+
+    with open(path) as f:
+        tree = ast.parse(f.read())
+
+    def calls(node):
+        return [c.func for c in ast.walk(node) if isinstance(c, ast.Call)]
+
+    loops = [w for w in ast.walk(tree) if isinstance(w, ast.While) and any(
+        getattr(f, "id", None) == "send" for f in calls(w))]
+    inner = {id(w) for outer in loops for w in ast.walk(outer)
+             if w is not outer}
+    return [calls(w) for w in loops if id(w) not in inner]
+
+
+@pytest.mark.parametrize("name", sorted(
+    f for f in os.listdir(os.path.join(runner.ROOT, "drivers"))
+    if f.startswith("serve") and f.endswith(".py")))
+def test_no_serve_driver_stops_the_profile_inside_its_sending_loop(name):
+    """A driver copied from an old one cannot bring the disease back:
+    inside the loop that sends requests nothing calls ``trace.stop`` or
+    ``stop_trace``; the loop hands the stop to ``stop_off_thread``."""
+    loops = _sending_loops(os.path.join(runner.ROOT, "drivers", name))
+    if name in ("serve_engine.py", "serve_lm.py"):
+        assert len(loops) == 1
+    for funcs in loops:
+        attrs = [f.attr for f in funcs if hasattr(f, "attr")]
+        assert "stop" not in attrs and "stop_trace" not in attrs, name
+        assert "stop_off_thread" in attrs, name

@@ -235,3 +235,134 @@ def test_trace_reduction_on_a_step_recorded_on_the_chip(data_root):
     assert "custom-call[tpu_custom_call]" in name and "112,112,64" in name
     assert 0.29 < secs < 0.30          # the 7x7 stem: half of the step
     assert trace.exposed_collective_s(rec, win)["collective_s"] == 0
+
+
+def _idle_gaps_oracle(profile, host_spans=(), window=None, top=10):
+    """``trace.idle_gaps`` as it was until PR 41: every span for every
+    gap, the device chosen by a ``busy`` a device.  Kept as the oracle."""
+    window = window or trace.span_of(profile)
+    dev = min(profile["devices"],
+              key=lambda d: trace.busy(profile, window)["per_device_s"][d])
+    lines = profile["devices"][dev]
+    merged = trace._union((s, e) for _, s, e in trace._clip(
+        lines.get(trace.OPS) or lines.get(trace.MODULES) or [], window))
+    gaps, cur = [], window[0]
+    for s, e in merged:
+        if s > cur:
+            gaps.append((cur, s))
+        cur = max(cur, e)
+    if window[1] > cur:
+        gaps.append((cur, window[1]))
+    named = {}
+    for gs, ge in gaps:
+        best, cover = "host:untraced", 0
+        for name, ss, se in host_spans:
+            c = min(ge, se) - max(gs, ss)
+            if c > cover:
+                best, cover = name, c
+        named[best] = named.get(best, 0.0) + (ge - gs) / 1e9
+    return [[k, v] for k, v in sorted(named.items(),
+                                      key=lambda kv: -kv[1])[:top]]
+
+
+def _random_profile(rng, devices, ops):
+    """Operations of 1-40 ns with gaps of 0-200 ns between them; host
+    spans of 0-400 ns on up to three threads (none overlap on a thread,
+    any may across threads), many of equal length, handed over shuffled."""
+    devs = {}
+    for d in range(devices):
+        t, evs = rng.randrange(50), []
+        for i in range(ops):
+            dur = rng.randrange(1, 40)
+            evs.append([f"op.{i % 7}", t, dur])
+            t += dur + rng.choice([0, 0, 0, 1, 5, 30, 200])
+        devs[str(d)] = {trace.OPS: evs}
+    spans = []
+    for th in range(rng.choice([1, 2, 3])):
+        t = rng.randrange(100)
+        for _ in range(rng.randrange(0, 60)):
+            dur = rng.choice([0, 1, 10, 50, 50, 400])
+            spans.append((f"{rng.choice('abc')}@{th}", t, t + dur))
+            t += dur + rng.choice([0, 0, 3, 50])
+    rng.shuffle(spans)
+    return {"devices": devs, "host": []}, spans
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_idle_gaps_names_and_seconds_are_the_quadratic_scans(devices):
+    import random
+
+    rng = random.Random(41 + devices)
+    seen = set()
+    for case in range(150):
+        prof, spans = _random_profile(rng, devices, rng.randrange(1, 200))
+        if case % 7 == 0:
+            spans = []
+        lo, hi = trace.span_of(prof)
+        win = None if case % 3 == 0 else (lo + rng.randrange(-20, 50),
+                                          hi - rng.randrange(-20, 50))
+        want = _idle_gaps_oracle(prof, spans, win)
+        assert trace.idle_gaps(prof, spans, win) == want, case
+        seen.update(n for n, _ in want)
+    # gaps under no span, and spans of every thread, did occur
+    assert "host:untraced" in seen and {"a@0", "b@1", "c@2"} <= seen
+
+
+def test_idle_gaps_of_equal_covers_takes_the_first_span_handed_over():
+    prof = {"devices": {"0": {trace.OPS: [["a", 0, 10], ["b", 110, 10]]}},
+            "host": []}
+    spans = [("late", 5, 200), ("first", 0, 300), ("second", 10, 110)]
+    assert trace.idle_gaps(prof, spans) == _idle_gaps_oracle(prof, spans) \
+        == [["late", 100e-9]]
+    assert trace.idle_gaps(prof, spans[1:])[0][0] == "first"
+    assert trace.idle_gaps(prof, [("half", 10, 60)] + spans[1:])[0][0] \
+        == "first"
+
+
+def test_idle_gaps_is_near_linear_in_gaps_and_spans():
+    """200k gaps x 1.2k spans (a traced `zaya1_serve_closed64` run holds
+    657k x 1.2k, which took the quadratic scan 3 min of a 5 min watchdog)."""
+    import time
+
+    step, n = 4500, 200_000
+    prof = {"devices": {"0": {trace.OPS: [
+        ["%fusion." + str(i % 500), i * step, 3000] for i in range(n)]}},
+        "host": []}
+    width = n * step // 1200
+    spans = [("serve_decode@loop", i * width, (i + 1) * width - 10)
+             for i in range(1200)]
+    t0 = time.perf_counter()
+    got = trace.idle_gaps(prof, spans)
+    assert time.perf_counter() - t0 < 5.0
+    assert got[0][0] == "serve_decode@loop"
+    assert got[0][1] == pytest.approx((n - 1) * 1500e-9, rel=1e-3)
+
+
+@pytest.mark.parametrize("window, total_ns, count", [
+    # five calls of 900-1100 ns every 1500; the window's ends cut the first
+    # and the last in half: the 3 whole ones + 1000 ns in units of their mean
+    ((500, 6500), 4000, 4.0),
+    ((0, 7000), 5000, 5),             # none cut
+    ((1200, 6500), 3500, 3.5),        # an end in a pause cuts no call
+    (None, 5000, 5)])
+def test_module_ms_counts_a_cut_call_by_its_time_inside(window, total_ns,
+                                                        count):
+    prof = {"devices": {"0": {trace.MODULES: [
+        ["jit_decode(7)", 1500 * i, d]
+        for i, d in enumerate((1000, 900, 1000, 1100, 1000))] + [
+        ["jit_prefill(3)", 1100, 300]]}}, "host": []}
+    m = trace.module_ms(prof, "jit_decode", window)
+    assert m["total_ms"] == pytest.approx(total_ns / 1e6)
+    assert m["count"] == pytest.approx(count)
+    # time over count is the mean of the calls that lie whole inside,
+    # whatever the ends cut; counting the two halves as calls read 800
+    assert m["total_ms"] / m["count"] == pytest.approx(1000 / 1e6)
+    if window == (500, 6500):
+        # the profile's own end cut the last call short as well (a profile
+        # ends within milliseconds of the window): its length is not used
+        prof["devices"]["0"][trace.MODULES][4][2] = 600
+        m = trace.module_ms(prof, "jit_decode", window)
+        assert m["total_ms"] / m["count"] == pytest.approx(1000 / 1e6)
+        # nothing lies whole inside: the calls are counted as they come
+        m = trace.module_ms(prof, "jit_decode", (1600, 2300))
+        assert m["count"] == 1 and m["total_ms"] == pytest.approx(700e-6)
