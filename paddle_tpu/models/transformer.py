@@ -32,7 +32,7 @@ from paddle_tpu.ops import attention as attn_ops
 
 
 # a layer pattern's characters -> the kind's name in ``params["blocks"]``
-_KINDS = {"*": "attn", "-": "mlp", "E": "moe", "M": "mamba"}
+_KINDS = {"*": "attn", "-": "mlp", "E": "moe", "M": "mamba", "K": "kda"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,9 +78,9 @@ class TransformerConfig:
     # experts are ``mlp_dim`` wide with no bias, ``mlp`` their kind
     # ("relu2" | "gelu": act(x W_in) W_out; "swiglu": gated, (silu(x
     # W_gate) * (x W_in)) W_out); a shared expert of ``moe_shared_dim`` (0
-    # = none; ungated) takes every token.  ``moe_held`` = [lo, hi): the
-    # experts THIS device holds and computes (None = all) — expert
-    # parallelism's share of the layer
+    # = none; of the experts' kind, gated where they are) takes every
+    # token.  ``moe_held`` = [lo, hi): the experts THIS device holds and
+    # computes (None = all) — expert parallelism's share of the layer
     moe_router: str = "softmax"
     moe_scale: float = 1.0
     moe_shared_dim: int = 0
@@ -119,8 +119,9 @@ class TransformerConfig:
     # layers of several kinds: one character per layer, each layer ONE
     # mixer behind a pre-norm and a residual add — "*" attention, "-" the
     # dense MLP, "E" routed experts (dropless: ``moe_router`` "sigmoid" or
-    # "softmax_topk"), "M" a Mamba-2 mixer.  None = ``num_layers`` blocks
-    # of (attention, MLP).
+    # "softmax_topk"), "M" a Mamba-2 mixer, "K" a gated delta-rule
+    # linear-attention mixer (KDA).  None = ``num_layers`` blocks of
+    # (attention, MLP).
     # ``params["blocks"]`` is then a list of per-layer trees in pattern
     # order, walked by the pattern.
     pattern: str | None = None
@@ -168,6 +169,18 @@ class TransformerConfig:
     # a pattern layer's residual add: False = x + y; True = (g_x * x +
     # b_x) + (g_y * y + b_y), four learned vectors a layer
     residual_scale: bool = False
+    # the Kimi Delta Attention mixer of a "K" layer (arXiv:2510.26692):
+    # ``kda_heads`` heads of ``head_dim`` keys and values, q | k | v each
+    # through a depthwise causal conv of ``kda_conv`` taps, a decay per key
+    # channel and an output gate, both through a low-rank pair at rank
+    # ``head_dim``, a step beta in (0, 2); prefill in chunks of
+    # ``kda_chunk`` (``ops/kda.py``)
+    kda_heads: int = 0
+    kda_conv: int = 4
+    kda_chunk: int = 64
+    # a "*" layer's output gate: a * sigmoid(h W_g), elementwise over the
+    # heads' outputs, h the layer's normed input
+    attn_gate: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -191,10 +204,6 @@ class TransformerConfig:
             raise ValueError(f"num_heads {self.num_heads} is not a multiple "
                              f"of kv_heads {self.kv_heads}")
         if self.moe_experts and self.moe_dropless:
-            if self.mlp == "swiglu" and self.moe_shared_dim:
-                raise NotImplementedError(
-                    "a shared expert beside gated routed experts: the "
-                    "shared expert has no gate matrix")
             self.routed  # validates top_k and the held share
         if self.block_len < 1:
             raise ValueError(f"block_len must be >= 1, got {self.block_len}")
@@ -208,11 +217,12 @@ class TransformerConfig:
                 raise NotImplementedError(
                     f"attn_impl {self.attn_impl!r} under block_len > 1: only "
                     "'exact' and 'flash' take the block-causal mask")
-            if self.loop_steps > 1 or (self.pattern and "M" in self.pattern):
+            if self.loop_steps > 1 or (self.pattern
+                                       and set("MK") & set(self.pattern)):
                 raise NotImplementedError(
-                    "block_len > 1 with loop_steps > 1 or Mamba layers: a "
-                    "block pass over a looped stack or a recurrent state is "
-                    "not built")
+                    "block_len > 1 with loop_steps > 1 or Mamba / KDA "
+                    "layers: a block pass over a looped stack or a "
+                    "recurrent state is not built")
         if self.pattern is not None:
             bad = sorted(set(self.pattern) - set(_KINDS))
             if bad or len(self.pattern) != self.num_layers:
@@ -229,10 +239,21 @@ class TransformerConfig:
                     % self.mamba_groups == 0):
                 raise ValueError("an 'M' layer needs mamba_heads > 0, a "
                                  "multiple of mamba_groups")
+            if "K" in self.pattern:
+                if self.kda_heads < 1 or self.kda_conv < 2 \
+                        or self.kda_chunk % 16:
+                    raise ValueError(
+                        "a 'K' layer needs kda_heads > 0, kda_conv >= 2 "
+                        "taps and a kda_chunk that is a multiple of 16")
+                if self.cca_taps is not None:
+                    raise NotImplementedError(
+                        "a 'K' layer beside cca_taps: KDA state and CCA "
+                        "state in one cache is not built")
             if self.loop_steps > 1 or self.norm_sandwich:
                 raise NotImplementedError(
-                    "a layer pattern with loop_steps > 1 or norm_sandwich: "
-                    "the pattern walk runs one pass of pre-norm layers")
+                    "a layer pattern with loop_steps > 1 or norm_sandwich "
+                    "(whatever its kinds: '*', '-', 'E', 'M', 'K'): the "
+                    "pattern walk runs one pass of pre-norm layers")
         if not 0.0 < self.rope_fraction <= 1.0 or int(
                 self.head_dim * self.rope_fraction) % 2:
             raise ValueError(
@@ -241,13 +262,16 @@ class TransformerConfig:
         walked = self.pattern is not None
         for field, on in (("cca_taps", self.cca_taps is not None),
                           ("moe_router_hidden", self.moe_router_hidden),
-                          ("residual_scale", self.residual_scale)):
+                          ("residual_scale", self.residual_scale),
+                          ("kda_heads", self.kda_heads),
+                          ("attn_gate", self.attn_gate)):
             if on and not walked:
                 raise NotImplementedError(
                     f"{field} without a layer pattern: the homogeneous "
                     "stack's scan carries neither a state a layer, nor the "
                     "router's state from layer to layer, nor a layer's "
-                    "residual scales; only the pattern walk does")
+                    "residual scales, gate matrix or KDA mixer; only the "
+                    "pattern walk does")
         if self.cca_taps is not None:
             if len(self.cca_taps) != 2 or min(self.cca_taps) < 2:
                 raise ValueError(
@@ -293,8 +317,9 @@ class TransformerConfig:
         """The kinds of layer that keep a fixed state per sequence: kind
         -> (how many layers of it the pattern has, {part: one layer's
         shape for one sequence}).  A Mamba-2 layer keeps a state and no
-        pages; a CCA attention layer keeps one BESIDE its pages."""
-        from paddle_tpu.ops import cca, mamba2
+        pages, nor does a KDA layer; a CCA attention layer keeps one
+        BESIDE its pages."""
+        from paddle_tpu.ops import cca, kda, mamba2
 
         kinds = {}
         if self.pattern is None:
@@ -303,6 +328,9 @@ class TransformerConfig:
             kinds["mamba"] = (self.pattern.count("M"), mamba2.state_shapes(
                 self.mamba_heads, self.mamba_head_dim, self.mamba_state,
                 self.mamba_groups, self.mamba_conv))
+        if "K" in self.pattern:
+            kinds["kda"] = (self.pattern.count("K"), kda.state_shapes(
+                self.kda_heads, self.head_dim, self.kda_conv))
         if self.cca_taps is not None and "*" in self.pattern:
             kinds["attn"] = (self.pattern.count("*"), cca.state_shapes(
                 self.cca_taps, self.num_heads, self.kv_heads, self.head_dim))
@@ -390,6 +418,8 @@ def _ffn_params(cfg: TransformerConfig, norm, zeros, lead: tuple,
         if cfg.moe_shared_dim:
             sh = cfg.moe_shared_dim
             p["shared_in"] = norm(*lead, e, sh) * (e ** -0.5)
+            if cfg.routed.gated:
+                p["shared_gate"] = norm(*lead, e, sh) * (e ** -0.5)
             p["shared_out"] = norm(*lead, sh, e) * (sh ** -0.5) \
                 / (2 * depth) ** 0.5
         return p
@@ -457,13 +487,36 @@ def _pattern_params(cfg: TransformerConfig, norm, zeros, norm_p) -> list:
 
     def layer(kind):
         if kind == "attn":
+            # the gate is drawn last: the leaves before it are the draws
+            # they were without one
             return {"wq": norm(e, h) * (e ** -0.5),
                     "wk": norm(e, hk) * (e ** -0.5),
                     "wv": norm(e, hk) * (e ** -0.5),
                     "wo": norm(h, e) * (h ** -0.5) / (2 * s) ** 0.5,
-                    **_qk_norm_params(cfg, ()), **cca()}
+                    **_qk_norm_params(cfg, ()), **cca(),
+                    **({"w_ogate": norm(e, h) * (e ** -0.5)}
+                       if cfg.attn_gate else {})}
         if kind in ("mlp", "moe"):
             return _ffn_params(cfg, norm, zeros, (), s, kind == "moe")
+        if kind == "kda":
+            # decays alpha = exp(-exp(a_log) softplus(. + dt_bias)) around
+            # 0.99 and drawn, so a swapped head or channel shows
+            hd, dk = cfg.head_dim, cfg.kda_heads * cfg.head_dim
+            return {
+                "wq": norm(e, dk) * (e ** -0.5),
+                "wk": norm(e, dk) * (e ** -0.5),
+                "wv": norm(e, dk) * (e ** -0.5),
+                "conv_w": norm(cfg.kda_conv, 3 * dk) * (cfg.kda_conv ** -0.5),
+                "decay_a": norm(e, hd) * (e ** -0.5),
+                "decay_b": norm(hd, dk) * (hd ** -0.5),
+                "a_log": 0.5 * norm(cfg.kda_heads),
+                "dt_bias": -4.6 + 0.5 * norm(dk),
+                "w_beta": norm(e, cfg.kda_heads) * (e ** -0.5),
+                "gate_a": norm(e, hd) * (e ** -0.5),
+                "gate_b": norm(hd, dk) * (hd ** -0.5),
+                "gate_bias": zeros(dk),
+                "norm_g": jnp.ones((hd,), cfg.dtype),
+                "wo": norm(dk, e) * (dk ** -0.5) / (2 * s) ** 0.5}
         # dt_bias around softplus^-1(0.01) and A = -exp(a_log) <= -1 (the
         # usual ranges); drawn, not constant, so a swapped head shows
         return {
@@ -499,7 +552,8 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
     hk = cfg.kv_heads * cfg.head_dim
     s = cfg.num_layers
     k = iter(jax.random.split(
-        key, 14 if cfg.pattern is None else 8 + 8 * cfg.num_layers))
+        key, 14 if cfg.pattern is None else 8 + (
+            12 if "K" in cfg.pattern else 8) * cfg.num_layers))
     norm = lambda *shape: jax.random.normal(next(k), shape, cfg.dtype)
     zeros = lambda *shape: jnp.zeros(shape, cfg.dtype)
 
@@ -914,6 +968,43 @@ def _mamba_mixer(cfg: TransformerConfig, h, layer, conv, ssd):
     return y @ layer["out_proj"]
 
 
+def _kda_mixer(cfg: TransformerConfig, h, layer, conv, rule):
+    """The Kimi Delta Attention mixer over normed states h [..., E].
+    ``conv(x, w, bias)`` and ``rule(q, k, v, g, beta)`` are the caller's
+    arrangement of the causal convolution over q | k | v and of the gated
+    delta rule (whole padded prompts in prefill, one token against the
+    state pools in decode: ``ops/mamba2.py``, ``ops/kda.py``), both
+    returning float32."""
+    f32 = jnp.float32
+    lead = h.shape[:-1]
+    nh, hd = cfg.kda_heads, cfg.head_dim
+    qkv = jnp.concatenate([h @ layer["wq"], h @ layer["wk"],
+                           h @ layer["wv"]], axis=-1)
+    q, k, v = jnp.split(jax.nn.silu(conv(qkv, layer["conv_w"], None)), 3,
+                        axis=-1)
+    # q and k to unit length a head (q then scaled as attention scales)
+    unit = lambda x: x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
+                                   + 1e-6)
+    q = (unit(q.reshape(*lead, nh, hd)) * hd ** -0.5).astype(h.dtype)
+    k = unit(k.reshape(*lead, nh, hd)).astype(h.dtype)
+    v = v.reshape(*lead, nh, hd).astype(h.dtype)
+    # the decay, a log <= 0 per head and key channel; the step in (0, 2):
+    # past 1 the transition I - beta k k^T has a negative eigenvalue
+    dt = ((h @ layer["decay_a"]) @ layer["decay_b"]).astype(f32) \
+        + layer["dt_bias"].astype(f32)
+    g = -jnp.exp(layer["a_log"].astype(f32))[:, None] \
+        * jax.nn.softplus(dt).reshape(*lead, nh, hd)
+    beta = 2.0 * jax.nn.sigmoid((h @ layer["w_beta"]).astype(f32))
+    o = rule(q, k, v, g, beta)
+    # RMSNorm over each head's values, one gain; then the output gate
+    o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps)
+    gate = jax.nn.sigmoid(
+        ((h @ layer["gate_a"]) @ layer["gate_b"]).astype(f32)
+        + layer["gate_bias"].astype(f32))
+    y = (o * layer["norm_g"].astype(f32)).reshape(*lead, nh * hd) * gate
+    return y.astype(h.dtype) @ layer["wo"]
+
+
 def _pattern_layer(cfg: TransformerConfig, kind, layer, x, rope, attend,
                    mamba, live=None, mesh=None, carry=None, window=None):
     """One layer of a ``pattern``: ``x + mixer(norm(x))`` (under
@@ -921,7 +1012,8 @@ def _pattern_layer(cfg: TransformerConfig, kind, layer, x, rope, attend,
     vectors), the mixer by the layer's ``kind`` (a value of ``_KINDS``).
     ``attend(q, k, v) -> a`` is the caller's cache write and attention of
     this attention layer, ``mamba = (conv, ssd)`` this state layer's
-    arrangement (``_mamba_mixer``), ``window`` a CCA layer's
+    arrangement (``_mamba_mixer``; of a KDA layer ``(conv, rule)``,
+    ``_kda_mixer``), ``window`` a CCA layer's
     (``_cca_qkv``); all keep what they must hand back in the caller's own
     variables.  ``live`` (bool, x's leading shape) marks the rows that are
     tokens, for the routing counts.  ``carry`` [..., moe_router_hidden]
@@ -935,10 +1027,14 @@ def _pattern_layer(cfg: TransformerConfig, kind, layer, x, rope, attend,
     if kind == "attn":
         qkv = (_qkv(cfg, h, layer, rope) if cfg.cca_taps is None
                else _cca_qkv(cfg, h, layer, rope, window))
-        a = attend(*qkv)
-        y = a.reshape(*lead, cfg.num_heads * cfg.head_dim) @ layer["wo"]
+        a = attend(*qkv).reshape(*lead, cfg.num_heads * cfg.head_dim)
+        if cfg.attn_gate:
+            a = a * jax.nn.sigmoid(h @ layer["w_ogate"])
+        y = a @ layer["wo"]
     elif kind == "mamba":
         y = _mamba_mixer(cfg, h, layer, *mamba)
+    elif kind == "kda":
+        y = _kda_mixer(cfg, h, layer, *mamba)
     else:
         if kind == "moe" and cfg.moe_router_hidden:
             from paddle_tpu.parallel.moe import router_state
@@ -1129,7 +1225,7 @@ def _prefill_layer(cfg: TransformerConfig, kind, mesh, layer, x, rope,
     pattern's prefill program ready is the host tracing and lowering it,
     seconds an engine (PERF.md section 6, PR 31); XLA inlines the
     calls."""
-    from paddle_tpu.ops import cca, mamba2
+    from paddle_tpu.ops import cca, kda, mamba2
 
     kept = {}
 
@@ -1138,8 +1234,14 @@ def _prefill_layer(cfg: TransformerConfig, kind, mesh, layer, x, rope,
         return _attention(cfg, q, k, v, mesh)
 
     def conv(xbc, w, bias):
-        out, kept["conv"] = mamba2.conv_prefill(xbc, w, bias, seq_lens)
+        part = "kda_conv" if kind == "kda" else "conv"
+        out, kept[part] = mamba2.conv_prefill(xbc, w, bias, seq_lens)
         return out
+
+    def rule(*args):
+        o, kept["kda_s"] = kda.kda_prefill(*args, seq_lens=seq_lens,
+                                           chunk=cfg.kda_chunk)
+        return o
 
     def ssd(*args):
         y, kept["ssm"] = mamba2.ssd_prefill(*args, seq_lens=seq_lens,
@@ -1153,7 +1255,8 @@ def _prefill_layer(cfg: TransformerConfig, kind, mesh, layer, x, rope,
     live = None if seq_lens is None else (
         jnp.arange(x.shape[1])[None, :] < seq_lens[:, None])
     x, counts, carry = _pattern_layer(
-        cfg, kind, layer, x, rope, attend, (conv, ssd), live, mesh,
+        cfg, kind, layer, x, rope, attend,
+        (conv, rule if kind == "kda" else ssd), live, mesh,
         carry=carry, window=window)
     return x, kept, counts, carry
 
@@ -1366,7 +1469,7 @@ def _decode_pattern(cfg: TransformerConfig, params, x, rope, positions,
     K, V and the state parts — is rebound as it is updated, at a static
     layer index: the buffers that entered the program are written where
     they are."""
-    from paddle_tpu.ops import cca, mamba2
+    from paddle_tpu.ops import cca, kda, mamba2
     from paddle_tpu.ops.pallas import paged_attention as pa
 
     pools = [k_cache, v_cache]
@@ -1390,18 +1493,23 @@ def _decode_pattern(cfg: TransformerConfig, params, x, rope, positions,
         state[name] = state[name].at[i].set(
             jnp.where(mask, new.astype(old.dtype), old))
 
-    def mamba(i):
-        def conv(xbc, w, bias):
-            out, new = mamba2.conv_step(state["conv"][i], xbc, w, bias)
-            keep("conv", i, new)
+    def recurrent(i, conv_part, state_part, step):
+        """A state layer's arrangement against row i of its two pools: the
+        convolution's tail and the recurrence's state."""
+        def conv(x, w, bias):
+            out, new = mamba2.conv_step(state[conv_part][i], x, w, bias)
+            keep(conv_part, i, new)
             return out
 
-        def ssd(*args):
-            y, new = mamba2.ssd_step(state["ssm"][i], *args)
-            keep("ssm", i, new)
+        def rule(*args):
+            y, new = step(state[state_part][i], *args)
+            keep(state_part, i, new)
             return y
 
-        return conv, ssd
+        return conv, rule
+
+    arrangement = {"mamba": ("conv", "ssm", mamba2.ssd_step),
+                   "kda": ("kda_conv", "kda_s", kda.kda_step)}
 
     def window(i, part, x, n):
         win, new = cca.window_step(state[part][i], x)
@@ -1411,7 +1519,8 @@ def _decode_pattern(cfg: TransformerConfig, params, x, rope, positions,
     x, counts = _run_pattern(
         cfg, params, x, lambda kind, i, layer, x, carry: _pattern_layer(
             cfg, kind, layer, x, rope, functools.partial(attend, i),
-            mamba(i) if kind == "mamba" else None, live, carry=carry,
+            recurrent(i, *arrangement[kind]) if kind in arrangement
+            else None, live, carry=carry,
             window=functools.partial(window, i)))
     return (_head(cfg, params, x), *pools,
             {"state": state, "moe_counts": counts})

@@ -345,7 +345,8 @@ def moe_ffn_sharded(params: dict, x: jax.Array, cfg: MoEConfig, mesh,
 # 1856 take 1.8 / 1.9 / 3.5 / 7.1 ms at 64 / 256 / 512 / 1024 rows, against
 # 18 ms for the pair of ``lax.ragged_dot`` calls whatever the rows (PERF.md
 # section 6, PR 30).  Above it the assignments are sorted by expert and go
-# through the grouped product, which multiplies only the assignments made.
+# through the grouped product, which multiplies only the assignments made
+# to experts held here.
 DENSE_MAX_TOKENS = 2048
 # a padded pass is mostly padding: its live rows are gathered to the front
 # and the masked product runs over the smallest of these row counts that
@@ -460,7 +461,8 @@ def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None,
     ``params``: ``router`` [D, X] and, optionally, ``router_bias`` [X]
     over all X experts; ``w_in`` [held, D, F] and ``w_out`` [held, F, D]
     of the held ones (gated: ``w_gate`` [held, D, F] too); optionally
-    ``shared_in`` [D, S] / ``shared_out`` [S, D].  ``y``
+    ``shared_in`` [D, S] / ``shared_out`` [S, D] (gated: ``shared_gate``
+    [D, S] too).  ``y``
     is the held experts' part of the layer's result plus the shared
     expert.  ``live`` (bool, x's leading shape) masks rows that are no
     token (idle decode rows, padding): they are routed nowhere.
@@ -522,26 +524,53 @@ def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None,
                 jnp.searchsorted(jnp.asarray(buckets), jnp.sum(live)),
                 [over(b) for b in buckets] + [lambda: masked(x2, comb)])
     else:
-        # assignments sorted by held expert (the absent ones last), each
-        # expert's rows through its own matrices
+        # assignments sorted by held expert (the absent ones last); only
+        # the held ones are gathered and multiplied, each expert's rows
+        # through its own matrices, ``bound`` sorted assignments at a time
+        # -- a static count a quarter above the held share of an even
+        # router -- as often as the count of held ones asks (a held eighth
+        # of the experts: one round, an eighth of the rows and of ``ys``)
         flat = local.reshape(-1)
         order = jnp.argsort(flat)
         loads = jnp.bincount(flat, length=cfg.num_held + 1)[:-1]
-        rows = x2[order // k]
-        h = lax.ragged_dot(rows, w_in, loads, preferred_element_type=f32)
-        if cfg.gated:
-            h = h * _act(cfg.act, lax.ragged_dot(
-                rows, w_gate, loads, preferred_element_type=f32))
+        ends = jnp.cumsum(loads)
+        bound = min(t * k, -(-5 * t * k * cfg.num_held
+                             // (4 * cfg.num_experts)) // 512 * 512 + 512)
+        order_p = jnp.pad(order, (0, bound))
+        ws_all = w.reshape(-1)
+
+        def gathered(c, y):
+            at = c * bound
+            sel = lax.dynamic_slice(order_p, (at,), (bound,))
+            # this round's rows of every expert's group
+            sizes = jnp.clip(ends - at, 0, bound) \
+                - jnp.clip(ends - loads - at, 0, bound)
+            rows = x2[sel // k]
+            h = lax.ragged_dot(rows, w_in, sizes, preferred_element_type=f32)
+            if cfg.gated:
+                h = h * _act(cfg.act, lax.ragged_dot(
+                    rows, w_gate, sizes, preferred_element_type=f32))
+            else:
+                h = _act(cfg.act, h)
+            h = h.astype(x.dtype)
+            ys = lax.ragged_dot(h, w_out, sizes, preferred_element_type=f32)
+            ws = jnp.where(at + jnp.arange(bound) < ends[-1], ws_all[sel],
+                           0.0)
+            ys = jnp.where(ws[:, None] > 0, ys * ws[:, None], 0.0)
+            return y.at[sel // k].add(ys)
+
+        y = jnp.zeros((t, shape[-1]), f32)
+        if bound == t * k:      # every assignment fits one round
+            y = gathered(0, y)
         else:
-            h = _act(cfg.act, h)
-        h = h.astype(x.dtype)
-        ys = lax.ragged_dot(h, w_out, loads, preferred_element_type=f32)
-        ws = w.reshape(-1)[order]
-        ys = jnp.where(ws[:, None] > 0, ys * ws[:, None], 0.0)
-        y = jnp.zeros((t, shape[-1]), f32).at[order // k].add(ys)
+            y = lax.fori_loop(0, -(-ends[-1] // bound), gathered, y)
     if "shared_in" in params:
-        hs = _act(cfg.act, jnp.dot(x2, params["shared_in"],
-                                   preferred_element_type=f32))
+        hs = jnp.dot(x2, params["shared_in"], preferred_element_type=f32)
+        if "shared_gate" in params:
+            hs = hs * _act(cfg.act, jnp.dot(x2, params["shared_gate"],
+                                            preferred_element_type=f32))
+        else:
+            hs = _act(cfg.act, hs)
         y = y + jnp.dot(hs.astype(x.dtype), params["shared_out"],
                         preferred_element_type=f32)
     counts = jnp.stack([jnp.sum(held), absent, jnp.sum(loads > 0),

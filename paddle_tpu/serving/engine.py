@@ -78,7 +78,11 @@ the engine's first request; set once, at construction); under routed
 experts counters ``serve_moe_assignments_total{where=held|absent}`` /
 ``serve_moe_experts_touched_total`` and gauge
 ``serve_moe_load_max_over_mean`` (decode steps: the busiest held
-expert's tokens over the mean); under a model that generates by blocks
+expert's tokens over the mean); under delta-rule (KDA) layers counters
+``serve_kda_state_bytes_total`` (state and convolution tail the passes
+read and wrote for their live rows) / ``serve_kda_chunk_tokens_total``
+(tokens of the chunks that held a prompt token); under a model that
+generates by blocks
 (``block_len`` > 1) gauge ``serve_block_length`` and counters
 ``serve_block_passes_total{kind=denoise|commit}`` (a row of a block
 pass, by whether it went in with masked positions),
@@ -343,6 +347,15 @@ class ServingEngine:
         if cfg.pattern is not None:
             self._loop_args.update(kv_heads=cfg.kv_heads,
                                    state_layers=cfg.state_layers)
+        # a pattern's delta-rule layers: their state's bytes a slot (the
+        # pass's spans and counters say what of it a pass moves)
+        self._kda_slot_bytes = 0
+        if cfg.pattern is not None and "K" in cfg.pattern:
+            self._loop_args["kda_layers"] = cfg.pattern.count("K")
+            self._kda_slot_bytes = sum(
+                int(a.nbytes) // a.shape[1]
+                for part, a in self.cache.state.items()
+                if part.startswith("kda_"))
         self._block = cfg.block_len
         # the one configuration whose every pass is read before the next
         # is dispatched (module docstring)
@@ -856,6 +869,18 @@ class ServingEngine:
                 "prompt_tokens": int(batch["seq_lens"].sum())}
         if self._block > 1:
             fill["blocks_written"] = fill["prompt_tokens"] // self._block
+        if self._kda_slot_bytes:
+            # the chunks that hold a token, the causal query-key pairs of
+            # an attention layer, and the state the rows leave
+            chunk, lens = self.cfg.kda_chunk, batch["seq_lens"].astype(int)
+            fill["kda_chunks"] = int((-(-lens // chunk)).sum())
+            fill["attn_pairs"] = int((lens * (lens + 1) // 2).sum())
+            fill["state_bytes"] = self._kda_state_moved(len(admitted), 1)
+            reg.counter(
+                "serve_kda_chunk_tokens_total",
+                "tokens of the chunks of the chunked delta rule that held "
+                "a prompt token, over the from-zero prefill passes").inc(
+                    fill["kda_chunks"] * chunk)
         # the ratio of the two counters is the passes' fill share
         reg.counter(
             "serve_prefill_padded_tokens_total",
@@ -871,6 +896,16 @@ class ServingEngine:
             if tracer.enabled else {}, t0), programs[rows], *args)
         if self._block == 1:    # by blocks a prefill pass samples nothing
             self.scheduler.sent(admitted)
+
+    def _kda_state_moved(self, rows: int, times: int) -> int:
+        """Bytes of delta-rule state a pass moves for ``rows`` live rows
+        (``times``: 2 = read and written, 1 = written), booked."""
+        moved = times * rows * self._kda_slot_bytes
+        self.registry.counter(
+            "serve_kda_state_bytes_total",
+            "bytes of delta-rule (KDA) state and convolution tail the "
+            "passes read and wrote for their live rows").inc(moved)
+        return moved
 
     def _send_decode(self, tracer, programs, batch) -> None:
         """Dispatch one decode step over ``batch`` (``decode_batch``): a
@@ -891,6 +926,10 @@ class ServingEngine:
             if bl > 1:      # masked going in: the host's count
                 said.update(positions=len(live) * bl,
                             masked_in=sum(a.block.masked for a in live))
+        if self._kda_slot_bytes:    # every live row's, read and written
+            moved = self._kda_state_moved(len(live), 2)
+            if tracer.enabled:
+                said["state_bytes"] = moved
         self._send(tracer, _Pass("decode", live, self._decode_out, said, t0),
                    programs["decode"], *args)
         self.scheduler.sent(live)

@@ -587,8 +587,9 @@ def test_the_engine_refuses_what_is_not_built(serving, match, params):
     (dict(block_len=0), "block_len must be >= 1"),
     (dict(attn_impl="blockwise"), "only 'exact' and 'flash'"),
     (dict(pattern=None, num_layers=2, moe_experts=0, loop_steps=2),
-     "loop_steps > 1 or Mamba layers"),
-    (dict(moe_shared_dim=8), "shared expert beside gated"),
+     "loop_steps > 1 or Mamba / KDA layers"),
+    (dict(pattern="*EKE", num_layers=4, kda_heads=2),
+     "loop_steps > 1 or Mamba / KDA layers"),
     (dict(moe_router="softmax"), "dropless moe_router")])
 def test_the_config_refuses_what_is_not_built(fields, match):
     with pytest.raises((ValueError, NotImplementedError), match=match):

@@ -524,8 +524,9 @@ def test_state_beside_incremental_prefill_raises_by_name(serving, named,
     (dict(mamba_heads=0), ValueError),
     (dict(kv_heads=3), ValueError),                       # 4 % 3
     (dict(moe_held=(8, 20)), ValueError),                 # outside [0, 16)
-    # gated experts run since PR 32, but not beside a shared expert
-    (dict(mlp="swiglu"), NotImplementedError),
+    # gated experts run since PR 32, beside a (gated) shared expert since
+    # PR 42 (tests/test_kda_lm.py); a pattern under a sandwich norm does not
+    (dict(norm_sandwich=True), NotImplementedError),
     (dict(loop_steps=2), NotImplementedError),
     (dict(positions="sinusoid"), ValueError),
 ])
