@@ -121,6 +121,8 @@ class Sizes:
         ragged_paged_attention_sdar=(64, 4, 128, 3073, 16, 48),
         ragged_paged_attention_zaya=(64, 2, 128, 8193, 16, 128),
         ragged_paged_attention_vmem=(4, 32, 128, 129, 16, 32),
+        # a one-row prefill pass of solar-open2-250b's KDA layer
+        kda_prefill=(1, 4096, 64, 64),          # B, T, H, chunk  (D = 128)
         softmax_xent=(4096, 50257),             # N, V
         conv2d_bn_act=(128, 56, 64, 64, 3, 1, 1),  # N, HW, Cin, Cout, k, s, p
         conv2d_direct=(128, 224, 3, 64, 7, 2, 3),  # the ResNet stem
@@ -538,7 +540,9 @@ def _kernel_cases() -> list[KernelCase]:
     import jax
     import jax.numpy as jnp
 
+    from paddle_tpu.ops import kda
     from paddle_tpu.ops.pallas import ctc, gru, lstm
+    from paddle_tpu.ops.pallas import kda as kda_kernel
     from paddle_tpu.ops.pallas import paged_attention as pa
     from paddle_tpu.ops.pallas.flash_attention import (
         flash_attention, flash_attention_reference)
@@ -646,6 +650,21 @@ def _kernel_cases() -> list[KernelCase]:
         return (normal(key, 0, (b, h, d), dtype),
                 normal(key, 1, pool, dtype), normal(key, 2, pool, dtype),
                 jnp.int32(1), table % pages, lens)
+
+    def make_kda(shape, key):
+        b, t, h, _ = shape
+        d = kda_kernel.HEAD_DIM
+        unit = lambda x: x * jax.lax.rsqrt(
+            jnp.sum(x * x, axis=-1, keepdims=True))
+        # as the mixer hands them over: q and k of unit length a head,
+        # decays from a softplus, steps in (0, 2); a ragged row
+        return (unit(normal(key, 0, (b, t, h, d))).astype(bf16) * d ** -0.5,
+                unit(normal(key, 1, (b, t, h, d))).astype(bf16),
+                normal(key, 2, (b, t, h, d), bf16),
+                -jnp.exp(normal(key, 3, (b, t, h, d), f32, 1.5) - 3.0),
+                2 * jax.nn.sigmoid(normal(key, 4, (b, t, h))),
+                jax.random.randint(jax.random.fold_in(key, 5), (b,),
+                                   max(t // 2, 1), t + 1))
 
     def make_xent(shape, key):
         n, v = shape
@@ -774,6 +793,11 @@ def _kernel_cases() -> list[KernelCase]:
         case("ragged_paged_attention[vmem]", make_paged, paged_kernel,
              lambda s: pa.ragged_paged_attention_reference, tol=MXU_TOL,
              shape_key="ragged_paged_attention_vmem"),
+        case("kda_prefill", make_kda,
+             lambda interp, s: lambda *a: kda.kda_prefill(
+                 *a, chunk=s[3], impl="kernel", interpret=interp),
+             lambda s: lambda *a: kda.kda_prefill(
+                 *a, chunk=s[3], impl="reference"), tol=BF16_TOL),
         case("softmax_xent", make_xent,
              lambda interp, s: lambda lg, tg: sx.softmax_xent(
                  lg, tg, 256, 2048, interp),

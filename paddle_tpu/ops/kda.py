@@ -28,15 +28,20 @@ through a unit-lower-triangular system (the WY / UT form).
 - :func:`kda_step` is the one-token recurrence of a decode step.
 
 The depthwise causal convolutions in front (q | k | v) are
-``mamba2.conv_prefill`` / ``conv_step``.  Plain ``jax.numpy`` (XLA): decay
-arithmetic and the triangular solve in float32, the products against the
-state in the inputs' type with float32 accumulation.  ``state_shapes`` is
-the one place the per-slot state of a layer is spelt; the serving cache
-sizes its state pools from it.
+``mamba2.conv_prefill`` / ``conv_step``.  Decay arithmetic and the
+triangular solve in float32, the products against the state in the inputs'
+type with float32 accumulation.  On a TPU :func:`kda_prefill` is one Pallas
+kernel a layer (``ops/pallas/kda.py``: heads of 128, nothing but inputs and
+outputs in HBM); the plain ``jax.numpy`` below is the CPU path and its
+oracle.  ``state_shapes`` is the one place the per-slot state of a layer is
+spelt; the serving cache sizes its state pools from it.
 """
 
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -122,24 +127,67 @@ def _unit_lower_inverse(low):
 
 
 def kda_prefill(q, k, v, g, beta, seq_lens=None, chunk: int = 64,
-                state=None):
+                state=None, impl: str = "auto", interpret=None):
     """A whole (right-padded) sequence.  q, k [B, T, H, K] (normalised, q
     scaled); v [B, T, H, V]; g [B, T, H, K] float32 log-decay (<= 0);
     beta [B, T, H] float32; seq_lens [B] valid lengths (None = all T);
     ``state`` [B, H, K, V] the state to start from (None = zeros).
-    Returns (o [B, T, H, V] float32, the state at each row's last valid
-    token [B, H, K, V] float32)."""
+    ``impl``: "kernel" (``ops/pallas/kda.py``), "reference" (the plain
+    XLA below: the CPU path and the kernel's oracle) or "auto" (kernel on
+    a TPU); a shape the kernel does not take runs the reference and the
+    routing census says so.  Returns (o [B, T, H, V] float32, the state
+    at each row's last valid token [B, H, K, V] float32)."""
+    from paddle_tpu.ops import pallas
+    from paddle_tpu.ops.pallas import kda as kernel
+
     f32 = jnp.float32
-    bsz, t, h, dk = q.shape
-    dv = v.shape[-1]
+    t, dk, dv = q.shape[1], q.shape[-1], v.shape[-1]
     if chunk % _SUB:
         raise ValueError(f"chunk {chunk} must be a multiple of {_SUB}")
-    dtype = v.dtype
     g, beta = g.astype(f32), beta.astype(f32)
     if seq_lens is not None:
         valid = jnp.arange(t)[None, :, None] < seq_lens[:, None, None]
         g = jnp.where(valid[..., None], g, 0.0)
         beta = jnp.where(valid, beta, 0.0)
+    route = pallas.resolve_impl(impl)
+    if route == "kernel" and not kernel.supports(dk, dv, chunk):
+        route = "reference_shape"
+    pallas.note_route("kda_prefill", route)
+    if route == "kernel":
+        return _fused(q, k, v, g, beta, state, chunk,
+                      pallas.resolve_interpret(interpret))
+    return _chunked(q, k, v, g, beta, state, chunk)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _fused(q, k, v, g, beta, state, chunk, interpret):
+    """The kernel, differentiated as :func:`_chunked` is (it has no
+    backward pass of its own: training a ``K`` layer is XLA's autodiff of
+    the plain form, as it was)."""
+    from paddle_tpu.ops.pallas import kda as kernel
+
+    return kernel.kda_chunk_prefill(q, k, v, g, beta, chunk, state,
+                                    interpret=interpret)
+
+
+def _fused_fwd(q, k, v, g, beta, state, chunk, interpret):
+    return (_fused(q, k, v, g, beta, state, chunk, interpret),
+            (q, k, v, g, beta, state))
+
+
+def _fused_bwd(chunk, interpret, saved, ct):
+    return jax.vjp(lambda *a: _chunked(*a, chunk), *saved)[1](ct)
+
+
+_fused.defvjp(_fused_fwd, _fused_bwd)
+
+
+def _chunked(q, k, v, g, beta, state, chunk):
+    """:func:`kda_prefill` past its masking, in plain XLA: g, beta float32
+    and already 0 at and past each row's length."""
+    f32 = jnp.float32
+    bsz, t, h, dk = q.shape
+    dv, dtype = v.shape[-1], v.dtype
     pad = -t % chunk
     if pad:
         q, k, v, g = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
