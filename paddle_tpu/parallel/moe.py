@@ -348,6 +348,14 @@ def moe_ffn_sharded(params: dict, x: jax.Array, cfg: MoEConfig, mesh,
 # through the grouped product, which multiplies only the assignments made
 # to experts held here.
 DENSE_MAX_TOKENS = 2048
+# The masked product does ``num_experts / top_k`` times the assigned work,
+# and the limit above was measured at 21 x (128 experts, top-6; 16 x at
+# 128 / 8 and 16 / 1 read alike).  Past 32 x the limit falls in proportion
+# -- ``_DENSE_WASTE * top_k // num_experts`` rows, 1,638 at 320 / 8's 40 x
+# -- and is never raised: 40 held experts of 4096 x 1280 over 2,048 rows
+# take 14.7 ms masked (7.7 where a bucket of 1,024 holds the live rows)
+# against 6.0-6.3 through the grouped product (PERF.md section 6, PR 44).
+_DENSE_WASTE = 65_536
 # a padded pass is mostly padding: its live rows are gathered to the front
 # and the masked product runs over the smallest of these row counts that
 # holds them (chosen at run time from ``live``; the last is all rows)
@@ -490,7 +498,7 @@ def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None,
     local = jnp.where(held, idx - lo, cfg.num_held)   # num_held = nowhere
     w_in, w_out = params["w_in"], params["w_out"]
     w_gate = params["w_gate"] if cfg.gated else None
-    if t <= DENSE_MAX_TOKENS:
+    if t <= min(DENSE_MAX_TOKENS, _DENSE_WASTE * k // cfg.num_experts):
         # [T, held] combine weights; every held expert over every row
         comb = jnp.sum(w[..., None] * (local[..., None] == jnp.arange(
             cfg.num_held)), axis=1)

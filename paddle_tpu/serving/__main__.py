@@ -49,12 +49,14 @@ def build_argparser() -> argparse.ArgumentParser:
     p.add_argument("--page_size", type=int, default=16)
     p.add_argument("--num_pages", type=int, default=64)
     p.add_argument("--max_prompt_len", type=int, default=32,
-                   help="longest prompt accepted, and the length of every "
-                        "prefill pass; the engine compiles its prefill "
-                        "ladder (one row and prefill_batch rows at this "
-                        "length: two programs) and the decode program "
-                        "when its first request arrives, before serving "
-                        "it, not when traffic first needs each")
+                   help="longest prompt accepted, and the length of the "
+                        "longest prefill pass; the engine compiles its "
+                        "prefill ladder (two programs: one row and "
+                        "prefill_batch rows at this length, or, from "
+                        "2048 on, one row at half of it and one row at "
+                        "this length) and the decode program when its "
+                        "first request arrives, before serving it, not "
+                        "when traffic first needs each")
     p.add_argument("--prefix_cache", action="store_true",
                    help="share full KV pages across requests with a "
                         "common prompt prefix (copy-on-write, LRU "

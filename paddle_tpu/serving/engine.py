@@ -71,10 +71,12 @@ decode}`` (passes dispatched while the one before was unread) /
 ``serve_state_bytes_per_slot`` (set once, at construction); from the
 from-zero prefill passes counters ``serve_prefill_padded_tokens_total``
 (rows x length of each pass's shape) / ``serve_prefill_prompt_tokens_total``
-(their ratio is the passes' fill share) and gauge
+(their ratio is the passes' fill share) / ``serve_prefill_passes_total{length}``
+(how often each length of the ladder ran) and gauge
 ``serve_prefill_programs`` (the shapes a pass may take: the scheduler's
-``prefill_rows``, compiled with the decode program by the step that admits
-the engine's first request; set once, at construction); under routed
+``prefill_shapes`` — two, spent on rows where a pass is short and on
+length where it is long — compiled with the decode program by the step
+that admits the engine's first request; set once, at construction); under routed
 experts counters ``serve_moe_assignments_total{where=held|absent}`` /
 ``serve_moe_experts_touched_total`` and gauge
 ``serve_moe_load_max_over_mean`` (decode steps: the busiest held
@@ -340,7 +342,7 @@ class ServingEngine:
             "serve_prefill_programs",
             "shapes a from-zero prefill pass may take, all compiled "
             "before the engine's first pass").set(
-                len(self.scheduler.prefill_rows))
+                len(self.scheduler.prefill_shapes))
         # what every device pass's span says of the stack it ran
         self._loop_args = {"loop_steps": cfg.loop_steps,
                            "cache_layers": cfg.cache_layers}
@@ -384,7 +386,8 @@ class ServingEngine:
         self._kv_steps = paged_attention.decode_steps
         self._chunk_passes = 0  # incremental prefill passes this engine ran
         # the compiled executables the step loop dispatches, by a prefill
-        # pass's rows and "decode" (_make_ready, at the first admission)
+        # pass's (rows, length) and "decode" (_make_ready, at the first
+        # admission)
         self._programs: dict = {}
         self._base_key = self.place(jax.random.key(s.seed))
         self._lock = threading.Lock()
@@ -721,7 +724,8 @@ class ServingEngine:
         """Compile every program this engine will dispatch, before the
         first of them serves (the step that admits the first request
         calls this ahead of its prefill pass): each member of the
-        scheduler's prefill ladder and the decode program, lowered and
+        scheduler's prefill ladder (``prefill_shapes``: rows x length)
+        and the decode program, lowered and
         compiled for a batch of slack rows only — the token array
         (``PagedKVCache.tokens``) among their arguments — and held: the
         step loop calls these executables, not the jitted functions.
@@ -741,22 +745,22 @@ class ServingEngine:
         (``tracing.XlaBuildListener``); the log lines read the same
         clock readings."""
         cache, sched = self.cache, self.scheduler
-        rows = () if self.serving.incremental_prefill else sched.prefill_rows
-        length = self.serving.max_prompt_len
+        shapes = (() if self.serving.incremental_prefill
+                  else sched.prefill_shapes)
         tracer = tracing.get_tracer()
         programs = {}
-        with tracer.timed("engine_ready", programs=len(rows) + 1) as ready:
-            for n in rows:
+        with tracer.timed("engine_ready", programs=len(shapes) + 1) as ready:
+            for rows, length in shapes:
                 with tracer.timed("program_ready", program="prefill",
-                                  rows=n, length=length) as one:
-                    args = self._dev(sched.prefill_arrays([], n), "ids",
-                                     "seq_lens", "page_table", "rids",
+                                  rows=rows, length=length) as one:
+                    args = self._dev(sched.prefill_arrays([], rows, length),
+                                     "ids", "seq_lens", "page_table", "rids",
                                      "temps", "slots")
-                    programs[n] = self._prefill.lower(
+                    programs[rows, length] = self._prefill.lower(
                         self._params(), self._base_key, cache.k, cache.v,
                         *self._carried("prefill", args)).compile()
-                log.debug("prefill program of %d row(s) ready after %.2f s",
-                          n, one.seconds)
+                log.debug("prefill program of %d row(s) x %d ready after "
+                          "%.2f s", rows, length, one.seconds)
             with tracer.timed("program_ready", program="decode",
                               rows=self.serving.max_slots,
                               length=self._block):
@@ -768,9 +772,10 @@ class ServingEngine:
         with self._lock:    # all or none: a failure is met again
             self._programs = programs
         what = "block in progress" if self._block > 1 else "last token"
-        log.info("serving engine ready: %d prefill program(s) of %s row(s) x "
-                 "%d + decode in %.2f s; %s, %s", len(rows), list(rows),
-                 length, ready.seconds,
+        log.info("serving engine ready: %d prefill program(s) of %s (rows x "
+                 "length) + decode in %.2f s; %s, %s", len(shapes),
+                 [f"{rows} x {length}" for rows, length in shapes],
+                 ready.seconds,
                  f"the {what} of {self.serving.max_slots} slot(s) stays on "
                  "the device (the token array)",
                  "every pass is read before the next" if self._sync
@@ -865,7 +870,8 @@ class ServingEngine:
         args = self._dev(batch, "ids", "seq_lens", "page_table", "rids",
                          "temps", "slots")
         rows, length = batch["ids"].shape
-        fill = {"rows": rows, "padded_tokens": rows * length,
+        fill = {"rows": rows, "length": length,
+                "padded_tokens": rows * length,
                 "prompt_tokens": int(batch["seq_lens"].sum())}
         if self._block > 1:
             fill["blocks_written"] = fill["prompt_tokens"] // self._block
@@ -890,10 +896,14 @@ class ServingEngine:
             "serve_prefill_prompt_tokens_total",
             "prompt tokens the from-zero prefill passes carried").inc(
                 fill["prompt_tokens"])
+        reg.counter(
+            "serve_prefill_passes_total",
+            "from-zero prefill passes, by the length of the ladder's "
+            "member they ran at").inc(length=length)
         self._send(tracer, _Pass(
             "prefill", admitted, rows,
             dict(batch=len(admitted), **fill, **self._loop_args)
-            if tracer.enabled else {}, t0), programs[rows], *args)
+            if tracer.enabled else {}, t0), programs[rows, length], *args)
         if self._block == 1:    # by blocks a prefill pass samples nothing
             self.scheduler.sent(admitted)
 

@@ -59,9 +59,11 @@ class ServingConfig:
     max_slots: int = 8           # decode batch size = max concurrent seqs
     page_size: int = 16
     num_pages: int = 256         # pool size incl. the null page
-    max_prompt_len: int = 64     # longest prompt = a prefill pass's length
+    max_prompt_len: int = 64     # longest prompt = the longest prefill pass
     max_new_tokens: int = 64     # per-request cap (requests may ask less)
-    prefill_batch: int = 4       # admissions per step = a pass's most rows
+    # admissions per step = a pass's most rows (``prefill_shapes``: one
+    # where the passes are long)
+    prefill_batch: int = 4
     # 0 = no budget; else cap on the summed reservations (prompt +
     # max_new_tokens) of resident sequences — bounds worst-case context
     max_concurrent_tokens: int = 0
@@ -103,24 +105,53 @@ class ServingConfig:
 
 
 def prefill_rows(prefill_batch: int) -> tuple[int, ...]:
-    """The row counts a from-zero prefill pass may take, smallest first:
-    one, and ``prefill_batch`` — the prefill ladder; every member is
-    ``max_prompt_len`` long.
+    """The row counts of the ROW ladder, smallest first: one, and
+    ``prefill_batch`` — what :func:`prefill_shapes` gives every engine
+    whose passes are short, each member ``max_prompt_len`` long.
 
     A pass's time follows its shape, and most passes carry one row (a
     closed loop admits a request the moment one finishes: 72-87% of the
     passes of the benchmark's serve cells), so the single row is where a
-    second program pays.  It is also all the ladder there is, because the
-    engine compiles every member before it serves
-    (``ServingEngine._make_ready``) and a member costs set-up time in
-    every process, warm cache or not: the host tracing and lowering the
-    program, about 1.2 s on a v5e machine, a twentieth of a serving
-    process's whole set-up.  A two-row member and half- and
-    quarter-length rows were measured too and bring 4-12% more tokens a
-    second on a compute-bound stack for that second each (PERF.md section
-    6, PR 31): not here until a member costs less.  ``prefill_batch`` rows
-    hold whatever ``admit`` hands over."""
+    second program pays.  ``prefill_batch`` rows hold whatever ``admit``
+    hands over."""
     return tuple(sorted({1, prefill_batch}))
+
+
+# From this many positions in HALF a pass the ladder's second member is
+# spent on length, not rows.  A member's price is a constant: the host
+# traces and lowers its program in every process, warm cache or not, 1-3 s
+# of set-up (``ServingEngine._make_ready``), which is why there are two
+# and no more.  What a member saves grows with the pass: a half-length
+# member saves a short prompt 43 ms of a 104 ms pass at 4,096 positions
+# and 2-6 ms at 512-768, where it was measured at + 2-5% tokens a second
+# beside the row ladder's two (PERF.md section 6, PRs 31 and 44) and
+# ``setup_s`` has no room for a third.  And a pass this long is
+# compute-bound many times over, so rows buy nothing there: two rows cost
+# two one-row passes (261.7 against 139 ms, PR 42).
+LENGTH_LADDER_MIN_HALF = 1024
+# what a member's length has to divide into: pages, the chunks of the
+# state layers' scans, the flash kernel's blocks
+_LENGTH_MULTIPLE = 256
+
+
+def prefill_shapes(prefill_batch: int,
+                   max_prompt_len: int) -> tuple[tuple[int, int], ...]:
+    """The ``(rows, length)`` shapes a from-zero prefill pass may take,
+    fewest positions first — the prefill ladder, at most TWO members for
+    every model alike (the engine compiles every member before it
+    serves, and a member costs set-up time in every process):
+
+    - short passes: ``(1, L)`` and ``(prefill_batch, L)`` at ``L`` =
+      ``max_prompt_len`` — :func:`prefill_rows`;
+    - long passes (``L // 2`` a whole multiple of 256 and at least
+      ``LENGTH_LADDER_MIN_HALF``): ``(1, L // 2)`` and ``(1, L)`` — a
+      prompt of up to half the length stops paying for the whole of it,
+      and ``admit`` hands over one request an iteration."""
+    half = max_prompt_len // 2
+    if half >= LENGTH_LADDER_MIN_HALF and half % _LENGTH_MULTIPLE == 0:
+        return ((1, half), (1, max_prompt_len))
+    return tuple((rows, max_prompt_len)
+                 for rows in prefill_rows(prefill_batch))
 
 
 @dataclasses.dataclass
@@ -236,9 +267,15 @@ class Scheduler:
         self.queue: collections.deque[Request] = collections.deque()
         self.slots: list[_Active | None] = [None] * serving.max_slots
         self.rejected_admissions = 0  # out-of-pages/budget head blocks
-        self.prefill_rows = prefill_rows(serving.prefill_batch)
+        self.prefill_shapes = prefill_shapes(serving.prefill_batch,
+                                             serving.max_prompt_len)
 
     # -- state views ----------------------------------------------------------
+    @property
+    def prefill_rows(self) -> tuple[int, ...]:
+        """The rows of each of ``prefill_shapes``."""
+        return tuple(rows for rows, _ in self.prefill_shapes)
+
     @property
     def active(self) -> list[_Active]:
         return [a for a in self.slots if a is not None]
@@ -293,13 +330,19 @@ class Scheduler:
         self.queue.append(req)
 
     def admit(self, now: float = 0.0) -> list[_Active]:
-        """Admit up to ``prefill_batch`` queued requests into free slots
-        (FIFO, head-of-line blocking — see module docstring).  Allocates
-        pages and table rows; the engine prefills the returned batch."""
+        """Admit queued requests into free slots (FIFO, head-of-line
+        blocking — see module docstring), as many as one prefill pass
+        holds: ``prefill_batch``, or the rows of the largest member of
+        ``prefill_shapes`` where every from-zero pass is one of those (one
+        request an iteration under one-row members: the next goes out an
+        iteration later, behind this one).  Allocates pages and table
+        rows; the engine prefills the returned batch."""
         s = self.serving
         admitted: list[_Active] = []
         budget = s.max_concurrent_tokens or None
-        while self.queue and len(admitted) < s.prefill_batch:
+        most = (s.prefill_batch if s.incremental_prefill
+                else max(self.prefill_rows))
+        while self.queue and len(admitted) < most:
             free = [i for i, a in enumerate(self.slots) if a is None]
             if not free:
                 break
@@ -530,14 +573,18 @@ class Scheduler:
 
     def prefill_batch(self, admitted: list[_Active]) -> dict:
         """Arrays for one prefill pass over newly admitted sequences, at
-        the fewest rows of ``prefill_rows`` that hold them, not at
-        ``prefill_batch`` rows whatever they are: a pass's time follows
-        its shape."""
-        return self.prefill_arrays(admitted, next(
-            n for n in self.prefill_rows if n >= len(admitted)))
+        the first member of ``prefill_shapes`` (fewest positions first)
+        that holds them and their longest prompt, not at the largest
+        whatever they are: a pass's time follows its shape."""
+        longest = max(a.prompt_len for a in admitted)
+        return self.prefill_arrays(admitted, *next(
+            (rows, length) for rows, length in self.prefill_shapes
+            if rows >= len(admitted) and length >= longest))
 
-    def prefill_arrays(self, admitted: list[_Active], rows: int) -> dict:
-        """A prefill pass's arrays at ``rows`` x ``max_prompt_len``.  Slack
+    def prefill_arrays(self, admitted: list[_Active], rows: int,
+                       length: int | None = None) -> dict:
+        """A prefill pass's arrays at ``rows`` x ``length``
+        (``max_prompt_len`` unless given).  Slack
         rows are masked with len 0 and the null-page table row — with no
         sequence at all it is the batch the engine compiles that member
         of the ladder for (``ServingEngine._make_ready``).  ``slots``
@@ -545,7 +592,7 @@ class Scheduler:
         its recurrent state goes to; a slack row names ``max_slots``, a
         row that does not exist, and its write is dropped."""
         s = self.serving
-        nb, t = rows, s.max_prompt_len
+        nb, t = rows, length or s.max_prompt_len
         ids = np.zeros((nb, t), np.int32)
         lens = np.zeros((nb,), np.int32)
         table = np.zeros((nb, self.cache.max_pages_per_seq), np.int32)

@@ -477,8 +477,8 @@ def test_one_span_a_pass_and_none_overlap_one_pass_ahead(tracer, incremental):
 
 @pytest.mark.serving
 def test_serve_prefill_says_what_its_shape_carried(tracer):
-    """A pass's span names the shape it ran (``rows``, ``padded_tokens`` =
-    rows x length) and the prompt tokens in it; two counters sum the same
+    """A pass's span names the shape it ran (``rows``, ``length``,
+    ``padded_tokens`` = rows x length) and the prompt tokens in it; two counters sum the same
     over the passes, so their ratio is the fill share; the gauge (set
     once, in the registry the engine was built with) is the number of
     shapes the engine compiles."""
@@ -493,6 +493,7 @@ def test_serve_prefill_says_what_its_shape_carried(tracer):
     for span, (lens, rows) in zip(passes, script):
         assert span.args["batch"] == len(lens)
         assert span.args["rows"] == rows
+        assert span.args["length"] == 24
         assert span.args["padded_tokens"] == rows * 24
         assert span.args["prompt_tokens"] == sum(lens)
     padded = reg.get("serve_prefill_padded_tokens_total").value()
@@ -500,6 +501,8 @@ def test_serve_prefill_says_what_its_shape_carried(tracer):
     assert padded == (1 + 4 + 1 + 4) * 24
     assert prompt == 10 + 23 + 24 + 27
     assert prompt / padded == pytest.approx(84 / 240)   # the fill share
+    # how often each length of the ladder ran: here it has one
+    assert reg.get("serve_prefill_passes_total").value(length=24) == 4
     assert built_with.get("serve_prefill_programs").value() == len(
         eng.scheduler.prefill_rows) == 2
 

@@ -721,8 +721,8 @@ def _ladder_engines(kind):
     params = T.init_params(cfg, jax.random.key(3))
     shaped = ServingEngine(cfg, params, ServingConfig(**LADDER_SERVING))
     padded = ServingEngine(cfg, params, ServingConfig(**LADDER_SERVING))
-    assert shaped.scheduler.prefill_rows == (1, 4)
-    padded.scheduler.prefill_rows = (4,)
+    assert shaped.scheduler.prefill_shapes == LADDER
+    padded.scheduler.prefill_shapes = LADDER[1:]
     return shaped, padded
 
 
@@ -749,30 +749,110 @@ class _Compiles:
 
 class TestPrefillLadder:
     @pytest.mark.parametrize("batch,longest,shape", [
-        (4, 768, ((1, 768), (4, 768))),
+        # the benchmark's six serve configurations
+        pytest.param(4, 768, ((1, 768), (4, 768)), id="gpt2-large"),
+        pytest.param(2, 192, ((1, 192), (2, 192)), id="ouro-2.6b"),
+        pytest.param(4, 512, ((1, 512), (4, 512)), id="nemotron-3-nano"),
+        pytest.param(4, 512, ((1, 512), (4, 512)), id="sdar-30b"),
+        pytest.param(4, 512, ((1, 512), (4, 512)), id="zaya1-8b"),
+        pytest.param(2, 4096, ((1, 2048), (1, 4096)), id="solar-open2"),
         (4, 96, LADDER),
-        (2, 192, ((1, 192), (2, 192))),
         (8, 16, ((1, 16), (8, 16))),
         (3, 100, ((1, 100), (3, 100))),
-        (1, 2048, ((1, 2048),)),     # one row is all such an engine admits
+        (1, 1024, ((1, 1024),)),     # one row is all such an engine admits
+        (1, 2048, ((1, 1024), (1, 2048))),
+        (4, 2048, ((1, 1024), (1, 2048))),   # the least that is long
+        (4, 2046, ((1, 2046), (4, 2046))),   # half a pass under 1,024
+        (2, 2304, ((1, 2304), (2, 2304))),   # half no multiple of 256
+        (2, 8192, ((1, 4096), (1, 8192))),
     ])
     def test_ladder_from_two_numbers(self, batch, longest, shape):
-        """One row and ``prefill_batch`` rows at ``max_prompt_len``: one
-        length whatever it is (a program costs set-up time), and the
-        largest member holds whatever ``admit`` may hand over."""
+        """Two programs whatever the model (a program costs set-up time):
+        one row and ``prefill_batch`` rows at ``max_prompt_len`` where a
+        pass is short; one row at half the length and one at all of it
+        where half a pass is 1,024 positions or more and a whole number
+        of 256.  The largest member holds whatever ``admit`` may hand
+        over, and ``prefill_rows`` is what it was."""
         from paddle_tpu.serving.kv_cache import PagedKVCache
-        from paddle_tpu.serving.scheduler import Scheduler, prefill_rows
+        from paddle_tpu.serving.scheduler import (
+            Scheduler,
+            prefill_rows,
+            prefill_shapes,
+        )
 
-        assert prefill_rows(batch) == tuple(rows for rows, _ in shape)
+        assert prefill_rows(batch) == tuple(sorted({1, batch}))
+        assert prefill_shapes(batch, longest) == shape
         s = ServingConfig(**{**LADDER_SERVING, "prefill_batch": batch,
                              "max_prompt_len": longest, "max_slots": 8,
                              "num_pages": 8 * (-(-longest // 16) + 1) + 1})
         sched = Scheduler(s, PagedKVCache(1, 2, 16, s.num_pages, s.page_size,
                                           s.max_slots, s.max_pages_per_seq))
-        got = tuple(sched.prefill_arrays([], rows)["ids"].shape
-                    for rows in sched.prefill_rows)
+        assert sched.prefill_shapes == shape
+        assert sched.prefill_rows == tuple(rows for rows, _ in shape)
+        got = tuple(sched.prefill_arrays([], *member)["ids"].shape
+                    for member in sched.prefill_shapes)
         assert got == shape
-        assert got[-1] == (batch, longest)
+        assert got[-1][1] == longest
+        # without a length a member is ``max_prompt_len`` long
+        assert sched.prefill_arrays([], 1)["ids"].shape == (1, longest)
+
+    @pytest.mark.parametrize("n,member", [
+        (1, (1, 2048)), (512, (1, 2048)), (2047, (1, 2048)),
+        (2048, (1, 2048)), (2049, (1, 4096)), (4096, (1, 4096)),
+    ])
+    def test_a_prompt_takes_the_shortest_member_that_holds_it(self, n,
+                                                               member):
+        """A prompt of 2,048 rides the half-length member, one of 2,049
+        the full one; positions past the prompt are masked by ``seq_lens``
+        at either length, slack as at every shape."""
+        from paddle_tpu.serving.kv_cache import PagedKVCache
+        from paddle_tpu.serving.scheduler import Request, Scheduler
+
+        s = ServingConfig(max_slots=2, page_size=16, num_pages=2 * 260 + 1,
+                          max_prompt_len=4096, max_new_tokens=8,
+                          prefill_batch=2)
+        sched = Scheduler(s, PagedKVCache(1, 2, 16, s.num_pages, s.page_size,
+                                          s.max_slots, s.max_pages_per_seq))
+        sched.enqueue(Request(id=0, prompt=[3] * n, max_new_tokens=2))
+        (a,) = sched.admit()
+        batch = sched.prefill_batch([a])
+        assert batch["ids"].shape == member
+        assert batch["seq_lens"].tolist() == [n]
+        assert batch["ids"][0, :n].tolist() == [3] * n
+        assert not batch["ids"][0, n:].any()
+        assert batch["slots"].tolist() == [a.slot]
+        assert batch["page_table"].shape == (1, s.max_pages_per_seq)
+
+    @pytest.mark.parametrize("batch,longest,queued,handed", [
+        (4, 96, 6, [4, 2]),         # today's ladder: prefill_batch a step
+        (2, 192, 3, [2, 1]),
+        (1, 96, 2, [1, 1]),
+        (2, 4096, 3, [1, 1, 1]),    # one-row members: one an iteration
+        (4, 2048, 2, [1, 1]),
+    ])
+    def test_admit_hands_over_what_one_member_holds(self, batch, longest,
+                                                    queued, handed):
+        from paddle_tpu.serving.kv_cache import PagedKVCache
+        from paddle_tpu.serving.scheduler import Request, Scheduler
+
+        s = ServingConfig(max_slots=8, page_size=16,
+                          num_pages=8 * (-(-longest // 16) + 1) + 1,
+                          max_prompt_len=longest, max_new_tokens=4,
+                          prefill_batch=batch)
+        sched = Scheduler(s, PagedKVCache(1, 2, 16, s.num_pages, s.page_size,
+                                          s.max_slots, s.max_pages_per_seq))
+        for i in range(queued):
+            sched.enqueue(Request(id=i, prompt=[1 + i] * 5, max_new_tokens=2))
+        got, order = [], []
+        while sched.queue:
+            admitted = sched.admit()
+            got.append(len(admitted))
+            order += [a.request.id for a in admitted]
+            # every hand-over fits a member of the ladder
+            assert sched.prefill_batch(admitted)["ids"].shape[0] >= len(
+                admitted)
+        assert got == handed
+        assert order == list(range(queued))     # FIFO
 
     @pytest.mark.parametrize("kind", ["plain", "looped", "pattern"])
     def test_every_engine_has_the_same_ladder(self, kind):
@@ -870,6 +950,58 @@ class TestPrefillLadder:
             eng.generate([list(rng_np.integers(1, 64, size=n))
                           for n in lens], max_new_tokens=3)
         assert watch.count == ready
+
+    def test_length_members_serve_the_same_tokens(self, monkeypatch, rng_np):
+        """Where half a pass is long enough (the constant lowered to a
+        toy's size) the second member is HALF AS LONG, not wider: greedy
+        tokens are those of an engine whose every pass is the full
+        length, nothing compiles once the first request is admitted, and
+        the passes are counted by the length they ran at."""
+        from paddle_tpu.serving import scheduler
+
+        monkeypatch.setattr(scheduler, "LENGTH_LADDER_MIN_HALF", 256)
+        # attention, two state layers and an expert sublayer; a vocabulary
+        # no other test serves: its programs are not compiled yet
+        cfg = dataclasses.replace(_ladder_cfg("pattern"), vocab_size=73,
+                                  max_seq_len=528)
+        params = T.init_params(cfg, jax.random.key(8))
+        serving = ServingConfig(max_slots=4, page_size=16, num_pages=4 * 33
+                                + 1, max_prompt_len=512, max_new_tokens=4,
+                                prefill_batch=2, seed=0)
+        reg = MetricsRegistry("length_ladder")
+        shaped = ServingEngine(cfg, params, serving, registry=reg)
+        full = ServingEngine(cfg, params, serving)
+        assert shaped.scheduler.prefill_shapes == ((1, 256), (1, 512))
+        assert reg.get("serve_prefill_programs").value() == 2
+        full.scheduler.prefill_shapes = ((1, 512),)
+        seen = []
+        real = shaped.scheduler.prefill_batch
+
+        def recorded(admitted):
+            batch = real(admitted)
+            seen.append(batch["ids"].shape)
+            return batch
+
+        shaped.scheduler.prefill_batch = recorded
+        watch = _Compiles.listen()
+        shaped.generate([[5, 17, 3]], max_new_tokens=2)
+        assert set(shaped._programs) == {(1, 256), (1, 512), "decode"}
+        ready = watch.count
+        lens = (1, 255, 256, 257, 300, 512, 40, 511)
+        prompts = [list(rng_np.integers(1, 73, size=n)) for n in lens]
+        a = shaped.generate(prompts, max_new_tokens=3)
+        assert watch.count == ready
+        b = full.generate(prompts, max_new_tokens=3)
+        assert [r.tokens for r in a] == [r.tokens for r in b]
+        # one request a pass, in arrival order, each at the shortest
+        # member that holds it
+        assert seen == [(1, 256)] + [(1, 256 if n <= 256 else 512)
+                                     for n in lens]
+        passes = reg.get("serve_prefill_passes_total")
+        assert passes.value(length=256) == 1 + 4
+        assert passes.value(length=512) == 4
+        assert reg.get("serve_prefill_padded_tokens_total").value() == (
+            5 * 256 + 4 * 512)
 
     def test_making_ready_leaves_the_cache_as_it_was(self, rng_np):
         """Getting every member of the ladder and the decode program
@@ -1846,9 +1978,9 @@ def _program_texts(kind):
     cache, sched, bl = eng.cache, eng.scheduler, eng.cfg.block_len
     head = (eng.params, eng._base_key, cache.k, cache.v)
     texts = {}
-    for n in sched.prefill_rows:
-        args = eng._dev(sched.prefill_arrays([], n), "ids", "seq_lens",
-                        "page_table", "rids", "temps", "slots")
+    for n, length in sched.prefill_shapes:
+        args = eng._dev(sched.prefill_arrays([], n, length), "ids",
+                        "seq_lens", "page_table", "rids", "temps", "slots")
         texts[f"prefill {n}"] = eng._prefill.lower(
             *head, *args, cache.state,
             *([cache.tokens] if bl == 1 else [])).as_text()
