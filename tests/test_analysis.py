@@ -547,6 +547,8 @@ def _run_preflight(cfg, *extra, inject="", devices=0, jsonl=None):
 
 
 def test_preflight_cli_clean_config_exits_zero(tmp_path):
+    from paddle_tpu.telemetry.registry import SCHEMA
+
     cfg = _write_preflight_config(tmp_path)
     jsonl = str(tmp_path / "metrics.jsonl")
     out = _run_preflight(cfg, jsonl=jsonl)
@@ -556,7 +558,7 @@ def test_preflight_cli_clean_config_exits_zero(tmp_path):
     recs = [json.loads(line) for line in open(jsonl)]
     pf = [r for r in recs if r.get("kind") == "preflight"]
     assert pf and pf[0]["clean"] is True
-    assert pf[0]["schema"] == "paddle_tpu.metrics/16"
+    assert pf[0]["schema"] == SCHEMA
     # the schema/9 GL-P-MEM memory report rode along
     mem = pf[0]["memory"]
     assert mem["params_bytes"] > 0 and mem["opt_state_bytes"] > 0

@@ -1,6 +1,10 @@
 """Attention numerics: blockwise == exact, ring == exact (on the 8-device
-virtual mesh), plus gradient agreement — the compare-two-implementations
-pattern of the reference's test_matrixCompare/Compare2Function harnesses."""
+virtual mesh), the flash kernel under a block-causal mask (interpret mode),
+plus gradient agreement — the compare-two-implementations pattern of the
+reference's test_matrixCompare/Compare2Function harnesses.  Gradients are
+differentiated, then compiled: the eager tape dispatches op by op."""
+
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -8,7 +12,16 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+import paddle_tpu.ops.pallas.flash_attention  # noqa: F401 (the module)
 from paddle_tpu.ops import attention as A
+
+# the package re-exports the function under the module's name
+FA = sys.modules["paddle_tpu.ops.pallas.flash_attention"]
+BL = 4      # the block length of the block-causal cases
+
+
+def _grads(loss):
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
 
 
 def _qkv(b=2, t=32, h=4, d=8, seed=0):
@@ -41,8 +54,8 @@ def test_blockwise_grads_match():
     def loss_block(q, k, v):
         return jnp.sum(A.blockwise_attention(q, k, v, block_size=4) ** 2)
 
-    g_ref = jax.grad(loss_exact, argnums=(0, 1, 2))(q, k, v)
-    g_out = jax.grad(loss_block, argnums=(0, 1, 2))(q, k, v)
+    g_ref = _grads(loss_exact)(q, k, v)
+    g_out = _grads(loss_block)(q, k, v)
     for a, b in zip(g_out, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
@@ -73,8 +86,8 @@ def test_ring_attention_grads_match():
         m = A.causal_mask(16, 16)
         return jnp.sum(A.dot_product_attention(q, k, v, mask=m) ** 2)
 
-    g_ring = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(loss_exact, argnums=(0, 1, 2))(q, k, v)
+    g_ring = _grads(loss_ring)(q, k, v)
+    g_ref = _grads(loss_exact)(q, k, v)
     for a, b in zip(g_ring, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
@@ -150,8 +163,8 @@ def test_ulysses_attention_grads_match():
         m = A.causal_mask(16, 16)
         return jnp.sum(A.dot_product_attention(q, k, v, mask=m) ** 2)
 
-    g_u = jax.grad(loss_u, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(loss_exact, argnums=(0, 1, 2))(q, k, v)
+    g_u = _grads(loss_u)(q, k, v)
+    g_ref = _grads(loss_exact)(q, k, v)
     for a, b in zip(g_u, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
@@ -192,3 +205,47 @@ def test_ulysses_transformer_trains_on_dp_sp_mesh():
         params, state, loss = step(params, state, ids)
         losses.append(float(loss))
     assert np.isfinite(losses).all() and losses[-1] < losses[0]
+
+
+# -- the flash kernel's block-causal mask -------------------------------------------
+# ``causal`` = a block length: a position sees its block and the blocks
+# before it (generation by blocks, tests/test_block_lm.py).
+
+
+def _flash(causal, bq):
+    return jax.jit(lambda q, k, v: FA.flash_attention(
+        q, k, v, causal, None, bq, bq, True))
+
+
+@pytest.mark.parametrize("t,bq", [(24, 8), (40, 16), (12, 1024)])
+def test_flash_forward_under_the_block_causal_mask(t, bq):
+    """Interpret mode, several tiles and one: the mask at block
+    granularity against the jnp mask, tiles above the block diagonal
+    skipped; and ``causal=1`` is ``causal=True`` bit for bit."""
+    k1, k2, k3 = jax.random.split(jax.random.key(t), 3)
+    q, k, v = (jax.random.normal(kk, (2, t, 2, 8)) for kk in (k1, k2, k3))
+    got = _flash(BL, bq)(q, k, v)
+    blk = jnp.arange(t) // BL
+    want = A.dot_product_attention(
+        q, k, v, mask=(blk[:, None] >= blk[None, :])[None, None])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(FA.flash_attention_reference(q, k, v, BL)),
+        np.asarray(want), atol=2e-5)
+    one = _flash(1, bq)(q, k, v)
+    true = _flash(True, bq)(q, k, v)
+    assert np.array_equal(np.asarray(one), np.asarray(true))
+    assert float(jnp.max(jnp.abs(true - got))) > 1e-3
+
+
+def test_flash_backward_under_the_block_causal_mask():
+    k1, k2, k3 = jax.random.split(jax.random.key(9), 3)
+    q, k, v = (jax.random.normal(kk, (1, 24, 2, 8)) for kk in (k1, k2, k3))
+    blk = jnp.arange(24) // BL
+    mask = (blk[:, None] >= blk[None, :])[None, None]
+    f = lambda q, k, v: jnp.sum(FA.flash_attention(
+        q, k, v, BL, None, 8, 8, True) ** 2)
+    g = lambda q, k, v: jnp.sum(A.dot_product_attention(
+        q, k, v, mask=mask) ** 2)
+    for a, b in zip(_grads(f)(q, k, v), _grads(g)(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)

@@ -8,7 +8,9 @@ generation loop, for both policies and 1 / 2 / 4 denoising passes; the
 ``[noisy ; clean]`` layout against the block pass over the paged cache;
 prompts whose tail opens the first block; a last block's surplus dropped;
 one slot and many; a slot reused; what the counters and spans say of a
-pass; the kernels' masks; the routed layer's scores, gate and shares.
+pass.  (The kernels' masks are held in ``tests/test_attention.py`` and
+``tests/test_paged_attention.py``, the routed layer's scores, gate and
+shares in ``tests/test_moe_arrangement.py``.)
 
 Tolerances.  Program and reference both compute in float32 here (the CPU
 backend's dots are exact float32), so they differ by the order of
@@ -20,8 +22,6 @@ many, with wider gaps (``test_bf16_passes_and_int8_weights_fail``).
 """
 
 import dataclasses
-import importlib.util
-import os
 
 import jax
 import jax.numpy as jnp
@@ -29,21 +29,14 @@ import numpy as np
 import pytest
 
 from paddle_tpu.models import transformer as T
-from paddle_tpu.ops import attention as attn_ops
-import paddle_tpu.ops.pallas.flash_attention  # noqa: F401 (the module)
 from paddle_tpu.ops.pallas import paged_attention as PA
-from paddle_tpu.parallel import moe
-from paddle_tpu.serving import ServingConfig, ServingEngine, sampling
+from paddle_tpu.serving import ServingConfig, sampling
 from paddle_tpu.serving.kv_cache import PagedKVCache
 from paddle_tpu.serving.scheduler import Request, Scheduler
 from paddle_tpu.telemetry import MetricsRegistry
-from paddle_tpu.telemetry import tracing as tracing_mod
 
-import sys  # noqa: E402
+import lm_toy
 
-# the package re-exports the function under the module's name
-FA = sys.modules["paddle_tpu.ops.pallas.flash_attention"]
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOL = 2e-4
 BL = 4
 M = dict(vocab_size=97, num_layers=4, num_heads=4, kv_heads=2, head_dim=8,
@@ -60,24 +53,7 @@ def block_cfg(**kw):
     return T.TransformerConfig(**{**M, "remat": False, **kw})
 
 
-@pytest.fixture(scope="module")
-def ref():
-    spec = importlib.util.spec_from_file_location(
-        "sdar_reference",
-        os.path.join(REPO, "benchmarks", "references", "sdar.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-@pytest.fixture(scope="module")
-def weights(ref):
-    return ref.init_weights(M, 7, jnp.float32)
-
-
-@pytest.fixture(scope="module")
-def params(ref, weights):
-    return ref.program_tree(weights)
+ref, weights, params = lm_toy.fixtures("sdar", M, 7)
 
 
 @pytest.fixture(scope="module")
@@ -87,9 +63,9 @@ def prompts():
 
 
 def engine(params, cfg=None, registry=None, **serving):
-    return ServingEngine(cfg or block_cfg(), params,
-                         ServingConfig(**{**SERVING, **serving}),
-                         registry=registry or MetricsRegistry("block_lm"))
+    return lm_toy.engine(cfg or block_cfg(), params,
+                         registry or MetricsRegistry("block_lm"),
+                         **{**SERVING, **serving})
 
 
 # -- the engine against the reference's generation loop --------------------------
@@ -126,19 +102,21 @@ def test_block_pass_logits_equal_the_noisy_clean_layout(ref, weights, params):
     rng = np.random.default_rng(3)
     seq = rng.integers(0, 96, size=16).tolist()
     start = 12
-    _, ks, vs, _ = T.forward_prefill(cfg, params, jnp.asarray([seq]),
-                                     jnp.asarray([start]))
+    _, ks, vs, _ = lm_toy.jitted(T.forward_prefill, cfg)(
+        params, jnp.asarray([seq]), jnp.asarray([start]))
     kc, vc = PA.init_kv_pages(2, 2, 8, 8, 8)
     table = jnp.asarray([[1, 2, 3, 0]], jnp.int32)
     kc, vc = PA.write_prefill_kv(kc, vc, ks, vs, table, jnp.asarray([start]))
     masked = jnp.asarray([[False, True, False, True]])
-    got, *_ = T.forward_decode_block(
-        cfg, params, jnp.asarray([seq[start:]]), masked, jnp.asarray([start]),
-        jnp.asarray([start + BL]), table, kc, vc, attn_impl="reference")
+    got, *_ = lm_toy.jitted(T.forward_decode_block, cfg,
+                            attn_impl="reference")(
+        params, jnp.asarray([seq[start:]]), masked, jnp.asarray([start]),
+        jnp.asarray([start + BL]), table, kc, vc)
     clean = jnp.asarray(seq)
     noisy = clean.at[jnp.asarray([13, 15])].set(M["mask_id"])
     with jax.default_matmul_precision("highest"):
-        want = ref.denoise_logits(weights, noisy, clean, M)[start:]
+        want = jax.jit(lambda noisy, clean: ref.denoise_logits(
+            weights, noisy, clean, M))(noisy, clean)[start:]
     assert got.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want),
                                atol=TOL, rtol=TOL)
@@ -147,14 +125,17 @@ def test_block_pass_logits_equal_the_noisy_clean_layout(ref, weights, params):
 def test_forward_equals_the_reference_under_the_block_causal_mask(
         ref, weights, params):
     seq = np.random.default_rng(4).integers(0, 96, size=22).tolist()
-    got = T.forward(block_cfg(), params, jnp.asarray([seq]))[0]
+    got = lm_toy.jitted(T.forward, block_cfg())(params,
+                                                jnp.asarray([seq]))[0]
+    # at its own length: a block sees all of itself, padding included
     with jax.default_matmul_precision("highest"):
-        want = ref.logits_fn(weights, jnp.asarray(seq), M)
+        want = jax.jit(lambda ids: ref.logits_fn(weights, ids, M))(
+            jnp.asarray(seq))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=TOL,
                                rtol=TOL)
     # and it is not the token-causal forward: position 0 sees position 3
-    causal = T.forward(block_cfg(block_len=1, mask_id=None), params,
-                       jnp.asarray([seq]))[0]
+    causal = lm_toy.jitted(T.forward, block_cfg(block_len=1, mask_id=None))(
+        params, jnp.asarray([seq]))[0]
     assert float(jnp.max(jnp.abs(causal[0] - got[0]))) > 1e-2
 
 
@@ -459,17 +440,8 @@ def test_a_drain_mid_block_loses_nothing(via, params, prompts):
 
 def test_counters_and_spans_count_what_a_block_pass_does(params, prompts):
     reg = MetricsRegistry("block_counts")
-    tracing_mod.configure_tracing(enabled=True)
-    tracer = tracing_mod.get_tracer()
-    tracer.clear()
-    try:
-        eng = engine(params, registry=reg)
-        res = eng.generate(prompts, max_new_tokens=10)
-        spans = [s for s in tracer.spans if s.name in ("serve_decode",
-                                                       "serve_prefill")]
-    finally:
-        tracing_mod.configure_tracing(enabled=False)
-        tracer.clear()
+    res, spans = lm_toy.traced(lambda: engine(params, registry=reg).generate(
+        prompts, max_new_tokens=10))
     val = lambda name, **lab: reg.get(name).value(**lab)
     blocks = sum(-(-(n + 10) // BL) - n // BL for n in LENS)
     positions = sum(len(r.trail["tokens"]) for r in res)
@@ -485,7 +457,7 @@ def test_counters_and_spans_count_what_a_block_pass_does(params, prompts):
     assert val("serve_block_positions_total") == rows * BL
     assert val("serve_layer_passes_total") == rows * BL * 2
     assert reg.get("serve_block_length").value() == BL
-    dec = [s.args for s in spans if s.name == "serve_decode"]
+    dec = [s.args for s in spans["serve_decode"]]
     assert sum(a["batch"] for a in dec) == rows
     assert sum(a["committed"] for a in dec) == blocks
     assert sum(a["tokens_out"] for a in dec) == 10 * len(LENS)
@@ -495,7 +467,7 @@ def test_counters_and_spans_count_what_a_block_pass_does(params, prompts):
                and a["context_tokens"] >= a["batch"] * BL
                and a["moe_assignments"] == a["positions"] * 2 * 2
                for a in dec)
-    pre = [s.args for s in spans if s.name == "serve_prefill"]
+    pre = [s.args for s in spans["serve_prefill"]]
     assert sum(a["blocks_written"] for a in pre) == sum(n // BL for n in LENS)
     assert sum(a["prompt_tokens"] for a in pre) == sum(
         n // BL * BL for n in LENS)
@@ -611,198 +583,21 @@ def test_a_dense_stack_generates_by_blocks_too():
         max_seq_len=64, block_len=BL, mask_id=60, remat=False)
     params = T.init_params(cfg, jax.random.key(1))
     seq = list(range(1, 13))
-    _, ks, vs = T.forward_prefill(cfg, params, jnp.asarray([seq]),
-                                  jnp.asarray([8]))
+    _, ks, vs = lm_toy.jitted(T.forward_prefill, cfg)(
+        params, jnp.asarray([seq]), jnp.asarray([8]))
     kc, vc = PA.init_kv_pages(2, 2, 8, 8, 8)
     table = jnp.asarray([[1, 2, 0]], jnp.int32)
     kc, vc = PA.write_prefill_kv(kc, vc, ks, vs, table, jnp.asarray([8]))
-    got, *_ = T.forward_decode_block(
-        cfg, params, jnp.asarray([seq[8:]]), jnp.zeros((1, BL), bool),
-        jnp.asarray([8]), jnp.asarray([12]), table, kc, vc,
-        attn_impl="reference")
-    want = T.forward(cfg, params, jnp.asarray([seq]))[0, 8:]
+    got, *_ = lm_toy.jitted(T.forward_decode_block, cfg,
+                            attn_impl="reference")(
+        params, jnp.asarray([seq[8:]]), jnp.zeros((1, BL), bool),
+        jnp.asarray([8]), jnp.asarray([12]), table, kc, vc)
+    want = lm_toy.jitted(T.forward, cfg)(params, jnp.asarray([seq]))[0, 8:]
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want),
                                atol=TOL, rtol=TOL)
-    eng = ServingEngine(cfg, params, ServingConfig(**SERVING),
-                        registry=MetricsRegistry("dense_block"))
+    eng = engine(params, cfg, MetricsRegistry("dense_block"))
     r, = eng.generate([seq[:9]], max_new_tokens=6)
     assert len(r.tokens) == 6 and len(r.trail["tokens"]) == 7
-
-
-# -- the kernels' masks -----------------------------------------------------------
-
-
-@pytest.mark.parametrize("t,bq", [(24, 8), (40, 16), (12, 1024)])
-def test_flash_forward_under_the_block_causal_mask(t, bq):
-    """Interpret mode, several tiles and one: the mask at block
-    granularity against the jnp mask, tiles above the block diagonal
-    skipped; and ``causal=1`` is ``causal=True`` bit for bit."""
-    k1, k2, k3 = jax.random.split(jax.random.key(t), 3)
-    q, k, v = (jax.random.normal(kk, (2, t, 2, 8)) for kk in (k1, k2, k3))
-    got = FA.flash_attention(q, k, v, BL, None, bq, bq, True)
-    blk = jnp.arange(t) // BL
-    want = attn_ops.dot_product_attention(
-        q, k, v, mask=(blk[:, None] >= blk[None, :])[None, None])
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
-    np.testing.assert_allclose(
-        np.asarray(FA.flash_attention_reference(q, k, v, BL)),
-        np.asarray(want), atol=2e-5)
-    one = FA.flash_attention(q, k, v, 1, None, bq, bq, True)
-    true = FA.flash_attention(q, k, v, True, None, bq, bq, True)
-    assert np.array_equal(np.asarray(one), np.asarray(true))
-    assert float(jnp.max(jnp.abs(true - got))) > 1e-3
-
-
-def test_flash_backward_under_the_block_causal_mask():
-    k1, k2, k3 = jax.random.split(jax.random.key(9), 3)
-    q, k, v = (jax.random.normal(kk, (1, 24, 2, 8)) for kk in (k1, k2, k3))
-    blk = jnp.arange(24) // BL
-    mask = (blk[:, None] >= blk[None, :])[None, None]
-    f = lambda q, k, v: jnp.sum(FA.flash_attention(
-        q, k, v, BL, None, 8, 8, True) ** 2)
-    g = lambda q, k, v: jnp.sum(attn_ops.dot_product_attention(
-        q, k, v, mask=mask) ** 2)
-    for a, b in zip(jax.grad(f, (0, 1, 2))(q, k, v),
-                    jax.grad(g, (0, 1, 2))(q, k, v)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
-
-
-@pytest.mark.parametrize("impl", ["reference", "kernel"])
-def test_block_positions_ride_the_query_heads(impl):
-    """``rep`` 8 x block 4 = 32 query rows a K/V head (the shape the
-    benchmark's configuration runs), ragged lengths and an idle row: the
-    folded call against plain attention of every position over the row's
-    whole context; the kernel in interpret mode."""
-    b, kv, rep, d, ps, maxp = 3, 2, 8, 128, 8, 4
-    h = kv * rep
-    ks = jax.random.split(jax.random.key(2), 4)
-    q = jax.random.normal(ks[0], (b, BL, h, d))
-    kc = jax.random.normal(ks[1], PA.kv_pool_shape(2, kv, 16, ps, d))
-    vc = jax.random.normal(ks[2], PA.kv_pool_shape(2, kv, 16, ps, d))
-    table = jnp.asarray([[1, 2, 3, 4], [5, 6, 0, 0], [0, 0, 0, 0]], jnp.int32)
-    lens = jnp.asarray([28, 12, 0], jnp.int32)
-    got = PA.block_paged_attention(q, kc, vc, 1, table, lens, impl=impl,
-                                   interpret=True, kv_heads=kv)
-    assert got.shape == q.shape
-    kk = PA._gather_context(kc, 1, table, kv, d)     # [B, KV, T, D]
-    vv = PA._gather_context(vc, 1, table, kv, d)
-    s = jnp.einsum("btgrd,bgkd->btgrk", q.reshape(b, BL, kv, rep, d),
-                   kk) * d ** -0.5
-    s = jnp.where(jnp.arange(maxp * ps) < lens[:, None, None, None, None],
-                  s, -1e30)
-    want = jnp.einsum("btgrk,bgkd->btgrd", jax.nn.softmax(s, -1), vv)
-    want = jnp.where(lens[:, None, None, None, None] > 0, want, 0.0)
-    np.testing.assert_allclose(np.asarray(got),
-                               np.asarray(want.reshape(q.shape)), atol=2e-5)
-
-
-# -- the routed layer: score, gate, shares ----------------------------------------
-
-
-def _routed_layer(key, score, gated, experts=8, d=16, f=12):
-    ks = jax.random.split(key, 5)
-    p = {"router": jax.random.normal(ks[0], (d, experts)),
-         "w_in": jax.random.normal(ks[1], (experts, d, f)) * d ** -0.5,
-         "w_out": jax.random.normal(ks[2], (experts, f, d)) * f ** -0.5}
-    if score == "sigmoid":
-        p["router_bias"] = 0.1 * jax.random.normal(ks[3], (experts,))
-    if gated:
-        p["w_gate"] = jax.random.normal(ks[4], (experts, d, f)) * d ** -0.5
-    return p
-
-
-def _expert_loop(p, x, cfg):
-    """The layer as a loop over experts, in plain form."""
-    logits = x @ p["router"]
-    s = jax.nn.sigmoid(logits) if cfg.score == "sigmoid" \
-        else jax.nn.softmax(logits, -1)
-    _, idx = jax.lax.top_k(s + p.get("router_bias", 0.0), cfg.top_k)
-    w = jnp.take_along_axis(s, idx, -1)
-    w = w / w.sum(-1, keepdims=True) * cfg.scale
-    y = jnp.zeros_like(x)
-    for e in range(cfg.num_experts):
-        c = jnp.sum(jnp.where(idx == e, w, 0.0), -1, keepdims=True)
-        h = x @ p["w_in"][e]
-        h = jax.nn.silu(x @ p["w_gate"][e]) * h if cfg.gated \
-            else moe._act(cfg.act, h)
-        y = y + c * (h @ p["w_out"][e])
-    return y
-
-
-@pytest.mark.parametrize("rows", [24, moe.DENSE_MAX_TOKENS + 8])
-@pytest.mark.parametrize("score,gated", [("sigmoid", False),
-                                         ("softmax", True)])
-def test_routed_layer_equals_a_loop_over_experts(score, gated, rows):
-    """Both arrangements of ``moe_routed`` (every expert over every row;
-    rows sorted by expert) for both kinds of layer."""
-    cfg = moe.RoutedConfig(num_experts=8, top_k=2, scale=1.5, score=score,
-                           gated=gated, act="silu" if gated else "relu2")
-    p = _routed_layer(jax.random.key(5), score, gated)
-    x = jax.random.normal(jax.random.key(6), (rows, 16))
-    got, counts = moe.moe_routed(p, x, cfg)
-    np.testing.assert_allclose(np.asarray(got),
-                               np.asarray(_expert_loop(p, x, cfg)),
-                               atol=TOL, rtol=TOL)
-    assert int(counts[0]) == 2 * rows and int(counts[1]) == 0
-
-
-def _zaya_reference():
-    spec = importlib.util.spec_from_file_location(
-        "zaya_reference",
-        os.path.join(REPO, "benchmarks", "references", "zaya.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-@pytest.mark.parametrize("score,gated", [("sigmoid", False),
-                                         ("softmax", True),
-                                         ("mlp_top1", True)])
-def test_the_shares_add_up_to_the_uncut_layer(score, gated):
-    """Expert parallelism's unit: every device routes over all experts
-    and computes its own share's part; the parts of all shares add up to
-    what the uncut layer gives.  ``mlp_top1``: the experts chosen by an
-    MLP router over its carried state, one a token, weighing its own
-    probability (not renormalised), gated — the whole against the plain
-    reference's sublayer (``references/zaya.py``)."""
-    x = jax.random.normal(jax.random.key(8), (2, 9, 16))
-    if score == "mlp_top1":
-        ref = _zaya_reference()
-        m = dict(vocab_size=31, num_layers=2, num_heads=2, kv_heads=2,
-                 head_dim=4, embed_dim=16, mlp_dim=12, cca_taps=[2, 2],
-                 moe_experts=8, moe_router_hidden=6, norm_eps=1e-5,
-                 init={"router_gain": 4.0})
-        l = ref.init_weights(m, 3, jnp.float32)["layers"][0]["moe"]
-        p = {ref._MOE.get(n, ref._RES.get(n, n)): v for n, v in l.items()}
-        prev = jax.random.normal(jax.random.key(9), (2, 9, 6))
-        kw = dict(num_experts=8, top_k=1, score="softmax", gated=True,
-                  act="silu", router_hidden=6, renorm=False)
-        carry, k = moe.router_state(p, x, prev), 1
-        with jax.default_matmul_precision("highest"):
-            want, r = ref.moe_mixer(l, x.reshape(18, 16),
-                                    prev.reshape(18, 6), m)
-        np.testing.assert_allclose(np.asarray(carry).reshape(18, 6),
-                                   np.asarray(r), atol=TOL, rtol=TOL)
-    else:
-        kw = dict(num_experts=8, top_k=3, score=score, gated=gated,
-                  act="silu" if gated else "relu2")
-        p, carry, k, want = (_routed_layer(jax.random.key(7), score, gated),
-                             None, 3, None)
-    whole, counts = moe.moe_routed(p, x, moe.RoutedConfig(**kw), None, carry)
-    if want is not None:
-        np.testing.assert_allclose(np.asarray(whole).reshape(18, 16),
-                                   np.asarray(want), atol=TOL, rtol=TOL)
-    parts, held = 0.0, 0
-    for lo, hi in ((0, 3), (3, 4), (4, 8)):
-        share = {k_: (v[lo:hi] if k_ in ("w_in", "w_out", "w_gate") else v)
-                 for k_, v in p.items()}
-        y, c = moe.moe_routed(share, x, moe.RoutedConfig(held=(lo, hi), **kw),
-                              None, carry)
-        parts, held = parts + y, held + int(c[0])
-        assert int(c[0]) + int(c[1]) == 18 * k
-    np.testing.assert_allclose(np.asarray(parts), np.asarray(whole),
-                               atol=TOL, rtol=TOL)
-    assert held == int(counts[0]) == 18 * k
 
 
 def test_default_fields_leave_the_tree_and_the_programs_alone():

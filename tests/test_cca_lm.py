@@ -17,7 +17,6 @@ in the program's place moves them by 1e-2 or more.
 """
 
 import dataclasses
-import importlib.util
 import os
 
 import jax
@@ -29,12 +28,14 @@ from paddle_tpu.models import transformer as T
 from paddle_tpu.ops import cca
 from paddle_tpu.ops.pallas import paged_attention as PA
 from paddle_tpu.parallel import moe
-from paddle_tpu.serving import ServingConfig, ServingEngine
+from paddle_tpu.serving import ServingConfig
 from paddle_tpu.telemetry import MetricsRegistry
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+import lm_toy
+from lm_toy import PS, REPO
+
 TOL = 1e-4
-PS = 4
+PAD = 32    # the reference's one compiled length: 30 positions, 22 served
 M = dict(vocab_size=97, num_layers=6, num_heads=4, kv_heads=2, head_dim=8,
          embed_dim=32, mlp_dim=24, max_seq_len=128, norm="rms",
          norm_eps=1e-5, positions="rotary", rope_theta=5e6,
@@ -50,39 +51,8 @@ def cca_cfg(**kw):
     return T.TransformerConfig(**{**FIELDS, "remat": False, **kw})
 
 
-def _load(name):
-    spec = importlib.util.spec_from_file_location(
-        name + "_reference",
-        os.path.join(REPO, "benchmarks", "references", name + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-@pytest.fixture(scope="module")
-def ref():
-    return _load("zaya")
-
-
-@pytest.fixture(scope="module")
-def weights(ref):
-    return ref.init_weights(M, 11, jnp.float32)
-
-
-@pytest.fixture(scope="module")
-def params(ref, weights):
-    return ref.program_tree(weights)
-
-
-@pytest.fixture(scope="module")
-def seq():
-    return [int(t) for t in np.random.default_rng(5).integers(0, 97, 30)]
-
-
-@pytest.fixture(scope="module")
-def ref_logits(ref, weights, seq):
-    with jax.default_matmul_precision("highest"):
-        return np.asarray(ref.logits_fn(weights, jnp.asarray(seq), M))
+ref, weights, params, seq, ref_logits = lm_toy.fixtures(
+    "zaya", M, 11, seq_len=30, pad=PAD)
 
 
 def test_the_reference_imports_nothing_of_the_program():
@@ -111,12 +81,17 @@ def test_cca_qkv_equals_the_reference(ref, weights, params):
     cfg = cca_cfg()
     h = jax.random.normal(jax.random.key(3), (2, 21, 32))
     rope = T._rope_table(cfg, jnp.arange(21)[None])
-    window, kept = _prefill_window()
-    got = T._cca_qkv(cfg, h, params["blocks"][0], rope, window)
+
+    def qkv(h, layer):      # what the window keeps rides out with q, k, v
+        window, kept = _prefill_window()
+        return T._cca_qkv(cfg, h, layer, rope, window), kept
+
+    got, kept = jax.jit(qkv)(h, params["blocks"][0])
     l = ref._f32(weights["layers"][0]["attn"])
+    one = jax.jit(lambda l, x: ref.cca_qkv(l, x, M))
     with jax.default_matmul_precision("highest"):
         for b in range(2):
-            want = ref.cca_qkv(l, h[b], M)
+            want = one(l, h[b])
             for g, w in zip(got, want):
                 np.testing.assert_allclose(np.asarray(g[b]), np.asarray(w),
                                            atol=TOL, rtol=TOL)
@@ -189,7 +164,7 @@ def test_router_carries_its_state_from_layer_to_layer(ref, weights, params):
 def test_full_forward_equals_the_reference(params, seq, ref_logits):
     """Whole sequences through the training-side forward (no cache, no
     state kept): every position's logits."""
-    logits = T.forward(cca_cfg(), params, jnp.asarray([seq]))
+    logits = lm_toy.jitted(T.forward, cca_cfg())(params, jnp.asarray([seq]))
     np.testing.assert_allclose(np.asarray(logits[0]), ref_logits, atol=TOL,
                                rtol=TOL)
 
@@ -198,7 +173,8 @@ def test_the_loss_has_a_gradient_through_the_router_carry(params, seq):
     """Training is not refused: the walk is plain jax.numpy, so autodiff
     reaches the depth average's decay and k's temperature."""
     cfg = cca_cfg()
-    g = jax.grad(lambda p: T.loss_fn(cfg, p, jnp.asarray([seq])))(params)
+    g = jax.jit(jax.grad(
+        lambda p: T.loss_fn(cfg, p, jnp.asarray([seq]))))(params)
     for i, name in ((3, "router_decay"), (1, "router_down"), (0, "cca_temp"),
                     (2, "cca_conv1_w"), (4, "res_y_g")):
         leaf = np.asarray(g["blocks"][i][name])
@@ -206,14 +182,6 @@ def test_the_loss_has_a_gradient_through_the_router_carry(params, seq):
 
 
 # -- pages and state ------------------------------------------------------------
-
-
-def _pools(cfg, pages=40, slots=2):
-    kc, vc = PA.init_kv_pages(cfg.cache_layers, cfg.kv_heads, pages, PS,
-                              cfg.head_dim)
-    state = {n: jnp.zeros((layers, slots, *s))
-             for n, (layers, s) in cfg.state_parts.items()}
-    return kc, vc, state
 
 
 @pytest.mark.parametrize("attn_impl, p_len", [
@@ -226,34 +194,11 @@ def test_pages_and_state_equal_the_reference_at_every_position(
     state in slot rows, then decode the rest token by token: every
     position's logits are the reference's full forward.  Row 0 idles
     through the decode and keeps its state."""
-    cfg = cca_cfg()
-    kc, vc, state = _pools(cfg)
-    ids = np.zeros((2, 16), np.int32)
-    ids[0, :5] = seq[10:15]
-    ids[1, :p_len] = seq[:p_len]
-    lens = jnp.asarray([5, p_len])
-    logits, ks, vs, extras = T.forward_prefill(cfg, params, jnp.asarray(ids),
-                                               lens)
-    np.testing.assert_allclose(np.asarray(logits[1]), ref_logits[p_len - 1],
-                               atol=TOL, rtol=TOL)
+    ks, handed, state = lm_toy.walk_positions(
+        cca_cfg(), params, seq, ref_logits, p_len, 16, attn_impl, TOL)
     assert ks.shape == (3, 2, 16, 2, 8)    # cache layers x B x T x KV x Dh
-    table = jnp.asarray([[1, 2, 3, 4, 5, 6, 7, 8],
-                         [9, 10, 11, 12, 13, 14, 15, 16]], jnp.int32)
-    kc, vc = PA.write_prefill_kv(kc, vc, ks, vs, table, lens)
-    assert set(extras["state"]) == set(state)
-    state = {n: extras["state"][n] for n in state}   # slot = row
-    idle_state = {n: np.asarray(v[:, 0]) for n, v in state.items()}
-    for pos in range(p_len, len(seq)):
-        logits, kc, vc, extras = T.forward_decode(
-            cfg, params, jnp.asarray([0, seq[pos]]), jnp.asarray([0, pos]),
-            jnp.asarray([0, pos + 1]), table.at[0].set(0), kc, vc,
-            attn_impl=attn_impl, state=state)
-        state = extras["state"]
-        np.testing.assert_allclose(np.asarray(logits[1]), ref_logits[pos],
-                                   atol=TOL, rtol=TOL)
-    for n, v in state.items():
-        np.testing.assert_array_equal(np.asarray(v[:, 0]), idle_state[n])
-        assert np.abs(np.asarray(v[:, 1])).max() > 0
+    assert handed == set(state) == {"cca_u", "cca_c", "cca_v"}
+    assert all(np.abs(np.asarray(v[:, 1])).max() > 0 for v in state.values())
 
 
 def test_prefill_state_is_the_last_valid_tokens(ref, weights, params, seq):
@@ -262,8 +207,8 @@ def test_prefill_state_is_the_last_valid_tokens(ref, weights, params, seq):
     cfg = cca_cfg()
     ids = np.full((1, 16), 7, np.int32)     # padding: a real token's id
     ids[0, :9] = seq[:9]
-    _, _, _, extras = T.forward_prefill(cfg, params, jnp.asarray(ids),
-                                        jnp.asarray([9]))
+    _, _, _, extras = lm_toy.jitted(T.forward_prefill, cfg)(
+        params, jnp.asarray(ids), jnp.asarray([9]))
     l = ref._f32(weights["layers"][0]["attn"])
     x = weights["wte"][jnp.asarray(seq[:9])]
     h = ref._rms(x, l["g"], M["norm_eps"])
@@ -280,33 +225,12 @@ def test_prefill_state_is_the_last_valid_tokens(ref, weights, params, seq):
 # -- the engine ---------------------------------------------------------------------
 
 
-_PADDED = {}
-
-
-def _greedy(ref, weights, prompt, n, pad=24):
-    """The reference's greedy continuation.  One compiled forward at a
-    padded length: the model is causal, so what lies right of a position
-    does not reach it."""
-    f = _PADDED.get(id(weights))
-    if f is None:
-        f = _PADDED[id(weights)] = jax.jit(
-            lambda ids: ref.logits_fn(weights, ids, M))
-    out = list(prompt)
-    with jax.default_matmul_precision("highest"):
-        for _ in range(n):
-            ids = np.zeros((pad,), np.int32)
-            ids[:len(out)] = out
-            out.append(int(jnp.argmax(f(jnp.asarray(ids))[len(out) - 1])))
-    return out[len(prompt):]
-
-
 def _engine(params, slots=3, reg=None, **kw):
-    return ServingEngine(
-        cca_cfg(), params,
-        ServingConfig(**{**dict(max_slots=slots, page_size=PS, num_pages=40,
-                                max_prompt_len=16, max_new_tokens=6,
-                                prefill_batch=2), **kw}),
-        registry=reg or MetricsRegistry("cca"))
+    return lm_toy.engine(
+        cca_cfg(), params, reg or MetricsRegistry("cca"),
+        **{**dict(max_slots=slots, page_size=PS, num_pages=40,
+                  max_prompt_len=16, max_new_tokens=6, prefill_batch=2),
+           **kw})
 
 
 def test_engine_serves_the_reference_greedy_tokens(ref, weights, params):
@@ -328,7 +252,7 @@ def test_engine_serves_the_reference_greedy_tokens(ref, weights, params):
     eng.run_until_idle()
     got = {r.id: r.tokens for r in eng.results()}
     for rid, prompt, n in zip(ids, prompts, news):
-        assert got[rid] == _greedy(ref, weights, prompt, n)
+        assert got[rid] == lm_toy.greedy(ref, weights, M, prompt, n, PAD)
     cfg = eng.cfg
     assert eng.cache.k.shape == PA.kv_pool_shape(3, 2, 40, PS, 8)
     assert {n: v.shape for n, v in eng.cache.state.items()} == {
@@ -354,40 +278,28 @@ def test_a_reused_slot_starts_from_its_own_prefill(ref, weights, params):
     left = {n: np.asarray(v) for n, v in eng.cache.state.items()}
     assert all(np.abs(v).max() > 0 for v in left.values())
     second = eng.generate([[11, 3]], max_new_tokens=6)[0].tokens
-    assert first == _greedy(ref, weights, [5, 6, 7, 8, 9], 5)
-    assert second == _greedy(ref, weights, [11, 3], 6)
+    assert first == lm_toy.greedy(ref, weights, M, [5, 6, 7, 8, 9], 5, PAD)
+    assert second == lm_toy.greedy(ref, weights, M, [11, 3], 6, PAD)
 
 
 def test_spans_say_what_a_step_touched(params):
-    from paddle_tpu.telemetry import tracing
-
-    tracing.configure_tracing(enabled=True)
-    try:
-        tracing.get_tracer().clear()
-        eng = _engine(params, max_new_tokens=4, max_prompt_len=8)
-        eng.generate([[1, 2, 3], [4, 5]], max_new_tokens=3)
-        spans = [s for s in tracing.get_tracer().spans
-                 if s.name == "serve_decode"]
-        assert spans
-        for s in spans:
-            a = s.args
-            assert a["kv_heads"] == 2 and a["cache_layers"] == 3
-            assert a["state_layers"] == 3
-            assert a["state_slots"] == a["batch"] == 2
-            assert a["moe_assignments"] == 2 * 3      # top-1, three layers
-            assert 0 < a["experts_touched"] <= a["moe_assignments"]
-            assert 1 <= a["moe_load_max"] <= 2
-            assert a["moe_load_max_over_mean"] >= 1.0
-        pre = [s for s in tracing.get_tracer().spans
-               if s.name == "serve_prefill"][0]
-        assert pre.args["moe_assignments"] == 5 * 3
-        assert pre.args["moe_load_max"] >= 1
-        init = [s for s in tracing.get_tracer().spans
-                if s.name == "engine_init"][-1]
-        assert init.args["state_bytes"] == 3 * 3 * 4 * (48 + 48 + 8)
-    finally:
-        tracing.configure_tracing(enabled=False)
-        tracing.get_tracer().drain()
+    _, spans = lm_toy.traced(lambda: _engine(params).generate(
+        [[1, 2, 3], [4, 5]], max_new_tokens=3))
+    assert spans["serve_decode"]
+    for s in spans["serve_decode"]:
+        a = s.args
+        assert a["kv_heads"] == 2 and a["cache_layers"] == 3
+        assert a["state_layers"] == 3
+        assert a["state_slots"] == a["batch"] == 2
+        assert a["moe_assignments"] == 2 * 3      # top-1, three layers
+        assert 0 < a["experts_touched"] <= a["moe_assignments"]
+        assert 1 <= a["moe_load_max"] <= 2
+        assert a["moe_load_max_over_mean"] >= 1.0
+    pre = spans["serve_prefill"][0]
+    assert pre.args["moe_assignments"] == 5 * 3
+    assert pre.args["moe_load_max"] >= 1
+    assert spans["engine_init"][-1].args["state_bytes"] == (
+        3 * 3 * 4 * (48 + 48 + 8))
 
 
 def test_memory_report_counts_the_state_of_every_kind(params):
@@ -424,7 +336,7 @@ def test_bf16_passes_and_int8_weights_fail(ref):
     cfg = T.TransformerConfig(
         **{k: v for k, v in m.items() if k != "init"}, remat=False,
         dtype=jnp.bfloat16)
-    weights = ref.init_weights(m, 11, jnp.float32)
+    weights = lm_toy.draw(ref, m, 11)
     params = ref.program_tree(weights)
     low = lambda f: jax.tree.map(f, params)
     trees = {"bf16": low(lambda a: a.astype(jnp.bfloat16)),
@@ -435,11 +347,10 @@ def test_bf16_passes_and_int8_weights_fail(ref):
                for n in (9, 14, 4, 16, 11, 7)]
     mean, served = {}, {}
     for name, tree in trees.items():
-        eng = ServingEngine(
-            cfg, tree, ServingConfig(max_slots=4, page_size=PS, num_pages=80,
-                                     max_prompt_len=16, max_new_tokens=40,
-                                     prefill_batch=2),
-            registry=MetricsRegistry(name))
+        eng = lm_toy.engine(
+            cfg, tree, MetricsRegistry(name), max_slots=4, page_size=PS,
+            num_pages=80, max_prompt_len=16, max_new_tokens=40,
+            prefill_batch=2)
         served[name] = [(r.prompt, r.tokens)
                         for r in eng.generate(prompts, max_new_tokens=40)]
         gaps = ref.served_gaps(m, weights, served[name], 56)
@@ -518,7 +429,7 @@ def test_config_refuses_by_name(fields, err, said):
 
 def test_chunk_forward_refuses_cca_state(params):
     cfg = cca_cfg()
-    kc, vc, _ = _pools(cfg)
+    kc, vc, _ = lm_toy.pools(cfg, pages=40)
     with pytest.raises(NotImplementedError, match="state layers"):
         T.forward_prefill_chunk(
             cfg, params, jnp.zeros((1, 4), jnp.int32), jnp.zeros((1,), jnp.int32),
@@ -546,7 +457,7 @@ def test_default_fields_draw_the_parents_weights(fields):
     cfg = T.TransformerConfig(**{**base, **fields})
     assert (cfg.cca_taps, cfg.rope_fraction, cfg.moe_router_hidden,
             cfg.moe_renorm, cfg.residual_scale) == (None, 1.0, 0, True, False)
-    p = T.init_params(cfg, jax.random.key(0))
+    p = lm_toy.jitted(T.init_params, cfg)(jax.random.key(0))
     names = {k for b in (p["blocks"] if cfg.pattern else [p["blocks"]])
              for k in b}
     assert not {n for n in names
@@ -554,7 +465,7 @@ def test_default_fields_draw_the_parents_weights(fields):
                                  "router_n"))}
     if cfg.pattern and "*" in cfg.pattern and cfg.kv_heads % 2 == 0:
         on = dataclasses.replace(cfg, cca_taps=(2, 2), residual_scale=True)
-        q = T.init_params(on, jax.random.key(0))
+        q = lm_toy.jitted(T.init_params, on)(jax.random.key(0))
         i = cfg.pattern.index("*")
         np.testing.assert_array_equal(np.asarray(q["embed"]),
                                       np.asarray(p["embed"]))

@@ -183,8 +183,8 @@ def test_network_compare_reference_configs(pair):
             return values[out_name]
 
         out = np.asarray(fwd(params, x))
-        g = jax.grad(
-            lambda p: jnp.sum(jnp.cos(fwd(p, x))))(params)
+        g = jax.jit(jax.grad(
+            lambda p: jnp.sum(jnp.cos(fwd(p, x)))))(params)
         outs.append(out)
         grads.append({i: np.asarray(g[s.name])
                       for i, s in enumerate(specs)})

@@ -80,7 +80,7 @@ class TestCRF:
             return jnp.mean(crf_ops.crf_nll(
                 SequenceBatch(x, self.emis.length), labels, w))
 
-        gw, gx = jax.grad(loss, argnums=(0, 1))(jnp.asarray(self.w),
+        gw, gx = jax.jit(jax.grad(loss, argnums=(0, 1)))(jnp.asarray(self.w),
                                                 jnp.asarray(self.x))
         assert np.all(np.isfinite(np.asarray(gw)))
         assert np.all(np.isfinite(np.asarray(gx)))
@@ -135,7 +135,7 @@ class TestCTC:
                 lp, jnp.asarray(in_lens), jnp.asarray(labels),
                 jnp.asarray(lbl_lens), blank=0))
 
-        g_jax = np.asarray(jax.grad(loss_jax)(jnp.asarray(logits)))
+        g_jax = np.asarray(jax.jit(jax.grad(loss_jax))(jnp.asarray(logits)))
 
         lg_t = torch.tensor(logits, requires_grad=True)
         lp_t = F.log_softmax(lg_t, dim=-1).permute(1, 0, 2)

@@ -83,7 +83,7 @@ def test_linear_chain_crf_gradient(rng_np):
             {}, jax.random.key(0))
         return -jnp.mean(out["LogLikelihood"][0])
 
-    gt, ge = jax.grad(loss, argnums=(0, 1))(jnp.asarray(trans),
+    gt, ge = jax.jit(jax.grad(loss, argnums=(0, 1)))(jnp.asarray(trans),
                                             jnp.asarray(emission))
     eps = 1e-3
     for arr, g, idx in [(trans, gt, (1, 2)), (trans, gt, (4, 0)),

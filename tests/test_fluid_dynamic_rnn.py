@@ -88,7 +88,7 @@ def test_static_rnn_gradient_flows(rng_np):
             _run_op(op, env, rng, prog)
         return env[loss.name].reshape(())
 
-    g = jax.grad(loss_fn)(jnp.asarray(boot_np))
+    g = jax.jit(jax.grad(loss_fn))(jnp.asarray(boot_np))
     assert np.isfinite(np.asarray(g)).all()
     # finite differences
     eps = 1e-3

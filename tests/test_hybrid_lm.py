@@ -3,10 +3,10 @@ experts of which this device holds a share, attention with fewer K/V
 heads than query heads — each ONE mixer behind a pre-norm and a residual
 add (``TransformerConfig.pattern``), against the plain float32 reference
 ``benchmarks/references/nemotron_h.py`` on seeded random weights at a toy
-size: every mixer alone, the full forward, the chunked scan against the
-token-by-token recurrence, prefill + decode through the paged cache AND
-the recurrent-state pool, the shares of the experts adding up to the
-uncut layer, the engine under requests that join mid-flight.
+size: every mixer alone, the full forward, prefill + decode through the
+paged cache AND the recurrent-state pool, the engine under requests that
+join mid-flight.  (The scan alone: ``test_mamba2.py``; the experts'
+shares: ``test_moe_arrangement.py``.)
 
 Tolerances.  Program and reference both compute in float32 here (the CPU
 backend's dots are exact float32), so they differ by the order of
@@ -16,8 +16,6 @@ in the program's place moves them by 1e-2 or more
 from the one below it.
 """
 
-import dataclasses
-import importlib.util
 import os
 
 import jax
@@ -29,12 +27,14 @@ from paddle_tpu.models import transformer as T
 from paddle_tpu.ops import mamba2
 from paddle_tpu.ops.pallas import paged_attention as PA
 from paddle_tpu.parallel import moe
-from paddle_tpu.serving import ServingConfig, ServingEngine
+from paddle_tpu.serving import ServingConfig
 from paddle_tpu.telemetry import MetricsRegistry
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+import lm_toy
+from lm_toy import PS, REPO
+
 TOL = 2e-4
-PS = 4
+PAD = 32    # the reference's one compiled length: 30 positions, 22 served
 M = dict(vocab_size=97, num_layers=6, num_heads=4, kv_heads=2, head_dim=8,
          embed_dim=32, mlp_dim=24, max_seq_len=128, norm="rms",
          norm_eps=1e-5, positions="none", mlp="relu2", tie_embeddings=False,
@@ -44,39 +44,17 @@ M = dict(vocab_size=97, num_layers=6, num_heads=4, kv_heads=2, head_dim=8,
          mamba_chunk=8)
 
 
+# one serving shape for every engine of this file
+SERVING = dict(max_slots=2, page_size=PS, num_pages=24, max_prompt_len=16,
+               max_new_tokens=6, prefill_batch=2)
+
+
 def hybrid_cfg(**kw):
     return T.TransformerConfig(**{**M, "remat": False, **kw})
 
 
-@pytest.fixture(scope="module")
-def ref():
-    spec = importlib.util.spec_from_file_location(
-        "nemotron_h_reference",
-        os.path.join(REPO, "benchmarks", "references", "nemotron_h.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-@pytest.fixture(scope="module")
-def weights(ref):
-    return ref.init_weights(M, 11, jnp.float32)
-
-
-@pytest.fixture(scope="module")
-def params(ref, weights):
-    return ref.program_tree(weights)
-
-
-@pytest.fixture(scope="module")
-def seq():
-    return [int(t) for t in np.random.default_rng(5).integers(0, 97, 30)]
-
-
-@pytest.fixture(scope="module")
-def ref_logits(ref, weights, seq):
-    with jax.default_matmul_precision("highest"):
-        return np.asarray(ref.logits_fn(weights, jnp.asarray(seq), M))
+ref, weights, params, seq, ref_logits = lm_toy.fixtures(
+    "nemotron_h", M, 11, seq_len=30, pad=PAD)
 
 
 def _layer(params, kind):
@@ -92,23 +70,29 @@ def test_each_mixer_equals_the_reference(kind, ref, weights, params):
     cfg = hybrid_cfg()
     i, layer = _layer(params, kind)
     h = jax.random.normal(jax.random.key(3), (2, 21, 32))
-    want = np.stack([np.asarray(ref._MIXERS[ref.KINDS[kind]](
-        weights["layers"][i], h[b], M)) for b in range(2)])
-    if kind == "*":
-        q, k, v = T._qkv(cfg, h, layer, None)
-        got = T._attention(cfg, q, k, v, None).reshape(2, 21, -1) @ layer["wo"]
-    elif kind == "E":
-        got, _ = moe.moe_routed(layer, h, cfg.routed)
-    else:
-        got = T._mamba_mixer(
+    one = jax.jit(lambda l, x: ref._MIXERS[ref.KINDS[kind]](l, x, M))
+    want = np.stack([np.asarray(one(weights["layers"][i], h[b]))
+                     for b in range(2)])
+
+    def mixer(h, layer):
+        if kind == "*":
+            q, k, v = T._qkv(cfg, h, layer, None)
+            return T._attention(cfg, q, k, v, None).reshape(
+                2, 21, -1) @ layer["wo"]
+        if kind == "E":
+            return moe.moe_routed(layer, h, cfg.routed)[0]
+        return T._mamba_mixer(
             cfg, h, layer,
             lambda x, w, b: mamba2.conv_prefill(x, w, b)[0],
             lambda *a: mamba2.ssd_prefill(*a, chunk=8)[0])
+
+    got = jax.jit(mixer)(h, layer)
     np.testing.assert_allclose(np.asarray(got), want, atol=TOL, rtol=TOL)
 
 
 def test_forward_equals_the_reference(params, seq, ref_logits):
-    got = T.forward(hybrid_cfg(), params, jnp.asarray([seq]))[0]
+    got = lm_toy.jitted(T.forward, hybrid_cfg())(params,
+                                                 jnp.asarray([seq]))[0]
     np.testing.assert_allclose(np.asarray(got), ref_logits, atol=TOL,
                                rtol=TOL)
 
@@ -119,133 +103,12 @@ def test_bf16_would_fail(ref, weights, seq, ref_logits):
     cfg = hybrid_cfg(dtype=jnp.bfloat16)
     low = jax.tree.map(lambda a: a.astype(jnp.bfloat16),
                        ref.program_tree(weights))
-    got = T.forward(cfg, low, jnp.asarray([seq]))[0].astype(jnp.float32)
+    got = lm_toy.jitted(T.forward, cfg)(low, jnp.asarray([seq]))[0].astype(
+        jnp.float32)
     assert float(np.max(np.abs(np.asarray(got) - ref_logits))) > 50 * TOL
 
 
-# -- the scan --------------------------------------------------------------------
-
-
-def _ssd_inputs(t=21, b=3, h=4, p=8, g=2, n=16, seed=0):
-    ks = jax.random.split(jax.random.key(seed), 6)
-    return dict(
-        x=jax.random.normal(ks[0], (b, t, h, p)),
-        dt=jax.nn.softplus(jax.random.normal(ks[1], (b, t, h)) - 2.0),
-        a=-jnp.exp(jax.random.normal(ks[2], (h,))),
-        b=jax.random.normal(ks[3], (b, t, g, n)),
-        c=jax.random.normal(ks[4], (b, t, g, n)),
-        d=jax.random.normal(ks[5], (h,)))
-
-
-def _recurrence(i, upto=None):
-    """Token by token through ``ssd_step``: (ys [B, T, H, P], state)."""
-    bsz, t, h, p = i["x"].shape
-    state = jnp.zeros((bsz, h, p, i["b"].shape[-1]))
-    ys = []
-    for s in range(t):
-        dt = i["dt"][:, s]
-        if upto is not None:
-            dt = jnp.where((s < upto)[:, None], dt, 0.0)
-        y, state = mamba2.ssd_step(state, i["x"][:, s], dt, i["a"],
-                                   i["b"][:, s], i["c"][:, s], i["d"])
-        ys.append(y)
-    return jnp.stack(ys, 1), state
-
-
-@pytest.mark.parametrize("chunk", [4, 8, 32])
-def test_chunked_scan_equals_the_recurrence(chunk):
-    i = _ssd_inputs()
-    want_y, want_s = _recurrence(i)
-    y, s = mamba2.ssd_prefill(i["x"], i["dt"], i["a"], i["b"], i["c"], i["d"],
-                              chunk=chunk)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), atol=TOL,
-                               rtol=TOL)
-    np.testing.assert_allclose(np.asarray(s), np.asarray(want_s), atol=TOL,
-                               rtol=TOL)
-
-
-def test_a_padded_rows_state_is_the_state_at_its_last_valid_token():
-    """Rows of 21, 13 and 1 valid tokens in one padded pass: each row's
-    SSM and conv state are those of a pass over its valid tokens alone —
-    padding decays nothing, adds nothing, is no conv tap."""
-    i = _ssd_inputs()
-    lens = jnp.asarray([21, 13, 1])
-    _, s = mamba2.ssd_prefill(i["x"], i["dt"], i["a"], i["b"], i["c"], i["d"],
-                              seq_lens=lens, chunk=8)
-    xbc = jax.random.normal(jax.random.key(9), (3, 21, 10))
-    w, bias = jax.random.normal(jax.random.key(8), (4, 10)), jnp.ones((10,))
-    out, conv = mamba2.conv_prefill(xbc, w, bias, lens)
-    for r, n in enumerate([21, 13, 1]):
-        alone = {k: (v[r:r + 1, :n] if v.ndim > 1 else v)
-                 for k, v in i.items()}
-        _, want = mamba2.ssd_prefill(
-            alone["x"], alone["dt"], alone["a"], alone["b"], alone["c"],
-            alone["d"], chunk=8)
-        np.testing.assert_allclose(np.asarray(s[r]), np.asarray(want[0]),
-                                   atol=TOL, rtol=TOL)
-        out1, conv1 = mamba2.conv_prefill(xbc[r:r + 1, :n], w, bias)
-        np.testing.assert_array_equal(np.asarray(conv[r]),
-                                      np.asarray(conv1[0]))
-        np.testing.assert_allclose(np.asarray(out[r, :n]),
-                                   np.asarray(out1[0]), atol=1e-6)
-    # the one-token arrangement continues where the prefill stopped
-    state = jnp.zeros((1, 3, 10))
-    for t in range(5):
-        o, state = mamba2.conv_step(state, xbc[:1, t], w, bias)
-        np.testing.assert_allclose(np.asarray(o[0]), np.asarray(out[0, t]),
-                                   atol=1e-6)
-
-
-def test_a_scan_continues_from_a_state():
-    i = _ssd_inputs()
-    y, s = mamba2.ssd_prefill(i["x"], i["dt"], i["a"], i["b"], i["c"], i["d"],
-                              chunk=8)
-    cut = lambda v, sl: v[:, sl] if v.ndim > 1 else v
-    head = {k: cut(v, slice(0, 9)) for k, v in i.items()}
-    tail = {k: cut(v, slice(9, None)) for k, v in i.items()}
-    _, mid = mamba2.ssd_prefill(*[head[k] for k in "x dt a b c d".split()],
-                                chunk=8)
-    y2, s2 = mamba2.ssd_prefill(*[tail[k] for k in "x dt a b c d".split()],
-                                chunk=8, state=mid)
-    np.testing.assert_allclose(np.asarray(y2), np.asarray(y[:, 9:]),
-                               atol=TOL, rtol=TOL)
-    np.testing.assert_allclose(np.asarray(s2), np.asarray(s), atol=TOL,
-                               rtol=TOL)
-
-
 # -- the experts -------------------------------------------------------------------
-
-
-def test_the_shares_add_up(ref, weights, params):
-    """16 experts over two devices: the routed parts of shares [0, 8) and
-    [8, 16), with the shared expert counted once, are the uncut layer —
-    in the program and in the reference."""
-    m_all = {**M, "moe_held": [0, 16]}
-    w_all = ref.init_weights(m_all, 11, jnp.float32)
-    i = M["pattern"].index("E")
-    l_all = w_all["layers"][i]
-    h = jax.random.normal(jax.random.key(4), (19, 32))
-    with jax.default_matmul_precision("highest"):
-        whole = np.asarray(ref.moe_mixer(l_all, h, m_all))
-        shared = np.asarray(ref.moe_mixer(
-            {**l_all, "up": l_all["up"][:0], "down": l_all["down"][:0]}, h,
-            m_all, held=(0, 0)))
-    p_all = ref.program_tree(w_all)["blocks"][i]
-    parts = []
-    for lo, hi in ((0, 8), (8, 16)):
-        share = {**p_all, "w_in": p_all["w_in"][lo:hi],
-                 "w_out": p_all["w_out"][lo:hi]}
-        cfg = hybrid_cfg(moe_held=(lo, hi))
-        y, counts = moe.moe_routed(share, h, cfg.routed)
-        parts.append(np.asarray(y) - shared)
-        ref_part = ref.moe_mixer(
-            {**l_all, "up": l_all["up"][lo:hi], "down": l_all["down"][lo:hi]},
-            h, m_all, held=(lo, hi), shared=False)
-        np.testing.assert_allclose(parts[-1], np.asarray(ref_part), atol=TOL,
-                                   rtol=TOL)
-        assert int(counts[0]) + int(counts[1]) == 19 * 3
-    np.testing.assert_allclose(parts[0] + parts[1] + shared, whole, atol=TOL,
-                               rtol=TOL)
 
 
 @pytest.mark.parametrize("lens", [[15, 9, 1, 0], [3, 0, 2, 0],
@@ -260,12 +123,15 @@ def test_sorted_and_masked_arrangements_agree(lens, params, monkeypatch):
     _, layer = _layer(params, "E")
     h = jax.random.normal(jax.random.key(6), (4, 15, 32))
     live = jnp.arange(15)[None, :] < jnp.asarray(lens)[:, None]
+    # a closure a setting: the constants are read when it is traced
+    routed = lambda: jax.jit(
+        lambda: moe.moe_routed(layer, h, cfg.routed, live))()
     monkeypatch.setattr(moe, "DENSE_BUCKETS", ())
-    dense, c_dense = moe.moe_routed(layer, h, cfg.routed, live)
+    dense, c_dense = routed()
     monkeypatch.setattr(moe, "DENSE_BUCKETS", (8, 32))
-    bucketed, c_bucketed = moe.moe_routed(layer, h, cfg.routed, live)
+    bucketed, c_bucketed = routed()
     monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 8)
-    sorted_, c_sorted = moe.moe_routed(layer, h, cfg.routed, live)
+    sorted_, c_sorted = routed()
     for got, counts in ((bucketed, c_bucketed), (sorted_, c_sorted)):
         np.testing.assert_allclose(np.asarray(got)[np.asarray(live)],
                                    np.asarray(dense)[np.asarray(live)],
@@ -297,14 +163,6 @@ def test_the_correction_bias_chooses_and_does_not_weigh(params):
 # -- pages and state ------------------------------------------------------------
 
 
-def _pools(cfg, pages=40, slots=2):
-    kc, vc = PA.init_kv_pages(cfg.cache_layers, cfg.kv_heads, pages, PS,
-                              cfg.head_dim)
-    state = {n: jnp.zeros((cfg.state_layers, slots, *s))
-             for n, s in cfg.state_shapes.items()}
-    return kc, vc, state
-
-
 @pytest.mark.parametrize("attn_impl", ["reference", "kernel"])
 def test_pages_and_state_equal_the_reference_at_every_position(
         attn_impl, params, seq, ref_logits):
@@ -313,42 +171,17 @@ def test_pages_and_state_equal_the_reference_at_every_position(
     decode the rest token by token: every position's logits are the
     reference's full forward.  Query heads 4 over K/V heads 2, through
     the jnp route and the interpreted kernel."""
-    cfg = hybrid_cfg()
-    kc, vc, state = _pools(cfg)
-    p_len = 12
-    ids = np.zeros((2, 16), np.int32)
-    ids[0, :5] = seq[10:15]
-    ids[1, :p_len] = seq[:p_len]
-    lens = jnp.asarray([5, p_len])
-    logits, ks, vs, extras = T.forward_prefill(cfg, params, jnp.asarray(ids),
-                                               lens)
-    np.testing.assert_allclose(np.asarray(logits[1]), ref_logits[p_len - 1],
-                               atol=TOL, rtol=TOL)
+    ks, _, _ = lm_toy.walk_positions(hybrid_cfg(), params, seq, ref_logits, 12,
+                                     16, attn_impl, TOL)
     assert ks.shape == (2, 2, 16, 2, 8)    # cache layers x B x T x KV x Dh
-    table = jnp.asarray([[1, 2, 3, 4, 5, 6, 7, 8],
-                         [9, 10, 11, 12, 13, 14, 15, 16]], jnp.int32)
-    kc, vc = PA.write_prefill_kv(kc, vc, ks, vs, table, lens)
-    state = {n: extras["state"][n] for n in state}   # slot = row
-    # row 0 idles through the decode: its state must stay what it was
-    idle_state = {n: np.asarray(v[:, 0]) for n, v in state.items()}
-    for pos in range(p_len, len(seq)):
-        logits, kc, vc, extras = T.forward_decode(
-            cfg, params, jnp.asarray([0, seq[pos]]), jnp.asarray([0, pos]),
-            jnp.asarray([0, pos + 1]), table.at[0].set(0), kc, vc,
-            attn_impl=attn_impl, state=state)
-        state = extras["state"]
-        np.testing.assert_allclose(np.asarray(logits[1]), ref_logits[pos],
-                                   atol=TOL, rtol=TOL)
-    for n, v in state.items():
-        np.testing.assert_array_equal(np.asarray(v[:, 0]), idle_state[n])
 
 
 def test_prefill_state_is_the_references_state(ref, weights, params, seq):
     cfg = hybrid_cfg()
     ids = np.zeros((1, 16), np.int32)
     ids[0, :9] = seq[:9]
-    _, _, _, extras = T.forward_prefill(cfg, params, jnp.asarray(ids),
-                                        jnp.asarray([9]))
+    _, _, _, extras = lm_toy.jitted(T.forward_prefill, cfg)(
+        params, jnp.asarray(ids), jnp.asarray([9]))
     i = M["pattern"].index("M")    # the first layer: its input is the embedding
     x = weights["wte"][jnp.asarray(seq[:9])]
     h = ref._rms(x, weights["layers"][i]["g"], M["norm_eps"])
@@ -361,47 +194,7 @@ def test_prefill_state_is_the_references_state(ref, weights, params, seq):
                                np.asarray(conv), atol=TOL, rtol=TOL)
 
 
-@pytest.mark.parametrize("h, kv, d", [(4, 2, 8), (6, 3, 64), (32, 2, 128),
-                                      (3, 3, 64)])
-def test_decode_kernel_with_fewer_kv_heads(h, kv, d):
-    """The interpreted kernel against the jnp reference: rep query heads
-    of a K/V head on the query rows, with and without lane groups of
-    several heads, a padded last group, rep above and below 8."""
-    ks = jax.random.split(jax.random.key(h * d), 3)
-    b, pages, maxp = 3, 12, 3
-    shape = PA.kv_pool_shape(2, kv, pages, PS, d)
-    kc, vc = (jax.random.normal(k, shape) for k in ks[:2])
-    q = jax.random.normal(ks[2], (b, h, d))
-    table = jnp.asarray([[1, 2, 3], [4, 5, 0], [0, 0, 0]], jnp.int32)
-    lens = jnp.asarray([11, 6, 0])
-    for layer in (0, 1):
-        want = PA.ragged_paged_attention(q, kc, vc, layer, table, lens,
-                                         impl="reference", kv_heads=kv)
-        got = PA.ragged_paged_attention(q, kc, vc, layer, table, lens,
-                                        impl="kernel", kv_heads=kv)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   atol=2e-5, rtol=2e-5)
-    # and the reference is attention with each K/V head repeated
-    k = PA._gather_context(kc, 0, table, kv, d)[:1, :, :11]
-    v = PA._gather_context(vc, 0, table, kv, d)[:1, :, :11]
-    k, v = (jnp.repeat(x, h // kv, axis=1) for x in (k, v))
-    p = jax.nn.softmax(jnp.einsum("hd,hkd->hk", q[0], k[0]) * d ** -0.5, -1)
-    np.testing.assert_allclose(
-        np.asarray(PA.ragged_paged_attention(
-            q, kc, vc, 0, table, lens, impl="reference", kv_heads=kv)[0]),
-        np.asarray(jnp.einsum("hk,hkd->hd", p, v[0])), atol=2e-5, rtol=2e-5)
-
-
 # -- the engine ---------------------------------------------------------------------
-
-
-def _greedy(ref, weights, prompt, n):
-    out = list(prompt)
-    with jax.default_matmul_precision("highest"):
-        for _ in range(n):
-            out.append(int(jnp.argmax(
-                ref.logits_fn(weights, jnp.asarray(out), M)[-1])))
-    return out[len(prompt):]
 
 
 def test_engine_serves_the_reference_greedy_tokens(ref, weights, params):
@@ -411,11 +204,7 @@ def test_engine_serves_the_reference_greedy_tokens(ref, weights, params):
     row was written whole by its prefill and an idle row's state never
     moved."""
     reg = MetricsRegistry("hybrid")
-    eng = ServingEngine(
-        hybrid_cfg(), params,
-        ServingConfig(max_slots=2, page_size=PS, num_pages=24,
-                      max_prompt_len=16, max_new_tokens=6, prefill_batch=2),
-        registry=reg)
+    eng = lm_toy.engine(hybrid_cfg(), params, reg, **SERVING)
     rng = np.random.default_rng(2)
     prompts = [[int(t) for t in rng.integers(0, 97, n)]
                for n in (7, 12, 3, 16, 1)]
@@ -427,7 +216,7 @@ def test_engine_serves_the_reference_greedy_tokens(ref, weights, params):
     eng.run_until_idle()
     got = {r.id: r.tokens for r in eng.results()}
     for rid, prompt, n in zip(ids, prompts, news):
-        assert got[rid] == _greedy(ref, weights, prompt, n)
+        assert got[rid] == lm_toy.greedy(ref, weights, M, prompt, n, PAD)
     cfg = eng.cfg
     assert eng.cache.k.shape == PA.kv_pool_shape(2, 2, 24, PS, 8)
     assert eng.cache.state["ssm"].shape == (2, 2, 4, 8, 16)
@@ -446,32 +235,17 @@ def test_engine_serves_the_reference_greedy_tokens(ref, weights, params):
 
 
 def test_decode_span_says_what_the_step_touched(params):
-    from paddle_tpu.telemetry import tracing
-
-    tracing.configure_tracing(enabled=True)
-    try:
-        tracing.get_tracer().clear()
-        eng = ServingEngine(
-            hybrid_cfg(), params,
-            ServingConfig(max_slots=3, page_size=PS, num_pages=24,
-                          max_prompt_len=8, max_new_tokens=4,
-                          prefill_batch=2), registry=MetricsRegistry("s"))
-        eng.generate([[1, 2, 3], [4, 5]], max_new_tokens=3)
-        spans = [s for s in tracing.get_tracer().spans
-                 if s.name == "serve_decode"]
-        assert spans
-        for s in spans:
-            a = s.args
-            assert a["kv_heads"] == 2 and a["cache_layers"] == 2
-            assert a["state_slots"] == a["batch"] == 2
-            assert 0 < a["moe_assignments"] <= 2 * 3 * 2
-            assert 0 < a["experts_touched"] <= a["moe_assignments"]
-        pre = [s for s in tracing.get_tracer().spans
-               if s.name == "serve_prefill"][0]
-        assert pre.args["moe_assignments"] > 0
-    finally:
-        tracing.configure_tracing(enabled=False)
-        tracing.get_tracer().clear()
+    _, spans = lm_toy.traced(lambda: lm_toy.engine(
+        hybrid_cfg(), params, MetricsRegistry("s"), **SERVING).generate(
+        [[1, 2, 3], [4, 5]], max_new_tokens=3))
+    assert spans["serve_decode"]
+    for s in spans["serve_decode"]:
+        a = s.args
+        assert a["kv_heads"] == 2 and a["cache_layers"] == 2
+        assert a["state_slots"] == a["batch"] == 2
+        assert 0 < a["moe_assignments"] <= 2 * 3 * 2
+        assert 0 < a["experts_touched"] <= a["moe_assignments"]
+    assert spans["serve_prefill"][0].args["moe_assignments"] > 0
 
 
 def test_compiled_decode_updates_pages_and_state_in_place():
@@ -484,7 +258,7 @@ def test_compiled_decode_updates_pages_and_state_in_place():
     cfg = hybrid_cfg(mamba_state=128, mamba_head_dim=64)
     params = T.init_params(cfg, jax.random.key(0))
     slots = 16
-    kc, vc, state = _pools(cfg, pages=64, slots=slots)
+    kc, vc, state = lm_toy.pools(cfg, pages=64, slots=slots)
     pools = sum(a.size * a.dtype.itemsize
                 for a in (kc, vc, *state.values()))
     i32 = lambda *shape: jnp.ones(shape, jnp.int32)
@@ -507,9 +281,7 @@ def test_compiled_decode_updates_pages_and_state_in_place():
 def test_state_beside_incremental_prefill_raises_by_name(serving, named,
                                                          params):
     with pytest.raises(NotImplementedError) as e:
-        ServingEngine(hybrid_cfg(), params, ServingConfig(
-            max_slots=2, page_size=PS, num_pages=24, max_prompt_len=8,
-            max_new_tokens=4, **serving))
+        lm_toy.engine(hybrid_cfg(), params, **SERVING, **serving)
     assert named in str(e.value)
     with pytest.raises(NotImplementedError):
         T.forward_prefill_chunk(hybrid_cfg(), params, jnp.zeros((1, 4), int),
@@ -549,14 +321,11 @@ def test_pattern_without_state_serves_chunked_and_cached(ref):
     params = T.init_params(cfg, jax.random.key(1))
     prompts = [[3, 1, 4, 1, 5, 9, 2, 6, 5, 3], [3, 1, 4, 1, 5, 9, 2, 6, 8]]
     for serving in (dict(prefill_chunk_tokens=4), dict(prefix_cache=True)):
-        eng = ServingEngine(cfg, params, ServingConfig(
-            max_slots=2, page_size=PS, num_pages=24, max_prompt_len=12,
-            max_new_tokens=4, **serving), registry=MetricsRegistry("c"))
+        eng = lm_toy.engine(cfg, params, MetricsRegistry("c"), **SERVING,
+                            **serving)
         for prompt, res in zip(prompts, eng.generate(prompts, 3)):
-            full = prompt + res.tokens
-            logits = T.forward(cfg, params, jnp.asarray([full]))
-            assert res.tokens == [int(t) for t in jnp.argmax(
-                logits[0, len(prompt) - 1:-1], axis=-1)]
+            assert res.tokens == lm_toy.forward_argmax(
+                cfg, params, prompt, res.tokens, 16)
 
 
 def test_homogeneous_block_takes_kv_heads_and_routed_experts():
@@ -569,13 +338,11 @@ def test_homogeneous_block_takes_kv_heads_and_routed_experts():
         moe_experts=4, moe_router="sigmoid", moe_top_k=2)
     params = T.init_params(cfg, jax.random.key(2))
     assert params["blocks"]["wk"].shape == (2, 32, 8)
-    eng = ServingEngine(cfg, params, ServingConfig(
-        max_slots=2, page_size=PS, num_pages=16, max_prompt_len=8,
-        max_new_tokens=4), registry=MetricsRegistry("h"))
+    eng = lm_toy.engine(cfg, params, MetricsRegistry("h"), **SERVING)
     prompt = [5, 17, 3, 9]
     res = eng.generate([prompt], 4)[0]
-    logits = T.forward(cfg, params, jnp.asarray([prompt + res.tokens]))
-    assert res.tokens == [int(t) for t in jnp.argmax(logits[0, 3:-1], -1)]
+    assert res.tokens == lm_toy.forward_argmax(cfg, params, prompt,
+                                               res.tokens, 16)
 
 
 def test_defaults_are_still_the_gpt2_block():
@@ -596,13 +363,11 @@ def test_defaults_are_still_the_gpt2_block():
     assert sorted(p["blocks"]) == ["b_in", "b_out", "ln1_b", "ln1_g", "ln2_b",
                                    "ln2_g", "w_in", "w_out", "wk", "wo", "wq",
                                    "wv"]
-    eng = ServingEngine(cfg, p, ServingConfig(
-        max_slots=2, page_size=PS, num_pages=16, max_prompt_len=8,
-        max_new_tokens=4), registry=MetricsRegistry("d"))
+    eng = lm_toy.engine(cfg, p, MetricsRegistry("d"), **SERVING)
     assert eng.cache.state == {} and eng.cache.state_bytes_per_slot == 0
     res = eng.generate([[3, 7, 1]], 3)[0]
-    logits = T.forward(cfg, p, jnp.asarray([[3, 7, 1] + res.tokens]))
-    assert res.tokens == [int(t) for t in jnp.argmax(logits[0, 2:-1], -1)]
+    assert res.tokens == lm_toy.forward_argmax(cfg, p, [3, 7, 1], res.tokens,
+                                               16)
 
 
 def test_parameter_counts():
@@ -647,26 +412,7 @@ def test_serving_cli_serves_a_hybrid_stack(monkeypatch, capsys):
     """``python -m paddle_tpu.serving --random --model_json`` builds the
     pattern from the JSON's fields and serves the greedy tokens of the
     same seeded weights' full forward."""
-    import io
-    import json
-
-    from paddle_tpu.serving.__main__ import main
-
     parts = {k: v for k, v in M.items() if k not in (
         "vocab_size", "num_layers", "num_heads", "embed_dim", "mlp_dim",
         "max_seq_len")}
-    monkeypatch.setattr("sys.stdin", io.StringIO("5 17 3\n"))
-    assert main(["--random", "--vocab", "97", "--embed", "32", "--layers",
-                 "6", "--heads", "4", "--max_new_tokens", "4", "--seed", "7",
-                 "--model_json", json.dumps(parts)]) == 0
-    served = [int(t) for t in
-              capsys.readouterr().out.strip().split(":")[1].split()]
-    cfg = T.TransformerConfig(
-        vocab_size=97, num_layers=6, num_heads=4, embed_dim=32, mlp_dim=128,
-        max_seq_len=256, remat=False, **parts)
-    weights = T.init_params(cfg, jax.random.key(7))
-    out = [5, 17, 3]
-    for _ in range(4):
-        out.append(int(jnp.argmax(
-            T.forward(cfg, weights, jnp.asarray([out]))[0, -1])))
-    assert served == out[3:]
+    lm_toy.cli_serves_the_forward(monkeypatch, capsys, 97, 6, parts)

@@ -27,6 +27,7 @@ import paddle_tpu as paddle
 from paddle_tpu import metrics as metrics_mod
 from paddle_tpu.core import flags
 from paddle_tpu.telemetry import MemorySink, MetricsRegistry, introspect
+from paddle_tpu.telemetry.registry import SCHEMA
 from paddle_tpu.telemetry.tracing import (
     ProfileWindow,
     Tracer,
@@ -406,7 +407,7 @@ def test_profile_steps_window_emits_record(tmp_path):
     assert len(prof) == 1
     rec = prof[0]
     assert rec["start_step"] == 1 and rec["end_step"] == 3
-    assert rec["schema"] == "paddle_tpu.metrics/16"
+    assert rec["schema"] == SCHEMA
     assert rec["trace_dir"] == str(tmp_path / "prof")
     assert os.path.isdir(rec["trace_dir"])  # the device capture landed
     assert rec["spans"]["compute"]["count"] == 2  # the window's steps

@@ -186,14 +186,22 @@ def test_elastic_rank_death_updates_membership_and_notifies(tmp_path):
         "r = int(os.environ['PADDLE_TPU_TRAINER_ID'])\n"
         "path = os.environ['PADDLE_TPU_MEMBERSHIP']\n"
         "assert os.environ['PADDLE_TPU_RENDEZVOUS_EPOCH'] == '0'\n"
+        "deadline = time.monotonic() + 50\n"
         "if r == 1:\n"
+        # die once the survivor has ARMED its handler (children start with
+        # SIGUSR1 ignored: a notice that beats the handler is dropped, and
+        # the survivor then waited out its whole deadline)
+        "    log0 = os.path.join(os.path.dirname(path), 'rank0.log')\n"
+        "    while time.monotonic() < deadline and not (\n"
+        "            os.path.exists(log0) and 'ready' in open(log0).read()):\n"
+        "        time.sleep(0.02)\n"
         "    sys.exit(5)\n"
         "hit = []\n"
         "signal.signal(signal.SIGUSR1, lambda s, f: hit.append(s))\n"
         "print('ready', flush=True)\n"
-        "deadline = time.monotonic() + 60\n"
         "while not hit and time.monotonic() < deadline:\n"
         "    time.sleep(0.05)\n"
+        "assert hit, 'no SIGUSR1 inside the deadline'\n"
         "m = json.load(open(path))\n"
         "print('notified epoch', m['epoch'], 'ranks', m['ranks'],\n"
         "      flush=True)\n"

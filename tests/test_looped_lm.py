@@ -15,8 +15,6 @@ from the one below it.
 """
 
 import dataclasses
-import importlib.util
-import os
 
 import jax
 import jax.numpy as jnp
@@ -25,12 +23,15 @@ import pytest
 
 from paddle_tpu.models import transformer as T
 from paddle_tpu.ops.pallas import paged_attention as PA
-from paddle_tpu.serving import ServingConfig, ServingEngine
+from paddle_tpu.serving import ServingConfig
 from paddle_tpu.telemetry import MetricsRegistry
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+import lm_toy
+from lm_toy import PS
+
 TOL = 2e-4
-LAYERS, STEPS, PS = 3, 3, 4
+PAD = 24    # the reference's one compiled length: 23 positions, 19 served
+LAYERS, STEPS = 3, 3
 M = {"vocab_size": 96, "num_layers": LAYERS, "num_heads": 4, "head_dim": 12,
      "embed_dim": 32, "mlp_dim": 48, "norm_eps": 1e-6, "rope_theta": 1e6,
      "loop_steps": STEPS}
@@ -46,41 +47,12 @@ def looped_cfg(**kw):
     return T.TransformerConfig(**base)
 
 
-@pytest.fixture(scope="module")
-def ref():
-    spec = importlib.util.spec_from_file_location(
-        "ouro_reference",
-        os.path.join(REPO, "benchmarks", "references", "ouro.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+ref, weights, params, seq, ref_logits = lm_toy.fixtures(
+    "ouro", M, 2**31 + 7, seq_len=23, pad=PAD)
 
 
-@pytest.fixture(scope="module")
-def weights(ref):
-    return ref.init_weights(M, 2**31 + 7, jnp.float32)
-
-
-@pytest.fixture(scope="module")
-def params(ref, weights):
-    return ref.program_tree(weights)
-
-
-@pytest.fixture(scope="module")
-def seq():
-    return np.random.default_rng(5).integers(0, M["vocab_size"], 23).astype(
-        np.int32)
-
-
-@pytest.fixture(scope="module")
-def ref_logits(ref, weights, seq):
-    with jax.default_matmul_precision("highest"):
-        return np.asarray(ref.logits_fn(weights, jnp.asarray(seq), M))
-
-
-def _pools(cfg, pages=16):
-    return PA.init_kv_pages(cfg.cache_layers, cfg.num_heads, pages, PS,
-                            cfg.head_dim)
+def _pools(cfg):
+    return lm_toy.pools(cfg, pages=16)[:2]      # no state beside the pages
 
 
 def _table(n_tokens, maxp=8, first=1):
@@ -92,17 +64,18 @@ def _table(n_tokens, maxp=8, first=1):
 
 def _decode_rest(cfg, params, seq, start, pt, kc, vc):
     """Teacher-forced decode of seq[start:]; logits at those positions."""
-    out = []
+    out, decode = [], lm_toy.jitted(T.forward_decode, cfg)
     for p in range(start, len(seq)):
-        logits, kc, vc = T.forward_decode(
-            cfg, params, jnp.asarray(seq[p:p + 1]), jnp.asarray([p]),
+        logits, kc, vc = decode(
+            params, jnp.asarray(seq[p:p + 1]), jnp.asarray([p]),
             jnp.asarray([p + 1]), pt, kc, vc)
         out.append(np.asarray(logits)[0])
     return np.stack(out), kc, vc
 
 
 def test_forward_equals_the_reference(params, seq, ref_logits):
-    got = T.forward(looped_cfg(), params, jnp.asarray(seq)[None])
+    got = lm_toy.jitted(T.forward, looped_cfg())(params,
+                                                 jnp.asarray(seq)[None])
     np.testing.assert_allclose(np.asarray(got)[0], ref_logits, atol=TOL,
                                rtol=0)
 
@@ -126,9 +99,9 @@ def test_paged_cache_routes_equal_the_reference_at_every_position(
     kc, vc = _pools(cfg)
     pt = _table(len(seq))
     ids = jnp.asarray(seq[:p_len])[None]
+    prefill = lm_toy.jitted(T.forward_prefill, cfg)
     if route == "prefill":
-        logits, ks, vs = T.forward_prefill(cfg, params, ids,
-                                           jnp.asarray([p_len]))
+        logits, ks, vs = prefill(params, ids, jnp.asarray([p_len]))
         assert ks.shape == (STEPS * LAYERS, 1, p_len, 4, 12)
         kc, vc = PA.write_prefill_kv(kc, vc, ks, vs, pt, jnp.asarray([p_len]))
     else:
@@ -138,8 +111,7 @@ def test_paged_cache_routes_equal_the_reference_at_every_position(
             # another sequence's prefill left the first two pages (8
             # tokens) resident; this row maps them and computes the tail
             donor = _table(8)
-            _, ks, vs = T.forward_prefill(cfg, params, ids[:, :8],
-                                          jnp.asarray([8]))
+            _, ks, vs = prefill(params, ids[:, :8], jnp.asarray([8]))
             kc, vc = PA.write_prefill_kv(kc, vc, ks, vs, donor,
                                          jnp.asarray([8]))
             pt = jnp.asarray(np.concatenate(
@@ -148,8 +120,8 @@ def test_paged_cache_routes_equal_the_reference_at_every_position(
         for a, b in cuts:
             chunk = np.zeros((1, 5), np.int32)
             chunk[0, :b - a] = seq[a:b]
-            logits, kc, vc = T.forward_prefill_chunk(
-                cfg, params, jnp.asarray(chunk), jnp.asarray([a]),
+            logits, kc, vc = lm_toy.jitted(T.forward_prefill_chunk, cfg)(
+                params, jnp.asarray(chunk), jnp.asarray([a]),
                 jnp.asarray([b - a]), pt, kc, vc)
     np.testing.assert_allclose(np.asarray(logits)[0], ref_logits[p_len - 1],
                                atol=TOL, rtol=0)
@@ -160,7 +132,8 @@ def test_paged_cache_routes_equal_the_reference_at_every_position(
 def test_cache_layer_t_l_holds_pass_t_of_layer_l(ref, weights, params, seq):
     cfg = looped_cfg()
     ids = jnp.asarray(seq)[None]
-    _, ks, _ = T.forward_prefill(cfg, params, ids, jnp.asarray([len(seq)]))
+    prefill = lm_toy.jitted(T.forward_prefill, cfg)
+    _, ks, _ = prefill(params, ids, jnp.asarray([len(seq)]))
     # layer 0 of pass t reads the normed output of pass t-1 (the
     # embedding for pass 0): its K is the reference's, at row t * LAYERS
     with jax.default_matmul_precision("highest"):
@@ -179,8 +152,7 @@ def test_cache_layer_t_l_holds_pass_t_of_layer_l(ref, weights, params, seq):
     kc, vc = _pools(cfg)
     pt = _table(len(seq))
     p_len = len(seq) - 1
-    _, ks, vs = T.forward_prefill(cfg, params, ids[:, :p_len],
-                                  jnp.asarray([p_len]))
+    _, ks, vs = prefill(params, ids[:, :p_len], jnp.asarray([p_len]))
     kc, vc = PA.write_prefill_kv(kc, vc, ks, vs, pt, jnp.asarray([p_len]))
     hit = 1 * LAYERS + 1
     base, kc1, _ = _decode_rest(cfg, params, seq, p_len, pt, kc, vc)
@@ -260,10 +232,11 @@ def test_gradients_collect_every_pass(ref, weights, params, seq):
         tgt = jnp.take_along_axis(logits, ids[0, 1:, None], axis=-1)[:, 0]
         return jnp.mean(lse - tgt)
 
+    # the eager function differentiated, then compiled: one dispatch
     with jax.default_matmul_precision("highest"):
-        want_loss, want = jax.value_and_grad(ref_loss)(weights)
-    got_loss, got = jax.value_and_grad(
-        lambda p: T.loss_fn(cfg, p, ids))(params)
+        want_loss, want = jax.jit(jax.value_and_grad(ref_loss))(weights)
+    got_loss, got = jax.jit(jax.value_and_grad(
+        lambda p: T.loss_fn(cfg, p, ids)))(params)
     assert float(got_loss) == pytest.approx(float(want_loss), abs=1e-5)
     want = ref.program_tree(want)
     flat, _ = jax.tree_util.tree_flatten_with_path(got)
@@ -280,10 +253,10 @@ def test_gradients_collect_every_pass(ref, weights, params, seq):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    atol=1e-4 * scale, rtol=0, err_msg=name)
     # a one-pass stack has a third of the uses: the gradient differs
-    one = jax.grad(lambda p: T.loss_fn(
+    one = jax.jit(jax.grad(lambda p: T.loss_fn(
         dataclasses.replace(cfg, loop_steps=1),
         {k: v for k, v in p.items() if not k.startswith("exit_")},
-        ids))(params)
+        ids)))(params)
     assert float(jnp.abs(one["blocks"]["wq"] - got["blocks"]["wq"]).max()) > 1e-3
 
 
@@ -299,7 +272,7 @@ def test_gate_parameters_give_the_reference_exit_distribution(
     eye = dict(params, head=jnp.eye(M["embed_dim"]))
     lam = []
     for t in range(1, STEPS + 1):
-        h = T.forward(looped_cfg(loop_steps=t), eye, ids)[0]
+        h = lm_toy.jitted(T.forward, looped_cfg(loop_steps=t))(eye, ids)[0]
         lam.append(jax.nn.sigmoid(h @ params["exit_w"] + params["exit_b"]))
     lam = np.asarray(jnp.stack(lam))
     np.testing.assert_allclose(lam, np.asarray(lam_ref), atol=TOL, rtol=0)
@@ -341,41 +314,28 @@ def test_parameter_counts():
         shard, is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec))
 
 
-def _greedy(ref, weights, prompt, n):
-    out = list(prompt)
-    with jax.default_matmul_precision("highest"):
-        for _ in range(n):
-            logits = ref.logits_fn(weights, jnp.asarray(out, jnp.int32), M)
-            out.append(int(jnp.argmax(logits[-1])))
-    return out[len(prompt):]
-
-
 @pytest.mark.parametrize("mode", [
     dict(), dict(prefill_chunk_tokens=5), dict(prefix_cache=True),
     dict(prefix_cache=True, prefill_chunk_tokens=5)],
     ids=["plain", "chunked", "prefix", "prefix+chunked"])
 def test_engine_serves_the_reference_greedy_tokens(mode, ref, weights,
                                                    params, seq):
-    from paddle_tpu.telemetry import tracing
-
     cfg = looped_cfg()
     reg = MetricsRegistry("looped")
-    tracer = tracing.configure_tracing(enabled=True)
-    tracer.clear()
-    try:
-        eng = ServingEngine(cfg, params, ServingConfig(
-            max_slots=3, page_size=PS, num_pages=40, max_prompt_len=16,
-            max_new_tokens=6, prefill_batch=2, **mode), registry=reg)
-        assert eng.cache.k.shape == PA.kv_pool_shape(STEPS * LAYERS, 4, 40, PS, 12)
-        assert eng.cache.k.shape == (STEPS * LAYERS, 1, 40, PS, 48)
-        prompts = [seq[:13].tolist(), seq[3:12].tolist(), seq[:13].tolist()]
-        first = eng.generate(prompts[:2])
-        again = eng.generate(prompts[2:])     # a prefix hit when the cache is on
-        spans = [s for s in tracer.spans if s.name == "serve_decode"]
-    finally:
-        tracing.configure_tracing(enabled=False)
-        tracer.clear()
-    want = [_greedy(ref, weights, p, 6) for p in prompts[:2]]
+    prompts = [seq[:13].tolist(), seq[3:12].tolist(), seq[:13].tolist()]
+
+    def serve():
+        eng = lm_toy.engine(
+            cfg, params, reg, max_slots=3, page_size=PS, num_pages=40,
+            max_prompt_len=16, max_new_tokens=6, prefill_batch=2, **mode)
+        # the second call: a prefix hit when the cache is on
+        return eng, eng.generate(prompts[:2]), eng.generate(prompts[2:])
+
+    (eng, first, again), spans = lm_toy.traced(serve)
+    spans = spans["serve_decode"]
+    assert eng.cache.k.shape == PA.kv_pool_shape(STEPS * LAYERS, 4, 40, PS, 12)
+    assert eng.cache.k.shape == (STEPS * LAYERS, 1, 40, PS, 48)
+    want = [lm_toy.greedy(ref, weights, M, p, 6, PAD) for p in prompts[:2]]
     assert [r.tokens for r in first] == want
     assert again[0].tokens == want[0]
     if mode.get("prefix_cache"):
@@ -420,9 +380,9 @@ def test_engine_tokens_equal_full_forward_argmax(steps, mode):
     head = rng.integers(1, 64, 9).tolist()        # two full pages + 1
     prompts = [head + rng.integers(1, 64, 4).tolist(), head[:7],
                head + rng.integers(1, 64, 2).tolist()]
-    eng = ServingEngine(cfg, params, ServingConfig(
-        max_slots=3, page_size=PS, num_pages=40, max_prompt_len=16,
-        max_new_tokens=6, prefill_batch=2, **mode))
+    eng = lm_toy.engine(
+        cfg, params, max_slots=3, page_size=PS, num_pages=40,
+        max_prompt_len=16, max_new_tokens=6, prefill_batch=2, **mode)
     assert eng.cache.k.shape == (2 * steps, 2, 40, PS, 128)
     got = [r.tokens for r in eng.generate(prompts[:2], max_new_tokens=5)]
     eng.submit(prompts[2], max_new_tokens=5)
@@ -439,10 +399,8 @@ def test_engine_tokens_equal_full_forward_argmax(steps, mode):
     eng.run_until_idle()
     got.append(eng.results()[0].tokens)
     for prompt, tokens in zip(prompts, got):
-        full = prompt + tokens
-        logits = T.forward(cfg, params, jnp.asarray([full]))
-        assert tokens == [int(t) for t in jnp.argmax(
-            logits[0, len(prompt) - 1:-1], axis=-1)]
+        assert tokens == lm_toy.forward_argmax(cfg, params, prompt, tokens,
+                                               PAD)
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
@@ -497,27 +455,9 @@ def test_serving_cli_serves_a_looped_stack(monkeypatch, capsys):
     """``python -m paddle_tpu.serving --random --model_json`` builds the
     looped stack from the JSON's fields and serves the greedy tokens of
     the same seeded weights' full forward."""
-    import io
-    import json
-
-    from paddle_tpu.serving.__main__ import main
-
     parts = dict(norm="rms", norm_sandwich=True, positions="rotary",
                  mlp="swiglu", head_dim=12, tie_embeddings=False,
                  loop_steps=STEPS)
-    monkeypatch.setattr("sys.stdin", io.StringIO("5 17 3\n"))
-    assert main(["--random", "--vocab", "96", "--embed", "32", "--layers",
-                 str(LAYERS), "--heads", "4", "--max_new_tokens", "4",
-                 "--seed", "7", "--model_json", json.dumps(parts)]) == 0
-    served = [int(t) for t in
-              capsys.readouterr().out.strip().split(":")[1].split()]
-    cfg = T.TransformerConfig(
-        vocab_size=96, num_layers=LAYERS, num_heads=4, embed_dim=32,
-        mlp_dim=128, max_seq_len=256, remat=False, **parts)
+    cfg = lm_toy.cli_serves_the_forward(monkeypatch, capsys, 96, LAYERS,
+                                        parts)
     assert cfg.cache_layers == LAYERS * STEPS
-    weights = T.init_params(cfg, jax.random.key(7))
-    out = [5, 17, 3]
-    for _ in range(4):
-        logits = T.forward(cfg, weights, jnp.asarray(out)[None])
-        out.append(int(jnp.argmax(logits[0, -1])))
-    assert served == out[3:]

@@ -81,7 +81,7 @@ def test_sharded_gradients_equal_dense():
     placed = place_moe_params(params, mesh)
     xs = jax.device_put(x, NamedSharding(mesh, P("expert")))
     g_sh = jax.jit(jax.grad(loss_sharded))(placed, xs)
-    g_ref = jax.grad(loss_ref)(params, x)
+    g_ref = jax.jit(jax.grad(loss_ref))(params, x)
     for k in params:
         np.testing.assert_allclose(
             np.asarray(g_sh[k]), np.asarray(g_ref[k]), rtol=2e-4, atol=2e-5,
@@ -213,7 +213,7 @@ def test_moe_layer_trains():
             y, aux = moe_ffn(p, x, cfg)
             return jnp.mean((y - tgt) ** 2) + cfg.aux_loss_weight * aux
 
-        l, g = jax.value_and_grad(loss_fn)(p)
+        l, g = jax.jit(jax.value_and_grad(loss_fn))(p)
         return jax.tree.map(lambda w, gw: w - 0.1 * gw, p, g), l
 
     losses = []
@@ -248,8 +248,8 @@ def test_sort_dispatch_equals_einsum(top_k):
                                rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(float(aux_s), float(aux_e), rtol=1e-6)
 
-    g_e = jax.grad(loss(cfg_e))(params, x)
-    g_s = jax.grad(loss(cfg_s))(params, x)
+    g_e = jax.jit(jax.grad(loss(cfg_e)))(params, x)
+    g_s = jax.jit(jax.grad(loss(cfg_s)))(params, x)
     for k in params:
         np.testing.assert_allclose(np.asarray(g_s[k]), np.asarray(g_e[k]),
                                    rtol=2e-4, atol=2e-6, err_msg=k)

@@ -43,8 +43,8 @@ def test_flash_grads_match_exact(rng_np, causal):
     def loss_flash(q, k, v):
         return jnp.sum(flash_attention(q, k, v, causal, None, 32, 32) ** 2)
 
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    g_fl = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
+    g_fl = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
     for a, b_ in zip(g_fl, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=2e-4, atol=2e-4)
@@ -60,11 +60,12 @@ def test_flash_cross_attention_rectangular(rng_np):
     out = flash_attention(q, k, v, False, None, 32, 64)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
-    g_ref = jax.grad(lambda *a: jnp.sum(A.dot_product_attention(*a) ** 2),
-                     argnums=(0, 1, 2))(q, k, v)
-    g_fl = jax.grad(
+    g_ref = jax.jit(jax.grad(
+        lambda *a: jnp.sum(A.dot_product_attention(*a) ** 2),
+        argnums=(0, 1, 2)))(q, k, v)
+    g_fl = jax.jit(jax.grad(
         lambda *a: jnp.sum(flash_attention(*a, False, None, 32, 64) ** 2),
-        argnums=(0, 1, 2))(q, k, v)
+        argnums=(0, 1, 2)))(q, k, v)
     for a, b_ in zip(g_fl, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=2e-4, atol=2e-4)
@@ -107,10 +108,11 @@ def test_softmax_xent_matches_xla():
            - jnp.take_along_axis(logits, tgt[:, None], axis=-1)[:, 0])
     np.testing.assert_allclose(np.asarray(nll), np.asarray(ref), atol=1e-4)
 
-    g1 = jax.grad(lambda l: jnp.mean(softmax_xent(l, tgt, 32, 128)))(logits)
-    g2 = jax.grad(lambda l: jnp.mean(
+    g1 = jax.jit(jax.grad(
+        lambda l: jnp.mean(softmax_xent(l, tgt, 32, 128))))(logits)
+    g2 = jax.jit(jax.grad(lambda l: jnp.mean(
         jax.nn.logsumexp(l, axis=-1)
-        - jnp.take_along_axis(l, tgt[:, None], axis=-1)[:, 0]))(logits)
+        - jnp.take_along_axis(l, tgt[:, None], axis=-1)[:, 0])))(logits)
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), atol=1e-5)
 
 
@@ -127,12 +129,12 @@ def test_flash_matches_in_module_reference(rng_np):
         out = flash_attention(q, k, v, causal, None, 32, 32)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
-        g_r = jax.grad(lambda *a: jnp.sum(
+        g_r = jax.jit(jax.grad(lambda *a: jnp.sum(
             flash_attention_reference(*a, causal) ** 2),
-            argnums=(0, 1, 2))(q, k, v)
-        g_k = jax.grad(lambda *a: jnp.sum(
+            argnums=(0, 1, 2)))(q, k, v)
+        g_k = jax.jit(jax.grad(lambda *a: jnp.sum(
             flash_attention(*a, causal, None, 32, 32) ** 2),
-            argnums=(0, 1, 2))(q, k, v)
+            argnums=(0, 1, 2)))(q, k, v)
         for a, b_ in zip(g_k, g_r):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                        rtol=2e-4, atol=2e-4)
@@ -150,6 +152,8 @@ def test_softmax_xent_matches_in_module_reference():
     np.testing.assert_allclose(
         np.asarray(softmax_xent(logits, tgt, 32, 128)),
         np.asarray(softmax_xent_reference(logits, tgt)), atol=1e-4)
-    g1 = jax.grad(lambda l: jnp.mean(softmax_xent(l, tgt, 32, 128)))(logits)
-    g2 = jax.grad(lambda l: jnp.mean(softmax_xent_reference(l, tgt)))(logits)
+    g1 = jax.jit(jax.grad(
+        lambda l: jnp.mean(softmax_xent(l, tgt, 32, 128))))(logits)
+    g2 = jax.jit(jax.grad(
+        lambda l: jnp.mean(softmax_xent_reference(l, tgt))))(logits)
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), atol=1e-5)

@@ -53,8 +53,8 @@ def test_ctc_loss_fused_matches_reference_fwd_and_grad(ragged_ctc,
     lr = ctc_loss_fused_reference(inp, ilen, labels, llen, 0, normalize)
     np.testing.assert_allclose(np.asarray(lk), np.asarray(lr),
                                rtol=1e-5, atol=1e-5)
-    gk = jax.grad(k_loss)(inp)
-    gr = jax.grad(r_loss)(inp)
+    gk = jax.jit(jax.grad(k_loss))(inp)
+    gr = jax.jit(jax.grad(r_loss))(inp)
     np.testing.assert_allclose(np.asarray(gk), np.asarray(gr),
                                rtol=1e-4, atol=1e-5)
 
@@ -84,8 +84,8 @@ def test_ctc_fused_kernel_infeasible_pins_loss_and_zeroes_grad(rng_np):
     lk = ctc_loss_fused(lp, ilen, labels, llen, 0, impl="kernel",
                         interpret=True)
     assert float(lk[0]) == float(np.float32(-NEG_INF))
-    gk = jax.grad(lambda x: jnp.sum(ctc_loss_fused(
-        x, ilen, labels, llen, 0, impl="kernel", interpret=True)))(lp)
+    gk = jax.jit(jax.grad(lambda x: jnp.sum(ctc_loss_fused(
+        x, ilen, labels, llen, 0, impl="kernel", interpret=True))))(lp)
     assert np.array_equal(np.asarray(gk), np.zeros_like(np.asarray(gk)))
 
 
@@ -112,8 +112,8 @@ def test_ctc_scan_degenerate_inputs_regression(rng_np):
         jnp.asarray(rng_np.normal(size=(1, 4, V)).astype(np.float32)))
     loss = ctc_ops.ctc_loss(lp4, ilen, labels, llen, 0)
     assert float(loss[0]) == float(np.float32(-NEG_INF))  # pinned, finite
-    g = jax.grad(lambda x: jnp.sum(ctc_ops.ctc_loss(
-        x, ilen, labels, llen, 0)))(lp4)
+    g = jax.jit(jax.grad(lambda x: jnp.sum(ctc_ops.ctc_loss(
+        x, ilen, labels, llen, 0))))(lp4)
     assert np.array_equal(np.asarray(g), np.zeros_like(np.asarray(g)))
 
     # (c) T < 2L+1 but feasible (distinct labels skip blanks): finite
@@ -127,8 +127,8 @@ def test_ctc_scan_degenerate_inputs_regression(rng_np):
     assert np.isfinite(float(l_scan[0])) and float(l_scan[0]) < 1e29
     np.testing.assert_allclose(np.asarray(l_kern), np.asarray(l_scan),
                                rtol=1e-5, atol=1e-5)
-    g2 = jax.grad(lambda x: jnp.sum(ctc_ops.ctc_loss(
-        x, ilen, labels2, llen, 0)))(lp5)
+    g2 = jax.jit(jax.grad(lambda x: jnp.sum(ctc_ops.ctc_loss(
+        x, ilen, labels2, llen, 0))))(lp5)
     assert np.all(np.isfinite(np.asarray(g2)))
 
 
@@ -160,8 +160,10 @@ def test_ctc_fused_batch_blocking_covers_non_multiple_batches(rng_np):
         ilen = jnp.asarray(rng_np.integers(3, T + 1, size=(B,)), jnp.int32)
         labels = jnp.asarray(rng_np.integers(1, V, size=(B, L)), jnp.int32)
         llen = jnp.asarray(rng_np.integers(0, L + 1, size=(B,)), jnp.int32)
-        lk = ctc_loss_fused(lp, ilen, labels, llen, 0, impl="kernel",
-                            interpret=True)
-        lr = ctc_ops.ctc_loss(lp, ilen, labels, llen, 0)
+        # a batch size a program: each compiled once, not walked op by op
+        lk = jax.jit(lambda *a: ctc_loss_fused(
+            *a, 0, impl="kernel", interpret=True))(lp, ilen, labels, llen)
+        lr = jax.jit(lambda *a: ctc_ops.ctc_loss(*a, 0))(
+            lp, ilen, labels, llen)
         np.testing.assert_allclose(np.asarray(lk), np.asarray(lr),
                                    rtol=1e-5, atol=1e-5)

@@ -18,6 +18,12 @@ from paddle_tpu.core.lod import SequenceBatch
 from paddle_tpu.ops import rnn
 
 
+def _grad(loss, argnums, static=()):
+    """``jax.grad`` compiled (``static``: the positions of Python flags):
+    the eager tape dispatches every step's ops one by one."""
+    return jax.jit(jax.grad(loss, argnums=argnums), static_argnums=static)
+
+
 @pytest.fixture
 def ragged(rng_np):
     B, T, D = 4, 7, 8
@@ -50,8 +56,8 @@ def test_lstm_fused_matches_scan_with_peephole(rng_np, ragged):
         r = scan_loss(wh, peep, reverse)
         k = fused_loss(wh, peep, reverse)
         assert abs(float(r - k)) < 1e-5, (reverse, float(r), float(k))
-        gr = jax.grad(scan_loss, argnums=(0, 1))(wh, peep, reverse)
-        gk = jax.grad(fused_loss, argnums=(0, 1))(wh, peep, reverse)
+        gr = _grad(scan_loss, (0, 1), (2,))(wh, peep, reverse)
+        gk = _grad(fused_loss, (0, 1), (2,))(wh, peep, reverse)
         for a, b in zip(gr, gk):
             np.testing.assert_allclose(np.asarray(a),
                                        np.asarray(b).reshape(a.shape),
@@ -81,8 +87,8 @@ def test_lstm_fused_dxw_and_state_grads(rng_np, ragged):
         ys, last = rnn.lstm_fused(sb, wh, rnn.LSTMState(h=h0, c=c0))
         return jnp.sum(ys.data * jnp.asarray(mask)[:, :, None]) + jnp.sum(last.c)
 
-    gr = jax.grad(scan_loss, argnums=(0, 1, 2))(xw, init.h, init.c)
-    gk = jax.grad(fused_loss, argnums=(0, 1, 2))(xw, init.h, init.c)
+    gr = _grad(scan_loss, (0, 1, 2))(xw, init.h, init.c)
+    gk = _grad(fused_loss, (0, 1, 2))(xw, init.h, init.c)
     for a, b in zip(gr, gk):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-5, atol=2e-5)
@@ -113,8 +119,8 @@ def test_gru_fused_matches_scan(rng_np, ragged):
         r = scan_loss(wh, whc, xw, reverse)
         k = fused_loss(wh, whc, xw, reverse)
         assert abs(float(r - k)) < 1e-5
-        gr = jax.grad(scan_loss, argnums=(0, 1, 2))(wh, whc, xw, reverse)
-        gk = jax.grad(fused_loss, argnums=(0, 1, 2))(wh, whc, xw, reverse)
+        gr = _grad(scan_loss, (0, 1, 2), (3,))(wh, whc, xw, reverse)
+        gk = _grad(fused_loss, (0, 1, 2), (3,))(wh, whc, xw, reverse)
         for a, b in zip(gr, gk):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-5, atol=2e-5)
@@ -251,8 +257,8 @@ def test_lstm_seq_matches_reference_fwd_and_vjp(rng_np):
                                    rtol=2e-5, atol=2e-5)
         np.testing.assert_allclose(np.asarray(cT_k), np.asarray(cT_r),
                                    rtol=2e-5, atol=2e-5)
-        gk = jax.grad(k_loss, argnums=(0, 1, 2, 3, 4))(xw, wh, peep, h0, c0)
-        gr = jax.grad(r_loss, argnums=(0, 1, 2, 3, 4))(xw, wh, peep, h0, c0)
+        gk = _grad(k_loss, (0, 1, 2, 3, 4))(xw, wh, peep, h0, c0)
+        gr = _grad(r_loss, (0, 1, 2, 3, 4))(xw, wh, peep, h0, c0)
         for a, b in zip(gk, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-5, atol=2e-5)
@@ -290,8 +296,8 @@ def test_lstm_seq_fi_matches_reference_fwd_and_vjp(rng_np):
 
             args = (x, wx, b, wh, peep, h0, c0)
             assert abs(float(k_loss(*args) - r_loss(*args))) < 1e-4
-            gk = jax.grad(k_loss, argnums=tuple(range(7)))(*args)
-            gr = jax.grad(r_loss, argnums=tuple(range(7)))(*args)
+            gk = _grad(k_loss, tuple(range(7)))(*args)
+            gr = _grad(r_loss, tuple(range(7)))(*args)
             for a, bb in zip(gk, gr):
                 np.testing.assert_allclose(np.asarray(a), np.asarray(bb),
                                            rtol=3e-5, atol=3e-5)
@@ -362,8 +368,8 @@ def test_bilstm_seq_matches_reference_fwd_and_vjp(rng_np):
 
         args = (x, wxf, whf, wxb, whb)
         assert abs(float(k_loss(*args) - r_loss(*args))) < 1e-4
-        gk = jax.grad(k_loss, argnums=tuple(range(5)))(*args)
-        gr = jax.grad(r_loss, argnums=tuple(range(5)))(*args)
+        gk = _grad(k_loss, tuple(range(5)))(*args)
+        gr = _grad(r_loss, tuple(range(5)))(*args)
         for a, bb in zip(gk, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(bb),
                                        rtol=3e-5, atol=3e-5)
@@ -396,8 +402,8 @@ def test_gru_seq_fi_matches_reference_fwd_and_vjp(rng_np):
 
             args = (x, wx, b, wh, whc, h0)
             assert abs(float(k_loss(*args) - r_loss(*args))) < 1e-4
-            gk = jax.grad(k_loss, argnums=tuple(range(6)))(*args)
-            gr = jax.grad(r_loss, argnums=tuple(range(6)))(*args)
+            gk = _grad(k_loss, tuple(range(6)))(*args)
+            gr = _grad(r_loss, tuple(range(6)))(*args)
             for a, bb in zip(gk, gr):
                 np.testing.assert_allclose(np.asarray(a), np.asarray(bb),
                                            rtol=3e-5, atol=3e-5)
@@ -521,8 +527,8 @@ def test_gru_seq_matches_reference_fwd_and_vjp(rng_np):
             hs, hT = gru_seq_reference(xw, mask, wh, whc, h0, reverse)
             return jnp.sum(hs * mask[:, :, None]) + jnp.sum(hT)
 
-        gk = jax.grad(k_loss, argnums=(0, 1, 2, 3))(xw, wh, whc, h0)
-        gr = jax.grad(r_loss, argnums=(0, 1, 2, 3))(xw, wh, whc, h0)
+        gk = _grad(k_loss, (0, 1, 2, 3))(xw, wh, whc, h0)
+        gr = _grad(r_loss, (0, 1, 2, 3))(xw, wh, whc, h0)
         for a, b in zip(gk, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-5, atol=2e-5)
@@ -566,8 +572,8 @@ def test_bigru_seq_matches_reference_fwd_and_vjp(rng_np):
 
         args = (x, wxf, whf, whcf, wxb, whb, whcb)
         assert abs(float(k_loss(*args) - r_loss(*args))) < 1e-4
-        gk = jax.grad(k_loss, argnums=tuple(range(7)))(*args)
-        gr = jax.grad(r_loss, argnums=tuple(range(7)))(*args)
+        gk = _grad(k_loss, tuple(range(7)))(*args)
+        gr = _grad(r_loss, tuple(range(7)))(*args)
         for a, bb in zip(gk, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(bb),
                                        rtol=3e-5, atol=3e-5)
@@ -656,9 +662,9 @@ def test_lstm_seq_batch_blocked_matches_reference(rng_np):
             xw, mask, wh, peep, h0, c0, reverse)
         np.testing.assert_allclose(hs_k, hs_r, rtol=2e-5, atol=2e-5)
         np.testing.assert_allclose(cT_k, cT_r, rtol=2e-5, atol=2e-5)
-        gk = jax.grad(loss_k, argnums=(0, 1, 2, 3, 4))(
+        gk = _grad(loss_k, (0, 1, 2, 3, 4), (5, 6))(
             xw, wh, peep, h0, c0, reverse, remat)
-        gr = jax.grad(loss_r, argnums=(0, 1, 2, 3, 4))(
+        gr = _grad(loss_r, (0, 1, 2, 3, 4), (5,))(
             xw, wh, peep, h0, c0, reverse)
         for a, b in zip(gk, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -694,9 +700,9 @@ def test_gru_seq_batch_blocked_matches_reference(rng_np):
         hs_r, hT_r = gru_seq_reference(xw, mask, wh, whc, h0, reverse)
         np.testing.assert_allclose(hs_k, hs_r, rtol=2e-5, atol=2e-5)
         np.testing.assert_allclose(hT_k, hT_r, rtol=2e-5, atol=2e-5)
-        gk = jax.grad(loss_k, argnums=(0, 1, 2, 3))(
+        gk = _grad(loss_k, (0, 1, 2, 3), (4, 5))(
             xw, wh, whc, h0, reverse, remat)
-        gr = jax.grad(loss_r, argnums=(0, 1, 2, 3))(
+        gr = _grad(loss_r, (0, 1, 2, 3), (4,))(
             xw, wh, whc, h0, reverse)
         for a, b in zip(gk, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),

@@ -47,8 +47,8 @@ def test_pipeline_grads_match_sequential():
     def loss_seq(params):
         return jnp.sum(sequential(params, x) ** 2)
 
-    g_pipe = jax.grad(loss_pipe)(params)
-    g_seq = jax.grad(loss_seq)(params)
+    g_pipe = jax.jit(jax.grad(loss_pipe))(params)
+    g_seq = jax.jit(jax.grad(loss_seq))(params)
     for a, b in zip(g_pipe, g_seq):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 

@@ -16,6 +16,7 @@ from paddle_tpu.parallel.mesh import MeshContext, apply_remainder, make_mesh
 from paddle_tpu.reader.decorator import buffered, xmap_readers
 from paddle_tpu.reader.feeder import DataFeeder, _densify_ids, _densify_pairs
 from paddle_tpu.reader.prefetch import DevicePrefetcher, SynchronousFeeds
+from paddle_tpu.telemetry.registry import SCHEMA
 
 
 # -- trainer helpers ----------------------------------------------------------
@@ -400,7 +401,7 @@ def test_deferred_fence_bursts_and_schema2_fields():
     _, steps, events = _run_train(4, 2, n_samples=32, batch=8, passes=1)
     assert len(steps) == 4
     for r in steps:
-        assert r["schema"] == "paddle_tpu.metrics/16"
+        assert r["schema"] == SCHEMA
         assert "input_wait_ms" in r and "host_stall_ms" in r
         assert r["input_wait_ms"] >= 0.0 and r["host_stall_ms"] >= 0.0
     # with sync_period=4 the EndIterations arrive as one burst after the

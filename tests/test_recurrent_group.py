@@ -129,7 +129,8 @@ def test_seqtoseq_training_cost_and_grads():
         vals, _ = topo.forward(pvals, topo.init_states(), feed, is_train=False)
         return vals[cost.name]
 
-    loss, grads = jax.value_and_grad(loss_fn)(params.as_dict())
+    # compiled, as a trainer runs it: the eager tape walks both scans
+    loss, grads = jax.jit(jax.value_and_grad(loss_fn))(params.as_dict())
     assert np.isfinite(float(loss))
     # every trainable parameter gets a gradient signal somewhere
     flat = jax.tree.leaves(grads)
@@ -226,7 +227,9 @@ def test_scan_tail_sink_equivalence():
             v = vals[cost.name]
             return v if v.ndim == 0 else v.mean()
 
-        loss, grads = jax.value_and_grad(f)(params)
+        # both arrangements compiled (a fresh closure: the flag is read
+        # when it is traced)
+        loss, grads = jax.jit(jax.value_and_grad(f))(params)
         return loss, grads
 
     def seq(r):
@@ -308,7 +311,7 @@ def test_fused_logits_ce_equivalence():
                                params, topo.init_states(), feed)
             return vals[cost.name].mean()
 
-        loss, grads = jax.value_and_grad(f)(params)
+        loss, grads = jax.jit(jax.value_and_grad(f))(params)
         # reference: -log(softmax[y]) computed by hand
         w1 = params[[k for k in params if "fc_layer_0" in k and "w" in k
                      and "bias" not in k][0]]

@@ -88,8 +88,8 @@ class TestShardedEmbedding:
         def loss_dense(t):
             return jnp.sum(jnp.take(t, ids, axis=0) ** 2)
 
-        g1 = jax.grad(loss_sharded)(emb_par.shard_table(table, mesh))
-        g2 = jax.grad(loss_dense)(table)
+        g1 = jax.jit(jax.grad(loss_sharded))(emb_par.shard_table(table, mesh))
+        g2 = jax.jit(jax.grad(loss_dense))(table)
         np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), rtol=1e-5)
 
 
@@ -181,7 +181,7 @@ class TestShardedEmbeddingClass:
             def loss(t):
                 return jnp.sum(emb.lookup(t, ids) ** 2)
 
-            g = np.asarray(jax.grad(loss)(table))
+            g = np.asarray(jax.jit(jax.grad(loss))(table))
             # only the one valid id gets gradient; pad rows get none
             assert np.any(g[2] != 0)
             mask = np.ones(12, bool)
@@ -199,7 +199,7 @@ class TestShardedEmbeddingClass:
         def oracle(t):
             return jnp.sum(jnp.take(t, ids, axis=0) * ct)
 
-        g_dense = np.asarray(jax.grad(oracle)(dense))
+        g_dense = np.asarray(jax.jit(jax.grad(oracle))(dense))
         for path in ("gspmd", "shard_map"):
             emb = self._emb(path)
             table = emb.place(dense)
@@ -207,7 +207,7 @@ class TestShardedEmbeddingClass:
             def loss(t):
                 return jnp.sum(emb.lookup(t, ids) * ct)
 
-            g = np.asarray(jax.grad(loss)(table))
+            g = np.asarray(jax.jit(jax.grad(loss))(table))
             np.testing.assert_allclose(g[:10], g_dense, rtol=1e-5,
                                        atol=1e-6)
             np.testing.assert_array_equal(g[10:], 0.0)

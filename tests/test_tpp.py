@@ -73,8 +73,8 @@ def test_channel_stats_matches_reference_fwd_and_grad(rng_np):
         s, ss = tpp.channel_stats(x, "kernel", True)
         return jnp.sum(s * 0.5) + jnp.sum(ss * 0.25)
 
-    np.testing.assert_allclose(np.asarray(jax.grad(loss_r)(x)),
-                               np.asarray(jax.grad(loss_k)(x)),
+    np.testing.assert_allclose(np.asarray(jax.jit(jax.grad(loss_r))(x)),
+                               np.asarray(jax.jit(jax.grad(loss_k))(x)),
                                rtol=2e-5, atol=2e-5)
 
 
@@ -102,11 +102,11 @@ def test_conv2d_direct_matches_reference(rng_np, cfg):
     def loss(fn):
         return lambda x, w: jnp.sum(fn(x, w) ** 2)
 
-    gr = jax.grad(loss(lambda x, w: tpp.conv2d_direct_reference(
-        x, w, stride=s, padding=p)), argnums=(0, 1))(x, w)
-    gk = jax.grad(loss(lambda x, w: tpp.conv2d_direct(
+    gr = jax.jit(jax.grad(loss(lambda x, w: tpp.conv2d_direct_reference(
+        x, w, stride=s, padding=p)), argnums=(0, 1)))(x, w)
+    gk = jax.jit(jax.grad(loss(lambda x, w: tpp.conv2d_direct(
         x, w, stride=s, padding=p, impl="kernel", interpret=True)),
-        argnums=(0, 1))(x, w)
+        argnums=(0, 1)))(x, w)
     for a, b in zip(gr, gk):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-4)
@@ -142,8 +142,9 @@ def test_conv2d_bn_act_matches_reference(rng_np, is_train):
             return jnp.sum(y ** 2) + jnp.sum(nm) + 0.5 * jnp.sum(nv)
         return f
 
-    gr = jax.grad(loss("reference"), argnums=(0, 1, 2, 3))(x, w, ga, be)
-    gk = jax.grad(loss("kernel"), argnums=(0, 1, 2, 3))(x, w, ga, be)
+    grads = lambda impl: jax.jit(jax.grad(loss(impl), argnums=(0, 1, 2, 3)))
+    gr = grads("reference")(x, w, ga, be)
+    gk = grads("kernel")(x, w, ga, be)
     for a, b in zip(gr, gk):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-4)
@@ -402,8 +403,8 @@ def test_img_conv_bn_layer_matches_separate_layers(rng_np):
             return jnp.sum(v[name] ** 2)
         return f
 
-    gf = jax.grad(loss(topo_f, name_f, states_f))(shared)
-    gu = jax.grad(loss(topo_u, name_u, states_u))(shared)
+    gf = jax.jit(jax.grad(loss(topo_f, name_f, states_f)))(shared)
+    gu = jax.jit(jax.grad(loss(topo_u, name_u, states_u)))(shared)
     for k in gf:
         np.testing.assert_allclose(np.asarray(gf[k]), np.asarray(gu[k]),
                                    rtol=1e-5, atol=1e-5, err_msg=k)

@@ -195,8 +195,8 @@ def test_fused_embedding_lookup_fwd_and_vjp(rng_np, dtype, ids):
     def loss_dense(tbl):
         return jnp.sum(_dense_oracle(tbl, ids).astype(jnp.float32) ** 2)
 
-    gk = jax.grad(loss_fused)(table)
-    gr = jax.grad(loss_dense)(table)
+    gk = jax.jit(jax.grad(loss_fused))(table)
+    gr = jax.jit(jax.grad(loss_dense))(table)
     assert gk.dtype == table.dtype
     tol = dict(rtol=1e-5, atol=1e-6) if dtype == jnp.float32 else \
         dict(rtol=2e-2, atol=2e-2)
@@ -215,8 +215,8 @@ def test_fused_embedding_lookup_padding_idx(rng_np):
     got = fused_embedding_lookup(table, ids, 0, "kernel", True)
     np.testing.assert_array_equal(got, _dense_oracle(table, ids, 0))
 
-    g = jax.grad(lambda tbl: jnp.sum(
-        fused_embedding_lookup(tbl, ids, 0, "kernel", True)))(table)
+    g = jax.jit(jax.grad(lambda tbl: jnp.sum(
+        fused_embedding_lookup(tbl, ids, 0, "kernel", True))))(table)
     # the padding row receives NO gradient
     np.testing.assert_array_equal(np.asarray(g)[0], 0.0)
     np.testing.assert_array_equal(np.asarray(g)[3], 1.0)
@@ -232,7 +232,7 @@ def test_fused_embedding_lookup_2d_ids_under_jit(rng_np):
         out = fused_embedding_lookup(tbl, ids, None, "kernel", True)
         return jnp.sum(out ** 2)
 
-    got = jax.grad(f)(table)
-    ref = jax.grad(lambda tbl: jnp.sum(
-        _dense_oracle(tbl, ids) ** 2))(table)
+    got = jax.jit(jax.grad(f))(table)
+    ref = jax.jit(jax.grad(lambda tbl: jnp.sum(
+        _dense_oracle(tbl, ids) ** 2)))(table)
     np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-6)

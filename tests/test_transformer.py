@@ -27,13 +27,19 @@ def _cfg(**kw):
     return T.TransformerConfig(**base)
 
 
+def _forward(cfg):
+    """``T.forward`` under ``cfg``, compiled: eagerly the layer scan and
+    every op around it dispatch (and compile) one by one."""
+    return jax.jit(functools.partial(T.forward, cfg))
+
+
 def test_forward_shapes_and_loss():
     cfg = _cfg()
     params = T.init_params(cfg, jax.random.key(0))
     ids = jnp.asarray(np.random.default_rng(0).integers(0, 64, (2, 16)))
-    logits = T.forward(cfg, params, ids)
+    logits = _forward(cfg)(params, ids)
     assert logits.shape == (2, 16, 64)
-    loss = T.loss_fn(cfg, params, ids)
+    loss = jax.jit(functools.partial(T.loss_fn, cfg))(params, ids)
     assert np.isfinite(float(loss))
     assert float(loss) < 2 * np.log(64)
 
@@ -42,13 +48,12 @@ def test_attn_impls_agree():
     cfg = _cfg()
     params = T.init_params(cfg, jax.random.key(0))
     ids = jnp.asarray(np.random.default_rng(0).integers(0, 64, (2, 16)))
-    ref = T.forward(cfg, params, ids)
-    blk = T.forward(
-        dataclasses.replace(cfg, attn_impl="blockwise", attn_block_size=4),
-        params, ids,
-    )
+    ref = _forward(cfg)(params, ids)
+    blk = _forward(
+        dataclasses.replace(cfg, attn_impl="blockwise", attn_block_size=4))(
+        params, ids)
     np.testing.assert_allclose(np.asarray(blk), np.asarray(ref), atol=1e-4)
-    flash = T.forward(dataclasses.replace(cfg, attn_impl="flash"), params, ids)
+    flash = _forward(dataclasses.replace(cfg, attn_impl="flash"))(params, ids)
     np.testing.assert_allclose(np.asarray(flash), np.asarray(ref), atol=1e-4)
 
 
@@ -92,7 +97,7 @@ def test_sharded_train_step_dp_tp_sp():
     cfg1 = _cfg()
     params1 = T.init_params(cfg1, jax.random.key(0))
     ids1 = jnp.asarray(np.asarray(ids))
-    loss1 = float(T.loss_fn(cfg1, params1, ids1))
+    loss1 = float(jax.jit(functools.partial(T.loss_fn, cfg1))(params1, ids1))
     np.testing.assert_allclose(l0, loss1, atol=1e-3)
 
 
@@ -166,5 +171,5 @@ def test_sharded_forward_flash_dp_tp():
     ids = jnp.asarray(np.random.default_rng(0).integers(0, 64, (4, 16)))
     ids = jax.device_put(ids, NamedSharding(mesh, P("data", None)))
     logits = jax.jit(lambda p, i: T.forward(cfg, p, i, mesh=mesh))(params, ids)
-    ref = T.forward(_cfg(), params, jnp.asarray(np.asarray(ids)))
+    ref = _forward(_cfg())(params, jnp.asarray(np.asarray(ids)))
     np.testing.assert_allclose(np.asarray(logits), np.asarray(ref), atol=1e-4)
