@@ -33,6 +33,45 @@ from paddle_tpu.ops import attention as attn_ops
 
 # a layer pattern's characters -> the kind's name in ``params["blocks"]``
 _KINDS = {"*": "attn", "-": "mlp", "E": "moe", "M": "mamba", "K": "kda"}
+# the kinds the ROLLED pattern walk carries (``_keeps_unrolled``): their
+# pools ride a scan's carry and they hand nothing from layer to layer
+_ROLLED = frozenset("*-M")
+
+
+def _period(pattern: str) -> int:
+    """The smallest p such that ``pattern`` is its first p characters
+    repeated (its length where it repeats nothing)."""
+    n = len(pattern)
+    return next(p for p in range(1, n + 1)
+                if n % p == 0 and pattern == pattern[:p] * (n // p))
+
+
+def _keeps_unrolled(cfg) -> str | None:
+    """Why ``cfg``'s pattern walk stays unrolled — every layer written
+    into the program's text, ``params["blocks"]`` one tree a layer — or
+    None where it ROLLS: a pattern that is two or more repeats of its
+    smallest period runs as ``lax.scan`` over the repeats, the body
+    walking one period (``_run_pattern``).  The rule is the pattern's own
+    shape and what its layers keep; there is no switch."""
+    if cfg.pattern is None:
+        return "no layer pattern: the homogeneous stack has its own scan"
+    if _period(cfg.pattern) == len(cfg.pattern):
+        return "the pattern is not two or more repeats of a period"
+    kinds = sorted(set(cfg.pattern) - _ROLLED)
+    if kinds:
+        return (f"layers of kind {kinds}: a routed layer's counts and a "
+                "delta-rule layer's state pools do not ride the rolled "
+                "walk's carry yet")
+    if cfg.cca_taps is not None:
+        return ("cca_taps: a CCA layer's state beside its pages does not "
+                "ride the rolled walk's carry yet")
+    if cfg.moe_router_hidden:
+        return ("moe_router_hidden: the MLP router's state goes from layer "
+                "to layer across the period's end")
+    if cfg.block_len > 1:
+        return ("block_len > 1: the block pass walks its pools outside the "
+                "rolled walk's carry")
+    return None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -123,7 +162,9 @@ class TransformerConfig:
     # linear-attention mixer (KDA).  None = ``num_layers`` blocks of
     # (attention, MLP).
     # ``params["blocks"]`` is then a list of per-layer trees in pattern
-    # order, walked by the pattern.
+    # order, walked by the pattern — or, where the pattern repeats a
+    # period and the walk rolls over it (``pattern_roll``), one tree a
+    # position in the period, stacked over the repeats.
     pattern: str | None = None
     # the Mamba-2 mixer: ``mamba_heads`` heads of ``mamba_head_dim``,
     # ``mamba_state`` state columns, B/C in ``mamba_groups`` groups, a
@@ -181,6 +222,15 @@ class TransformerConfig:
     # a "*" layer's output gate: a * sigmoid(h W_g), elementwise over the
     # heads' outputs, h the layer's normed input
     attn_gate: bool = False
+    # four scalars of a block, each default what every program was: the
+    # token embedding times ``embed_multiplier``; softmax(q k^T x
+    # ``attn_scale``) (None = head_dim^-1/2); a pattern layer's residual
+    # add x + ``residual_multiplier`` x mixer(norm(x)); the logits divided
+    # by ``logits_divisor``
+    embed_multiplier: float = 1.0
+    attn_scale: float | None = None
+    residual_multiplier: float = 1.0
+    logits_divisor: float = 1.0
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -264,14 +314,21 @@ class TransformerConfig:
                           ("moe_router_hidden", self.moe_router_hidden),
                           ("residual_scale", self.residual_scale),
                           ("kda_heads", self.kda_heads),
-                          ("attn_gate", self.attn_gate)):
+                          ("attn_gate", self.attn_gate),
+                          ("residual_multiplier",
+                           self.residual_multiplier != 1.0)):
             if on and not walked:
                 raise NotImplementedError(
                     f"{field} without a layer pattern: the homogeneous "
                     "stack's scan carries neither a state a layer, nor the "
                     "router's state from layer to layer, nor a layer's "
-                    "residual scales, gate matrix or KDA mixer; only the "
-                    "pattern walk does")
+                    "residual scales or multiplier, gate matrix or KDA "
+                    "mixer; only the pattern walk does")
+        if self.attn_scale is not None and self.attn_impl in ("ring",
+                                                              "ulysses"):
+            raise NotImplementedError(
+                f"attn_scale under attn_impl {self.attn_impl!r}: the "
+                "sequence-parallel wrappers take no softmax scale")
         if self.cca_taps is not None:
             if len(self.cca_taps) != 2 or min(self.cca_taps) < 2:
                 raise ValueError(
@@ -311,6 +368,20 @@ class TransformerConfig:
         if self.pattern is not None:
             return self.pattern.count("*")
         return self.num_layers * self.loop_steps
+
+    @property
+    def pattern_roll(self) -> tuple:
+        """(period, repeats) of the pattern walk: the pattern's smallest
+        period and how often ``lax.scan`` repeats it, or (its length, 1)
+        where the walk stays unrolled (``_keeps_unrolled`` says why).
+        ``params["blocks"]`` is a list of ``period`` trees, one a
+        POSITION in the period, each leaf stacked ``[repeats, ...]``
+        where repeats > 1 (``lay_blocks``)."""
+        n = len(self.pattern or "")
+        if _keeps_unrolled(self) is not None:
+            return n, 1
+        p = _period(self.pattern)
+        return p, n // p
 
     @property
     def state_kinds(self) -> dict:
@@ -541,6 +612,29 @@ def _pattern_params(cfg: TransformerConfig, norm, zeros, norm_p) -> list:
             for c in cfg.pattern]
 
 
+def lay_blocks(cfg: TransformerConfig, layers: list) -> list:
+    """A pattern's per-LAYER trees (pattern order) as ``params["blocks"]``
+    holds them: themselves where the walk is unrolled; where it rolls
+    (``pattern_roll`` = (p, r)), ``p`` trees, position j's the layers j,
+    j + p, ... stacked ``[r, ...]`` — per POSITION, not per kind, so the
+    scan slices every stack along its leading axis and nothing else."""
+    p, r = cfg.pattern_roll
+    if r == 1:
+        return list(layers)
+    return [jax.tree.map(lambda *a: jnp.stack(a), *layers[j::p])
+            for j in range(p)]
+
+
+def layers_of(cfg: TransformerConfig, blocks: list) -> list:
+    """``lay_blocks``' inverse: ``params["blocks"]`` -> one tree a layer,
+    in pattern order."""
+    p, r = cfg.pattern_roll
+    if r == 1:
+        return list(blocks)
+    return [jax.tree.map(lambda a: a[i // p], blocks[i % p])
+            for i in range(p * r)]
+
+
 def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
     """Stacked-layer params: block weights have leading dim num_layers
     (under a layer ``pattern``: a list of per-layer trees,
@@ -567,7 +661,8 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
         params = {"embed": norm(v_sz, e) * (e ** -0.5)}
         if cfg.positions == "learned":
             params["pos_embed"] = norm(cfg.max_seq_len, e) * 0.02
-        params["blocks"] = _pattern_params(cfg, norm, zeros, norm_p)
+        params["blocks"] = lay_blocks(
+            cfg, _pattern_params(cfg, norm, zeros, norm_p))
         params.update(norm_p("ln_f"))
         if not cfg.tie_embeddings:
             params["head"] = norm(e, v_sz) * (e ** -0.5)
@@ -721,6 +816,8 @@ def _embed(cfg: TransformerConfig, params, ids, positions=None):
     positions (None under learned positions, which are added here).
     ``positions`` None = ids [B, T] sit at 0..T-1."""
     x = params["embed"][ids]
+    if cfg.embed_multiplier != 1.0:
+        x = x * cfg.embed_multiplier
     if cfg.positions == "learned":
         x = x + (params["pos_embed"][:ids.shape[1]][None]
                  if positions is None else params["pos_embed"][positions])
@@ -733,9 +830,11 @@ def _embed(cfg: TransformerConfig, params, ids, positions=None):
 
 def _head(cfg: TransformerConfig, params, x, out_dtype=None):
     w = params["embed"].T if cfg.tie_embeddings else params["head"]
-    if out_dtype is None:
-        return x @ w
-    return jnp.matmul(x, w, preferred_element_type=out_dtype)
+    logits = x @ w if out_dtype is None else jnp.matmul(
+        x, w, preferred_element_type=out_dtype)
+    if cfg.logits_divisor != 1.0:
+        logits = logits / cfg.logits_divisor
+    return logits
 
 
 def _attention(cfg: TransformerConfig, q, k, v, mesh):
@@ -758,7 +857,7 @@ def _attention(cfg: TransformerConfig, q, k, v, mesh):
     if cfg.attn_impl == "blockwise":
         return attn_ops.blockwise_attention(
             q, k, v, block_size=min(cfg.attn_block_size, q.shape[1]),
-            causal=True
+            causal=True, scale=cfg.attn_scale
         )
     # True = causal over tokens; an int > 1 = causal over blocks of it
     causal = True if cfg.block_len == 1 else cfg.block_len
@@ -767,7 +866,7 @@ def _attention(cfg: TransformerConfig, q, k, v, mesh):
 
         bs = cfg.attn_block_size
         if mesh is None:
-            return flash_attention(q, k, v, causal, None, bs, bs)
+            return flash_attention(q, k, v, causal, cfg.attn_scale, bs, bs)
         # pallas_call has no GSPMD partitioning rule — run the kernel
         # per-device under shard_map (batch over data, heads over model;
         # sequence sharding needs attn_impl="ring" or "ulysses" instead)
@@ -784,7 +883,8 @@ def _attention(cfg: TransformerConfig, q, k, v, mesh):
             None,
         )
         fn = shard_map(
-            lambda q, k, v: flash_attention(q, k, v, causal, None, bs, bs),
+            lambda q, k, v: flash_attention(q, k, v, causal, cfg.attn_scale,
+                                            bs, bs),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False,
         )
@@ -795,7 +895,8 @@ def _attention(cfg: TransformerConfig, q, k, v, mesh):
         mask = (blk[:, None] >= blk[None, :])[None, None]
     else:
         mask = attn_ops.causal_mask(t, t)
-    return attn_ops.dot_product_attention(q, k, v, mask=mask)
+    return attn_ops.dot_product_attention(q, k, v, mask=mask,
+                                          scale=cfg.attn_scale)
 
 
 def _qkv(cfg: TransformerConfig, h, layer, rope):
@@ -1045,35 +1146,90 @@ def _pattern_layer(cfg: TransformerConfig, kind, layer, x, rope, attend,
             y = y + bias
         if kind == "moe":
             counts = aux
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
     if cfg.residual_scale:
         return ((x * layer["res_x_g"] + layer["res_x_b"])
                 + (y * layer["res_y_g"] + layer["res_y_b"])), counts, carry
     return x + y, counts, carry
 
 
-def _run_pattern(cfg: TransformerConfig, params, x, layer_fn):
+def _run_pattern(cfg: TransformerConfig, params, x, layer_fn, held=None):
     """The stack of a layer ``pattern``, the final norm closing it.
-    ``params["blocks"]`` is the list of the layers' own trees, so nothing
-    is sliced out of a stack, statically or dynamically.
-    ``layer_fn(kind, i, layer, x, carry) -> (x, counts, carry)`` is the
-    caller's arrangement of ``_pattern_layer`` for the ``i``-th layer of
-    its kind (an attention layer's ``i`` is its cache layer); ``carry`` is
-    the MLP router's state, walked beside x from layer to layer (None
-    without one: nothing is carried).  Returns (x, counts): the routed
-    layers' ``moe_routed`` counts summed (the busiest expert's tokens:
-    the largest), None without routed layers."""
+    ``layer_fn(kind, i, layer, x, carry, held) -> (x, counts, carry, held,
+    left)`` is the caller's arrangement of ``_pattern_layer`` for the
+    ``i``-th layer of its kind (an attention layer's ``i`` is its cache
+    layer, a state layer's its row of the state pools); ``carry`` is the
+    MLP router's state, walked beside x from layer to layer (None without
+    one: nothing is carried); ``held`` is what the caller carries WHOLE
+    through the walk and every layer may replace (the K/V pools, the
+    state pools: None where there are none) and ``left`` {name: tree}
+    what the layer leaves behind (a prefill's K/V and state; None or
+    empty: nothing).
+
+    Where the walk is unrolled, ``params["blocks"]`` is the list of the
+    layers' own trees and ``i`` a Python number: nothing is sliced out of
+    a stack, statically or dynamically.  Where it ROLLS
+    (``TransformerConfig.pattern_roll`` = (p, r), r > 1) one ``lax.scan``
+    runs the r repeats and its body walks the p positions of the period:
+    position j's tree is ``params["blocks"][j]`` sliced along its leading
+    axis by the scan, x and ``held`` are the scan's carry, and ``i`` =
+    repeat x (layers of the kind a period) + the layer's offset among
+    them, traced.
+
+    Returns (x, counts, held, left): the routed layers' ``moe_routed``
+    counts summed (the busiest expert's tokens: the largest; None without
+    routed layers), and every name's leavings stacked over its layers in
+    pool order ``[layers that leave it, ...]``."""
+    p, r = cfg.pattern_roll
     seen = dict.fromkeys(_KINDS.values(), 0)
-    counts = None
-    carry = jnp.zeros((*x.shape[:-1], cfg.moe_router_hidden), jnp.float32) \
-        if cfg.moe_router_hidden else None
-    for c, layer in zip(cfg.pattern, params["blocks"]):
-        kind = _KINDS[c]
-        x, aux, carry = layer_fn(kind, seen[kind], layer, x, carry)
-        seen[kind] += 1     # the layer's index among its kind
-        if aux is not None:
-            counts = aux if counts is None else jnp.concatenate(
-                [counts[:3] + aux[:3], jnp.maximum(counts[3:], aux[3:])])
-    return _norm(cfg, x, params, "ln_f"), counts
+    counts, left = None, {}
+
+    def leave(kept):
+        for name, v in (kept or {}).items():
+            left.setdefault(name, []).append(v)
+
+    if r > 1:
+        # layers of each kind a period: what a repeat advances ``i`` by
+        per = {k: cfg.pattern[:p].count(c) for c, k in _KINDS.items()}
+
+        def period(walked, xs):
+            x, held = walked
+            layers, rep = xs
+            at, lefts = dict(seen), []
+            for c, layer in zip(cfg.pattern[:p], layers):
+                kind = _KINDS[c]
+                x, _, _, held, kept = layer_fn(
+                    kind, rep * per[kind] + at[kind], layer, x, None, held)
+                at[kind] += 1
+                lefts.append(kept)
+            return (x, held), lefts
+
+        (x, held), lefts = lax.scan(
+            period, (x, held), (params["blocks"], jnp.arange(r)))
+        for kept in lefts:
+            leave(kept)
+        # a position's leavings came out stacked [r, ...]: repeat-major
+        # over the positions that leave the name is pool order
+        pooled = lambda *a: jnp.stack(a, 1).reshape(-1, *a[0].shape[1:])
+    else:
+        carry = jnp.zeros((*x.shape[:-1], cfg.moe_router_hidden),
+                          jnp.float32) if cfg.moe_router_hidden else None
+        for c, layer in zip(cfg.pattern, params["blocks"]):
+            kind = _KINDS[c]
+            x, aux, carry, held, kept = layer_fn(kind, seen[kind], layer, x,
+                                                 carry, held)
+            seen[kind] += 1     # the layer's index among its kind
+            leave(kept)
+            if aux is not None:
+                counts = aux if counts is None else jnp.concatenate(
+                    [counts[:3] + aux[:3], jnp.maximum(counts[3:], aux[3:])])
+        pooled = lambda *a: jnp.stack(a)
+    x = _norm(cfg, x, params, "ln_f")
+    # K/V first, then the state parts in the cache's order
+    left = {name: jax.tree.map(pooled, *left[name])
+            for name in ("kv", *cfg.state_parts) if name in left}
+    return x, counts, held, left
 
 
 def _run_stack(cfg: TransformerConfig, params, x, layer_fn, pools=None,
@@ -1267,21 +1423,14 @@ def _prefill_pattern(cfg: TransformerConfig, params, x, rope, seq_lens, mesh):
     (x, (ks, vs) [cache_layers, B, T, KV, Dh] or (None, None), extras):
     ``extras["state"]`` = {part: [layers that keep it, B, ...]}, each
     row's state at its last valid token."""
-    kept = {}
-
-    def layer_fn(kind, i, layer, x, carry):
+    def layer_fn(kind, i, layer, x, carry, held):
         x, left, counts, carry = _prefill_layer(cfg, kind, mesh, layer, x,
                                                 rope, seq_lens, carry)
-        for name, v in left.items():
-            kept.setdefault(name, []).append(v)
-        return x, counts, carry
+        return x, counts, carry, held, left
 
-    x, counts = _run_pattern(cfg, params, x, layer_fn)
-    kv = kept.pop("kv", [])
-    ks, vs = ((jnp.stack([k for k, _ in kv]), jnp.stack([v for _, v in kv]))
-              if kv else (None, None))
-    return x, (ks, vs), {
-        "state": {n: jnp.stack(kept[n]) for n in cfg.state_parts},
+    x, counts, _, left = _run_pattern(cfg, params, x, layer_fn)
+    return x, left.get("kv", (None, None)), {
+        "state": {n: left[n] for n in cfg.state_parts},
         "moe_counts": counts}
 
 
@@ -1318,20 +1467,25 @@ def forward_prefill_chunk(cfg: TransformerConfig, params: dict,
                 "have to start from its slot's recurrent state and leave "
                 "it behind, and a prefix-cache hit needs a snapshot of the "
                 "state at the shared prefix's last token; neither is built")
-        pools = [k_cache, v_cache]
-
-        def attend_chunk(i, q, k, v):
-            pools[:] = pa.write_chunk_kv(*pools, k, v, i, page_table, starts,
-                                         seq_lens)
-            return pa.paged_prefill_attention(
-                q, *pools, i, page_table, starts, seq_lens,
-                kv_heads=cfg.kv_heads)
-
         live = jnp.arange(c)[None, :] < seq_lens[:, None]
-        x, counts = _run_pattern(
-            cfg, params, x, lambda kind, i, layer, x, carry: _pattern_layer(
-                cfg, kind, layer, x, rope,
-                functools.partial(attend_chunk, i), None, live, carry=carry))
+
+        def layer_fn(kind, i, layer, x, carry, pools):
+            pools = list(pools)
+
+            def attend_chunk(q, k, v):
+                pools[:] = pa.write_chunk_kv(*pools, k, v, i, page_table,
+                                             starts, seq_lens)
+                return pa.paged_prefill_attention(
+                    q, *pools, i, page_table, starts, seq_lens,
+                    scale=cfg.attn_scale, kv_heads=cfg.kv_heads)
+
+            x, counts, carry = _pattern_layer(
+                cfg, kind, layer, x, rope, attend_chunk, None, live,
+                carry=carry)
+            return x, counts, carry, tuple(pools), None
+
+        x, counts, pools, _ = _run_pattern(cfg, params, x, layer_fn,
+                                           (k_cache, v_cache))
         return (_head(cfg, params, _last_valid(x, seq_lens)), *pools,
                 {"state": {}, "moe_counts": counts})
 
@@ -1341,7 +1495,7 @@ def forward_prefill_chunk(cfg: TransformerConfig, params: dict,
                                       starts, seq_lens)
             return pa.paged_prefill_attention(
                 q, *pools, cache_layer, page_table, starts, seq_lens,
-                kv_heads=cfg.kv_heads), pools
+                scale=cfg.attn_scale, kv_heads=cfg.kv_heads), pools
 
         x, _, pools = _block(cfg, x, layer, attend, rope)
         return x, pools
@@ -1390,7 +1544,8 @@ def forward_decode(cfg: TransformerConfig, params: dict, ids: jax.Array,
                                        positions, plan)
             return pa.ragged_paged_attention(
                 q, *pools, cache_layer, page_table, seq_lens,
-                impl=attn_impl, kv_heads=cfg.kv_heads, plan=plan), pools
+                scale=cfg.attn_scale, impl=attn_impl, kv_heads=cfg.kv_heads,
+                plan=plan), pools
 
         x, _, pools = _block(cfg, x, layer, attend, rope)
         return x, pools
@@ -1434,21 +1589,24 @@ def forward_decode_block(cfg: TransformerConfig, params: dict,
         pools = pa.write_chunk_kv(kc, vc, k, v, cache_layer, page_table,
                                   starts, new)
         return pa.block_paged_attention(
-            q, *pools, cache_layer, page_table, seq_lens, impl=attn_impl,
-            kv_heads=cfg.kv_heads), pools
+            q, *pools, cache_layer, page_table, seq_lens,
+            scale=cfg.attn_scale, impl=attn_impl, kv_heads=cfg.kv_heads), pools
 
     if cfg.pattern is not None:
-        pools = [k_cache, v_cache]
+        def layer_fn(kind, i, layer, x, carry, pools):
+            pools = list(pools)
 
-        def attend_layer(i, q, k, v):
-            a, pools[:] = attend(i, *pools, q, k, v)
-            return a
+            def attend_layer(q, k, v):
+                a, pools[:] = attend(i, *pools, q, k, v)
+                return a
 
-        x, counts = _run_pattern(
-            cfg, params, x, lambda kind, i, layer, x, carry: _pattern_layer(
-                cfg, kind, layer, x, rope,
-                functools.partial(attend_layer, i), None,
-                jnp.broadcast_to(live[:, None], ids.shape), carry=carry))
+            x, counts, carry = _pattern_layer(
+                cfg, kind, layer, x, rope, attend_layer, None,
+                jnp.broadcast_to(live[:, None], ids.shape), carry=carry)
+            return x, counts, carry, tuple(pools), None
+
+        x, counts, pools, _ = _run_pattern(cfg, params, x, layer_fn,
+                                           (k_cache, v_cache))
         return (_head(cfg, params, x, jnp.float32), *pools,
                 {"state": {}, "moe_counts": counts})
 
@@ -1466,63 +1624,66 @@ def forward_decode_block(cfg: TransformerConfig, params: dict,
 def _decode_pattern(cfg: TransformerConfig, params, x, rope, positions,
                     seq_lens, page_table, k_cache, v_cache, attn_impl, state):
     """One token per row x [B, E] through a layer pattern.  Every pool —
-    K, V and the state parts — is rebound as it is updated, at a static
-    layer index: the buffers that entered the program are written where
-    they are."""
+    K, V and the state parts — is carried through the walk and rebound as
+    a layer updates it at its own index (a Python number in the unrolled
+    walk, traced in the rolled one): the buffers that entered the program
+    are written where they are."""
     from paddle_tpu.ops import cca, kda, mamba2
     from paddle_tpu.ops.pallas import paged_attention as pa
 
-    pools = [k_cache, v_cache]
-    state = dict(state or {})
     live = seq_lens > 0
 
     plan = pa.decode_plan(k_cache, page_table, positions, seq_lens,
                           cfg.kv_heads, cfg.head_dim)
-
-    def attend(i, q, k, v):
-        pools[:] = pa.write_decode_kv(*pools, k, v, i, page_table, positions,
-                                      plan)
-        return pa.ragged_paged_attention(q, *pools, i, page_table, seq_lens,
-                                         impl=attn_impl,
-                                         kv_heads=cfg.kv_heads, plan=plan)
-
-    def keep(name, i, new):
-        """Layer i's rows of pool ``name`` <- ``new`` on live rows."""
-        old = state[name][i]
-        mask = live.reshape(-1, *[1] * (old.ndim - 1))
-        state[name] = state[name].at[i].set(
-            jnp.where(mask, new.astype(old.dtype), old))
-
-    def recurrent(i, conv_part, state_part, step):
-        """A state layer's arrangement against row i of its two pools: the
-        convolution's tail and the recurrence's state."""
-        def conv(x, w, bias):
-            out, new = mamba2.conv_step(state[conv_part][i], x, w, bias)
-            keep(conv_part, i, new)
-            return out
-
-        def rule(*args):
-            y, new = step(state[state_part][i], *args)
-            keep(state_part, i, new)
-            return y
-
-        return conv, rule
-
     arrangement = {"mamba": ("conv", "ssm", mamba2.ssd_step),
                    "kda": ("kda_conv", "kda_s", kda.kda_step)}
 
-    def window(i, part, x, n):
-        win, new = cca.window_step(state[part][i], x)
-        keep(part, i, new)
-        return win
+    def layer_fn(kind, i, layer, x, carry, held):
+        pools, state = list(held[:2]), dict(held[2])
 
-    x, counts = _run_pattern(
-        cfg, params, x, lambda kind, i, layer, x, carry: _pattern_layer(
-            cfg, kind, layer, x, rope, functools.partial(attend, i),
-            recurrent(i, *arrangement[kind]) if kind in arrangement
-            else None, live, carry=carry,
-            window=functools.partial(window, i)))
-    return (_head(cfg, params, x), *pools,
+        def attend(q, k, v):
+            pools[:] = pa.write_decode_kv(*pools, k, v, i, page_table,
+                                          positions, plan)
+            return pa.ragged_paged_attention(
+                q, *pools, i, page_table, seq_lens, scale=cfg.attn_scale,
+                impl=attn_impl, kv_heads=cfg.kv_heads, plan=plan)
+
+        def keep(name, new):
+            """Layer i's rows of pool ``name`` <- ``new`` on live rows."""
+            old = state[name][i]
+            mask = live.reshape(-1, *[1] * (old.ndim - 1))
+            state[name] = state[name].at[i].set(
+                jnp.where(mask, new.astype(old.dtype), old))
+
+        def recurrent(conv_part, state_part, step):
+            """A state layer's arrangement against row i of its two
+            pools: the convolution's tail and the recurrence's state."""
+            def conv(x, w, bias):
+                out, new = mamba2.conv_step(state[conv_part][i], x, w, bias)
+                keep(conv_part, new)
+                return out
+
+            def rule(*args):
+                y, new = step(state[state_part][i], *args)
+                keep(state_part, new)
+                return y
+
+            return conv, rule
+
+        def window(part, x, n):
+            win, new = cca.window_step(state[part][i], x)
+            keep(part, new)
+            return win
+
+        x, counts, carry = _pattern_layer(
+            cfg, kind, layer, x, rope, attend,
+            recurrent(*arrangement[kind]) if kind in arrangement else None,
+            live, carry=carry, window=window)
+        return x, counts, carry, (*pools, state), None
+
+    x, counts, (k_cache, v_cache, state), _ = _run_pattern(
+        cfg, params, x, layer_fn, (k_cache, v_cache, dict(state or {})))
+    return (_head(cfg, params, x), k_cache, v_cache,
             {"state": state, "moe_counts": counts})
 
 

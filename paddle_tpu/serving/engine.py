@@ -80,10 +80,12 @@ that admits the engine's first request; set once, at construction); under routed
 experts counters ``serve_moe_assignments_total{where=held|absent}`` /
 ``serve_moe_experts_touched_total`` and gauge
 ``serve_moe_load_max_over_mean`` (decode steps: the busiest held
-expert's tokens over the mean); under delta-rule (KDA) layers counters
-``serve_kda_state_bytes_total`` (state and convolution tail the passes
-read and wrote for their live rows) / ``serve_kda_chunk_tokens_total``
-(tokens of the chunks that held a prompt token); under a model that
+expert's tokens over the mean); under layers that keep a state a
+sequence counter ``serve_state_bytes_total{kind=mamba|kda|attn}`` (the
+state pools' bytes the passes read and wrote for their live rows: a
+decode step both, a prefill pass the write); under delta-rule (KDA)
+layers counter ``serve_kda_chunk_tokens_total`` (tokens of the chunks
+that held a prompt token); under a model that
 generates by blocks
 (``block_len`` > 1) gauge ``serve_block_length`` and counters
 ``serve_block_passes_total{kind=denoise|commit}`` (a row of a block
@@ -349,15 +351,15 @@ class ServingEngine:
         if cfg.pattern is not None:
             self._loop_args.update(kv_heads=cfg.kv_heads,
                                    state_layers=cfg.state_layers)
-        # a pattern's delta-rule layers: their state's bytes a slot (the
-        # pass's spans and counters say what of it a pass moves)
-        self._kda_slot_bytes = 0
-        if cfg.pattern is not None and "K" in cfg.pattern:
+        # the state one slot holds, by the kind of layer that keeps it (a
+        # pass's spans and the counter say what of it the pass moves)
+        self._state_slot_bytes = {
+            kind: sum(int(self.cache.state[part].nbytes) // s.max_slots
+                      for part in shapes)
+            for kind, (_, shapes) in cfg.state_kinds.items()}
+        self._kda = "kda" in self._state_slot_bytes
+        if self._kda:
             self._loop_args["kda_layers"] = cfg.pattern.count("K")
-            self._kda_slot_bytes = sum(
-                int(a.nbytes) // a.shape[1]
-                for part, a in self.cache.state.items()
-                if part.startswith("kda_"))
         self._block = cfg.block_len
         # the one configuration whose every pass is read before the next
         # is dispatched (module docstring)
@@ -738,7 +740,10 @@ class ServingEngine:
         traces, lowers and compiles once.  The incremental path has one
         prefill shape, compiled by its first pass as before.
 
-        One ``engine_ready`` set-up span around one ``program_ready`` a
+        One ``engine_ready`` set-up span (under a layer pattern it says
+        ``pattern_period`` and ``pattern_repeats``: what the programs'
+        text holds of the pattern, and how often a scan repeats it)
+        around one ``program_ready`` a
         program (``program``, ``rows``, ``length``: a prefill shape, or
         the decode step's slots x positions a pass), each with XLA's own
         trace / lower / compile-or-fetch under it
@@ -749,7 +754,12 @@ class ServingEngine:
                   else sched.prefill_shapes)
         tracer = tracing.get_tracer()
         programs = {}
-        with tracer.timed("engine_ready", programs=len(shapes) + 1) as ready:
+        walk = {}
+        if self.cfg.pattern is not None:    # how its programs walk it
+            walk = dict(zip(("pattern_period", "pattern_repeats"),
+                            self.cfg.pattern_roll))
+        with tracer.timed("engine_ready", programs=len(shapes) + 1,
+                          **walk) as ready:
             for rows, length in shapes:
                 with tracer.timed("program_ready", program="prefill",
                                   rows=rows, length=length) as one:
@@ -875,13 +885,14 @@ class ServingEngine:
                 "prompt_tokens": int(batch["seq_lens"].sum())}
         if self._block > 1:
             fill["blocks_written"] = fill["prompt_tokens"] // self._block
-        if self._kda_slot_bytes:
-            # the chunks that hold a token, the causal query-key pairs of
-            # an attention layer, and the state the rows leave
+        if self._state_slot_bytes:      # the state the rows leave
+            fill["state_bytes"] = self._state_moved(len(admitted), 1)
+        if self._kda:
+            # the chunks that hold a token and the causal query-key pairs
+            # of an attention layer
             chunk, lens = self.cfg.kda_chunk, batch["seq_lens"].astype(int)
             fill["kda_chunks"] = int((-(-lens // chunk)).sum())
             fill["attn_pairs"] = int((lens * (lens + 1) // 2).sum())
-            fill["state_bytes"] = self._kda_state_moved(len(admitted), 1)
             reg.counter(
                 "serve_kda_chunk_tokens_total",
                 "tokens of the chunks of the chunked delta rule that held "
@@ -907,14 +918,19 @@ class ServingEngine:
         if self._block == 1:    # by blocks a prefill pass samples nothing
             self.scheduler.sent(admitted)
 
-    def _kda_state_moved(self, rows: int, times: int) -> int:
-        """Bytes of delta-rule state a pass moves for ``rows`` live rows
-        (``times``: 2 = read and written, 1 = written), booked."""
-        moved = times * rows * self._kda_slot_bytes
-        self.registry.counter(
-            "serve_kda_state_bytes_total",
-            "bytes of delta-rule (KDA) state and convolution tail the "
-            "passes read and wrote for their live rows").inc(moved)
+    def _state_moved(self, rows: int, times: int) -> int:
+        """Bytes of the state pools a pass moves for ``rows`` live rows
+        (``times``: 2 = read and written, 1 = written), booked by the
+        kind of layer that keeps them."""
+        booked = self.registry.counter(
+            "serve_state_bytes_total",
+            "bytes of the state pools (a recurrent layer's state and "
+            "convolution tail, a CCA layer's) the passes read and wrote "
+            "for their live rows, by the kind of layer")
+        moved = 0
+        for kind, per_slot in self._state_slot_bytes.items():
+            booked.inc(times * rows * per_slot, kind=kind)
+            moved += times * rows * per_slot
         return moved
 
     def _send_decode(self, tracer, programs, batch) -> None:
@@ -936,8 +952,8 @@ class ServingEngine:
             if bl > 1:      # masked going in: the host's count
                 said.update(positions=len(live) * bl,
                             masked_in=sum(a.block.masked for a in live))
-        if self._kda_slot_bytes:    # every live row's, read and written
-            moved = self._kda_state_moved(len(live), 2)
+        if self._state_slot_bytes:  # every live row's, read and written
+            moved = self._state_moved(len(live), 2)
             if tracer.enabled:
                 said["state_bytes"] = moved
         self._send(tracer, _Pass("decode", live, self._decode_out, said, t0),

@@ -177,7 +177,14 @@ def load_servable(path: str):
     params = {k: jnp.asarray(v, dtype=cfg.dtype if v.dtype.kind == "f"
                              else None)
               for k, v in flat.items()}
-    return cfg, _unflatten(params)
+    params = _unflatten(params)
+    if cfg.pattern is not None and len(params["blocks"]) != cfg.pattern_roll[0]:
+        # written one tree a LAYER, before the pattern walk rolled over a
+        # period: stacked here by position, as the walk reads them
+        from paddle_tpu.models.transformer import lay_blocks
+
+        params["blocks"] = lay_blocks(cfg, params["blocks"])
+    return cfg, params
 
 
 def checkpoint_path_to_servable(path: str, out_dir: str, cfg,

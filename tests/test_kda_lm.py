@@ -246,7 +246,7 @@ def test_engine_logits_are_the_references(monkeypatch, ref, weights, params,
     # what the passes moved of the state: a prefill writes its rows', a
     # decode step reads and writes its live rows'
     steps = sum(n - 1 for n in news)
-    assert reg.get("serve_kda_state_bytes_total").value() == per_slot * (
+    assert reg.get("serve_state_bytes_total").value(kind="kda") == per_slot * (
         len(prompts) + 2 * steps)
     assert reg.get("serve_kda_chunk_tokens_total").value() == 16 * sum(
         -(-len(p) // 16) for p in prompts)
@@ -367,7 +367,7 @@ def test_default_fields_draw_the_parents_weights(fields):
     cfg = T.TransformerConfig(**{**base, **fields})
     assert (cfg.kda_heads, cfg.kda_conv, cfg.kda_chunk, cfg.attn_gate) == (
         0, 4, 64, False)
-    assert len(dataclasses.fields(T.TransformerConfig)) == 50
+    assert len(dataclasses.fields(T.TransformerConfig)) == 54
     p = lm_toy.jitted(T.init_params, cfg)(jax.random.key(0))
     names = {k for b in (p["blocks"] if cfg.pattern else [p["blocks"]])
              for k in b}
