@@ -123,6 +123,9 @@ class Sizes:
         ragged_paged_attention_vmem=(4, 32, 128, 129, 16, 32),
         # a one-row prefill pass of solar-open2-250b's KDA layer
         kda_prefill=(1, 4096, 64, 64),          # B, T, H, chunk  (D = 128)
+        # a round of that pass's sorted expert product: gate | up, down
+        grouped_matmul=(3072, 40, 4096, 1280),  # rows, experts, K, N
+        grouped_matmul_down=(3072, 40, 1280, 4096),
         softmax_xent=(4096, 50257),             # N, V
         conv2d_bn_act=(128, 56, 64, 64, 3, 1, 1),  # N, HW, Cin, Cout, k, s, p
         conv2d_direct=(128, 224, 3, 64, 7, 2, 3),  # the ResNet stem
@@ -542,6 +545,7 @@ def _kernel_cases() -> list[KernelCase]:
 
     from paddle_tpu.ops import kda
     from paddle_tpu.ops.pallas import ctc, gru, lstm
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
     from paddle_tpu.ops.pallas import kda as kda_kernel
     from paddle_tpu.ops.pallas import paged_attention as pa
     from paddle_tpu.ops.pallas.flash_attention import (
@@ -665,6 +669,23 @@ def _kernel_cases() -> list[KernelCase]:
                 2 * jax.nn.sigmoid(normal(key, 4, (b, t, h))),
                 jax.random.randint(jax.random.fold_in(key, 5), (b,),
                                    max(t // 2, 1), t + 1))
+
+    def make_grouped(shape, key):
+        m, g, k, n = shape
+        # two thirds of the rows lie in groups, every seventh expert has none
+        ids = jax.random.randint(jax.random.fold_in(key, 3), (2 * m // 3,),
+                                 0, g)
+        sizes = jnp.bincount(ids, length=g) * (jnp.arange(g) % 7 != 3)
+        return (normal(key, 0, (m, k), bf16),
+                normal(key, 1, (g, k, n), bf16, k ** -0.5),
+                normal(key, 2, (g, k, n), bf16, k ** -0.5),
+                sizes.astype(jnp.int32))
+
+    def grouped(gated, impl, interp=None):
+        form = dict(act=jax.nn.silu, out_dtype=bf16) if gated else {}
+        return lambda rows, w, gate, sizes: gm.grouped_matmul(
+            rows, w, sizes, gate if gated else None, impl=impl,
+            interpret=interp, **form)
 
     def make_xent(shape, key):
         n, v = shape
@@ -798,6 +819,13 @@ def _kernel_cases() -> list[KernelCase]:
                  *a, chunk=s[3], impl="kernel", interpret=interp),
              lambda s: lambda *a: kda.kda_prefill(
                  *a, chunk=s[3], impl="reference"), tol=BF16_TOL),
+        case("grouped_matmul", make_grouped,
+             lambda interp, s: grouped(True, "kernel", interp),
+             lambda s: grouped(True, "reference"), tol=BF16_TOL),
+        case("grouped_matmul[down]", make_grouped,
+             lambda interp, s: grouped(False, "kernel", interp),
+             lambda s: grouped(False, "reference"), tol=BF16_TOL,
+             shape_key="grouped_matmul_down"),
         case("softmax_xent", make_xent,
              lambda interp, s: lambda lg, tg: sx.softmax_xent(
                  lg, tg, 256, 2048, interp),

@@ -49,6 +49,7 @@ the absent share.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -341,21 +342,38 @@ def moe_ffn_sharded(params: dict, x: jax.Array, cfg: MoEConfig, mesh,
 # Up to this many rows every held expert runs over every row (masked by
 # the combine weight): the layer streams its weights once, which is what a
 # decode batch costs whatever the arrangement, and on a v5e the MXU hides
-# the wasted rows for a long while — both matmuls over 64 experts of 2688 x
-# 1856 take 1.8 / 1.9 / 3.5 / 7.1 ms at 64 / 256 / 512 / 1024 rows, against
-# 18 ms for the pair of ``lax.ragged_dot`` calls whatever the rows (PERF.md
-# section 6, PR 30).  Above it the assignments are sorted by expert and go
-# through the grouped product, which multiplies only the assignments made
-# to experts held here.
+# the wasted rows for a while — both matmuls over 64 experts of 2688 x
+# 1856 take 1.8 / 2.0 / 3.5 / 7.1 ms at 64 / 256 / 512 / 1024 rows (PERF.md
+# section 6, PRs 30 and 47).  Above it the assignments are sorted by expert
+# and go through the grouped product (``ops/pallas/grouped_matmul.py``),
+# which multiplies only the assignments made to experts held here and
+# reads each expert's matrices once: 40 held experts of 4096 x 1280, three
+# matrices, take 3.7 ms at 2,048 rows and 5.7 at 4,096 (gate | up 1.46,
+# down 0.74, the rest the sort, the gather and the scatter-add in XLA),
+# where the three ``lax.ragged_dot`` calls it replaced took 6.5 and 8.6
+# (PERF.md section 6, PR 47).
 DENSE_MAX_TOKENS = 2048
 # The masked product does ``num_experts / top_k`` times the assigned work,
 # and the limit above was measured at 21 x (128 experts, top-6; 16 x at
 # 128 / 8 and 16 / 1 read alike).  Past 32 x the limit falls in proportion
 # -- ``_DENSE_WASTE * top_k // num_experts`` rows, 1,638 at 320 / 8's 40 x
 # -- and is never raised: 40 held experts of 4096 x 1280 over 2,048 rows
-# take 14.7 ms masked (7.7 where a bucket of 1,024 holds the live rows)
-# against 6.0-6.3 through the grouped product (PERF.md section 6, PR 44).
+# take 15.3 ms masked (7.7 where a bucket of 1,024 holds the live rows).
+# Both limits date from ``ragged_dot``'s fixed 4.6 ms a sublayer; against
+# the kernel the masked product of that router loses from 256 rows on
+# (2.4 | 2.1 ms, 4.4 | 2.4 at 600, 11.8 | 3.1 at 1,638: PERF.md section 7
+# has what lowering them would take).
 _DENSE_WASTE = 65_536
+# The masked product streams every held expert's matrices whatever the
+# rows; the kernel streams those of the experts its rows chose.  Where an
+# even router is expected to leave more than one held expert in seven without
+# a row -- ``1 - (1 - top_k / num_experts) ** rows`` under this share --
+# a pass under the limits above takes the kernel all the same: 32 rows of
+# 8 / 320 (55% touched) 1.83 ms masked | 1.15 through the kernel, 64 rows
+# (80%) 1.85 | 1.52; at 95-98% the masked product is level or ahead (128
+# rows of 8 / 320 2.08 | 1.92, 64 rows of 6 / 128 1.81 | 2.03, of 1 / 16
+# 0.65 | 0.67: PERF.md section 6, PR 47).
+_SPARSE_SHARE = 0.85
 # a padded pass is mostly padding: its live rows are gathered to the front
 # and the masked product runs over the smallest of these row counts that
 # holds them (chosen at run time from ``live``; the last is all rows)
@@ -405,10 +423,13 @@ class RoutedConfig:
         return self.held[1] - self.held[0]
 
 
+# (one function object a name: the grouped kernel's jit is keyed by it)
+_ACTS = {"relu2": lambda h: jnp.square(jax.nn.relu(h)),
+         "gelu": jax.nn.gelu, "silu": jax.nn.silu}
+
+
 def _act(name: str, h):
-    if name == "relu2":
-        return jnp.square(jax.nn.relu(h))
-    return {"gelu": jax.nn.gelu, "silu": jax.nn.silu}[name](h)
+    return _ACTS[name](h)
 
 
 def _choose(s, bias, cfg: RoutedConfig):
@@ -460,11 +481,34 @@ def route_mlp(r2, params: dict, cfg: RoutedConfig):
     return _choose(s, params.get("router_bias"), cfg)
 
 
+def product_path(t: int, cfg: RoutedConfig, w_in, impl: str = "auto") -> str:
+    """The arrangement :func:`moe_routed` gives the expert product of a
+    pass of ``t`` rows over matrices ``w_in`` [held, D, F] — read at trace
+    time from shapes alone, so a caller that builds a program can say what
+    it was built with: "masked" (every held expert over every row), or the
+    sorted side's route — "kernel" (``ops/pallas/grouped_matmul.py``),
+    "reference" (``lax.ragged_dot``: ``impl``'s answer off a TPU) or
+    "reference_shape" (matrices the kernel takes no block of)."""
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+
+    _, d, f = w_in.shape
+    route = gm.route(impl, (d, f, w_in.dtype, cfg.gated), (f, d, w_in.dtype))
+    if t > min(DENSE_MAX_TOKENS, _DENSE_WASTE * cfg.top_k // cfg.num_experts):
+        return route
+    # few rows of a wide router leave held experts without a row, and the
+    # kernel (it alone) does not read those
+    touched = 1.0 - (1.0 - cfg.top_k / cfg.num_experts) ** t
+    return route if route == "kernel" and touched < _SPARSE_SHARE \
+        else "masked"
+
+
 def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None,
-               carry=None):
+               carry=None, impl: str = "auto"):
     """x [..., D] -> (y like x, counts int32 [4]).  ``carry`` [..., R]:
     this layer's router state under ``cfg.router_hidden``
     (``router_state``), which then chooses the experts in x's place.
+    ``impl``: the sorted side's kernel-or-reference choice
+    (:func:`product_path`; the routing census says ``moe_experts``).
 
     ``params``: ``router`` [D, X] and, optionally, ``router_bias`` [X]
     over all X experts; ``w_in`` [held, D, F] and ``w_out`` [held, F, D]
@@ -477,6 +521,9 @@ def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None,
     ``counts`` = assignments on held experts, assignments on absent ones,
     held experts with at least one token, the busiest held expert's
     tokens — over live rows."""
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    from paddle_tpu.ops.pallas import note_route, resolve_interpret
+
     f32 = jnp.float32
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
@@ -498,7 +545,9 @@ def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None,
     local = jnp.where(held, idx - lo, cfg.num_held)   # num_held = nowhere
     w_in, w_out = params["w_in"], params["w_out"]
     w_gate = params["w_gate"] if cfg.gated else None
-    if t <= min(DENSE_MAX_TOKENS, _DENSE_WASTE * k // cfg.num_experts):
+    path = product_path(t, cfg, w_in, impl)
+    note_route("moe_experts", path)
+    if path == "masked":
         # [T, held] combine weights; every held expert over every row
         comb = jnp.sum(w[..., None] * (local[..., None] == jnp.arange(
             cfg.num_held)), axis=1)
@@ -534,7 +583,8 @@ def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None,
     else:
         # assignments sorted by held expert (the absent ones last); only
         # the held ones are gathered and multiplied, each expert's rows
-        # through its own matrices, ``bound`` sorted assignments at a time
+        # through its own matrices (the grouped product: gate | up to the
+        # activations' type, then down), ``bound`` sorted assignments at a time
         # -- a static count a quarter above the held share of an even
         # router -- as often as the count of held ones asks (a held eighth
         # of the experts: one round, an eighth of the rows and of ``ys``)
@@ -546,6 +596,12 @@ def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None,
                              // (4 * cfg.num_experts)) // 512 * 512 + 512)
         order_p = jnp.pad(order, (0, bound))
         ws_all = w.reshape(-1)
+        if path == "kernel":
+            product = functools.partial(gm.grouped_matmul_kernel,
+                                        interpret=resolve_interpret(None))
+        else:
+            product = gm.grouped_matmul_reference
+        act = _ACTS[cfg.act]
 
         def gathered(c, y):
             at = c * bound
@@ -553,15 +609,8 @@ def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None,
             # this round's rows of every expert's group
             sizes = jnp.clip(ends - at, 0, bound) \
                 - jnp.clip(ends - loads - at, 0, bound)
-            rows = x2[sel // k]
-            h = lax.ragged_dot(rows, w_in, sizes, preferred_element_type=f32)
-            if cfg.gated:
-                h = h * _act(cfg.act, lax.ragged_dot(
-                    rows, w_gate, sizes, preferred_element_type=f32))
-            else:
-                h = _act(cfg.act, h)
-            h = h.astype(x.dtype)
-            ys = lax.ragged_dot(h, w_out, sizes, preferred_element_type=f32)
+            h = product(x2[sel // k], w_in, sizes, w_gate, act, x.dtype)
+            ys = product(h, w_out, sizes)
             ws = jnp.where(at + jnp.arange(bound) < ends[-1], ws_all[sel],
                            0.0)
             ys = jnp.where(ws[:, None] > 0, ys * ws[:, None], 0.0)

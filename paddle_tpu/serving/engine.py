@@ -78,7 +78,10 @@ from-zero prefill passes counters ``serve_prefill_padded_tokens_total``
 length where it is long — compiled with the decode program by the step
 that admits the engine's first request; set once, at construction); under routed
 experts counters ``serve_moe_assignments_total{where=held|absent}`` /
-``serve_moe_experts_touched_total`` and gauge
+``serve_moe_experts_touched_total`` /
+``serve_moe_product_passes_total{path=masked|kernel|reference}`` (passes
+by the arrangement their program gives the expert product:
+``parallel.moe.product_path``, which the spans say as ``moe_path``) and gauge
 ``serve_moe_load_max_over_mean`` (decode steps: the busiest held
 expert's tokens over the mean); under layers that keep a state a
 sequence counter ``serve_state_bytes_total{kind=mamba|kda|attn}`` (the
@@ -108,6 +111,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import queue
 import threading
 import time
@@ -212,6 +216,7 @@ class _Pass:
                         # its own where that ended later
     out: object = None  # the int32 device array its tokens come out in
     span: object = None  # its open span
+    t: int = 0          # rows its program runs a layer over
 
 
 def _resident_bytes(engine, _) -> dict:
@@ -322,6 +327,14 @@ class ServingEngine:
         self._routed = bool(cfg.pattern and "E" in cfg.pattern)
         if self._routed:
             self._expert_slots = cfg.routed.num_held * cfg.pattern.count("E")
+            # what ``moe_routed`` reads when a program of ``t`` rows is
+            # traced: the arrangement of its expert product
+            from paddle_tpu.parallel.moe import product_path
+
+            w_in = next(b["w_in"] for b in params["blocks"] if "router" in b)
+            w_in = jax.ShapeDtypeStruct(w_in.shape, w_in.dtype)
+            self._moe_path = functools.cache(
+                lambda t: product_path(t, cfg.routed, w_in))
             self._flops_per_token -= 2.0 * max(
                 0.0, 1.0 - cfg.moe_top_k / cfg.moe_experts) * count(
                     [(b["w_in"], b["w_out"]) for b in params["blocks"]
@@ -401,6 +414,20 @@ class ServingEngine:
         self._loop_error: BaseException | None = None
         self._stopped = False  # a stop()ed loop marks the engine dead
         self._build_fns()
+
+    def _product_pass(self, t: int) -> dict:
+        """Books a pass of ``t`` rows through routed expert layers under
+        the arrangement its program gives the expert product (read from
+        shapes, as ``moe_routed`` read it when the program was traced);
+        returned as a span arg."""
+        if not self._routed:
+            return {}
+        path = self._moe_path(t)
+        self.registry.counter(
+            "serve_moe_product_passes_total",
+            "passes through routed expert layers, by the arrangement of "
+            "the expert product in the program that ran").inc(path=path)
+        return {"moe_path": path}
 
     def _split_counts(self, out, rows: int, where: str):
         """A pass's output -> its ``rows`` sampled tokens; the routing
@@ -914,7 +941,8 @@ class ServingEngine:
         self._send(tracer, _Pass(
             "prefill", admitted, rows,
             dict(batch=len(admitted), **fill, **self._loop_args)
-            if tracer.enabled else {}, t0), programs[rows, length], *args)
+            if tracer.enabled else {}, t0, t=rows * length),
+            programs[rows, length], *args)
         if self._block == 1:    # by blocks a prefill pass samples nothing
             self.scheduler.sent(admitted)
 
@@ -956,8 +984,9 @@ class ServingEngine:
             moved = self._state_moved(len(live), 2)
             if tracer.enabled:
                 said["state_bytes"] = moved
-        self._send(tracer, _Pass("decode", live, self._decode_out, said, t0),
-                   programs["decode"], *args)
+        self._send(tracer, _Pass(
+            "decode", live, self._decode_out, said, t0,
+            t=self.serving.max_slots * bl), programs["decode"], *args)
         self.scheduler.sent(live)
         self.registry.counter(
             "serve_layer_passes_total",
@@ -1015,6 +1044,7 @@ class ServingEngine:
         p = flight[0]
         self._open(tracer, p)
         toks, counts = self._split_counts(p.out, p.n_out, p.kind)
+        counts.update(self._product_pass(p.t))
         flight.popleft()
         if self._block > 1 and p.kind == "decode":
             # booked inside the span, which says what came of the pass
@@ -1163,6 +1193,7 @@ class ServingEngine:
             *args)
         toks, counts = self._split_counts(
             toks, self.serving.prefill_batch, "prefill")
+        counts.update(self._product_pass(batch["ids"].size))
         if tk is not None:
             tracer.end(tk, **counts)
         t1 = time.perf_counter()

@@ -37,6 +37,7 @@ CASES = (
     "ragged_paged_attention[sdar]", "ragged_paged_attention[zaya]",
     "ragged_paged_attention[vmem]",
     "kda_prefill",  # the cell's one-row pass: ~5 s
+    "grouped_matmul", "grouped_matmul[down]",   # that pass's expert product
     "softmax_xent",
     "fused_momentum_update", "ctc_loss_fused", "ctc_loss_fused[logits]",
     "ctc_greedy_decode_fused", "embedding_gather", "embedding_scatter_add",

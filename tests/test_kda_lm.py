@@ -289,6 +289,49 @@ def test_spans_say_what_a_pass_moved(params):
     assert [r.tokens for r in plain] == [r.tokens for r in traced]
 
 
+@pytest.mark.parametrize("limit, four_rows", [(2048, "masked"),
+                                              (40, "reference")])
+def test_engine_says_its_expert_products_arrangement(
+        monkeypatch, ref, weights, params, limit, four_rows):
+    """The arrangement of the expert product is chosen a PROGRAM, when it
+    is traced.  Under a row limit of 40 the four-row pass (4 x 36 rows)
+    sorts its assignments and runs the grouped product — its
+    ``lax.ragged_dot`` twin off a TPU — while the one-row pass and the
+    decode step stay masked: the census says so at the build, every
+    ``serve_prefill`` / ``serve_decode`` span says its own program's
+    (``moe_path``), the registry counts passes by it, and the tokens are
+    the reference's on either arrangement."""
+    from paddle_tpu.ops import pallas
+
+    monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", limit)
+    # a config of its own: the engine's programs are memoised by config
+    cfg = kda_cfg(max_seq_len=140 + limit % 7)
+    reg = MetricsRegistry("kda")
+    prompts = [[1, 2, 3], list(range(20)), [5, 6, 7]]
+
+    def serve():
+        eng = _engine(params, reg=reg, cfg=cfg)
+        return eng.generate(prompts[:2], max_new_tokens=3) \
+            + eng.generate(prompts[2:], max_new_tokens=2)
+
+    with pallas.capture_routes() as routes:
+        served, spans = lm_toy.traced(serve)
+    built = {path for (op, path) in routes if op == "moe_experts"}
+    assert built == {"masked", four_rows}
+    pre = {s.args["rows"]: s.args["moe_path"] for s in spans["serve_prefill"]}
+    assert pre == {4: four_rows, 1: "masked"}
+    assert {s.args["moe_path"] for s in spans["serve_decode"]} == {"masked"}
+    passes = reg.get("serve_moe_product_passes_total")
+    n = len(spans["serve_prefill"]) + len(spans["serve_decode"])
+    if four_rows == "masked":
+        assert passes.value(path="masked") == n
+    else:
+        assert passes.value(path=four_rows) == 1
+        assert passes.value(path="masked") == n - 1
+    for r, prompt, new in zip(served, prompts, (3, 3, 2)):
+        assert r.tokens == lm_toy.greedy(ref, weights, M, prompt, new, PAD)
+
+
 def test_memory_report_counts_the_kda_state(params):
     from paddle_tpu.analysis.memory import serving_memory_report
 

@@ -1,7 +1,9 @@
 """The routed expert sublayer (``parallel/moe.py:moe_routed``).  Which
 arrangement it picks for a pass's rows: every held expert over every row
 (masked by the combine weight) up to a limit, above it the assignments
-sorted by expert through ``lax.ragged_dot``.  The masked product does
+sorted by expert through the grouped product (``ops/pallas/
+grouped_matmul.py``: the kernel on a TPU, ``lax.ragged_dot`` here; the
+cases that run the sorted side run it on both routes).  The masked product does
 ``num_experts / top_k`` times the assigned work, so past 32 x the limit
 falls in proportion; it is never raised.  And what the arrangements owe:
 each equals a plain loop over experts, they agree over a held share, and
@@ -40,41 +42,60 @@ def _tree(cfg, key=None):
             for k, (n, s) in zip(keys, shapes.items())}
 
 
-def _grouped(cfg, t):
-    fn = lambda p, x, live: moe.moe_routed(p, x, cfg, live)
+def _grouped(cfg, t, impl="auto"):
+    fn = lambda p, x, live: moe.moe_routed(p, x, cfg, live, impl=impl)
     text = str(jax.make_jaxpr(fn)(
         _tree(cfg), jax.ShapeDtypeStruct((t, D), jnp.float32),
         jax.ShapeDtypeStruct((t,), jnp.bool_)))
-    return "ragged_dot" in text
+    return "ragged_dot" in text or "pallas_call" in text
 
 
 # (num_experts, top_k, held) of the benchmark's four routed configurations
 # and the rows of the programs their engines compile (decode step or block
 # pass, the prefill ladder's members), with a row count either side of
-# each limit
+# each limit.  ``on_chip``: the arrangement where the kernel runs; off the
+# chip the sorted side is ``ragged_dot``'s ("reference") and the few-row
+# side, which is the kernel's alone, stays masked.
 NEMO3N, SDAR30, ZAYA1, SOLAR2 = ((128, 6, (0, 64)), (128, 8, None),
                                  (16, 1, None), (320, 8, (0, 40)))
+SPARSE = moe._SPARSE_SHARE
 
 
-@pytest.mark.parametrize("name,router,t,grouped", [
-    *[(name, router, t, False)
+@pytest.mark.parametrize("name,router,t,on_chip", [
+    *[(name, router, t, "masked")
       for name, router in (("nemo3n", NEMO3N), ("sdar30", SDAR30),
                            ("zaya1", ZAYA1))
       for t in (64, 256, 512, 2048)],
-    ("nemo3n", NEMO3N, 2049, True),
-    ("sdar30", SDAR30, 4096, True),
-    ("solar2", SOLAR2, 32, False),
-    ("solar2", SOLAR2, 1638, False),
-    ("solar2", SOLAR2, 1639, True),
-    ("solar2", SOLAR2, 2048, True),
-    ("solar2", SOLAR2, 4096, True),
-    ("solar2", SOLAR2, 8192, True),
+    ("nemo3n", NEMO3N, 2049, "kernel"),
+    ("sdar30", SDAR30, 4096, "kernel"),
+    # 32 rows of 8 / 320 touch 55.5% of the held experts in expectation:
+    # the decode step of ``solar-open2-250b`` reads only those
+    ("solar2", SOLAR2, 32, "kernel"),
+    ("solar2", SOLAR2, 74, "kernel"),       # 84.6%
+    ("solar2", SOLAR2, 75, "masked"),       # 85.0%
+    ("nemo3n", NEMO3N, 39, "kernel"),       # 84.6%: a step of 39 slots
+    ("nemo3n", NEMO3N, 40, "masked"),       # 85.3%
+    ("zaya1", ZAYA1, 29, "kernel"),         # 84.6%
+    ("zaya1", ZAYA1, 30, "masked"),         # 85.6%
+    ("solar2", SOLAR2, 1638, "masked"),
+    ("solar2", SOLAR2, 1639, "kernel"),
+    ("solar2", SOLAR2, 2048, "kernel"),
+    ("solar2", SOLAR2, 4096, "kernel"),
+    ("solar2", SOLAR2, 8192, "kernel"),
 ])
-def test_arrangement_by_router_and_rows(name, router, t, grouped):
+def test_arrangement_by_router_and_rows(name, router, t, on_chip):
     experts, top_k, held = router
     cfg = moe.RoutedConfig(num_experts=experts, top_k=top_k, held=held,
                            act="silu", gated=True)
-    assert _grouped(cfg, t) is grouped
+    w_in = _tree(cfg)["w_in"]
+    over = t > min(moe.DENSE_MAX_TOKENS, moe._DENSE_WASTE * top_k // experts)
+    share = 1 - (1 - top_k / experts) ** t
+    assert on_chip == ("kernel" if over or share < SPARSE else "masked")
+    assert moe.product_path(t, cfg, w_in, "kernel") == on_chip
+    assert moe.product_path(t, cfg, w_in, "reference") == (
+        "reference" if over else "masked")
+    assert _grouped(cfg, t, "kernel") is (on_chip == "kernel")
+    assert _grouped(cfg, t) is over
 
 
 def test_the_limit_is_read_when_called(monkeypatch):
@@ -92,19 +113,21 @@ def test_the_limit_is_read_when_called(monkeypatch):
     assert _grouped(wide, 2048) and not _grouped(wide, 1638)
 
 
+@pytest.mark.parametrize("impl", ["reference", "kernel"])
 @pytest.mark.parametrize("held", [(0, 10), (30, 40), None])
-def test_masked_and_grouped_agree_past_32x(monkeypatch, held):
+def test_masked_and_grouped_agree_past_32x(monkeypatch, held, impl):
     """80 experts, top-2 (40 x): 1,640 rows go through the grouped product
-    where the limit is 1,638; the masked product over the same rows (the
-    limit's rule lifted) gives the same result and counts, padding rows
-    routed nowhere."""
+    (``impl``: ``ragged_dot`` | the kernel, interpreted) where the limit is
+    1,638; the masked product over the same rows (the limit's rule lifted)
+    gives the same result and counts, padding rows routed nowhere."""
     cfg = moe.RoutedConfig(num_experts=80, top_k=2, held=held, act="silu",
                            gated=True)
     tree = _tree(cfg, jax.random.key(3))
     x = jax.random.normal(jax.random.key(4), (1640, D), jnp.float32)
     live = jnp.arange(1640) % 5 != 2
     assert _grouped(cfg, 1640)
-    got, counts = _routed(cfg, live)(tree, x)
+    assert moe.product_path(1640, cfg, tree["w_in"], impl) == impl
+    got, counts = _routed(cfg, live, None, impl)(tree, x)
     monkeypatch.setattr(moe, "_DENSE_WASTE", 1 << 30)
     assert not _grouped(cfg, 1640)
     with jax.default_matmul_precision("highest"):
@@ -115,6 +138,31 @@ def test_masked_and_grouped_agree_past_32x(monkeypatch, held):
                                   np.asarray(counts_masked))
     assert not np.asarray(got)[~np.asarray(live)].any()
     assert int(counts[0] + counts[1]) == int(live.sum()) * 2
+
+
+def test_few_rows_of_a_wide_router_take_the_kernel_and_agree():
+    """Ten rows of top-2 of 64 touch a quarter of the held experts in
+    expectation: where the kernel runs they go through it (idle rows
+    routed nowhere, the untouched experts never read), and the result and
+    counts are the masked product's, which the same rows get off the chip."""
+    from paddle_tpu.ops import pallas
+
+    cfg = moe.RoutedConfig(num_experts=64, top_k=2, held=(8, 24), act="silu",
+                           gated=True)
+    tree = _tree(cfg, jax.random.key(13))
+    x = jax.random.normal(jax.random.key(14), (10, D), jnp.float32)
+    live = jnp.arange(10) != 3
+    out = {}
+    for impl, path in (("kernel", "kernel"), ("reference", "masked")):
+        with pallas.capture_routes() as routes:
+            out[path] = _routed(cfg, live, None, impl)(tree, x)
+        assert routes == {("moe_experts", path): 1}
+    (got, counts), (want, counts_masked) = out["kernel"], out["masked"]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5,
+                               rtol=1e-5)
+    np.testing.assert_array_equal(np.asarray(counts),
+                                  np.asarray(counts_masked))
+    assert not np.asarray(got)[3].any() and int(counts[2]) < cfg.num_held
 
 
 # -- the layer against a loop over experts, and its shares --------------------------
@@ -291,17 +339,20 @@ def test_the_eight_shares_add_up_to_the_uncut_sublayer():
                                atol=TOL_SOLAR, rtol=TOL_SOLAR)
 
 
+@pytest.mark.parametrize("impl", ["reference", "kernel"])
 @pytest.mark.parametrize("rows, held, note", [
     (300, (0, 8), "half the experts: more than one round of the gather"),
     (300, (4, 6), "an eighth of the experts"),
     (300, None, "every expert held: the bound is every assignment"),
     (700, (0, 1), "one expert, every token sent to it: past the bound"),
 ])
-def test_grouped_experts_over_a_held_share(monkeypatch, rows, held, note):
+def test_grouped_experts_over_a_held_share(monkeypatch, rows, held, note,
+                                           impl):
     """Above ``DENSE_MAX_TOKENS`` rows the assignments to HELD experts are
     gathered (a static bound of rows at a time, as often as the count
-    asks) and multiplied group by group: the result is the masked
-    product's and the reference's."""
+    asks) and multiplied group by group (``impl``: ``ragged_dot`` | the
+    kernel, interpreted): the result is the masked product's and the
+    reference's."""
     ref = lm_toy.load_reference("solar_open2")
     monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 64)
     cfg = test_kda_lm.kda_cfg(moe_held=held)
@@ -315,7 +366,7 @@ def test_grouped_experts_over_a_held_share(monkeypatch, rows, held, note):
         layer["router_bias"] = layer["router_bias"].at[0].set(10.0)
     h = jax.random.normal(jax.random.key(7), (rows, 32))
     live = jnp.arange(rows) % 7 != 3
-    got, counts = _routed(cfg.routed, live)(layer, h)
+    got, counts = _routed(cfg.routed, live, None, impl)(layer, h)
     monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 4096)
     want, counts_dense = _routed(cfg.routed, live)(layer, h)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
