@@ -126,6 +126,8 @@ class Sizes:
         # a round of that pass's sorted expert product: gate | up, down
         grouped_matmul=(3072, 40, 4096, 1280),  # rows, experts, K, N
         grouped_matmul_down=(3072, 40, 1280, 4096),
+        # a Mamba-2 layer of nemotron-3-nano's decode step against its pool
+        ssd_step=(6, 64, 64, 64, 128, 8),       # layers, B, H, P, N, groups
         softmax_xent=(4096, 50257),             # N, V
         conv2d_bn_act=(128, 56, 64, 64, 3, 1, 1),  # N, HW, Cin, Cout, k, s, p
         conv2d_direct=(128, 224, 3, 64, 7, 2, 3),  # the ResNet stem
@@ -543,7 +545,7 @@ def _kernel_cases() -> list[KernelCase]:
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.ops import kda
+    from paddle_tpu.ops import kda, mamba2
     from paddle_tpu.ops.pallas import ctc, gru, lstm
     from paddle_tpu.ops.pallas import grouped_matmul as gm
     from paddle_tpu.ops.pallas import kda as kda_kernel
@@ -669,6 +671,16 @@ def _kernel_cases() -> list[KernelCase]:
                 2 * jax.nn.sigmoid(normal(key, 4, (b, t, h))),
                 jax.random.randint(jax.random.fold_in(key, 5), (b,),
                                    max(t // 2, 1), t + 1))
+
+    def make_ssd(shape, key):
+        layers, b, h, p, n, g = shape
+        # the pool, a traced row, the mixer's six, and one idle slot
+        return (normal(key, 0, (layers, b, h, p, n)), jnp.int32(layers - 2),
+                normal(key, 1, (b, h, p), bf16),
+                jax.nn.softplus(normal(key, 2, (b, h))),
+                -jnp.exp(normal(key, 3, (h,))),
+                normal(key, 4, (b, g, n), bf16), normal(key, 5, (b, g, n), bf16),
+                normal(key, 6, (h,)), jnp.arange(b) != 1)
 
     def make_grouped(shape, key):
         m, g, k, n = shape
@@ -819,6 +831,10 @@ def _kernel_cases() -> list[KernelCase]:
                  *a, chunk=s[3], impl="kernel", interpret=interp),
              lambda s: lambda *a: kda.kda_prefill(
                  *a, chunk=s[3], impl="reference"), tol=BF16_TOL),
+        case("ssd_step", make_ssd,
+             lambda interp, s: lambda *a: mamba2.ssd_pool_step(
+                 *a, impl="kernel", interpret=interp),
+             lambda s: lambda *a: mamba2.ssd_pool_step(*a, impl="reference")),
         case("grouped_matmul", make_grouped,
              lambda interp, s: grouped(True, "kernel", interp),
              lambda s: grouped(True, "reference"), tol=BF16_TOL),

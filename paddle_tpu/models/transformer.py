@@ -1531,7 +1531,7 @@ def forward_decode(cfg: TransformerConfig, params: dict, ids: jax.Array,
     if cfg.pattern is not None:
         return _decode_pattern(cfg, params, x, rope, positions, seq_lens,
                                page_table, k_cache, v_cache, attn_impl,
-                               state)
+                               state, mesh)
 
     # what the step's cache layers share, made once: XLA leaves it in the
     # layer loop's body otherwise
@@ -1622,7 +1622,8 @@ def forward_decode_block(cfg: TransformerConfig, params: dict,
 
 
 def _decode_pattern(cfg: TransformerConfig, params, x, rope, positions,
-                    seq_lens, page_table, k_cache, v_cache, attn_impl, state):
+                    seq_lens, page_table, k_cache, v_cache, attn_impl, state,
+                    mesh=None):
     """One token per row x [B, E] through a layer pattern.  Every pool —
     K, V and the state parts — is carried through the walk and rebound as
     a layer updates it at its own index (a Python number in the unrolled
@@ -1635,8 +1636,9 @@ def _decode_pattern(cfg: TransformerConfig, params, x, rope, positions,
 
     plan = pa.decode_plan(k_cache, page_table, positions, seq_lens,
                           cfg.kv_heads, cfg.head_dim)
-    arrangement = {"mamba": ("conv", "ssm", mamba2.ssd_step),
-                   "kda": ("kda_conv", "kda_s", kda.kda_step)}
+    arrangement = {"mamba": ("conv", "ssm"), "kda": ("kda_conv", "kda_s")}
+    # GSPMD cannot partition a Mosaic kernel: under a mesh the plain form
+    ssd_impl = "auto" if mesh is None else "reference"
 
     def layer_fn(kind, i, layer, x, carry, held):
         pools, state = list(held[:2]), dict(held[2])
@@ -1655,7 +1657,7 @@ def _decode_pattern(cfg: TransformerConfig, params, x, rope, positions,
             state[name] = state[name].at[i].set(
                 jnp.where(mask, new.astype(old.dtype), old))
 
-        def recurrent(conv_part, state_part, step):
+        def recurrent(conv_part, state_part):
             """A state layer's arrangement against row i of its two
             pools: the convolution's tail and the recurrence's state."""
             def conv(x, w, bias):
@@ -1664,7 +1666,11 @@ def _decode_pattern(cfg: TransformerConfig, params, x, rope, positions,
                 return out
 
             def rule(*args):
-                y, new = step(state[state_part][i], *args)
+                if kind == "mamba":     # the pool itself, live rows of row i
+                    y, state[state_part] = mamba2.ssd_pool_step(
+                        state[state_part], i, *args, live, impl=ssd_impl)
+                    return y
+                y, new = kda.kda_step(state[state_part][i], *args)
                 keep(state_part, new)
                 return y
 

@@ -12,7 +12,9 @@ needs.  Per head ``h`` (B/C group ``h // (H / G)``), with state ``S``
   token: positions at or past ``seq_lens`` get ``dt = 0``, which decays
   nothing (``exp(0)``) and adds nothing, so the state after the padded
   length is the state at ``seq_lens - 1``;
-- :func:`ssd_step` is the one-token recurrence of a decode step.
+- :func:`ssd_step` is the one-token recurrence of a decode step, and
+  :func:`ssd_pool_step` the same against one layer's row of the serving
+  cache's state pool, live rows only.
 
 The depthwise causal convolution in front of it keeps its own state —
 the last ``K - 1`` inputs — with the same two arrangements
@@ -21,9 +23,12 @@ valid token (it lies to the right) and never part of the state handed
 back.
 
 Plain ``jax.numpy`` (XLA): decay arithmetic in float32, the products in
-the inputs' type with float32 accumulation.  ``state_shapes`` is the one
-place the per-slot state of a layer is spelt; the serving cache sizes
-its state pool from it.
+the inputs' type with float32 accumulation.  On a TPU
+:func:`ssd_pool_step` is one Pallas kernel a layer
+(``ops/pallas/ssd.py``: a slot's state through VMEM once, the pool
+written where it lies); :func:`ssd_step` is the CPU path and its oracle.
+``state_shapes`` is the one place the per-slot state of a layer is
+spelt; the serving cache sizes its state pool from it.
 """
 
 from __future__ import annotations
@@ -58,6 +63,37 @@ def ssd_step(state, x, dt, a, b, c, d):
              + (dt[..., None] * xf)[..., None] * bh[:, :, None, :])
     y = jnp.einsum("bhpn,bhn->bhp", state, ch) + d.astype(f32)[:, None] * xf
     return y, state
+
+
+def ssd_pool_step(pool, row, x, dt, a, b, c, d, live, impl: str = "auto",
+                  interpret=None):
+    """One token of the layer that keeps row ``row`` (a Python number or
+    traced) of ``pool`` [layers, B, H, P, N] float32; x, dt, a, b, c, d as
+    :func:`ssd_step` takes them; live bool[B]: an idle row keeps its state
+    bit for bit.  ``impl``: "kernel" (``ops/pallas/ssd.py``), "reference"
+    (:func:`ssd_step` on the row and a ``where``: the CPU path and the
+    kernel's oracle) or "auto" (kernel on a TPU); a shape the kernel does
+    not take runs the reference and the routing census (``ssd_step``) says
+    so.  Returns (y [B, H, P] float32, the pool with that row replaced)."""
+    from paddle_tpu.ops import pallas
+    from paddle_tpu.ops.pallas import ssd as kernel
+
+    route = pallas.resolve_impl(impl)
+    slots, *head_shape = pool.shape[1:]
+    if route == "kernel" and not kernel.supports(*head_shape, b.shape[1],
+                                                   slots):
+        route = "reference_shape"
+    pallas.note_route("ssd_step", route)
+    if route == "kernel":
+        return kernel.ssd_pool_step(
+            pool, row, x, dt, a, b, c, d, live,
+            interpret=pallas.resolve_interpret(interpret))
+    # (the row sliced twice, in this order: the text every CPU program of a
+    # pattern lowered to before the kernel, which the serving tests hold)
+    y, new = ssd_step(pool[row], x, dt, a, b, c, d)
+    old = pool[row]
+    return y, pool.at[row].set(
+        jnp.where(live.reshape(-1, 1, 1, 1), new, old))
 
 
 def _segsum(a):

@@ -100,6 +100,32 @@ def engine(cfg, params, registry=None, **serving):
                          registry=registry or MetricsRegistry("toy"))
 
 
+def serve_on_route(monkeypatch, route, cfg, params, prompts, news, **serving):
+    """``prompts`` through a fresh engine, the first two steps ahead of
+    the rest, whose decode step takes the Mamba-2 recurrence by ``route``
+    ("kernel": interpreted here; "reference": ``ssd_step``).  Returns
+    (each request's tokens, the routing census of what the engine
+    traced).  The route is traced INTO the programs, so this engine
+    shares none with another."""
+    from paddle_tpu.ops import mamba2, pallas
+    from paddle_tpu.serving import engine as E
+
+    routed = mamba2.ssd_pool_step
+    monkeypatch.setattr(E, "_FN_MEMO", {})
+    monkeypatch.setattr(
+        mamba2, "ssd_pool_step",
+        lambda *a, impl=None, **kw: routed(*a, impl=route, **kw))
+    with pallas.capture_routes() as routes:
+        eng = engine(cfg, params, **serving)
+        ids = [eng.submit(prompts[0], news[0])]
+        eng.step()
+        eng.step()
+        ids += [eng.submit(p, n) for p, n in zip(prompts[1:], news[1:])]
+        eng.run_until_idle()
+    got = {r.id: r.tokens for r in eng.results()}
+    return [got[i] for i in ids], routes
+
+
 _PADDED = {}
 
 

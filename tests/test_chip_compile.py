@@ -38,6 +38,7 @@ CASES = (
     "ragged_paged_attention[vmem]",
     "kda_prefill",  # the cell's one-row pass: ~5 s
     "grouped_matmul", "grouped_matmul[down]",   # that pass's expert product
+    "ssd_step",     # a Mamba-2 layer of a decode step, the pool aliased
     "softmax_xent",
     "fused_momentum_update", "ctc_loss_fused", "ctc_loss_fused[logits]",
     "ctc_greedy_decode_fused", "embedding_gather", "embedding_scatter_add",

@@ -234,6 +234,28 @@ def test_engine_serves_the_reference_greedy_tokens(ref, weights, params):
     assert reg.get("serve_moe_load_max_over_mean").value() >= 1.0
 
 
+@pytest.mark.parametrize("route", ["reference", "kernel"])
+def test_the_decode_recurrence_serves_the_references_tokens_by_either_route(
+        route, ref, monkeypatch):
+    """At a state of whole lanes the decode step's recurrence is one
+    kernel a layer on a TPU (``ops/pallas/ssd.py``; interpreted here, two
+    B/C groups, the pool's row a number of the unrolled walk): by it as by
+    ``ssd_step`` the engine serves the reference's greedy tokens through a
+    reused slot, and the census says which ran."""
+    m = {**M, "mamba_state": 128}
+    weights = lm_toy.draw(ref, m, 11)
+    rng = np.random.default_rng(2)
+    prompts = [[int(t) for t in rng.integers(0, 97, n)] for n in (7, 12, 3, 1)]
+    news = [6, 3, 5, 4]
+    got, routes = lm_toy.serve_on_route(
+        monkeypatch, route, hybrid_cfg(mamba_state=128),
+        ref.program_tree(weights), prompts, news, **SERVING)
+    for tokens, prompt, n in zip(got, prompts, news):
+        assert tokens == lm_toy.greedy(ref, weights, m, prompt, n, PAD)
+    assert {k: v for k, v in routes.items() if k[0] == "ssd_step"} \
+        == {("ssd_step", route): M["pattern"].count("M")}
+
+
 def test_decode_span_says_what_the_step_touched(params):
     _, spans = lm_toy.traced(lambda: lm_toy.engine(
         hybrid_cfg(), params, MetricsRegistry("s"), **SERVING).generate(

@@ -178,6 +178,30 @@ def test_engine_serves_the_references_tokens_and_counts_the_state(
         == (6, 2)
 
 
+@pytest.mark.parametrize("route", ["reference", "kernel"])
+def test_the_decode_recurrence_serves_the_references_tokens_by_either_route(
+        route, ref, monkeypatch):
+    """At a state of whole lanes the decode step's recurrence is one
+    kernel a layer on a TPU (``ops/pallas/ssd.py``; interpreted here, the
+    pool's row traced by the rolled walk): by it as by ``ssd_step`` the
+    engine serves the reference's greedy tokens, live and idle rows mixed,
+    and the census says which ran."""
+    m = {**M, "mamba_state": 128}
+    weights = lm_toy.draw(ref, m, 11)
+    rng = np.random.default_rng(3)
+    prompts = [[int(t) for t in rng.integers(0, V, n)] for n in (7, 16, 3, 12)]
+    news = [6, 3, 5, 4]
+    got, routes = lm_toy.serve_on_route(
+        monkeypatch, route, ssm_cfg(mamba_state=128),
+        ref.program_tree(weights), prompts, news, max_slots=3, page_size=PS,
+        num_pages=40, max_prompt_len=16, max_new_tokens=6, prefill_batch=2)
+    for tokens, prompt, n in zip(got, prompts, news):
+        assert tokens == lm_toy.greedy(ref, weights, m, prompt, n, PAD)
+    # two M positions of the period, one decode program
+    assert {k: v for k, v in routes.items() if k[0] == "ssd_step"} \
+        == {("ssd_step", route): 2}
+
+
 # -- the rolled walk is the unrolled walk --------------------------------------------
 
 
