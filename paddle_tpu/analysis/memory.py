@@ -355,7 +355,11 @@ def serving_memory_report(cfg, serving, params=None, cache=None) -> dict:
     dtype: ``cache layers × kv_heads × pages × page_size × head_dim``, the
     heads rounded up to whole lane groups; cache layers = ``num_layers ×
     loop_steps``: a looped stack keeps one cache per pass; under a layer
-    pattern its attention layers) and the float32 state pools
+    pattern its "*" layers — a cross layer reads one of those and holds
+    nothing), the RINGS of a pattern's window layers (k AND v, the same
+    layout: ``window layers × kv_heads × (1 + max_slots × window /
+    page_size) pages``, bounded whatever the contexts:
+    ``window_pool_bytes``) and the float32 state pools
     (``cfg.state_parts``: ``max_slots`` rows a layer that keeps the part,
     whatever kind of layer that is) next to the
     servable params — the same artifact :func:`memory_report` computes for training, so an
@@ -377,6 +381,15 @@ def serving_memory_report(cfg, serving, params=None, cache=None) -> dict:
         cfg.cache_layers, cfg.kv_heads, serving.num_pages,
         serving.page_size, cfg.head_dim))) * int(np.dtype(cfg.dtype).itemsize)
     kv = 2 * per_pool  # k and v pools
+    window = 0
+    if getattr(cfg, "window_layers", 0):
+        from paddle_tpu.ops.pallas.paged_attention import window_pool_pages
+
+        window = 2 * int(np.prod(kv_pool_shape(
+            cfg.window_layers, cfg.kv_heads, window_pool_pages(
+                cfg.attn_window, serving.page_size, serving.max_slots),
+            serving.page_size, cfg.head_dim))) * int(
+                np.dtype(cfg.dtype).itemsize)
     state = 4 * int(serving.max_slots) * sum(
         layers * int(np.prod(shape))
         for layers, shape in cfg.state_parts.values())
@@ -384,11 +397,12 @@ def serving_memory_report(cfg, serving, params=None, cache=None) -> dict:
     report = {
         "kv_pool_bytes": kv,
         "state_pool_bytes": state,
+        "window_pool_bytes": window,
         "params_bytes": p_bytes,
         "num_pages": int(serving.num_pages),
         "page_size": int(serving.page_size),
         "dtype": np.dtype(cfg.dtype).name,
-        "total_bytes": kv + state + p_bytes,
+        "total_bytes": kv + state + window + p_bytes,
     }
     if cache is not None:
         page_bytes = kv // max(int(serving.num_pages), 1)

@@ -32,7 +32,15 @@ from paddle_tpu.ops import attention as attn_ops
 
 
 # a layer pattern's characters -> the kind's name in ``params["blocks"]``
-_KINDS = {"*": "attn", "-": "mlp", "E": "moe", "M": "mamba", "K": "kda"}
+_KINDS = {"*": "attn", "-": "mlp", "E": "moe", "M": "mamba", "K": "kda",
+          "S": "mamba1", "W": "window", "G": "gmu", "X": "cross"}
+# the kinds whose mixer is an attention: "*" over everything before it, "W"
+# over its last ``attn_window`` positions, "X" with its own queries over
+# the K/V of the nearest "*" before it
+_ATTENDS = ("attn", "window", "cross")
+# queries a step of the plain blocked attention takes at once (the window
+# layers' band, and every attention under ``attn_diff``)
+_ATTN_BLOCK = 512
 # the kinds the ROLLED pattern walk carries (``_keeps_unrolled``): their
 # pools ride a scan's carry and they hand nothing from layer to layer
 _ROLLED = frozenset("*-M")
@@ -159,8 +167,12 @@ class TransformerConfig:
     # mixer behind a pre-norm and a residual add — "*" attention, "-" the
     # dense MLP, "E" routed experts (dropless: ``moe_router`` "sigmoid" or
     # "softmax_topk"), "M" a Mamba-2 mixer, "K" a gated delta-rule
-    # linear-attention mixer (KDA).  None = ``num_layers`` blocks of
-    # (attention, MLP).
+    # linear-attention mixer (KDA), "S" a Mamba-1 mixer (selective scan),
+    # "W" attention over the last ``attn_window`` positions, "G" a gated
+    # memory unit (the nearest "S" before it hands its scan output on,
+    # gated here by a projection of this layer's input), "X" attention
+    # that projects q only and reads the K/V of the nearest "*" before it
+    # (nine kinds).  None = ``num_layers`` blocks of (attention, MLP).
     # ``params["blocks"]`` is then a list of per-layer trees in pattern
     # order, walked by the pattern — or, where the pattern repeats a
     # period and the walk rolls over it (``pattern_roll``), one tree a
@@ -231,6 +243,32 @@ class TransformerConfig:
     attn_scale: float | None = None
     residual_multiplier: float = 1.0
     logits_divisor: float = 1.0
+    # a "W" layer's window: a query sees itself and the ``attn_window`` - 1
+    # positions before it (0 = the pattern has no such layer).  Served
+    # from a RING a slot (``serving/kv_cache.py``): position p's K/V rest
+    # at ``p mod attn_window``, which is exact only because such a model
+    # has no position signal (``positions`` "none") and softmax does not
+    # care in which order keys arrive
+    attn_window: int = 0
+    # differential attention (arXiv:2410.05258) on every "*", "W", "X"
+    # layer: query heads 2p, 2p+1 are pair p's q1, q2, K/V heads 2r, 2r+1
+    # pair r's k1, k2 and v1, v2, query pair p reads K/V pair p //
+    # (num_heads / kv_heads); a1 = softmax(q1 k1^T) [v1 | v2], a2 likewise,
+    # the pair's output RMSNorm_2D(a1 - lambda a2) (1 - lambda_init) with
+    # lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init, lambda_init =
+    # 0.8 - 0.6 exp(-0.3 depth), depth the layer's block (``diff_depths``)
+    attn_diff: bool = False
+    # biases on a pattern's attention projections (bq, bk, bv, bo)
+    attn_bias: bool = False
+    # the Mamba-1 mixer of an "S" layer: ``mamba1_inner`` channels,
+    # ``mamba1_state`` state columns a channel, a depthwise causal conv of
+    # ``mamba1_conv`` taps, dt through a low-rank pair at ``mamba1_dt_rank``,
+    # prefill in chunks of ``mamba1_chunk`` (``ops/mamba1.py``)
+    mamba1_inner: int = 0
+    mamba1_state: int = 16
+    mamba1_conv: int = 4
+    mamba1_dt_rank: int = 0
+    mamba1_chunk: int = 64
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -304,6 +342,7 @@ class TransformerConfig:
                     "a layer pattern with loop_steps > 1 or norm_sandwich "
                     "(whatever its kinds: '*', '-', 'E', 'M', 'K'): the "
                     "pattern walk runs one pass of pre-norm layers")
+            self._check_yoco()
         if not 0.0 < self.rope_fraction <= 1.0 or int(
                 self.head_dim * self.rope_fraction) % 2:
             raise ValueError(
@@ -315,6 +354,8 @@ class TransformerConfig:
                           ("residual_scale", self.residual_scale),
                           ("kda_heads", self.kda_heads),
                           ("attn_gate", self.attn_gate),
+                          ("attn_diff", self.attn_diff),
+                          ("attn_bias", self.attn_bias),
                           ("residual_multiplier",
                            self.residual_multiplier != 1.0)):
             if on and not walked:
@@ -361,13 +402,109 @@ class TransformerConfig:
                 "that varies by token, which neither the scans here nor the "
                 "scheduler's equal-work-per-token batches have")
 
+    def _check_yoco(self):
+        """What the four kinds of a decoder-hybrid-decoder pattern ("S",
+        "W", "G", "X") need of the rest of the configuration."""
+        pat = self.pattern
+        new = sorted(set("SWGX") & set(pat))
+        if "G" in pat and "S" not in pat[:pat.index("G")]:
+            raise ValueError("a 'G' layer (gated memory unit) needs an 'S' "
+                             "layer before it: it gates that layer's scan "
+                             "output")
+        if "X" in pat and "*" not in pat[:pat.index("X")]:
+            raise ValueError("an 'X' layer (cross attention) needs a '*' "
+                             "layer before it: it reads that layer's K/V")
+        if ("W" in pat) != (self.attn_window > 0):
+            raise ValueError(
+                f"'W' layers and attn_window go together: pattern "
+                f"{pat!r}, attn_window {self.attn_window}")
+        if "S" in pat and not (self.mamba1_inner > 0 and self.mamba1_dt_rank
+                               > 0 and self.mamba1_conv >= 2):
+            raise ValueError("an 'S' layer needs mamba1_inner > 0, "
+                             "mamba1_dt_rank > 0 and mamba1_conv >= 2 taps")
+        if "W" in pat and self.positions != "none":
+            raise NotImplementedError(
+                "'W' layers with a position signal: the served window is a "
+                "ring (position p at p mod attn_window), which needs the "
+                "positions of what it holds once q and k carry them")
+        from paddle_tpu.ops.pallas.paged_attention import head_group
+
+        if self.attn_diff and (
+                self.kv_heads % 2 or self.num_heads % 2 or self.qk_norm
+                or head_group(self.kv_heads, self.head_dim) != 2):
+            raise NotImplementedError(
+                "attn_diff needs even num_heads and kv_heads (pairs), no "
+                "qk_norm, and a K/V pair that is exactly one lane group of "
+                "the cache (paged_attention.head_group = 2: head_dim 64, or "
+                "2 K/V heads of at most 64): the decode kernel's caller "
+                "keeps a lane group's values whole")
+        if new and (self.block_len > 1 or self.cca_taps is not None
+                    or self.moe_router_hidden):
+            raise NotImplementedError(
+                f"layers of kind {new} under block_len > 1, cca_taps or "
+                "moe_router_hidden: a block pass, a CCA state and the MLP "
+                "router's carry are not built beside a ring, a Mamba-1 "
+                "state or the memory the walk hands on")
+        if (self.attn_diff or self.attn_bias) and (
+                self.cca_taps is not None or self.block_len > 1):
+            raise NotImplementedError(
+                "attn_diff / attn_bias under cca_taps or block_len > 1")
+
     @property
     def cache_layers(self) -> int:
-        """K/V cache layers a served token occupies: one per (pass,
-        attention layer)."""
+        """Cache layers of the GROWING paged K/V cache — what a served
+        token occupies for as long as its sequence lives: one per (pass,
+        layer) of a homogeneous stack; under a pattern one per "*" layer.
+        A "W" layer's bounded ring is counted by ``window_layers``, an
+        "X" layer holds nothing (``cross_reads``)."""
         if self.pattern is not None:
             return self.pattern.count("*")
         return self.num_layers * self.loop_steps
+
+    @property
+    def window_layers(self) -> int:
+        """"W" layers: each keeps the K/V of a sequence's last
+        ``attn_window`` positions in a ring a slot, whatever the context."""
+        return (self.pattern or "").count("W")
+
+    @property
+    def cross_reads(self) -> tuple:
+        """For each "X" layer, in order, the cache layer it reads: that of
+        the nearest "*" before it."""
+        pat = self.pattern or ""
+        return tuple(pat[:i].count("*") - 1
+                     for i, c in enumerate(pat) if c == "X")
+
+    @property
+    def kv_reads(self) -> int:
+        """Layer-reads of the growing cache a decode step makes: its "*"
+        layers and the "X" layers that read them."""
+        return self.cache_layers + len(self.cross_reads)
+
+    @property
+    def diff_depths(self) -> dict:
+        """Under ``attn_diff``: kind -> each layer of the kind's depth, the
+        block it belongs to (a block = a mixer and the MLP behind it: the
+        "-" / "E" entries before the layer), which sets ``lambda_init``."""
+        out = {k: [] for k in _ATTENDS}
+        for i, c in enumerate(self.pattern or ""):
+            if _KINDS[c] in out:
+                out[_KINDS[c]].append(sum(self.pattern[:i].count(m)
+                                          for m in "-E"))
+        return {k: tuple(v) for k, v in out.items()}
+
+    @property
+    def narrow_at(self) -> int | None:
+        """Where a prefill pass NARROWS to each row's last token: the
+        index of the first layer behind the last one that leaves anything
+        in a cache (K/V, a ring, a state), if the pattern has "X" layers
+        and only "G", "X", "-" follow — nothing later reads the other
+        positions (the decoder-hybrid-decoder rule).  None: no narrowing."""
+        pat = self.pattern or ""
+        if "X" not in pat:
+            return None
+        at = 1 + max(i for i, c in enumerate(pat) if c not in "GX-")
+        return at if at < len(pat) else None
 
     @property
     def pattern_roll(self) -> tuple:
@@ -385,11 +522,12 @@ class TransformerConfig:
 
     @property
     def state_kinds(self) -> dict:
-        """The kinds of layer that keep a fixed state per sequence: kind
-        -> (how many layers of it the pattern has, {part: one layer's
+        """The kinds of layer that keep a fixed float32 state per sequence:
+        kind -> (how many layers of it the pattern has, {part: one layer's
         shape for one sequence}).  A Mamba-2 layer keeps a state and no
-        pages, nor does a KDA layer; a CCA attention layer keeps one
-        BESIDE its pages."""
+        pages, nor does a KDA or a Mamba-1 layer; a CCA attention layer
+        keeps one BESIDE its pages.  (A window layer's ring is K/V, in
+        the K/V type: ``window_layers``.)"""
         from paddle_tpu.ops import cca, kda, mamba2
 
         kinds = {}
@@ -402,6 +540,11 @@ class TransformerConfig:
         if "K" in self.pattern:
             kinds["kda"] = (self.pattern.count("K"), kda.state_shapes(
                 self.kda_heads, self.head_dim, self.kda_conv))
+        if "S" in self.pattern:
+            from paddle_tpu.ops import mamba1
+
+            kinds["mamba1"] = (self.pattern.count("S"), mamba1.state_shapes(
+                self.mamba1_inner, self.mamba1_state, self.mamba1_conv))
         if self.cca_taps is not None and "*" in self.pattern:
             kinds["attn"] = (self.pattern.count("*"), cca.state_shapes(
                 self.cca_taps, self.num_heads, self.kv_heads, self.head_dim))
@@ -556,8 +699,23 @@ def _pattern_params(cfg: TransformerConfig, norm, zeros, norm_p) -> list:
                 "cca_conv1_b": zeros(heads * hd),
                 "cca_temp": jnp.ones((cfg.kv_heads,), cfg.dtype)}
 
+    def attn_extras(cross=False):
+        """Biases on the projections and the differential leaves (four
+        lambda vectors a head wide, one gain over a pair's 2 x head_dim);
+        a cross layer has neither K nor V."""
+        p = {}
+        if cfg.attn_bias:
+            p.update(bq=zeros(h), bo=zeros(e))
+            if not cross:
+                p.update(bk=zeros(hk), bv=zeros(hk))
+        if cfg.attn_diff:
+            p.update({f"lambda_{n}": 0.1 * norm(cfg.head_dim)
+                      for n in ("q1", "k1", "q2", "k2")},
+                     subln_g=jnp.ones((2 * cfg.head_dim,), cfg.dtype))
+        return p
+
     def layer(kind):
-        if kind == "attn":
+        if kind in ("attn", "window"):
             # the gate is drawn last: the leaves before it are the draws
             # they were without one
             return {"wq": norm(e, h) * (e ** -0.5),
@@ -566,7 +724,31 @@ def _pattern_params(cfg: TransformerConfig, norm, zeros, norm_p) -> list:
                     "wo": norm(h, e) * (h ** -0.5) / (2 * s) ** 0.5,
                     **_qk_norm_params(cfg, ()), **cca(),
                     **({"w_ogate": norm(e, h) * (e ** -0.5)}
-                       if cfg.attn_gate else {})}
+                       if cfg.attn_gate else {}), **attn_extras()}
+        if kind == "cross":
+            return {"wq": norm(e, h) * (e ** -0.5),
+                    "wo": norm(h, e) * (h ** -0.5) / (2 * s) ** 0.5,
+                    **attn_extras(cross=True)}
+        if kind == "gmu":
+            d1 = cfg.mamba1_inner
+            return {"gmu_in": norm(e, d1) * (e ** -0.5),
+                    "gmu_out": norm(d1, e) * (d1 ** -0.5) / (2 * s) ** 0.5}
+        if kind == "mamba1":
+            # dt_bias around softplus^-1(0.01), A = -exp(a_log) <= -1 and
+            # drawn per channel and state column (kept [state, inner]: the
+            # state's layout), so a swapped axis shows
+            d1, n1, r1 = (cfg.mamba1_inner, cfg.mamba1_state,
+                          cfg.mamba1_dt_rank)
+            return {"in_proj": norm(e, 2 * d1) * (e ** -0.5),
+                    "conv_w": norm(cfg.mamba1_conv, d1)
+                    * (cfg.mamba1_conv ** -0.5),
+                    "conv_b": zeros(d1),
+                    "x_proj": norm(d1, r1 + 2 * n1) * (d1 ** -0.5),
+                    "dt_proj": norm(r1, d1) * (r1 ** -0.5),
+                    "dt_bias": -4.6 + 0.5 * norm(d1),
+                    "a_log": jnp.abs(norm(n1, d1)),
+                    "d": jnp.ones((d1,), cfg.dtype),
+                    "out_proj": norm(d1, e) * (d1 ** -0.5) / (2 * s) ** 0.5}
         if kind in ("mlp", "moe"):
             return _ffn_params(cfg, norm, zeros, (), s, kind == "moe")
         if kind == "kda":
@@ -647,7 +829,8 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> dict:
     s = cfg.num_layers
     k = iter(jax.random.split(
         key, 14 if cfg.pattern is None else 8 + (
-            12 if "K" in cfg.pattern else 8) * cfg.num_layers))
+            12 if set("KSWGX") & set(cfg.pattern) or cfg.attn_diff
+            else 8) * cfg.num_layers))
     norm = lambda *shape: jax.random.normal(next(k), shape, cfg.dtype)
     zeros = lambda *shape: jnp.zeros(shape, cfg.dtype)
 
@@ -899,13 +1082,130 @@ def _attention(cfg: TransformerConfig, q, k, v, mesh):
                                           scale=cfg.attn_scale)
 
 
+def _blocked_attention(cfg: TransformerConfig, q, k, v, window: int = 0,
+                       seq_lens=None):
+    """Causal attention in plain XLA, a block of ``_ATTN_BLOCK`` queries
+    at a time against only the keys the block can see — everything before
+    it, or under ``window`` > 0 the band (a query sees itself and the
+    ``window`` - 1 positions before it): key blocks wholly outside are
+    never multiplied, not masked.  q [B, T, H, D] in the cache's order
+    (query head h reads K head ``h // (H / KV)``; under ``attn_diff``
+    after ``_diff_order``), k and v [B, Tk, KV, D].  Under ``attn_diff``
+    K head 2r + s reads the VALUES of its whole pair, [v_2r | v_2r+1],
+    and the result is [B, T, H, 2 D]; otherwise [B, T, H, D].
+    ``seq_lens`` [B] with T = 1: q is each row's LAST token (position
+    ``seq_lens - 1``) and sees keys ``[0, seq_lens)``."""
+    from paddle_tpu.ops.pallas import NEG_INF
+
+    b, t, h, d = q.shape
+    tk, kv = k.shape[1], k.shape[2]
+    s = 2 if cfg.attn_diff else 1
+    r, rep = kv // s, h // kv
+    scale = cfg.attn_scale if cfg.attn_scale is not None else d ** -0.5
+    kg = k.reshape(b, tk, r, s, d)
+    vg = v.reshape(b, tk, r, s * d)
+
+    def attend(qb, lo, hi, mask):
+        """Queries qb [B, Q, H, D] over keys [lo, hi); mask [B or 1, Q,
+        hi - lo]."""
+        qg = qb.reshape(b, -1, r, s, rep, d)
+        sc = jnp.einsum("bqrsjd,bkrsd->brsjqk", qg, kg[:, lo:hi],
+                        preferred_element_type=jnp.float32) * scale
+        sc = jnp.where(mask[:, None, None, None], sc, NEG_INF)
+        p = jax.nn.softmax(sc, axis=-1).astype(v.dtype)
+        out = jnp.einsum("brsjqk,bkre->bqrsje", p, vg[:, lo:hi],
+                         preferred_element_type=jnp.float32)
+        return out.reshape(b, -1, h, s * d).astype(q.dtype)
+
+    if seq_lens is not None:
+        assert t == 1, "seq_lens: one query a row, its last token"
+        assert not window, "the last-token form takes no band"
+        mask = jnp.arange(tk)[None, None, :] < seq_lens[:, None, None]
+        return attend(q, 0, tk, mask)
+    assert t == tk, "whole sequences: a key a query"
+    bq = min(_ATTN_BLOCK, t)
+    behind = -(-(window - 1) // bq) if window else None   # key blocks back
+    outs = []
+    for i in range(-(-t // bq)):
+        q0, q1 = i * bq, min((i + 1) * bq, t)
+        lo = 0 if behind is None else max(0, (i - behind) * bq)
+        qpos = jnp.arange(q0, q1)[:, None]
+        kpos = jnp.arange(lo, q1)[None, :]
+        mask = kpos <= qpos
+        if window:
+            mask &= kpos > qpos - window
+        outs.append(attend(q[:, q0:q1], lo, q1, mask[None]))
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+
+
+def _diff_order(cfg: TransformerConfig, q):
+    """q [..., H, D] with heads 2p, 2p+1 pair p's q1, q2 -> the cache's
+    order: K head 2r + s (pair r's k1 | k2) is read by the q_(s+1) of the
+    ``H / KV`` query pairs of its K/V pair, and a cache built for grouped
+    queries gives K head j the query heads ``[j rep, (j + 1) rep)`` —
+    inside every K/V pair's 2 rep heads the order (pair, s) becomes (s,
+    pair): (0, 2, 1, 3) at rep 2.  It is NOT the grouped-query map
+    h // rep on the published order."""
+    *lead, h, d = q.shape
+    rep = cfg.num_heads // cfg.kv_heads
+    return q.reshape(*lead, h // (2 * rep), rep, 2, d).swapaxes(
+        -3, -2).reshape(*lead, h, d)
+
+
+def _diff_combine(cfg: TransformerConfig, a, layer, depth):
+    """a [..., H, 2 D] (heads in ``_diff_order``: K/V pair r, then s, then
+    the pair in r; each its softmax over [v1 | v2]) -> the pairs' outputs
+    [..., H D] in the published order: RMSNorm_2D(a1 - lambda a2) (1 -
+    lambda_init), one gain a layer."""
+    f32 = jnp.float32
+    *lead, h, e = a.shape
+    rep = cfg.num_heads // cfg.kv_heads
+    lam0 = 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(depth, f32))
+    dot = lambda x, y: jnp.sum(layer["lambda_" + x].astype(f32)
+                               * layer["lambda_" + y].astype(f32))
+    lam = jnp.exp(dot("q1", "k1")) - jnp.exp(dot("q2", "k2")) + lam0
+    a = a.astype(f32).reshape(*lead, h // (2 * rep), 2, rep, e)
+    y = a[..., 0, :, :] - lam * a[..., 1, :, :]
+    y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + cfg.norm_eps)
+    y = y * layer["subln_g"].astype(f32) * (1.0 - lam0)
+    return y.reshape(*lead, h * e // 2)
+
+
+def _mamba1_mixer(cfg: TransformerConfig, h, layer, conv, scan):
+    """The Mamba-1 mixer over normed states h [..., E] -> (its output
+    [..., E], the scan's output y [..., d_inner] float32 BEFORE the gate
+    by z, with the D skip: what a gated memory unit reads).  ``conv(x, w,
+    bias)`` and ``scan(x, dt, a, b, c, d)`` are the caller's arrangement
+    (``ops/mamba1.py``, ``mamba2.conv_*``), both returning float32."""
+    f32 = jnp.float32
+    n, r = cfg.mamba1_state, cfg.mamba1_dt_rank
+    x, z = jnp.split(h @ layer["in_proj"], 2, axis=-1)
+    x = jax.nn.silu(conv(x, layer["conv_w"], layer["conv_b"])
+                    ).astype(h.dtype)
+    dt, b, c = jnp.split(x @ layer["x_proj"], [r, r + n], axis=-1)
+    dt = jax.nn.softplus((dt @ layer["dt_proj"]).astype(f32)
+                         + layer["dt_bias"].astype(f32))
+    y = scan(x, dt, -jnp.exp(layer["a_log"].astype(f32)), b, c, layer["d"])
+    out = (y * jax.nn.silu(z.astype(f32))).astype(h.dtype)
+    return out @ layer["out_proj"], y
+
+
 def _qkv(cfg: TransformerConfig, h, layer, rope):
     """Normed states h [..., E] -> q [..., H, Dh], k and v [..., KV, Dh],
-    RoPE applied from ``rope``."""
+    RoPE applied from ``rope``.  A layer with no ``wk`` (a cross layer:
+    it reads another layer's K/V) gives (q, None, None)."""
     lead, hd = h.shape[:-1], cfg.head_dim
-    q = (h @ layer["wq"]).reshape(*lead, cfg.num_heads, hd)
-    k = (h @ layer["wk"]).reshape(*lead, cfg.kv_heads, hd)
-    v = (h @ layer["wv"]).reshape(*lead, cfg.kv_heads, hd)
+
+    def proj(name, heads):
+        y = h @ layer["w" + name]
+        if cfg.attn_bias:
+            y = y + layer["b" + name]
+        return y.reshape(*lead, heads, hd)
+
+    q = proj("q", cfg.num_heads)
+    if "wk" not in layer:
+        return (q if rope is None else _rope(q, rope)), None, None
+    k, v = proj("k", cfg.kv_heads), proj("v", cfg.kv_heads)
     if cfg.qk_norm:
         q, k = (_rms(cfg, q, layer["q_norm_g"]),
                 _rms(cfg, k, layer["k_norm_g"]))
@@ -1107,7 +1407,8 @@ def _kda_mixer(cfg: TransformerConfig, h, layer, conv, rule):
 
 
 def _pattern_layer(cfg: TransformerConfig, kind, layer, x, rope, attend,
-                   mamba, live=None, mesh=None, carry=None, window=None):
+                   mamba, live=None, mesh=None, carry=None, window=None,
+                   depth=None):
     """One layer of a ``pattern``: ``x + mixer(norm(x))`` (under
     ``residual_scale`` both terms scaled and shifted by the layer's own
     vectors), the mixer by the layer's ``kind`` (a value of ``_KINDS``).
@@ -1120,18 +1421,37 @@ def _pattern_layer(cfg: TransformerConfig, kind, layer, x, rope, attend,
     tokens, for the routing counts.  ``carry`` [..., moe_router_hidden]
     float32 is the router's state of the routed layer before (None
     without an MLP router): a routed layer reads and replaces it, every
-    other kind passes it on.  Returns (x, counts, carry): a routed
-    layer's ``moe_routed`` counts, else None."""
+    other kind passes it on.  In a pattern with Mamba-1 layers ``carry``
+    is instead the MEMORY: the last "S" layer's scan output [...,
+    mamba1_inner] float32, which a "G" layer gates (``__post_init__``
+    refuses the two together).  ``attend`` gets k and v None from an "X"
+    layer; under ``attn_diff`` it gets q in ``_diff_order`` and returns
+    each head's [..., H, 2 Dh], and ``depth`` is the layer's block
+    (``diff_depths``).  Returns (x, counts, carry): a routed layer's
+    ``moe_routed`` counts, else None."""
     lead = x.shape[:-1]
     counts = None
     h = _norm(cfg, x, layer, "ln")
-    if kind == "attn":
-        qkv = (_qkv(cfg, h, layer, rope) if cfg.cca_taps is None
-               else _cca_qkv(cfg, h, layer, rope, window))
-        a = attend(*qkv).reshape(*lead, cfg.num_heads * cfg.head_dim)
+    if kind in _ATTENDS:
+        q, k, v = (_qkv(cfg, h, layer, rope) if cfg.cca_taps is None
+                   else _cca_qkv(cfg, h, layer, rope, window))
+        if cfg.attn_diff:
+            # each head's softmax over its pair's [v1 | v2], then the pair
+            a = _diff_combine(cfg, attend(_diff_order(cfg, q), k, v), layer,
+                              depth).astype(h.dtype)
+        else:
+            a = attend(q, k, v).reshape(*lead, cfg.num_heads * cfg.head_dim)
         if cfg.attn_gate:
             a = a * jax.nn.sigmoid(h @ layer["w_ogate"])
         y = a @ layer["wo"]
+        if cfg.attn_bias:
+            y = y + layer["bo"]
+    elif kind == "mamba1":
+        # the scan's output before its gate is what the walk hands on
+        y, carry = _mamba1_mixer(cfg, h, layer, *mamba)
+    elif kind == "gmu":
+        gate = jax.nn.silu((h @ layer["gmu_in"]).astype(jnp.float32))
+        y = (carry * gate).astype(h.dtype) @ layer["gmu_out"]
     elif kind == "mamba":
         y = _mamba_mixer(cfg, h, layer, *mamba)
     elif kind == "kda":
@@ -1154,7 +1474,8 @@ def _pattern_layer(cfg: TransformerConfig, kind, layer, x, rope, attend,
     return x + y, counts, carry
 
 
-def _run_pattern(cfg: TransformerConfig, params, x, layer_fn, held=None):
+def _run_pattern(cfg: TransformerConfig, params, x, layer_fn, held=None,
+                 narrow=None):
     """The stack of a layer ``pattern``, the final norm closing it.
     ``layer_fn(kind, i, layer, x, carry, held) -> (x, counts, carry, held,
     left)`` is the caller's arrangement of ``_pattern_layer`` for the
@@ -1176,6 +1497,10 @@ def _run_pattern(cfg: TransformerConfig, params, x, layer_fn, held=None):
     axis by the scan, x and ``held`` are the scan's carry, and ``i`` =
     repeat x (layers of the kind a period) + the layer's offset among
     them, traced.
+
+    ``narrow(x, carry) -> (x, carry)`` (unrolled walk only) is applied
+    once, in front of layer ``cfg.narrow_at``: a prefill pass that goes
+    on with each row's last token alone.
 
     Returns (x, counts, held, left): the routed layers' ``moe_routed``
     counts summed (the busiest expert's tokens: the largest; None without
@@ -1215,8 +1540,10 @@ def _run_pattern(cfg: TransformerConfig, params, x, layer_fn, held=None):
     else:
         carry = jnp.zeros((*x.shape[:-1], cfg.moe_router_hidden),
                           jnp.float32) if cfg.moe_router_hidden else None
-        for c, layer in zip(cfg.pattern, params["blocks"]):
+        for n, (c, layer) in enumerate(zip(cfg.pattern, params["blocks"])):
             kind = _KINDS[c]
+            if narrow is not None and n == cfg.narrow_at:
+                x, carry = narrow(x, carry)
             x, aux, carry, held, kept = layer_fn(kind, seen[kind], layer, x,
                                                  carry, held)
             seen[kind] += 1     # the layer's index among its kind
@@ -1228,7 +1555,7 @@ def _run_pattern(cfg: TransformerConfig, params, x, layer_fn, held=None):
     x = _norm(cfg, x, params, "ln_f")
     # K/V first, then the state parts in the cache's order
     left = {name: jax.tree.map(pooled, *left[name])
-            for name in ("kv", *cfg.state_parts) if name in left}
+            for name in ("kv", "window", *cfg.state_parts) if name in left}
     return x, counts, held, left
 
 
@@ -1359,7 +1686,10 @@ def forward_prefill(cfg: TransformerConfig, params: dict, ids: jax.Array,
     if cfg.pattern is not None:
         x, (ks, vs), extras = _prefill_pattern(cfg, params, x, rope,
                                                seq_lens, mesh)
-        return _head(cfg, params, _last_valid(x, seq_lens)), ks, vs, extras
+        # a pass that narrowed comes back one position a row: the last
+        last = x[:, 0] if cfg.narrow_at is not None else _last_valid(
+            x, seq_lens)
+        return _head(cfg, params, last), ks, vs, extras
 
     def layer_fn(x, layer):
         x, _, kv = _block(
@@ -1373,26 +1703,48 @@ def forward_prefill(cfg: TransformerConfig, params: dict, ids: jax.Array,
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2))
 def _prefill_layer(cfg: TransformerConfig, kind, mesh, layer, x, rope,
-                   seq_lens, carry=None):
+                   seq_lens, carry=None, shared=None, depth=None):
     """One layer of a pattern over whole right-padded sequences x [B, T,
     E]: (x, what the layer leaves behind, counts, the router's carry).  A
     jitted function of its own, so the program that calls it traces and
     lowers each KIND of layer once and not each layer: getting a
     pattern's prefill program ready is the host tracing and lowering it,
     seconds an engine (PERF.md section 6, PR 31); XLA inlines the
-    calls."""
-    from paddle_tpu.ops import cca, kda, mamba2
+    calls.  ``shared``: the (k, v) [B, T, KV, Dh] of the nearest "*"
+    layer before an "X" layer; ``depth``: an attention layer's block
+    under ``attn_diff`` (traced: one text a kind).  An x of ONE position
+    a row is each row's last token (the pass narrowed, ``narrow_at``)."""
+    from paddle_tpu.ops import cca, kda, mamba1, mamba2
+    from paddle_tpu.ops.pallas import paged_attention as pa
 
     kept = {}
 
     def attend(q, k, v):
+        if kind == "cross":     # its own queries over another layer's K/V
+            return _blocked_attention(
+                cfg, q, *shared,
+                seq_lens=seq_lens if x.shape[1] != shared[0].shape[1]
+                else None)
+        if kind == "window":
+            if seq_lens is not None:    # each row's last window, as a ring
+                kept["window"] = tuple(
+                    pa.ring_rows(a, seq_lens, cfg.attn_window)
+                    for a in (k, v))
+            return _blocked_attention(cfg, q, k, v, cfg.attn_window)
         kept["kv"] = (k, v)
+        if cfg.attn_diff:
+            return _blocked_attention(cfg, q, k, v)
         return _attention(cfg, q, k, v, mesh)
 
     def conv(xbc, w, bias):
-        part = "kda_conv" if kind == "kda" else "conv"
+        part = {"kda": "kda_conv", "mamba1": "conv1"}.get(kind, "conv")
         out, kept[part] = mamba2.conv_prefill(xbc, w, bias, seq_lens)
         return out
+
+    def scan1(*args):
+        y, kept["ssm1"] = mamba1.scan_prefill(*args, seq_lens=seq_lens,
+                                              chunk=cfg.mamba1_chunk)
+        return y
 
     def rule(*args):
         o, kept["kda_s"] = kda.kda_prefill(*args, seq_lens=seq_lens,
@@ -1412,8 +1764,8 @@ def _prefill_layer(cfg: TransformerConfig, kind, mesh, layer, x, rope,
         jnp.arange(x.shape[1])[None, :] < seq_lens[:, None])
     x, counts, carry = _pattern_layer(
         cfg, kind, layer, x, rope, attend,
-        (conv, rule if kind == "kda" else ssd), live, mesh,
-        carry=carry, window=window)
+        (conv, {"kda": rule, "mamba1": scan1}.get(kind, ssd)), live, mesh,
+        carry=carry, window=window, depth=depth)
     return x, kept, counts, carry
 
 
@@ -1422,16 +1774,44 @@ def _prefill_pattern(cfg: TransformerConfig, params, x, rope, seq_lens, mesh):
     (``seq_lens`` None = training: every position is a token).  Returns
     (x, (ks, vs) [cache_layers, B, T, KV, Dh] or (None, None), extras):
     ``extras["state"]`` = {part: [layers that keep it, B, ...]}, each
-    row's state at its last valid token."""
+    row's state at its last valid token; under window layers
+    ``extras["window"]`` = (ks, vs) [window_layers, B, attn_window, KV,
+    Dh], each row's last window in ring order
+    (``paged_attention.ring_rows``).
+
+    A pattern with a cross-decoder (``cfg.narrow_at``) serves the layers
+    behind its last producer — nothing later reads what they compute at
+    any position but the last — for each row's LAST token only: x (and
+    the memory the walk carries) is cut to ``[B, 1, ...]`` there, the
+    "X" layers read that one query against the K/V in hand, and x comes
+    back ``[B, 1, E]``.  Training walks every position."""
+    shared = []     # the nearest "*" layer's (k, v), for the "X" layers
+    depths = cfg.diff_depths if cfg.attn_diff else {}
+
     def layer_fn(kind, i, layer, x, carry, held):
-        x, left, counts, carry = _prefill_layer(cfg, kind, mesh, layer, x,
-                                                rope, seq_lens, carry)
+        more = {}       # (none for the kinds that take none: their call
+        if kind == "cross":                     # is what it always was)
+            more["shared"] = shared[-1]
+        if kind in depths:
+            more["depth"] = jnp.float32(depths[kind][i])
+        x, left, counts, carry = _prefill_layer(
+            cfg, kind, mesh, layer, x, rope, seq_lens, carry, **more)
+        if kind == "attn" and cfg.cross_reads:
+            shared.append(left["kv"])
         return x, counts, carry, held, left
 
-    x, counts, _, left = _run_pattern(cfg, params, x, layer_fn)
-    return x, left.get("kv", (None, None)), {
-        "state": {n: left[n] for n in cfg.state_parts},
-        "moe_counts": counts}
+    def narrow(x, carry):
+        cut = lambda a: _last_valid(a, seq_lens)[:, None]
+        return cut(x), None if carry is None else cut(carry)
+
+    x, counts, _, left = _run_pattern(
+        cfg, params, x, layer_fn,
+        narrow=narrow if seq_lens is not None else None)
+    extras = {"state": {n: left[n] for n in cfg.state_parts},
+              "moe_counts": counts}
+    if "window" in left:
+        extras["window"] = left["window"]
+    return x, left.get("kv", (None, None)), extras
 
 
 def forward_prefill_chunk(cfg: TransformerConfig, params: dict,
@@ -1461,6 +1841,11 @@ def forward_prefill_chunk(cfg: TransformerConfig, params: dict,
                    cfg.max_seq_len - 1)
     x, rope = _embed(cfg, params, ids, pos)
     if cfg.pattern is not None:
+        if cfg.window_layers or cfg.cross_reads:
+            raise NotImplementedError(
+                "forward_prefill_chunk with 'W' or 'X' layers: a chunk "
+                "would have to read and advance its slot's ring, and a "
+                "prefix hit share one; neither is built")
         if cfg.state_layers:
             raise NotImplementedError(
                 "forward_prefill_chunk with state layers: a chunk would "
@@ -1629,26 +2014,54 @@ def _decode_pattern(cfg: TransformerConfig, params, x, rope, positions,
     a layer updates it at its own index (a Python number in the unrolled
     walk, traced in the rolled one): the buffers that entered the program
     are written where they are."""
-    from paddle_tpu.ops import cca, kda, mamba2
+    from paddle_tpu.ops import cca, kda, mamba1, mamba2
     from paddle_tpu.ops.pallas import paged_attention as pa
 
     live = seq_lens > 0
 
     plan = pa.decode_plan(k_cache, page_table, positions, seq_lens,
                           cfg.kv_heads, cfg.head_dim)
-    arrangement = {"mamba": ("conv", "ssm"), "kda": ("kda_conv", "kda_s")}
+    arrangement = {"mamba": ("conv", "ssm"), "kda": ("kda_conv", "kda_s"),
+                   "mamba1": ("conv1", "ssm1")}
     # GSPMD cannot partition a Mosaic kernel: under a mesh the plain form
     ssd_impl = "auto" if mesh is None else "reference"
+    # the differential layers keep each head's whole pair of value lanes
+    wide = {"wide_v": True} if cfg.attn_diff else {}
+    depths = cfg.diff_depths if cfg.attn_diff else {}
+    if cfg.window_layers:
+        # the rings ride with the state pools (``window_k``, ``window_v``:
+        # ``PagedKVCache.window``); a slot's ring is its own run of pages,
+        # the token goes to ``position mod window`` and the step reads the
+        # ``min(length, window)`` entries there are, in whatever order
+        w = cfg.attn_window
+        ring_table = pa.window_table(
+            state["window_k"], w, jnp.arange(seq_lens.shape[0]), live)
+        ring_at, ring_lens = positions % w, jnp.minimum(seq_lens, w)
+        ring_plan = pa.decode_plan(state["window_k"], ring_table, ring_at,
+                                   ring_lens, cfg.kv_heads, cfg.head_dim)
 
     def layer_fn(kind, i, layer, x, carry, held):
         pools, state = list(held[:2]), dict(held[2])
 
         def attend(q, k, v):
-            pools[:] = pa.write_decode_kv(*pools, k, v, i, page_table,
-                                          positions, plan)
+            if kind == "window":
+                ring = pa.write_decode_kv(
+                    state["window_k"], state["window_v"], k, v, i,
+                    ring_table, ring_at, ring_plan)
+                state["window_k"], state["window_v"] = ring
+                return pa.ragged_paged_attention(
+                    q, *ring, i, ring_table, ring_lens, scale=cfg.attn_scale,
+                    impl=attn_impl, kv_heads=cfg.kv_heads, plan=ring_plan,
+                    **wide)
+            at = i
+            if kind == "cross":     # another layer's cache layer: no write
+                at = cfg.cross_reads[i]
+            else:
+                pools[:] = pa.write_decode_kv(*pools, k, v, i, page_table,
+                                              positions, plan)
             return pa.ragged_paged_attention(
-                q, *pools, i, page_table, seq_lens, scale=cfg.attn_scale,
-                impl=attn_impl, kv_heads=cfg.kv_heads, plan=plan)
+                q, *pools, at, page_table, seq_lens, scale=cfg.attn_scale,
+                impl=attn_impl, kv_heads=cfg.kv_heads, plan=plan, **wide)
 
         def keep(name, new):
             """Layer i's rows of pool ``name`` <- ``new`` on live rows."""
@@ -1670,7 +2083,8 @@ def _decode_pattern(cfg: TransformerConfig, params, x, rope, positions,
                     y, state[state_part] = mamba2.ssd_pool_step(
                         state[state_part], i, *args, live, impl=ssd_impl)
                     return y
-                y, new = kda.kda_step(state[state_part][i], *args)
+                step = mamba1.scan_step if kind == "mamba1" else kda.kda_step
+                y, new = step(state[state_part][i], *args)
                 keep(state_part, new)
                 return y
 
@@ -1684,7 +2098,8 @@ def _decode_pattern(cfg: TransformerConfig, params, x, rope, positions,
         x, counts, carry = _pattern_layer(
             cfg, kind, layer, x, rope, attend,
             recurrent(*arrangement[kind]) if kind in arrangement else None,
-            live, carry=carry, window=window)
+            live, carry=carry, window=window,
+            depth=depths[kind][i] if kind in depths else None)
         return x, counts, carry, (*pools, state), None
 
     x, counts, (k_cache, v_cache, state), _ = _run_pattern(

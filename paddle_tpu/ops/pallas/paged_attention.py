@@ -224,6 +224,62 @@ def write_chunk_kv(k_pool, v_pool, k, v, cache_layer, page_table, starts,
             _write_tokens(v_pool, v, cache_layer, pages, pos % ps))
 
 
+# -- a window layer's ring --------------------------------------------------------
+#
+# A layer that sees only its last ``window`` positions keeps, for each
+# batch slot, ONE run of ``window / page_size`` pages of a second pool of
+# the same layout (``kv_pool_shape(window layers, H, 1 + slots * window /
+# page_size, page_size, D)``; page 0 the null page, as in the growing
+# pool): slot ``s`` owns pages ``1 + s * n ... (s + 1) * n``, for good — no
+# allocator, no table on the host.  Position ``p``'s K/V rest at row ``p
+# mod window`` of the run, so a decode step overwrites the position that
+# left the window and reads ``min(length, window)`` rows in whatever order
+# they lie: exact where nothing but the mask tells positions apart (no
+# position signal; softmax is a sum).  A prefill pass writes a slot's run
+# whole, so a reused slot never reads an earlier request's rows.  The
+# writes, the plan and the kernel are the growing pool's own, handed this
+# pool, this table and these lengths.
+
+
+def window_pool_pages(window: int, page_size: int, slots: int) -> int:
+    """Pages of a ring pool: the null page and ``window / page_size`` a
+    slot."""
+    return 1 + slots * (window // page_size)
+
+
+def window_table(pool, window: int, slots, live):
+    """[B, window / page_size] int32: the ring pages of batch slots
+    ``slots`` [B] in ``pool`` (a ring pool); a row that is not ``live``
+    [B] names the null page throughout."""
+    n = window // pool.shape[3]
+    pages = 1 + slots[:, None] * n + jnp.arange(n)[None, :]
+    return jnp.where(live[:, None], pages, 0).astype(jnp.int32)
+
+
+def ring_rows(x, seq_lens, window: int):
+    """x [B, T, H, D], row ``b`` valid to ``seq_lens[b]`` -> [B, window,
+    H, D]: ring row ``r`` holds the LAST valid position congruent to ``r``
+    mod ``window`` (rows no valid position maps to hold a copy of some
+    position: every reader masks them by ``min(length, window)``)."""
+    t = x.shape[1]
+    r = jnp.arange(window)[None, :]
+    last = seq_lens[:, None] - 1
+    at = r + window * jnp.maximum((last - r) // window, 0)
+    return jnp.take_along_axis(x, jnp.clip(at, 0, t - 1)[:, :, None, None],
+                               axis=1)
+
+
+def write_prefill_window(k_pool, v_pool, ks, vs, window: int, slots):
+    """Write a prompt pass's rings (``ring_rows`` of every window layer:
+    ks/vs [window layers, B, window, H, D]) into the ring pools, row
+    ``b``'s into the run of batch slot ``slots[b]``, whole; a slack row
+    (a slot the pool does not have) goes to the null page."""
+    per = window // k_pool.shape[3]
+    have = (k_pool.shape[2] - 1) // per
+    pages = window_table(k_pool, window, slots, slots < have)
+    return _write_pages(k_pool, ks, pages), _write_pages(v_pool, vs, pages)
+
+
 class DecodePlan(NamedTuple):
     """What every cache layer of ONE decode step shares: where the new
     token's K/V rows go (``rows`` [B, H/g, 4]: cache layer 0, head group,
@@ -349,20 +405,33 @@ def paged_prefill_attention(q, k_pool, v_pool, cache_layer, page_table,
 # -- reference implementation --------------------------------------------------
 
 
+def _lane_group_values(v, head_dim: int):
+    """v [B, KV, K, D] -> [B, KV, K, g·D]: every K/V head with the values
+    of its whole lane group side by side (``wide_v``)."""
+    b, kv, k, d = v.shape
+    g = head_group(kv, head_dim)
+    wide = v.reshape(b, kv // g, g, k, d).transpose(0, 1, 3, 2, 4).reshape(
+        b, kv // g, 1, k, g * d)
+    return jnp.broadcast_to(wide, (b, kv // g, g, k, g * d)).reshape(
+        b, kv, k, g * d)
+
+
 def ragged_paged_attention_reference(q, k_pool, v_pool, cache_layer,
                                      page_table, seq_lens, scale=None,
-                                     kv_heads=None):
+                                     kv_heads=None, wide_v=False):
     """Pure-jnp oracle: gather each sequence's pages of cache layer
     ``cache_layer``, mask, softmax.
 
     q: [B, H, D] (one decode token per row); k_pool/v_pool:
-    ``kv_pool_shape``; returns [B, H, D].  Rows with ``seq_lens == 0``
-    produce zeros (idle slots), not NaNs."""
+    ``kv_pool_shape``; returns [B, H, D] (``wide_v``: [B, H, g·D]).  Rows
+    with ``seq_lens == 0`` produce zeros (idle slots), not NaNs."""
     b, h, d = q.shape
     kv = kv_heads or h
     scale = scale if scale is not None else d ** -0.5
     k = _gather_context(k_pool, cache_layer, page_table, kv, d)
     v = _gather_context(v_pool, cache_layer, page_table, kv, d)
+    if wide_v:
+        v = _lane_group_values(v, d)
     s = jnp.einsum("bhrd,bhkd->bhrk", _grouped(q, kv).astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     pos = jnp.arange(k.shape[2])
@@ -376,7 +445,7 @@ def ragged_paged_attention_reference(q, k_pool, v_pool, cache_layer,
     # sum above is a mean of null/stale pages — zero them explicitly to
     # match the kernel's l == 0 path
     out = jnp.where(seq_lens[:, None, None, None] > 0, out, 0.0)
-    return out.reshape(b, h, d).astype(q.dtype)
+    return out.reshape(b, h, v.shape[-1]).astype(q.dtype)
 
 
 # -- the Pallas kernel ---------------------------------------------------------
@@ -527,7 +596,7 @@ def _decode_kernel(layer_ref, rows_ref, blocks_ref, table_ref, steps_ref,
 
 
 def _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale,
-                 interpret, kv_heads=None, work=None):
+                 interpret, kv_heads=None, work=None, wide_v=False):
     b, h, d = q.shape
     kv = kv_heads or h
     rep = h // kv
@@ -588,6 +657,10 @@ def _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale,
     # this head's weights
     j = jnp.arange(g)
     out = out.reshape(b, groups, g, qr, g, d)
+    if wide_v:
+        # every lane of a query head's row: its K/V head's whole lane
+        # group's values under this head's weights (heads that exist)
+        return out[:, :, :, :rep].reshape(b, groups * g * rep, g * d)[:, :h]
     if rep == 1:
         return out[:, :, j, 0, j].reshape(b, groups * g, d)[:, :h]
     out = out[:, :, j, :rep, j]                        # [g, B, H/g, rep, D]
@@ -596,7 +669,8 @@ def _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale,
 
 def ragged_paged_attention(q, k_pool, v_pool, cache_layer, page_table,
                            seq_lens, scale=None, impl="auto", interpret=None,
-                           kv_heads=None, plan: DecodePlan | None = None):
+                           kv_heads=None, plan: DecodePlan | None = None,
+                           wide_v: bool = False):
     """Decode-step attention of q [B, H, D] over cache layer
     ``cache_layer`` of a paged KV-cache (k_pool/v_pool:
     ``kv_pool_shape`` of ``kv_heads`` heads, None = H: query head h reads
@@ -608,7 +682,12 @@ def ragged_paged_attention(q, k_pool, v_pool, cache_layer, page_table,
     per-block Python loop, far too slow to serve from), or "auto"
     (kernel on TPU, reference elsewhere).  ``plan``: the step's
     :func:`decode_plan`, whose work list the kernel then takes instead of
-    making its own."""
+    making its own.  ``wide_v``: a query head keeps ALL ``g·D`` lanes of
+    its row — the values of every head of its K/V head's lane group under
+    its own weights, [B, H, g·D] — where the caller otherwise keeps the
+    head's own ``D`` (differential attention: a K/V pair ``[v1 | v2]`` IS
+    a lane group, and each of a pair's two softmaxes weighs both; the
+    cache's heads must fill whole lane groups)."""
     d = q.shape[-1]
     scale = scale if scale is not None else d ** -0.5
     from paddle_tpu.ops.pallas import resolve_impl, resolve_interpret
@@ -616,10 +695,10 @@ def ragged_paged_attention(q, k_pool, v_pool, cache_layer, page_table,
     if resolve_impl(impl, "ragged_paged_attention") == "reference":
         return ragged_paged_attention_reference(
             q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale=scale,
-            kv_heads=kv_heads)
+            kv_heads=kv_heads, **({"wide_v": True} if wide_v else {}))
     return _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens,
                         scale, resolve_interpret(interpret), kv_heads,
-                        plan and plan.work)
+                        plan and plan.work, wide_v)
 
 
 def block_paged_attention(q, k_pool, v_pool, cache_layer, page_table,

@@ -84,9 +84,14 @@ by the arrangement their program gives the expert product:
 ``parallel.moe.product_path``, which the spans say as ``moe_path``) and gauge
 ``serve_moe_load_max_over_mean`` (decode steps: the busiest held
 expert's tokens over the mean); under layers that keep a state a
-sequence counter ``serve_state_bytes_total{kind=mamba|kda|attn}`` (the
-state pools' bytes the passes read and wrote for their live rows: a
-decode step both, a prefill pass the write); under delta-rule (KDA)
+sequence counter ``serve_state_bytes_total{kind=mamba|mamba1|kda|attn}``
+(the state pools' bytes the passes read and wrote for their live rows: a
+decode step both, a prefill pass the write); under cross-attention or
+window layers counters ``serve_shared_kv_bytes_total`` (K/V of the
+growing cache the decode steps' layer-reads streamed: its own layers AND
+the layers that read them, every live context once a read) and
+``serve_window_tokens_total`` (ring rows a window layer read:
+``min(length, window)`` a live slot); under delta-rule (KDA)
 layers counter ``serve_kda_chunk_tokens_total`` (tokens of the chunks
 that held a prompt token); under a model that
 generates by blocks
@@ -225,9 +230,12 @@ def _resident_bytes(engine, _) -> dict:
 
     nbytes = lambda tree: sum(int(x.nbytes) for x in jax.tree.leaves(tree))
     cache = engine.cache
-    return {"params_bytes": nbytes(engine.params),
+    said = {"params_bytes": nbytes(engine.params),
             "pool_bytes": nbytes((cache.k, cache.v)),
             "state_bytes": nbytes(cache.state)}
+    if cache.window:
+        said["window_bytes"] = nbytes(cache.window)
+    return said
 
 
 class ServingEngine:
@@ -270,6 +278,12 @@ class ServingEngine:
                 "on a block boundary, and a shared prefix page may hold the "
                 "prompt's tail, which is the first generated block's to "
                 "write; none of it is built")
+        if (cfg.window_layers or cfg.cross_reads) and s.incremental_prefill:
+            raise NotImplementedError(
+                "prefix_cache / prefill_chunk_tokens with window ('W') or "
+                "cross ('X') attention layers: a chunk would have to read "
+                "and advance its slot's ring (forward_prefill_chunk walks "
+                "neither kind), and a shared prefix has no ring to share")
         if cfg.state_layers and s.incremental_prefill:
             raise NotImplementedError(
                 "prefix_cache / prefill_chunk_tokens with layers that keep "
@@ -310,10 +324,11 @@ class ServingEngine:
                 cfg.cache_layers, cfg.kv_heads, cfg.head_dim, s.num_pages,
                 s.page_size, s.max_slots, s.max_pages_per_seq,
                 dtype=cfg.dtype, prefix_cache=s.prefix_cache,
-                state_parts=cfg.state_parts, block_len=cfg.block_len)
-        (self.cache.k, self.cache.v, self.cache.state,
+                state_parts=cfg.state_parts, block_len=cfg.block_len,
+                window=(cfg.window_layers, cfg.attn_window))
+        (self.cache.k, self.cache.v, self.cache.state, self.cache.window,
          self.cache.tokens) = self.place(
-            (self.cache.k, self.cache.v, self.cache.state,
+            (self.cache.k, self.cache.v, self.cache.state, self.cache.window,
              self.cache.tokens))
         self.scheduler = Scheduler(s, self.cache, cfg.block_len)
         # 2·params is the standard per-token forward-FLOPs estimate —
@@ -364,6 +379,14 @@ class ServingEngine:
         if cfg.pattern is not None:
             self._loop_args.update(kv_heads=cfg.kv_heads,
                                    state_layers=cfg.state_layers)
+        # a decoder-hybrid-decoder pattern: layer-reads of the growing
+        # cache a step ("*" and the "X" layers that read it), the window
+        # layers' rings, and where a prefill pass narrows to its last
+        # tokens (``_yoco_args``)
+        self._yoco = bool(cfg.window_layers or cfg.cross_reads)
+        if self._yoco:
+            self._loop_args.update(kv_reads=cfg.kv_reads,
+                                   window_layers=cfg.window_layers)
         # the state one slot holds, by the kind of layer that keeps it (a
         # pass's spans and the counter say what of it the pass moves)
         self._state_slot_bytes = {
@@ -749,6 +772,30 @@ class ServingEngine:
                 "kv_block_len": block,
                 "kv_steps": int(self._kv_steps(seq_lens, block).sum())}
 
+    def _yoco_args(self, seq_lens) -> dict:
+        """What a decode step of a pattern with cross or window layers
+        reads beside its own cache layers, as span args and counters: the
+        bytes of the growing cache its ``kv_reads`` layer-reads stream
+        (every live context once a read) and the ring rows its window
+        layers read (``min(length, window)`` a live slot, a layer)."""
+        cfg, reg = self.cfg, self.registry
+        ctx = int(seq_lens.sum())
+        said = {"shared_kv_bytes": cfg.kv_reads * ctx * (
+            self.kv_bytes_per_token // max(cfg.cache_layers, 1))}
+        reg.counter(
+            "serve_shared_kv_bytes_total",
+            "K/V bytes of the growing cache the decode steps' layer-reads "
+            "streamed: every '*' and 'X' layer reads every live context"
+        ).inc(said["shared_kv_bytes"])
+        if cfg.window_layers:
+            said["window_tokens"] = int(
+                np.minimum(seq_lens, cfg.attn_window).sum())
+            reg.counter(
+                "serve_window_tokens_total",
+                "ring rows a window layer read over the decode steps: "
+                "min(length, window) a live slot").inc(said["window_tokens"])
+        return said
+
     def _make_ready(self) -> dict:
         """Compile every program this engine will dispatch, before the
         first of them serves (the step that admits the first request
@@ -914,6 +961,12 @@ class ServingEngine:
             fill["blocks_written"] = fill["prompt_tokens"] // self._block
         if self._state_slot_bytes:      # the state the rows leave
             fill["state_bytes"] = self._state_moved(len(admitted), 1)
+        if self._yoco:
+            # positions that walked the layers behind the last producer:
+            # the live rows where the pass narrows to their last tokens
+            fill["cross_positions"] = (
+                len(admitted) if self.cfg.narrow_at is not None
+                else fill["prompt_tokens"])
         if self._kda:
             # the chunks that hold a token and the causal query-key pairs
             # of an attention layer
@@ -980,6 +1033,10 @@ class ServingEngine:
             if bl > 1:      # masked going in: the host's count
                 said.update(positions=len(live) * bl,
                             masked_in=sum(a.block.masked for a in live))
+        if self._yoco:      # counted always, said where a span listens
+            reads = self._yoco_args(batch["seq_lens"])
+            if tracer.enabled:
+                said.update(reads)
         if self._state_slot_bytes:  # every live row's, read and written
             moved = self._state_moved(len(live), 2)
             if tracer.enabled:
@@ -1005,9 +1062,10 @@ class ServingEngine:
             # own dispatch and the wait
             self._open(tracer, p)
         t0 = time.perf_counter()
-        p.out, cache.k, cache.v, cache.state, tokens = program(
+        p.out, cache.k, cache.v, by_slot, tokens = program(
             self._params(), self._base_key, cache.k, cache.v,
             *self._carried(p.kind, args))
+        cache.carry(by_slot)
         if tokens is not None:
             cache.tokens = tokens
         if p.args:
@@ -1024,11 +1082,12 @@ class ServingEngine:
         its batch fields and what is carried.  The token array is a
         one-token decode step's ids; a prefill pass by blocks never sees it."""
         cache = self.cache
+        by_slot = cache.carried()   # the state pools and the rings
         if self._block == 1 and kind == "decode":
-            return (cache.tokens, *args, cache.state)
+            return (cache.tokens, *args, by_slot)
         if self._block > 1 and kind == "prefill":
-            return (*args, cache.state)
-        return (*args, cache.state, cache.tokens)
+            return (*args, by_slot)
+        return (*args, by_slot, cache.tokens)
 
     def _open(self, tracer, p: _Pass) -> None:
         if p.span is None:
@@ -1392,10 +1451,17 @@ def _serving_fns(cfg, attn_impl, donate, policy="low_confidence_static"):
         # state layer (a slack row's slot does not exist: dropped)
         state = dict(state or {})
         for name, pool in state.items():
+            if name not in extras["state"]:
+                continue    # a ring: below
             for i in range(pool.shape[0]):
                 pool = pool.at[i, slots].set(
                     extras["state"][name][i].astype(pool.dtype), mode="drop")
             state[name] = pool
+        if "window" in extras:
+            # each row's last window, whole, into its slot's ring
+            state["window_k"], state["window_v"] = pa.write_prefill_window(
+                state["window_k"], state["window_v"], *extras["window"],
+                cfg.attn_window, slots)
         if cfg.block_len > 1:
             # nothing is sampled: the pass leaves K/V (the head is dead
             # code here); the counts ride behind a row of zeros
@@ -1474,7 +1540,8 @@ def _serving_fns(cfg, attn_impl, donate, policy="low_confidence_static"):
     # blocks the prefill programs are not, and the block pass takes it last)
     def donated(state_at, tokens_at):
         return tuple(donate) + (
-            (state_at,) if donate and cfg.state_layers else ()) + (
+            (state_at,) if donate and (cfg.state_layers
+                                       or cfg.window_layers) else ()) + (
             (tokens_at,) if donate else ())
 
     fns = (jax.jit(prefill, donate_argnums=donated(10, 11)),

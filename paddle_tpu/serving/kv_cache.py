@@ -305,6 +305,16 @@ class PagedKVCache:
     the prefix trie has no snapshot of it (``ServingEngine`` refuses the
     combination).
 
+    ``window``: {"window_k", "window_v"}, the RINGS of a model's window
+    layers (``window`` = (how many such layers, their window); empty
+    without them): two more pools of the page pools' layout and type,
+    ``1 + max_slots x window / page_size`` pages — slot ``s`` owns one
+    run of them for good, position ``p``'s K/V at row ``p mod window``
+    (``ops/pallas/paged_attention.py``: "a window layer's ring").  A
+    slot's bytes there do not grow with its context, no allocator or
+    table on the host knows them, and they ride the programs beside
+    ``state`` (``carried`` / ``carry``), donated alike.
+
     ``tokens``: int32 [max_slots], the last token each slot's sequence
     sampled — the next decode step's input, kept where it was made: the
     from-zero prefill programs scatter a row's first token into it, the
@@ -321,10 +331,12 @@ class PagedKVCache:
                  num_pages: int, page_size: int, max_slots: int,
                  max_pages_per_seq: int, dtype=None,
                  prefix_cache: bool = False,
-                 state_parts: dict | None = None, block_len: int = 1):
+                 state_parts: dict | None = None, block_len: int = 1,
+                 window: tuple = (0, 0)):
         import jax.numpy as jnp
 
-        from paddle_tpu.ops.pallas.paged_attention import init_kv_pages
+        from paddle_tpu.ops.pallas.paged_attention import (
+            init_kv_pages, window_pool_pages)
 
         self.page_size = page_size
         self.max_pages_per_seq = max_pages_per_seq
@@ -334,6 +346,15 @@ class PagedKVCache:
         self.state = {
             part: jnp.zeros((layers, max_slots, *shape), jnp.float32)
             for part, (layers, shape) in (state_parts or {}).items()}
+        self.window = {}
+        if window[0]:
+            enforce(window[1] % page_size == 0,
+                    f"a window of {window[1]} positions is not whole pages "
+                    f"of {page_size}: a slot's ring is a run of pages")
+            self.window = dict(zip(("window_k", "window_v"), init_kv_pages(
+                window[0], num_heads,
+                window_pool_pages(window[1], page_size, max_slots),
+                page_size, head_dim, dtype=dtype or jnp.float32)))
         self.tokens = jnp.zeros(
             (max_slots,) if block_len == 1 else (max_slots, 2 * block_len),
             jnp.int32)
@@ -348,6 +369,24 @@ class PagedKVCache:
         """Bytes of fixed state one sequence holds, over every part and
         every layer that keeps it."""
         return sum(int(a.nbytes) // a.shape[1] for a in self.state.values())
+
+    @property
+    def window_bytes_per_slot(self) -> int:
+        """Bytes of ring one slot owns, K and V over every window layer
+        (the null page left out)."""
+        slots = self.page_table.shape[0]
+        return sum(int(a.nbytes) // a.shape[2] * ((a.shape[2] - 1) // slots)
+                   for a in self.window.values())
+
+    def carried(self) -> dict:
+        """What rides a program by batch slot, in one tree: the float32
+        state pools and the rings."""
+        return {**self.state, **self.window}
+
+    def carry(self, tree: dict) -> None:
+        """A program's ``carried`` tree back where it came from."""
+        self.window = {n: tree[n] for n in self.window}
+        self.state = {n: a for n, a in tree.items() if n not in self.window}
 
     def pages_needed(self, tokens: int) -> int:
         return -(-tokens // self.page_size)

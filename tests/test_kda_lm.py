@@ -371,7 +371,7 @@ def test_engine_refuses_incremental_prefill_beside_kda_state(serving,
     (dict(kda_heads=0), ValueError, "needs kda_heads > 0"),
     (dict(kda_conv=1), ValueError, "kda_conv >= 2"),
     (dict(kda_chunk=24), ValueError, "multiple of 16"),
-    (dict(pattern="*EKEKEKX"), ValueError, "characters of"),
+    (dict(pattern="*EKEKEKQ"), ValueError, "characters of"),
 ])
 def test_config_refuses_by_name(fields, err, said):
     with pytest.raises(err, match=said):
@@ -410,7 +410,7 @@ def test_default_fields_draw_the_parents_weights(fields):
     cfg = T.TransformerConfig(**{**base, **fields})
     assert (cfg.kda_heads, cfg.kda_conv, cfg.kda_chunk, cfg.attn_gate) == (
         0, 4, 64, False)
-    assert len(dataclasses.fields(T.TransformerConfig)) == 54
+    assert len(dataclasses.fields(T.TransformerConfig)) == 62
     p = lm_toy.jitted(T.init_params, cfg)(jax.random.key(0))
     names = {k for b in (p["blocks"] if cfg.pattern else [p["blocks"]])
              for k in b}
