@@ -121,6 +121,10 @@ class Sizes:
         ragged_paged_attention_sdar=(64, 4, 128, 3073, 16, 48),
         ragged_paged_attention_zaya=(64, 2, 128, 8193, 16, 128),
         ragged_paged_attention_vmem=(4, 32, 128, 129, 16, 32),
+        # the writing form (``decode_attention``) runs at the gpt2l, ouro
+        # and zaya shapes above and at phi-4-mini-flash's ring: 64 slots'
+        # runs of 32 pages, 20 K/V heads of 64 under 40 query heads
+        decode_attention_ring=(64, 20, 64, 2049, 16, 32),
         # a one-row prefill pass of solar-open2-250b's KDA layer
         kda_prefill=(1, 4096, 64, 64),          # B, T, H, chunk  (D = 128)
         # a round of that pass's sorted expert product: gate | up, down
@@ -657,6 +661,25 @@ def _kernel_cases() -> list[KernelCase]:
                 normal(key, 1, pool, dtype), normal(key, 2, pool, dtype),
                 jnp.int32(1), table % pages, lens)
 
+    def make_token(ring):
+        """``decode_attention``'s arguments over ``make_paged``'s pools:
+        the token of every row at its last position or, ``ring``, anywhere
+        in a run that is full (so mostly not in the row's last block),
+        under two query heads a K/V head."""
+        def make(shape, key):
+            b, h, d, _, ps, maxp = shape
+            q, kc, vc, layer, table, lens = make_paged(shape, key, bf16)
+            at = lens - 1
+            if ring:
+                q = normal(key, 4, (b, 2 * h, d), bf16)
+                lens = jnp.full((b,), maxp * ps, jnp.int32)
+                at = jax.random.randint(jax.random.fold_in(key, 5), (b,), 0,
+                                        maxp * ps)
+            return (q, normal(key, 6, (b, h, d), bf16),
+                    normal(key, 7, (b, h, d), bf16), kc, vc, layer, table,
+                    at, lens)
+        return make
+
     def make_kda(shape, key):
         b, t, h, _ = shape
         d = kda_kernel.HEAD_DIM
@@ -763,6 +786,21 @@ def _kernel_cases() -> list[KernelCase]:
         return lambda *a: pa.ragged_paged_attention(
             *a, impl="kernel", interpret=interp)
 
+    def decode_case(cell, ring=False):
+        """The decode step's write + attention in one call against the
+        scatter and the oracle: the attention, then both pools whole."""
+        def run(impl, interp, s):
+            # the ring's: two query heads a K/V head, wide values
+            form = dict(kv_heads=s[1], wide_v=True) if ring else {}
+            return lambda *a: pa.decode_attention(
+                *a, impl=impl, interpret=interp, **form)
+        return case(
+            f"decode_attention[{cell}]", make_token(ring),
+            lambda interp, s: run("kernel", interp, s),
+            lambda s: run("reference", None, s), tol=BF16_TOL,
+            shape_key=("decode_attention_" if ring
+                       else "ragged_paged_attention_") + cell)
+
     return [
         case("lstm_seq", make_lstm,
              lambda interp, s: lambda *a: lstm.lstm_seq(*a, False, interp,
@@ -826,6 +864,8 @@ def _kernel_cases() -> list[KernelCase]:
         case("ragged_paged_attention[vmem]", make_paged, paged_kernel,
              lambda s: pa.ragged_paged_attention_reference, tol=MXU_TOL,
              shape_key="ragged_paged_attention_vmem"),
+        *(decode_case(cell) for cell in ("gpt2l", "ouro", "zaya")),
+        decode_case("ring", ring=True),
         case("kda_prefill", make_kda,
              lambda interp, s: lambda *a: kda.kda_prefill(
                  *a, chunk=s[3], impl="kernel", interpret=interp),

@@ -1902,9 +1902,13 @@ def forward_decode(cfg: TransformerConfig, params: dict, ids: jax.Array,
     seq_lens [B] = positions + 1 on live rows and 0 on idle rows,
     page_table [B, max_pages], k_cache/v_cache the pools of
     ``paged_attention.kv_pool_shape`` (``init_kv_pages``).  Each block
-    writes the new token's K/V into its pages, then runs ragged paged
-    attention over the whole resident context.  Returns (logits [B, V],
-    k_cache', v_cache'); idle rows write the null page and read zeros.
+    hands the new token's K/V and its query to
+    ``paged_attention.decode_attention``, which writes the token into its
+    page and attends the whole resident context, the token included: on a
+    TPU one Mosaic call that does both, elsewhere a scatter and the jnp
+    reference.  Returns (logits [B, V], k_cache', v_cache'); idle rows
+    read zeros and write nothing but, on the reference path, the null
+    page.
 
     ``attn_impl`` is the paged-attention implementation ("auto" =
     Pallas kernel on TPU, jnp reference elsewhere) — deliberately
@@ -1925,12 +1929,10 @@ def forward_decode(cfg: TransformerConfig, params: dict, ids: jax.Array,
 
     def layer_fn(x, layer, cache_layer, kc, vc):
         def attend(q, k, v):
-            pools = pa.write_decode_kv(kc, vc, k, v, cache_layer, page_table,
-                                       positions, plan)
-            return pa.ragged_paged_attention(
-                q, *pools, cache_layer, page_table, seq_lens,
-                scale=cfg.attn_scale, impl=attn_impl, kv_heads=cfg.kv_heads,
-                plan=plan), pools
+            return pa.decode_attention(
+                q, k, v, kc, vc, cache_layer, page_table, positions,
+                seq_lens, scale=cfg.attn_scale, impl=attn_impl,
+                kv_heads=cfg.kv_heads, plan=plan)
 
         x, _, pools = _block(cfg, x, layer, attend, rope)
         return x, pools
@@ -2044,24 +2046,22 @@ def _decode_pattern(cfg: TransformerConfig, params, x, rope, positions,
         pools, state = list(held[:2]), dict(held[2])
 
         def attend(q, k, v):
+            shared = dict(scale=cfg.attn_scale, impl=attn_impl,
+                          kv_heads=cfg.kv_heads, **wide)
             if kind == "window":
-                ring = pa.write_decode_kv(
-                    state["window_k"], state["window_v"], k, v, i,
-                    ring_table, ring_at, ring_plan)
+                a, ring = pa.decode_attention(
+                    q, k, v, state["window_k"], state["window_v"], i,
+                    ring_table, ring_at, ring_lens, plan=ring_plan, **shared)
                 state["window_k"], state["window_v"] = ring
-                return pa.ragged_paged_attention(
-                    q, *ring, i, ring_table, ring_lens, scale=cfg.attn_scale,
-                    impl=attn_impl, kv_heads=cfg.kv_heads, plan=ring_plan,
-                    **wide)
-            at = i
+                return a
             if kind == "cross":     # another layer's cache layer: no write
-                at = cfg.cross_reads[i]
-            else:
-                pools[:] = pa.write_decode_kv(*pools, k, v, i, page_table,
-                                              positions, plan)
-            return pa.ragged_paged_attention(
-                q, *pools, at, page_table, seq_lens, scale=cfg.attn_scale,
-                impl=attn_impl, kv_heads=cfg.kv_heads, plan=plan, **wide)
+                return pa.ragged_paged_attention(
+                    q, *pools, cfg.cross_reads[i], page_table, seq_lens,
+                    plan=plan, **shared)
+            a, pools[:] = pa.decode_attention(
+                q, k, v, *pools, i, page_table, positions, seq_lens,
+                plan=plan, **shared)
+            return a
 
         def keep(name, new):
             """Layer i's rows of pool ``name`` <- ``new`` on live rows."""

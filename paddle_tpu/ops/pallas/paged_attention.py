@@ -27,8 +27,9 @@ module owns that cache layout end to end:
 - per-sequence **page tables**: ``page_table[b, i]`` = pool page holding
   positions ``[i*page_size, (i+1)*page_size)`` of sequence ``b``.  Page 0
   is the NULL/scratch page: never allocated to a sequence, it absorbs the
-  writes of idle batch rows (so the decode step needs no host-side
-  gather/compact of active slots) and backs unused table entries, which
+  scatters' writes of idle batch rows (so the decode step needs no
+  host-side gather/compact of active slots; the decode kernel's own
+  write skips such a row instead) and backs unused table entries, which
   the kernel never reads;
 - ``seq_lens[b]`` = tokens resident INCLUDING the one being decoded; the
   decode query is the last token, so the length mask alone is the causal
@@ -80,7 +81,14 @@ Two interchangeable implementations of the attention itself:
   score columns (128 tokens) as make a step carry a megabyte of K and V
   at the cache's bytes a token — one pass where the cache holds sixteen
   lane groups, two at ten, eight (1,024 tokens) where it holds two —
-  less where VMEM or the table is smaller.  It is exactly one Mosaic call per
+  less where VMEM or the table is smaller.  The decode step's WRITE rides
+  the same call (``decode_attention``): the work item whose block holds
+  a row's write position puts the new token's K/V row into the page it
+  has just copied into VMEM and sends that page back, one copy a pool,
+  under the block's arithmetic — both pools are then outputs aliased to
+  their inputs.  (As two XLA scatters ahead of the call the write cost
+  0.08 µs a 128-lane ROW: 14% of a ``gpt2-large`` decode step for 24
+  tokens a layer, PERF.md §6, PR 51.)  It is exactly one Mosaic call per
   cache layer;
   the benchmark's reducers count ``tpu_custom_call``s inside
   ``jit_decode`` (``loop_passes_per_token``) and charge this name's time
@@ -283,11 +291,14 @@ def write_prefill_window(k_pool, v_pool, ks, vs, window: int, slots):
 class DecodePlan(NamedTuple):
     """What every cache layer of ONE decode step shares: where the new
     token's K/V rows go (``rows`` [B, H/g, 4]: cache layer 0, head group,
-    page, row of the page) and the kernel's work list (``work``:
-    ``_decode_work``).  Index arithmetic over ``page_table``, ``positions``
-    and ``seq_lens`` alone — a program that walks the layers in a loop
-    makes it once, before the loop, and hands it to :func:`write_decode_kv`
-    and :func:`ragged_paged_attention` (XLA moves none of it out of a
+    page, row of the page — the scatter's index rows, so the REFERENCE
+    path's alone: the kernel finds the page from ``positions`` itself, and
+    a program built on it drops ``rows`` as dead code) and the kernel's
+    work list (``work``: ``_decode_work``).  Index arithmetic over
+    ``page_table``, ``positions`` and ``seq_lens`` alone — a program that
+    walks the layers in a loop makes it once, before the loop, and hands
+    it to :func:`decode_attention` (or :func:`write_decode_kv`) and
+    :func:`ragged_paged_attention` (XLA moves none of it out of a
     ``while`` body: it was a third of the operations of a ``gpt2-large``
     decode step, PERF.md §6, PR 33)."""
 
@@ -325,7 +336,8 @@ _ROWS = lax.ScatterDimensionNumbers(
 def write_decode_kv(k_pool, v_pool, k, v, cache_layer, page_table, positions,
                     plan: DecodePlan | None = None):
     """Write one new token's K/V per batch row into cache layer
-    ``cache_layer`` of the pools.
+    ``cache_layer`` of the pools: the reference half of the decode step's
+    one write (:func:`decode_attention`; on a TPU the kernel writes).
 
     k/v: [B, H, D]; k_pool/v_pool: ``kv_pool_shape``; page_table:
     [B, max_pages]; positions: [B] absolute token index.
@@ -514,8 +526,16 @@ def _decode_work(page_table, seq_lens, n, page_size):
 
 
 def _decode_kernel(layer_ref, rows_ref, blocks_ref, table_ref, steps_ref,
-                   lens_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
-                   acc_ref, m_ref, l_ref, *, scale, page_size, n):
+                   lens_ref, *refs, scale, page_size, n, token_at=None):
+    if token_at is None:
+        q_ref, k_hbm, v_hbm, o_ref, *scratch = refs
+    else:
+        # the writing form: the rows' write positions, and the pools as
+        # outputs aliased to the inputs — every copy, in and out, goes
+        # through the output refs (one buffer on the chip; the interpreter
+        # keeps two, and only the outputs see the writes)
+        at_ref, q_ref, _, _, o_ref, k_hbm, v_hbm, *scratch = refs
+    k_buf, v_buf, sems, acc_ref, m_ref, l_ref = scratch
     g = pl.program_id(0)
     i = blocks_ref[g]
     seq_len = lens_ref[rows_ref[g]]
@@ -557,12 +577,52 @@ def _decode_kernel(layer_ref, rows_ref, blocks_ref, table_ref, steps_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
+    if token_at is not None:
+        at = at_ref[rows_ref[g]]
+        # the block that holds the row's write position — the row's last
+        # for a growing cache, any for a ring; never an idle row's step
+        writes = (at // block == i) & (at < seq_len)
+        # the token's page: its rows of the block, its place in the pools
+        off = pl.ds(pl.multiple_of(at % block // page_size * page_size,
+                                   page_size), page_size)
+        dst = table_ref[rows_ref[g] * maxp + at // page_size]
+
+        def token_page(then):
+            """``then(copy)`` of the token's page, as the buffer holds it,
+            back to its place in each pool."""
+            for p, (hbm, vmem) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                then(pltpu.make_async_copy(vmem.at[slot, :, off],
+                                           hbm.at[layer, :, dst],
+                                           sems.at[p, 2]))
+
     @pl.when(i * block < seq_len)  # false only for an idle row's one step
     def _block():
         pages(g, slot, lambda copy: copy.wait())
-        # [H/g, g * 8, g·D]: each head's query on 8 sublanes, zero in the
-        # lanes of its group's other heads
-        q = q_ref[0]
+        if token_at is None:
+            # [H/g, g * 8, g·D]: each head's query on 8 sublanes, zero in
+            # the lanes of its group's other heads
+            q = q_ref[0]
+        else:
+            q = q_ref[0, :, :acc_ref.shape[1]]      # the query rows
+
+            @pl.when(writes)
+            def _token():
+                # the token's K and V rows are the first two of the
+                # sublane tile behind the queries.  A select over the
+                # page's rows puts them in: one row of a packed dtype is
+                # half a sublane, no store of its own.  The page then goes
+                # back under the block's arithmetic, which reads the
+                # token from the buffer: HBM need not hold it yet.
+                tile = q_ref[0, :, token_at:].astype(jnp.float32)
+                here = lax.broadcasted_iota(
+                    jnp.int32, (1, page_size, 1), 1) == at % page_size
+                for p, buf in enumerate((k_buf, v_buf)):
+                    page = buf.at[slot, :, off]
+                    new = jnp.broadcast_to(tile[:, p:p + 1], page.shape)
+                    page[...] = jnp.where(here, new.astype(buf.dtype),
+                                          page[...])
+                token_page(lambda copy: copy.start())
+
         k = k_buf[slot]                                 # [H/g, block, g·D]
 
         def live(shape, axis):
@@ -588,6 +648,15 @@ def _decode_kernel(layer_ref, rows_ref, blocks_ref, table_ref, steps_ref,
             preferred_element_type=jnp.float32)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
+        if token_at is not None:
+            # before the next work item's ``_next`` refills this buffer,
+            # and before the call ends.  (Waited instead at the row's
+            # NEXT write, out of a staging buffer: the same time on the
+            # chip — PERF.md §6, PR 51 — so the copy hides as it stands)
+            @pl.when(writes)
+            def _written():
+                token_page(lambda copy: copy.wait())
+
     @pl.when((i + 1) * block >= seq_len)  # the row's last step
     def _finalize():
         # idle rows (seq_len 0) never accumulated: l == 0 -> output 0
@@ -596,7 +665,11 @@ def _decode_kernel(layer_ref, rows_ref, blocks_ref, table_ref, steps_ref,
 
 
 def _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale,
-                 interpret, kv_heads=None, work=None, wide_v=False):
+                 interpret, kv_heads=None, work=None, wide_v=False,
+                 token=None):
+    """The Mosaic call.  ``token``: None, or the step's new ``(k, v)``
+    [B, KV, D] and the rows' write positions [B] — the writing form,
+    which returns ``(attention, k_pool, v_pool)``."""
     b, h, d = q.shape
     kv = kv_heads or h
     rep = h // kv
@@ -620,20 +693,49 @@ def _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale,
     elif qr > rep:
         qb = jnp.pad(qb, [(0, 0)] * 3 + [(0, qr - rep)] + [(0, 0)] * 2)
     qb = qb.reshape(b, groups, g * qr, lanes)
-    row = pl.BlockSpec(
-        (1, groups, g * qr, lanes),
-        lambda s, layer, rows, blocks, table, steps, lens: (rows[s], 0, 0, 0))
+    # (index maps: the grid index, then the prefetched scalars)
+    by_row = lambda s, layer, rows, *_: (rows[s], 0, 0, 0)
+    row = pl.BlockSpec((1, groups, g * qr, lanes), by_row)
     pool = pl.BlockSpec(memory_space=pl.ANY)  # where it rests: the kernel copies
+    grid = (steps[0],)
+    scalars = [jnp.asarray(cache_layer, jnp.int32).reshape(1), rows, blocks,
+               table, steps, seq_lens.astype(jnp.int32)]
+    out_row = jax.ShapeDtypeStruct((b, groups, g * qr, lanes), q.dtype)
+    if token is None:
+        q_row, token_at = row, None
+        out_specs, out_shape, aliases = row, out_row, {}
+    else:
+        # the token rides the query's block, in the query's dtype (k, v
+        # and q are one projection's): one more sublane tile a row, whose
+        # first two rows are the K and V rows as the pool will hold them.
+        # Two more block specs would cost their bookkeeping every grid
+        # step (PERF.md §6, PR 27)
+        k_new, v_new, at = token
+        tile = 8 * max(4 // q.dtype.itemsize, 1)
+        token_at = round_up(g * qr, tile)
+        new = jnp.stack([_pack_heads(x).astype(k_pool.dtype).astype(q.dtype)
+                         for x in (k_new, v_new)], axis=2)
+        qb = jnp.concatenate([
+            qb, jnp.zeros((b, groups, token_at - g * qr, lanes), q.dtype),
+            new, jnp.zeros((b, groups, tile - 2, lanes), q.dtype)], axis=2)
+        q_row = pl.BlockSpec((1, groups, token_at + tile, lanes), by_row)
+        scalars.append(at.astype(jnp.int32))
+        out_specs = [row, pool, pool]
+        out_shape = [out_row, *(jax.ShapeDtypeStruct(x.shape, x.dtype)
+                                for x in (k_pool, v_pool))]
+        # operands count the prefetched scalars: both pools, in place
+        aliases = {len(scalars) + 1: 1, len(scalars) + 2: 2}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         # the cache layer, the work list and seq_lens ride SMEM
-        num_scalar_prefetch=6,
-        grid=(steps[0],),
-        in_specs=[row, pool, pool],
-        out_specs=row,
+        num_scalar_prefetch=len(scalars),
+        grid=grid,
+        in_specs=[q_row, pool, pool],
+        out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((2, groups, n * page_size, lanes), k_pool.dtype),
             pltpu.VMEM((2, groups, n * page_size, lanes), v_pool.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),  # [pool, buffer]
+            # [pool, buffer | the token's page going back]
+            pltpu.SemaphoreType.DMA((2, 2 if token is None else 3)),
             pltpu.VMEM((groups, g * qr, lanes), jnp.float32),
             pltpu.VMEM((groups, g * qr, _LANES), jnp.float32),
             pltpu.VMEM((groups, g * qr, _LANES), jnp.float32),
@@ -641,17 +743,19 @@ def _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale,
     )
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, page_size=page_size,
-                          n=n),
+                          n=n, token_at=token_at),
         name="paged_attention_decode",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qb.shape, q.dtype),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
         compiler_params=tpu_compiler_params(
             # in order: a row's steps share its accumulators and output
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(jnp.asarray(cache_layer, jnp.int32).reshape(1), rows, blocks, table,
-      steps, seq_lens.astype(jnp.int32), qb, k_pool, v_pool)
+    )(*scalars, qb, k_pool, v_pool)
+    if token is not None:
+        out, *pools = out
     # rows [qr·j, qr·(j + 1)) hold K/V head (group, j)'s query heads in
     # ITS lanes; the rest of each row is the other heads' values under
     # this head's weights
@@ -660,11 +764,20 @@ def _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale,
     if wide_v:
         # every lane of a query head's row: its K/V head's whole lane
         # group's values under this head's weights (heads that exist)
-        return out[:, :, :, :rep].reshape(b, groups * g * rep, g * d)[:, :h]
-    if rep == 1:
-        return out[:, :, j, 0, j].reshape(b, groups * g, d)[:, :h]
-    out = out[:, :, j, :rep, j]                        # [g, B, H/g, rep, D]
-    return out.transpose(1, 2, 0, 3, 4).reshape(b, groups * g * rep, d)[:, :h]
+        out = out[:, :, :, :rep].reshape(b, groups * g * rep, g * d)[:, :h]
+    elif rep == 1:
+        out = out[:, :, j, 0, j].reshape(b, groups * g, d)[:, :h]
+    else:
+        out = out[:, :, j, :rep, j]                    # [g, B, H/g, rep, D]
+        out = out.transpose(1, 2, 0, 3, 4).reshape(
+            b, groups * g * rep, d)[:, :h]
+    return out if token is None else (out, *pools)
+
+
+# traced and lowered once a program, however many layers an unrolled walk
+# spells out (the reading form stays as it was: one call a cache layer)
+_write_attend_kernel = jax.jit(
+    _kernel_impl, static_argnames=("scale", "interpret", "kv_heads", "wide_v"))
 
 
 def ragged_paged_attention(q, k_pool, v_pool, cache_layer, page_table,
@@ -699,6 +812,47 @@ def ragged_paged_attention(q, k_pool, v_pool, cache_layer, page_table,
     return _kernel_impl(q, k_pool, v_pool, cache_layer, page_table, seq_lens,
                         scale, resolve_interpret(interpret), kv_heads,
                         plan and plan.work, wide_v)
+
+
+def decode_attention(q, k, v, k_pool, v_pool, cache_layer, page_table,
+                     positions, seq_lens, scale=None, impl="auto",
+                     interpret=None, kv_heads=None,
+                     plan: DecodePlan | None = None, wide_v: bool = False):
+    """A decode step's cache write and attention of one layer, as one:
+    the new token's k/v [B, KV, D] go to ``positions`` [B] of cache layer
+    ``cache_layer`` and q [B, H, D] attends ``[0, seq_lens)`` of it, the
+    token included.  Returns ``(attention, (k_pool, v_pool))``; the other
+    arguments are :func:`ragged_paged_attention`'s, and the pair routes
+    under its name, once.
+
+    "kernel": ONE Mosaic call.  The work item whose block holds a row's
+    position puts the token into the page it has just copied into VMEM,
+    runs the block's arithmetic over it, and copies that page
+    (``[H/g, page_size, g·D]``) back to the pool, one DMA a pool; both
+    pools are outputs aliased to their inputs, so a layer loop keeps them
+    where they are.  The rest of the page goes back as it was read: the
+    page must be the row's OWN (a page shared between rows is a full
+    prefix page, which no row writes).  A row with ``seq_lens`` 0 (idle,
+    or mid-prefill) writes nothing, the null page included.
+    "reference": :func:`write_decode_kv` — whose scatter sends such a
+    row's token to the null page, behind its all-zero table row — then
+    :func:`ragged_paged_attention_reference`: the CPU path and the
+    kernel's oracle."""
+    d = q.shape[-1]
+    scale = scale if scale is not None else d ** -0.5
+    from paddle_tpu.ops.pallas import resolve_impl, resolve_interpret
+
+    if resolve_impl(impl, "ragged_paged_attention") == "reference":
+        pools = write_decode_kv(k_pool, v_pool, k, v, cache_layer, page_table,
+                                positions, plan)
+        return ragged_paged_attention_reference(
+            q, *pools, cache_layer, page_table, seq_lens, scale=scale,
+            kv_heads=kv_heads, wide_v=wide_v), pools
+    out, *pools = _write_attend_kernel(
+        q, k_pool, v_pool, cache_layer, page_table, seq_lens, scale=scale,
+        interpret=resolve_interpret(interpret), kv_heads=kv_heads,
+        work=plan and plan.work, wide_v=wide_v, token=(k, v, positions))
+    return out, tuple(pools)
 
 
 def block_paged_attention(q, k_pool, v_pool, cache_layer, page_table,

@@ -62,6 +62,10 @@ Telemetry rides the shared :class:`MetricsRegistry`: histograms
 / ``serve_ttft_ms`` / ``serve_tpot_ms``, counters ``serve_requests`` /
 ``serve_tokens`` / ``serve_layer_passes_total`` (decoder blocks run by
 decode steps: batch × num_layers × loop_steps a step) /
+``serve_decode_kv_writes_total{path=kernel|scatter}`` (cache layers and
+rings a one-token decode step writes its tokens' K/V into, by who writes
+them in the decode program — the paged-attention kernel, or a scatter a
+pool ahead of it — which the ``serve_decode`` span says as ``kv_write``) /
 ``serve_loop_crashes`` (background loops that died —
 pending ``results()`` callers get the loop's exception re-raised
 instead of blocking forever) / ``serve_passes_ahead_total{kind=prefill|
@@ -541,6 +545,16 @@ class ServingEngine:
         (self._prefill, self._prefill_chunk,
          self._decode) = _serving_fns(cfg, attn_impl, donate,
                                       self.serving.unmask_policy)
+        # what a one-token decode step writes of the K/V caches — its
+        # cache layers and rings (a cross layer writes nothing; a block
+        # pass writes through the chunk's scatter) — and who writes it in
+        # the program built above: ``paged_attention.decode_attention``
+        from paddle_tpu.ops.pallas import resolve_impl
+
+        self._kv_writes = (cfg.cache_layers + cfg.window_layers
+                           if cfg.block_len == 1 else 0)
+        self._kv_write_path = ("kernel" if resolve_impl(attn_impl) == "kernel"
+                               else "scatter")
 
     # -- public API -----------------------------------------------------------
     def check_request(self, prompt,
@@ -1033,6 +1047,16 @@ class ServingEngine:
             if bl > 1:      # masked going in: the host's count
                 said.update(positions=len(live) * bl,
                             masked_in=sum(a.block.masked for a in live))
+            else:
+                said["kv_write"] = self._kv_write_path
+        if self._kv_writes:
+            self.registry.counter(
+                "serve_decode_kv_writes_total",
+                "cache layers and rings the one-token decode steps wrote "
+                "the new token's K/V into, by who writes it in the decode "
+                "program: the paged-attention kernel itself, or an XLA "
+                "scatter a pool ahead of it").inc(
+                    self._kv_writes, path=self._kv_write_path)
         if self._yoco:      # counted always, said where a span listens
             reads = self._yoco_args(batch["seq_lens"])
             if tracer.enabled:

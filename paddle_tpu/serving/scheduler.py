@@ -529,11 +529,15 @@ class Scheduler:
             rids[i] = a.request.id
             gens[i] = a.sampled
             temps[i] = a.request.temperature
-        # write_decode_kv's idle-row contract is "all-zero table row →
-        # null page", which mid-prefill slots (mapped pages, no token
-        # yet) would silently break: their masked write at position 0
-        # would corrupt the first prompt page.  So only the decoding rows
-        # are copied (a fresh array every step: the transfer may alias it)
+        # the idle-row contract of the decode step's write
+        # (``paged_attention.decode_attention``): an all-zero table row
+        # AND ``seq_len`` 0 -> nothing of the row's is written.  The
+        # scatter (``write_decode_kv``, off a TPU) reads only the table —
+        # "all-zero row → null page" — so a mid-prefill slot (mapped
+        # pages, no token yet) handed over with its pages would have its
+        # masked write at position 0 corrupt the first prompt page; the
+        # kernel reads only ``seq_len``.  So only the decoding rows are
+        # copied (a fresh array every step: the transfer may alias it)
         rows = [a.slot for a in live]
         table = np.zeros_like(self.cache.page_table)
         table[rows] = self.cache.page_table[rows]

@@ -36,6 +36,10 @@ CASES = (
     "ragged_paged_attention[gpt2l]", "ragged_paged_attention[ouro]",
     "ragged_paged_attention[sdar]", "ragged_paged_attention[zaya]",
     "ragged_paged_attention[vmem]",
+    # the decode step's write inside the kernel: a row store or a page
+    # copy off the tiling is refused here, before any chip run
+    "decode_attention[gpt2l]", "decode_attention[ouro]",
+    "decode_attention[zaya]", "decode_attention[ring]",
     "kda_prefill",  # the cell's one-row pass: ~5 s
     "grouped_matmul", "grouped_matmul[down]",   # that pass's expert product
     "ssd_step",     # a Mamba-2 layer of a decode step, the pool aliased

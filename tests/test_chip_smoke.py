@@ -36,7 +36,8 @@ TOY = chip_smoke.Sizes(
         ctc_loss_fused=(4, 9, 7, 3), ctc_greedy_decode_fused=(5, 11, 6),
         fused_momentum_update=(3, 3, 4, 8), embedding_gather=(20, 8, 13),
         lstm_seq=(4, 6, 8), gru_seq=(4, 6, 8), kda_prefill=(1, 32, 2, 16),
-        grouped_matmul=(40, 5, 16, 128), ssd_step=(3, 3, 4, 8, 128, 2)),
+        grouped_matmul=(40, 5, 16, 128), ssd_step=(3, 3, 4, 8, 128, 2),
+        decode_attention_ring=(3, 2, 64, 13, 16, 4)),
     model_shapes=dict(
         lstm=(4, 6, 8, 50), nmt=(3, 5, 8, 40),
         ctr=(8, 30, 12, 3, 4, (8, 4)), crnn=(4, 32, 32, 3, 10),
@@ -177,9 +178,9 @@ def test_serve_mismatch_must_be_a_near_tie():
 def test_kernels_phase_toy(monkeypatch):
     """The comparison machinery on a few cheap cases, in interpret mode
     (every kernel's own parity test lives with the kernel)."""
-    names = ("ctc_loss_fused", "ctc_greedy_decode_fused", "kda_prefill",
-             "ssd_step", "grouped_matmul", "fused_momentum_update",
-             "embedding_gather")
+    names = ("ctc_loss_fused", "ctc_greedy_decode_fused",
+             "decode_attention[ring]", "kda_prefill", "ssd_step",
+             "grouped_matmul", "fused_momentum_update", "embedding_gather")
     rows = chip_smoke.phase_kernels(TOY, names=names, interpret=True)["rows"]
     assert [r["kernel"] for r in rows] == list(names)
     assert all(r["pass"] for r in rows)

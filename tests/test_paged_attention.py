@@ -1,7 +1,7 @@
 """Paged attention and the cache it reads (``ops/pallas/paged_attention.py``):
 ragged and block attention against the jnp oracle (the kernels in
-interpret mode), the writes, the decode step's plan, and bit-exact
-incremental decode.  The engine around them: ``tests/test_serving_engine.py``,
+interpret mode), the writes — the decode kernel's own among them — the
+decode step's plan, and bit-exact incremental decode.  The engine around them: ``tests/test_serving_engine.py``,
 ``tests/test_serving_loop.py``.  What an interpreted-kernel case is FOR is
 its id; one that needs ``head_dim`` 128 or a whole pass says so.
 """
@@ -336,13 +336,14 @@ class TestRaggedPagedAttention:
         np.testing.assert_array_equal(np.asarray(kc1), want)
 
     @pytest.mark.parametrize("heads,head_dim", _GROUPINGS)
-    @pytest.mark.parametrize("write", ["decode", "chunk", "prefill"])
+    @pytest.mark.parametrize("write", ["decode", "fused", "chunk", "prefill"])
     def test_write_touches_only_its_cache_layer(self, rng_np, write, heads,
                                                 head_dim):
         """A write at cache layer 1 leaves every other layer's pages, and
         every page of layer 1 it does not name, bit-identical; what it
         wrote reads back through the oracle's gather; a whole-stack
-        prefill writes every layer."""
+        prefill writes every layer.  ``fused``: the decode kernel's own
+        write (``decode_attention``, interpreted)."""
         layers, pages, ps, b, t = 3, 12, 4, 2, 8
         shape = PA.kv_pool_shape(layers, heads, pages, ps, head_dim)
         kc = jnp.asarray(rng_np.normal(size=shape).astype(np.float32))
@@ -351,10 +352,16 @@ class TestRaggedPagedAttention:
         lens = jnp.asarray([7, 5])
         new = lambda *lead: jnp.asarray(rng_np.normal(
             size=(*lead, heads, head_dim)).astype(np.float32))
-        if write == "decode":
+        if write in ("decode", "fused"):
             k, v = new(b), new(b)
-            kc1, vc1 = PA.write_decode_kv(kc, vc, k, v, 1, pt, lens - 1)
+            if write == "decode":
+                kc1, vc1 = PA.write_decode_kv(kc, vc, k, v, 1, pt, lens - 1)
+            else:
+                _, (kc1, vc1) = jax.jit(functools.partial(
+                    PA.decode_attention, impl="kernel", interpret=True))(
+                    new(b), k, v, kc, vc, 1, pt, lens - 1, lens)
             named = [(1, 2, int(lens[0] - 1) % ps), (1, 5, int(lens[1] - 1) % ps)]
+            write = "decode"
         elif write == "chunk":
             k, v = new(b, t), new(b, t)
             starts = jnp.asarray([2, 0])
@@ -461,11 +468,15 @@ class TestDecodePlan:
         np.testing.assert_array_equal(np.asarray(run(plan=plan)),
                                       np.asarray(run()))
 
+    @pytest.mark.parametrize("impl", ["kernel", "reference"])
     @pytest.mark.parametrize("kind", ["dense", "looped"])
-    def test_the_layer_loop_holds_none_of_the_index_arithmetic(self, kind):
+    def test_the_layer_loop_holds_none_of_the_index_arithmetic(self, kind,
+                                                               impl):
         """``forward_decode``'s layer loop: no cumulative sum (the work
         list) and no whole-number division or remainder (page and row of
-        a position, blocks of a length) is left in its body."""
+        a position, blocks of a length) is left in its body — and under
+        the kernel no scatter either: the Mosaic call writes the token
+        itself; the reference path keeps the scatter and has no call."""
         cfg = lm_toy.small_cfg(loop_steps=2 if kind == "looped" else 1)
         params = T.init_params(cfg, jax.random.key(0))
         kc, vc = PA.init_kv_pages(cfg.cache_layers, cfg.kv_heads, 9, 4,
@@ -475,10 +486,101 @@ class TestDecodePlan:
                 jnp.asarray([6, 0, 3]), jnp.zeros((b, 4), jnp.int32))
         body = _body_primitives(jax.make_jaxpr(
             lambda *a: T.forward_decode(cfg, params, *a, kc, vc,
-                                        attn_impl="kernel"))(*args).jaxpr)
+                                        attn_impl=impl))(*args).jaxpr)
         names = {name for name, _ in body}
-        assert {"pallas_call", "scatter"} <= names and "cumsum" not in names
+        mine, other = (("pallas_call", "scatter") if impl == "kernel"
+                       else ("scatter", "pallas_call"))
+        assert mine in names and other not in names and "cumsum" not in names
         assert not body & {("div", "i"), ("rem", "i"), ("floor_divide", "i")}
+
+
+# -- the decode step's write inside the kernel ------------------------------------
+
+_FUSED_ROWS = {"token_in_the_rows_last_block": (0,),
+               "token_in_an_earlier_block_of_a_full_ring": (1, 5),
+               "token_opens_a_new_page": (2,),
+               "idle_and_mid_prefill_rows_write_nothing": (3, 4)}
+_FUSED_FORMS = {"fewer_kv_heads_than_query_heads": dict(rep=2),
+                "wide_v_over_whole_lane_groups": dict(rep=2, wide_v=True)}
+
+
+@functools.lru_cache(maxsize=None)
+def _fused_case(heads, head_dim, rep=1, wide_v=False):
+    """One batch holding every row of ``_FUSED_ROWS`` through
+    ``decode_attention`` at cache layer 1 of 3, kernel (interpreted) and
+    reference: blocks of 128 tokens (8 pages of 16: ``_STEP_BYTES`` cut
+    to nothing, or a toy's block is its whole table), a table of 20
+    pages.  Row 0 writes its last position, in its second block; rows 1
+    and 5 are rings full at 320 whose position lies in their first and
+    second block of three; row 2's token is the first of its third page;
+    row 3 is idle behind a zero table row; row 4 is mid-prefill: pages
+    mapped, ``seq_len`` 0.  The d128 grouping runs in bfloat16 (a packed
+    row is half a sublane)."""
+    ps, maxp, layers = 16, 20, 3
+    dtype = jnp.bfloat16 if head_dim == 128 else jnp.float32
+    if wide_v:  # whole lane groups of K/V heads
+        heads = -(-heads // PA.head_group(heads, head_dim)) * PA.head_group(
+            heads, head_dim)
+    lens = np.array([150, 320, 33, 0, 0, 320], np.int32)
+    at = np.array([149, 37, 32, 0, 0, 200], np.int32)
+    used = [10, 20, 3, 0, 2, 20]
+    rng = np.random.default_rng(heads * head_dim + rep)
+    pool = 1 + sum(used) + 4
+    ids = rng.permutation(np.arange(1, pool))
+    pt, nxt = np.zeros((len(lens), maxp), np.int32), 0
+    for b, u in enumerate(used):
+        pt[b, :u] = ids[nxt:nxt + u]
+        nxt += u
+    shape = PA.kv_pool_shape(layers, heads, pool, ps, head_dim)
+    kc, vc = (jnp.asarray(rng.normal(size=shape), dtype) for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(len(lens), heads * rep, head_dim)), dtype)
+    k, v = (jnp.asarray(rng.normal(size=(len(lens), heads, head_dim)), dtype)
+            for _ in range(2))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(PA, "_STEP_BYTES", 1)
+        assert PA.decode_block_pages(heads, ps, head_dim, dtype.dtype.itemsize,
+                                     maxp) == 8
+        run = lambda impl: jax.jit(functools.partial(
+            PA.decode_attention, impl=impl, interpret=True, kv_heads=heads,
+            wide_v=wide_v))(q, k, v, kc, vc, 1, pt, at, lens)
+        (a, pools), (want_a, want) = run("kernel"), run("reference")
+    f32 = lambda x: np.asarray(x.astype(jnp.float32))
+    return (pt, lens, f32(a), f32(want_a), [f32(x) for x in pools],
+            [f32(x) for x in want], [f32(kc), f32(vc)], dtype)
+
+
+@pytest.mark.parametrize("case", [*_FUSED_ROWS, *_FUSED_FORMS])
+@pytest.mark.parametrize("heads,head_dim", _GROUPINGS)
+def test_the_fused_write_is_the_scatter_then_the_reference(heads, head_dim,
+                                                           case):
+    """``decode_attention`` under the interpreted kernel against
+    ``write_decode_kv`` + the jnp oracle: the attention to the kernel
+    tests' tolerance, BOTH pools bit for bit — every page but the null
+    page, which the scatter gives an idle row's token and the kernel
+    leaves as it was, as it does the pages of a row with ``seq_len`` 0."""
+    pt, lens, a, want_a, pools, want, before, dtype = _fused_case(
+        heads, head_dim, **_FUSED_FORMS.get(case, {}))
+    rows = _FUSED_ROWS.get(case, range(len(lens)))
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    assert np.isfinite(a).all()
+    np.testing.assert_allclose(a[list(rows)], want_a[list(rows)], rtol=tol,
+                               atol=tol)
+    untouched = [0, *pt[4][pt[4] > 0]]  # the null page, the mid-prefill row's
+    for got, ref, was in zip(pools, want, before):
+        np.testing.assert_array_equal(got[:, :, untouched],
+                                      was[:, :, untouched])
+        if case not in _FUSED_ROWS:     # a form: the whole of both pools
+            ref = ref.copy()
+            ref[:, :, untouched] = was[:, :, untouched]
+            np.testing.assert_array_equal(got, ref)
+            continue
+        for b in rows:
+            mine = pt[b][pt[b] > 0]
+            if lens[b]:     # the scatter's pages, and the token is in them
+                np.testing.assert_array_equal(got[:, :, mine], ref[:, :, mine])
+                assert (got[1][:, mine] != was[1][:, mine]).any()
+            else:
+                assert not a[b].any()
 
 
 class TestBitExactDecode:

@@ -219,6 +219,42 @@ class TestServeTelemetry:
         assert summaries and "serve_ttft_ms" in summaries[-1]["summary"]
         assert reg.counter("serve_tokens").value() == 12.0
 
+    @pytest.mark.parametrize("kind,parts,writes", [
+        pytest.param("dense", {}, 2, id="dense"),
+        pytest.param("looped", dict(loop_steps=2), 4, id="looped"),
+        # the ring and the cache layer; the cross layer reads, writes nothing
+        pytest.param("window_and_cross", dict(
+            num_layers=6, pattern="W-*-X-", attn_window=8, positions="none"),
+            2, id="window_and_cross"),
+        # a block pass writes its block through the chunk's scatter
+        pytest.param("block", dict(block_len=4, mask_id=63,
+                                   positions="rotary"), 0, id="block")])
+    def test_decode_kv_writes_are_counted_by_who_writes(self, rng_np, kind,
+                                                        parts, writes):
+        """``serve_decode_kv_writes_total{path}``: cache layers and rings a
+        one-token decode step writes, times the steps, under the path the
+        decode program was built with — off a TPU the scatter — which
+        every ``serve_decode`` span says too."""
+        import lm_toy
+
+        cfg = small_cfg(**parts)
+        reg = MetricsRegistry("kv_writes_" + kind)
+        eng = ServingEngine(cfg, T.init_params(cfg, jax.random.key(3)),
+                            ServingConfig(**SERVING), registry=reg)
+        prompts = [list(rng_np.integers(1, 60, size=n)) for n in (5, 3)]
+        _, spans = lm_toy.traced(
+            lambda: eng.generate(prompts, max_new_tokens=4))
+        steps = spans["serve_decode"]
+        assert steps
+        said = {s.args.get("kv_write") for s in steps}
+        counted = reg.get("serve_decode_kv_writes_total")
+        if not writes:
+            assert counted is None and said == {None}
+            return
+        assert said == {"scatter"}
+        assert counted.value(path="scatter") == writes * len(steps)
+        assert counted.value(path="kernel") == 0
+
     def test_metrics_to_md_renders_serving_table(self, tmp_path, capsys):
         import json
         import sys

@@ -252,6 +252,29 @@ def test_a_reused_slot_never_reads_the_request_before(ref, weights, params):
     assert b.tokens == lm_toy.greedy(ref, weights, M, short, 9, PAD)
 
 
+def test_the_decode_kernel_writes_the_rings_and_the_cache(ref, weights,
+                                                           params):
+    """The same two requests through a decode program built on the
+    (interpreted) Mosaic kernel: the cache layer and both rings — a ring
+    that wraps: the token lands behind the rows the step reads — are
+    written inside ``paged_attention_decode``, the cross layers read what
+    it wrote, the tokens are the reference's, and the engine counts three
+    writes a step under ``kernel``."""
+    reg = MetricsRegistry("yoco_kernel")
+    eng = _engine(params, reg=reg, max_slots=1, prefill_batch=1,
+                  attn_impl="kernel")
+    rng = np.random.default_rng(4)
+    long = [int(t) for t in rng.integers(0, V, 22)]
+    short = [int(t) for t in rng.integers(0, V, 3)]
+    a, b = eng.generate([long, short], max_new_tokens=9)
+    assert a.tokens == lm_toy.greedy(ref, weights, M, long, 9, PAD)
+    assert b.tokens == lm_toy.greedy(ref, weights, M, short, 9, PAD)
+    steps = reg.get("serve_decode_step_ms").summary()["count"]
+    writes = reg.get("serve_decode_kv_writes_total")
+    assert steps and writes.value(path="kernel") == 3 * steps
+    assert writes.value(path="scatter") == 0
+
+
 def test_spans_and_counters_say_what_a_pass_reads(params):
     """``serve_decode`` says the layer-reads of the growing cache, the
     window layers and the ring rows read; ``serve_prefill`` the positions
