@@ -20,6 +20,7 @@ import jax
 from paddle_tpu.core.enforce import enforce, error_scope
 from paddle_tpu.core.lod import NestedSequenceBatch, SequenceBatch
 from paddle_tpu.core.parameters import ParamSpec
+from paddle_tpu.telemetry.scopes import part
 
 Value = Any  # jax.Array | SequenceBatch | NestedSequenceBatch
 
@@ -184,7 +185,10 @@ def evaluate(
         parent_vals = [values[p.name] for p in node.parents]
         pvals = {s.name: params[s.name] for s in node.param_specs}
         svals = {s.name: new_states[s.name] for s in node.state_specs}
-        with error_scope(node.name):
+        # every operation the node traces says whose it is: the part is
+        # the layer TYPE (telemetry/scopes.py)
+        with error_scope(node.name), \
+                part(f"{node.layer_type}/{node.name}"):
             result = node.fn(ctx, pvals, svals, *parent_vals)
         if isinstance(result, tuple) and len(result) == 2 and isinstance(result[1], dict):
             value, supd = result

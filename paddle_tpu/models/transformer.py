@@ -29,6 +29,7 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu.ops import attention as attn_ops
+from paddle_tpu.telemetry.scopes import part, scoped
 
 
 # a layer pattern's characters -> the kind's name in ``params["blocks"]``
@@ -953,6 +954,7 @@ def place_params(params: dict, mesh, cfg: TransformerConfig | None = None) -> di
 from paddle_tpu.ops.nn import layer_norm as _ln  # shared with the v2 path
 
 
+@scoped("norm")
 def _norm(cfg: TransformerConfig, x, p, name):
     """The config's norm over the last axis with the parameters
     ``p[name + "_g"]`` (and ``"_b"`` under "layer")."""
@@ -994,6 +996,7 @@ def _rope(x, table):
             ).astype(x.dtype)
 
 
+@scoped("embed")
 def _embed(cfg: TransformerConfig, params, ids, positions=None):
     """Token states entering the stack, and the RoPE table of their
     positions (None under learned positions, which are added here).
@@ -1011,6 +1014,7 @@ def _embed(cfg: TransformerConfig, params, ids, positions=None):
                           if positions is None else positions)
 
 
+@scoped("head")
 def _head(cfg: TransformerConfig, params, x, out_dtype=None):
     w = params["embed"].T if cfg.tie_embeddings else params["head"]
     logits = x @ w if out_dtype is None else jnp.matmul(
@@ -1171,6 +1175,7 @@ def _diff_combine(cfg: TransformerConfig, a, layer, depth):
     return y.reshape(*lead, h * e // 2)
 
 
+@scoped("mamba1.proj")
 def _mamba1_mixer(cfg: TransformerConfig, h, layer, conv, scan):
     """The Mamba-1 mixer over normed states h [..., E] -> (its output
     [..., E], the scan's output y [..., d_inner] float32 BEFORE the gate
@@ -1180,16 +1185,20 @@ def _mamba1_mixer(cfg: TransformerConfig, h, layer, conv, scan):
     f32 = jnp.float32
     n, r = cfg.mamba1_state, cfg.mamba1_dt_rank
     x, z = jnp.split(h @ layer["in_proj"], 2, axis=-1)
-    x = jax.nn.silu(conv(x, layer["conv_w"], layer["conv_b"])
-                    ).astype(h.dtype)
+    with part("mamba1.conv"):
+        x = jax.nn.silu(conv(x, layer["conv_w"], layer["conv_b"])
+                        ).astype(h.dtype)
     dt, b, c = jnp.split(x @ layer["x_proj"], [r, r + n], axis=-1)
     dt = jax.nn.softplus((dt @ layer["dt_proj"]).astype(f32)
                          + layer["dt_bias"].astype(f32))
-    y = scan(x, dt, -jnp.exp(layer["a_log"].astype(f32)), b, c, layer["d"])
+    with part("mamba1.scan"):
+        y = scan(x, dt, -jnp.exp(layer["a_log"].astype(f32)), b, c,
+                 layer["d"])
     out = (y * jax.nn.silu(z.astype(f32))).astype(h.dtype)
     return out @ layer["out_proj"], y
 
 
+@scoped("attn.qkv")
 def _qkv(cfg: TransformerConfig, h, layer, rope):
     """Normed states h [..., E] -> q [..., H, Dh], k and v [..., KV, Dh],
     RoPE applied from ``rope``.  A layer with no ``wk`` (a cross layer:
@@ -1214,6 +1223,7 @@ def _qkv(cfg: TransformerConfig, h, layer, rope):
     return q, k, v
 
 
+@scoped("attn.qkv")
 def _cca_qkv(cfg: TransformerConfig, h, layer, rope, window):
     """CCA's q [..., H, Dh], k and v [..., KV, Dh] from normed states h
     [..., E] (``TransformerConfig.cca_taps``).  ``window(part, x, n) ->
@@ -1227,14 +1237,15 @@ def _cca_qkv(cfg: TransformerConfig, h, layer, rope, window):
     k0, k1 = cfg.cca_taps
     u = jnp.concatenate([h @ layer["wq"], h @ layer["wk"]], axis=-1)
     # depthwise over the sequence, then a head's own matrix a tap
-    c0 = jnp.einsum("...kc,kc->...c", window("cca_u", u, k0 - 1),
-                    layer["cca_conv0_w"].astype(f32)) \
-        + layer["cca_conv0_b"].astype(f32)
-    win = window("cca_c", c0, k1 - 1).astype(h.dtype).reshape(
-        *lead, k1, nh + kv, hd)
-    c1 = jnp.einsum("...kgd,kgde->...ge", win, layer["cca_conv1_w"],
-                    preferred_element_type=f32) \
-        + layer["cca_conv1_b"].astype(f32).reshape(nh + kv, hd)
+    with part("cca.conv"):
+        c0 = jnp.einsum("...kc,kc->...c", window("cca_u", u, k0 - 1),
+                        layer["cca_conv0_w"].astype(f32)) \
+            + layer["cca_conv0_b"].astype(f32)
+        win = window("cca_c", c0, k1 - 1).astype(h.dtype).reshape(
+            *lead, k1, nh + kv, hd)
+        c1 = jnp.einsum("...kgd,kgde->...ge", win, layer["cca_conv1_w"],
+                        preferred_element_type=f32) \
+            + layer["cca_conv1_b"].astype(f32).reshape(nh + kv, hd)
     # the mean of the projections q and k came from, of a K/V head and
     # the query heads that read it, added back
     uh = u.astype(f32).reshape(*lead, nh + kv, hd)
@@ -1252,7 +1263,8 @@ def _cca_qkv(cfg: TransformerConfig, h, layer, rope, window):
     # the previous token's
     hv = h @ layer["wv"]
     half = kv * hd // 2
-    prev = window("cca_v", hv[..., half:], 1)[..., 0, :].astype(h.dtype)
+    with part("cca.conv"):
+        prev = window("cca_v", hv[..., half:], 1)[..., 0, :].astype(h.dtype)
     v = jnp.concatenate([hv[..., :half], prev], axis=-1)
     return q, k, v.reshape(*lead, kv, hd)
 
@@ -1275,19 +1287,22 @@ def _mlp(cfg: TransformerConfig, h, layer, mesh=None, live=None,
         from paddle_tpu.parallel.moe import moe_ffn, moe_ffn_sharded
 
         moe_p = {n: layer[n] for n in ("wg", "w1", "b1", "w2", "b2")}
-        if mesh is not None and "expert" in mesh.axis_names:
-            y, aux = moe_ffn_sharded(moe_p, h, cfg.moe, mesh)
-        else:
-            y, aux = moe_ffn(moe_p, h, cfg.moe)
+        # the capacity form: gate, dispatch, experts, combine in one
+        with part("moe.product"):
+            if mesh is not None and "expert" in mesh.axis_names:
+                y, aux = moe_ffn_sharded(moe_p, h, cfg.moe, mesh)
+            else:
+                y, aux = moe_ffn(moe_p, h, cfg.moe)
         return y, None, aux
-    if cfg.mlp == "swiglu":
-        return (jax.nn.silu(h @ layer["w_gate"]) * (h @ layer["w_up"])
-                ) @ layer["w_out"], None, None
-    if cfg.mlp == "relu2":
-        return jnp.square(jax.nn.relu(h @ layer["w_in"])) @ layer["w_out"], \
-            None, None
-    h = jax.nn.gelu(h @ layer["w_in"] + layer["b_in"])
-    return h @ layer["w_out"], layer["b_out"], None
+    with part("ffn"):
+        if cfg.mlp == "swiglu":
+            return (jax.nn.silu(h @ layer["w_gate"]) * (h @ layer["w_up"])
+                    ) @ layer["w_out"], None, None
+        if cfg.mlp == "relu2":
+            return jnp.square(jax.nn.relu(h @ layer["w_in"])) \
+                @ layer["w_out"], None, None
+        h = jax.nn.gelu(h @ layer["w_in"] + layer["b_in"])
+        return h @ layer["w_out"], layer["b_out"], None
 
 
 def _block(cfg: TransformerConfig, x, layer, attend, rope=None, mesh=None,
@@ -1318,15 +1333,17 @@ def _block(cfg: TransformerConfig, x, layer, attend, rope=None, mesh=None,
         """The residual add of one branch: under a sandwich the branch's
         output (bias included) is normed first; otherwise the bias goes on
         after the add, GPT-2's order of rounding."""
-        if cfg.norm_sandwich:
-            return x + _norm(cfg, y if bias is None else y + bias, layer,
-                             post)
-        x = x + y
-        return x if bias is None else x + bias
+        with part("norm"):
+            if cfg.norm_sandwich:
+                return x + _norm(cfg, y if bias is None else y + bias,
+                                 layer, post)
+            x = x + y
+            return x if bias is None else x + bias
 
     def tail_fn(x, a, layer):
-        x = branch_out(x, a.reshape(*lead, nh * hd) @ layer["wo"], None,
-                       "ln1_post")
+        with part("attn.out"):
+            y = a.reshape(*lead, nh * hd) @ layer["wo"]
+        x = branch_out(x, y, None, "ln1_post")
         y, bias, aux = _mlp(cfg, _norm(cfg, x, layer, "ln2"), layer, mesh)
         if cfg.moe_dropless:
             aux = None  # routing counts: the pattern walk's to report
@@ -1337,11 +1354,13 @@ def _block(cfg: TransformerConfig, x, layer, attend, rope=None, mesh=None,
         qkv_fn = jax.checkpoint(qkv_fn, policy=policy)
         tail_fn = jax.checkpoint(tail_fn, policy=policy)
     q, k, v = qkv_fn(x, layer)
-    a, kept = attend(q, k, v)
+    with part("attn.core"):
+        a, kept = attend(q, k, v)
     x, aux = tail_fn(x, a, layer)
     return x, aux, kept
 
 
+@scoped("mamba2.proj")
 def _mamba_mixer(cfg: TransformerConfig, h, layer, conv, ssd):
     """The Mamba-2 mixer over normed states h [..., E].  ``conv(xbc, w,
     bias)`` and ``ssd(x, dt, a, b, c, d)`` are the caller's arrangement of
@@ -1355,12 +1374,15 @@ def _mamba_mixer(cfg: TransformerConfig, h, layer, conv, ssd):
     di = nh * p
     z, xbc, dt = jnp.split(h @ layer["in_proj"], [di, 2 * di + 2 * g * n],
                            axis=-1)
-    xbc = jax.nn.silu(conv(xbc, layer["conv_w"], layer["conv_b"])
-                      ).astype(h.dtype)
+    with part("mamba2.conv"):
+        xbc = jax.nn.silu(conv(xbc, layer["conv_w"], layer["conv_b"])
+                          ).astype(h.dtype)
     x, b, c = jnp.split(xbc, [di, di + g * n], axis=-1)
     dt = jax.nn.softplus(dt.astype(f32) + layer["dt_bias"].astype(f32))
-    y = ssd(x.reshape(*lead, nh, p), dt, -jnp.exp(layer["a_log"].astype(f32)),
-            b.reshape(*lead, g, n), c.reshape(*lead, g, n), layer["d"])
+    with part("mamba2.scan"):
+        y = ssd(x.reshape(*lead, nh, p), dt,
+                -jnp.exp(layer["a_log"].astype(f32)),
+                b.reshape(*lead, g, n), c.reshape(*lead, g, n), layer["d"])
     # gate, then RMSNorm over each of the g groups of d_inner / g
     y = (y.reshape(*lead, di) * jax.nn.silu(z.astype(f32))
          ).reshape(*lead, g, di // g)
@@ -1369,6 +1391,7 @@ def _mamba_mixer(cfg: TransformerConfig, h, layer, conv, ssd):
     return y @ layer["out_proj"]
 
 
+@scoped("kda.proj")
 def _kda_mixer(cfg: TransformerConfig, h, layer, conv, rule):
     """The Kimi Delta Attention mixer over normed states h [..., E].
     ``conv(x, w, bias)`` and ``rule(q, k, v, g, beta)`` are the caller's
@@ -1381,8 +1404,9 @@ def _kda_mixer(cfg: TransformerConfig, h, layer, conv, rule):
     nh, hd = cfg.kda_heads, cfg.head_dim
     qkv = jnp.concatenate([h @ layer["wq"], h @ layer["wk"],
                            h @ layer["wv"]], axis=-1)
-    q, k, v = jnp.split(jax.nn.silu(conv(qkv, layer["conv_w"], None)), 3,
-                        axis=-1)
+    with part("kda.conv"):
+        q, k, v = jnp.split(jax.nn.silu(conv(qkv, layer["conv_w"], None)),
+                            3, axis=-1)
     # q and k to unit length a head (q then scaled as attention scales)
     unit = lambda x: x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
                                    + 1e-6)
@@ -1396,7 +1420,8 @@ def _kda_mixer(cfg: TransformerConfig, h, layer, conv, rule):
     g = -jnp.exp(layer["a_log"].astype(f32))[:, None] \
         * jax.nn.softplus(dt).reshape(*lead, nh, hd)
     beta = 2.0 * jax.nn.sigmoid((h @ layer["w_beta"]).astype(f32))
-    o = rule(q, k, v, g, beta)
+    with part("kda.rule"):
+        o = rule(q, k, v, g, beta)
     # RMSNorm over each head's values, one gain; then the output gate
     o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps)
     gate = jax.nn.sigmoid(
@@ -1435,23 +1460,27 @@ def _pattern_layer(cfg: TransformerConfig, kind, layer, x, rope, attend,
     if kind in _ATTENDS:
         q, k, v = (_qkv(cfg, h, layer, rope) if cfg.cca_taps is None
                    else _cca_qkv(cfg, h, layer, rope, window))
-        if cfg.attn_diff:
-            # each head's softmax over its pair's [v1 | v2], then the pair
-            a = _diff_combine(cfg, attend(_diff_order(cfg, q), k, v), layer,
-                              depth).astype(h.dtype)
-        else:
-            a = attend(q, k, v).reshape(*lead, cfg.num_heads * cfg.head_dim)
-        if cfg.attn_gate:
-            a = a * jax.nn.sigmoid(h @ layer["w_ogate"])
-        y = a @ layer["wo"]
-        if cfg.attn_bias:
-            y = y + layer["bo"]
+        with part("attn.core"):
+            a = attend(_diff_order(cfg, q) if cfg.attn_diff else q, k, v)
+        with part("attn.out"):
+            if cfg.attn_diff:
+                # each head's softmax was over its pair's [v1 | v2]: now
+                # the pair
+                a = _diff_combine(cfg, a, layer, depth).astype(h.dtype)
+            else:
+                a = a.reshape(*lead, cfg.num_heads * cfg.head_dim)
+            if cfg.attn_gate:
+                a = a * jax.nn.sigmoid(h @ layer["w_ogate"])
+            y = a @ layer["wo"]
+            if cfg.attn_bias:
+                y = y + layer["bo"]
     elif kind == "mamba1":
         # the scan's output before its gate is what the walk hands on
         y, carry = _mamba1_mixer(cfg, h, layer, *mamba)
     elif kind == "gmu":
-        gate = jax.nn.silu((h @ layer["gmu_in"]).astype(jnp.float32))
-        y = (carry * gate).astype(h.dtype) @ layer["gmu_out"]
+        with part("gmu.proj"):
+            gate = jax.nn.silu((h @ layer["gmu_in"]).astype(jnp.float32))
+            y = (carry * gate).astype(h.dtype) @ layer["gmu_out"]
     elif kind == "mamba":
         y = _mamba_mixer(cfg, h, layer, *mamba)
     elif kind == "kda":
@@ -1460,20 +1489,24 @@ def _pattern_layer(cfg: TransformerConfig, kind, layer, x, rope, attend,
         if kind == "moe" and cfg.moe_router_hidden:
             from paddle_tpu.parallel.moe import router_state
 
-            carry = router_state(layer, h, carry)
+            with part("moe.route"):
+                carry = router_state(layer, h, carry)
         y, bias, aux = _mlp(cfg, h, layer, mesh, live, kind == "moe", carry)
         if bias is not None:
             y = y + bias
         if kind == "moe":
             counts = aux
-    if cfg.residual_multiplier != 1.0:
-        y = y * cfg.residual_multiplier
-    if cfg.residual_scale:
-        return ((x * layer["res_x_g"] + layer["res_x_b"])
-                + (y * layer["res_y_g"] + layer["res_y_b"])), counts, carry
-    return x + y, counts, carry
+    with part("norm"):      # the residual add
+        if cfg.residual_multiplier != 1.0:
+            y = y * cfg.residual_multiplier
+        if cfg.residual_scale:
+            return ((x * layer["res_x_g"] + layer["res_x_b"])
+                    + (y * layer["res_y_g"] + layer["res_y_b"])), counts, \
+                carry
+        return x + y, counts, carry
 
 
+@scoped("stack")
 def _run_pattern(cfg: TransformerConfig, params, x, layer_fn, held=None,
                  narrow=None):
     """The stack of a layer ``pattern``, the final norm closing it.
@@ -1554,11 +1587,14 @@ def _run_pattern(cfg: TransformerConfig, params, x, layer_fn, held=None,
         pooled = lambda *a: jnp.stack(a)
     x = _norm(cfg, x, params, "ln_f")
     # K/V first, then the state parts in the cache's order
-    left = {name: jax.tree.map(pooled, *left[name])
-            for name in ("kv", "window", *cfg.state_parts) if name in left}
+    with part("kv.write"):
+        left = {name: jax.tree.map(pooled, *left[name])
+                for name in ("kv", "window", *cfg.state_parts)
+                if name in left}
     return x, counts, held, left
 
 
+@scoped("stack")
 def _run_stack(cfg: TransformerConfig, params, x, layer_fn, pools=None,
                unroll=1):
     """``loop_steps`` passes of the layer scan over the ONE stacked
@@ -1666,6 +1702,7 @@ def forward_with_aux(cfg: TransformerConfig, params: dict, ids: jax.Array,
 # (tests/test_looped_lm.py holds the compiled programs to it).
 
 
+@scoped("head")
 def _last_valid(x, seq_lens):
     return jnp.take_along_axis(
         x, jnp.maximum(seq_lens - 1, 0)[:, None, None], axis=1)[:, 0]
@@ -1727,9 +1764,10 @@ def _prefill_layer(cfg: TransformerConfig, kind, mesh, layer, x, rope,
                 else None)
         if kind == "window":
             if seq_lens is not None:    # each row's last window, as a ring
-                kept["window"] = tuple(
-                    pa.ring_rows(a, seq_lens, cfg.attn_window)
-                    for a in (k, v))
+                with part("kv.write"):
+                    kept["window"] = tuple(
+                        pa.ring_rows(a, seq_lens, cfg.attn_window)
+                        for a in (k, v))
             return _blocked_attention(cfg, q, k, v, cfg.attn_window)
         kept["kv"] = (k, v)
         if cfg.attn_diff:
@@ -1837,8 +1875,9 @@ def forward_prefill_chunk(cfg: TransformerConfig, params: dict,
     b, c = ids.shape
     # padding of offset rows can index past max_seq_len — clip (valid
     # positions satisfy starts + t < max_prompt_len <= max_seq_len)
-    pos = jnp.clip(starts[:, None] + jnp.arange(c)[None, :], 0,
-                   cfg.max_seq_len - 1)
+    with part("embed"):
+        pos = jnp.clip(starts[:, None] + jnp.arange(c)[None, :], 0,
+                       cfg.max_seq_len - 1)
     x, rope = _embed(cfg, params, ids, pos)
     if cfg.pattern is not None:
         if cfg.window_layers or cfg.cross_reads:
@@ -1858,8 +1897,9 @@ def forward_prefill_chunk(cfg: TransformerConfig, params: dict,
             pools = list(pools)
 
             def attend_chunk(q, k, v):
-                pools[:] = pa.write_chunk_kv(*pools, k, v, i, page_table,
-                                             starts, seq_lens)
+                with part("kv.write"):
+                    pools[:] = pa.write_chunk_kv(*pools, k, v, i, page_table,
+                                                 starts, seq_lens)
                 return pa.paged_prefill_attention(
                     q, *pools, i, page_table, starts, seq_lens,
                     scale=cfg.attn_scale, kv_heads=cfg.kv_heads)
@@ -1876,8 +1916,9 @@ def forward_prefill_chunk(cfg: TransformerConfig, params: dict,
 
     def layer_fn(x, layer, cache_layer, kc, vc):
         def attend(q, k, v):
-            pools = pa.write_chunk_kv(kc, vc, k, v, cache_layer, page_table,
-                                      starts, seq_lens)
+            with part("kv.write"):
+                pools = pa.write_chunk_kv(kc, vc, k, v, cache_layer,
+                                          page_table, starts, seq_lens)
             return pa.paged_prefill_attention(
                 q, *pools, cache_layer, page_table, starts, seq_lens,
                 scale=cfg.attn_scale, kv_heads=cfg.kv_heads), pools
@@ -1924,8 +1965,9 @@ def forward_decode(cfg: TransformerConfig, params: dict, ids: jax.Array,
 
     # what the step's cache layers share, made once: XLA leaves it in the
     # layer loop's body otherwise
-    plan = pa.decode_plan(k_cache, page_table, positions, seq_lens,
-                          cfg.kv_heads, cfg.head_dim)
+    with part("attn.core"):
+        plan = pa.decode_plan(k_cache, page_table, positions, seq_lens,
+                              cfg.kv_heads, cfg.head_dim)
 
     def layer_fn(x, layer, cache_layer, kc, vc):
         def attend(q, k, v):
@@ -1965,16 +2007,18 @@ def forward_decode_block(cfg: TransformerConfig, params: dict,
     from paddle_tpu.ops.pallas import paged_attention as pa
 
     t = ids.shape[1]
-    ids = jnp.where(masked, cfg.mask_id, ids)
-    pos = jnp.clip(starts[:, None] + jnp.arange(t)[None, :], 0,
-                   cfg.max_seq_len - 1)
+    with part("embed"):
+        ids = jnp.where(masked, cfg.mask_id, ids)
+        pos = jnp.clip(starts[:, None] + jnp.arange(t)[None, :], 0,
+                       cfg.max_seq_len - 1)
     x, rope = _embed(cfg, params, ids, pos)
     live = seq_lens > 0
     new = jnp.where(live, t, 0)
 
     def attend(cache_layer, kc, vc, q, k, v):
-        pools = pa.write_chunk_kv(kc, vc, k, v, cache_layer, page_table,
-                                  starts, new)
+        with part("kv.write"):
+            pools = pa.write_chunk_kv(kc, vc, k, v, cache_layer, page_table,
+                                      starts, new)
         return pa.block_paged_attention(
             q, *pools, cache_layer, page_table, seq_lens,
             scale=cfg.attn_scale, impl=attn_impl, kv_heads=cfg.kv_heads), pools
@@ -2021,8 +2065,9 @@ def _decode_pattern(cfg: TransformerConfig, params, x, rope, positions,
 
     live = seq_lens > 0
 
-    plan = pa.decode_plan(k_cache, page_table, positions, seq_lens,
-                          cfg.kv_heads, cfg.head_dim)
+    with part("attn.core"):
+        plan = pa.decode_plan(k_cache, page_table, positions, seq_lens,
+                              cfg.kv_heads, cfg.head_dim)
     arrangement = {"mamba": ("conv", "ssm"), "kda": ("kda_conv", "kda_s"),
                    "mamba1": ("conv1", "ssm1")}
     # GSPMD cannot partition a Mosaic kernel: under a mesh the plain form
@@ -2036,11 +2081,13 @@ def _decode_pattern(cfg: TransformerConfig, params, x, rope, positions,
         # the token goes to ``position mod window`` and the step reads the
         # ``min(length, window)`` entries there are, in whatever order
         w = cfg.attn_window
-        ring_table = pa.window_table(
-            state["window_k"], w, jnp.arange(seq_lens.shape[0]), live)
-        ring_at, ring_lens = positions % w, jnp.minimum(seq_lens, w)
-        ring_plan = pa.decode_plan(state["window_k"], ring_table, ring_at,
-                                   ring_lens, cfg.kv_heads, cfg.head_dim)
+        with part("attn.core"):
+            ring_table = pa.window_table(
+                state["window_k"], w, jnp.arange(seq_lens.shape[0]), live)
+            ring_at, ring_lens = positions % w, jnp.minimum(seq_lens, w)
+            ring_plan = pa.decode_plan(
+                state["window_k"], ring_table, ring_at, ring_lens,
+                cfg.kv_heads, cfg.head_dim)
 
     def layer_fn(kind, i, layer, x, carry, held):
         pools, state = list(held[:2]), dict(held[2])
@@ -2125,13 +2172,15 @@ def loss_fn(cfg: TransformerConfig, params: dict, ids: jax.Array,
             "blocks beside their clean copy) is not built; next-token "
             "cross-entropy is not this model's loss")
     logits, aux = forward_with_aux(cfg, params, ids[:, :-1], mesh=mesh)
-    targets = ids[:, 1:]
-    lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
-    tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    ce = jnp.mean(lse - tgt.astype(jnp.float32))
-    if cfg.moe_experts:
-        ce = ce + cfg.moe_aux_weight * aux
-    return ce
+    with part("loss"):
+        targets = ids[:, 1:]
+        lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+        tgt = jnp.take_along_axis(logits, targets[..., None],
+                                  axis=-1)[..., 0]
+        ce = jnp.mean(lse - tgt.astype(jnp.float32))
+        if cfg.moe_experts:
+            ce = ce + cfg.moe_aux_weight * aux
+        return ce
 
 
 def build_train_step(cfg: TransformerConfig, optimizer, mesh=None,
@@ -2206,7 +2255,9 @@ def build_train_step(cfg: TransformerConfig, optimizer, mesh=None,
             loss, grads = jax.value_and_grad(lf)(params, ids, mesh)
             if zero_on and zero >= 2:
                 grads = zero_mod.constrain_grads(grads, gspecs, mesh)
-        new_params, new_opt = optimizer.apply_tree(grads, params, opt_state)
+        with part("update"):
+            new_params, new_opt = optimizer.apply_tree(grads, params,
+                                                       opt_state)
         if zero_on:
             sspecs = zero_mod.state_specs(new_opt, params, mesh,
                                           param_specs=pspecs)

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from paddle_tpu.core import dtype as dt
+from paddle_tpu.telemetry.scopes import part
 
 
 def _pair(v):
@@ -262,12 +263,15 @@ def conv2d_bn_relu(
             x, w, scale, bias, running_mean, running_var, is_train,
             momentum=momentum, eps=eps, stride=stride, padding=padding,
             act=act or None)
-    y = conv2d_xla(x, w, stride=stride, padding=padding)
-    y, nm, nv = batch_norm(y, scale, bias, running_mean, running_var,
-                           is_train=is_train, momentum=momentum, eps=eps,
-                           use_fused_stats=False)
-    if act == "relu":
-        y = jax.nn.relu(y)
+    # the two halves of the one "conv_bn" node, by the plain layers' names
+    with part("conv"):
+        y = conv2d_xla(x, w, stride=stride, padding=padding)
+    with part("batch_norm"):
+        y, nm, nv = batch_norm(y, scale, bias, running_mean, running_var,
+                               is_train=is_train, momentum=momentum, eps=eps,
+                               use_fused_stats=False)
+        if act == "relu":
+            y = jax.nn.relu(y)
     return y, nm, nv
 
 

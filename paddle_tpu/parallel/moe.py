@@ -59,6 +59,7 @@ from jax.sharding import PartitionSpec as P
 
 from paddle_tpu.compat import shard_map
 from paddle_tpu.parallel import collective
+from paddle_tpu.telemetry.scopes import part, scoped
 
 
 @dataclass(frozen=True)
@@ -502,6 +503,20 @@ def product_path(t: int, cfg: RoutedConfig, w_in, impl: str = "auto") -> str:
         else "masked"
 
 
+@scoped("ffn")
+def _shared_expert(params: dict, x2, cfg: RoutedConfig):
+    f32 = jnp.float32
+    hs = jnp.dot(x2, params["shared_in"], preferred_element_type=f32)
+    if "shared_gate" in params:
+        hs = hs * _act(cfg.act, jnp.dot(x2, params["shared_gate"],
+                                        preferred_element_type=f32))
+    else:
+        hs = _act(cfg.act, hs)
+    return jnp.dot(hs.astype(x2.dtype), params["shared_out"],
+                   preferred_element_type=f32)
+
+
+@scoped("moe.route")
 def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None,
                carry=None, impl: str = "auto"):
     """x [..., D] -> (y like x, counts int32 [4]).  ``carry`` [..., R]:
@@ -520,7 +535,11 @@ def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None,
     token (idle decode rows, padding): they are routed nowhere.
     ``counts`` = assignments on held experts, assignments on absent ones,
     held experts with at least one token, the busiest held expert's
-    tokens — over live rows."""
+    tokens — over live rows.
+
+    Its parts (``telemetry/scopes.py``): ``moe.route`` — the router, the
+    top-k, the sort and the gather / scatter-add around the products;
+    ``moe.product`` — the expert matrices; the shared expert is ``ffn``."""
     from paddle_tpu.ops.pallas import grouped_matmul as gm
     from paddle_tpu.ops.pallas import note_route, resolve_interpret
 
@@ -553,6 +572,7 @@ def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None,
             cfg.num_held)), axis=1)
         loads = jnp.sum(comb > 0, axis=0)
 
+        @scoped("moe.product")
         def masked(rows, comb):
             h = jnp.einsum("td,xdf->xtf", rows, w_in,
                            preferred_element_type=f32)
@@ -609,8 +629,10 @@ def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None,
             # this round's rows of every expert's group
             sizes = jnp.clip(ends - at, 0, bound) \
                 - jnp.clip(ends - loads - at, 0, bound)
-            h = product(x2[sel // k], w_in, sizes, w_gate, act, x.dtype)
-            ys = product(h, w_out, sizes)
+            rows = x2[sel // k]
+            with part("moe.product"):
+                h = product(rows, w_in, sizes, w_gate, act, x.dtype)
+                ys = product(h, w_out, sizes)
             ws = jnp.where(at + jnp.arange(bound) < ends[-1], ws_all[sel],
                            0.0)
             ys = jnp.where(ws[:, None] > 0, ys * ws[:, None], 0.0)
@@ -622,14 +644,7 @@ def moe_routed(params: dict, x: jax.Array, cfg: RoutedConfig, live=None,
         else:
             y = lax.fori_loop(0, -(-ends[-1] // bound), gathered, y)
     if "shared_in" in params:
-        hs = jnp.dot(x2, params["shared_in"], preferred_element_type=f32)
-        if "shared_gate" in params:
-            hs = hs * _act(cfg.act, jnp.dot(x2, params["shared_gate"],
-                                            preferred_element_type=f32))
-        else:
-            hs = _act(cfg.act, hs)
-        y = y + jnp.dot(hs.astype(x.dtype), params["shared_out"],
-                        preferred_element_type=f32)
+        y = y + _shared_expert(params, x2, cfg)
     counts = jnp.stack([jnp.sum(held), absent, jnp.sum(loads > 0),
                         jnp.max(loads)]).astype(jnp.int32)
     return y.astype(x.dtype).reshape(shape), counts

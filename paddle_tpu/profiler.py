@@ -125,9 +125,10 @@ def benchmark(fn, args: tuple, iters: int = 50, warmup: int = 3,
 def read_device_trace(logdir: str):
     """Parse a jax.profiler chrome trace: returns (op_events, module_ms)
     where op_events are the per-HLO-op events of the device's "XLA Ops"
-    thread (dur_us, model_flops, raw_bytes_accessed, tf_op, source) and
+    thread (name, dur_us, tf_op = the operation's jax name stack) and
     module_ms sums the "XLA Modules" thread — the device-side wall time.
-    ``device_step_ms`` reads the second."""
+    ``device_ms_by_part`` reads the first, ``device_step_ms`` the
+    second."""
     import glob
     import gzip
     import json
@@ -158,20 +159,53 @@ def read_device_trace(logdir: str):
             events.append({
                 "name": e["name"],
                 "dur_us": e.get("dur", 0.0),
-                "flops": float(a.get("model_flops", 0) or 0),
-                "bytes": float(a.get("raw_bytes_accessed", 0) or 0),
                 "tf_op": a.get("tf_op", ""),
-                "source": a.get("source", ""),
             })
     return events, module_us / 1000.0
 
 
-def device_step_ms(step_fn, steps: int = 10, warmup: int = 3) -> float:
+def device_ms_by_part(events, steps: int = 1) -> list[dict]:
+    """``read_device_trace``'s op events summed by the sublayer that
+    issued them (``telemetry.scopes.part_of`` over ``tf_op``): rows
+    ``{"part", "ms", "share", "fwd_ms", "bwd_ms"}`` — ms a step over
+    ``steps``, ``share`` of the summed operation time — longest first,
+    ``unscoped`` last.  Loops and branches are left out: their bodies'
+    operations are events themselves."""
+    from paddle_tpu.telemetry.scopes import CONTAINERS, UNSCOPED, part_of
+
+    rows: dict[str, dict] = {}
+    for e in events:
+        if e["name"].split(".")[0].lstrip("%") in CONTAINERS:
+            continue
+        name, way = part_of(e["tf_op"])
+        row = rows.setdefault(name or UNSCOPED, {"fwd": 0.0, "bwd": 0.0})
+        row[way] += e["dur_us"]
+    total = sum(r["fwd"] + r["bwd"] for r in rows.values()) or 1.0
+    per = 1e3 * max(int(steps), 1)
+    out = [{"part": name, "ms": (r["fwd"] + r["bwd"]) / per,
+            "share": (r["fwd"] + r["bwd"]) / total,
+            "fwd_ms": r["fwd"] / per, "bwd_ms": r["bwd"] / per}
+           for name, r in rows.items()]
+    return sorted(out, key=lambda r: (r["part"] == UNSCOPED, -r["ms"]))
+
+
+def format_parts(rows: list[dict]) -> str:
+    """``device_ms_by_part``'s rows as the table ``--job=time`` prints."""
+    lines = [f"{'part':<22}{'ms/step':>10}{'share':>8}"
+             f"{'fwd ms':>10}{'bwd ms':>10}"]
+    lines += [f"{r['part']:<22}{r['ms']:>10.3f}{100 * r['share']:>7.1f}%"
+              f"{r['fwd_ms']:>10.3f}{r['bwd_ms']:>10.3f}" for r in rows]
+    return "\n".join(lines)
+
+
+def device_step_ms(step_fn, steps: int = 10, warmup: int = 3,
+                   parts: list | None = None) -> float:
     """ms/step measured on the DEVICE via a jax.profiler trace: the sum
     of the device's "XLA Modules" durations, so host dispatch gaps (which
     dominate wall-clock timing of sub-10 ms steps) are not counted.
     ``step_fn`` must keep its own state and return an array (the trace
-    window is fenced on it)."""
+    window is fenced on it).  ``parts`` (a list) gets the step's split
+    by sublayer, ``device_ms_by_part``'s rows."""
     import shutil
     import tempfile
 
@@ -185,20 +219,25 @@ def device_step_ms(step_fn, steps: int = 10, warmup: int = 3) -> float:
             out = step_fn()
         jax.block_until_ready(out)
         jax.profiler.stop_trace()
-        return read_device_trace(logdir)[1] / steps
+        events, module_ms = read_device_trace(logdir)
+        if parts is not None:
+            parts.extend(device_ms_by_part(events, steps))
+        return module_ms / steps
     finally:
         shutil.rmtree(logdir, ignore_errors=True)
 
 
 def step_ms_with_fallback(step_fn, wall_fn, steps: int = 10,
-                          warmup: int = 3) -> tuple[float, str, str]:
+                          warmup: int = 3, parts: list | None = None
+                          ) -> tuple[float, str, str]:
     """(ms, "device-side"|"wall-clock", reason): try device_step_ms, fall
     back to ``wall_fn()`` (a callable returning ms) when the trace is
     unavailable OR empty (non-TPU backends write traces whose module
     filter matches nothing — a 0.0 must never masquerade as a
-    measurement).  The reason string records why the fallback fired."""
+    measurement).  The reason string records why the fallback fired.
+    ``parts``: as ``device_step_ms`` (left empty by the fallback)."""
     try:
-        ms = device_step_ms(step_fn, steps=steps, warmup=warmup)
+        ms = device_step_ms(step_fn, steps=steps, warmup=warmup, parts=parts)
         if ms > 0:
             return ms, "device-side", ""
         reason = "empty device trace (non-TPU backend?)"

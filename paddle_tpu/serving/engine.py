@@ -136,7 +136,7 @@ from paddle_tpu.serving.scheduler import (
     Scheduler,
     ServingConfig,
 )
-from paddle_tpu.telemetry import tracing
+from paddle_tpu.telemetry import scopes, tracing
 from paddle_tpu.telemetry.registry import geometric_buckets
 
 # the latency histograms whose quantiles are read (the router's and the
@@ -836,7 +836,10 @@ class ServingEngine:
         the decode step's slots x positions a pass), each with XLA's own
         trace / lower / compile-or-fetch under it
         (``tracing.XlaBuildListener``); the log lines read the same
-        clock readings."""
+        clock readings.  Under an armed tracer each ``program_ready``
+        also says ``routes`` (the kernel-or-reference census of THAT
+        program's trace) and ``op_scopes`` (its operations by sublayer:
+        ``telemetry/scopes.py``)."""
         cache, sched = self.cache, self.scheduler
         shapes = (() if self.serving.incremental_prefill
                   else sched.prefill_shapes)
@@ -854,19 +857,21 @@ class ServingEngine:
                     args = self._dev(sched.prefill_arrays([], rows, length),
                                      "ids", "seq_lens", "page_table", "rids",
                                      "temps", "slots")
-                    programs[rows, length] = self._prefill.lower(
-                        self._params(), self._base_key, cache.k, cache.v,
-                        *self._carried("prefill", args)).compile()
+                    _, programs[rows, length] = scopes.compile_described(
+                        one, lambda: self._prefill.lower(
+                            self._params(), self._base_key, cache.k, cache.v,
+                            *self._carried("prefill", args)))
                 log.debug("prefill program of %d row(s) x %d ready after "
                           "%.2f s", rows, length, one.seconds)
             with tracer.timed("program_ready", program="decode",
                               rows=self.serving.max_slots,
-                              length=self._block):
+                              length=self._block) as one:
                 args = self._dev(sched.decode_arrays([]),
                                  *self._decode_fields)
-                programs["decode"] = self._decode.lower(
-                    self._params(), self._base_key, cache.k, cache.v,
-                    *self._carried("decode", args)).compile()
+                _, programs["decode"] = scopes.compile_described(
+                    one, lambda: self._decode.lower(
+                        self._params(), self._base_key, cache.k, cache.v,
+                        *self._carried("decode", args)))
         with self._lock:    # all or none: a failure is met again
             self._programs = programs
         what = "block in progress" if self._block > 1 else "last token"
@@ -1447,14 +1452,16 @@ def _serving_fns(cfg, attn_impl, donate, policy="low_confidence_static"):
     from paddle_tpu.models import transformer as T
     from paddle_tpu.ops.pallas import paged_attention as pa
     from paddle_tpu.serving import sampling
+    from paddle_tpu.telemetry.scopes import part
 
     def with_counts(toks, extras):
         """What a pass hands the host; behind it the routed layers'
         counts of this pass (one small int32 array rides out, nothing
         else to wait for)."""
         counts = extras.get("moe_counts")
-        return toks if counts is None else jnp.concatenate(
-            [toks.astype(jnp.int32), counts])
+        with part("sample"):
+            return toks if counts is None else jnp.concatenate(
+                [toks.astype(jnp.int32), counts])
 
     def unpack(out):
         """A forward's result -> (logits, k, v, extras): a config without
@@ -1469,34 +1476,39 @@ def _serving_fns(cfg, attn_impl, donate, policy="low_confidence_static"):
         lowers the pass): the program is then the one it always was."""
         logits, ks, vs, extras = unpack(
             T.forward_prefill(cfg, params, ids, lens))
-        if ks is not None:
-            kc, vc = pa.write_prefill_kv(kc, vc, ks, vs, table, lens)
-        # each row's recurrent state, whole, into its slot's row of every
-        # state layer (a slack row's slot does not exist: dropped)
-        state = dict(state or {})
-        for name, pool in state.items():
-            if name not in extras["state"]:
-                continue    # a ring: below
-            for i in range(pool.shape[0]):
-                pool = pool.at[i, slots].set(
-                    extras["state"][name][i].astype(pool.dtype), mode="drop")
-            state[name] = pool
-        if "window" in extras:
-            # each row's last window, whole, into its slot's ring
-            state["window_k"], state["window_v"] = pa.write_prefill_window(
-                state["window_k"], state["window_v"], *extras["window"],
-                cfg.attn_window, slots)
+        # what the pass leaves in the pools: pages, state rows, rings
+        with part("kv.write"):
+            if ks is not None:
+                kc, vc = pa.write_prefill_kv(kc, vc, ks, vs, table, lens)
+            # each row's recurrent state, whole, into its slot's row of
+            # every state layer (a slack row's slot does not exist: dropped)
+            state = dict(state or {})
+            for name, pool in state.items():
+                if name not in extras["state"]:
+                    continue    # a ring: below
+                for i in range(pool.shape[0]):
+                    pool = pool.at[i, slots].set(
+                        extras["state"][name][i].astype(pool.dtype),
+                        mode="drop")
+                state[name] = pool
+            if "window" in extras:
+                # each row's last window, whole, into its slot's ring
+                state["window_k"], state["window_v"] = \
+                    pa.write_prefill_window(
+                        state["window_k"], state["window_v"],
+                        *extras["window"], cfg.attn_window, slots)
         if cfg.block_len > 1:
             # nothing is sampled: the pass leaves K/V (the head is dead
             # code here); the counts ride behind a row of zeros
             return (with_counts(jnp.zeros_like(rids), extras), kc, vc, state,
                     last)
-        keys = sampling.request_keys(
-            base_key, rids, jnp.zeros_like(rids))
-        toks = sampling.sample_tokens(logits, keys, temps)
-        if last is not None:
-            # a slack row's slot does not exist: dropped, as its state is
-            last = last.at[slots].set(toks, mode="drop")
+        with part("sample"):
+            keys = sampling.request_keys(
+                base_key, rids, jnp.zeros_like(rids))
+            toks = sampling.sample_tokens(logits, keys, temps)
+            if last is not None:
+                # a slack row's slot does not exist: dropped, as its state is
+                last = last.at[slots].set(toks, mode="drop")
         return with_counts(toks, extras), kc, vc, state, last
 
     def decode(params, base_key, kc, vc, last, positions, lens, table,
@@ -1507,10 +1519,13 @@ def _serving_fns(cfg, attn_impl, donate, policy="low_confidence_static"):
         logits, kc, vc, extras = unpack(T.forward_decode(
             cfg, params, last, positions, lens, table, kc, vc,
             attn_impl=attn_impl, state=state))
-        keys = sampling.request_keys(base_key, rids, gens)
-        toks = sampling.sample_tokens(logits, keys, temps)
-        return (with_counts(toks, extras), kc, vc, extras.get("state", {}),
-                jnp.where(lens > 0, toks, last))
+        with part("sample"):
+            keys = sampling.request_keys(base_key, rids, gens)
+            toks = sampling.sample_tokens(logits, keys, temps)
+        out = with_counts(toks, extras)
+        with part("sample"):
+            last = jnp.where(lens > 0, toks, last)
+        return out, kc, vc, extras.get("state", {}), last
 
     def decode_block(params, base_key, kc, vc, ids, positions, lens, table,
                      rids, gens, temps, state=None, last=None):
@@ -1525,24 +1540,27 @@ def _serving_fns(cfg, attn_impl, donate, policy="low_confidence_static"):
         Out: per position the chosen token, whether the policy unmasked
         it, its confidence's float32 bits; then the routing counts."""
         bl = cfg.block_len
-        masked, known = ids[:, bl:2 * bl] > 0, ids[:, :bl]
-        if last is not None:
-            opens = ids[:, 2 * bl + 1:] > 0
-            known = jnp.where(opens, known, last[:, :bl])
-            masked = jnp.where(opens, masked, last[:, bl:] > 0)
+        with part("sample"):
+            masked, known = ids[:, bl:2 * bl] > 0, ids[:, :bl]
+            if last is not None:
+                opens = ids[:, 2 * bl + 1:] > 0
+                known = jnp.where(opens, known, last[:, :bl])
+                masked = jnp.where(opens, masked, last[:, bl:] > 0)
         logits, kc, vc, extras = T.forward_decode_block(
             cfg, params, known, masked, positions, lens, table, kc,
             vc, attn_impl=attn_impl)
-        toks, conf = sampling.sample_block(
-            logits, base_key, rids, gens, temps)
-        chosen = sampling.choose_unmask(policy, masked, conf, ids[:, 2 * bl])
-        out = jnp.concatenate([
-            toks.reshape(-1), chosen.astype(jnp.int32).reshape(-1),
-            jax.lax.bitcast_convert_type(conf, jnp.int32).reshape(-1)])
-        if last is not None:
-            last = jnp.where((lens > 0)[:, None], jnp.concatenate(
-                [jnp.where(chosen, toks, known),
-                 (masked & ~chosen).astype(jnp.int32)], axis=1), last)
+        with part("sample"):
+            toks, conf = sampling.sample_block(
+                logits, base_key, rids, gens, temps)
+            chosen = sampling.choose_unmask(policy, masked, conf,
+                                            ids[:, 2 * bl])
+            out = jnp.concatenate([
+                toks.reshape(-1), chosen.astype(jnp.int32).reshape(-1),
+                jax.lax.bitcast_convert_type(conf, jnp.int32).reshape(-1)])
+            if last is not None:
+                last = jnp.where((lens > 0)[:, None], jnp.concatenate(
+                    [jnp.where(chosen, toks, known),
+                     (masked & ~chosen).astype(jnp.int32)], axis=1), last)
         return with_counts(out, extras), kc, vc, {}, last
 
     if cfg.block_len > 1:
@@ -1554,10 +1572,11 @@ def _serving_fns(cfg, attn_impl, donate, policy="low_confidence_static"):
                       table, rids, temps):
         logits, kc, vc, extras = unpack(T.forward_prefill_chunk(
             cfg, params, ids, starts, lens, table, kc, vc))
-        keys = sampling.request_keys(
-            base_key, rids, jnp.zeros_like(rids))
-        return (with_counts(sampling.sample_tokens(logits, keys, temps),
-                            extras), kc, vc, {})
+        with part("sample"):
+            keys = sampling.request_keys(
+                base_key, rids, jnp.zeros_like(rids))
+            toks = sampling.sample_tokens(logits, keys, temps)
+        return with_counts(toks, extras), kc, vc, {}
 
     # the state pools ride behind the batch and are donated with the page
     # pools, and so is the token array wherever a program is handed it (by

@@ -10,6 +10,9 @@ this package holds the implementation:
   ``SGD.train`` and ``trainer/cli.py``;
 - ``tracing``      — Span/Tracer phase timeline (Chrome-trace export)
   + the ``--profile_steps`` ProfileWindow;
+- ``scopes``       — ``part(name)``: the named scopes that say which
+  sublayer issued a device operation, ``part_of`` / ``op_scopes``: their
+  readers (a held program's operations by part);
 - ``introspect``   — the per-process ``--status_port`` HTTP server
   (/metrics /healthz /snapshot /trace) + the Prometheus scrape
   helpers the fleet aggregator uses.
@@ -29,6 +32,11 @@ from paddle_tpu.telemetry.registry import (  # noqa: F401
     record_comm,
     safe_inc,
     swallow,
+)
+from paddle_tpu.telemetry.scopes import (  # noqa: F401
+    op_scopes,
+    part,
+    part_of,
 )
 from paddle_tpu.telemetry.sinks import (  # noqa: F401
     JsonlSink,

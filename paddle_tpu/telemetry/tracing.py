@@ -49,7 +49,10 @@ Instrumented phase boundaries (all behind the same flag):
   import, booked when the global tracer is armed); a serving replica's
   ``engine_init`` (``params_bytes``, ``pool_bytes``, ``state_bytes``)
   and ``engine_ready`` around one ``program_ready`` (``program``,
-  ``rows``, ``length``) a compiled program; the trainer's
+  ``rows``, ``length``; ``routes`` and ``op_scopes``: what the held
+  program was built with and which of its operations are whose,
+  ``scopes.compile_described``) a compiled program — the trainer's
+  step program too (``program="step"``), once a feed signature; the trainer's
   ``train_setup`` from the top of ``SGD.train`` to its first ``step``,
   around ``build_step``, ``place_state`` (``arrays``, ``bytes``), the
   ``restore`` span and ``params_sync`` (``arrays``, ``bytes``; also the
@@ -110,6 +113,8 @@ import functools
 import threading
 import time
 
+from paddle_tpu.telemetry.scopes import scope_counts
+
 # spans the ring keeps by default; at ~120 bytes/span this is ~1 MB
 DEFAULT_RING = 8192
 
@@ -158,7 +163,8 @@ class Span:
         if self.parent_id is not None:
             args["parent"] = self.parent_id
         if self.args:
-            args.update(self.args)
+            # a held program's ``op_scopes`` as its counts, not its lists
+            args.update(scope_counts(self.args))
         return {
             "name": self.name, "cat": self.cat, "ph": "X",
             "ts": round(self.t_start * 1e6, 3),

@@ -15,7 +15,9 @@ Job modes:
 - ``test``: forward over the test source, printing cost + evaluators
   (≅ Trainer::test / Tester.cpp).
 - ``time``: ``--job=time`` benchmark of the train step
-  (≅ TrainerBenchmark.cpp), ms/batch via the two-point method.
+  (≅ TrainerBenchmark.cpp): ms/batch on the device's own clock and the
+  step's split by part (layer type, cost, update; forward | backward),
+  or the two-point method where no device trace is to be had.
 - ``checkgrad``: finite-difference vs ``jax.grad`` on every parameter
   (≅ Trainer::checkGradient, Trainer.cpp:332); exits nonzero on mismatch.
 """
@@ -700,7 +702,8 @@ def cmd_time(args, parsed) -> int:
                                  name=os.path.basename(args.config))
         return res.seconds_per_step * 1000.0
 
-    ms, how, why = profiler.step_ms_with_fallback(stateful, wall)
+    parts: list = []    # the device step's split by sublayer
+    ms, how, why = profiler.step_ms_with_fallback(stateful, wall, parts=parts)
     if why:
         from paddle_tpu.core import logger as log
 
@@ -720,6 +723,10 @@ def cmd_time(args, parsed) -> int:
         }, kind="bench")
     print(f"TrainerBenchmark {args.config}: {ms:.3f} ms/batch "
           f"(batch_size={batch_size}, {how})")
+    if parts:
+        # which layer types, the cost and the update the device's time
+        # went to (telemetry/scopes.py), forward | backward
+        print(profiler.format_parts(parts))
     return 0
 
 

@@ -29,6 +29,7 @@ from paddle_tpu import compat
 from paddle_tpu.config.topology import Topology
 from paddle_tpu.layers.base import is_sequence, raw
 from paddle_tpu.parallel.mesh import MeshContext
+from paddle_tpu.telemetry.scopes import part, scoped
 
 
 def _metric_parts(metric_specs, values) -> dict[str, tuple]:
@@ -205,11 +206,12 @@ def build_train_step(topology: Topology, optimizer,
             allp = {**static_c, **tp}
             values, new_states = topology.forward(
                 allp, states, feed_c, True, key)
-            cost = functools.reduce(
-                lambda a, b: a + b,
-                [jnp.sum(values[n], dtype=jnp.float32) for n in out_names]
-            )
-            parts = _metric_parts(metric_specs, values)
+            with part("loss"):
+                cost = functools.reduce(
+                    lambda a, b: a + b,
+                    [jnp.sum(values[n], dtype=jnp.float32)
+                     for n in out_names])
+                parts = _metric_parts(metric_specs, values)
             fetch = {f"layer:{n}": jax.lax.stop_gradient(values[n])
                      for n in fetch_layers if n in values}
             return cost, (new_states, parts, fetch)
@@ -220,6 +222,7 @@ def build_train_step(topology: Topology, optimizer,
         )(tp)
         return cost, new_states, parts, fetch, grads
 
+    @scoped("update")
     def apply_update(grads, train_p, opt_state, gspecs):
         """Optimizer update (+ ZeRO constraints); returns
         (new_train, new_opt) with new_train back at its base layout."""

@@ -26,6 +26,7 @@ from paddle_tpu.core.parameters import Parameters
 from paddle_tpu.layers.base import LayerOutput
 from paddle_tpu.parallel.mesh import MeshContext, get_mesh
 from paddle_tpu.reader.feeder import DataFeeder, parse_seq_buckets
+from paddle_tpu.telemetry import scopes
 from paddle_tpu.telemetry import tracing as tracing_mod
 from paddle_tpu.trainer import event as v2_event
 from paddle_tpu.trainer.step import build_eval_step, build_train_step
@@ -992,10 +993,22 @@ class SGD:
                     if telem is not None and telem.registry.active:
                         # FLOPs/bytes/comm of THIS signature's program
                         # (cached; lower() only traces — the live args are
-                        # not read)
+                        # not read).  Under an armed tracer that lowering
+                        # is a kept ``program_ready`` span with the
+                        # program's ``routes`` and ``op_scopes``: its
+                        # ``compile()`` is what the dispatch below fetches
+                        def lower_step():
+                            lower = lambda: self._train_step.lower(
+                                params, opt_state, states, feed, step_key)
+                            if not tracer.enabled:
+                                return lower()
+                            with tracer.timed("program_ready",
+                                              program="step") as one:
+                                return scopes.compile_described(one,
+                                                                lower)[0]
+
                         step_flops, step_bytes, step_comm = telem.cost_for(
-                            sig, lambda: self._train_step.lower(
-                                params, opt_state, states, feed, step_key))
+                            sig, lower_step)
                     else:
                         step_flops, step_bytes, step_comm = 0.0, 0.0, {}
                     if self._tap_grads is not None:
